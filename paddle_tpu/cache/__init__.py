@@ -24,8 +24,12 @@ monitor-registry counters additionally tick when FLAGS_monitor is on
 (the disabled-mode contract keeps the registry untouched otherwise).
 """
 
+import os
 import pickle
 from collections import OrderedDict
+
+import jax
+from jax.experimental import serialize_executable as _se
 
 from .. import flags
 from . import service
@@ -33,8 +37,7 @@ from .keys import environment, program_digest, stable_digest
 from .store import L2Store
 
 __all__ = ["CompileCache", "L2Store", "default_store", "environment",
-           "program_digest", "serialize_support", "service",
-           "stable_digest"]
+           "place_jax_cache", "program_digest", "service", "stable_digest"]
 
 flags.define(
     "compile_cache_dir", str, "",
@@ -51,21 +54,22 @@ flags.define(
     "write the directory is pruned oldest-used-first (mtime LRU) down "
     "to the cap; <= 0 leaves it unbounded.")
 
-_SE_UNSET = object()
-_se_mod = [_SE_UNSET]
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def serialize_support():
-    """jax.experimental.serialize_executable, or None when this jax build
-    doesn't ship it — L2 then degrades to disabled instead of raising."""
-    if _se_mod[0] is _SE_UNSET:
-        try:
-            from jax.experimental import serialize_executable as se
-
-            _se_mod[0] = se
-        except Exception:
-            _se_mod[0] = None
-    return _se_mod[0]
+def place_jax_cache():
+    """Place JAX's own persistent compilation cache; both executors call
+    this from their constructors. Where JAX_COMPILATION_CACHE_DIR is set
+    (jax reads it into jax_compilation_cache_dir at import) the cache
+    lives there and this sets nothing. Otherwise it is the fixed path
+    <checkout>/.jax_cache (git-ignored): the directory is part of what a
+    later process must find again, so it is never built from tempfile, a
+    pid or the time. Returns the directory in effect."""
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
 
 
 def default_store():
@@ -189,8 +193,7 @@ class CompileCache:
 
     # -- L2 ------------------------------------------------------------
     def l2_enabled(self):
-        return bool(flags.get("compile_cache_dir")) \
-            and serialize_support() is not None
+        return bool(flags.get("compile_cache_dir"))
 
     def store(self):
         return default_store()
@@ -203,13 +206,16 @@ class CompileCache:
             program, key_tail,
             extra=(("kind", self.kind),) + tuple(extra))
 
-    def l2_load(self, digest, mon=None):
-        """Deserialize one stored executable into a callable Compiled.
-        None on miss or fallback (corrupt / version-stale / deserialize
-        failure) — counted, never raised."""
+    def l2_load(self, digest, devices, mon=None):
+        """Deserialize one stored executable into a callable Compiled,
+        loaded onto `devices` — the executor's own device assignment, in
+        mesh order. Without it jax loads onto every device of the default
+        backend, and a one-device step reloaded in a process that sees
+        eight (or on a four-chip host) expects eight shards. None on miss
+        or fallback (corrupt / version-stale / deserialize failure) —
+        counted, never raised."""
         store = self.store()
-        se = serialize_support()
-        if store is None or se is None or digest is None:
+        if store is None or digest is None:
             return None
         outcome, payload, _header = store.get(digest)
         if outcome == "miss":
@@ -221,9 +227,12 @@ class CompileCache:
         elif outcome != "hit":
             self.count_l2_fallback(mon, reason=outcome)
             return None
+        devices = list(devices)
         try:
             parts = pickle.loads(payload)
-            compiled = se.deserialize_and_load(*parts)
+            compiled = _se.deserialize_and_load(
+                *parts, backend=devices[0].client,
+                execution_devices=devices)
         except Exception:
             self.count_l2_fallback(mon, reason="deserialize")
             return None
@@ -290,12 +299,11 @@ class CompileCache:
 
         def sink(compiled_exe):
             store = self.store()
-            se = serialize_support()
-            if store is None or se is None:
+            if store is None:
                 return
             try:
                 payload = pickle.dumps(
-                    se.serialize(compiled_exe),
+                    _se.serialize(compiled_exe),
                     protocol=pickle.HIGHEST_PROTOCOL)
                 max_mb = int(flags.get("compile_cache_dir_max_mb"))
                 nbytes = store.put(
@@ -321,9 +329,10 @@ class CompileCache:
         """Wrap a deserialized executable so a latent incompatibility the
         header checks can't see (aval/sharding/device-assignment drift)
         surfaces on the FIRST call — jax validates arguments before
-        dispatch, so the TypeError/ValueError arrives with no buffer
-        donated yet and it is safe to rebuild fresh and retry. After one
-        clean call the loaded executable is trusted unguarded."""
+        dispatch (TypeError/ValueError) and the runtime rejects a shard or
+        device mismatch before it executes (JaxRuntimeError), so no buffer
+        has been donated yet and it is safe to rebuild fresh and retry.
+        After one clean call the loaded executable is trusted unguarded."""
         box = [None]
 
         def call(*args):
@@ -331,7 +340,7 @@ class CompileCache:
                 return box[0](*args)
             try:
                 out = loaded(*args)
-            except (TypeError, ValueError):
+            except (TypeError, ValueError, jax.errors.JaxRuntimeError):
                 self.count_l2_fallback(mon, reason="call")
                 box[0] = rebuild()
                 return box[0](*args)

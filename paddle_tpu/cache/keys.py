@@ -11,10 +11,10 @@ order) and appends everything else that changes the compiled executable:
     ParallelExecutor — zero1/overlap/autoshard digests), which is already
     process-stable by construction (sorted tuples of primitives; no id()s,
     no hash()es)
-  * the runtime environment: jax + jaxlib versions and the backend
-    platform (an executable serialized by one XLA build must never be fed
-    to another — the store ALSO stamps these in the entry header and
-    re-checks at load)
+  * the runtime environment: jax + jaxlib versions, the backend platform
+    and the PJRT platform_version, i.e. the libtpu build (an executable
+    serialized by one XLA build must never be fed to another — the store
+    ALSO stamps these in the entry header and re-checks at load)
   * the device geometry the caller passes as `extra` (device ids, mesh
     axis names/sizes): a serialized executable is bound to its device
     assignment, so a resized mesh takes a clean miss instead of a
@@ -62,17 +62,19 @@ def program_digest(program):
 
 
 def environment():
-    """(jax, jaxlib, backend platform) triple stamped into every entry and
-    folded into every digest — a version bump is an automatic cold start."""
+    """(jax, jaxlib, backend platform, PJRT platform_version) stamped into
+    every entry and folded into every digest — a version bump is an
+    automatic cold start. platform_version carries the runtime build (on a
+    TPU the libtpu build date and change number): jax and jaxlib can agree
+    while libtpu differs, and an AOT blob from another libtpu does not
+    load."""
     import jax
     import jaxlib
+    from jax.extend import backend as jax_backend
 
-    backend = "unknown"
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        pass
-    return (jax.__version__, jaxlib.__version__, backend)
+    client = jax_backend.get_backend()
+    return (jax.__version__, jaxlib.__version__, client.platform,
+            client.platform_version)
 
 
 def stable_digest(program, key_tail, extra=()):

@@ -9,7 +9,8 @@ touching the payload):
     magic   b"PTAC1\\n"
     8 bytes big-endian header length
     header  JSON (utf-8): digest, kind, created, jax, jaxlib, backend,
-            device_count, device_ids, payload_bytes, payload_sha256, meta
+            runtime (PJRT platform_version), payload_bytes,
+            payload_sha256, meta
     payload pickle((jax_serialized_executable, in_tree, out_tree))
 
 Writes commit atomically — tmp file in the same directory, fsync, then
@@ -19,7 +20,7 @@ successful read touches the entry's mtime, making directory pruning
 (size cap, oldest-mtime-first) true LRU rather than FIFO.
 
 get() NEVER raises on a bad entry: corruption, a truncated header, a
-jax/jaxlib/backend mismatch, or a payload checksum failure all come back
+jax/jaxlib/backend/runtime mismatch, or a payload checksum failure all come back
 as a ("corrupt" | "stale") outcome for the caller to count as a fallback
 and recompile over. The only exceptions that escape are programming
 errors, not cache-content errors.
@@ -75,9 +76,10 @@ class L2Store:
             return "corrupt", None, None
         if payload is None or _sha256(payload) != header.get("payload_sha256"):
             return "corrupt", None, header
-        jx, jl, backend = environment()
+        jx, jl, backend, runtime = environment()
         if (header.get("jax") != jx or header.get("jaxlib") != jl
-                or header.get("backend") != backend):
+                or header.get("backend") != backend
+                or header.get("runtime") != runtime):
             return "stale", None, header
         try:
             # LRU recency stamp: pruning deletes oldest-mtime first
@@ -110,7 +112,7 @@ class L2Store:
         """Atomically commit one entry; returns bytes written (whole
         file). Prunes the directory to max_bytes (oldest mtime first)
         after the commit when a cap is given."""
-        jx, jl, backend = environment()
+        jx, jl, backend, runtime = environment()
         header = {
             "digest": digest,
             "kind": kind,
@@ -118,6 +120,7 @@ class L2Store:
             "jax": jx,
             "jaxlib": jl,
             "backend": backend,
+            "runtime": runtime,
             "payload_bytes": len(payload),
             "payload_sha256": _sha256(payload),
             "meta": meta or {},
@@ -177,7 +180,7 @@ class L2Store:
         """Commit a whole-file blob fetched from a peer, re-validating
         magic, framing, digest binding, and the payload checksum BEFORE
         the commit — a corrupt or mislabeled publish must not poison
-        this cache. Environment (jax/jaxlib/backend) is NOT checked
+        this cache. Environment (jax/jaxlib/backend/runtime) is NOT checked
         here: get() refuses stale entries on read, same as local ones.
         Returns True on commit."""
         blob = bytes(blob)

@@ -1242,12 +1242,15 @@ def _cmd_fleet_router(args):
         obs_client = obs_mod.maybe_start("router", endpoint=args.obs)
     autoscaler = None
     if args.autoscale_model_dir:
-        import tempfile
-
+        from .cache import place_jax_cache
         from .serve.fleet import (Autoscaler, AutoscalerConfig,
                                   ProcessReplicaSpawner)
 
-        workdir = tempfile.mkdtemp(prefix="fleet_autoscale_")
+        # port files and the per-replica L2 stores live under the same
+        # root as JAX's own cache, named by this router's listen address:
+        # a restarted router finds its replicas' caches again
+        workdir = os.path.join(place_jax_cache(), "fleet_autoscale",
+                               f"{args.host}_{args.port}")
         argv_base = [sys.executable, "-m", "paddle_tpu", "fleet",
                      "replica", "--model-dir", args.autoscale_model_dir,
                      "--place", "cpu", "--port", "0"]

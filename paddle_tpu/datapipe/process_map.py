@@ -3,8 +3,8 @@
 Sibling to ParallelMap with the same public contract (bounded in-flight
 tickets, ordered emission, object-level close()/join_workers() for the
 DataPipe 3-phase shutdown) but the workers are OS processes, so pure-
-Python decode that never releases the GIL still scales. BENCH_r05 showed
-the thread path capped at 0.72 of device rate by exactly that.
+Python decode that never releases the GIL still scales past the thread
+path's ceiling.
 
 Two modes:
 
@@ -37,7 +37,14 @@ resilience.chaos fires `worker_kill` faults through the
 `on_map_dispatch` hook below.
 
 Start method: fork by default (fn needn't pickle; decode closures work),
-FLAGS_datapipe_start_method=spawn for libraries that dislike fork.
+FLAGS_datapipe_start_method=spawn for libraries that dislike fork. The
+workers are forked from a parent that usually holds the TPU client
+already (bench.py and chip_smoke.py touch the device first and build the
+pipe second): on the v5e host that works and shuts down clean, and
+chip_smoke.py's train phase re-checks it on every run. Python and JAX both
+warn about fork in a multi-threaded process; what keeps it safe here is
+that a worker runs only the decode fn and the shm writer — it must never
+touch jax, whose client state it inherited but does not own.
 """
 
 import os
@@ -302,7 +309,15 @@ class ProcessPoolMap:
                 pass
         ring = state.get("ring")
         if ring is not None:
-            ring.close()  # workers joined: safe to unlink
+            # workers joined: chunks still assembling are abandoned and
+            # their slots go back; chunks already emitted keep theirs (and
+            # their memory) until the consumer releases the lease
+            with state["cond"]:
+                abandoned = list(state["chunk_lease"].values())
+                state["chunk_lease"].clear()
+            for lease in abandoned:
+                lease.release()
+            ring.close()
         if self._active is state:
             self._active = None
         return ok

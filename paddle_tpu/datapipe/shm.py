@@ -20,7 +20,14 @@ Ownership protocol:
     map->device path that consumer is the feeder, which releases after
     `device_put` + `block_until_ready` (or after its defensive host copy
     on aliasing XLA:CPU backends);
-  * `close()` closes and unlinks every segment (idempotent). Worker
+  * `close()` unlinks every segment at once and unmaps each one as soon
+    as none of its slots is leased (idempotent): a chunk the feeder is
+    still moving to the device keeps its memory until the lease is
+    released. numpy views do not pin the mapping (numpy keeps a reference
+    to the buffer object, not an export of it), so unmapping under a live
+    transfer is a SIGSEGV inside the TPU client's host-side copy — with
+    two transfer lanes the lane that finds the source exhausted tears the
+    map stage down while the other is still in device_put. Worker
     processes merely close their attachments.
 
 Segment names carry the `ptpipe_` prefix so leaked segments are greppable
@@ -182,17 +189,19 @@ class ShmRing:
         """Next free slot index, or None after `timeout` (caller re-polls
         so stop flags stay responsive)."""
         with self._cond:
-            if not self._free:
+            if not self._free and not self._closed:
                 self._cond.wait(timeout)
-            if not self._free:
+            if not self._free or self._closed:
                 return None
             return self._free.pop()
 
     def release(self, slot):
         with self._cond:
-            if not self._closed and slot not in self._free:
+            if slot not in self._free:
                 self._free.append(slot)
                 self._cond.notify()
+                if self._closed:
+                    self._unmap_idle()
 
     def lease(self, slot):
         return SlotLease(self, slot)
@@ -209,26 +218,43 @@ class ShmRing:
                                    offset=off)
         return out
 
+    @property
+    def mapped(self):
+        """Segments this process still has mapped."""
+        with self._cond:
+            return sum(seg is not None for seg in self._segs)
+
+    def _unmap_idle(self):
+        """Under the lock, after close(): unmap every segment none of
+        whose slots is still leased."""
+        free = set(self._free)
+        for i, seg in enumerate(self._segs):
+            lo = i * self._coalesce
+            hi = min(lo + self._coalesce, self._n_slots)
+            if seg is not None and free.issuperset(range(lo, hi)):
+                try:
+                    seg.close()
+                except BufferError:
+                    continue  # the buffer is still exported: stay mapped
+                self._segs[i] = None
+
     def close(self):
-        """Close + unlink every segment (idempotent). Call after worker
-        processes are joined; POSIX keeps the memory alive for any
-        straggler mapping until its last close."""
+        """Unlink every segment and unmap those with no leased slot; the
+        rest are unmapped by the release() of their last lease
+        (idempotent). Call after worker processes are joined; POSIX keeps
+        the memory alive for any mapping until its last close."""
         with self._cond:
             if self._closed:
                 return
             self._closed = True
             self._cond.notify_all()
-        for seg in self._segs:
-            try:
-                seg.close()
-            except Exception:
-                pass
-            try:
-                seg.unlink()
-            except Exception:
-                pass
-            _unregister(seg.name)
-        self._segs = []
+            for seg in self._segs:
+                try:
+                    seg.unlink()
+                except FileNotFoundError:
+                    pass
+                _unregister(seg.name)
+            self._unmap_idle()
 
 
 class _MMapSeg:

@@ -1,12 +1,11 @@
 """Transfer engine: compressed wire formats for the host->device link.
 
-The fed path is link-bound, not device-bound (BENCH_r05: the compiled
-device loop runs 2959 img/s while the measured single-stream link moves
-56 MB/s, a 372 img/s ceiling for float32 image chunks). The only host-side
-lever that raises that ceiling is shrinking bytes-per-sample ON THE WIRE:
-ship each feed in a compact wire dtype (uint8 pixels, bf16 activations)
-and fuse the cast + affine normalize into the compiled step, where XLA
-folds it into the first consumer for free.
+Where the fed path is bound by the host->device link, the host-side lever
+that raises its ceiling is shrinking bytes-per-sample ON THE WIRE: ship
+each feed in a compact wire dtype (uint8 pixels, bf16 activations) and
+fuse the cast + affine normalize into the compiled step, where XLA folds
+it into the first consumer for free. (Whether the link binds on the
+current host has not been measured.)
 
 A WireSpec maps feed names to WireFormats. It rides the pipeline in two
 places:

@@ -117,11 +117,11 @@ define("fold_ema_multi_step", bool, False,
        "OUT of the lax.scan carry (they are pure EMA recurrences, read by "
        "nothing else in a training program) and reconstruct the exact "
        "K-step fold after the scan. Built to shrink the scan's back-edge "
-       "copy set (docs/perf_r04.md residual) but measured NO gain on the "
-       "bench chip (ResNet-50 bs128 K=40: 2938 on vs 2944-2950 off — the "
+       "copy set; showed no gain on ResNet-50 on an older stack (the "
        "stacked per-step stats + post-scan fold cost what the copies "
-       "saved; docs/perf_r05.md). Default OFF, kept as an opt-in for "
-       "topologies with much larger normalization state.")
+       "saved) and has not been re-measured on the current host. Default "
+       "OFF, kept as an opt-in for topologies with much larger "
+       "normalization state.")
 define("pack_small_state", bool, False,
        "Under Executor.run(iters=K), carry all small (<=64Ki elems) float "
        "mut-state entries as ONE concatenated buffer per dtype instead of "
@@ -129,11 +129,10 @@ define("pack_small_state", bool, False,
        "fuse into consumers, and the per-parameter optimizer updates "
        "concatenate into the donated packed carry — the "
        "aliasing-preserving answer to the suspected launch-bound update "
-       "kernels. Measured NO gain (2951 vs 2959 img/s, ResNet-50 NHWC "
-       "K=40): traces show the eliminated 85 kernels/step reappear inside "
-       "the conv fusions — the step is scheduler-bound, not launch-bound "
-       "(docs/perf_r05.md). Default OFF; the mechanism stays for "
-       "topologies with far more small state.")
+       "kernels. Showed no gain on ResNet-50 on an older stack (the time "
+       "of the eliminated kernels reappeared inside the conv fusions); "
+       "not re-measured on the current host. Default OFF; the mechanism "
+       "stays for topologies with far more small state.")
 define("monitor", bool, True,
        "Step-level training telemetry (paddle_tpu.monitor): per-step phase "
        "breakdown, compile-cache hit/miss accounting, datapipe merge, "
@@ -151,8 +150,8 @@ define("compile_cache_cap", int, 0,
        "that churn program shapes and silently re-compile.")
 define("fuse_optimizer_ops", bool, False,
        "Batch identical small-parameter optimizer updates (sgd/momentum) "
-       "into one kernel call over concatenated flats. Default OFF: on the "
-       "bench chip the slice-back defeats XLA's in-place donation aliasing "
-       "and measures NET SLOWER on ResNet-50 (2767 -> 2583 img/s) even "
-       "though the per-update kernels are launch-overhead-bound; kept as "
-       "an opt-in for topologies dominated by thousands of tiny params.")
+       "into one kernel call over concatenated flats. Default OFF: on an "
+       "older stack the slice-back defeated XLA's in-place donation "
+       "aliasing and ResNet-50 ran slower with it on (not re-measured on "
+       "the current host); kept as an opt-in for topologies dominated by "
+       "thousands of tiny params.")

@@ -42,7 +42,9 @@ flags.define(
     "On every compile-cache miss, lower the step once more and record the "
     "HLO cost analysis (FLOPs + bytes accessed) per program fingerprint — "
     "the model-FLOPs source for MFU accounting (bench.py). Off by "
-    "default: the extra lowering roughly doubles trace time per compile.")
+    "default: the extra lowering roughly doubles trace time per compile, "
+    "and on a TPU, where only a compiled executable can be analysed, it "
+    "costs one more compile of the step.")
 flags.define(
     "monitor_replica_skew", bool, False,
     "Measure per-replica step-completion times on the ParallelExecutor "
@@ -203,10 +205,12 @@ def compile_probe(fingerprint):
     record the HLO cost analysis under `fingerprint`."""
 
     def probe(jitted, args):
-        try:
-            ca = jitted.lower(*args).cost_analysis()
-        except Exception:
-            return
+        lowered = jitted.lower(*args)
+        ca = lowered.cost_analysis()
+        if ca is None:
+            # the TPU client (PJRT C API, libtpu 0.0.34) analyses compiled
+            # executables only: Lowered.cost_analysis() is None there
+            ca = lowered.compile().cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
         if not isinstance(ca, dict):

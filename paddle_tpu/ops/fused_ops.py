@@ -31,8 +31,10 @@ Two families:
   On an all-f32 bucket with no ambient device mesh, adam and momentum
   dispatch to a Pallas TPU kernel (paddle_tpu.fusion.kernels): one
   (8,128)-blocked VMEM pass over the bucket instead of XLA's generic
-  loop fusion. Interpret mode keeps CPU semantics identical; the
-  `fuse_pallas` flag (defined by paddle_tpu.fusion) turns it off.
+  loop fusion. On a TPU place Mosaic compiles it; on any other place it
+  runs interpreted (bitwise on the chip, a few ulp on the adam parameter
+  on the CPU — see fusion/kernels.py). The `fuse_pallas` flag (defined
+  by paddle_tpu.fusion) turns it off.
 """
 
 import jax
@@ -40,6 +42,7 @@ import jax.numpy as jnp
 
 from .. import flags
 from ..core import registry
+from ..core.places import ambient_mesh
 from ..core.registry import register_op
 from .util import first, many, out
 
@@ -58,14 +61,7 @@ def _pallas_ok():
     """Pallas buckets only fire OUTSIDE an ambient mesh: a GSPMD-sharded
     operand cannot feed pallas_call without an explicit shard_map, and
     the zero1 shard layout already keeps the jnp path one fused loop."""
-    if not _flag("fuse_pallas", True):
-        return False
-    try:
-        from jax._src.mesh import thread_resources
-
-        return thread_resources.env.physical_mesh.empty
-    except Exception:
-        return False
+    return bool(_flag("fuse_pallas", True)) and ambient_mesh() is None
 
 
 def _pack(vals, rows):

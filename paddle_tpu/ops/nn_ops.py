@@ -206,15 +206,14 @@ def batch_norm_op(ctx, ins, attrs):
         xf = x.astype(jnp.float32)
         # one-pass statistics: E[x] and E[x^2] reduce the SAME read of
         # the activation, so XLA fuses both into a single HBM sweep —
-        # jnp.var's E[(x-mean)^2] forces a second full read (measured
-        # ~7.6 ms/step of BN stat reductions on ResNet-50 bs128; one-pass
-        # is worth +5.7% end to end). Numerical boundary, chosen with
-        # measurements (docs/perf_r04.md): the naive difference form loses
-        # the variance to fp32 cancellation when |mean|/std exceeds ~2^12
-        # — far outside post-conv BN inputs. Shifted variants that close
-        # that corner were measured and rejected: running-mean shift -5%,
-        # first-sample shift -19% (the shifted stats path can no longer
-        # share its read with the normalize path).
+        # jnp.var's E[(x-mean)^2] forces a second full read. Numerical
+        # boundary: the naive difference form loses the variance to fp32
+        # cancellation when |mean|/std exceeds ~2^12 — far outside
+        # post-conv BN inputs (tests/test_ops_nn.py holds the supported
+        # regime). Shifted variants that close that corner can no longer
+        # share the stats read with the normalize path; they ran slower
+        # on an older stack and have not been re-measured on the current
+        # host.
         m = jnp.mean(xf, axis=axes)
         msq = jnp.mean(jnp.square(xf), axis=axes)
         v = jnp.maximum(msq - jnp.square(m), 0.0)

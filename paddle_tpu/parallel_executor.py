@@ -38,10 +38,11 @@ from . import amp
 from . import analysis
 from . import flags
 from . import monitor
-from .cache import CompileCache
+from .cache import CompileCache, place_jax_cache
 from .core import executor_core
 from .core.framework import Parameter, Variable, default_main_program
 from .core.lod_tensor import LoDTensor
+from .core.places import accelerator_devices
 from .core.registry import SeqTensor
 from .core.scope import global_scope
 from .executor import as_numpy, _apply_debug_nans
@@ -119,12 +120,12 @@ class ParallelExecutor:
             # explicit device subset — the elastic resize path re-forms a
             # smaller mesh over the survivors' device slots
             accel_devs = list(devices)
+        elif accel:
+            # same rule as TPUPlace: the accelerators, or the host devices
+            # under an explicit CPU pin — never a silent CPU stand-in
+            accel_devs = accelerator_devices(jax.devices())
         else:
-            devs = jax.devices()
-            if accel:
-                accel_devs = [d for d in devs if d.platform != "cpu"] or devs
-            else:
-                accel_devs = devs
+            accel_devs = jax.devices()
         self._devices = accel_devs
         if mesh_shape:
             # user-declared multi-axis mesh ({"dp": 2, "mp": 4}); variables
@@ -140,6 +141,7 @@ class ParallelExecutor:
                 tuple(n for n, _ in axes))
         else:
             self._mesh = Mesh(np.array(self._devices), ("dp",))
+        place_jax_cache()
         self._compile_cache = CompileCache("parallel_executor")
         # zero1/grad-scale rewritten program clones, keyed on the source
         # program identity + mutation counter; strong refs keep id() stable
@@ -625,7 +627,8 @@ class ParallelExecutor:
                     donate_feeds=donate_feeds, probe=probe,
                     aot=cache_obj.aot_sink(export_digest))
 
-            loaded = cache_obj.l2_load(digest, mon=mon) \
+            loaded = cache_obj.l2_load(
+                digest, self._mesh.devices.flat, mon=mon) \
                 if digest is not None else None
             if loaded is not None:
                 # warm start (fleet replica spin-up, resilience restore,

@@ -78,7 +78,7 @@ _FATAL_TYPES = (ValueError, TypeError, KeyError, IndexError,
 
 
 def register_transient(exc_type):
-    """Mark an exception type as always-transient (plugin backends)."""
+    """Mark an exception type as always-transient."""
     if exc_type not in _TRANSIENT_TYPES:
         _TRANSIENT_TYPES.append(exc_type)
 

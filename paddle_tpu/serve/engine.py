@@ -507,9 +507,9 @@ class Server:
         self._gauge("serve_ready").set(0)
 
     def _replica_place(self, i):
-        """Replica i's device: TPUPlace(i) walks the accelerator list (and
-        on an all-CPU host, XLA's virtual host devices); a CPU server
-        keeps every replica on the host place."""
+        """Replica i's place: an accelerator server gives each replica its
+        own device, TPUPlace(device_id + i); a CPU server keeps every
+        replica on the host place."""
         if isinstance(self.place, TPUPlace):
             return type(self.place)(
                 (getattr(self.place, "device_id", 0) + i))
@@ -518,21 +518,24 @@ class Server:
     def _build_replicas(self):
         """Replica 0 serves from the caller's scope; further replicas get
         a scope holding device-local copies of every persistable var (the
-        round-robin fan-out — each replica owns one device end to end)."""
+        round-robin fan-out — each replica owns one device end to end).
+        More replicas than the place has devices is an error
+        (jax_device_for raises; replicas never share or wrap a device)."""
         import jax
 
         from ..core.places import jax_device_for
 
+        places = [self._replica_place(i)
+                  for i in range(self.config.replicas)]
+        devices = [jax_device_for(p) for p in places]
         persistables = [
             n for n, v in self.program.global_block().vars.items()
             if v.persistable and self.scope.find_var(n) is not None]
-        for i in range(self.config.replicas):
-            place = self._replica_place(i)
+        for i, (place, dev) in enumerate(zip(places, devices)):
             if i == 0:
                 scope = self.scope
             else:
                 scope = Scope()
-                dev = jax_device_for(place)
                 for n in persistables:
                     scope.set_var(n, jax.device_put(
                         np.asarray(self.scope.find_var(n)), dev))

@@ -36,6 +36,7 @@ autoscale drill proves the whole loop against real processes under a
 `load_spike` chaos surge.
 """
 
+import argparse
 import math
 import os
 import subprocess
@@ -307,6 +308,14 @@ class Autoscaler:
                 "spawned": list(self._spawned)}
 
 
+def _argv_place(argv):
+    """The --place a `fleet replica` command line asks for (cli default:
+    cpu)."""
+    parser = argparse.ArgumentParser(add_help=False, allow_abbrev=False)
+    parser.add_argument("--place", default="cpu")
+    return parser.parse_known_args(list(argv))[0].place
+
+
 class ProcessReplicaSpawner:
     """Spawn `paddle_tpu fleet replica` subprocesses for scale-out.
 
@@ -321,6 +330,14 @@ class ProcessReplicaSpawner:
     `workdir` — a fresh host's L2 starts empty, so warm start must come
     through fetch_compiled, never a shared filesystem (this is what the
     drill's compile_cache_misses == 0 assertion actually proves).
+
+    One process per chip: a `--place tpu` replica takes TPUPlace(0) and
+    with it every chip of the host (a second process that asks for the
+    chips fails at start-up, measured on a v5e host: rc 1 after ~3 s).
+    Nothing here confines a child to one chip, so while one chip-holding
+    child is alive a second launch is refused with a RuntimeError instead
+    of being started to die — serve more chips from ONE replica process
+    with `--replicas N`.
     """
 
     def __init__(self, argv_base, workdir, name_prefix="as", env=None,
@@ -331,6 +348,7 @@ class ProcessReplicaSpawner:
         self.env = dict(env) if env is not None else None
         self.per_replica_cache = bool(per_replica_cache)
         self.start_timeout_s = float(start_timeout_s)
+        self.holds_chip = _argv_place(self.argv_base) == "tpu"
         self.procs = {}      # name -> Popen
         self.endpoints = {}  # name -> host:port
         self.exit_codes = {}
@@ -344,6 +362,15 @@ class ProcessReplicaSpawner:
             return name
 
     def _launch(self, name):
+        if self.holds_chip:
+            live = [n for n, p in self.procs.items() if p.poll() is None]
+            if live:
+                raise RuntimeError(
+                    f"refusing to start replica {name} with --place tpu: "
+                    f"replica {live[0]} of this spawner already holds "
+                    "this host's chips, and a chip belongs to one process "
+                    "at a time. Serve several chips from one replica "
+                    "process (--replicas N), or spawn --place cpu replicas.")
         os.makedirs(self.workdir, exist_ok=True)
         port_file = os.path.join(self.workdir, f"{name}.port")
         try:

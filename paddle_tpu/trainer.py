@@ -9,7 +9,7 @@ import os
 from . import core
 from . import monitor as monitor_mod
 from .core.framework import Program, program_guard, default_main_program, default_startup_program
-from .core.places import CPUPlace, TPUPlace
+from .core.places import TPUPlace
 from .core.scope import Scope, global_scope, scope_guard
 from .executor import Executor
 from .parallel_executor import ParallelExecutor
@@ -74,12 +74,10 @@ def _pipe_stats(pipe):
 
 
 def check_and_get_place(place):
-    """reference trainer.py check_and_get_place — prefer the accelerator."""
-    if place is None:
-        from .core.places import is_compiled_with_tpu
-
-        return TPUPlace(0) if is_compiled_with_tpu() else CPUPlace()
-    return place
+    """reference trainer.py check_and_get_place: no place means the
+    accelerator, like Executor(place=None). jax_device_for(TPUPlace(0))
+    raises where there is none and the CPU was not explicitly pinned."""
+    return TPUPlace(0) if place is None else place
 
 
 class Trainer:

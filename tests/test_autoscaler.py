@@ -332,3 +332,36 @@ def test_model_above_half_target_blocks_scale_in():
     now[0] += 1.0
     a.tick()
     assert a.scale_ins == 1
+
+
+def test_spawner_refuses_second_chip_holding_child(tmp_path):
+    """One process per chip: while a `--place tpu` child is alive a second
+    launch is refused at once with a clear error (not started to fail or
+    to wait out start_timeout_s); `--place cpu` children are unlimited."""
+    import sys
+    import time
+
+    from paddle_tpu.serve.fleet import ProcessReplicaSpawner
+
+    sleeper = [sys.executable, "-c", "import time; time.sleep(60)"]
+    chip = ProcessReplicaSpawner(sleeper + ["--place", "tpu"],
+                                 str(tmp_path / "chip"))
+    cpu = ProcessReplicaSpawner(sleeper + ["--place=cpu"],
+                                str(tmp_path / "cpu"))
+    try:
+        assert chip.holds_chip and not cpu.holds_chip
+        first, _ = chip._launch("a0")
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError, match="already holds"):
+            chip._launch("a1")
+        assert time.monotonic() - t0 < 1.0
+        cpu._launch("b0")
+        cpu._launch("b1")
+        # once the chip holder is gone the chips are free again
+        chip.stop(first, timeout_s=10.0)
+        chip._launch("a2")
+    finally:
+        chip.stop_all(timeout_s=10.0)
+        cpu.stop_all(timeout_s=10.0)
+    assert all(p.poll() is not None for p in chip.procs.values())
+    assert all(p.poll() is not None for p in cpu.procs.values())

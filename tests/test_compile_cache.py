@@ -19,14 +19,9 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import flags, monitor
 from paddle_tpu.cache import (CompileCache, L2Store, program_digest,
-                              serialize_support, stable_digest)
+                              stable_digest)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-needs_serialize = pytest.mark.skipif(
-    serialize_support() is None,
-    reason="this jax build ships no serialize_executable")
-
 
 @pytest.fixture(autouse=True)
 def _fresh_monitor():
@@ -158,7 +153,6 @@ print(json.dumps({
 """
 
 
-@needs_serialize
 def test_digest_and_warm_start_stable_across_processes(tmp_path):
     """The two cross-process contracts at once: the same program in two
     processes (with DIFFERENT hash seeds — nothing in the key may lean on
@@ -190,7 +184,6 @@ def test_digest_and_warm_start_stable_across_processes(tmp_path):
     assert warm["loss"] == cold["loss"]
 
 
-@needs_serialize
 def test_flag_flip_changes_l2_key(tmp_path):
     """A config that changes the compiled step (amp here; zero1/autoshard/
     overlap ride the same key tail on the ParallelExecutor) must land on a
@@ -213,7 +206,6 @@ def test_flag_flip_changes_l2_key(tmp_path):
     assert after > before, (before, after)
 
 
-@needs_serialize
 def test_zero1_flag_flips_parallel_executor_l2_key(tmp_path):
     from paddle_tpu.parallel_executor import BuildStrategy, ParallelExecutor
 
@@ -249,7 +241,6 @@ def test_zero1_flag_flips_parallel_executor_l2_key(tmp_path):
 # fallbacks: corrupt / stale entries recompile, never raise
 # ---------------------------------------------------------------------------
 
-@needs_serialize
 def test_corrupt_entry_falls_back_and_self_heals(tmp_path):
     main, startup, loss = _mlp()
     feed = {"x": np.ones((4, 8), np.float32)}
@@ -285,12 +276,18 @@ def test_store_version_mismatch_is_stale(tmp_path, monkeypatch):
     assert store.get(digest)[0] == "hit"
     import paddle_tpu.cache.store as store_mod
 
-    monkeypatch.setattr(store_mod, "environment",
-                        lambda: ("other-jax", "other-jaxlib", "cpu"))
+    real = store_mod.environment()
+    monkeypatch.setattr(
+        store_mod, "environment",
+        lambda: ("other-jax", "other-jaxlib", "cpu", real[3]))
     outcome, payload, header = store.get(digest)
     assert outcome == "stale"
     assert payload is None
     assert header["jax"] != "other-jax"  # the REAL header survives for ls
+    # same jax and jaxlib, another runtime build (libtpu): stale too
+    monkeypatch.setattr(store_mod, "environment",
+                        lambda: real[:3] + ("another libtpu build",))
+    assert store.get(digest)[0] == "stale"
 
 
 def test_store_corrupt_truncated_garbage_and_miss(tmp_path):
@@ -580,7 +577,6 @@ def test_remote_fetch_commits_to_local_l2_and_counts(tmp_path):
         svc.stop()
 
 
-@needs_serialize
 def test_l2_hit_journals_as_hit_with_cache_load_phase(tmp_path):
     """An L2 warm start is a cache HIT in the journal (level "l2") with
     the deserialize time attributed to a cache_load phase, not compile."""

@@ -456,6 +456,32 @@ def test_process_pipe_fused_shm_end_to_end():
     assert datapipe.live_segments() == []
 
 
+def test_shm_ring_close_keeps_leased_slots_mapped():
+    """close() while a chunk is still leased (the feeder is moving it to
+    the device from another transfer lane) unlinks the names but must not
+    unmap that chunk's memory: numpy views do not pin the mapping, and on
+    the TPU host the unmap was a SIGSEGV inside device_put. The segment
+    goes when its last lease is released."""
+    from paddle_tpu.datapipe.shm import ShmRing
+
+    ring = ShmRing(3, {"x": ((4, 8), "uint8")}, coalesce=1)
+    assert ring.mapped == 3
+    busy, idle = ring.acquire(), ring.acquire()
+    lease = ring.lease(busy)
+    view = ring.views(busy)["x"]
+    view[...] = 7
+    ring.release(idle)
+    ring.close()
+    assert datapipe.live_segments() == []   # every name is unlinked now
+    assert ring.mapped == 1                 # only the leased slot's segment
+    assert int(view.sum()) == 7 * 32        # still readable: not unmapped
+    assert ring.acquire(0.01) is None       # a closed ring hands out nothing
+    lease.release()
+    assert ring.mapped == 0
+    lease.release()                         # idempotent
+    ring.close()
+
+
 def test_process_pipe_plain_feeds_batcher():
     """Unfused process decode (no chunk fusion) feeds the downstream
     thread stages like ParallelMap — leases (if any) released, order

@@ -256,7 +256,16 @@ def test_pallas_momentum_bucket_bitwise(n):
 
 
 @pytest.mark.parametrize("n", [1, 17, 1029])
-def test_pallas_adam_bucket_bitwise(n):
+def test_pallas_adam_bucket_parity(n):
+    """What parity holds (fusion/kernels.py): the moments, which only
+    multiply and add, are bitwise everywhere. The parameter goes through
+    sqrt and a divide; interpreted on the CPU the kernel's per-block loop
+    body and the whole-array reference are two XLA:CPU compilations whose
+    vectorised divide differs by a few ulp (14 of 1029 elements here), so
+    the parameter is held to 32 eps of the terms of its final subtraction,
+    |update| + |result| (measured worst case 13.4 eps at n=1e6). Compiled
+    by Mosaic on a v5e the parameter is bitwise too (chip_smoke.py checks
+    it there)."""
     from paddle_tpu.fusion import kernels as fk
 
     rs = np.random.RandomState(n)
@@ -277,8 +286,10 @@ def test_pallas_adam_bucket_bitwise(n):
         np.asarray(m1o), np.asarray(m1_ref).reshape(-1)[:n])
     np.testing.assert_array_equal(
         np.asarray(m2o), np.asarray(m2_ref).reshape(-1)[:n])
-    np.testing.assert_array_equal(
-        np.asarray(po), np.asarray(p_ref).reshape(-1)[:n])
+    p_ref = np.asarray(p_ref).reshape(-1)[:n]
+    bound = 32 * np.finfo(np.float32).eps * (
+        np.abs(np.asarray(p) - p_ref) + np.abs(p_ref))
+    assert np.all(np.abs(np.asarray(po) - p_ref) <= bound)
 
 
 def test_bucket_splitting_respects_budget_and_partitions():

@@ -503,8 +503,7 @@ class TestProximalAdagrad(OpTest):
 class TestBatchNormLargeMeanStability:
     """One-pass BN statistics stay accurate across the supported regime:
     |mean|/std up to ~2^12 (the fp32 cancellation boundary, documented in
-    the kernel and docs/perf_r04.md — post-conv activations sit orders of
-    magnitude below it). Channel ~ 100 +/- 0.1 (ratio 1e3) must normalize
+    the kernel — post-conv activations sit orders of magnitude below it). Channel ~ 100 +/- 0.1 (ratio 1e3) must normalize
     to ~N(0,1), not collapse."""
 
     def test_variance_accuracy(self):

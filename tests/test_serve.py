@@ -234,6 +234,26 @@ def test_concurrent_clients_get_their_own_rows():
     assert snap["serve_rows_total"] == 24
 
 
+def test_more_replicas_than_devices_raises():
+    """An accelerator server gives each replica its own device; asking for
+    more replicas than devices is an error, not a wrap onto device 0."""
+    import jax
+
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(prog, startup):
+        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
+        y = fluid.layers.fc(input=x, size=3)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.TPUPlace(0)).run(startup)
+    server = serve.Server(
+        prog, ["x"], [y], place=fluid.TPUPlace(0), scope=scope,
+        config=serve.ServeConfig(max_batch=2,
+                                 replicas=len(jax.devices()) + 1))
+    with pytest.raises(ValueError, match="device_id must be in"):
+        server.start()
+
+
 def test_multi_replica_round_robin():
     server, exe, scope, prog, y = _fc_server(max_batch=2, replicas=2)
     with server:
