@@ -133,7 +133,7 @@ class CompileCache:
                     and key not in self._entries:
                 self._entries.popitem(last=False)
                 self.evictions += 1
-                if mon is not None:
+                if mon is not None and mon.monitored:
                     from .. import monitor
 
                     monitor.cache_evicted(self.kind)

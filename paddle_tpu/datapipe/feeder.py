@@ -112,7 +112,7 @@ class AsyncDeviceFeeder:
     def __init__(self, source, chunk=None, place=None, capacity=None,
                  transfer_threads=None, stage_fn=None, wire=None,
                  donate=None, stack_stats=None, transfer_stats=None,
-                 link_stats=None, wire_cb=None):
+                 link_stats=None, wire_cb=None, pipe_id=None):
         if chunk is not None and int(chunk) < 1:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
         if capacity is None:
@@ -140,6 +140,7 @@ class AsyncDeviceFeeder:
         self._transfer_stats = transfer_stats
         self._link_stats = link_stats
         self._wire_cb = wire_cb  # called once with a resolved "auto" spec
+        self._pipe_id = pipe_id  # the `pipe` attr of this stage's spans
         self._active = None  # stop flag of the live iteration (for close())
 
     def _device(self):
@@ -213,6 +214,12 @@ class AsyncDeviceFeeder:
         # flag so workers don't re-read it per chunk
         tracing = _trace.enabled()
         tctx = _trace.current() if tracing else None
+
+        def tspan(name, t0, t1, **attrs):
+            # callers gate on `tracing`
+            attrs["pipe"] = self._pipe_id
+            _trace.record(name, t0, t1, kind="datapipe", attrs=attrs)
+
         puts_copy = self._stage_fn is not None or _device_put_copies(dev)
         reuse_buffers = self._stage_fn is None and puts_copy
 
@@ -239,16 +246,25 @@ class AsyncDeviceFeeder:
             for the emitted chunk's markers. The copy-under-lock is the
             zero-copy ring boundary."""
             lease = None
+            tl = time.perf_counter() if tracing else None
             with src_lock:
                 if state["eof_at"] is not None or state["error"] is not None \
                         or state["stop"]:
                     return None
+                idx = state["next_in"]  # moves under this lock only
+                if tracing:
+                    tspan("datapipe.lock_wait", tl, time.perf_counter(),
+                          chunk=idx)
                 try:
                     if K is None:
                         t0 = time.perf_counter()
                         item = next(src, _End)
+                        tb = time.perf_counter()
                         if sst:
-                            sst.add_wait_in(time.perf_counter() - t0)
+                            sst.add_wait_in(tb - t0)
+                        if tracing:
+                            tspan("datapipe.upstream_wait", t0, tb,
+                                  chunk=idx)
                         if item is _End:
                             state["eof_at"] = state["next_in"]
                             with cond:
@@ -271,6 +287,9 @@ class AsyncDeviceFeeder:
                         if sst:
                             sst.add_item(nbytes=sum(
                                 a.nbytes for a in stacked.values()))
+                        if tracing:
+                            tspan("datapipe.stack", tb, time.perf_counter(),
+                                  chunk=idx)
                     else:
                         got = 0
                         buf = buf_holder[0]
@@ -278,8 +297,12 @@ class AsyncDeviceFeeder:
                         while got < K:
                             t0 = time.perf_counter()
                             item = next(src, _End)
+                            tb = time.perf_counter()
                             if sst:
-                                sst.add_wait_in(time.perf_counter() - t0)
+                                sst.add_wait_in(tb - t0)
+                            if tracing:
+                                tspan("datapipe.upstream_wait", t0, tb,
+                                      chunk=idx)
                             if item is _End:
                                 # partial tail: drop (DeviceChunkFeeder
                                 # semantics — no odd-shape recompile)
@@ -288,7 +311,6 @@ class AsyncDeviceFeeder:
                                     cond.notify_all()
                                 return None
                             w = eff_wire(item)
-                            tb = time.perf_counter()
                             if buf is None:
                                 # __valid__ (the Batcher's pad mask) is a
                                 # real [bs] bool array and rides the chunk;
@@ -308,12 +330,15 @@ class AsyncDeviceFeeder:
                                     v = w[n].encode(v)
                                 b[got] = v
                             got += 1
+                            tc = time.perf_counter()
                             if sst:
                                 # wire bytes: what the link will move
                                 sst.add_item(
-                                    busy_s=time.perf_counter() - tb,
+                                    busy_s=tc - tb,
                                     nbytes=sum(b[0].nbytes
                                                for b in buf.values()))
+                            if tracing:
+                                tspan("datapipe.stack", tb, tc, chunk=idx)
                         if reuse_buffers:
                             stacked = buf
                         else:
@@ -323,7 +348,6 @@ class AsyncDeviceFeeder:
                         lease.release()
                     fail(e)
                     return None
-                idx = state["next_in"]
                 state["next_in"] += 1
                 return idx, stacked, lease, w
 
@@ -350,16 +374,14 @@ class AsyncDeviceFeeder:
                     if waited and tst:
                         # prefetch budget full: downstream backpressure
                         tst.add_bp_wait(time.perf_counter() - tw)
-                    tp = time.perf_counter()
+                    if tracing:
+                        tspan("datapipe.ticket_wait", tw,
+                              time.perf_counter())
                     nxt = pull_chunk(buf_holder)
                     if nxt is None:
                         tickets.release()
                         return
                     idx, stacked, lease, w = nxt
-                    if tracing:
-                        _trace.record("datapipe.stack", tp,
-                                      time.perf_counter(), kind="datapipe",
-                                      attrs={"chunk": idx})
                     try:
                         t0 = time.perf_counter()
 
@@ -381,10 +403,8 @@ class AsyncDeviceFeeder:
                         dt = time.perf_counter() - t0
                         nb = sum(a.nbytes for a in stacked.values())
                         if tracing:
-                            _trace.record(
-                                "datapipe.transfer", t0, t0 + dt,
-                                kind="datapipe",
-                                attrs={"chunk": idx, "bytes": nb})
+                            tspan("datapipe.transfer", t0, t0 + dt,
+                                  chunk=idx, bytes=nb)
                         if tst:
                             tst.add_item(busy_s=dt, nbytes=nb)
                         if lst is not None:
@@ -440,6 +460,12 @@ class AsyncDeviceFeeder:
                         if tst:
                             tst.add_wait_out(time.perf_counter() - t0)
                             tst.sample_depth(len(done) + 1)
+                        if tracing:
+                            # the consumer's wait for this chunk; `depth`:
+                            # staged chunks left behind it
+                            tspan("datapipe.next", t0, time.perf_counter(),
+                                  chunk=state["next_out"] - 1,
+                                  depth=len(done))
                         return res
                     if state["eof_at"] is not None and \
                             state["next_out"] >= state["eof_at"]:

@@ -33,7 +33,7 @@ class ParallelMap:
     """
 
     def __init__(self, source, fn, num_workers=2, buffer_size=None,
-                 order=True, stats=None):
+                 order=True, stats=None, pipe_id=None):
         if num_workers < 1:
             raise ValueError(f"num_workers must be >= 1, got {num_workers}")
         self._source = source
@@ -47,6 +47,7 @@ class ParallelMap:
                 f"would idle workers permanently")
         self._order = order
         self._stats = stats
+        self._pipe_id = pipe_id  # the `pipe` attr of this stage's spans
         self._active = None  # live iteration's state (for close/join)
 
     def close(self):
@@ -148,7 +149,8 @@ class ParallelMap:
                         if tracing:
                             _trace.record("datapipe.map", t0, t1,
                                           kind="datapipe",
-                                          attrs={"idx": idx})
+                                          attrs={"pipe": self._pipe_id,
+                                                 "idx": idx})
                     except BaseException as e:
                         with cond:
                             if state["error"] is None:

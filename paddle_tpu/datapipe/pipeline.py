@@ -26,6 +26,9 @@ batch into its chunk buffer under the pull lock before the next batch is
 pulled, which is the ring-reuse boundary batcher.py documents.
 """
 
+import itertools
+import time
+
 from .. import trace as _trace
 from ..flags import get as get_flag
 from .batcher import Batcher
@@ -35,6 +38,29 @@ from .source import GeneratorSource, RecordIOSource, SkipSource, Source
 from .stats import PipeStats
 
 __all__ = ["DataPipe"]
+
+_pipe_ids = itertools.count(1)
+
+
+def _traced_reads(source, pipe_id):
+    """FLAGS_trace: one `datapipe.read` span per item pulled off the
+    source, recorded by the stage thread that pulls it. `idx` counts the
+    items of this iteration — the index every later stage knows the item
+    by, so a chunk of K items is idx // K — and `bytes` is set where the
+    item is raw bytes (a RecordIO record)."""
+    it = iter(source)
+    for idx in itertools.count():
+        t0 = time.perf_counter()
+        try:
+            item = next(it)
+        except StopIteration:
+            return
+        attrs = {"pipe": pipe_id, "idx": idx}
+        if isinstance(item, (bytes, bytearray, memoryview)):
+            attrs["bytes"] = len(item)
+        _trace.record("datapipe.read", t0, time.perf_counter(),
+                      kind="datapipe", attrs=attrs)
+        yield item
 
 
 def _named_sample_adapter(reader, feed_names):
@@ -75,6 +101,8 @@ class DataPipe:
         self._resume_base = 0       # records skipped at this pass's build
         self._resume_records = None  # pending skip for the NEXT build
         self._resolved_wire = None   # wire="auto" resolution, once built
+        # what the spans of one pipe have in common (their `pipe` attr)
+        self._pipe_id = next(_pipe_ids)
 
     # -- construction ----------------------------------------------------
     @classmethod
@@ -194,9 +222,7 @@ class DataPipe:
         return self._stage_memo[(i, name)]
 
     def _build(self):
-        with _trace.span("datapipe.build", kind="datapipe",
-                         stages=len(self._ops)):
-            return self._build_stages()
+        return self._build_stages()
 
     def _build_stages(self):
         from .feeder import AsyncDeviceFeeder
@@ -209,6 +235,10 @@ class DataPipe:
             self._resume_records = None
         layers, objs = [], []
         cur = src
+        pid = self._pipe_id
+        if _trace.enabled():
+            # a snapshot of the flag per iteration, like every stage's
+            cur = _traced_reads(src, pid)
         fused_map = None  # index of a map op fused into the next device op
         for i, (kind, kw) in enumerate(self._ops):
             if kind == "map":
@@ -236,14 +266,16 @@ class DataPipe:
                             # budget, +1 so release latency never stalls
                             ring_slots=int(cap) + 2,
                             wire_cb=self._set_resolved_wire,
-                            stats=self._stage(i, "map"), **kw2)
+                            stats=self._stage(i, "map"), pipe_id=pid,
+                            **kw2)
                         fused_map = i
                     else:
                         obj = ProcessPoolMap(
-                            cur, stats=self._stage(i, "map"), **kw2)
+                            cur, stats=self._stage(i, "map"), pipe_id=pid,
+                            **kw2)
                 else:
                     obj = ParallelMap(cur, stats=self._stage(i, "map"),
-                                      **kw2)
+                                      pipe_id=pid, **kw2)
             elif kind == "batch":
                 nxt = self._ops[i + 1] if i + 1 < len(self._ops) else None
                 zero_copy = bool(nxt and nxt[0] == "device"
@@ -264,7 +296,7 @@ class DataPipe:
                     # stats() show whether the streams share the link's
                     # bandwidth or serialize on it
                     link_stats=lambda t, _i=i: self._stage(_i, f"link{t}"),
-                    wire_cb=self._set_resolved_wire,
+                    wire_cb=self._set_resolved_wire, pipe_id=pid,
                     **kw2)
             else:  # pragma: no cover - builder invariant
                 raise AssertionError(f"unknown op {kind!r}")

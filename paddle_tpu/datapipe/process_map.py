@@ -92,6 +92,20 @@ class _End:
     pass
 
 
+def _item_bytes(item):
+    """Bytes a dispatched item puts on the task pipe, as far as its type
+    says (bytes-likes, arrays, and lists or dicts of them)."""
+    if isinstance(item, (bytes, bytearray, memoryview)):
+        return len(item)
+    if isinstance(item, np.ndarray):
+        return item.nbytes
+    if isinstance(item, dict):
+        item = list(item.values())
+    if isinstance(item, (list, tuple)):
+        return sum(_item_bytes(v) for v in item)
+    return 0
+
+
 def _rebuild_exc(etype, msg, tb):
     """Parent-side reconstruction of a worker exception. Builtin types
     re-raise as themselves (so `ValueError` from a decode fn propagates
@@ -108,7 +122,7 @@ def _rebuild_exc(etype, msg, tb):
     return DataPipeError(f"decode worker raised {etype}: {msg}\n{tb}")
 
 
-def _worker_main(wid, fn, task_q, conn):
+def _worker_main(wid, fn, task_q, conn, tracing=False):
     """Worker process body: decode tasks until the stop pill.
 
     Messages in (task_q): ("task", idx, slot, off, item) /
@@ -117,11 +131,21 @@ def _worker_main(wid, fn, task_q, conn):
     Messages out (conn): ("ok", idx, res, dur) / ("okshm", idx, dur) /
     ("okshmb", idx0, n, dur) / ("probe_ok", idx, res, dur) /
     ("err", idx, etype, msg, tb).
+
+    `tracing` is the parent's FLAGS_trace as its iterator snapshot it,
+    handed over at the fork. When set, every task message ends in the
+    parent's `perf_counter` stamp of its put, and every ack ends in this
+    worker's own stamps (put, free, got, decode start, decode end, write
+    end or None) — `perf_counter` is CLOCK_MONOTONIC on Linux, one clock
+    for the parent and its workers — from which the parent records the
+    `datapipe.handoff` / `idle` / `decode` / `ring_put` spans. When not,
+    neither message carries anything more than it ever did.
     """
     import traceback
 
     client = None
     wire = None
+    t_free = time.perf_counter() if tracing else None
     try:
         while True:
             task = task_q.get()
@@ -132,34 +156,39 @@ def _worker_main(wid, fn, task_q, conn):
                 client = ShmRingClient(task[1])
                 wire = task[2]
                 continue
+            t_got = time.perf_counter() if tracing else None
             idx = task[1]
             try:
+                t0 = time.perf_counter()
+                t_w = None  # end of the shm write, where there is one
                 if kind == "probe":
-                    item = task[2]
-                    t0 = time.perf_counter()
-                    res = fn(item)
-                    dur = time.perf_counter() - t0
-                    conn.send(("probe_ok", idx, res, dur))
+                    res = fn(task[2])
+                    t1 = time.perf_counter()
+                    ack = ("probe_ok", idx, res, t1 - t0)
                 elif kind == "taskb":
                     # coalesced dispatch: decode a run of rows into one
                     # slot, one ~100-byte ack for the whole run
-                    _, idx, slot, off, items = task
-                    t0 = time.perf_counter()
-                    client.write_batch(slot, off, [fn(it) for it in items],
-                                       wire)
-                    dur = time.perf_counter() - t0
-                    conn.send(("okshmb", idx, len(items), dur))
+                    slot, off, items = task[2:5]
+                    decoded = [fn(it) for it in items]
+                    t1 = time.perf_counter()
+                    client.write_batch(slot, off, decoded, wire)
+                    t_w = time.perf_counter()
+                    ack = ("okshmb", idx, len(items), t_w - t0)
                 else:  # "task"
-                    _, idx, slot, off, item = task
-                    t0 = time.perf_counter()
+                    slot, off, item = task[2:5]
                     res = fn(item)
+                    t1 = time.perf_counter()
                     if slot is None:
-                        dur = time.perf_counter() - t0
-                        conn.send(("ok", idx, res, dur))
+                        ack = ("ok", idx, res, t1 - t0)
                     else:
                         client.write(slot, off, res, wire)
-                        dur = time.perf_counter() - t0
-                        conn.send(("okshm", idx, dur))
+                        t_w = time.perf_counter()
+                        ack = ("okshm", idx, t_w - t0)
+                if tracing:
+                    ack += ((task[-1], t_free, t_got, t0, t1, t_w),)
+                conn.send(ack)
+                if tracing:
+                    t_free = time.perf_counter()
             except Exception as e:
                 conn.send(("err", idx, type(e).__name__, str(e),
                            traceback.format_exc()))
@@ -220,7 +249,7 @@ class ProcessPoolMap:
     def __init__(self, source, fn, num_workers=2, buffer_size=None,
                  order=True, stats=None, chunk=None, wire=None,
                  ring_slots=4, restart_workers=None, start_method=None,
-                 wire_cb=None):
+                 wire_cb=None, pipe_id=None):
         if num_workers < 1:
             raise ValueError(f"num_workers must be >= 1, got {num_workers}")
         if chunk is not None and int(chunk) < 1:
@@ -251,6 +280,7 @@ class ProcessPoolMap:
         self._restart = restart_workers
         self._start_method = start_method
         self._wire_cb = wire_cb  # called once with the resolved WireSpec
+        self._pipe_id = pipe_id  # the `pipe` attr of this stage's spans
         self._active = None
 
     # -- lifecycle (DataPipe 3-phase close contract) ---------------------
@@ -342,7 +372,10 @@ class ProcessPoolMap:
         if restart is None:
             restart = bool(get_flag("datapipe_restart_workers"))
         st = self._stats
+        # per-iteration snapshot of the flag, handed to the workers at
+        # their start; the consumer's context rides into the dispatcher
         tracing = _trace.enabled()
+        tctx = _trace.current() if tracing else None
         cond = threading.Condition()
         tickets = threading.Semaphore(self._buf)
         done = {}    # plain ordered: idx -> result
@@ -390,7 +423,8 @@ class ProcessPoolMap:
             task_q = ctx.Queue()
             r_conn, w_conn = ctx.Pipe(duplex=False)
             proc = ctx.Process(
-                target=_worker_main, args=(wid, self._fn, task_q, w_conn),
+                target=_worker_main,
+                args=(wid, self._fn, task_q, w_conn, tracing),
                 daemon=True, name=f"datapipe-proc-{wid}")
             proc.start()
             w_conn.close()  # parent keeps only the read end
@@ -407,6 +441,11 @@ class ProcessPoolMap:
                 if state["error"] is None:
                     state["error"] = e
                 cond.notify_all()
+
+        def put_task(w, *msg):
+            """One task message; under tracing it ends in the stamp of
+            its put (the start of the item's `datapipe.handoff`)."""
+            w.task_q.put(msg + (time.perf_counter(),) if tracing else msg)
 
         def pick_worker():
             alive = [w for w in state["workers"].values() if not w.dead]
@@ -448,13 +487,10 @@ class ProcessPoolMap:
                     rec.wid = tgt.wid
                     tgt.outstanding.add(idx)
                     if rec.probe:
-                        tgt.task_q.put(("probe", idx, rec.item))
-                    elif rec.batch:
-                        tgt.task_q.put(("taskb", idx, rec.slot, rec.off,
-                                        rec.item))
+                        put_task(tgt, "probe", idx, rec.item)
                     else:
-                        tgt.task_q.put(("task", idx, rec.slot, rec.off,
-                                        rec.item))
+                        put_task(tgt, "taskb" if rec.batch else "task",
+                                 idx, rec.slot, rec.off, rec.item)
 
         def _count(name):
             from .. import monitor
@@ -532,6 +568,7 @@ class ProcessPoolMap:
                 disp_b = int(get_flag("datapipe_dispatch_batch")) \
                     or max(1, K // max(1, self._workers_n))
             pending = []  # [(idx, item)] of the assembling coalesced run
+            t_slot = None  # since when the next chunk waits for a ring slot
 
             def flush_run():
                 """Ship the pending run as one taskb message. False when
@@ -554,7 +591,7 @@ class ProcessPoolMap:
                         w.wid, cur_chunk, off0, cur_slot, items,
                         batch=True)
                     w.outstanding.add(idx0)
-                w.task_q.put(("taskb", idx0, cur_slot, off0, items))
+                put_task(w, "taskb", idx0, cur_slot, off0, items)
                 pending = []
                 return True
 
@@ -590,9 +627,18 @@ class ProcessPoolMap:
                         continue
                     if fused and state["ring"] is not None \
                             and cur_slot is None:
+                        if t_slot is None:
+                            t_slot = time.perf_counter()
                         slot = state["ring"].acquire(0.2)
                         if slot is None:
                             continue
+                        if tracing:
+                            _trace.record(
+                                "datapipe.slot_wait", t_slot,
+                                time.perf_counter(), kind="datapipe",
+                                attrs={"pipe": self._pipe_id,
+                                       "chunk": cur_chunk})
+                        t_slot = None
                         cur_slot = slot
                         with cond:
                             state["chunk_lease"][cur_chunk] = \
@@ -637,7 +683,7 @@ class ProcessPoolMap:
                                 w.wid, 0, 0, None, item, probe=True)
                             w.outstanding.add(idx)
                             state["probe_sent"] = True
-                        w.task_q.put(("probe", idx, item))
+                        put_task(w, "probe", idx, item)
                         continue
                     if fused:
                         pending.append((idx, item))
@@ -661,7 +707,7 @@ class ProcessPoolMap:
                         state["inflight"][idx] = _InFlight(
                             w.wid, cur_chunk, 0, None, item)
                         w.outstanding.add(idx)
-                    w.task_q.put(("task", idx, None, 0, item))
+                    put_task(w, "task", idx, None, 0, item)
             except BaseException as e:  # pragma: no cover - defensive
                 fail(e)
             finally:
@@ -671,13 +717,46 @@ class ProcessPoolMap:
 
         for _ in range(self._workers_n):
             spawn_worker()
-        disp = threading.Thread(target=dispatch_loop, daemon=True,
-                                name="datapipe-pmap-dispatch")
+        def dispatch():
+            with _trace.attach(tctx):   # None when not tracing
+                dispatch_loop()
+
+        disp = threading.Thread(target=dispatch, daemon=True,
+                                name="datapipe-dispatch")
         state["dispatcher"] = disp
         disp.start()
         row_bytes = [None]  # chunk mode: bytes of one decoded row
 
-        def handle_msg(msg, recv_t):
+        def chunk_row_bytes():
+            if row_bytes[0] is None and state["ring"]:
+                row_bytes[0] = sum(
+                    int(np.prod(s[1:], dtype=np.int64))
+                    * np.dtype(d).itemsize
+                    for s, d in state["ring"].schema.values())
+            return row_bytes[0] or 0
+
+        def record_worker_spans(idx, rec, n_items, stamps):
+            """The worker's own stamps, as they rode in on its ack."""
+            t_put, t_free, t_got, d0, d1, t_w = stamps
+            attrs = {"pipe": self._pipe_id, "idx": idx, "n": n_items,
+                     "worker": rec.wid}
+            if fused:
+                attrs["chunk"] = rec.chunk
+            lane = f"datapipe-proc-{rec.wid}"
+            for name, a, b, nb in (
+                    ("datapipe.handoff", t_put, t_got,
+                     _item_bytes(rec.item)),
+                    ("datapipe.idle", t_free, t_got, None),
+                    ("datapipe.decode", d0, d1, None),
+                    ("datapipe.ring_put", d1, t_w,
+                     chunk_row_bytes() * n_items)):
+                if b is not None:
+                    _trace.record(
+                        name, a, b, kind="datapipe", thread=lane,
+                        attrs=attrs if nb is None
+                        else dict(attrs, bytes=nb))
+
+        def handle_msg(msg):
             kind = msg[0]
             if kind == "err":
                 _, idx, etype, emsg, tb = msg
@@ -691,8 +770,11 @@ class ProcessPoolMap:
                 w = state["workers"].get(rec.wid)
                 if w is not None:
                     w.outstanding.discard(idx)
+                n_items = msg[2] if kind == "okshmb" else 1
+                if tracing:
+                    record_worker_spans(idx, rec, n_items, msg[-1])
                 if kind == "probe_ok":
-                    _, _, res, dur = msg
+                    res, dur = msg[2:4]
                     # push back: settle_probe (dispatcher) does the ring
                     # build + slot write outside the lock
                     state["inflight"][idx] = rec
@@ -703,7 +785,6 @@ class ProcessPoolMap:
                         st.add_item(busy_s=dur)
                     cond.notify_all()
                     return
-                n_items = msg[2] if kind == "okshmb" else 1
                 state["acked"] += n_items
                 dur = msg[2] if kind == "okshm" else msg[3]
                 if kind == "ok":
@@ -718,19 +799,9 @@ class ProcessPoolMap:
                         state["chunk_acks"].get(c, 0) + n_items
                     tickets.release(n_items)
                 if st:
-                    nb = 0
-                    if kind in ("okshm", "okshmb"):
-                        if row_bytes[0] is None and state["ring"]:
-                            sch = state["ring"].schema
-                            row_bytes[0] = sum(
-                                int(np.prod(s[1:], dtype=np.int64))
-                                * np.dtype(d).itemsize
-                                for s, d in sch.values())
-                        nb = (row_bytes[0] or 0) * n_items
+                    nb = chunk_row_bytes() * n_items \
+                        if kind in ("okshm", "okshmb") else 0
                     st.add_item(busy_s=dur, nbytes=nb, count=n_items)
-                if tracing:
-                    _trace.record("datapipe.pmap", recv_t - dur, recv_t,
-                                  kind="datapipe", attrs={"idx": idx})
                 cond.notify_all()
 
         def emit_check():
@@ -800,7 +871,6 @@ class ProcessPoolMap:
                     ready_conns = mpc2.wait(list(conns), timeout=0.2)
                 except OSError:
                     ready_conns = []
-                recv_t = time.perf_counter()
                 for conn in ready_conns:
                     try:
                         msg = conn.recv()
@@ -810,7 +880,7 @@ class ProcessPoolMap:
                         # stop polling this pipe
                         conns[conn].conn_dead = True
                         continue
-                    handle_msg(msg, recv_t)
+                    handle_msg(msg)
 
         try:
             while True:

@@ -27,6 +27,7 @@ from .core.registry import SeqTensor
 from . import health as _health
 from .resilience import chaos as _chaos
 from .resilience import watchdog as _watchdog
+from . import trace as _trace
 from .trace import costs as _trace_costs
 
 __all__ = ["Executor", "FetchFuture", "global_scope", "scope_guard",
@@ -204,6 +205,17 @@ def stack_multi_step_feeds(program, feed, iters, wire=None):
     return vals
 
 
+def lap_call(mon, was_miss, build_s, fp, program):
+    """Close the call of the compiled step (both executors): `dispatch`
+    (enqueue time under async dispatch) on a hit or an L2 load, `compile`
+    on a miss, whose first call holds the XLA compile."""
+    call_s = mon.lap("compile" if was_miss else "dispatch")
+    if was_miss:
+        if mon.monitored:
+            monitor.record_compile(fp, wall_s=build_s + call_s)
+        _trace_costs.register_program(fp, program)
+
+
 class FetchFuture:
     """Handle to one in-flight fetch from run(async_fetch=True).
 
@@ -342,18 +354,20 @@ class Executor:
             program = default_main_program()
         if scope is None:
             scope = global_scope()
-        # the ONE per-step monitor flag check; mon stays None when off and
-        # every telemetry site below is gated on `mon is not None`
-        mon = monitor.step_begin("executor") if monitor.enabled() else None
+        # the per-step flag reads (FLAGS_monitor, FLAGS_trace); mon stays
+        # None when both are off and every telemetry site below is gated on
+        # `mon is not None`. Its laps tile the step: each closes the
+        # stretch since the one before under the phase's name.
+        monitored = monitor.enabled()
+        mon = monitor.step_begin("executor", monitored) \
+            if monitored or _trace.enabled() else None
         pipe = feed if hasattr(feed, "next_feed") else None
         if pipe is not None:  # datapipe.DataPipe (duck-typed)
             if iters is None:
                 iters = getattr(pipe, "feed_iters", None)
+            feed = pipe.next_feed()
             if mon is not None:
-                with mon.timed("feed_wait"):
-                    feed = pipe.next_feed()
-            else:
-                feed = pipe.next_feed()
+                mon.lap("feed_wait")
         if isinstance(feed, (list, tuple)) and iters is None:
             iters = len(feed)  # length consistency checked in the helper
         feed = feed if feed is not None else {}
@@ -398,14 +412,16 @@ class Executor:
                 outs = self._run_compiled(
                     program, scope, feed, fetch_names, use_program_cache,
                     wire=wire, donate_feeds=donate_feeds, mon=mon)
+        if mon is not None:
+            # unpackers, scope.set_var over the state, health and NaN
+            # checks, and leaving the watchdog and the device scope
+            mon.lap("write_back")
         if async_fetch:
             outs = [FetchFuture(o) for o in outs]
         elif return_numpy:
+            outs = [as_numpy(o) for o in outs]
             if mon is not None:
-                with mon.timed("fetch_readback"):
-                    outs = [as_numpy(o) for o in outs]
-            else:
-                outs = [as_numpy(o) for o in outs]
+                mon.lap("fetch_readback")
         if mon is not None:
             monitor.step_end(mon, iters=iters, datapipe=pipe)
         return outs
@@ -456,16 +472,18 @@ class Executor:
 
     def _run_compiled(self, program, scope, feed, fetch_names, use_cache,
                       wire=None, donate_feeds=False, mon=None):
+        feed_vals = self._feed_values(program, feed, wire=wire)
         if mon is not None:
-            with mon.timed("feed_encode"):
-                feed_vals = self._feed_values(program, feed, wire=wire)
-        else:
-            feed_vals = self._feed_values(program, feed, wire=wire)
+            mon.lap("feed_encode")
         fplan = None
         if flags.get("fuse"):
             program, fplan = self._fuse_program(
                 program, list(feed_vals), list(fetch_names))
+            if mon is not None:
+                mon.lap("cache_lookup")
         state_names, state_out_names = executor_core.collect_state_names(program, scope)
+        if mon is not None:
+            mon.lap("state_gather")
         if flags.get("debug_nans"):
             donate_feeds = False  # re-run needs the inputs (see below)
         hplan = _health.plan_if_enabled(program)
@@ -484,7 +502,10 @@ class Executor:
             ("fuse", fplan.digest() if fplan is not None else None),
         )
         entry = self._compile_cache.get(cache_key) if use_cache else None
-        fp = monitor.fingerprint_of(cache_key) if mon is not None else None
+        fp = None
+        if mon is not None:
+            fp = monitor.fingerprint_of(cache_key)
+            mon.lap("cache_lookup")
         build_s = 0.0
         was_miss = entry is None
         level = "l1" if entry is not None else None
@@ -517,8 +538,8 @@ class Executor:
                     # per param INSIDE the jit (health/stats.py)
                     step = hplan.wrap_step(step, len(fetch_names))
                 probe = monitor.compile_probe(fp) \
-                    if mon is not None and flags.get("monitor_hlo_cost") \
-                    else None
+                    if mon is not None and mon.monitored \
+                    and flags.get("monitor_hlo_cost") else None
                 # under debug_nans the trap fires INSIDE compiled() before
                 # the scope write-back; donated buffers would already be
                 # deleted, wrecking both the scope and jax's op-by-op
@@ -545,6 +566,10 @@ class Executor:
             entry = (compiled, state_names, state_out_names)
             if use_cache:
                 self._cache_store(cache_key, entry, mon=mon)
+            if mon is not None:
+                # a miss compiles inside the first call as well (async
+                # dispatch): both stretches are the `compile` phase
+                mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level)
         compiled, state_names, state_out_names = entry
@@ -560,26 +585,15 @@ class Executor:
         step0 = self._step_counter.get(id(program), 0)
         rng = self._rng_for(program)
         t0 = time.perf_counter() if flags.get("benchmark") else None
-        tc = time.perf_counter() if mon is not None else None
+        if mon is not None:
+            mon.lap("state_gather")
         fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
         hstats = None
         if hplan is not None:
             hstats = fetches[-1]
             fetches = fetches[:-1]
         if mon is not None:
-            call_s = time.perf_counter() - tc
-            if was_miss:
-                # under async dispatch the FIRST call includes XLA compile;
-                # attribute trace + compile to the "compile" phase
-                mon.phase("compile", build_s + call_s)
-                monitor.record_compile(fp, wall_s=build_s + call_s)
-                _trace_costs.register_program(fp, program)
-            elif level == "l2":
-                # warm start: deserialize wall time, no XLA compile
-                mon.phase("cache_load", build_s)
-                mon.phase("dispatch", call_s)
-            else:
-                mon.phase("dispatch", call_s)  # enqueue time (async)
+            lap_call(mon, was_miss, build_s, fp, program)
         # write back BEFORE any nan check can raise: mut_state was donated,
         # so skipping this would leave the scope holding deleted buffers
         for n, v in new_mut.items():
@@ -626,15 +640,15 @@ class Executor:
     def _run_compiled_multi(self, program, scope, feed, fetch_names,
                             use_cache, iters, wire=None, donate_feeds=False,
                             mon=None):
+        feed_vals = self._stack_feeds(program, feed, iters, wire=wire)
         if mon is not None:
-            with mon.timed("feed_encode"):
-                feed_vals = self._stack_feeds(program, feed, iters, wire=wire)
-        else:
-            feed_vals = self._stack_feeds(program, feed, iters, wire=wire)
+            mon.lap("feed_encode")
         fplan = None
         if flags.get("fuse"):
             program, fplan = self._fuse_program(
                 program, list(feed_vals), list(fetch_names))
+            if mon is not None:
+                mon.lap("cache_lookup")
         state_names, state_out_names = executor_core.collect_state_names(
             program, scope)
         missing = [n for n in state_out_names if not scope.has_var(n)]
@@ -644,6 +658,15 @@ class Executor:
                 f"before the scan (the carry structure is fixed); missing: "
                 f"{missing}. Run the startup program (or one plain "
                 f"exe.run) first.")
+        out_set = set(state_out_names)
+        mut_state, const_state = {}, {}
+        for n in state_names:
+            v = scope.find_var(n)
+            if isinstance(v, LoDTensor):
+                v = executor_core.feed_to_tracevalue(v)
+            (mut_state if n in out_set else const_state)[n] = v
+        if mon is not None:
+            mon.lap("state_gather")
         if flags.get("debug_nans"):
             donate_feeds = False  # the op-by-op re-run needs the inputs
         hplan = _health.plan_if_enabled(program)
@@ -665,16 +688,11 @@ class Executor:
             ("health", hplan.digest if hplan is not None else None),
             ("fuse", fplan.digest() if fplan is not None else None),
         )
-        out_set = set(state_out_names)
-        mut_state, const_state = {}, {}
-        for n in state_names:
-            v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                v = executor_core.feed_to_tracevalue(v)
-            (mut_state if n in out_set else const_state)[n] = v
-
         entry = self._compile_cache.get(cache_key) if use_cache else None
-        fp = monitor.fingerprint_of(cache_key) if mon is not None else None
+        fp = None
+        if mon is not None:
+            fp = monitor.fingerprint_of(cache_key)
+            mon.lap("cache_lookup")
         build_s = 0.0
         was_miss = entry is None
         level = "l1" if entry is not None else None
@@ -728,8 +746,8 @@ class Executor:
                 multi = executor_core.build_multi_step_fn(step, iters,
                                                           ema=ema)
                 probe = monitor.compile_probe(fp) \
-                    if mon is not None and flags.get("monitor_hlo_cost") \
-                    else None
+                    if mon is not None and mon.monitored \
+                    and flags.get("monitor_hlo_cost") else None
                 return executor_core.compile_step_fn(
                     multi, donate_state=not flags.get("debug_nans"),
                     donate_feeds=donate_feeds, probe=probe,
@@ -755,6 +773,8 @@ class Executor:
                      unpackers, {})
             if use_cache:
                 self._cache_store(cache_key, entry, mon=mon)
+            if mon is not None:
+                mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level)
         compiled, state_names, state_out_names, plan, unpackers, memo = entry
@@ -796,23 +816,15 @@ class Executor:
         # step0 rides as a traced array to keep the compile cache hot
         rng = (jax.random.PRNGKey(program.random_seed),
                jnp.asarray(step0, jnp.int32))
-        tc = time.perf_counter() if mon is not None else None
+        if mon is not None:
+            mon.lap("state_gather")  # the PackPlan repack above
         fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
         hstats = None
         if hplan is not None:
             hstats = fetches[-1]
             fetches = fetches[:-1]
         if mon is not None:
-            call_s = time.perf_counter() - tc
-            if was_miss:  # first call compiles under async dispatch
-                mon.phase("compile", build_s + call_s)
-                monitor.record_compile(fp, wall_s=build_s + call_s)
-                _trace_costs.register_program(fp, program)
-            elif level == "l2":
-                mon.phase("cache_load", build_s)
-                mon.phase("dispatch", call_s)
-            else:
-                mon.phase("dispatch", call_s)
+            lap_call(mon, was_miss, build_s, fp, program)
         if plan is not None:
             plain = {n: v for n, v in new_mut.items()
                      if not n.startswith("__packed__")}
@@ -870,13 +882,10 @@ class Executor:
 
     def _run_eager(self, program, scope, feed, fetch_names, wire=None,
                    mon=None):
+        feed_vals = self._feed_values(program, feed, wire=wire,
+                                      decode_eager=True)
         if mon is not None:
-            with mon.timed("feed_encode"):
-                feed_vals = self._feed_values(program, feed, wire=wire,
-                                              decode_eager=True)
-        else:
-            feed_vals = self._feed_values(program, feed, wire=wire,
-                                          decode_eager=True)
+            mon.lap("feed_encode")
         env = {}
         touched = set()
         for b in program.blocks:
@@ -900,10 +909,10 @@ class Executor:
             place=self.place,
         )
         if mon is not None:
-            with mon.timed("dispatch"):
-                executor_core.run_ops(program.global_block().ops, env, ctx)
-        else:
-            executor_core.run_ops(program.global_block().ops, env, ctx)
+            mon.lap("state_gather")
+        executor_core.run_ops(program.global_block().ops, env, ctx)
+        if mon is not None:
+            mon.lap("dispatch")
         persistable = {
             n
             for blk in program.blocks

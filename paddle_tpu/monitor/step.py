@@ -2,17 +2,27 @@
 
 The executors call exactly three things on the hot path:
 
-    mon = monitor.step_begin("executor") if monitor.enabled() else None
+    m = monitor.enabled()
+    mon = monitor.step_begin("executor", monitored=m) \
+        if m or trace.enabled() else None
     ...
-    mon.phase("dispatch", seconds)          # guarded by `mon is not None`
+    mon.lap("dispatch")                     # guarded by `mon is not None`
     ...
     monitor.step_end(mon, iters=K, datapipe=pipe)
 
-step_begin is gated on ONE flag check; with FLAGS_monitor=0 nothing else
-runs — no allocation, no registry mutation, no journal I/O (asserted by
-tests/test_monitor.py). step_end folds the record into the process
-registry (counters/gauges/histograms), captures it as last_step(), and
-appends one JSONL line when FLAGS_monitor_journal names a path.
+A StepRecord serves two readers, each behind its own flag: under
+FLAGS_monitor step_end folds it into the process registry (counters/
+gauges/histograms), captures it as last_step(), and appends one JSONL
+line when FLAGS_monitor_journal names a path; under FLAGS_trace it is
+replayed as a `<kind>.step` span with one child per phase interval.
+With both flags off no record is made: two flag reads per run(), no
+`perf_counter` call, no allocation, no registry mutation, no journal I/O
+(asserted by tests/test_monitor.py and tests/test_trace.py). FLAGS_trace
+alone leaves the registry untouched.
+
+Phases TILE the step: lap(name) closes the stretch since the previous
+lap (or the step's start) under `name`, so the children of a step span
+neither overlap nor leave the step's own Python between them unnamed.
 
 Compile-cache visibility: executors mark every cache lookup
 (mark_cache), and on a miss hand compile_probe() to
@@ -23,7 +33,6 @@ analysis (FLOPs + bytes accessed) plus compile wall time per cache-key
 fingerprint. bench.py turns those FLOPs into MFU (see mfu.py).
 """
 
-import contextlib
 import threading
 import time
 
@@ -105,14 +114,18 @@ def reset():
 
 
 class StepRecord:
-    """Accumulates one step's phases; built only when monitoring is on."""
+    """Accumulates one step's phases; built only when FLAGS_monitor or
+    FLAGS_trace is on. `monitored` says whether the registry, journal
+    and last_step() may be touched (FLAGS_monitor); a record made for
+    tracing alone only carries the intervals step_end replays as spans."""
 
     __slots__ = ("kind", "t0", "phases", "cache", "cache_level",
-                 "fingerprint", "extra", "intervals")
+                 "fingerprint", "extra", "intervals", "monitored", "t_lap")
 
-    def __init__(self, kind):
+    def __init__(self, kind, monitored=True):
         self.kind = kind
-        self.t0 = time.perf_counter()
+        self.monitored = bool(monitored)
+        self.t0 = self.t_lap = time.perf_counter()  # t_lap: last lap's stamp
         self.phases = {}        # name -> seconds
         self.cache = None       # "hit" | "miss"
         self.cache_level = None  # "l1" | "l2" on a hit (l2 = warm start)
@@ -121,24 +134,16 @@ class StepRecord:
         self.intervals = []  # (name, t0, t1) per occurrence — the phase
         #                      boundaries step_end replays as trace spans
 
-    def phase(self, name, seconds, interval=None):
-        self.phases[name] = self.phases.get(name, 0.0) + float(seconds)
-        if interval is None:
-            # direct callers report a duration after the fact; anchor the
-            # interval so it ENDS now (executor calls phase() right after
-            # timing the block)
-            t1 = time.perf_counter()
-            interval = (t1 - float(seconds), t1)
-        self.intervals.append((name, interval[0], interval[1]))
-
-    @contextlib.contextmanager
-    def timed(self, name):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self.phase(name, t1 - t0, interval=(t0, t1))
+    def lap(self, name):
+        """Close the stretch since the previous lap (the step's start for
+        the first) as one interval of phase `name`; returns its seconds.
+        Consecutive laps share their boundary stamp, which is what makes
+        the phase children of a step span tile it."""
+        t1 = time.perf_counter()
+        t0, self.t_lap = self.t_lap, t1
+        self.phases[name] = self.phases.get(name, 0.0) + t1 - t0
+        self.intervals.append((name, t0, t1))
+        return t1 - t0
 
     def mark_cache(self, hit, fingerprint=None, level=None):
         """level: "l1" (in-process) or "l2" (deserialized from the
@@ -148,6 +153,8 @@ class StepRecord:
         self.cache = "hit" if hit else "miss"
         self.cache_level = level if hit else None
         self.fingerprint = fingerprint
+        if not self.monitored:
+            return
         _registry.counter(
             "compile_cache_hits_total" if hit else
             "compile_cache_misses_total",
@@ -155,10 +162,11 @@ class StepRecord:
             cache=self.kind).inc()
 
 
-def step_begin(kind="executor"):
-    """One step's record; callers gate on enabled() themselves so the
-    disabled path stays a single flag check."""
-    return StepRecord(kind)
+def step_begin(kind="executor", monitored=True):
+    """One step's record; callers gate on `enabled() or trace.enabled()`
+    themselves (and pass the first as `monitored`), so the disabled path
+    stays two flag reads."""
+    return StepRecord(kind, monitored)
 
 
 def fingerprint_of(cache_key):
@@ -274,6 +282,31 @@ def step_end(rec, iters=None, datapipe=None, replica_ms=None,
     if rec is None:
         return None
     total_ms = (time.perf_counter() - rec.t0) * 1000.0
+    record = _monitor_step(rec, total_ms, iters, datapipe, replica_ms,
+                           replica_ids) if rec.monitored else None
+    # retroactive trace emission: the step and its phase boundaries are
+    # already measured, so the flight recorder gets them for the price of
+    # the dicts
+    tr = _trace()
+    if tr.enabled():
+        attrs = {}
+        if iters is not None:
+            attrs["iters"] = iters
+        if rec.cache is not None:
+            attrs["cache"] = rec.cache
+            attrs["fingerprint"] = rec.fingerprint
+            if rec.cache_level is not None:
+                attrs["cache_level"] = rec.cache_level
+        ctx = tr.record(f"{rec.kind}.step", rec.t0,
+                        rec.t0 + total_ms / 1000.0, kind="step",
+                        attrs=attrs)
+        for name, p0, p1 in rec.intervals:
+            tr.record(name, p0, p1, kind="phase", parent=ctx)
+    return record
+
+
+def _monitor_step(rec, total_ms, iters, datapipe, replica_ms, replica_ids):
+    """step_end's FLAGS_monitor half: registry, last_step, journal."""
     _registry.counter("steps_total", help="executor steps run",
                       kind=rec.kind).inc()
     _registry.histogram("step_ms", help="wall time per executor step",
@@ -345,23 +378,6 @@ def step_end(rec, iters=None, datapipe=None, replica_ms=None,
     writer = _journal_writer()
     if writer is not None:
         writer.write(record)
-
-    # retroactive trace emission: the step and its phase boundaries are
-    # already measured above, so the flight recorder gets them for free —
-    # one extra flag check per step when tracing is off
-    tr = _trace()
-    if tr.enabled():
-        attrs = {"step": step_idx}
-        if iters is not None:
-            attrs["iters"] = iters
-        if rec.cache is not None:
-            attrs["cache"] = rec.cache
-            attrs["fingerprint"] = rec.fingerprint
-        ctx = tr.record(f"{rec.kind}.step", rec.t0,
-                        rec.t0 + total_ms / 1000.0, kind="step",
-                        attrs=attrs)
-        for name, p0, p1 in rec.intervals:
-            tr.record(name, p0, p1, kind="phase", parent=ctx)
     return record
 
 

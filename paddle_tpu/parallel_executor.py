@@ -45,13 +45,13 @@ from .core.lod_tensor import LoDTensor
 from .core.places import accelerator_devices
 from .core.registry import SeqTensor
 from .core.scope import global_scope
-from .executor import as_numpy, _apply_debug_nans
+from .executor import as_numpy, lap_call, _apply_debug_nans
 from . import health as _health
 from .parallel import autoshard as _autoshard
 from .parallel import zero1 as _zero1
 from .resilience import chaos as _chaos
 from .resilience import watchdog as _watchdog
-from .trace import costs as _trace_costs
+from . import trace as _trace
 
 __all__ = ["ParallelExecutor", "ExecutionStrategy", "BuildStrategy"]
 
@@ -361,20 +361,20 @@ class ParallelExecutor:
         `async_fetch=True`
         returns FetchFuture handles instead of host arrays."""
         _apply_debug_nans()
-        # single flag check when monitoring is off (same contract as
-        # Executor.run); every site below gates on `mon is not None`
-        mon = monitor.step_begin("parallel_executor") \
-            if monitor.enabled() else None
+        # two flag reads when monitoring and tracing are off (same
+        # contract as Executor.run); every site below gates on `mon is not
+        # None`, the registry's on `monitored`; the laps tile the step
+        monitored = monitor.enabled()
+        mon = monitor.step_begin("parallel_executor", monitored) \
+            if monitored or _trace.enabled() else None
         feed = feed if feed is not None else feed_dict
         pipe = feed if hasattr(feed, "next_feed") else None
         if pipe is not None:  # datapipe.DataPipe (duck-typed)
             if iters is None:
                 iters = getattr(pipe, "feed_iters", None)
+            feed = pipe.next_feed()
             if mon is not None:
-                with mon.timed("feed_wait"):
-                    feed = pipe.next_feed()
-            else:
-                feed = pipe.next_feed()
+                mon.lap("feed_wait")
         from .datapipe.transfer import pop_markers
         feed, wire, chunk_donate = pop_markers(feed)
         if donate_feeds is None:
@@ -447,7 +447,7 @@ class ParallelExecutor:
             # restore onto zero1=0 after a sharded run: fold any shard-
             # layout accumulators back to their canonical full layout
             _zero1.ensure_scope_unsharded(scope, program)
-        if mon is not None and zplan.entries:
+        if monitored and zplan.entries:
             # analytic ring-collective accounting for the dp gradient path
             # (bytes, not time — XLA owns the schedule); journal extras ride
             # into the JSONL record for `python -m paddle_tpu monitor`
@@ -469,7 +469,7 @@ class ParallelExecutor:
                 k: int(v) for k, v in cb.items()}
             mon.extra["optimizer_state_bytes"] = int(osb)
             mon.extra["zero1"] = bool(use_zero1)
-        if mon is not None and osched is not None:
+        if monitored and osched is not None:
             analysis.schedule.record_gauges(
                 osched, context="parallel_executor")
             if mon.extra is None:
@@ -481,7 +481,7 @@ class ParallelExecutor:
                 "moves": len(osched.plan.moves),
                 "digest": osched.plan.digest(),
             }
-        if mon is not None and aplan is not None:
+        if monitored and aplan is not None:
             reg = monitor.registry()
             reg.gauge(
                 "autoshard_reshard_bytes_per_step",
@@ -512,7 +512,10 @@ class ParallelExecutor:
                 "conflicts": len(aplan.conflicts),
                 "reshard_bytes": int(aplan.reshard_bytes_per_step()),
             }
-        t_enc = time.perf_counter() if mon is not None else None
+        if mon is not None:
+            # program resolution: zero1 / overlap / fuse / autoshard plans
+            # (memoized per program) and their digests for the cache key
+            mon.lap("cache_lookup")
         feed_vals = {}
         if iters is not None:
             # shared stacking helper: list-length and leading-axis checks,
@@ -532,9 +535,11 @@ class ParallelExecutor:
                 feed_vals[name] = self._feed_sharding(tv)
         if mon is not None:
             # stacking + device_put onto the mesh (the h2d link for feeds)
-            mon.phase("feed_encode", time.perf_counter() - t_enc)
+            mon.lap("feed_encode")
 
         state_names, state_out_names = executor_core.collect_state_names(program, scope)
+        if mon is not None:
+            mon.lap("state_gather")
         # health sees the RESOLVED program, so under zero1 the plan pairs
         # the canonical param with its reduce-scattered [N, shard] grad —
         # shard-local reductions, no regather (health/stats.py)
@@ -563,7 +568,10 @@ class ParallelExecutor:
             ("pipeline", getattr(program, "_pipeline_stage", None)),
         )
         entry = self._compile_cache.get(cache_key)
-        fp = monitor.fingerprint_of(cache_key) if mon is not None else None
+        fp = None
+        if mon is not None:
+            fp = monitor.fingerprint_of(cache_key)
+            mon.lap("cache_lookup")
         build_s = 0.0
         was_miss = entry is None
         level = "l1" if entry is not None else None
@@ -620,8 +628,7 @@ class ParallelExecutor:
                 if iters is not None:
                     step = executor_core.build_multi_step_fn(step, iters)
                 probe = monitor.compile_probe(fp) \
-                    if mon is not None and flags.get("monitor_hlo_cost") \
-                    else None
+                    if monitored and flags.get("monitor_hlo_cost") else None
                 return executor_core.compile_step_fn(
                     step, donate_state=not flags.get("debug_nans"),
                     donate_feeds=donate_feeds, probe=probe,
@@ -643,6 +650,8 @@ class ParallelExecutor:
             build_s = time.perf_counter() - tb
             entry = (compiled, state_names, state_out_names)
             self._cache_store(cache_key, entry, mon=mon)
+            if mon is not None:
+                mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level)
         compiled, state_names, state_out_names = entry
@@ -703,7 +712,9 @@ class ParallelExecutor:
         # fault-injection hook (no-op without an installed ChaosMonkey),
         # before the dispatch so donated buffers are intact on a raise
         _chaos.on_run("parallel_executor")
-        tc = time.perf_counter() if mon is not None else None
+        if mon is not None:
+            # scope reads and (first step only) placement onto the mesh
+            mon.lap("state_gather")
         with _watchdog.armed("parallel_executor"), self._mesh:
             fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
         hstats = None
@@ -712,27 +723,18 @@ class ParallelExecutor:
             fetches = fetches[:-1]
         replica_ms = replica_ids = None
         if mon is not None:
-            if flags.get("monitor_replica_skew"):
+            if monitored and flags.get("monitor_replica_skew"):
                 # fence each replica's shard of a step output in device
                 # order — stamps per-replica completion. Synchronizes the
                 # dispatch queue, hence the separate opt-in flag.
                 leaf = fetches[0] if fetches else \
                     next(iter(new_mut.values()), None)
                 if leaf is not None:
-                    res = monitor.measure_replica_ms(leaf, tc)
+                    # t_lap: the stamp the call of `compiled` started at
+                    res = monitor.measure_replica_ms(leaf, mon.t_lap)
                     if res is not None:
                         replica_ms, replica_ids = res
-            call_s = time.perf_counter() - tc
-            if was_miss:  # first call compiles under async dispatch
-                mon.phase("compile", build_s + call_s)
-                monitor.record_compile(fp, wall_s=build_s + call_s)
-                _trace_costs.register_program(fp, program)
-            elif level == "l2":
-                # warm start: deserialize wall time, no XLA compile
-                mon.phase("cache_load", build_s)
-                mon.phase("dispatch", call_s)
-            else:
-                mon.phase("dispatch", call_s)
+            lap_call(mon, was_miss, build_s, fp, program)
         for n, v in new_mut.items():
             scope.set_var(n, v)
         if hstats is not None:
@@ -750,6 +752,11 @@ class ParallelExecutor:
                 help="measured per-device resident bytes of the step "
                      "state + fetches",
             ).set(float(live))
+        # the donated inputs die here and not at the return, where no phase
+        # would see it: some 500 arrays of four shards each are 1.8 ms
+        del mut_state, const_state, feed_vals, new_mut
+        if mon is not None:
+            mon.lap("write_back")
         outs = [
             executor_core.value_to_lod_tensor(f) if isinstance(f, SeqTensor) else f
             for f in fetches
@@ -759,11 +766,9 @@ class ParallelExecutor:
 
             outs = [FetchFuture(o) for o in outs]
         elif return_numpy:
+            outs = [as_numpy(o) for o in outs]
             if mon is not None:
-                with mon.timed("fetch_readback"):
-                    outs = [as_numpy(o) for o in outs]
-            else:
-                outs = [as_numpy(o) for o in outs]
+                mon.lap("fetch_readback")
         if mon is not None:
             monitor.step_end(mon, iters=iters, datapipe=pipe,
                              replica_ms=replica_ms, replica_ids=replica_ids)

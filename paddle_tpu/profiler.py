@@ -92,7 +92,18 @@ def start_profiler(state="All", trace_dir=None):
     if trace_dir:
         _trace_dir = trace_dir
         _last_trace_dir = trace_dir
-        jax.profiler.start_trace(trace_dir)
+        # the device trace only: this module's own events and the trace
+        # lane (paddle_tpu.trace) are the host side. JAX's host tracer
+        # logs one event per 48 bytes of every host-to-device copy on the
+        # v5e runtime (19 GB of host memory, 115 s to stop, copies 30x
+        # slower for a datapipe run: PERF.md section 6, PR 23). XLA:CPU
+        # alone keeps it: its "device" work runs on host threads and is
+        # in no other lane.
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        if jax.default_backend() != "cpu":
+            opts.host_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
         # the device trace's ts origin is (approximately) this instant;
         # host events are shifted to the same origin when exporting
         _trace_t0 = time.perf_counter()

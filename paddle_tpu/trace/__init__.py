@@ -8,11 +8,15 @@ hang". Three hot paths are instrumented end to end:
             child spans per request; the batcher's fan-in dispatch is a
             serve.batch span LINKED to every coalesced request's context
             (one slow request stays attributable after batching).
-  training  <kind>.step spans with feed_wait/feed_encode/compile/
-            dispatch/fetch_readback phase children, replayed from
-            monitor.StepRecord's existing phase boundaries at step_end;
-            datapipe.map / datapipe.stack / datapipe.transfer worker
-            spans with explicit context propagation into the pools.
+  training  <kind>.step spans whose phase children (feed_wait,
+            feed_encode, state_gather, cache_lookup, compile |
+            cache_load, dispatch, write_back, fetch_readback) tile the
+            step, replayed from monitor.StepRecord's laps at step_end
+            under FLAGS_trace alone; one chain of datapipe.* spans per
+            chunk (read, handoff, idle, decode, ring_put | map,
+            slot_wait, ticket_wait, lock_wait, upstream_wait, stack,
+            transfer, next), each recorded where the work happens — a
+            decode worker's own stamps ride its ack.
   compiles  compile phases carry the cache fingerprint; costs.py joins
             the fingerprint's HLO cost totals back onto ProgramDesc ops
             for the slowest-ops table (`paddle_tpu trace ops`).

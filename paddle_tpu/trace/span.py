@@ -16,8 +16,10 @@ Two recording styles, both landing in the flight recorder (recorder.py):
     trace.record("dispatch", d0, d1, parent=ctx)   # already-measured work
 
 Retroactive recording is how the executors emit step/phase spans without
-re-indenting their hot paths: monitor.StepRecord already carries the
-phase boundaries, and step_end replays them into spans after the step.
+re-indenting their hot paths: monitor.StepRecord carries the phase
+boundaries (its laps), and step_end replays them into spans after the
+step. It is also how a datapipe decode worker's stamps, taken in its own
+process, become spans in the parent (`thread=` names the worker's lane).
 
 Cross-thread propagation is explicit (thread pools outlive any one
 trace): capture `current()` where the work is submitted and `attach()`
@@ -32,6 +34,7 @@ as FLAGS_monitor).
 
 import contextlib
 import os
+import random
 import threading
 import time
 
@@ -55,8 +58,12 @@ _USE_CURRENT = object()
 _tls = threading.local()
 
 
+_ids = random.Random(os.urandom(16))
+
+
 def _new_id():
-    return os.urandom(8).hex()
+    # no system call per span: one span costs two ids, a step ten spans
+    return format(_ids.getrandbits(64), "016x")
 
 
 def enabled():
@@ -112,7 +119,7 @@ def attach(ctx):
 
 
 def record(name, t0, t1, kind="span", ctx=None, parent=_USE_CURRENT,
-           links=None, attrs=None):
+           links=None, attrs=None, thread=None):
     """Retroactively stamp one finished span into the flight recorder.
 
     t0/t1 are time.perf_counter() seconds (the manifest carries the
@@ -120,6 +127,9 @@ def record(name, t0, t1, kind="span", ctx=None, parent=_USE_CURRENT,
     (new_context), otherwise one is minted under `parent`; passing
     parent=None explicitly makes a root span. Returns the span's
     SpanContext (None when tracing is off) so children can parent to it.
+    `thread` names the lane of work that another thread or process did
+    and this one only reports (a datapipe decode worker's stamps arrive
+    with its ack); default: the calling thread.
     """
     if not enabled():
         return None
@@ -135,7 +145,7 @@ def record(name, t0, t1, kind="span", ctx=None, parent=_USE_CURRENT,
         "parent": parent.span_id if parent is not None else None,
         "t0": float(t0),
         "t1": float(t1),
-        "thread": threading.current_thread().name,
+        "thread": thread or threading.current_thread().name,
     }
     if links:
         sp["links"] = [l.to_dict() for l in links if l is not None]

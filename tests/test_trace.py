@@ -200,11 +200,31 @@ def test_feeder_transfer_spans_inherit_consumer_context():
     by_name = {}
     for s in spans:
         by_name.setdefault(s["name"], []).append(s)
-    assert len(by_name["datapipe.stack"]) == 3
-    assert len(by_name["datapipe.transfer"]) == 3
+    # what used to be one `datapipe.stack` span is three: the wait for
+    # the source lock, the wait for upstream, and the copy alone
+    for name in ("datapipe.lock_wait", "datapipe.upstream_wait",
+                 "datapipe.stack", "datapipe.transfer", "datapipe.next"):
+        chunks = sorted(s["attrs"]["chunk"] for s in by_name[name])
+        # the pull that found the source exhausted waited too (chunk 3)
+        assert chunks == [0, 1, 2] or (
+            name.endswith("_wait") and chunks == [0, 1, 2, 3]), name
     assert {s["trace"] for s in by_name["datapipe.transfer"]} == \
         {root.trace_id}
     assert all(s["attrs"]["bytes"] > 0 for s in by_name["datapipe.transfer"])
+    # the three parts follow one another on the lane that pulled the chunk
+    for c in range(3):
+        lw, uw, st, tr = (next(s for s in by_name[n]
+                               if s["attrs"]["chunk"] == c)
+                          for n in ("datapipe.lock_wait",
+                                    "datapipe.upstream_wait",
+                                    "datapipe.stack", "datapipe.transfer"))
+        assert lw["t1"] <= uw["t0"] <= uw["t1"] <= st["t0"] <= st["t1"] \
+            <= tr["t0"]
+        assert len({lw["thread"], uw["thread"], st["thread"],
+                    tr["thread"]}) == 1
+    assert {s["thread"] for s in by_name["datapipe.next"]} == {"MainThread"}
+    assert all(s["thread"].startswith("datapipe-feed")
+               for s in by_name["datapipe.ticket_wait"])
 
 
 # ---------------------------------------------------------------------------
@@ -444,3 +464,351 @@ def test_profiler_chrome_export_includes_trace_lane(tmp_path):
     traced = [e for e in events if e.get("name") == "traced-side"]
     assert host and host[0]["pid"] == 0
     assert traced and traced[0]["pid"] == trace.CHROME_PID
+
+
+# ---------------------------------------------------------------------------
+# PR 24: FLAGS_trace alone; phases that tile the step; the chunk's chain
+# ---------------------------------------------------------------------------
+
+def _train_program(size=8):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[size], dtype="float32")
+        h = fluid.layers.fc(input=x, size=size, act="relu")
+        loss = fluid.layers.mean(fluid.layers.fc(input=h, size=1))
+        fluid.optimizer.SGD(learning_rate=0.01).minimize(loss)
+    return main, startup, loss
+
+
+def _trace_only(**extra):
+    return flags.flag_guard(trace=True, monitor=False, **extra)
+
+
+def _children(spans, step):
+    return sorted((s for s in spans if s["kind"] == "phase"
+                   and s["parent"] == step["span"]), key=lambda s: s["t0"])
+
+
+def _assert_tiled(spans, step):
+    """The phase children neither overlap nor leave more than 5% of the
+    step between them."""
+    kids = _children(spans, step)
+    assert kids
+    assert kids[0]["t0"] >= step["t0"] and kids[-1]["t1"] <= step["t1"]
+    for a, b in zip(kids, kids[1:]):
+        assert a["t1"] <= b["t0"], (a["name"], b["name"])
+    covered = sum(k["t1"] - k["t0"] for k in kids)
+    assert covered >= 0.95 * (step["t1"] - step["t0"]), \
+        [(k["name"], k["t1"] - k["t0"]) for k in kids]
+    return [k["name"] for k in kids]
+
+
+def test_trace_alone_emits_step_spans_and_leaves_registry_empty():
+    main, startup, loss = _train_program()
+    feed = {"x": np.ones((4, 8), np.float32)}
+    scope = fluid.Scope()
+    with _trace_only(), fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])
+        exe.run(main, feed=feed, fetch_list=[loss])
+    spans, dropped = trace.snapshot()
+    assert dropped == 0
+    steps = [s for s in spans if s["name"] == "executor.step"]
+    assert len(steps) == 3
+    assert [s["attrs"]["cache"] for s in steps] == ["miss", "miss", "hit"]
+    assert steps[-1]["attrs"]["cache_level"] == "l1"
+    assert all(isinstance(s["attrs"]["fingerprint"], str) for s in steps)
+    assert {"feed_encode", "state_gather", "cache_lookup", "dispatch",
+            "write_back", "fetch_readback"} \
+        <= {k["name"] for k in _children(spans, steps[-1])}
+    # FLAGS_monitor=0: the registry, last_step and compile_info stay as
+    # they were
+    assert monitor.registry().snapshot() == {}
+    assert monitor.last_step() is None
+    assert monitor.compile_info() == {}
+
+
+@pytest.mark.parametrize("case", ["miss", "hit", "l2"])
+@pytest.mark.parametrize("iters", [None, 3])
+def test_executor_step_children_tile_the_step(tmp_path, case, iters):
+    main, startup, loss = _train_program()
+    feed = {"x": np.ones((4, 8) if iters is None else (iters, 4, 8),
+                         np.float32)}
+    scope = fluid.Scope()
+    with _trace_only(compile_cache_dir=str(tmp_path)), \
+            fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+
+        def run():
+            exe.run(main, feed=feed, fetch_list=[loss], iters=iters)
+
+        if case != "miss":
+            run()
+            if iters is not None:
+                run()   # the second scan call sees its own donated outputs
+        if case == "l2":
+            exe._compile_cache.clear()   # a fresh process's L1 miss
+        trace.reset()
+        run()
+    spans, _ = trace.snapshot()
+    step, = [s for s in spans if s["name"] == "executor.step"]
+    names = _assert_tiled(spans, step)
+    assert step["attrs"]["cache"] == ("miss" if case == "miss" else "hit")
+    assert step["attrs"].get("cache_level") == \
+        {"miss": None, "hit": "l1", "l2": "l2"}[case]
+    assert step["attrs"].get("iters") == iters
+    want = {"miss": "compile", "hit": "dispatch", "l2": "cache_load"}[case]
+    assert want in names
+    assert ("compile" in names) == (case == "miss")
+    assert {"feed_encode", "state_gather", "cache_lookup",
+            "write_back"} <= set(names)
+
+
+@pytest.mark.parametrize("case", ["miss", "hit", "l2"])
+def test_parallel_executor_step_children_tile_the_step(tmp_path, case):
+    import jax
+
+    main, startup, loss = _train_program()
+    feed = {"x": np.ones((8, 8), np.float32)}
+    scope = fluid.Scope()
+    with _trace_only(compile_cache_dir=str(tmp_path)), \
+            fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                    main_program=main,
+                                    devices=jax.devices()[:4])
+        assert pe.device_count == 4
+        if case != "miss":
+            pe.run([loss], feed=feed)
+            pe.run([loss], feed=feed)
+        if case == "l2":
+            pe._compile_cache.clear()
+        trace.reset()
+        pe.run([loss], feed=feed)
+    spans, _ = trace.snapshot()
+    step, = [s for s in spans if s["name"] == "parallel_executor.step"]
+    names = _assert_tiled(spans, step)
+    assert step["attrs"]["cache"] == ("miss" if case == "miss" else "hit")
+    want = {"miss": "compile", "hit": "dispatch", "l2": "cache_load"}[case]
+    assert want in names
+    assert {"feed_encode", "state_gather", "cache_lookup", "write_back",
+            "fetch_readback"} <= set(names)
+    assert monitor.registry().snapshot() == {}
+
+
+def _decode_sample(i):
+    return {"x": np.full((4, 3), i % 251, np.uint8),
+            "y": np.array([i], np.int32)}
+
+
+def _chunk_chain(spans, chunk, K):
+    """The spans of one chunk in time order: those that carry its chunk
+    id, and the per-item ones (read, map) whose idx falls into it."""
+    pipes = {s["attrs"]["pipe"] for s in spans
+             if s["name"].startswith("datapipe.")}
+    assert len(pipes) == 1, pipes
+    out = []
+    for s in spans:
+        a = s.get("attrs", {})
+        if a.get("chunk") == chunk or (
+                "chunk" not in a and "idx" in a and a["idx"] // K == chunk):
+            out.append(s)
+    return sorted(out, key=lambda s: s["t0"])
+
+
+def _first(chain, name):
+    return next(s for s in chain if s["name"] == name)
+
+
+@pytest.mark.parametrize("processes", [True, False])
+def test_datapipe_chunk_chain_in_time_order(processes,
+                                            no_datapipe_thread_leaks):
+    from paddle_tpu import datapipe
+
+    K = 4
+    with _trace_only():
+        t_before = time.perf_counter()
+        pipe = (datapipe.DataPipe.from_reader(lambda: iter(range(3 * K)))
+                .map(_decode_sample, num_workers=2, processes=processes)
+                .prefetch_to_device(place=fluid.CPUPlace(), chunk=K,
+                                    capacity=2, transfer_threads=2))
+        assert len(list(pipe)) == 3
+        t_after = time.perf_counter()
+    spans, dropped = trace.snapshot()
+    assert dropped == 0
+    # nothing is reconstructed in the parent any more: every name is one
+    # of the stages the chunk really passed
+    assert {s["name"] for s in spans} <= {
+        "datapipe." + n for n in (
+            "read", "map", "handoff", "idle", "decode", "ring_put",
+            "slot_wait", "ticket_wait", "lock_wait", "upstream_wait",
+            "stack", "transfer", "next")}
+    stages = ["datapipe.read", "datapipe.handoff", "datapipe.decode",
+              "datapipe.ring_put", "datapipe.stack", "datapipe.transfer",
+              "datapipe.next"] if processes else \
+        ["datapipe.read", "datapipe.map", "datapipe.stack",
+         "datapipe.transfer", "datapipe.next"]
+    for chunk in (1, 2):   # chunk 0 holds the schema probe's row
+        chain = _chunk_chain(spans, chunk, K)
+        # each stage starts its first piece of the chunk after the stage
+        # before it did (the consumer alone may be waiting already) ...
+        firsts = [_first(chain, n) for n in stages]
+        for a, b in zip(firsts[:-1], firsts[1:-1]):
+            assert a["t0"] <= b["t0"], (a["name"], b["name"])
+        # ... and is done with the chunk before the next stage is
+        lasts = [max(s["t1"] for s in chain if s["name"] == n)
+                 for n in stages]
+        assert lasts == sorted(lasts), list(zip(stages, lasts))
+        # the chain ends where the consumer has the chunk
+        assert max(s["t1"] for s in chain) == lasts[-1]
+        assert all(t_before <= s["t0"] <= s["t1"] <= t_after
+                   for s in chain)
+    if processes:
+        workers = {s["thread"] for s in spans
+                   if s["name"] == "datapipe.decode"}
+        assert workers and all(w.startswith("datapipe-proc-")
+                               for w in workers)
+        assert sum(s["attrs"]["n"] for s in spans
+                   if s["name"] == "datapipe.decode") == 3 * K
+
+
+def test_worker_decode_stamps_lie_inside_parents_put_to_ack(
+        no_datapipe_thread_leaks):
+    """One clock for the parent and its forked workers: a worker's own
+    `perf_counter` stamps of a decode fall between the parent's stamp of
+    the item's put and the parent's receipt of the ack."""
+    from paddle_tpu.datapipe.process_map import ProcessPoolMap
+
+    acked = {}
+
+    def decode(i):
+        time.sleep(0.002)
+        return i * 2
+
+    with _trace_only():
+        pm = ProcessPoolMap(range(8), decode, num_workers=2, pipe_id=7)
+        out = []
+        for i, v in enumerate(pm):
+            acked[i] = time.perf_counter()   # emitted after its ack came
+            out.append(v)
+    assert out == [i * 2 for i in range(8)]
+    spans, _ = trace.snapshot()
+    by = {}
+    for s in spans:
+        assert s["attrs"]["pipe"] == 7
+        by.setdefault(s["name"], {})[s["attrs"]["idx"]] = s
+    assert set(by) == {"datapipe.handoff", "datapipe.idle",
+                       "datapipe.decode"}
+    for idx in range(8):
+        put = by["datapipe.handoff"][idx]["t0"]       # parent's stamp
+        got = by["datapipe.handoff"][idx]["t1"]       # worker's stamp
+        dec = by["datapipe.decode"][idx]
+        assert put <= got <= dec["t0"] < dec["t1"] <= acked[idx], idx
+        assert dec["t1"] - dec["t0"] >= 0.002
+        idle = by["datapipe.idle"][idx]
+        assert idle["t1"] == got and idle["t0"] <= got
+        assert dec["thread"] == f"datapipe-proc-{dec['attrs']['worker']}"
+
+
+class _Conn:
+    def __init__(self):
+        self.sent = []
+
+    def send(self, msg):
+        self.sent.append(msg)
+
+    def close(self):
+        pass
+
+
+@pytest.mark.parametrize("tracing", [False, True])
+def test_worker_ack_carries_span_payload_only_when_tracing(tracing):
+    import queue
+
+    from paddle_tpu.datapipe.process_map import _worker_main
+
+    q, conn = queue.Queue(), _Conn()
+    stamp = (time.perf_counter(),) if tracing else ()
+    q.put(("task", 0, None, 0, 5) + stamp)
+    q.put(("probe", 1, 6) + stamp)
+    q.put(("stop",))
+    _worker_main(0, lambda v: v + 1, q, conn, tracing)
+    ok, probe = conn.sent
+    assert ok[:3] == ("ok", 0, 6) and probe[:3] == ("probe_ok", 1, 7)
+    if not tracing:
+        # the messages FLAGS_trace=0 has always sent: nothing rides along
+        assert len(ok) == 4 and len(probe) == 4
+        return
+    for msg in (ok, probe):
+        assert len(msg) == 5
+        t_put, t_free, t_got, d0, d1, t_w = msg[-1]
+        assert t_put == stamp[0] and t_w is None
+        assert t_free <= t_got <= d0 <= d1
+
+
+def test_both_flags_off_no_step_record_no_spans(monkeypatch):
+    from paddle_tpu import datapipe
+
+    main, startup, loss = _train_program()
+    feed = {"x": np.ones((4, 8), np.float32)}
+
+    def boom(*a, **k):
+        raise AssertionError("StepRecord made with both flags off")
+
+    monkeypatch.setattr(monitor, "step_begin", boom)
+    scope = fluid.Scope()
+    with flags.flag_guard(trace=False, monitor=False), \
+            fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])
+        exe.run(main, feed={"x": np.ones((2, 4, 8), np.float32)},
+                fetch_list=[loss], iters=2)
+        pipe = (datapipe.DataPipe.from_reader(lambda: iter(range(4)))
+                .map(_decode_sample, num_workers=2)
+                .prefetch_to_device(place=fluid.CPUPlace(), chunk=2))
+        assert len(list(pipe)) == 2
+    assert trace.snapshot() == ([], 0)
+    assert monitor.registry().snapshot() == {}
+
+
+def test_record_takes_the_lane_of_the_reporting_worker():
+    with _trace_only():
+        t = time.perf_counter()
+        trace.record("datapipe.decode", t, t + 1.0, thread="datapipe-proc-3")
+        trace.record("here", t, t + 1.0)
+    spans, _ = trace.snapshot()
+    assert {s["name"]: s["thread"] for s in spans} == {
+        "datapipe.decode": "datapipe-proc-3",
+        "here": threading.current_thread().name}
+    ids = {s["span"] for s in spans} | {s["trace"] for s in spans}
+    assert len(ids) == 4 and all(len(i) == 16 for i in ids)
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_start_profiler_leaves_jax_host_tracer_off(tmp_path, monkeypatch,
+                                                   backend):
+    """The profiler's host lane comes from its own events and the trace
+    lane; JAX's host tracer logs every 48 bytes of a host-to-device copy
+    on the chip's runtime (PERF.md section 6, PR 23). On XLA:CPU it is
+    the only place the executions show, and stays on."""
+    import jax
+
+    from paddle_tpu import profiler
+
+    seen = {}
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+
+    def fake_start(log_dir, *a, **kw):
+        seen["dir"] = log_dir
+        seen["opts"] = kw.get("profiler_options")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", fake_start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    profiler.start_profiler(trace_dir=str(tmp_path))
+    profiler.stop_profiler()
+    assert seen["dir"] == str(tmp_path)
+    assert seen["opts"].python_tracer_level == 0
+    assert (seen["opts"].host_tracer_level == 0) == (backend == "tpu")
