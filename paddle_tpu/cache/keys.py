@@ -11,6 +11,9 @@ order) and appends everything else that changes the compiled executable:
     ParallelExecutor — zero1/overlap/autoshard digests), which is already
     process-stable by construction (sorted tuples of primitives; no id()s,
     no hash()es)
+  * the lowering: a digest of this package's own sources (the kernels, the
+    trace-time peepholes and the executors' wrappers decide what a program
+    compiles to, and none of them is in the program's serialization)
   * the runtime environment: jax + jaxlib versions, the backend platform
     and the PJRT platform_version, i.e. the libtpu build (an executable
     serialized by one XLA build must never be fed to another — the store
@@ -27,7 +30,8 @@ tests/test_compile_cache.py.
 
 import hashlib
 
-__all__ = ["program_digest", "stable_digest", "environment", "is_digest"]
+__all__ = ["program_digest", "stable_digest", "environment", "is_digest",
+           "lowering_version"]
 
 _HEX = set("0123456789abcdef")
 
@@ -77,6 +81,33 @@ def environment():
             client.platform_version)
 
 
+_lowering_version = None
+
+
+def lowering_version():
+    """sha256 hex over this package's Python sources. The program digest
+    is the program's CONTENT, not what it lowers to: the same program
+    under other kernels, peepholes or executor wrappers is another
+    executable, so an upgrade of the package is a cold start, like an
+    upgrade of jax — with no salt to remember to bump. Read once a
+    process (2 MB, a few ms), on the first compile-cache miss."""
+    global _lowering_version
+    if _lowering_version is None:
+        import os
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        h = hashlib.sha256()
+        for folder, dirs, files in os.walk(root):
+            dirs.sort()
+            for name in sorted(f for f in files if f.endswith(".py")):
+                path = os.path.join(folder, name)
+                h.update(os.path.relpath(path, root).encode("utf-8") + b"\0")
+                with open(path, "rb") as f:
+                    h.update(f.read() + b"\0")
+        _lowering_version = h.hexdigest()
+    return _lowering_version
+
+
 def stable_digest(program, key_tail, extra=()):
     """Hex digest naming one L2 entry.
 
@@ -87,6 +118,8 @@ def stable_digest(program, key_tail, extra=()):
     h = hashlib.sha256()
     h.update(b"paddle_tpu-aot-v1\0")
     h.update(repr(environment()).encode("utf-8"))
+    h.update(b"\0")
+    h.update(lowering_version().encode("utf-8"))
     h.update(b"\0")
     h.update(program_digest(program).encode("utf-8"))
     h.update(b"\0")

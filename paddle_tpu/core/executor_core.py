@@ -21,6 +21,7 @@ from . import registry
 from .registry import SeqTensor
 from . import dtypes
 from .. import flags
+from ..ops import bn_pool
 
 
 def check_values_finite(named_values, context=""):
@@ -212,6 +213,9 @@ def _fuse_optimizer_group(ops, start, env, ctx, fused_ids):
 
 def run_ops(ops, env, ctx):
     fused_ids = set()
+    # offered every op of a traced block, says whether it lowered the op
+    pairs = None if ctx.eager else bn_pool.Lowering(
+        ops, ctx, _run_one_op, _bind_outputs)
     for i, op in enumerate(ops):
         if id(op) in fused_ids:
             continue
@@ -222,11 +226,13 @@ def run_ops(ops, env, ctx):
                 fused_ids |= done
                 if id(op) in fused_ids:
                     continue
-        _run_one_op(op, env, ctx)
+        if pairs is None or not pairs.offer(op, env, ctx):
+            _run_one_op(op, env, ctx)
     return env
 
 
-def _run_one_op(op, env, ctx):
+def _run_one_op(op, env, ctx, attrs=None):
+    attrs = op.attrs if attrs is None else attrs
     op_def = registry.lookup(op.type)
     if op_def.no_trace and not ctx.eager:
         raise TraceUnsupported(op.type)
@@ -246,9 +252,9 @@ def _run_one_op(op, env, ctx):
         if ctx.eager and _profiler_enabled():
             from .. import profiler
             with profiler.record_event(f"op::{op.type}"):
-                outs = registry.run_kernel(op_def, ctx, ins, op.attrs) or {}
+                outs = registry.run_kernel(op_def, ctx, ins, attrs) or {}
         else:
-            outs = registry.run_kernel(op_def, ctx, ins, op.attrs) or {}
+            outs = registry.run_kernel(op_def, ctx, ins, attrs) or {}
     except TraceUnsupported:
         raise
     except Exception as e:
@@ -261,6 +267,11 @@ def _run_one_op(op, env, ctx):
                 if n and i < len(vals) and vals[i] is not None:
                     named.append((n, vals[i]))
         check_values_finite(named, context=f" after op {op.type!r}")
+    _bind_outputs(op, outs, env, ctx)
+    return outs
+
+
+def _bind_outputs(op, outs, env, ctx):
     cons = ctx.constraints
     for slot, names in op.outputs.items():
         vals = outs.get(slot, [])

@@ -9,6 +9,7 @@ from . import util
 from . import math_ops
 from . import activation_ops
 from . import tensor_ops
+from . import bn_pool
 from . import nn_ops
 from . import optimizer_ops
 from . import sequence_ops

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register_op, register_grad_maker, set_stop_gradient_outputs
+from .bn_pool import PER_SAMPLE_SUMS
 from .util import first, many, out
 
 
@@ -198,6 +199,7 @@ def batch_norm_op(ctx, ins, attrs):
     shape = [1] * x.ndim
     shape[1 if layout == "NCHW" else -1] = x.shape[1 if layout == "NCHW" else -1]
 
+    s1 = None
     if is_test:
         m, v = mean, var
         saved_mean, saved_var = mean, var
@@ -214,7 +216,15 @@ def batch_norm_op(ctx, ins, attrs):
         # share the stats read with the normalize path; they ran slower
         # on an older stack and have not been re-measured on the current
         # host.
-        m = jnp.mean(xf, axis=axes)
+        # (bn_pool.PER_SAMPLE_SUMS: Y is read by a global average pool;
+        # the mean goes through the per-sample sums, which XLA emits from
+        # the fusion that makes x, and mean_hw(Y) is algebra on them)
+        if attrs.get(PER_SAMPLE_SUMS):
+            s1 = jnp.sum(xf, axis=axes[1:])  # [N, C]
+            hw = x.size // s1.size
+            m = jnp.sum(s1, axis=0) / (s1.shape[0] * hw)
+        else:
+            m = jnp.mean(xf, axis=axes)
         msq = jnp.mean(jnp.square(xf), axis=axes)
         v = jnp.maximum(msq - jnp.square(m), 0.0)
         mean_out = mean * momentum + m * (1 - momentum)
@@ -223,13 +233,17 @@ def batch_norm_op(ctx, ins, attrs):
     inv = lax.rsqrt(v.astype(jnp.float32) + eps)
     y = (x.astype(jnp.float32) - m.reshape(shape)) * inv.reshape(shape)
     y = y * scale.reshape(shape) + bias.reshape(shape)
-    return out(
+    outs = out(
         Y=y.astype(x.dtype),
         MeanOut=mean_out,
         VarianceOut=var_out,
         SavedMean=saved_mean,
         SavedVariance=jax.lax.stop_gradient(inv),
     )
+    if s1 is not None:
+        outs["SampleSum"] = [s1]
+        outs["PooledY"] = [(s1 / hw - m) * inv * scale + bias]
+    return outs
 
 
 set_stop_gradient_outputs("batch_norm", ["MeanOut", "VarianceOut", "SavedMean", "SavedVariance"])

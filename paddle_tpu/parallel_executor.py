@@ -46,6 +46,7 @@ from .core.places import accelerator_devices
 from .core.registry import SeqTensor
 from .core.scope import global_scope
 from .executor import as_numpy, lap_call, _apply_debug_nans
+from .ops import bn_pool
 from . import health as _health
 from .parallel import autoshard as _autoshard
 from .parallel import zero1 as _zero1
@@ -653,7 +654,8 @@ class ParallelExecutor:
             if mon is not None:
                 mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
-            mon.mark_cache(not was_miss, fingerprint=fp, level=level)
+            mon.mark_cache(not was_miss, fingerprint=fp, level=level,
+                           fused_bn_global_pool=bn_pool.count(program))
         compiled, state_names, state_out_names = entry
 
         multiproc = any(

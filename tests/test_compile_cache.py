@@ -123,6 +123,19 @@ def test_stable_digest_sensitive_to_tail_and_extra():
                                  extra=(("kind", "parallel_executor"),))
 
 
+def test_stable_digest_follows_the_package_sources(monkeypatch):
+    """The same program under other kernels or peepholes lowers to another
+    executable: the digest carries a hash of the package's sources, so no
+    salt has to be bumped by hand when the lowering changes."""
+    from paddle_tpu.cache import keys
+
+    m, _, _ = _mlp()
+    base = stable_digest(m, ())
+    assert keys.is_digest(keys.lowering_version())
+    monkeypatch.setattr(keys, "_lowering_version", "0" * 64)
+    assert stable_digest(m, ()) != base
+
+
 _CHILD = """
 import json, os
 import numpy as np

@@ -6,7 +6,10 @@ test_pool2d_op.py, test_batch_norm_op.py, test_layer_norm_op.py,
 test_lookup_table_op.py, test_cross_entropy_op.py.
 """
 
+import contextlib
+
 import numpy as np
+import pytest
 
 from op_test import OpTest
 
@@ -600,3 +603,283 @@ def test_conv2d_nhwc_trains():
             lv, = exe.run(main, feed={"x": xv, "y": yv}, fetch_list=[loss])
             losses.append(float(np.asarray(lv)))
     assert losses[-1] < 0.5 * losses[0], (losses[0], losses[-1])
+
+
+# ---------------------------------------------------------------------------
+# batch_norm + global average pool2d lowered together (executor_core.run_ops)
+# ---------------------------------------------------------------------------
+def _bn_pool_program(layout, pool="global_avg", act=None, is_test=False,
+                     third_reader=False, gates=1, pools=1):
+    """conv -> batch_norm -> pool2d -> fc gate -> Y * gate -> loss: the SE
+    squeeze, so Y has a second reader beside the pool. `gates` / `pools`:
+    how many per-sample gates multiply Y, how many global pools read it."""
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        shape = [6, 6, 3] if layout == "NHWC" else [3, 6, 6]
+        x = fluid.layers.data(name="x", shape=shape, dtype="float32")
+        c = fluid.layers.conv2d(x, num_filters=8, filter_size=3, padding=1,
+                                data_format=layout, bias_attr=False)
+        y = fluid.layers.batch_norm(c, act=act, data_layout=layout,
+                                    is_test=is_test)
+        kw = dict(pool_type="avg", global_pooling=True)
+        if pool == "global_max":
+            kw = dict(pool_type="max", global_pooling=True)
+        elif pool == "window_avg":
+            kw = dict(pool_type="avg", pool_size=6)
+        p = fluid.layers.pool2d(y, data_format=layout, **kw)
+        squeezed = p if pools == 1 else fluid.layers.elementwise_add(
+            p, fluid.layers.pool2d(y, data_format=layout, **kw))
+        loss = None
+        for k in range(gates):
+            g = fluid.layers.fc(squeezed, size=8,
+                                act=("sigmoid", "tanh")[k])
+            if layout == "NHWC":
+                s = fluid.layers.elementwise_mul(
+                    y, fluid.layers.reshape(g, [-1, 1, 1, 8]))
+            else:
+                s = fluid.layers.elementwise_mul(y, g, axis=0)
+            part = fluid.layers.mean(fluid.layers.square(s))
+            loss = part if loss is None else fluid.layers.elementwise_add(
+                loss, fluid.layers.scale(part, scale=float(k + 2)))
+        if third_reader:
+            loss = fluid.layers.elementwise_add(
+                loss, fluid.layers.mean(fluid.layers.square(y)))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    bn = [op for op in main.global_block().ops if op.type == "batch_norm"][0]
+    fc = [op for op in main.global_block().ops if op.type == "mul"][0]
+    fetch = {"pool": p.name, "y": bn.output("Y")[0], "loss": loss.name,
+             "x": bn.input("X")[0],
+             "dx": bn.input("X")[0] + "@GRAD",
+             "dscale": bn.input("Scale")[0] + "@GRAD",
+             "dbias": bn.input("Bias")[0] + "@GRAD",
+             "dgate_w": fc.input("Y")[0] + "@GRAD"}
+    fetch.update(("dgate_w%d" % k, op.input("Y")[0] + "@GRAD")
+                 for k, op in enumerate(main.global_block().ops)
+                 if op.type == "mul" and op is not fc)
+    state = {k: bn.input(s)[0] for k, s in
+             (("scale", "Scale"), ("bias", "Bias"),
+              ("running_mean", "Mean"), ("running_var", "Variance"))}
+    state["filter"] = [op for op in main.global_block().ops
+                       if op.type == "conv2d"][0].input("Filter")[0]
+    return main, startup, fetch, state
+
+
+@contextlib.contextmanager
+def _pair_lowering(monkeypatch, fuse):
+    """Counts what run_ops lowers together while tracing, as a list
+    [forward pairs, backward through the pair's algebra]; with `fuse`
+    false the peephole is forced off."""
+    from paddle_tpu.ops import bn_pool
+
+    counts = [0, 0]
+    real, real_grad = bn_pool.Lowering._forward, bn_pool._Pair.grad
+
+    def forward(*a):
+        done = real(*a)
+        counts[0] += done
+        return done
+
+    def grad(pair):
+        counts[1] += 1
+        return real_grad(pair)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(bn_pool.Lowering, "_forward", forward)
+        mp.setattr(bn_pool._Pair, "grad", grad)
+        if not fuse:
+            mp.setattr(bn_pool, "match", lambda ops: {})
+        yield counts
+
+
+def _run_bn_pool(monkeypatch, fuse, layout, use_amp, **kw):
+    """One training step; the fetched values, the state after it, and how
+    many pairs run_ops lowered together while tracing: (forward, and
+    backward through the pair's algebra)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import amp
+
+    main, startup, fetch, state = _bn_pool_program(layout, **kw)
+    with _pair_lowering(monkeypatch, fuse) as lowered:
+        if use_amp:
+            amp.enable("bfloat16")
+        try:
+            scope = fluid.Scope()
+            with fluid.scope_guard(scope):
+                exe = fluid.Executor(fluid.CPUPlace())
+                exe.run(startup)
+                # small whole numbers in, so the convolution's output is
+                # exact in bf16 whether or not XLA keeps that rounding
+                rs = np.random.RandomState(0)
+                scope.set_var(state["filter"], rs.randint(
+                    -1, 2, (8, 3, 3, 3)).astype("float32"))
+                before = {k: np.asarray(scope.find_var(n), np.float32)
+                          for k, n in state.items()}
+                xv = rs.randint(-3, 4, (4, 3, 6, 6))
+                if layout == "NHWC":
+                    xv = xv.transpose(0, 2, 3, 1)
+                vals = exe.run(main, feed={"x": xv.astype("float32")},
+                               fetch_list=list(fetch.values()))
+                got = {k: np.asarray(v, np.float32)
+                       for k, v in zip(fetch, vals)}
+                for k in ("running_mean", "running_var"):
+                    got[k] = np.asarray(scope.find_var(state[k]), np.float32)
+        finally:
+            amp.disable()
+    return got, before, tuple(lowered), main
+
+
+@pytest.mark.parametrize("case", [
+    "NCHW-float32", "NHWC-float32", "NCHW-bfloat16", "NHWC-bfloat16",
+    "no-fuse-max-pool", "no-fuse-window", "no-fuse-is-test",
+    "no-fuse-activation-between", "generic-backward-third-reader",
+    "generic-backward-two-gates-NCHW", "generic-backward-two-gates-NHWC",
+    "generic-backward-two-pools"])
+def test_batch_norm_global_pool_pair(monkeypatch, case):
+    """A training batch_norm whose Y a global average pool2d reads is
+    lowered with it (the pool's Out is algebra on the per-sample sums of
+    the statistics), and in an SE block so is its backward (the gate's and
+    the batch norm's gradients are algebra on per-sample sums of d_out):
+    same outputs, running statistics and gradients as the ops run apart,
+    and nothing else is lowered that way."""
+    from paddle_tpu.ops import bn_pool
+
+    if case.startswith("generic-backward"):
+        # Y's gradient is not the sum of one gate's and one pool's: the
+        # forward pair, and a backward that refuses (the generic vjp)
+        kw = {"third-reader": dict(third_reader=True),
+              "two-gates-NCHW": dict(gates=2),
+              "two-gates-NHWC": dict(gates=2),
+              "two-pools": dict(pools=2)}[case[len("generic-backward-"):]]
+        layout = "NHWC" if case.endswith("NHWC") else "NCHW"
+        got, _, lowered, _ = _run_bn_pool(monkeypatch, True, layout, False,
+                                          **kw)
+        want, _, _, _ = _run_bn_pool(monkeypatch, False, layout, False, **kw)
+        assert lowered == (1, 0)
+        assert sum(k.startswith("dgate_w") for k in want) \
+            == kw.get("gates", 1)
+        for k in want:
+            np.testing.assert_allclose(got[k], want[k], rtol=2e-5,
+                                       atol=1e-6, err_msg=k)
+        return
+    if case.startswith("no-fuse"):
+        kw = {"no-fuse-max-pool": dict(pool="global_max"),
+              "no-fuse-window": dict(pool="window_avg"),
+              "no-fuse-is-test": dict(is_test=True),
+              "no-fuse-activation-between": dict(act="relu")}[case]
+        got, _, lowered, main = _run_bn_pool(monkeypatch, True, "NCHW",
+                                             False, **kw)
+        want, _, _, _ = _run_bn_pool(monkeypatch, False, "NCHW", False, **kw)
+        assert lowered == (0, 0)
+        assert bn_pool.count(main) == 0
+        for k in want:  # the same expression tree, so the same bits
+            np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+        return
+
+    layout, dtype = case.split("-")
+    use_amp = dtype == "bfloat16"
+    got, before, lowered, main = _run_bn_pool(monkeypatch, True, layout,
+                                              use_amp)
+    want, _, apart, _ = _run_bn_pool(monkeypatch, False, layout, use_amp)
+    assert (lowered, apart) == ((1, 1), (0, 0))
+    assert bn_pool.count(main) == 1
+    assert got["pool"].shape == want["pool"].shape
+    if not use_amp:
+        for k in want:
+            np.testing.assert_allclose(got[k], want[k], rtol=2e-5,
+                                       atol=1e-6, err_msg=k)
+        return
+    # bf16: the pool's Out is one rounding of the float32 value, where the
+    # ops apart sum Y after its rounding to bf16. The exact value, from the
+    # fetched x and the float32 parameters:
+    ax = (0, 1, 2) if layout == "NHWC" else (0, 2, 3)
+    hw = (1, 2) if layout == "NHWC" else (2, 3)
+    x = got["x"].astype(np.float64)
+    m, v = x.mean(axis=ax, keepdims=True), x.var(axis=ax, keepdims=True)
+    cshape = m.shape
+    exact = ((x - m) / np.sqrt(v + 1e-5) * before["scale"].reshape(cshape)
+             + before["bias"].reshape(cshape)).mean(axis=hw, keepdims=True)
+    ulp = 2.0 ** (np.floor(np.log2(np.abs(exact))) - 7)
+    assert (np.abs(got["pool"] - exact) <= ulp).all()
+    # everything else, gradients included: no further from the float32
+    # run than the two ops apart are (dx, a difference of terms that
+    # nearly cancel, is 6% of its largest value away in both)
+    f32, _, _, _ = _run_bn_pool(monkeypatch, True, layout, False)
+    for k in want:
+        scale = np.abs(f32[k]).max()
+        err = np.abs(got[k] - f32[k]).max() / scale
+        err_apart = np.abs(want[k] - f32[k]).max() / scale
+        assert err <= 1.25 * err_apart + 1e-3, (k, err, err_apart)
+
+
+def _lowered_chipbench_step(monkeypatch, config, fuse=True):
+    """StableHLO text of the one-step training program of a chipbench
+    configuration (64 px, batch 2, bf16 AMP, CPU lowering), and how many
+    batch_norm + pool2d pairs run_ops lowered together while tracing
+    (forward, backward)."""
+    import importlib
+    import json
+    import os
+    import sys
+
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu import amp
+    from paddle_tpu.core import executor_core
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from chipbench import programs
+
+    with open(os.path.join(repo, "chipbench", "configs",
+                           config + ".json")) as f:
+        cfg = dict(json.load(f), image_size=64)
+    built = importlib.import_module("chipbench.configs." + config).build(
+        fluid, cfg, 7)
+    prog, gb = built["prog"], built["prog"].global_block()
+    with _pair_lowering(monkeypatch, fuse) as lowered:
+        names = sorted(n for n, v in gb.vars.items() if v.persistable)
+        touched = {n for op in gb.ops
+                   for n in op.input_arg_names() + op.output_arg_names()}
+        wrote = {n for op in gb.ops for n in op.output_arg_names()}
+        state = {n: jax.ShapeDtypeStruct(tuple(gb.vars[n].shape),
+                                         np.dtype(gb.vars[n].dtype))
+                 for n in names if n in touched}
+        mut = {n: s for n, s in state.items() if n in wrote}
+        const = {n: s for n, s in state.items() if n not in wrote}
+        feeds = {"data_u8": jax.ShapeDtypeStruct(
+                     (2, *programs.image_shape(cfg)), np.uint8),
+                 "label": jax.ShapeDtypeStruct((2, 1), np.int32)}
+        step = executor_core.build_step_fn(prog, [built["loss"].name],
+                                           sorted(mut))
+        amp.enable(cfg["amp"])
+        try:
+            text = jax.jit(step).lower(
+                mut, const, feeds,
+                jax.ShapeDtypeStruct((2,), np.uint32)).as_text()
+        finally:
+            amp.disable()
+    return text, tuple(lowered), prog
+
+
+def test_bn_pool_peephole_inert_on_resnet50(monkeypatch):
+    """resnet50's one global pool follows a ReLU of an add: no pair, and
+    the lowered step is the text it is with the peephole forced off."""
+    from paddle_tpu.ops import bn_pool
+
+    text, lowered, prog = _lowered_chipbench_step(monkeypatch, "resnet50")
+    off, _, _ = _lowered_chipbench_step(monkeypatch, "resnet50", fuse=False)
+    assert lowered == (0, 0)
+    assert bn_pool.count(prog) == 0
+    assert text == off
+
+
+def test_bn_pool_peephole_takes_every_se_block(monkeypatch):
+    from paddle_tpu.ops import bn_pool
+
+    _, lowered, prog = _lowered_chipbench_step(monkeypatch, "se_resnext50")
+    assert lowered == (16, 16)  # forward pairs, and their backward
+    assert bn_pool.count(prog) == 16

@@ -184,8 +184,12 @@ def test_submit_before_start_and_after_stop():
 
 
 def test_warmup_precompiles_every_bucket_no_steady_state_misses():
-    flags.set("monitor", True)
-    try:
+    # flag_guard restores the flag as it found it. Setting it False on the
+    # way out failed 7 tests of test_monitor.py whenever xdist's loadfile
+    # schedule put that file behind this one on a worker, as it does since
+    # tests/test_tpu_compile.py joined the suite:
+    # `pytest tests/test_serve.py tests/test_monitor.py` shows it
+    with flags.flag_guard(monitor=True):
         server, *_ = _fc_server(max_batch=4)
         server.start()
         # warmup compiled one executable per bucket
@@ -202,8 +206,6 @@ def test_warmup_precompiles_every_bucket_no_steady_state_misses():
         stats = server.stats()
         assert stats["steady_state_compiles"] == 0
         server.stop()
-    finally:
-        flags.set("monitor", False)
 
 
 def test_concurrent_clients_get_their_own_rows():

@@ -1,0 +1,172 @@
+"""Compiles for a TPU v5e that is described, not attached: what the chip's
+compiler makes of the program's lowering, at real sizes, at no chip time.
+Nothing runs, so nothing here is a result or a time.
+
+Every test that needs the TPU compiler lives in THIS file and reaches it
+through the module-scoped fixture below: only one process may load the
+TPU's library, and under pytest-xdist only the worker that is given this
+file does (/opt/skills/guides/on-chip-measurement, section 2).
+"""
+
+import os
+import re
+
+import numpy as np
+import pytest
+
+
+# ---------------------------------------------------------------- HLO text
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+          "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+          "f64": 8}
+_ARRAY = re.compile(r"\b(%s)\[([0-9,]*)\]" % "|".join(_BYTES))
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w\-]*)\((.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$")
+
+
+def shape_bytes(shape):
+    """Bytes of every array in a shape string (a tuple's are summed)."""
+    return sum(int(np.prod([int(d) for d in dims.split(",") if d]))
+               * _BYTES[dt] for dt, dims in _ARRAY.findall(shape))
+
+
+def fusions(hlo_text):
+    """The fusion instructions of `compiled.as_text()` that run as device
+    operations (those nested inside another fusion's computation are part
+    of it): name, kind, result shape (layouts dropped), bytes written (the
+    result) and read (the operands, looked up where they are defined)."""
+    shapes, out, inside, fused = {}, [], None, set()
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, opcode, rest = m.groups()
+        shapes[name] = shape
+        if opcode == "fusion":
+            kind = re.search(r"kind=(\w+)", rest)
+            fused.add(re.search(r"calls=%([^\s,]+)", rest).group(1))
+            out.append(dict(
+                name=name, kind=kind.group(1) if kind else None,
+                shape=re.sub(r"\{[^}]*\}", "", shape), inside=inside,
+                write=shape_bytes(shape),
+                operands=re.findall(r"%([^\s,()]+)", rest.split(")", 1)[0])))
+    out = [f for f in out if f["inside"] not in fused]
+    for f in out:
+        f["read"] = sum(shape_bytes(shapes.get(o, "")) for o in f["operands"])
+    return out
+
+
+def reduction_passes(hlo_text, min_read=20e6, max_write=2e6):
+    """Standalone reduction passes: loop fusions that read a whole
+    activation (more than `min_read` bytes) to write only a reduction of
+    it (less than `max_write`). PERF.md section 6 (PR 25) counts them: 59
+    in the se_resnext50 step before the squeeze rode the batch-norm sums,
+    43 after, 1 in resnet50's."""
+    return [f for f in fusions(hlo_text) if f["kind"] == "kLoop"
+            and f["read"] > min_read and f["write"] < max_write]
+
+
+# ---------------------------------------------------------------- fixtures
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to JAX's persistent cache
+    but cannot be read back without the chip: keep it out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# ------------------------------------------------------------------- tests
+def _se_bottleneck_step_hlo(one_chip):
+    """One stage-1 SE-ResNeXt bottleneck (128 x 256 x 56 x 56 in and out,
+    cardinality 32, SE reduction 16), forward + backward + SGD under bf16
+    AMP, as the Executor lowers it, compiled for one v5e chip."""
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu import amp
+    from paddle_tpu.core import executor_core
+    from paddle_tpu.models.se_resnext import bottleneck_block
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[256, 56, 56],
+                              dtype="float32")
+        x.stop_gradient = False     # a block inside a network: dx as well
+        out = bottleneck_block(x, 128, 1, 32, 16)
+        loss = fluid.layers.mean(out)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    gb = main.global_block()
+    wrote = {n for op in gb.ops for n in op.output_arg_names()}
+    read = {n for op in gb.ops for n in op.input_arg_names()}
+    state = {n: jax.ShapeDtypeStruct(tuple(v.shape), np.dtype(v.dtype),
+                                     sharding=one_chip)
+             for n, v in gb.vars.items()
+             if v.persistable and n in wrote | read}
+    mut = {n: s for n, s in state.items() if n in wrote}
+    const = {n: s for n, s in state.items() if n not in wrote}
+    feeds = {"x": jax.ShapeDtypeStruct((128, 256, 56, 56), np.float32,
+                                       sharding=one_chip)}
+    rng = jax.ShapeDtypeStruct((2,), np.uint32, sharding=one_chip)
+    step = executor_core.build_step_fn(
+        main, [loss.name, x.name + "@GRAD"], sorted(mut))
+    amp.enable("bfloat16")
+    try:
+        return jax.jit(step, donate_argnums=(0,)).lower(
+            mut, const, feeds, rng).compile().as_text()
+    finally:
+        amp.disable()
+
+
+def _squeeze_passes(hlo_text):
+    return [f for f in reduction_passes(hlo_text, min_read=50e6)
+            if re.fullmatch(r"(bf16|f32)\[128,256\]", f["shape"])]
+
+
+def test_se_squeeze_has_no_pass_of_its_own(one_chip, no_compile_cache,
+                                           monkeypatch):
+    """The squeeze of an SE block is algebra on the per-sample sums the
+    batch-norm statistics take anyway, and the v5e compiler emits those
+    from the fusion that makes the block's widest tensor: no loop fusion
+    reads >= 50 MB to write a [128, 256] result. With the pair lowered
+    apart there is one (205 MB in), which is what the counter must see."""
+    from paddle_tpu.ops import bn_pool
+
+    together = _se_bottleneck_step_hlo(one_chip)
+    assert _squeeze_passes(together) == []
+    monkeypatch.setattr(bn_pool, "match", lambda ops: {})
+    apart = _se_bottleneck_step_hlo(one_chip)
+    squeeze = _squeeze_passes(apart)
+    assert len(squeeze) == 1 and squeeze[0]["read"] >= 200e6
+    # the backward: sum(dy), sum(dy * x_hat) of the SE batch norm follow
+    # from per-sample sums of d_out, so that pass (411 MB in) goes too;
+    # what stays is the grouped convolution's batch norm and the first's
+    assert len(reduction_passes(apart)) == 4
+    assert len(reduction_passes(together)) == 2

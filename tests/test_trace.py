@@ -787,6 +787,50 @@ def test_record_takes_the_lane_of_the_reporting_worker():
     assert len(ids) == 4 and all(len(i) == 16 for i in ids)
 
 
+@pytest.mark.parametrize("runner", ["executor", "executor_scan",
+                                    "parallel_executor"])
+def test_step_span_and_counter_carry_fused_bn_global_pool(runner):
+    """A program with one SE-style squeeze: every step span says 1 pair
+    was lowered together, the registry counts it once per program
+    prepared, and the startup program's spans say 0."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[3, 4, 4], dtype="float32")
+        y = fluid.layers.batch_norm(
+            fluid.layers.conv2d(x, num_filters=4, filter_size=1))
+        gate = fluid.layers.fc(fluid.layers.pool2d(
+            y, pool_type="avg", global_pooling=True), size=4, act="sigmoid")
+        loss = fluid.layers.mean(
+            fluid.layers.elementwise_mul(y, gate, axis=0))
+        fluid.optimizer.SGD(learning_rate=0.01).minimize(loss)
+    iters = 2 if runner == "executor_scan" else None
+    feed = {"x": np.ones(((8, 3, 4, 4) if iters is None
+                          else (iters, 8, 3, 4, 4)), np.float32)}
+    kind = "parallel_executor" if runner == "parallel_executor" \
+        else "executor"
+    scope = fluid.Scope()
+    with _traced(), fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        startup_step, = [s for s in trace.snapshot()[0]
+                         if s["name"] == "executor.step"]
+        trace.reset()
+        if kind == "parallel_executor":
+            pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                        main_program=main)
+            for _ in range(3):
+                pe.run([loss.name], feed=feed)
+        else:
+            exe = fluid.Executor(fluid.CPUPlace())
+            for _ in range(3):
+                exe.run(main, feed=feed, fetch_list=[loss], iters=iters)
+    assert startup_step["attrs"]["fused_bn_global_pool"] == 0
+    steps = [s for s in trace.snapshot()[0] if s["name"] == kind + ".step"]
+    assert [s["attrs"]["fused_bn_global_pool"] for s in steps] == [1, 1, 1]
+    prepared = sum(s["attrs"]["cache"] == "miss" for s in steps)
+    assert monitor.registry().counter(
+        "fused_bn_global_pool", cache=kind).value == prepared
+
+
 @pytest.mark.parametrize("backend", ["tpu", "cpu"])
 def test_start_profiler_leaves_jax_host_tracer_off(tmp_path, monkeypatch,
                                                    backend):
