@@ -47,7 +47,19 @@ WHITE_LIST = frozenset({
     "elementwise_add", "elementwise_sub", "elementwise_mul",
     "lstm", "gru", "lstm_unit", "gru_unit", "sequence_conv", "row_conv",
     "attention_lstm_decoder", "im2sequence",
+    # the flash kernel and the grouped expert products take bf16 operands
+    # and accumulate in float32; rms_norm and rotary_embedding stay neutral
+    # (dtype-preserving, float32 inside, like layer_norm)
+    "causal_attention", "moe_ffn",
 })
+
+# Input slots of a white-list op that are NOT cast down: moe_ffn's router
+# weight (router matmul, softmax and top-k run in float32: a bf16 logit
+# flips the discrete choice between near-tied experts) and the incoming
+# gradients of its two float32 scalar losses.
+FLOAT32_SLOTS = {
+    "moe_ffn": frozenset({"Router", "AuxLoss@GRAD", "ZLoss@GRAD"}),
+}
 
 # Ops whose bf16 inputs are cast UP to float32 (numerics-sensitive math,
 # gradient accumulation, every optimizer/state update, metrics).
@@ -180,10 +192,12 @@ def apply_policy(op_type, ins):
         target, only_from = "float32", ("bfloat16", "float16")
     else:
         return ins
+    keep = FLOAT32_SLOTS.get(base, ()) if target != "float32" else ()
     changed = False
     new_ins = {}
     for slot, vals in ins.items():
-        nv = [_cast_value(v, target, only_from) for v in vals]
+        nv = vals if slot in keep else [
+            _cast_value(v, target, only_from) for v in vals]
         changed = changed or any(a is not b for a, b in zip(nv, vals))
         new_ins[slot] = nv
     return new_ins if changed else ins

@@ -4,7 +4,7 @@ GradientClipByGlobalNorm, set_gradient_clip, append_gradient_clip_ops)."""
 
 import copy
 
-from .core.framework import default_main_program
+from .core.framework import default_main_program, op_scope
 
 __all__ = [
     "ErrorClipByValue",
@@ -170,11 +170,13 @@ def set_gradient_clip(clip, param_list=None, program=None):
 def append_gradient_clip_ops(param_grad):
     context = {}
     clips = []
-    for p, g in param_grad:
-        clip_attr = getattr(p, "gradient_clip_attr", None) or NullGradientClipAttr()
-        clips.append(clip_attr)
-        clip_attr._process_context(context, p, g)
     res = []
-    for clip_attr, (p, g) in zip(clips, param_grad):
-        res.append(clip_attr._create_operators(p, g))
+    # the ops carry `op_namescope`, so a device trace names the clip's share
+    with op_scope("gradient_clip"):
+        for p, g in param_grad:
+            clip_attr = getattr(p, "gradient_clip_attr", None) or NullGradientClipAttr()
+            clips.append(clip_attr)
+            clip_attr._process_context(context, p, g)
+        for clip_attr, (p, g) in zip(clips, param_grad):
+            res.append(clip_attr._create_operators(p, g))
     return res

@@ -13,6 +13,8 @@ An eager interpret mode (`run_ops_eager`) remains for host-side programs
 (save/load/print/readers) — the analogue of the reference's op-by-op path.
 """
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,8 +22,9 @@ import numpy as np
 from . import registry
 from .registry import SeqTensor
 from . import dtypes
+from .framework import OP_NAMESCOPE_ATTR_NAME
 from .. import flags
-from ..ops import bn_pool
+from ..ops import bn_pool, lm_ops
 
 
 def check_values_finite(named_values, context=""):
@@ -231,6 +234,27 @@ def run_ops(ops, env, ctx):
     return env
 
 
+def _device_scope(op, ctx):
+    """An op appended under `fluid.name_scope` / `framework.op_scope` is
+    lowered under jax.named_scope(`<scopes>/<op type>`), so the operations
+    of a device trace carry the Fluid op they came from, forward and
+    backward (`<type>_grad`). Named scopes are metadata: the lowered
+    StableHLO is the same text."""
+    scopes = op.attrs.get(OP_NAMESCOPE_ATTR_NAME)
+    if ctx.eager or not scopes:
+        return contextlib.nullcontext()
+    return jax.named_scope(f"{scopes}/{op.type}")
+
+
+def lowered_counts(program, device):
+    """{counter: n} of what `run_ops` lowers specially in a step of
+    `program` compiled for `device`, for the step spans and the registry
+    (monitor.StepRecord.mark_cache): `fused_bn_global_pool` always, the
+    language-model lowerings where the program has such ops."""
+    return {"fused_bn_global_pool": bn_pool.count(program),
+            **lm_ops.lowered_counts(program, device)}
+
+
 def _run_one_op(op, env, ctx, attrs=None):
     attrs = op.attrs if attrs is None else attrs
     op_def = registry.lookup(op.type)
@@ -254,7 +278,8 @@ def _run_one_op(op, env, ctx, attrs=None):
             with profiler.record_event(f"op::{op.type}"):
                 outs = registry.run_kernel(op_def, ctx, ins, attrs) or {}
         else:
-            outs = registry.run_kernel(op_def, ctx, ins, attrs) or {}
+            with _device_scope(op, ctx):
+                outs = registry.run_kernel(op_def, ctx, ins, attrs) or {}
     except TraceUnsupported:
         raise
     except Exception as e:

@@ -49,6 +49,11 @@ class OpRole:
 
 OP_ROLE_ATTR_NAME = "op_role"
 OP_ROLE_VAR_ATTR_NAME = "op_role_var"
+# reference op_proto_maker.h OpNamescopeAttrName: the `name_scope`s an op was
+# appended under; a traced step emits the op's lowering under
+# jax.named_scope of it, so device traces carry it (executor_core)
+OP_NAMESCOPE_ATTR_NAME = "op_namescope"
+_name_scopes = []
 
 
 class VarType:
@@ -204,6 +209,9 @@ class Operator:
         self.attrs = dict(attrs or {})
         prog = block.program
         self.attrs.setdefault(OP_ROLE_ATTR_NAME, prog._op_role)
+        if _name_scopes:
+            self.attrs.setdefault(OP_NAMESCOPE_ATTR_NAME,
+                                  "/".join(_name_scopes))
         if prog._op_role_var:
             self.attrs.setdefault(OP_ROLE_VAR_ATTR_NAME, list(prog._op_role_var))
 
@@ -669,8 +677,21 @@ def program_guard(main_program, startup_program=None):
 
 
 @contextlib.contextmanager
+def op_scope(name):
+    """Ops appended inside (and the gradient ops backward.py derives from
+    them) carry `name` in their `op_namescope` attr."""
+    _name_scopes.append(name)
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
+
+
+@contextlib.contextmanager
 def name_scope(prefix):
-    with unique_name.guard_prefix(prefix):
+    """Names made inside get the prefix and ops appended inside the
+    `op_namescope` (reference framework.py name_scope)."""
+    with op_scope(prefix), unique_name.guard_prefix(prefix):
         yield
 
 

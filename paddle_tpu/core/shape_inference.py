@@ -758,6 +758,79 @@ def _layer_norm(ctx):
         ctx.set_output_dim("Variance", (left,))
 
 
+@register_infer_shape("rms_norm")
+def _rms_norm(ctx):
+    x = ctx.input_dim("X")
+    scale = ctx.input_dim("Scale")
+    if x is None:
+        return
+    if scale is not None:
+        ctx.enforce(len(scale) == 1 and _dim_match(scale[0], x[-1]),
+                    f"Scale{scale} must be [{x[-1]}], X's last dim")
+    ctx.set_output_dim("Y", x)
+
+
+@register_infer_shape("rotary_embedding")
+def _rotary_embedding(ctx):
+    x = ctx.input_dim("X")
+    if x is None:
+        return
+    ctx.enforce(len(x) == 4, f"X must be [B, S, H, D], got {x}")
+    ctx.enforce(x[-1] == -1 or x[-1] % 2 == 0,
+                f"head size {x[-1]} must be even (two rotated halves)")
+    ctx.set_output_dim("Out", x)
+
+
+@register_infer_shape("causal_attention")
+def _causal_attention(ctx):
+    q, k, v = (ctx.input_dim(s) for s in ("Q", "K", "V"))
+    if q is None:
+        return
+    ctx.enforce(len(q) == 4, f"Q must be [B, S, H, D], got {q}")
+    for name, other in (("K", k), ("V", v)):
+        if other is not None:
+            ctx.enforce(_shapes_match(q, other),
+                        f"{name}{other} must match Q{q} (no grouped KV)")
+    ctx.set_output_dim("Out", q)
+    ctx.set_output_dim("Lse", (q[0], q[2], q[1]))
+
+
+@register_infer_shape("causal_attention_grad")
+def _causal_attention_grad(ctx):
+    g = ctx.input_dim("Out@GRAD")
+    for slot in ("Q", "K", "V"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            if g is not None:
+                ctx.enforce(_shapes_match(d, g),
+                            f"Out@GRAD{g} must match {slot}{d}")
+            ctx.set_output_dim(slot + "@GRAD", d)
+
+
+@register_infer_shape("moe_ffn")
+def _moe_ffn(ctx):
+    x, r = ctx.input_dim("X"), ctx.input_dim("Router")
+    g, u, d = (ctx.input_dim(s) for s in ("Gate", "Up", "Down"))
+    if x is None or r is None:
+        return
+    ctx.enforce(len(x) == 2, f"X must be [T, H], got {x}")
+    ctx.enforce(len(r) == 2 and _dim_match(r[0], x[1]),
+                f"Router{r} must be [H={x[1]}, E]")
+    k = ctx.attr("top_k", 1)
+    ctx.enforce(0 < k <= r[1], f"top_k {k} out of range for {r[1]} experts")
+    if g is not None and u is not None and d is not None:
+        ctx.enforce(len(g) == 3 and g[0] == r[1] and _dim_match(g[1], x[1]),
+                    f"Gate{g} must be [E={r[1]}, H={x[1]}, F]")
+        ctx.enforce(tuple(u) == tuple(g), f"Up{u} must match Gate{g}")
+        ctx.enforce(tuple(d) == (g[0], g[2], g[1]),
+                    f"Down{d} must be [E, F, H] of Gate{g}")
+    ctx.set_output_dim("Out", x)
+    ctx.set_output_dim("AuxLoss", (1,))
+    ctx.set_output_dim("ZLoss", (1,))
+    ctx.set_output_dim("ExpertIds", (x[0], k))
+    ctx.set_output_dim("TokensPerExpert", (r[1],))
+
+
 @register_infer_shape("norm")
 def _norm(ctx):
     x = ctx.input_dim("X")

@@ -24,7 +24,6 @@ from .core.lod_tensor import LoDTensor
 from .core.places import CPUPlace, TPUPlace, jax_device_for
 from .core.scope import global_scope, Scope
 from .core.registry import SeqTensor
-from .ops import bn_pool
 from . import health as _health
 from .resilience import chaos as _chaos
 from .resilience import watchdog as _watchdog
@@ -573,7 +572,8 @@ class Executor:
                 mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level,
-                           fused_bn_global_pool=bn_pool.count(program))
+                           lowered=executor_core.lowered_counts(
+                               program, jax_device_for(self.place)))
         compiled, state_names, state_out_names = entry
 
         mut_state = {}
@@ -779,7 +779,8 @@ class Executor:
                 mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level,
-                           fused_bn_global_pool=bn_pool.count(program))
+                           lowered=executor_core.lowered_counts(
+                               program, jax_device_for(self.place)))
         compiled, state_names, state_out_names, plan, unpackers, memo = entry
 
         if plan is not None:

@@ -455,6 +455,8 @@ def _fuse_optimizers(clone, bucket_bytes):
                      if not k.startswith("op_")}
             attrs["shard_rows"] = int(rows)
             attrs["op_role"] = first.attrs.get("op_role", 0)
+            if "op_namescope" in first.attrs:
+                attrs["op_namescope"] = first.attrs["op_namescope"]
             role_vars = []
             for m in bucket:
                 role_vars.extend(m["op"].attrs.get("op_role_var", []))

@@ -18,7 +18,8 @@ __all__ = [
     "accuracy", "auc", "chunk_eval", "sequence_conv", "conv2d", "conv3d",
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
-    "layer_norm", "beam_search_decode", "conv2d_transpose", "sequence_expand",
+    "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
+    "moe_ffn", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_first_step", "sequence_last_step", "dropout",
     "l2_normalize", "matmul", "topk", "warpctc", "sequence_reshape",
@@ -631,6 +632,73 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-05,
         {"epsilon": epsilon, "begin_norm_axis": begin_norm_axis},
     )
     return helper.append_activation(layer_norm_out)
+
+
+def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
+    """Root-mean-square norm over the last axis with a learned scale and no
+    bias (Zhang & Sennrich, arXiv:1910.07467), statistics in float32."""
+    helper = LayerHelper("rms_norm", **locals())
+    dtype = helper.input_dtype()
+    scale_p = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        default_initializer=Constant(1.0))
+    y = helper.create_tmp_variable(dtype, shape=input.shape)
+    helper.append_op("rms_norm", {"X": [input], "Scale": [scale_p]},
+                     {"Y": [y]}, {"epsilon": epsilon})
+    return y
+
+
+def rotary_embedding(input, theta=10000.0, name=None):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) of
+    [B, S, H, D] in the `rotate_half` convention; position = index in S."""
+    helper = LayerHelper("rotary_embedding", **locals())
+    y = helper.create_tmp_variable(input.dtype, shape=input.shape)
+    helper.append_op("rotary_embedding", {"X": [input]}, {"Out": [y]},
+                     {"theta": float(theta)})
+    return y
+
+
+def causal_attention(q, k, v, name=None):
+    """softmax(Q K^T / sqrt(D) + causal mask) V on [B, S, H, D]; on a TPU
+    place the flash kernel of parallel/flash.py (no [S, S] scores in HBM)."""
+    helper = LayerHelper("causal_attention", **locals())
+    y = helper.create_tmp_variable(q.dtype, shape=q.shape)
+    lse = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op("causal_attention", {"Q": [q], "K": [k], "V": [v]},
+                     {"Out": [y], "Lse": [lse]})
+    return y
+
+
+def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
+            gate_attr=None, up_attr=None, down_attr=None, name=None):
+    """A layer of `num_experts` SwiGLU experts of width `expert_size` on
+    tokens [T, H], each token through its `top_k` by router probability
+    (not renormalised), grouped matmuls over the rows really routed.
+    Returns (out, load-balance loss [1], router z-loss [1],
+    expert ids [T, top_k], tokens per expert [num_experts])."""
+    helper = LayerHelper("moe_ffn", **locals())
+    dtype = helper.input_dtype()
+    hidden = int(input.shape[-1])
+    router, gate, up, down = (
+        helper.create_parameter(attr=ParamAttr.to_attr(a), shape=shape,
+                                dtype=dtype)
+        for a, shape in ((router_attr, [hidden, num_experts]),
+                         (gate_attr, [num_experts, hidden, expert_size]),
+                         (up_attr, [num_experts, hidden, expert_size]),
+                         (down_attr, [num_experts, expert_size, hidden])))
+    y = helper.create_tmp_variable(dtype, shape=input.shape)
+    aux = helper.create_tmp_variable("float32", shape=(1,))
+    z = helper.create_tmp_variable("float32", shape=(1,))
+    ids = helper.create_tmp_variable("int32", stop_gradient=True)
+    load = helper.create_tmp_variable("int32", stop_gradient=True)
+    helper.append_op(
+        "moe_ffn",
+        {"X": [input], "Router": [router], "Gate": [gate], "Up": [up],
+         "Down": [down]},
+        {"Out": [y], "AuxLoss": [aux], "ZLoss": [z], "ExpertIds": [ids],
+         "TokensPerExpert": [load]},
+        {"top_k": int(top_k)})
+    return y, aux, z, ids, load
 
 
 def conv2d_transpose(input, num_filters, output_size=None, filter_size=None,

@@ -2,7 +2,9 @@
 
 Each module exposes get_model(args) -> (avg_cost, inference_program,
 optimizer, train_reader, test_reader, batch_acc). args needs .batch_size and
-.data_set ("cifar10" | "flowers" | ...).
+.data_set ("cifar10" | "flowers" | ...). `olmoe` (a decoder language model
+with sparse experts) is built from a configuration instead: `olmoe.olmoe(tokens,
+cfg)`, `olmoe.olmoe_loss`, `olmoe.optimizer`.
 """
 
 from . import mnist
@@ -11,9 +13,10 @@ from . import vgg
 from . import se_resnext
 from . import stacked_dynamic_lstm
 from . import machine_translation
+from . import olmoe
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
-           "machine_translation"]
+           "machine_translation", "olmoe"]
 
 
 def get_model(name):

@@ -121,7 +121,7 @@ class StepRecord:
 
     __slots__ = ("kind", "t0", "phases", "cache", "cache_level",
                  "fingerprint", "extra", "intervals", "monitored", "t_lap",
-                 "fused_bn_global_pool")
+                 "lowered")
 
     def __init__(self, kind, monitored=True):
         self.kind = kind
@@ -131,7 +131,7 @@ class StepRecord:
         self.cache = None       # "hit" | "miss"
         self.cache_level = None  # "l1" | "l2" on a hit (l2 = warm start)
         self.fingerprint = None
-        self.fused_bn_global_pool = None  # see mark_cache
+        self.lowered = {}       # see mark_cache
         self.extra = None    # journal-only extras
         self.intervals = []  # (name, t0, t1) per occurrence — the phase
         #                      boundaries step_end replays as trace spans
@@ -147,28 +147,29 @@ class StepRecord:
         self.intervals.append((name, t0, t1))
         return t1 - t0
 
-    def mark_cache(self, hit, fingerprint=None, level=None,
-                   fused_bn_global_pool=None):
+    def mark_cache(self, hit, fingerprint=None, level=None, lowered=None):
         """level: "l1" (in-process) or "l2" (deserialized from the
         persistent store) on a hit. A warm-started process therefore
         reports compile_cache_misses == 0 — the contract bench.py and
         green_gate assert against FLAGS_compile_cache_dir.
-        fused_bn_global_pool: batch_norm + global average pool2d pairs
-        lowered together in this step's program (ops.bn_pool.count). The
-        step span carries the number; the registry counts it once per
+        lowered: {counter: how many ops (or op pairs) of this step's
+        program are lowered through that path}, from
+        `executor_core.lowered_counts`: `fused_bn_global_pool` (always),
+        `moe_ffn_grouped`, `flash_attention` (where there are any). The
+        step span carries the numbers; the registry counts them once per
         program prepared (compiled, or loaded from the persistent store)."""
         self.cache = "hit" if hit else "miss"
         self.cache_level = level if hit else None
         self.fingerprint = fingerprint
-        self.fused_bn_global_pool = fused_bn_global_pool
+        self.lowered = dict(lowered or {})
         if not self.monitored:
             return
-        if fused_bn_global_pool is not None and self.cache_level != "l1":
-            _registry.counter(
-                "fused_bn_global_pool",
-                help="batch_norm + global average pool2d pairs lowered "
-                     "together, over the programs prepared",
-                cache=self.kind).inc(fused_bn_global_pool)
+        if self.cache_level != "l1":
+            for name, n in self.lowered.items():
+                _registry.counter(
+                    name, help=f"ops (or op pairs) lowered through the "
+                               f"{name} path, over the programs prepared",
+                    cache=self.kind).inc(n)
         _registry.counter(
             "compile_cache_hits_total" if hit else
             "compile_cache_misses_total",
@@ -311,8 +312,7 @@ def step_end(rec, iters=None, datapipe=None, replica_ms=None,
             attrs["fingerprint"] = rec.fingerprint
             if rec.cache_level is not None:
                 attrs["cache_level"] = rec.cache_level
-        if rec.fused_bn_global_pool is not None:
-            attrs["fused_bn_global_pool"] = rec.fused_bn_global_pool
+        attrs.update(rec.lowered)
         ctx = tr.record(f"{rec.kind}.step", rec.t0,
                         rec.t0 + total_ms / 1000.0, kind="step",
                         attrs=attrs)

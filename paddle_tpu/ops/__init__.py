@@ -11,6 +11,7 @@ from . import activation_ops
 from . import tensor_ops
 from . import bn_pool
 from . import nn_ops
+from . import lm_ops
 from . import optimizer_ops
 from . import sequence_ops
 from . import loss_ops

@@ -163,6 +163,10 @@ def fused_adam_update_op(ctx, ins, attrs):
         m2o = b2 * m2 + (1 - b2) * jnp.square(gf)
         p_out = (p.astype(jnp.float32)
                  - lr_t * m1o / (jnp.sqrt(m2o) + eps)).astype(p.dtype)
+    wd = attrs.get("weight_decay", 0.0)   # decoupled (AdamW), as `adam`
+    if wd:
+        p_out = (p_out.astype(jnp.float32)
+                 - (lr * wd) * p.astype(jnp.float32)).astype(p.dtype)
     return out(ParamOut=_unpack(p_out, ps, rows),
                Moment1Out=_unpack(m1o, m1s, rows),
                Moment2Out=_unpack(m2o, m2s, rows))

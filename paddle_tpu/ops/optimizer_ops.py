@@ -60,6 +60,11 @@ def adam_op(ctx, ins, attrs):
     m2o = b2 * m2 + (1 - b2) * jnp.square(gf)
     lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
     p_out = p.astype(jnp.float32) - lr_t * m1o / (jnp.sqrt(m2o) + eps)
+    # AdamW (Loshchilov & Hutter, arXiv:1711.05101): the decay acts on the
+    # parameter itself, scaled by the learning rate, outside the moments
+    wd = attrs.get("weight_decay", 0.0)
+    if wd:
+        p_out = p_out - (lr * wd) * p.astype(jnp.float32)
     return out(ParamOut=p_out.astype(p.dtype), Moment1Out=m1o, Moment2Out=m2o)
 
 

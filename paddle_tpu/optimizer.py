@@ -15,6 +15,7 @@ from .core.framework import (
     Variable,
     default_main_program,
     default_startup_program,
+    op_scope,
 )
 from .backward import append_backward
 from . import unique_name
@@ -143,12 +144,14 @@ class Optimizer:
             block, [p for p, g in parameters_and_grads if p.trainable]
         )
         optimize_ops = []
-        for param_and_grad in parameters_and_grads:
-            if param_and_grad[1] is None or not param_and_grad[0].trainable:
-                continue
-            with program.optimized_guard(param_and_grad):
-                optimize_ops.append(self._append_optimize_op(block, param_and_grad))
-        self._finish_update(block)
+        # the ops carry `op_namescope`, so a device trace names the update
+        with op_scope("optimizer"):
+            for param_and_grad in parameters_and_grads:
+                if param_and_grad[1] is None or not param_and_grad[0].trainable:
+                    continue
+                with program.optimized_guard(param_and_grad):
+                    optimize_ops.append(self._append_optimize_op(block, param_and_grad))
+            self._finish_update(block)
         return optimize_ops
 
     def minimize(self, loss, startup_program=None, parameter_list=None, no_grad_set=None):
@@ -272,12 +275,19 @@ class AdamOptimizer(Optimizer):
     _moment1_acc_str = "moment1"
     _moment2_acc_str = "moment2"
 
-    def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8, **kwargs):
+    def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8,
+                 weight_decay=0.0, apply_decay_param_fun=None, **kwargs):
+        """weight_decay > 0 makes the update AdamW's (decoupled decay,
+        `lr * weight_decay * param` beside the Adam step) for the
+        parameters whose name `apply_decay_param_fun` accepts (all, when
+        it is None); the others get plain Adam from the same op."""
         super().__init__(learning_rate, **kwargs)
         self.type = "adam"
         self._beta1 = beta1
         self._beta2 = beta2
         self._epsilon = epsilon
+        self._weight_decay = float(weight_decay)
+        self._apply_decay_param_fun = apply_decay_param_fun
         self._beta1_pow_acc = None
         self._beta2_pow_acc = None
 
@@ -326,8 +336,16 @@ class AdamOptimizer(Optimizer):
                 "Moment1Out": [moment1],
                 "Moment2Out": [moment2],
             },
-            {"beta1": self._beta1, "beta2": self._beta2, "epsilon": self._epsilon},
+            self._adam_attrs(param_and_grad[0]),
         )
+
+    def _adam_attrs(self, param):
+        attrs = {"beta1": self._beta1, "beta2": self._beta2,
+                 "epsilon": self._epsilon}
+        decays = self._apply_decay_param_fun
+        if self._weight_decay and (decays is None or decays(param.name)):
+            attrs["weight_decay"] = self._weight_decay
+        return attrs
 
     def _finish_update(self, block):
         """update beta1/beta2 power accumulators (reference :459-471)."""

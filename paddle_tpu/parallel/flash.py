@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.places import pallas_interpret
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd"]
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -178,12 +178,34 @@ def flash_attention(q, k, v, causal=False, scale=None,
     faster than XLA's fused dense attention there, with O(S * block) memory
     instead of the dense [S, S] score matrix (S >= 16k runs comfortably).
     Blocks auto-shrink for short sequences."""
+    scale, block_q, block_k = _resolve(q, k, scale, block_q, block_k)
+    return _flash(q, k, v, scale, bool(causal), block_q, block_k)
+
+
+def _resolve(q, k, scale, block_q, block_k):
     block_q, block_k = normalize_blocks(block_q, block_k,
                                         q.shape[2], k.shape[2])
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    return _flash(q, k, v, float(scale), bool(causal),
-                  int(block_q), int(block_k))
+    return float(scale), int(block_q), int(block_k)
+
+
+def flash_attention_fwd(q, k, v, causal=False, scale=None,
+                        block_q=256, block_k=256):
+    """The forward kernel alone: (out [B, H, S, D], lse [B, H, S]). For a
+    caller that keeps `lse` itself and calls `flash_attention_bwd` later
+    (an op whose backward is another op), so the kernel runs once."""
+    scale, block_q, block_k = _resolve(q, k, scale, block_q, block_k)
+    return _fwd_padded(q, k, v, scale, bool(causal), block_q, block_k)
+
+
+def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
+                        block_q=256, block_k=256):
+    """(dq, dk, dv) from the saved output and logsumexp of
+    `flash_attention_fwd` with the same arguments."""
+    scale, block_q, block_k = _resolve(q, k, scale, block_q, block_k)
+    return _flash_vjp_bwd(scale, bool(causal), block_q, block_k,
+                          (q, k, v, out, lse), do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -46,7 +46,6 @@ from .core.places import accelerator_devices
 from .core.registry import SeqTensor
 from .core.scope import global_scope
 from .executor import as_numpy, lap_call, _apply_debug_nans
-from .ops import bn_pool
 from . import health as _health
 from .parallel import autoshard as _autoshard
 from .parallel import zero1 as _zero1
@@ -655,7 +654,8 @@ class ParallelExecutor:
                 mon.lap("cache_load" if level == "l2" else "compile")
         if mon is not None:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level,
-                           fused_bn_global_pool=bn_pool.count(program))
+                           lowered=executor_core.lowered_counts(
+                               program, self._devices[0]))
         compiled, state_names, state_out_names = entry
 
         multiproc = any(

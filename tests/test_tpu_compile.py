@@ -170,3 +170,83 @@ def test_se_squeeze_has_no_pass_of_its_own(one_chip, no_compile_cache,
     # what stays is the grouped convolution's batch norm and the first's
     assert len(reduction_passes(apart)) == 4
     assert len(reduction_passes(together)) == 2
+
+
+def _olmoe_step(one_chip, monkeypatch, rows=2):
+    """The one-layer OLMoE training step of the `olmoe_1b_7b` configuration
+    (published widths, `rows` rows of 4096 tokens, bf16 AMP, AdamW, global
+    clip) as the Executor lowers it on a TPU place, compiled for one v5e
+    chip. The place is the CPU here, so the test steers the two questions
+    the lowering asks of it."""
+    import json
+
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu import amp
+    from paddle_tpu.core import executor_core
+    from paddle_tpu.ops import lm_ops
+    from paddle_tpu.parallel import flash
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    import sys
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from chipbench.configs import olmoe_1b_7b as builder
+
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "olmoe_1b_7b.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    built = builder.build(fluid, cfg, 7)
+    gb = built["prog"].global_block()
+    wrote = {n for op in gb.ops for n in op.output_arg_names()}
+    read = {n for op in gb.ops for n in op.input_arg_names()}
+    state = {n: jax.ShapeDtypeStruct(tuple(v.shape), np.dtype(v.dtype),
+                                     sharding=one_chip)
+             for n, v in gb.vars.items()
+             if v.persistable and n in wrote | read}
+    mut = {n: s for n, s in state.items() if n in wrote}
+    const = {n: s for n, s in state.items() if n not in wrote}
+    S = cfg["sequence_length"]
+    feeds = {n: jax.ShapeDtypeStruct((rows, S), np.int32, sharding=one_chip)
+             for n in ("tokens", "labels")}
+    rng = jax.ShapeDtypeStruct((2,), np.uint32, sharding=one_chip)
+    step = executor_core.build_step_fn(
+        built["prog"], [built["loss"].name, built["routing"][0][1].name],
+        sorted(mut))
+    amp.enable("bfloat16")
+    try:
+        return cfg, jax.jit(step, donate_argnums=(0,)).lower(
+            mut, const, feeds, rng).compile()
+    finally:
+        amp.disable()
+
+
+def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
+        one_chip, no_compile_cache, monkeypatch):
+    """At 2 x 4096 tokens the compiled step holds the flash kernel and
+    the grouped products (Mosaic custom calls), no array with two trailing
+    4096 dims (the [S, S] attention scores) and no [T, 64, 1024] (every
+    token through every expert): attention never writes its scores to HBM
+    and the expert products run over the rows routed. It fits the chip."""
+    cfg, compiled = _olmoe_step(one_chip, monkeypatch)
+    text = compiled.as_text()
+    S, E, F = (cfg["sequence_length"], cfg["num_experts"],
+               cfg["intermediate_size"])
+    T = 2 * S
+    shapes = {tuple(int(d) for d in dims.split(",") if d)
+              for _, dims in _ARRAY.findall(text)}
+    scores = [s for s in shapes if len(s) >= 2 and s[-2:] == (S, S)]
+    assert scores == []
+    all_experts = [s for s in shapes
+                   if len(s) >= 3 and s[-3:] in ((T, E, F), (E, T, F))]
+    assert all_experts == []
+    # the routed rows are there: [T * 8, F] and [T * 8, H]
+    k, H = cfg["num_experts_per_tok"], cfg["hidden_size"]
+    assert (T * k, F) in shapes and (T * k, H) in shapes
+    assert text.count('custom_call_target="tpu_custom_call"') >= 10
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 15.5e9, held
