@@ -74,7 +74,10 @@ class Sizes:
     # landed 0.016 and 0.020 below these (PR 21); the bound is 5x that.
     first_losses_ref: tuple = (7.4719, 7.5962)
     first_loss_tol: float = 0.1
-    flash: tuple = ((2, 16, 4096, 128), (2, 16, 1000, 128))
+    # (shape, block): the olmoe_1b_7b cell's attention at the blocks its
+    # op picks (ops/lm_ops.py), and a row that the kernel's default block
+    # does not divide (padded queries and keys, the last key block masked)
+    flash: tuple = (((2, 16, 4096, 128), 1024), ((2, 16, 1000, 128), 256))
     bucket_small: int = 1029
     bucket_large: int = 0     # 0 = the model's parameter count
     fuse_width: int = 256
@@ -87,7 +90,8 @@ FULL = Sizes()
 TINY = Sizes(depth=18, classes=16, image=32, batch=8, k=2, chunks=4,
              distinct=2, serve_requests=8,
              first_losses_ref=(4.1299, 3.4761), first_loss_tol=0.3,
-             flash=((1, 2, 128, 64), (1, 2, 100, 64)), bucket_large=5000,
+             flash=(((1, 2, 128, 64), 64), ((1, 2, 100, 64), 32)),
+             bucket_large=5000,
              fuse_width=16)
 
 
@@ -463,9 +467,11 @@ def phase_serve(fluid, sizes, place, log, workdir, trained):
 # flash_attention: q/k/v and the output are bf16 and the kernel rounds the
 # softmax weights to bf16 before p @ v, so against a float32 reference the
 # forward carries a few roundings of 2^-9 relative to the largest value;
-# the backward is float32 jnp code whose einsums run at the TPU's default
-# matmul precision (bf16 operands). Errors are max |a - ref| over max |ref|;
-# on the v5e they were 0.0024 forward and 0.0063 backward at worst (PR 21)
+# the backward is two more Pallas kernels (dK/dV and dQ) that recompute the
+# scores from the saved logsumexp in float32 and round p and ds to bf16 as
+# they enter the MXU. Errors are max |a - ref| over max |ref|; on the v5e
+# they were 0.0024 forward and 0.0063 backward at worst (PR 21, and again
+# with these kernels, PR 27: 0.0051 at 4096, 0.0063 at the padded 1000)
 # and the bounds are 4x that.
 FLASH_FWD_TOL, FLASH_GRAD_TOL = 1e-2, 2.5e-2
 
@@ -482,7 +488,7 @@ def _dense_attention(q, k, v, causal):
     return (p / jnp.sum(p, axis=-1, keepdims=True)) @ v
 
 
-def _flash_case(shape, want_mosaic):
+def _flash_case(shape, block, want_mosaic):
     import jax
     import jax.numpy as jnp
 
@@ -494,7 +500,8 @@ def _flash_case(shape, want_mosaic):
     ct = jnp.asarray(rs.randn(*shape), jnp.float32)
 
     def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True)
+        out = flash_attention(q, k, v, causal=True, block_q=block,
+                              block_k=block)
         return jnp.sum(out.astype(jnp.float32) * ct), out
 
     def ref_loss(q, k, v):
@@ -508,7 +515,8 @@ def _flash_case(shape, want_mosaic):
 
     flash_vg = jax.jit(jax.value_and_grad(flash_loss, (0, 1, 2),
                                           has_aux=True))
-    mosaic = "tpu_custom_call" in flash_vg.lower(q, k, v).as_text()
+    # the forward, dK/dV and dQ: three Mosaic calls on the chip, none off it
+    calls = flash_vg.lower(q, k, v).as_text().count("@tpu_custom_call")
     (_, out), grads = flash_vg(q, k, v)
     with jax.default_matmul_precision("highest"):
         (_, ref), ref_grads = jax.jit(jax.value_and_grad(
@@ -520,10 +528,11 @@ def _flash_case(shape, want_mosaic):
 
     fwd = err(out, ref)
     bwd = max(err(g, r) for g, r in zip(grads, ref_grads))
-    return {"shape": list(shape), "mosaic": mosaic,
+    return {"shape": list(shape), "block": block, "mosaic": calls > 0,
+            "mosaic_calls": calls,
             "fwd_err": round(fwd, 5), "grad_err": round(bwd, 5),
-            "ok": mosaic == want_mosaic and fwd <= FLASH_FWD_TOL
-            and bwd <= FLASH_GRAD_TOL}
+            "ok": (calls >= 3 if want_mosaic else calls == 0)
+            and fwd <= FLASH_FWD_TOL and bwd <= FLASH_GRAD_TOL}
 
 
 def _bucket_case(n, want_mosaic):
@@ -620,8 +629,8 @@ def phase_kernels(fluid, sizes, place, n_params, want_mosaic):
     t0 = time.time()
     amp.disable()   # the fused-op programs are fp32 end to end
     out = {"flash_attention": [], "buckets": []}
-    for shape in sizes.flash:
-        out["flash_attention"].append(_flash_case(shape, want_mosaic))
+    for shape, block in sizes.flash:
+        out["flash_attention"].append(_flash_case(shape, block, want_mosaic))
         say(f"kernels: flash {out['flash_attention'][-1]}")
     for n in (sizes.bucket_small, sizes.bucket_large or n_params):
         out["buckets"].append(_bucket_case(n, want_mosaic))

@@ -73,14 +73,17 @@ def _plain_causal_attention(q, k, v):
     return o, lse
 
 
-# Blocks of the flash kernel for this op, from a sweep on the v5e at
-# [2, 16, 4096, 128] bf16 causal (PERF.md, PR 26): the forward kernel takes
-# 8.58 ms at the kernel's default (256, 256), 3.81 at (512, 512), 2.29 at
-# (1024, 1024) (a quarter of the grid steps, each still inside VMEM at
-# head sizes up to 128); its scan backward 15.0 ms at 256 keys a step and
-# 16.1 at 1024. Shorter rows shrink the blocks (flash.normalize_blocks).
+# Blocks of the flash kernels for this op, from sweeps on the v5e at
+# [2, 16, 4096, 128] bf16 causal. Forward (PERF.md, PR 26): 8.58 ms at the
+# kernel's default (256, 256), 3.81 at (512, 512), 2.29 at (1024, 1024) (a
+# quarter of the grid steps, each still inside VMEM at head sizes up to
+# 128). Backward, the dK/dV and dQ kernels together (PERF.md, PR 27): 7.73
+# ms at (256, 256), 4.12 at (512, 512), 3.75 at (1024, 1024) (dK/dV 2.11,
+# dQ 1.80), 3.94 at (1024, 512), 4.02 at (512, 1024), 4.14-4.19 with a
+# 2048 side; the lax.scan they replaced took 14.85. Shorter rows shrink
+# the blocks (flash.normalize_blocks).
 FLASH_FWD_BLOCKS = dict(block_q=1024, block_k=1024)
-FLASH_BWD_BLOCKS = dict(block_q=256, block_k=256)
+FLASH_BWD_BLOCKS = dict(block_q=1024, block_k=1024)
 
 
 def _heads_first(ins, *slots):
@@ -125,8 +128,9 @@ def _causal_attention_grad_maker(op, gout, gin):
 
 @register_op("causal_attention_grad")
 def causal_attention_grad_op(ctx, ins, attrs):
-    """On a TPU place the flash kernel's blockwise backward from the saved
-    output and logsumexp; elsewhere the vjp of the plain composition."""
+    """On a TPU place the flash backward kernels (dK/dV and dQ) from the
+    saved output and logsumexp; elsewhere the vjp of the plain
+    composition."""
     q, k, v, o, do = _heads_first(ins, "Q", "K", "V", "Out", "Out@GRAD")
     if on_tpu():
         from ..parallel.flash import flash_attention_bwd
@@ -227,21 +231,23 @@ def moe_ffn_op(ctx, ins, attrs):
 set_stop_gradient_outputs("moe_ffn", ["ExpertIds", "TokensPerExpert"])
 
 
+# (op type, counter, whether the lowering exists on a TPU place only)
+_LOWERED = (("moe_ffn", "moe_ffn_grouped", False),
+            ("causal_attention", "flash_attention", True),
+            ("causal_attention_grad", "flash_attention_bwd", True))
+
+
 def lowered_counts(program, device):
     """{counter: n} for the step spans and the registry: `moe_ffn` ops of
     the program (each lowers through the grouped products) and, on a TPU
     place, its `causal_attention` ops (each lowers through the flash
-    kernel). A program without them reports neither. Kept on the program
+    kernel) and `causal_attention_grad` ops (each through the two backward
+    kernels). A program without them reports none. Kept on the program
     until that is mutated, like `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)
     if memo is None or memo[0] != program._mutation:
         types = [op.type for b in program.blocks for op in b.ops]
         memo = program._lm_lowered = (
-            program._mutation, types.count("moe_ffn"),
-            types.count("causal_attention"))
-    found = {}
-    if memo[1]:
-        found["moe_ffn_grouped"] = memo[1]
-    if memo[2] and device.platform == "tpu":
-        found["flash_attention"] = memo[2]
-    return found
+            program._mutation, [types.count(t) for t, _, _ in _LOWERED])
+    return {name: n for n, (_, name, tpu_only) in zip(memo[1], _LOWERED)
+            if n and (device.platform == "tpu" or not tpu_only)}

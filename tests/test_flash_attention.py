@@ -40,28 +40,87 @@ def test_flash_matches_dense(causal, S):
                                atol=2e-5, rtol=1e-4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradients_match_dense(causal):
-    rng = np.random.RandomState(1)
-    B, H, S, D = 1, 2, 96, 16
-    q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
-    k = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
-    cot = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
+def _grads(fn, q, k, v, cot):
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * cot),
+                    argnums=(0, 1, 2))(q, k, v)
 
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       block_q=32, block_k=32) * cot)
 
-    def loss_dense(q, k, v):
-        return jnp.sum(_dense(q, k, v, causal) * cot)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+def _assert_grads_match_dense(q, k, v, cot, causal, blocks=(32, 32)):
+    gf = _grads(lambda *a: flash_attention(*a, causal=causal,
+                                           block_q=blocks[0],
+                                           block_k=blocks[1]),
+                q, k, v, cot)
+    gd = _grads(lambda *a: _dense(*a, causal), q, k, v, cot)
     for a, b, name in zip(gf, gd, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=1e-3,
                                    err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("S", [96, 100])  # 100: padded queries and keys
+def test_flash_gradients_match_dense(causal, S):
+    rng = np.random.RandomState(1)
+    B, H, D = 1, 2, 16
+    q, k, v, cot = (jnp.asarray(rng.randn(B, H, S, D).astype(np.float32))
+                    for _ in range(4))
+    _assert_grads_match_dense(q, k, v, cot, causal)
+
+
+def test_flash_gradients_cross_attention_lengths():
+    """Sq != Sk, neither a block multiple: the last key block is masked
+    in the kernels, the padded query rows add nothing."""
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(1, 2, 40, 16).astype(np.float32))
+    k = jnp.asarray(rng.randn(1, 2, 72, 16).astype(np.float32))
+    v = jnp.asarray(rng.randn(1, 2, 72, 16).astype(np.float32))
+    cot = jnp.asarray(rng.randn(1, 2, 40, 16).astype(np.float32))
+    _assert_grads_match_dense(q, k, v, cot, causal=False)
+
+
+@pytest.mark.parametrize("blocks", [(32, 64), (64, 32)])
+def test_flash_gradients_unequal_blocks(blocks):
+    """block_q != block_k: the causal frontier crosses blocks off their
+    corners, which the skipped steps and their clamped index maps have to
+    follow."""
+    rng = np.random.RandomState(6)
+    q, k, v, cot = (jnp.asarray(rng.randn(1, 2, 192, 16).astype(np.float32))
+                    for _ in range(4))
+    _assert_grads_match_dense(q, k, v, cot, causal=True, blocks=blocks)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_gradients_bf16(causal):
+    """bf16 operands, float32 softmax and accumulators inside the kernels:
+    against the float32 dense gradients at bf16 tolerance."""
+    rng = np.random.RandomState(8)
+    shape = (1, 2, 96, 32)
+    q, k, v, cot = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                    for _ in range(4))
+    gf = _grads(lambda *a: flash_attention(*a, causal=causal, block_q=32,
+                                           block_k=32).astype(jnp.float32),
+                q, k, v, cot.astype(jnp.float32))
+    gd = _grads(lambda *a: _dense(*a, causal),
+                *(t.astype(jnp.float32) for t in (q, k, v, cot)))
+    for a, b, name in zip(gf, gd, "qkv"):
+        assert a.dtype == jnp.bfloat16, name
+        b = np.asarray(b)
+        worst = np.max(np.abs(np.asarray(a, np.float32) - b)) / np.max(
+            np.abs(b))
+        assert worst <= 2.5e-2, (name, worst)
+
+
+def test_flash_backward_is_pallas_not_scan():
+    """The backward is the two kernels (dK/dV, dQ) beside the forward's
+    call, and no `scan` walks key blocks through HBM."""
+    q = jnp.zeros((1, 2, 96, 16), jnp.float32)
+
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True,
+                                                block_q=32, block_k=32)),
+        argnums=(0, 1, 2)))(q, q, q))
+    assert text.count("pallas_call") >= 3
+    assert "scan" not in text and "while" not in text
 
 
 def test_flash_bf16():
@@ -117,3 +176,4 @@ def test_ring_flash_matches_dense(causal):
     want = _dense(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=1e-4)
+

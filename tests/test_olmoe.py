@@ -143,12 +143,38 @@ def test_every_first_adamw_update(small):
                    for n in small["names"]) > 1e-3, key
 
 
+def test_lowered_counts_by_place():
+    """The training program holds one `causal_attention` and its grad op
+    a layer: on a TPU place both count as lowered through the flash
+    kernels, on any other place neither does; the inference clone has no
+    backward to count."""
+    from types import SimpleNamespace
+
+    from chipbench.configs import olmoe_1b_7b as builder
+    from paddle_tpu.ops import lm_ops
+
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "olmoe_1b_7b.json")) as f:
+        cfg = dict(json.load(f), **SMALL)
+    built = builder.build(fluid, cfg, 11)
+    tpu, cpu = SimpleNamespace(platform="tpu"), SimpleNamespace(
+        platform="cpu")
+    layers = cfg["num_hidden_layers"]
+    assert lm_ops.lowered_counts(built["prog"], tpu) == {
+        "moe_ffn_grouped": layers, "flash_attention": layers,
+        "flash_attention_bwd": layers}
+    assert lm_ops.lowered_counts(built["prog"], cpu) == {
+        "moe_ffn_grouped": layers}
+    assert lm_ops.lowered_counts(built["test_prog"], tpu) == {
+        "moe_ffn_grouped": layers, "flash_attention": layers}
+
+
 def test_scan_of_k_steps_runs_and_counts_its_lowerings():
     """`Executor.run(iters=K)` on stacked token feeds, under bf16 AMP: the
     losses are finite and fall on a repeated batch, every token is routed
     in the last step, and the step span and the registry carry
-    `moe_ffn_grouped` (one per layer) and, off a TPU place, no
-    `flash_attention`."""
+    `moe_ffn_grouped` (one per layer) and, off a TPU place, neither
+    `flash_attention` nor `flash_attention_bwd`."""
     from chipbench.configs import olmoe_1b_7b as builder
     from paddle_tpu import amp, flags, trace
 
@@ -182,4 +208,5 @@ def test_scan_of_k_steps_runs_and_counts_its_lowerings():
              if s["name"] == "executor.step" and s["attrs"].get("iters")]
     assert steps and steps[-1]["attrs"]["moe_ffn_grouped"] == 2
     assert "flash_attention" not in steps[-1]["attrs"]
+    assert "flash_attention_bwd" not in steps[-1]["attrs"]
     assert counted == 2            # once, for the one program prepared

@@ -182,8 +182,8 @@ def test_causal_attention_is_causal():
 
 
 def test_causal_attention_kernel_path(monkeypatch):
-    """The lowering a TPU place takes (the Pallas flash kernel, here in
-    interpret mode, with its blockwise backward) against the plain
+    """The lowering a TPU place takes (the Pallas flash kernel and its two
+    backward kernels, here in interpret mode) against the plain
     composition every other place takes: same op, same contract."""
     from paddle_tpu.ops import lm_ops
 

@@ -172,6 +172,46 @@ def test_se_squeeze_has_no_pass_of_its_own(one_chip, no_compile_cache,
     assert len(reduction_passes(together)) == 2
 
 
+@pytest.mark.parametrize("q_shape,k_shape,dtype,causal,blocks", [
+    ((2, 16, 4096, 128), (2, 16, 4096, 128), "bfloat16", True, (1024, 1024)),
+    ((2, 16, 4096, 128), (2, 16, 4096, 128), "bfloat16", True, (512, 1024)),
+    ((2, 16, 1000, 128), (2, 16, 1000, 128), "bfloat16", True, (256, 256)),
+    ((1, 2, 300, 64), (1, 2, 520, 64), "float32", False, (128, 256)),
+], ids=["cell", "unequal_blocks", "padded", "cross_length"])
+def test_flash_backward_compiles_for_v5e(one_chip, no_compile_cache,
+                                         monkeypatch, q_shape, k_shape,
+                                         dtype, causal, blocks):
+    """Mosaic takes the two backward kernels at the `olmoe_1b_7b` cell's
+    shape and blocks, with blocks the diagonal crosses off their corners,
+    with padded rows and a masked last key block, and at unequal lengths:
+    two custom calls, no loop around them, and nothing of the size of a
+    score block among the temporaries."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import flash
+
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def bwd(q, k, v, o, lse, do):
+        return flash.flash_attention_bwd(q, k, v, o, lse, do, causal=causal,
+                                         block_q=blocks[0], block_k=blocks[1])
+
+    compiled = jax.jit(bwd).lower(
+        sds(q_shape, dtype), sds(k_shape, dtype), sds(k_shape, dtype),
+        sds(q_shape, dtype), sds(q_shape[:3], "float32"),
+        sds(q_shape, dtype)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not any(m.group(3) == "while"
+                   for m in map(_INSTR.match, text.splitlines()) if m)
+    B, H, Sq, D = q_shape
+    operands = 4 * B * H * max(Sq, k_shape[2]) * D * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < operands
+
+
 def _olmoe_step(one_chip, monkeypatch, rows=2):
     """The one-layer OLMoE training step of the `olmoe_1b_7b` configuration
     (published widths, `rows` rows of 4096 tokens, bf16 AMP, AdamW, global
@@ -225,27 +265,38 @@ def _olmoe_step(one_chip, monkeypatch, rows=2):
 
 def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
         one_chip, no_compile_cache, monkeypatch):
-    """At 2 x 4096 tokens the compiled step holds the flash kernel and
-    the grouped products (Mosaic custom calls), no array with two trailing
-    4096 dims (the [S, S] attention scores) and no [T, 64, 1024] (every
-    token through every expert): attention never writes its scores to HBM
-    and the expert products run over the rows routed. It fits the chip."""
+    """At 2 x 4096 tokens the compiled step holds the flash kernels (the
+    forward, dK/dV and dQ) and the grouped products (Mosaic custom calls),
+    no float32 array whose trailing dims are [S, S] or [S, 256] (attention
+    scores, whole or by key block), no `while` under the attention
+    backward, and no [T, 64, 1024] (every token through every expert):
+    attention never writes its scores to HBM, forward or backward, and the
+    expert products run over the rows routed. It fits the chip."""
     cfg, compiled = _olmoe_step(one_chip, monkeypatch)
     text = compiled.as_text()
     S, E, F = (cfg["sequence_length"], cfg["num_experts"],
                cfg["intermediate_size"])
     T = 2 * S
-    shapes = {tuple(int(d) for d in dims.split(",") if d)
-              for _, dims in _ARRAY.findall(text)}
+    arrays = {(dt, tuple(int(d) for d in dims.split(",") if d))
+              for dt, dims in _ARRAY.findall(text)}
+    shapes = {s for _, s in arrays}
     scores = [s for s in shapes if len(s) >= 2 and s[-2:] == (S, S)]
     assert scores == []
+    blocks = [s for dt, s in arrays
+              if dt == "f32" and len(s) >= 2 and s[-2:] == (S, 256)]
+    assert blocks == []
+    loops = [ln for ln in text.splitlines()
+             if "causal_attention_grad" in ln and _INSTR.match(ln)
+             and _INSTR.match(ln).group(3) == "while"]
+    assert loops == []
     all_experts = [s for s in shapes
                    if len(s) >= 3 and s[-3:] in ((T, E, F), (E, T, F))]
     assert all_experts == []
     # the routed rows are there: [T * 8, F] and [T * 8, H]
     k, H = cfg["num_experts_per_tok"], cfg["hidden_size"]
     assert (T * k, F) in shapes and (T * k, H) in shapes
-    assert text.count('custom_call_target="tpu_custom_call"') >= 10
+    # 9 grouped products, the flash forward, dK/dV and dQ
+    assert text.count('custom_call_target="tpu_custom_call"') >= 12
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
