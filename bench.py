@@ -1475,110 +1475,6 @@ def measure_dry_cache(fluid):
     }
 
 
-def measure_dry_fusion(fluid):
-    """bench.py --dry fusion block: FLAGS_fuse A/B through the real
-    Executor miss path. One net with 6 parameters (3 fc layers, adam)
-    trained unfused then fused — the loss curves must agree BITWISE
-    (the fused kernels replay each sub-op's exact expression tree), the
-    per-step optimizer op count must collapse >= 5x (6 adam ops -> 1
-    fused bucket), and the warm fused step must not regress beyond timer
-    jitter. Slowest-ops tables (trace.costs analytic attribution) are
-    reported for both programs so the collapse shows up where a human
-    profiling the step would look for it."""
-    from paddle_tpu import flags, fusion
-    from paddle_tpu.trace import costs
-
-    OPT_OPS = ("sgd", "momentum", "adam")
-    K, batch, steps = 4, 8, 5
-
-    def build():
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-            x = fluid.layers.data(name="x", shape=[16], dtype="float32")
-            y = fluid.layers.data(name="y", shape=[1], dtype="float32")
-            h = fluid.layers.fc(input=x, size=32, act="relu")
-            h2 = fluid.layers.fc(input=h, size=16, act="relu")
-            p = fluid.layers.fc(input=h2, size=1)
-            loss = fluid.layers.mean(
-                fluid.layers.square_error_cost(input=p, label=y))
-            fluid.optimizer.Adam(learning_rate=0.001).minimize(loss)
-            main.random_seed = startup.random_seed = 7
-        return main, startup, loss
-
-    rs = np.random.RandomState(0)
-    xs = rs.randn(batch, 16).astype(np.float32)
-    ys = (xs.sum(axis=1, keepdims=True) * 0.1).astype(np.float32)
-
-    def run(fuse):
-        flags.set("fuse", fuse)
-        try:
-            main, startup, loss = build()
-            exe = fluid.Executor(fluid.CPUPlace())
-            scope = fluid.Scope()
-            with fluid.scope_guard(scope):
-                exe.run(startup)
-                losses = []
-                for _ in range(steps):
-                    (lv,) = exe.run(main, feed={"x": xs, "y": ys},
-                                    fetch_list=[loss])
-                    losses.append(np.asarray(lv).copy())
-                # warm-step timing, min-of-3 (the trace A/B's idiom)
-                best = float("inf")
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    for _ in range(K):
-                        exe.run(main, feed={"x": xs, "y": ys},
-                                fetch_list=[loss])
-                    best = min(best, time.perf_counter() - t0)
-            return np.stack(losses), best * 1000.0 / K
-        finally:
-            flags.set("fuse", False)
-
-    # the plan + analytic tables come from a direct fusion.apply on the
-    # same net the A/B trains
-    main, _startup, loss = build()
-    fused, plan = fusion.apply(main, feed_names=["x", "y"],
-                               fetch_names=[loss.name])
-    if plan is None:
-        raise RuntimeError("fusion.apply fused nothing on the bench net")
-
-    def table(prog):
-        return [{"op": r["op"], "out": r["out"],
-                 "flops_est": r["flops_est"],
-                 "share": round(r["share"], 4)}
-                for r in costs.attribute_costs(prog, batch_size=batch)[:5]]
-
-    unfused_losses, unfused_ms = run(False)
-    fused_losses, fused_ms = run(True)
-    diff = float(np.max(np.abs(unfused_losses - fused_losses)))
-    n_unfused = sum(1 for op in main.global_block().ops
-                    if op.type in OPT_OPS)
-    n_fused = sum(1 for op in fused.global_block().ops
-                  if op.type in OPT_OPS
-                  or op.type.startswith("fused_"))
-    delta = (fused_ms - unfused_ms) / unfused_ms if unfused_ms > 0 else 0.0
-    return {
-        "loss_parity_max_abs_diff": diff,
-        "parity_bitwise": diff == 0.0,
-        "optimizer_ops_unfused": n_unfused,
-        "optimizer_ops_fused": n_fused,
-        "optimizer_op_reduction_x": round(n_unfused / max(1, n_fused), 2),
-        "op_count_before": plan.n_ops_before,
-        "op_count_after": plan.n_ops_after,
-        "buckets": [{"opt": b["opt"], "n": b["n"],
-                     "shard_rows": b["shard_rows"]}
-                    for b in plan.buckets],
-        "chains": len(plan.chains),
-        "plan_digest": plan.digest(),
-        "unfused_step_ms": round(unfused_ms, 4),
-        "fused_step_ms": round(fused_ms, 4),
-        "fused_delta_frac": round(delta, 4),
-        "on_delta_ok": delta <= 0.01 or abs(fused_ms - unfused_ms) <= 0.25,
-        "slowest_ops_unfused": table(main),
-        "slowest_ops_fused": table(fused),
-    }
-
-
 def measure_dry(fluid):
     """bench.py --dry: a tiny MLP through the SAME public exe.run(iters=K)
     path with the monitor + HLO cost capture on, emitting the same
@@ -1772,13 +1668,6 @@ def measure_dry(fluid):
         result["cache_persist"] = measure_dry_cache(fluid)
     except Exception as e:
         result["cache_persist_error"] = f"{type(e).__name__}: {e}"
-    # cost-guided fusion A/B (FLAGS_fuse): bitwise loss parity, the >=5x
-    # optimizer-op collapse, warm-step delta, and slowest-ops tables for
-    # the unfused and fused programs
-    try:
-        result["fusion"] = measure_dry_fusion(fluid)
-    except Exception as e:
-        result["fusion_error"] = f"{type(e).__name__}: {e}"
     # serving mode, CI-sized: the same A/B the full --serve run does
     # (unbatched vs Server QPS, percentiles, zero-steady-compile check);
     # runs AFTER the cache snapshot above because it resets the monitor

@@ -14,9 +14,9 @@ per chip, bf16 AMP with fp32 masters, Momentum; weights random from a seed):
   serve      save_inference_model of the for_test clone ->
              serve.Server.from_inference_model behind make_http_server ->
              POST /v1/infer, compared with Executor.run on the same rows.
-  kernels    every Pallas kernel compiled by Mosaic (the lowered text must
-             hold the TPU custom call) and compared with a float32
-             jax.numpy reference.
+  kernels    the flash-attention Pallas kernels (forward, dK/dV, dQ)
+             compiled by Mosaic (the lowered text must hold the TPU custom
+             calls) and compared with a float32 jax.numpy reference.
   multichip  with more than one local chip: the same ResNet-50 through
              ParallelExecutor over all of them, then one dp x mp + ZeRO-1
              step. On one chip the result says "not run: 1 device".
@@ -78,9 +78,6 @@ class Sizes:
     # op picks (ops/lm_ops.py), and a row that the kernel's default block
     # does not divide (padded queries and keys, the last key block masked)
     flash: tuple = (((2, 16, 4096, 128), 1024), ((2, 16, 1000, 128), 256))
-    bucket_small: int = 1029
-    bucket_large: int = 0     # 0 = the model's parameter count
-    fuse_width: int = 256
 
 
 FULL = Sizes()
@@ -90,9 +87,7 @@ FULL = Sizes()
 TINY = Sizes(depth=18, classes=16, image=32, batch=8, k=2, chunks=4,
              distinct=2, serve_requests=8,
              first_losses_ref=(4.1299, 3.4761), first_loss_tol=0.3,
-             flash=(((1, 2, 128, 64), 64), ((1, 2, 100, 64), 32)),
-             bucket_large=5000,
-             fuse_width=16)
+             flash=(((1, 2, 128, 64), 64), ((1, 2, 100, 64), 32)))
 
 
 def say(msg):
@@ -164,10 +159,8 @@ class CompileLog:
 
 # the jitted functions that are an executor's compiled step: step and the
 # K-step scan multi (core/executor_core.py), or the wrapper a single step
-# was last given (datapipe/transfer.py wired, health/stats.py health_step,
-# PackPlan wrapped)
-STEP_NAMES = ("jit(step)", "jit(multi)", "jit(wired)", "jit(health_step)",
-              "jit(wrapped)")
+# was last given (datapipe/transfer.py wired, health/stats.py health_step)
+STEP_NAMES = ("jit(step)", "jit(multi)", "jit(wired)", "jit(health_step)")
 
 
 @contextlib.contextmanager
@@ -535,117 +528,14 @@ def _flash_case(shape, block, want_mosaic):
             and fwd <= FLASH_FWD_TOL and bwd <= FLASH_GRAD_TOL}
 
 
-def _bucket_case(n, want_mosaic):
-    """momentum_bucket / adam_bucket against the same expressions in plain
-    jax.numpy float32. Momentum and the adam moments only multiply and
-    add: bitwise. The adam parameter goes through sqrt and a divide: held
-    to 32 eps of |update| + |result| (tests/test_fusion.py gives the
-    reason; compiled by Mosaic it has been bitwise too)."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.fusion import kernels as fk
-
-    rs = np.random.RandomState(n % 9973)
-    p, g, v, m1 = (jnp.asarray(rs.randn(n), jnp.float32) for _ in range(4))
-    m2 = jnp.abs(jnp.asarray(rs.randn(n), jnp.float32))
-    lr, mu, b1, b2, eps = jnp.float32(0.01), 0.9, 0.9, 0.999, 1e-8
-
-    mom = jax.jit(lambda p, g, v: fk.momentum_bucket(p, g, v, lr, mu, False))
-    adam = jax.jit(lambda p, g, m1, m2: fk.adam_bucket(
-        p, g, m1, m2, lr, b1, b2, eps))
-    mosaic = ["tpu_custom_call" in mom.lower(p, g, v).as_text(),
-              "tpu_custom_call" in adam.lower(p, g, m1, m2).as_text()]
-
-    @jax.jit
-    def mom_ref(p, g, v):
-        v_out = mu * v + g
-        return p - lr * v_out, v_out
-
-    @jax.jit
-    def adam_ref(p, g, m1, m2):
-        m1o = jnp.float32(b1) * m1 + jnp.float32(1 - b1) * g
-        m2o = jnp.float32(b2) * m2 + jnp.float32(1 - b2) * jnp.square(g)
-        return p - lr * m1o / (jnp.sqrt(m2o) + jnp.float32(eps)), m1o, m2o
-
-    po, vo = (np.asarray(a) for a in mom(p, g, v))
-    pr, vr = (np.asarray(a) for a in mom_ref(p, g, v))
-    ao, a1o, a2o = (np.asarray(a) for a in adam(p, g, m1, m2))
-    ar, a1r, a2r = (np.asarray(a) for a in adam_ref(p, g, m1, m2))
-    bound = 32 * np.finfo(np.float32).eps * (
-        np.abs(np.asarray(p) - ar) + np.abs(ar))
-    adam_p_ok = bool(np.all(np.abs(ao - ar) <= bound))
-    bitwise = {"momentum_p": int(np.sum(po != pr)),
-               "momentum_v": int(np.sum(vo != vr)),
-               "adam_m1": int(np.sum(a1o != a1r)),
-               "adam_m2": int(np.sum(a2o != a2r)),
-               "adam_p": int(np.sum(ao != ar))}
-    return {"n": n, "mosaic": mosaic, "elements_differing": bitwise,
-            "ok": mosaic == [want_mosaic, want_mosaic] and adam_p_ok
-            and not any(bitwise[k] for k in
-                        ("momentum_p", "momentum_v", "adam_m1", "adam_m2"))}
-
-
-def _fused_op_case(fluid, place, optimizer, want_mosaic, width):
-    """fused_<opt>_update, the op the fusion pass emits, through the
-    Executor under FLAGS_fuse: on a TPU place its lowered step must hold
-    the Mosaic custom call — not the interpreted expansion, not the jnp
-    path."""
-    from paddle_tpu import flags
-
-    prog, startup = fluid.Program(), fluid.Program()
-    with fluid.unique_name.guard(), fluid.program_guard(prog, startup):
-        x = fluid.layers.data(name="x", shape=[width], dtype="float32")
-        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
-        h = fluid.layers.fc(input=x, size=width, act="relu")
-        h = fluid.layers.fc(input=h, size=width, act="relu")
-        loss = fluid.layers.mean(fluid.layers.square_error_cost(
-            input=fluid.layers.fc(input=h, size=1), label=y))
-        optimizer(fluid).minimize(loss)
-    rs = np.random.RandomState(3)
-    feed = {"x": rs.randn(8, width).astype(np.float32),
-            "y": rs.randn(8, 1).astype(np.float32)}
-    scope = fluid.Scope()
-    was = flags.get("fuse")
-    flags.set("fuse", True)
-    try:
-        with fluid.scope_guard(scope), captured_steps() as texts:
-            exe = fluid.Executor(place)
-            exe.run(startup)
-            lv, = exe.run(prog, feed=feed, fetch_list=[loss])
-    finally:
-        flags.set("fuse", was)
-    text = "\n".join(texts)
-    mosaic = "tpu_custom_call" in text
-    return {"steps_captured": len(texts), "mosaic": mosaic,
-            "loss": float(np.asarray(lv).reshape(-1)[0]),
-            "ok": len(texts) >= 1 and mosaic == want_mosaic
-            and bool(np.isfinite(lv).all())}
-
-
-def phase_kernels(fluid, sizes, place, n_params, want_mosaic):
-    from paddle_tpu import amp
-
+def phase_kernels(sizes, want_mosaic):
     t0 = time.time()
-    amp.disable()   # the fused-op programs are fp32 end to end
-    out = {"flash_attention": [], "buckets": []}
+    out = {"flash_attention": []}
     for shape, block in sizes.flash:
         out["flash_attention"].append(_flash_case(shape, block, want_mosaic))
         say(f"kernels: flash {out['flash_attention'][-1]}")
-    for n in (sizes.bucket_small, sizes.bucket_large or n_params):
-        out["buckets"].append(_bucket_case(n, want_mosaic))
-        say(f"kernels: bucket {out['buckets'][-1]}")
-    out["fused_momentum_update"] = _fused_op_case(
-        fluid, place, lambda f: f.optimizer.Momentum(
-            learning_rate=0.01, momentum=0.9), want_mosaic, sizes.fuse_width)
-    out["fused_adam_update"] = _fused_op_case(
-        fluid, place, lambda f: f.optimizer.Adam(learning_rate=0.001),
-        want_mosaic, sizes.fuse_width)
     checks = {
         "flash_attention": all(c["ok"] for c in out["flash_attention"]),
-        "momentum_adam_buckets": all(c["ok"] for c in out["buckets"]),
-        "fused_momentum_update": out["fused_momentum_update"]["ok"],
-        "fused_adam_update": out["fused_adam_update"]["ok"],
     }
     return dict(out, wall_s=round(time.time() - t0, 1), checks=checks,
                 mosaic_expected=want_mosaic,
@@ -783,8 +673,7 @@ def run_phases(fluid, sizes, device, rehearsal):
             n_params = param_count(trained["prog"])
             del trained
         say("phase kernels")
-        phases["kernels"] = phase_kernels(
-            fluid, sizes, place, n_params, want_mosaic)
+        phases["kernels"] = phase_kernels(sizes, want_mosaic)
         if n > 1:
             say("phase multichip")
             phases["multichip"] = phase_multichip(fluid, sizes, log, n)

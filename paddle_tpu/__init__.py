@@ -99,7 +99,6 @@ from . import datapipe
 from .datapipe import DataPipe, AsyncDeviceFeeder
 from . import monitor
 from . import analysis
-from . import fusion
 from . import health
 from . import resilience
 from .resilience import ResilienceConfig, ResilientRunner
@@ -129,6 +128,6 @@ __all__ = [
     "InferenceTranspiler", "memory_optimize", "release_memory",
     "reader", "dataset", "batch", "unique_name", "parallel", "flags",
     "concurrency", "pipeline", "DeviceChunkFeeder", "datapipe", "DataPipe",
-    "AsyncDeviceFeeder", "monitor", "health", "resilience", "fusion",
+    "AsyncDeviceFeeder", "monitor", "health", "resilience",
     "ResilienceConfig", "ResilientRunner", "serve", "trace",
 ]

@@ -199,8 +199,8 @@ class CompileCache:
         return default_store()
 
     def l2_digest(self, program, key_tail, extra=()):
-        """Stable store key for one L1 key: its content tail (everything
-        after the (id, mutation) head) + the executor kind + the caller's
+        """Stable store key for one L1 key: its content part
+        (executor_core.step_key) + the executor kind + the caller's
         device/mesh context."""
         return stable_digest(
             program, key_tail,

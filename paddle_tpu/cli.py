@@ -712,113 +712,6 @@ def _cmd_analyze(args):
             print(report.render(verbose=not args.quiet))
         return report.rc
 
-    if args.analyze_action == "fusion":
-        from . import analysis, fusion
-
-        bucket_bytes = (args.bucket_mb << 20) if args.bucket_mb else None
-
-        def render(plan):
-            if plan is None:
-                return "fusion: nothing fused"
-            lines = [f"fusion: ops {plan.n_ops_before} -> "
-                     f"{plan.n_ops_after}  digest={plan.digest()}"]
-            for c in plan.chains:
-                lines.append(f"  chain  {'+'.join(c['types'])}  "
-                             f"{c['vars'][0]} -> {c['vars'][1]}  "
-                             f"benefit={c['benefit_us']}us")
-            for b in plan.buckets:
-                lines.append(f"  bucket fused_{b['opt']}_update x{b['n']} "
-                             f"bytes={b['bytes']} "
-                             f"shard_rows={b['shard_rows']}")
-            if plan.skipped and not args.quiet:
-                for base, why in plan.skipped:
-                    lines.append(f"  skipped {base}: {why}")
-            return "\n".join(lines)
-
-        if args.selftest:
-            import paddle_tpu as fluid
-
-            # training demo: 6 params under adam -> one fused bucket,
-            # and the fused program must verify clean at level full
-            main, start = fluid.Program(), fluid.Program()
-            with fluid.unique_name.guard(), \
-                    fluid.program_guard(main, start):
-                x = fluid.layers.data(name="x", shape=[8],
-                                      dtype="float32")
-                y = fluid.layers.data(name="y", shape=[1],
-                                      dtype="float32")
-                h = fluid.layers.fc(x, 16, act="relu")
-                h2 = fluid.layers.fc(h, 8, act="relu")
-                p = fluid.layers.fc(h2, 1)
-                loss = fluid.layers.mean(
-                    fluid.layers.square_error_cost(p, y))
-                fluid.optimizer.Adam(learning_rate=0.01).minimize(loss)
-            fused, plan = fusion.apply(main, feed_names=["x", "y"],
-                                       fetch_names=[loss.name],
-                                       bucket_bytes=bucket_bytes)
-            rep = analysis.verify(fused, feed_names=["x", "y"],
-                                  fetch_names=[loss.name], level="full",
-                                  context="analyze fusion --selftest")
-            ok = plan is not None and bool(plan.buckets) \
-                and max(b["n"] for b in plan.buckets) >= 2 and rep.ok
-
-            # inference demo: an elementwise chain must fuse vertically
-            inf = fluid.Program()
-            with fluid.unique_name.guard(), \
-                    fluid.program_guard(inf, fluid.Program()):
-                xi = fluid.layers.data(name="x", shape=[64],
-                                       dtype="float32")
-                out = fluid.layers.scale(
-                    fluid.layers.sigmoid(fluid.layers.tanh(
-                        fluid.layers.relu(xi))), scale=2.0)
-            _, vplan = fusion.apply(inf, feed_names=["x"],
-                                    fetch_names=[out.name])
-            ok = ok and vplan is not None and len(vplan.chains) >= 1
-
-            # a hazardous source program must be REFUSED, never fused
-            refused, codes = False, []
-            try:
-                fusion.apply(_seed_cycle(main), feed_names=["x", "y"],
-                             fetch_names=[loss.name])
-            except ProgramVerificationError as e:
-                refused = True
-                codes = sorted(e.report.codes())
-            ok = ok and refused and "PTA030" in codes
-            if args.json:
-                print(json.dumps({
-                    "ok": bool(ok),
-                    "plan": plan.to_dict() if plan else None,
-                    "vertical": vplan.to_dict() if vplan else None,
-                    "verify_ok": rep.ok,
-                    "seeded_refused": refused,
-                    "seeded_codes": codes}, indent=2))
-            else:
-                print(render(plan))
-                print(render(vplan))
-                print("--- seeded cyclic source: "
-                      + ("refused " + str(codes) if refused
-                         else "NOT refused") + " ---")
-                print("analyze fusion selftest: "
-                      + ("OK" if ok else "FAILED"))
-            return 0 if ok else 1
-
-        resolved = _resolve_program()
-        if resolved is None:
-            return 2
-        program, feeds = resolved
-        try:
-            fused, plan = fusion.apply(program, feed_names=feeds,
-                                       bucket_bytes=bucket_bytes)
-        except ProgramVerificationError as e:
-            print(e.report.render(verbose=not args.quiet),
-                  file=sys.stderr)
-            return 1
-        if args.json:
-            print(json.dumps(plan.to_dict() if plan else None, indent=2))
-        else:
-            print(render(plan))
-        return 0
-
     # analyze schedule
     if args.selftest:
         from .parallel import zero1 as _z1
@@ -1751,29 +1644,6 @@ def main(argv=None):
                       help="emit the schedule report as JSON")
     asch.add_argument("--quiet", action="store_true",
                       help="show errors only, not warnings")
-    afu = ansub.add_parser(
-        "fusion", help="cost-guided operator fusion plan: vertical "
-                       "elementwise chains and the bucketed fused weight "
-                       "update (docs/fusion.md)")
-    afu.add_argument("--model-dir", default=None,
-                     help="save_inference_model directory to plan fusion "
-                          "for")
-    afu.add_argument("--zero1", type=int, default=0, metavar="N",
-                     help="apply the ZeRO-1 rewrite with N shards before "
-                          "fusing (exercises shard-aware bucketing)")
-    afu.add_argument("--bucket-mb", type=int, default=None,
-                     help="override FLAGS_fuse_bucket_mb for the update "
-                          "bucketing")
-    afu.add_argument("--selftest", action="store_true",
-                     help="fuse a demo trainer (must bucket >= 2 adam "
-                          "updates and verify clean at level full), fuse "
-                          "a demo elementwise chain, AND verify a seeded "
-                          "cyclic source is refused with PTA030; rc 0 "
-                          "when all behave")
-    afu.add_argument("--json", action="store_true",
-                     help="emit the fusion plan as JSON")
-    afu.add_argument("--quiet", action="store_true",
-                     help="hide skipped-candidate reasons")
     apl = ansub.add_parser(
         "pipeline", help="pp-axis stage partition (parallel.pipeline): "
                          "min-cut plan, PTA040/041 legality, and the 1F1B "

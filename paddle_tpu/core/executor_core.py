@@ -23,7 +23,7 @@ from . import registry
 from .registry import SeqTensor
 from . import dtypes
 from .framework import OP_NAMESCOPE_ATTR_NAME
-from .. import flags
+from .. import amp, flags
 from ..ops import bn_pool, lm_ops
 
 
@@ -100,135 +100,11 @@ def env_get(env, name, allow_missing=False):
     raise KeyError(f"Variable {name!r} not materialized (missing feed or init?)")
 
 
-_FUSABLE_OPT = {"sgd", "momentum"}
-# Only small parameters are worth batching: their update kernels are
-# launch-overhead-bound (ResNet-50's ~106 BN scales/biases measured ~65 us
-# each for <10 us of memory traffic), while large tensors are already
-# bandwidth-efficient and fusing them breaks XLA's in-place donation
-# aliasing (measured 2x slower when everything was concatenated).
-_FUSE_MAX_NUMEL = 1 << 18
-
-
-def _fuse_optimizer_group(ops, start, env, ctx, fused_ids):
-    """Batch all SMALL same-type/same-attrs optimizer updates remaining in
-    `ops` into ONE kernel call over concatenated flat parameters.
-
-    The updates are elementwise and independent (each op touches only its
-    own Param/Velocity), so gathering them from anywhere in the tail of
-    the op list is order-safe; all their Grad inputs exist by the time the
-    first optimizer op runs (the optimization pass appends them after the
-    whole backward). Numerically identical to the per-op path.
-
-    Returns the set of fused op ids (empty when no fusion applies).
-    """
-    first_op = ops[start]
-
-    def key_attrs(op):
-        # op_role / op_role_var markers differ per parameter and don't
-        # affect the math — ignore them when grouping
-        return {k: v for k, v in op.attrs.items()
-                if not k.startswith("op_")}
-
-    a0 = key_attrs(first_op)
-    lr_name = (first_op.inputs.get("LearningRate") or [None])[0]
-    slots = [s for s in first_op.inputs if s != "LearningRate"]
-    group, per_op_ins = [], []
-    # Hazards vs ops between `start` and the candidate that do NOT join the
-    # group (the fused kernel runs at the first member's position):
-    #  - RAW: a member whose input is (re)written by an intervening op
-    #    would read a stale value inside the fused call;
-    #  - WAR: an intervening op that READS a name the member writes would
-    #    observe the post-update value (the fused call commits early).
-    # Either way the candidate stays on the per-op path.
-    written_between, read_between = set(), set()
-
-    def skip(op):
-        written_between.update(op.output_arg_names())
-        read_between.update(op.input_arg_names())
-
-    for op in ops[start:]:
-        if id(op) in fused_ids or op.type != first_op.type:
-            skip(op)
-            continue
-        if key_attrs(op) != a0 or \
-                (op.inputs.get("LearningRate") or [None])[0] != lr_name:
-            skip(op)
-            continue
-        if any(n in written_between for n in op.input_arg_names()) or \
-                any(n in read_between for n in op.output_arg_names()):
-            skip(op)
-            continue
-        ins = {}
-        ok = True
-        for s in op.inputs:
-            vals = [env_get(env, n, allow_missing=True)
-                    for n in op.inputs[s]]
-            ins[s] = vals
-            if s == "LearningRate":
-                continue
-            for v in vals:
-                if v is None or isinstance(v, SeqTensor) \
-                        or not hasattr(v, "reshape") \
-                        or not hasattr(v, "dtype"):
-                    ok = False  # SelectedRows/ragged/missing: per-op path
-        if not ok:
-            skip(op)
-            continue
-        if int(np.prod(ins["Param"][0].shape)) > _FUSE_MAX_NUMEL:
-            skip(op)
-            continue
-        group.append(op)
-        per_op_ins.append(ins)
-        # members write too (Param/accumulators): a later candidate reading
-        # one of these (same Param updated twice) must stay per-op — inside
-        # the fused call it would read the pre-update value
-        written_between.update(op.output_arg_names())
-    if len(group) < 2:
-        return set()
-    # RAW dtype homogeneity per slot: run_kernel's amp policy then applies
-    # one cast to the concatenated slot, identical to per-op policy casts
-    for s in slots:
-        d0 = per_op_ins[0][s][0].dtype
-        if any(o[s][0].dtype != d0 for o in per_op_ins):
-            return set()
-
-    op_def = registry.lookup(first_op.type)
-    shapes = [o["Param"][0].shape for o in per_op_ins]
-    sizes = [int(np.prod(s)) for s in shapes]
-    cat_ins = {
-        s: [jnp.concatenate([o[s][0].reshape(-1) for o in per_op_ins])]
-        for s in slots
-    }
-    cat_ins["LearningRate"] = [env_get(env, lr_name)]
-    # through run_kernel, not op_def.fn: amp policy + op-coverage tracking
-    # apply to the fused call exactly like a per-op call
-    outs = registry.run_kernel(op_def, ctx, cat_ins, first_op.attrs) or {}
-    offsets = np.cumsum([0] + sizes)
-    for slot, vals in outs.items():
-        flat = vals[0] if isinstance(vals, list) else vals
-        for k, op in enumerate(group):
-            names = op.outputs.get(slot) or []
-            if not names or not names[0]:
-                continue
-            env[names[0]] = flat[offsets[k]:offsets[k + 1]].reshape(shapes[k])
-    return {id(op) for op in group}
-
-
 def run_ops(ops, env, ctx):
-    fused_ids = set()
     # offered every op of a traced block, says whether it lowered the op
     pairs = None if ctx.eager else bn_pool.Lowering(
         ops, ctx, _run_one_op, _bind_outputs)
-    for i, op in enumerate(ops):
-        if id(op) in fused_ids:
-            continue
-        if not ctx.eager and op.type in _FUSABLE_OPT \
-                and flags.get("fuse_optimizer_ops"):
-            done = _fuse_optimizer_group(ops, i, env, ctx, fused_ids)
-            if done:
-                fused_ids |= done
-                if id(op) in fused_ids:
-                    continue
+    for op in ops:
         if pairs is None or not pairs.offer(op, env, ctx):
             _run_one_op(op, env, ctx)
     return env
@@ -503,152 +379,7 @@ def compile_step_fn(step, donate_state=True, donate_feeds=False,
     return call
 
 
-def collect_ema_states(program, state_out_names, fetch_names=()):
-    """{var_name: momentum} for batch-norm running stats that are PURE EMA
-    recurrences of this (training) program: written only as a batch_norm's
-    MeanOut/VarianceOut, read only as the SAME op's Mean/Variance input,
-    and not fetched. These can leave the multi-step scan carry (sparing
-    the carry's back-edge copies) and be
-    reconstructed exactly after the scan — r_{k+1} = m r_k + (1-m) s_k is a
-    linear fold, so r_K = m^K r_0 + Σ m^{K-1-i} (o_i - m r_0) where o_i is
-    the step's output against the CONSTANT initial value r_0."""
-    candidates = {}
-    gb = program.global_block()
-    for op in gb.ops:
-        if op.type != "batch_norm" or op.attrs.get("is_test", False):
-            continue
-        momentum = float(op.attrs.get("momentum", 0.9))
-        for in_slot, out_slot in (("Mean", "MeanOut"),
-                                  ("Variance", "VarianceOut")):
-            ins = op.inputs.get(in_slot) or []
-            outs = op.outputs.get(out_slot) or []
-            if ins and outs and ins[0] == outs[0] and ins[0]:
-                candidates[ins[0]] = (momentum, op)
-    if not candidates:
-        return {}
-    fetched = set(fetch_names)
-    reads, writes = {}, {}
-    for op in gb.ops:
-        for n in op.input_arg_names():
-            reads.setdefault(n, []).append(op)
-        for n in op.output_arg_names():
-            writes.setdefault(n, []).append(op)
-    out_set = set(state_out_names)
-    ema = {}
-    for n, (momentum, owner) in candidates.items():
-        if n not in out_set or n in fetched:
-            continue
-
-        def harmless(o):
-            # batch_norm_grad receives the running stats because the
-            # default vjp maker forwards every forward input, but its
-            # cotangents don't depend on them: MeanOut/VarianceOut are
-            # stop-gradient outputs, and the training branch uses BATCH
-            # statistics for normalization
-            return o is owner or (o.type == "batch_norm_grad"
-                                  and not o.attrs.get("is_test", False))
-
-        if any(not harmless(o) for o in reads.get(n, [])):
-            continue  # another op consumes the running stat: keep carried
-        if any(o is not owner for o in writes.get(n, [])):
-            continue
-        ema[n] = momentum
-    return ema
-
-
-class PackPlan:
-    """Packed small-state storage for the multi-step scan (an experiment
-    aimed at the per-parameter update kernels of ResNet-50; see
-    FLAGS_pack_small_state for what it showed).
-
-    Instead of carrying each small float parameter/accumulator as its own
-    scan-carry leaf (one XLA buffer + back-edge copy + update kernel
-    each), all small same-dtype mut-state entries live CONCATENATED in one
-    buffer. Inside the step they are sliced back to views (slices fuse
-    into the consumers), and the updated values concatenate into the new
-    packed buffer — which is the donated carry leaf, so the update lowers
-    to (ideally) one fused kernel over one aliased buffer. Contrast with
-    r4's rejected concat-fusion, whose slice-back wrote SEPARATE per-param
-    output buffers and broke donation aliasing.
-    """
-
-    MAX_NUMEL = 1 << 16
-
-    def __init__(self, mut_values, exclude=()):
-        by_dtype = {}
-        for n in sorted(mut_values):
-            v = mut_values[n]
-            if n in exclude or isinstance(v, SeqTensor) \
-                    or not hasattr(v, "dtype") or not hasattr(v, "shape"):
-                continue
-            if not jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating):
-                continue
-            size = int(np.prod(v.shape)) if v.shape else 1
-            if size > self.MAX_NUMEL:
-                continue
-            by_dtype.setdefault(str(v.dtype), []).append(
-                (n, size, tuple(v.shape)))
-        self.groups = []
-        for dtype, entries in sorted(by_dtype.items()):
-            if len(entries) < 2:
-                continue
-            offs, off = [], 0
-            for _, size, _ in entries:
-                offs.append(off)
-                off += size
-            self.groups.append(dict(
-                key=f"__packed__{dtype}", dtype=dtype, total=off,
-                entries=[(n, o, s, shp) for (n, s, shp), o
-                         in zip(entries, offs)]))
-        self.packed_names = {n for g in self.groups
-                             for (n, _, _, _) in g["entries"]}
-
-    @staticmethod
-    def pack_group(g, values):
-        """One group's members ({name: value}) -> the packed 1-D buffer.
-        The single definition of the packed layout's write side."""
-        return jnp.concatenate([
-            jnp.asarray(values[n]).reshape(-1)
-            for n, _, _, _ in g["entries"]])
-
-    @staticmethod
-    def group_views(g, P):
-        """Packed buffer -> member views, in g["entries"] order. The
-        single definition of the packed layout's read side (also what the
-        Executor jits for the post-call scope write-back)."""
-        return [jax.lax.dynamic_slice(P, (off,), (size,)).reshape(shape)
-                for _, off, size, shape in g["entries"]]
-
-    def unpack_into(self, packed_mut):
-        """packed mut dict -> {name: view} for every packed member."""
-        views = {}
-        for g in self.groups:
-            for (n, _, _, _), v in zip(
-                    g["entries"], self.group_views(g, packed_mut[g["key"]])):
-                views[n] = v
-        return views
-
-    def wrap_step(self, step):
-        """step over individual names -> step over packed mut state."""
-
-        def wrapped(mut_state, const_state, feeds, rng):
-            mut = {n: v for n, v in mut_state.items()
-                   if not n.startswith("__packed__")}
-            views = self.unpack_into(mut_state)
-            mut.update(views)
-            fetches, new_mut = step(mut, const_state, feeds, rng)
-            out = {n: v for n, v in new_mut.items()
-                   if n not in self.packed_names}
-            for g in self.groups:
-                merged = {n: new_mut.get(n, views[n])
-                          for n, _, _, _ in g["entries"]}
-                out[g["key"]] = self.pack_group(g, merged)
-            return fetches, out
-
-        return wrapped
-
-
-def build_multi_step_fn(step, iters, ema=None):
+def build_multi_step_fn(step, iters):
     """Wrap a step function in a lax.scan over `iters` pre-stacked feeds.
 
     One XLA dispatch then covers `iters` training steps — the host-loop
@@ -666,49 +397,57 @@ def build_multi_step_fn(step, iters, ema=None):
     compiled computation and force a recompile per call).
     """
 
-    ema = ema or {}
-
     def multi(mut_state, const_state, stacked_feeds, rng):
         base_key, step0 = rng
-        # EMA sinks (collect_ema_states) ride OUTSIDE the carry: each step
-        # sees the constant initial value r_0 and its per-step output is
-        # stacked as a scan Y; the exact K-step fold happens after the scan
-        ema_r0 = {n: mut_state[n] for n in ema if n in mut_state}
-        carry0 = {n: v for n, v in mut_state.items() if n not in ema_r0}
 
         def body(st, xs):
             i, feeds = xs
             sub = jax.random.fold_in(base_key, step0 + i)
-            full = dict(st)
-            full.update(ema_r0)
-            fetches, new_mut = step(full, const_state, feeds, sub)
+            fetches, new_mut = step(st, const_state, feeds, sub)
             # carry structure must be invariant across iterations: state the
             # step writes replaces the carried entry; state it only reads
             # rides through unchanged. Written-but-never-carried names are
             # rejected up front by the Executor (see run(iters=...)).
             st = {n: new_mut.get(n, v) for n, v in st.items()}
-            ys = {n: new_mut[n] for n in ema_r0 if n in new_mut}
-            return st, (fetches, ys)
+            return st, fetches
 
-        st, (fetches, ema_ys) = jax.lax.scan(
-            body, carry0,
+        st, fetches = jax.lax.scan(
+            body, mut_state,
             (jnp.arange(iters, dtype=jnp.int32), stacked_feeds),
             length=iters)
-        # exact reconstruction: o_i = m r_0 + (1-m) s_i was computed against
-        # the constant r_0, and the true fold is linear:
-        #   r_K = m^K r_0 + Σ_i m^(K-1-i) (o_i - m r_0)
-        for n, o_stack in ema_ys.items():
-            m = jnp.asarray(ema[n], jnp.float32)
-            r0 = ema_r0[n].astype(jnp.float32)
-            w = jnp.power(m, jnp.arange(iters - 1, -1, -1, dtype=jnp.float32))
-            contrib = jnp.tensordot(
-                w, o_stack.astype(jnp.float32) - m * r0[None], axes=1)
-            rK = jnp.power(m, iters) * r0 + contrib
-            st = dict(st)
-            st[n] = rK.astype(ema_r0[n].dtype)
         return fetches, st
 
     return multi
+
+
+def step_key(program, feed_vals, fetch_names, state_names, *, iters=None,
+             wire=None, donate_feeds=False, health=None, extra=()):
+    """The compile key of one step, as (identity, content).
+
+    identity pins the program within this process: (id, mutation counter).
+    content is everything else that changes the traced or compiled step:
+    feed shape/dtype specs, fetch and state names, the amp policy,
+    debug_nans (it turns state donation off), `iters` (None = the plain
+    step, K = the K-step scan), the wire spec, feed donation, the health
+    plan, and the caller's `extra` entries (ParallelExecutor: zero1 /
+    overlap / autoshard / pipeline). It is built from sorted tuples of
+    primitives only, so CompileCache.l2_digest can take it as it is.
+
+    The in-memory (L1) key is identity + content. A trace-affecting input
+    that is not in here is a silently reused executable: add it here, and
+    a case to tests/test_compile_cache.py's ingredient test."""
+    content = (
+        tuple(sorted((n, spec_of(v)) for n, v in feed_vals.items())),
+        tuple(fetch_names),
+        tuple(state_names),
+        amp.fingerprint(),
+        flags.get("debug_nans"),
+        ("iters", iters),
+        ("wire", wire.fingerprint() if wire is not None else None),
+        ("donate_feeds", donate_feeds),
+        ("health", health.digest if health is not None else None),
+    ) + tuple(extra)
+    return (id(program), program._mutation), content
 
 
 # ---------------------------------------------------------------------------

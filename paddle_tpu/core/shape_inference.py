@@ -1302,50 +1302,6 @@ def _zero1_gather(ctx):
         ctx.set_output_dim("Out", [int(d) for d in shape])
 
 
-# -- fused ops (paddle_tpu.fusion) -----------------------------------------
-@register_infer_shape("fused_elementwise")
-def _fused_elementwise(ctx):
-    """Every sub-op in the replayed chain is unary elementwise, so the
-    chain preserves the input shape end to end."""
-    x = ctx.input_dim("X")
-    if x is not None:
-        ctx.set_output_dim("Out", x)
-
-
-@register_infer_shape("fused_sgd_update", "fused_momentum_update",
-                      "fused_adam_update")
-def _fused_update(ctx):
-    """Bucketed weight update: slot i of every variadic output mirrors
-    slot i of its input — the packed lane is sliced back exactly."""
-    n = len(ctx.op.inputs.get("Param") or [])
-    ctx.enforce(n >= 1, "fused update needs at least one Param")
-    ctx.enforce(len(ctx.op.inputs.get("Grad") or []) == n,
-                "fused update needs one Grad per Param")
-    rows = ctx.attr("shard_rows", 0)
-    for in_slot, out_slot in (("Param", "ParamOut"),
-                              ("Velocity", "VelocityOut"),
-                              ("Moment1", "Moment1Out"),
-                              ("Moment2", "Moment2Out")):
-        names = ctx.op.inputs.get(in_slot) or []
-        ctx.enforce(len(names) in (0, n),
-                    f"fused update slot {in_slot} must carry one entry "
-                    f"per Param")
-        for i in range(len(names)):
-            d = ctx.input_dim(in_slot, i)
-            if d is None:
-                continue
-            g = ctx.input_dim("Grad", i)
-            if g is not None:
-                ctx.enforce(_shapes_match(d, g),
-                            f"{in_slot}[{i}] shape {d} does not match "
-                            f"Grad[{i}] shape {g}")
-            if rows:
-                ctx.enforce(len(d) == 2 and _dim_match(d[0], int(rows)),
-                            f"shard-layout member {in_slot}[{i}] must be "
-                            f"(shard_rows={rows}, shard), got {d}")
-            ctx.set_output_dim(out_slot, d, i)
-
-
 # -- host / side-effect ops ------------------------------------------------
 def _host_noop(ctx):
     """Side-effect / host ops: no dense output shape semantics at build

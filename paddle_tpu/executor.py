@@ -13,7 +13,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import amp
 from . import analysis
 from . import flags
 from . import monitor
@@ -87,27 +86,6 @@ def _apply_debug_nans():
     if _debug_nans_applied[0] != want:
         jax.config.update("jax_debug_nans", bool(want))
         _debug_nans_applied[0] = want
-
-
-def _memoize_packed(memo, key, P, views):
-    """Cache a PackPlan group's (packed buffer, unpacked views) for reuse
-    on the next run WITHOUT pinning device memory: the views are held as
-    weak refs (the scope owns the strong ones), and a finalizer evicts the
-    entry when any view dies — so a dropped/retired scope releases the
-    packed buffer instead of it riding in the compile cache forever. The
-    identity guard keeps a dying PREVIOUS generation's finalizer from
-    evicting the entry the current run just stored."""
-    import weakref
-
-    entry = None
-
-    def _evict(_ref):
-        if memo.get(key) is entry:
-            memo.pop(key, None)
-
-    refs = [weakref.ref(v, _evict) for v in views]
-    entry = (P, refs)
-    memo[key] = entry
 
 
 def _program_has_host_ops(program):
@@ -263,24 +241,6 @@ class Executor:
         place_jax_cache()
         self._compile_cache = CompileCache("executor")
         self._step_counter = {}
-        self._fusion_cache = {}
-
-    def _fuse_program(self, program, feed_names, fetch_names):
-        """FLAGS_fuse: resolve (and cache) the fused clone of `program`
-        (paddle_tpu.fusion). Cached per (id, mutation, bucket budget,
-        feeds, fetches) so repeat steps reuse ONE clone — a stable clone
-        id keeps the compile-cache key stable."""
-        from . import fusion
-
-        key = (id(program), program._mutation,
-               flags.get("fuse_bucket_mb"),
-               tuple(sorted(feed_names)), tuple(fetch_names))
-        hit = self._fusion_cache.get(key)
-        if hit is None:
-            hit = fusion.apply(program, feed_names=feed_names,
-                               fetch_names=fetch_names)
-            self._fusion_cache[key] = hit
-        return hit
 
     def _device_scope(self):
         """Pin execution to the Place's device (executor.cc:133 runs ops on
@@ -413,8 +373,8 @@ class Executor:
                     program, scope, feed, fetch_names, use_program_cache,
                     wire=wire, donate_feeds=donate_feeds, mon=mon)
         if mon is not None:
-            # unpackers, scope.set_var over the state, health and NaN
-            # checks, and leaving the watchdog and the device scope
+            # scope.set_var over the state, health and NaN checks, and
+            # leaving the watchdog and the device scope
             mon.lap("write_back")
         if async_fetch:
             outs = [FetchFuture(o) for o in outs]
@@ -475,32 +435,16 @@ class Executor:
         feed_vals = self._feed_values(program, feed, wire=wire)
         if mon is not None:
             mon.lap("feed_encode")
-        fplan = None
-        if flags.get("fuse"):
-            program, fplan = self._fuse_program(
-                program, list(feed_vals), list(fetch_names))
-            if mon is not None:
-                mon.lap("cache_lookup")
         state_names, state_out_names = executor_core.collect_state_names(program, scope)
         if mon is not None:
             mon.lap("state_gather")
         if flags.get("debug_nans"):
             donate_feeds = False  # re-run needs the inputs (see below)
         hplan = _health.plan_if_enabled(program)
-        cache_key = (
-            id(program),
-            program._mutation,
-            tuple(sorted((n, executor_core.spec_of(v)) for n, v in feed_vals.items())),
-            tuple(fetch_names),
-            tuple(state_names),
-            amp.fingerprint(),
-            flags.get("fuse_optimizer_ops"),  # trace-affecting, like amp
-            flags.get("debug_nans"),  # changes donation (see below)
-            ("wire", wire.fingerprint() if wire is not None else None),
-            ("donate_feeds", donate_feeds),
-            ("health", hplan.digest if hplan is not None else None),
-            ("fuse", fplan.digest() if fplan is not None else None),
-        )
+        ident, content = executor_core.step_key(
+            program, feed_vals, fetch_names, state_names, wire=wire,
+            donate_feeds=donate_feeds, health=hplan)
+        cache_key = ident + content
         entry = self._compile_cache.get(cache_key) if use_cache else None
         fp = None
         if mon is not None:
@@ -521,7 +465,7 @@ class Executor:
             tb = time.perf_counter()
             cache_obj = self._compile_cache
             digest = cache_obj.l2_digest(
-                program, cache_key[2:], extra=self._l2_extra()) \
+                program, content, extra=self._l2_extra()) \
                 if use_cache and cache_obj.l2_enabled() else None
 
             def _fresh(export_digest=None):
@@ -645,12 +589,6 @@ class Executor:
         feed_vals = self._stack_feeds(program, feed, iters, wire=wire)
         if mon is not None:
             mon.lap("feed_encode")
-        fplan = None
-        if flags.get("fuse"):
-            program, fplan = self._fuse_program(
-                program, list(feed_vals), list(fetch_names))
-            if mon is not None:
-                mon.lap("cache_lookup")
         state_names, state_out_names = executor_core.collect_state_names(
             program, scope)
         missing = [n for n in state_out_names if not scope.has_var(n)]
@@ -672,24 +610,10 @@ class Executor:
         if flags.get("debug_nans"):
             donate_feeds = False  # the op-by-op re-run needs the inputs
         hplan = _health.plan_if_enabled(program)
-        cache_key = (
-            id(program),
-            program._mutation,
-            tuple(sorted((n, executor_core.spec_of(v))
-                         for n, v in feed_vals.items())),
-            tuple(fetch_names),
-            tuple(state_names),
-            amp.fingerprint(),
-            flags.get("fuse_optimizer_ops"),
-            flags.get("debug_nans"),
-            flags.get("fold_ema_multi_step"),
-            flags.get("pack_small_state"),
-            ("iters", iters),
-            ("wire", wire.fingerprint() if wire is not None else None),
-            ("donate_feeds", donate_feeds),
-            ("health", hplan.digest if hplan is not None else None),
-            ("fuse", fplan.digest() if fplan is not None else None),
-        )
+        ident, content = executor_core.step_key(
+            program, feed_vals, fetch_names, state_names, iters=iters,
+            wire=wire, donate_feeds=donate_feeds, health=hplan)
+        cache_key = ident + content
         entry = self._compile_cache.get(cache_key) if use_cache else None
         fp = None
         if mon is not None:
@@ -705,23 +629,9 @@ class Executor:
                 donate_state=not flags.get("debug_nans"),
                 context="executor")
             tb = time.perf_counter()
-            # ema folding and the pack plan are cheap host-side analyses
-            # needed on BOTH the fresh-compile and the L2-hit paths (the
-            # pack/unpack around the dispatch mirrors what the serialized
-            # executable was compiled against — both are derived
-            # deterministically from the program + state, and the flags
-            # gating them are part of the digest)
-            ema = executor_core.collect_ema_states(
-                program, state_out_names, fetch_names) \
-                if flags.get("fold_ema_multi_step") else {}
-            plan = None
-            if flags.get("pack_small_state"):
-                plan = executor_core.PackPlan(mut_state, exclude=set(ema))
-                if not plan.groups:
-                    plan = None
             cache_obj = self._compile_cache
             digest = cache_obj.l2_digest(
-                program, cache_key[2:], extra=self._l2_extra()) \
+                program, content, extra=self._l2_extra()) \
                 if use_cache and cache_obj.l2_enabled() else None
 
             def _fresh(export_digest=None):
@@ -743,10 +653,7 @@ class Executor:
                     # per step BEFORE the scan wraps them — the scan then
                     # stacks tiny stats, never raw [K, ...] gradients
                     step = hplan.wrap_step(step, len(fetch_names))
-                if plan is not None:
-                    step = plan.wrap_step(step)
-                multi = executor_core.build_multi_step_fn(step, iters,
-                                                          ema=ema)
+                multi = executor_core.build_multi_step_fn(step, iters)
                 probe = monitor.compile_probe(fp) \
                     if mon is not None and mon.monitored \
                     and flags.get("monitor_hlo_cost") else None
@@ -764,15 +671,8 @@ class Executor:
                 level = "l2"
             else:
                 compiled = _fresh(digest)
-            unpackers = {}
-            if plan is not None:
-                for g in plan.groups:
-                    unpackers[g["key"]] = jax.jit(
-                        lambda P, _g=g:
-                        executor_core.PackPlan.group_views(_g, P))
             build_s = time.perf_counter() - tb
-            entry = (compiled, state_names, state_out_names, plan,
-                     unpackers, {})
+            entry = (compiled, state_names, state_out_names)
             if use_cache:
                 self._cache_store(cache_key, entry, mon=mon)
             if mon is not None:
@@ -781,37 +681,7 @@ class Executor:
             mon.mark_cache(not was_miss, fingerprint=fp, level=level,
                            lowered=executor_core.lowered_counts(
                                program, jax_device_for(self.place)))
-        compiled, state_names, state_out_names, plan, unpackers, memo = entry
-
-        if plan is not None:
-            # reuse the previous call's packed buffers when the scope still
-            # holds exactly the views we wrote back (the steady state) —
-            # repacking costs one eager concat per group otherwise. The
-            # views are memoized as WEAK refs (the scope owns them): a dead
-            # ref or identity mismatch means the scope moved on, and the
-            # stale entry is evicted so its packed buffer's HBM is freed
-            # instead of riding in the compile cache forever.
-            packed_in = {}
-            for g in plan.groups:
-                prev = memo.get(g["key"])
-                if prev is not None:
-                    views_prev = [r() for r in prev[1]]
-                    if all(v is not None and scope.find_var(n) is v
-                           for (n, _, _, _), v in zip(g["entries"],
-                                                      views_prev)):
-                        packed_in[g["key"]] = prev[0]
-                    else:
-                        memo.pop(g["key"], None)
-            repack = {n: v for n, v in mut_state.items()
-                      if n in plan.packed_names}
-            mut_state = {n: v for n, v in mut_state.items()
-                         if n not in plan.packed_names}
-            for g in plan.groups:
-                if g["key"] in packed_in:
-                    mut_state[g["key"]] = packed_in[g["key"]]
-                else:
-                    mut_state[g["key"]] = \
-                        executor_core.PackPlan.pack_group(g, repack)
+        compiled, state_names, state_out_names = entry
 
         key = id(program)
         step0 = self._step_counter.get(key, 0)
@@ -820,8 +690,6 @@ class Executor:
         # step0 rides as a traced array to keep the compile cache hot
         rng = (jax.random.PRNGKey(program.random_seed),
                jnp.asarray(step0, jnp.int32))
-        if mon is not None:
-            mon.lap("state_gather")  # the PackPlan repack above
         fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
         hstats = None
         if hplan is not None:
@@ -829,16 +697,6 @@ class Executor:
             fetches = fetches[:-1]
         if mon is not None:
             lap_call(mon, was_miss, build_s, fp, program)
-        if plan is not None:
-            plain = {n: v for n, v in new_mut.items()
-                     if not n.startswith("__packed__")}
-            for g in plan.groups:
-                P = new_mut[g["key"]]
-                views = unpackers[g["key"]](P)
-                for (n, _, _, _), v in zip(g["entries"], views):
-                    plain[n] = v
-                _memoize_packed(memo, g["key"], P, views)
-            new_mut = plain
         for n, v in new_mut.items():
             scope.set_var(n, v)
         if hstats is not None:

@@ -112,27 +112,6 @@ define("debug_nans", bool, False,
        "offending jitted computation op-by-op and raises at the exact "
        "primitive. Heavier than check_nan_inf's step-boundary scan; use "
        "to localize, not in production runs.")
-define("fold_ema_multi_step", bool, False,
-       "Under Executor.run(iters=K), keep batch-norm running statistics "
-       "OUT of the lax.scan carry (they are pure EMA recurrences, read by "
-       "nothing else in a training program) and reconstruct the exact "
-       "K-step fold after the scan. Built to shrink the scan's back-edge "
-       "copy set; showed no gain on ResNet-50 on an older stack (the "
-       "stacked per-step stats + post-scan fold cost what the copies "
-       "saved) and has not been re-measured on the current host. Default "
-       "OFF, kept as an opt-in for topologies with much larger "
-       "normalization state.")
-define("pack_small_state", bool, False,
-       "Under Executor.run(iters=K), carry all small (<=64Ki elems) float "
-       "mut-state entries as ONE concatenated buffer per dtype instead of "
-       "one scan-carry leaf each (core/executor_core.py PackPlan): slices "
-       "fuse into consumers, and the per-parameter optimizer updates "
-       "concatenate into the donated packed carry — the "
-       "aliasing-preserving answer to the suspected launch-bound update "
-       "kernels. Showed no gain on ResNet-50 on an older stack (the time "
-       "of the eliminated kernels reappeared inside the conv fusions); "
-       "not re-measured on the current host. Default OFF; the mechanism "
-       "stays for topologies with far more small state.")
 define("monitor", bool, True,
        "Step-level training telemetry (paddle_tpu.monitor): per-step phase "
        "breakdown, compile-cache hit/miss accounting, datapipe merge, "
@@ -148,10 +127,3 @@ define("compile_cache_cap", int, 0,
        "is evicted (insertion order) and counted in "
        "monitor compile_cache_evictions_total — visibility for workloads "
        "that churn program shapes and silently re-compile.")
-define("fuse_optimizer_ops", bool, False,
-       "Batch identical small-parameter optimizer updates (sgd/momentum) "
-       "into one kernel call over concatenated flats. Default OFF: on an "
-       "older stack the slice-back defeated XLA's in-place donation "
-       "aliasing and ResNet-50 ran slower with it on (not re-measured on "
-       "the current host); kept as an opt-in for topologies dominated by "
-       "thousands of tiny params.")

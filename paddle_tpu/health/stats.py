@@ -68,7 +68,7 @@ class HealthPlan:
         """Wrap a built step fn: consume the appended grad fetches,
         emit one {label: [4]f32} stats dict as a single extra fetch.
 
-        Applied after the wire wrapper and before PackPlan/multi-step
+        Applied after the wire wrapper and before the multi-step
         wrapping, so `mut_state`/`new_mut` carry plain var names and the
         scan stacks only the [4]-element leaves, never raw grads.
         """

@@ -23,7 +23,6 @@ from . import io_ops
 from . import metric_ops
 from . import detection_ops
 from . import collective_ops
-from . import fused_ops
 from . import sparse_ops
 from . import rpc_ops
 from . import reader_ops

@@ -34,7 +34,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import amp
 from . import analysis
 from . import flags
 from . import monitor
@@ -153,10 +152,6 @@ class ParallelExecutor:
         # their ScheduleReport, keyed on (program identity, mutation,
         # bucket bytes); strong refs keep id() stable for the compile cache
         self._overlap_cache = {}
-        # fused clones of the resolved program + their FusionPlan, keyed
-        # on (program identity, mutation, bucket budget, fetches); strong
-        # refs keep id() stable for the compile cache
-        self._fusion_cache = {}
         self._step = 0
         self.num_trainers = num_trainers
         self.trainer_id = trainer_id
@@ -248,25 +243,6 @@ class ParallelExecutor:
                 program, sched.plan, feed_names=feed_names)
             hit = (reordered, sched)
             self._overlap_cache[key] = hit
-        return hit
-
-    def _fuse_program(self, program, feed_names, fetch_names):
-        """Apply cost-guided fusion (paddle_tpu.fusion) to the RESOLVED
-        program — after zero1 and overlap, so optimizer buckets see the
-        final shard-layout wiring, and before autoshard, so the fused
-        ops' operands inherit the plan like any other op. Returns
-        (program', FusionPlan or None); cached per (program identity,
-        mutation, bucket budget, feeds, fetches)."""
-        from . import fusion
-
-        key = (id(program), program._mutation,
-               int(flags.get("fuse_bucket_mb")),
-               tuple(sorted(feed_names or [])), tuple(fetch_names))
-        hit = self._fusion_cache.get(key)
-        if hit is None:
-            hit = fusion.apply(program, feed_names=feed_names,
-                               fetch_names=fetch_names)
-            self._fusion_cache[key] = hit
         return hit
 
     def _autoshard_plan(self, program):
@@ -414,15 +390,6 @@ class ParallelExecutor:
             program, osched = self._overlap_program(
                 program,
                 feed_names=list(feed) if isinstance(feed, dict) else None)
-        # cost-guided fusion (FLAGS_fuse): after zero1/overlap so buckets
-        # see the final wiring, before autoshard so fused operands get
-        # plan layouts like any other op. Digest joins the cache key.
-        fplan = None
-        if flags.get("fuse"):
-            program, fplan = self._fuse_program(
-                program,
-                feed_names=list(feed) if isinstance(feed, dict) else [],
-                fetch_names=fetch_names)
         use_autoshard = bs.auto_sharding
         if use_autoshard is None:
             use_autoshard = bool(flags.get("autoshard"))
@@ -513,7 +480,7 @@ class ParallelExecutor:
                 "reshard_bytes": int(aplan.reshard_bytes_per_step()),
             }
         if mon is not None:
-            # program resolution: zero1 / overlap / fuse / autoshard plans
+            # program resolution: zero1 / overlap / autoshard plans
             # (memoized per program) and their digests for the cache key
             mon.lap("cache_lookup")
         feed_vals = {}
@@ -544,29 +511,20 @@ class ParallelExecutor:
         # the canonical param with its reduce-scattered [N, shard] grad —
         # shard-local reductions, no regather (health/stats.py)
         hplan = _health.plan_if_enabled(program)
-        cache_key = (
-            id(program),
-            program._mutation,
-            tuple(sorted((n, executor_core.spec_of(v)) for n, v in feed_vals.items())),
-            tuple(fetch_names),
-            tuple(state_names),
-            amp.fingerprint(),
-            flags.get("fuse_optimizer_ops"),  # trace-affecting, like amp
-            flags.get("debug_nans"),  # changes donation, like Executor
-            ("iters", iters),
-            ("wire", wire.fingerprint() if wire is not None else None),
-            ("donate_feeds", donate_feeds),
-            ("zero1", use_zero1, gss, dp_n),
-            ("overlap",
-             osched.plan.digest() if osched is not None else None),
-            ("autoshard", aplan.digest() if aplan is not None else None),
-            ("fuse", fplan.digest() if fplan is not None else None),
-            ("health", hplan.digest if hplan is not None else None),
-            # stage programs from parallel.pipeline share var names with
-            # each other and the source program; the (plan digest, stage,
-            # phase) tag keeps their executables from colliding
-            ("pipeline", getattr(program, "_pipeline_stage", None)),
-        )
+        ident, content = executor_core.step_key(
+            program, feed_vals, fetch_names, state_names, iters=iters,
+            wire=wire, donate_feeds=donate_feeds, health=hplan,
+            extra=(
+                ("zero1", use_zero1, gss, dp_n),
+                ("overlap",
+                 osched.plan.digest() if osched is not None else None),
+                ("autoshard", aplan.digest() if aplan is not None else None),
+                # stage programs from parallel.pipeline share var names
+                # with each other and the source program; the (plan digest,
+                # stage, phase) tag keeps their executables from colliding
+                ("pipeline", getattr(program, "_pipeline_stage", None)),
+            ))
+        cache_key = ident + content
         entry = self._compile_cache.get(cache_key)
         fp = None
         if mon is not None:
@@ -598,7 +556,7 @@ class ParallelExecutor:
                         f"the startup program first.")
             cache_obj = self._compile_cache
             digest = cache_obj.l2_digest(
-                program, cache_key[2:], extra=self._l2_extra()) \
+                program, content, extra=self._l2_extra()) \
                 if cache_obj.l2_enabled() else None
 
             def _fresh(export_digest=None):

@@ -136,19 +136,6 @@ def _estimate(block, op, batch):
         in_shape = _shape_of(block, ins[0], batch) if ins else None
         return float(_numel(in_shape, batch)) if in_shape is not None \
             else float(out_elems)
-    if t == "fused_elementwise":
-        # the collapsed chain does every sub-op's arithmetic in one pass
-        subs = op.attrs.get("sub_types") or ()
-        return sum(_ELEM_WEIGHTS.get(s, 1.0) for s in subs) * out_elems
-    if t in ("fused_sgd_update", "fused_momentum_update",
-             "fused_adam_update"):
-        # per-element update cost x total bucket payload
-        per = {"fused_sgd_update": 2.0, "fused_momentum_update": 5.0,
-               "fused_adam_update": 12.0}[t]
-        total = 0.0
-        for nm in (op.input("Param") or []):
-            total += _numel(_shape_of(block, nm, batch), batch)
-        return per * max(1.0, total)
     if t.endswith("_grad"):
         # grad ops roughly mirror the forward cost for input grads plus
         # a comparable pass for parameter grads

@@ -19,8 +19,8 @@ import paddle_tpu as fluid
 from paddle_tpu import analysis, flags
 from paddle_tpu.analysis import (ProgramVerificationError, dataflow,
                                  schedule)
-from paddle_tpu.core.framework import (OpRole, OP_ROLE_ATTR_NAME, Program,
-                                       program_guard)
+from paddle_tpu.core.framework import (OpRole, OP_ROLE_ATTR_NAME, Operator,
+                                       Program, program_guard)
 from paddle_tpu.parallel import zero1
 from paddle_tpu.parallel import autoshard
 
@@ -724,19 +724,105 @@ def test_schedule_apply_plan_reorders_and_reverifies():
         [str(dd) for dd in full.diagnostics]
 
 
-def test_schedule_rejects_hazardous_program():
-    rewritten, _, feeds, _ = _zero1_program()
-    gb = rewritten.global_block()
-    gat = next(op for op in gb.ops if op.type == "zero1_gather")
+# one seeded illegal mutation per PTA03x class; each returns the program
+def _seed_cycle_pta030():
+    main, _, _ = _mlp()
+    gb = main.global_block()
+    for nm in ("cyc_a", "cyc_b"):
+        gb.create_var(name=nm, shape=[1], dtype="float32")
+    gb.append_op(type="scale", inputs={"X": ["cyc_b"]},
+                 outputs={"Out": ["cyc_a"]}, attrs={"scale": 1.0})
+    gb.append_op(type="scale", inputs={"X": ["cyc_a"]},
+                 outputs={"Out": ["cyc_b"]}, attrs={"scale": 1.0})
+    return main
+
+
+def _seed_clobbered_forward_pta031():
+    """In-place overwrite of a forward activation between forward and
+    backward: the grad op now reads a later SSA version (WAR)."""
+    main, _, _ = _mlp()
+    gb = main.global_block()
+    for i, op in enumerate(gb.ops):
+        if not op.type.endswith("_grad"):
+            continue
+        grad_reads = {n for ns in op.inputs.values() for n in ns
+                      if not n.endswith("@GRAD")}
+        for j in range(i - 1, -1, -1):
+            fwd = gb.ops[j]
+            if fwd.type != op.type[:-len("_grad")]:
+                continue
+            shared = [n for ns in fwd.inputs.values() for n in ns
+                      if n in grad_reads and not gb.vars[n].persistable]
+            if shared:
+                gb.ops.insert(j + 1, Operator(
+                    gb, "scale", {"X": [shared[0]]}, {"Out": [shared[0]]},
+                    {"scale": 1.0}))
+                main._mutation += 1
+                return main
+    pytest.fail("found no forward/grad pair sharing a non-persistable "
+                "input to clobber")
+
+
+def _weight(gb):
+    return next(n for n, v in gb.vars.items()
+                if getattr(v, "persistable", False) and n.endswith(".w_0"))
+
+
+def _seed_double_weight_write_pta032():
+    main, _, _ = _mlp()
+    gb = main.global_block()
+    w = _weight(gb)
+    gb.append_op(type="scale", inputs={"X": [w]}, outputs={"Out": [w]},
+                 attrs={"scale": 1.0})
+    return main
+
+
+def _seed_gather_rewire_pta033():
+    rewritten, _, _, _ = _zero1_program()
+    gat = next(op for op in rewritten.global_block().ops
+               if op.type == "zero1_gather")
     pupd = gat.input("X")[0]
     gat.rename_input(pupd, pupd.replace("@zero1_upd", "@zero1_shard"))
     rewritten._mutation += 1
-    with pytest.raises(ProgramVerificationError) as ei:
-        schedule.analyze(rewritten, mesh_axes={"dp": 8},
-                         feed_names=feeds)
-    assert "PTA033" in ei.value.report.codes()
-    with pytest.raises(ProgramVerificationError):
-        schedule.apply_plan(rewritten, feed_names=feeds)
+    return rewritten
+
+
+def _seed_stale_donated_view_pta034():
+    """A reshape view of a weight captured before the optimizer update,
+    read after it: stale alias of a donated buffer."""
+    main, _, _ = _mlp()
+    gb = main.global_block()
+    w = _weight(gb)
+    numel = int(np.prod(gb.vars[w].shape))
+    gb.create_var(name="w_view", shape=[numel], dtype="float32")
+    gb.create_var(name="w_stale", shape=[numel], dtype="float32")
+    gb.ops.insert(0, Operator(gb, "reshape", {"X": [w]},
+                              {"Out": ["w_view"]}, {"shape": [numel]}))
+    gb.ops.append(Operator(gb, "scale", {"X": ["w_view"]},
+                           {"Out": ["w_stale"]}, {"scale": 1.0}))
+    main._mutation += 1
+    return main
+
+
+@pytest.mark.parametrize("code,seed", [
+    ("PTA030", _seed_cycle_pta030),
+    ("PTA031", _seed_clobbered_forward_pta031),
+    ("PTA032", _seed_double_weight_write_pta032),
+    ("PTA033", _seed_gather_rewire_pta033),
+    ("PTA034", _seed_stale_donated_view_pta034),
+], ids=["PTA030", "PTA031", "PTA032", "PTA033", "PTA034"])
+def test_overlap_refuses_hazardous_source(code, seed):
+    """The program rewrite the executors apply (the overlap schedule) never
+    touches a program that carries a dataflow hazard: `analyze` has no
+    schedule for it and `apply_plan` refuses to reorder it."""
+    program = seed()
+    for rewrite in (
+            lambda: schedule.analyze(program, mesh_axes={"dp": 8},
+                                     feed_names=["x", "y"]),
+            lambda: schedule.apply_plan(program, feed_names=["x", "y"])):
+        with pytest.raises(ProgramVerificationError) as ei:
+            rewrite()
+        assert code in ei.value.report.codes()
 
 
 def test_schedule_bucket_bytes_knob_changes_plan():

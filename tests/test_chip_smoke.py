@@ -66,7 +66,7 @@ def test_rehearsal_kernels_are_interpreted_off_the_chip(rehearsal):
     assert kernels["mosaic_expected"] is False
     assert all(kernels["checks"].values()), kernels["checks"]
     assert not any(c["mosaic"] for c in kernels["flash_attention"])
-    assert kernels["fused_adam_update"]["steps_captured"] >= 1
+    assert kernels["checks"].keys() == {"flash_attention"}
 
 
 def test_rehearsal_multichip_phase_on_the_virtual_mesh(rehearsal):

@@ -614,3 +614,139 @@ def test_l2_hit_journals_as_hit_with_cache_load_phase(tmp_path):
     assert warm.get("cache_level") == "l2", warm
     assert "cache_load" in warm["phases_ms"], warm
     assert "compile" not in warm["phases_ms"], warm
+
+
+# ---------------------------------------------------------------------------
+# the compile key (executor_core.step_key): one case per site and ingredient
+# ---------------------------------------------------------------------------
+_COMMON = ("feed_shape", "fetch_list", "amp", "debug_nans", "wire",
+           "donate_feeds", "health")
+_KEY_CASES = ([("exe", i) for i in _COMMON]
+              + [("scan", i) for i in _COMMON + ("iters",)]
+              + [("pe", i) for i in _COMMON + ("iters", "zero1",
+                                               "grad_scale", "autoshard")])
+# what each ingredient is flipped to, from the base configuration below
+_FLIPS = {
+    "feed_shape": {"batch": 16},
+    "fetch_list": {"fetch": ("loss", "pred")},
+    "amp": {"amp": "bfloat16"},
+    "debug_nans": {"debug_nans": True},
+    "wire": {"wire": "affine"},        # from the cast-only wire, see below
+    "donate_feeds": {"donate": True},
+    "health": {"health": 1},
+    "iters": {"iters": 3},
+    "zero1": {"zero1": True},
+    "grad_scale": {"gss": fluid.BuildStrategy.GradientScaleStrategy.One},
+    "autoshard": {"autoshard": True},
+}
+
+
+def _key_net():
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        h = fluid.layers.fc(input=x, size=8, act="relu")
+        pred = fluid.layers.fc(input=h, size=1)
+        loss = fluid.layers.mean(
+            fluid.layers.square_error_cost(input=pred, label=y))
+        fluid.optimizer.Momentum(learning_rate=0.01,
+                                 momentum=0.9).minimize(loss)
+    return main, startup, {"loss": loss.name, "pred": pred.name}
+
+
+def _run_configured(target, main, names, cfg):
+    """One run of `main` on `target` (an Executor or a ParallelExecutor)
+    under the configuration `cfg`."""
+    from paddle_tpu import amp
+    from paddle_tpu.datapipe import WIRE_KEY, WireFormat, WireSpec
+    from paddle_tpu.executor import _apply_debug_nans
+
+    K, batch = cfg["iters"], cfg["batch"]
+    lead = (batch,) if K is None else (K, batch)
+    rs = np.random.RandomState(0)
+    if cfg["wire"]:
+        feed = {"x": rs.randint(0, 255, lead + (8,)).astype(np.uint8),
+                WIRE_KEY: (WireSpec({"x": WireFormat("uint8")})
+                           if cfg["wire"] == "cast"
+                           else WireSpec.uint8_images("x"))}
+    else:
+        feed = {"x": rs.randn(*lead, 8).astype(np.float32)}
+    feed["y"] = rs.randn(*lead, 1).astype(np.float32)
+    fetch = [names[n] for n in cfg["fetch"]]
+    if cfg["amp"]:
+        amp.enable(cfg["amp"])
+    try:
+        with flags.flag_guard(debug_nans=cfg["debug_nans"],
+                              health=cfg["health"]):
+            if isinstance(target, fluid.ParallelExecutor):
+                bs = target._build_strategy
+                bs.sharded_weight_update = cfg["zero1"]
+                bs.gradient_scale_strategy = cfg["gss"]
+                bs.auto_sharding = cfg["autoshard"]
+                target.run(fetch, feed=feed, iters=K,
+                           donate_feeds=cfg["donate"])
+            else:
+                target.run(main, feed=feed, fetch_list=fetch, iters=K,
+                           donate_feeds=cfg["donate"])
+    finally:
+        amp.disable()
+        _apply_debug_nans()     # jax's own switch follows the flag back
+
+
+@pytest.mark.parametrize("site,ingredient", _KEY_CASES,
+                         ids=[f"{s}-{i}" for s, i in _KEY_CASES])
+def test_step_key_ingredient_misses_then_hits(site, ingredient):
+    """At each of the three run bodies (Executor single step, Executor
+    iters=K, ParallelExecutor on the virtual mesh), flipping ONE
+    trace-affecting input compiles exactly one more step (one more L1
+    entry), and coming back to either configuration compiles none: the
+    input is in the key, and nothing unstable is."""
+    base = {"batch": 8, "fetch": ("loss",), "amp": None,
+            "debug_nans": False, "wire": None, "donate": False,
+            "health": 0, "iters": None if site == "exe" else 2,
+            "zero1": False, "autoshard": False,
+            "gss": fluid.BuildStrategy.GradientScaleStrategy.CoeffNumDevice}
+    if ingredient == "wire":
+        base["wire"] = "cast"   # uint8 feeds in both, only the spec differs
+    flipped = dict(base, **_FLIPS[ingredient])
+    main, startup, names = _key_net()
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        target = exe if site != "pe" else fluid.ParallelExecutor(
+            use_cuda=False, loss_name=names["loss"], main_program=main)
+
+        def entries():
+            return target.compile_cache_info()["entries"]
+
+        n0 = entries()
+        for cfg, want in ((base, 1), (flipped, 2), (base, 2), (flipped, 2)):
+            _run_configured(target, main, names, cfg)
+            assert entries() == n0 + want, (cfg, entries() - n0)
+
+
+def test_step_key_content_is_stable_primitives():
+    """The content part goes to the L2 digest as it is: no id(), no
+    object, and the identity part alone names the program."""
+    from paddle_tpu.core import executor_core
+
+    main, _, names = _key_net()
+    feed = {"x": np.zeros((4, 8), np.float32)}
+    ident, content = executor_core.step_key(
+        main, feed, [names["loss"]], ["w"], iters=2,
+        extra=(("zero1", True, 0, 8),))
+    assert ident == (id(main), main._mutation)
+
+    def primitive(v):
+        return v is None or isinstance(v, (str, int, float, bool)) or (
+            isinstance(v, tuple) and all(primitive(e) for e in v))
+
+    assert primitive(content)
+    assert ("iters", 2) in content and ("zero1", True, 0, 8) in content
+    assert stable_digest(main, content) == stable_digest(
+        main.clone(), executor_core.step_key(
+            main.clone(), feed, [names["loss"]], ["w"], iters=2,
+            extra=(("zero1", True, 0, 8),))[1])

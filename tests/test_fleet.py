@@ -770,6 +770,7 @@ def test_cli_replica_master_sigterm_drains_and_exits_clean(
 
     from paddle_tpu.cli import main as cli_main
     from paddle_tpu.parallel.master import MasterClient, MasterService
+    from paddle_tpu.serve import http as serve_http
 
     prog, startup, y = _fc_program()
     exe = fluid.Executor(fluid.CPUPlace())
@@ -784,6 +785,15 @@ def test_cli_replica_master_sigterm_drains_and_exits_clean(
     monkeypatch.setattr(  # signal.signal only works on the main thread
         _signal, "signal",
         lambda signum, handler: captured.__setitem__(signum, handler))
+    # a request is ACCEPTED once the HTTP loop took its connection and a
+    # handler began on it; counted here, on the server's side
+    accepted, real_post = [], serve_http._Handler.do_POST
+
+    def counting_post(handler):
+        accepted.append(handler.path)
+        real_post(handler)
+
+    monkeypatch.setattr(serve_http._Handler, "do_POST", counting_post)
 
     pf = tmp_path / "port"
     rc = []
@@ -799,10 +809,13 @@ def test_cli_replica_master_sigterm_drains_and_exits_clean(
         while not pf.exists() and time.time() < deadline:
             time.sleep(0.05)
         endpoint = f"127.0.0.1:{pf.read_text().strip()}"
-        while "hb0" not in probe.lookup("serve") \
-                and time.time() < deadline:
+        # the lookup that sees the registration is the one asserted on: a
+        # second one could find the 1 s lease lapsed on a stalled host
+        seen = probe.lookup("serve")
+        while "hb0" not in seen and time.time() < deadline:
             time.sleep(0.05)
-        assert probe.lookup("serve") == {"hb0": endpoint}
+            seen = probe.lookup("serve")
+        assert seen == {"hb0": endpoint}
         assert _signal.SIGTERM in captured
 
         codes, lock = [], threading.Lock()
@@ -816,8 +829,12 @@ def test_cli_replica_master_sigterm_drains_and_exits_clean(
                     code = resp.status
             except urllib.error.HTTPError as e:
                 code = e.code
-            except urllib.error.URLError:
-                code = "refused"  # listener already gone: never accepted
+            except (urllib.error.URLError, ConnectionError):
+                # never accepted: the listener was gone at connect(), or
+                # the connection sat in the listen backlog when the loop
+                # stopped and was reset as the socket closed (urllib wraps
+                # errors of connect() only, not of reading the status)
+                code = "refused"
             with lock:
                 codes.append(code)
 
@@ -836,6 +853,7 @@ def test_cli_replica_master_sigterm_drains_and_exits_clean(
         # 503 (draining) or a refused connect for rejected admissions —
         # an ACCEPTED request is never dropped
         assert len(codes) == 9 and set(codes) <= {200, 503, "refused"}
+        assert len(accepted) == sum(c != "refused" for c in codes)
         # the master survived its client's departure...
         assert isinstance(probe.counts(), dict)
         # ...and the lease lapses now that the beats stopped

@@ -74,9 +74,19 @@ def test_mesh_helpers():
         assert current_mesh() is mm
 
 
-def test_parallel_executor_matches_single_device():
+_PE_OPTIMIZERS = {
+    "sgd": lambda: fluid.optimizer.SGD(learning_rate=0.05),
+    "momentum": lambda: fluid.optimizer.Momentum(learning_rate=0.05,
+                                                 momentum=0.9),
+    "adam": lambda: fluid.optimizer.Adam(learning_rate=0.01),
+}
+
+
+@pytest.mark.parametrize("opt", list(_PE_OPTIMIZERS))
+def test_parallel_executor_matches_single_device(opt):
     """reference parallel_executor_test_base.check_network_convergence:
-    same net, Executor vs ParallelExecutor, losses must track."""
+    same net, Executor vs ParallelExecutor, losses must track, whatever
+    per-parameter update the optimizer appends."""
 
     def build():
         img = fluid.layers.data(name="img", shape=[32], dtype="float32")
@@ -85,7 +95,7 @@ def test_parallel_executor_matches_single_device():
         p = fluid.layers.fc(input=h, size=4, act="softmax")
         loss = fluid.layers.mean(
             fluid.layers.cross_entropy(input=p, label=label))
-        fluid.optimizer.SGD(learning_rate=0.05).minimize(loss)
+        _PE_OPTIMIZERS[opt]().minimize(loss)
         return loss
 
     rs = np.random.RandomState(0)
@@ -133,9 +143,11 @@ def test_collective_ops_single_device_identity():
     np.testing.assert_allclose(np.asarray(res["Out"][0]), np.arange(4.0))
 
 
-def test_parallel_executor_iters_scan():
+@pytest.mark.parametrize("opt", list(_PE_OPTIMIZERS))
+def test_parallel_executor_iters_scan(opt):
     """PE(iters=K): K data-parallel steps in one mesh dispatch must match
-    K sequential PE.run calls (same losses, same final params)."""
+    K sequential PE.run calls: same losses, and the same value in every
+    persistable var (the weights and the optimizer's accumulators)."""
     import paddle_tpu as fluid
 
     def build():
@@ -146,8 +158,14 @@ def test_parallel_executor_iters_scan():
             p = fluid.layers.fc(input=x, size=1)
             loss = fluid.layers.mean(
                 fluid.layers.square_error_cost(input=p, label=y))
-            fluid.optimizer.SGD(learning_rate=0.05).minimize(loss)
+            _PE_OPTIMIZERS[opt]().minimize(loss)
         return main, startup, loss
+
+    def persistables(main, scope):
+        return {n: np.asarray(fluid.executor._ensure_addressable(
+                    scope.find_var(n)))
+                for n, v in main.global_block().vars.items()
+                if v.persistable and scope.has_var(n)}
 
     K = 4
     rs = np.random.RandomState(2)
@@ -162,8 +180,7 @@ def test_parallel_executor_iters_scan():
                                     main_program=main)
         seq = [float(np.asarray(pe.run([loss.name], feed=f)[0]).mean())
                for f in feeds]
-        w_seq = np.asarray(fluid.executor._ensure_addressable(
-            sc1.find_var("fc_0.w_0")))
+        state_seq = persistables(main, sc1)
 
     main2, startup2, loss2 = build()
     sc2 = fluid.Scope()
@@ -173,8 +190,10 @@ def test_parallel_executor_iters_scan():
                                     main_program=main2)
         out, = pe.run([loss2.name], feed=feeds, iters=K)
         scan = np.asarray(out).reshape(-1)
-        w_scan = np.asarray(fluid.executor._ensure_addressable(
-            sc2.find_var("fc_0.w_0")))
+        state_scan = persistables(main2, sc2)
 
     np.testing.assert_allclose(scan, seq, rtol=2e-4, atol=1e-5)
-    np.testing.assert_allclose(w_scan, w_seq, rtol=2e-4, atol=1e-5)
+    assert set(state_scan) == set(state_seq) and len(state_seq) >= 3
+    for n in state_seq:
+        np.testing.assert_allclose(state_scan[n], state_seq[n], rtol=2e-4,
+                                   atol=1e-5, err_msg=n)

@@ -13,7 +13,7 @@ import pytest
 import paddle_tpu as fluid
 
 
-def _build_train(seed=7):
+def _build_train(seed=7, optimizer=None):
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.layers.data(name="x", shape=[8], dtype="float32")
@@ -22,7 +22,7 @@ def _build_train(seed=7):
         p = fluid.layers.fc(input=h, size=4, act="softmax")
         loss = fluid.layers.mean(
             fluid.layers.cross_entropy(input=p, label=label))
-        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        (optimizer or fluid.optimizer.SGD(learning_rate=0.1)).minimize(loss)
     return main, startup, loss
 
 
@@ -35,36 +35,77 @@ def _feeds(k, bs=8, seed=0):
     ]
 
 
-def test_iters_matches_sequential_steps():
-    """K steps in one scan dispatch == K sequential exe.run calls: same
-    per-step losses, same final parameters."""
+_OPTIMIZERS = {
+    "sgd": lambda: fluid.optimizer.SGD(learning_rate=0.1),
+    "momentum": lambda: fluid.optimizer.Momentum(learning_rate=0.1,
+                                                 momentum=0.9),
+    "adagrad": lambda: fluid.optimizer.Adagrad(learning_rate=0.1),
+    "adam": lambda: fluid.optimizer.Adam(learning_rate=0.01),
+    "adamax": lambda: fluid.optimizer.Adamax(learning_rate=0.01),
+    "decayed_adagrad": lambda: fluid.optimizer.DecayedAdagrad(
+        learning_rate=0.1),
+    "rmsprop": lambda: fluid.optimizer.RMSProp(learning_rate=0.01),
+    "ftrl": lambda: fluid.optimizer.Ftrl(learning_rate=0.1),
+    "adamw": lambda: fluid.optimizer.Adam(learning_rate=0.01,
+                                          weight_decay=0.1),
+}
+
+
+def _persistables(main, scope):
+    """Every persistable var of `main` that the scope holds, on the host
+    (parameters, optimizer accumulators, beta powers, the learning
+    rate)."""
+    return {n: np.asarray(scope.find_var(n))
+            for n, v in main.global_block().vars.items()
+            if v.persistable and scope.has_var(n)}
+
+
+@pytest.mark.parametrize(
+    "opt,amp_dtype",
+    [(o, None) for o in _OPTIMIZERS]
+    + [("momentum", "bfloat16"), ("adam", "bfloat16")],
+    ids=list(_OPTIMIZERS) + ["momentum-bf16_amp", "adam-bf16_amp"])
+def test_iters_matches_sequential_steps(opt, amp_dtype):
+    """K steps in one scan dispatch == K sequential exe.run calls: the
+    same per-step losses and the same value in EVERY persistable var
+    (weights, each optimizer's accumulators), per optimizer; under bf16
+    AMP the float32 master weights and accumulators across the scan."""
+    from paddle_tpu import amp
+
     K = 5
     feeds = _feeds(K)
 
-    main, startup, loss = _build_train()
-    sc1 = fluid.Scope()
-    with fluid.scope_guard(sc1):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        seq_losses = [
-            float(np.asarray(exe.run(main, feed=f,
-                                     fetch_list=[loss])[0]).item())
-            for f in feeds
-        ]
-        w_seq = np.asarray(fluid.fetch_var("fc_0.w_0", sc1))
+    def run(scan):
+        main, startup, loss = _build_train(optimizer=_OPTIMIZERS[opt]())
+        sc = fluid.Scope()
+        with fluid.scope_guard(sc):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            if scan:
+                out, = exe.run(main, feed=feeds, fetch_list=[loss], iters=K)
+                losses = np.asarray(out, np.float32).reshape(-1)
+            else:
+                losses = np.asarray(
+                    [np.asarray(exe.run(main, feed=f, fetch_list=[loss])[0],
+                                np.float32).item() for f in feeds])
+            return losses, _persistables(main, sc)
 
-    main2, startup2, loss2 = _build_train()
-    sc2 = fluid.Scope()
-    with fluid.scope_guard(sc2):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup2)
-        out, = exe.run(main2, feed=feeds, fetch_list=[loss2], iters=K)
-        scan_losses = np.asarray(out).reshape(-1)
-        w_scan = np.asarray(fluid.fetch_var("fc_0.w_0", sc2))
+    if amp_dtype:
+        amp.enable(amp_dtype)
+    try:
+        seq_losses, seq_state = run(scan=False)
+        scan_losses, scan_state = run(scan=True)
+    finally:
+        amp.disable()
 
     assert scan_losses.shape[0] == K
     np.testing.assert_allclose(scan_losses, seq_losses, rtol=2e-4, atol=1e-5)
-    np.testing.assert_allclose(w_scan, w_seq, rtol=2e-4, atol=1e-5)
+    assert set(scan_state) == set(seq_state) and len(seq_state) >= 5
+    for n in seq_state:
+        # masters and accumulators stay float32, under AMP too
+        assert scan_state[n].dtype == seq_state[n].dtype == np.float32, n
+        np.testing.assert_allclose(scan_state[n], seq_state[n], rtol=2e-4,
+                                   atol=1e-5, err_msg=n)
 
 
 def test_iters_prestacked_device_feed():
@@ -235,13 +276,9 @@ def test_double_buffer_reader_stages_to_device():
     assert r.read_next() is None
 
 
-def test_iters_ema_fold_matches_sequential_running_stats():
-    """FLAGS_fold_ema_multi_step keeps BN running stats out of the scan
-    carry and reconstructs the exact K-step EMA fold after the scan
-    (executor_core.collect_ema_states): K=5 under one iters=5 dispatch must
-    leave the SAME running statistics as 5 sequential run() calls."""
-    import paddle_tpu as fluid
-    from paddle_tpu.core import executor_core
+def test_iters_running_stats_match_sequential_steps():
+    """Batch-norm running statistics ride the scan carry: after one
+    iters=5 dispatch they equal those of 5 sequential run() calls."""
 
     def build():
         main, startup = fluid.Program(), fluid.Program()
@@ -272,9 +309,6 @@ def test_iters_ema_fold_matches_sequential_running_stats():
     with fluid.scope_guard(s2):
         e = fluid.Executor(fluid.CPUPlace())
         e.run(startup2)
-        _, son = executor_core.collect_state_names(main2, s2)
-        ema = executor_core.collect_ema_states(main2, son, [])
-        assert len(ema) == 2, ema  # MeanOut + VarianceOut of the one BN
         out, = e.run(main2, feed=feeds, fetch_list=[loss2], iters=5)
         stats2 = {n: np.asarray(s2.find_var(n))
                   for n in s2.local_var_names() if "batch_norm" in n}
@@ -343,13 +377,10 @@ def test_bucketed_seq_tensor_parity_and_iters():
     np.testing.assert_allclose(exact, k_losses, rtol=2e-5)
 
 
-def test_pack_small_state_parity():
-    """FLAGS_pack_small_state carries small float state as one packed
-    buffer per dtype inside the iters=K scan (executor_core.PackPlan):
-    losses AND every scope var must match the unpacked path across two
-    calls (the second exercises the packed-buffer memo reuse)."""
-    import paddle_tpu as fluid
-    from paddle_tpu import flags
+def test_two_scan_chunks_match_six_sequential_steps():
+    """Two chunks of iters=3 equal six sequential steps on a conv + BN +
+    Momentum net: the losses and EVERY scope var (the second chunk starts
+    from the state the first one wrote back)."""
 
     def build():
         main, startup = fluid.Program(), fluid.Program()
@@ -370,16 +401,23 @@ def test_pack_small_state_parity():
     feeds = [{"x": np.random.RandomState(i).randn(4, 3, 6, 6)
               .astype("float32")} for i in range(6)]
 
-    def run(pack):
+    def run(chunked):
         main, startup, loss = build()
         s = fluid.Scope()
-        with fluid.scope_guard(s), flags.flag_guard(pack_small_state=pack):
+        with fluid.scope_guard(s):
             e = fluid.Executor(fluid.CPUPlace())
             e.run(startup)
-            out1, = e.run(main, feed=feeds[:3], fetch_list=[loss], iters=3)
-            out2, = e.run(main, feed=feeds[3:], fetch_list=[loss], iters=3)
-            vals = list(np.asarray(out1).reshape(-1)) + \
-                list(np.asarray(out2).reshape(-1))
+            if chunked:
+                out1, = e.run(main, feed=feeds[:3], fetch_list=[loss],
+                              iters=3)
+                out2, = e.run(main, feed=feeds[3:], fetch_list=[loss],
+                              iters=3)
+                vals = list(np.asarray(out1).reshape(-1)) + \
+                    list(np.asarray(out2).reshape(-1))
+            else:
+                vals = [np.asarray(e.run(main, feed=f,
+                                         fetch_list=[loss])[0]).item()
+                        for f in feeds]
             state = {n: np.asarray(s.find_var(n))
                      for n in s.local_var_names()
                      if hasattr(s.find_var(n), "shape")}
@@ -388,48 +426,7 @@ def test_pack_small_state_parity():
     v0, st0 = run(False)
     v1, st1 = run(True)
     np.testing.assert_allclose(v0, v1, rtol=2e-5)
-    assert set(st0) == set(st1)
+    assert set(st0) == set(st1) and len(st0) >= 8
     for n in st0:
         np.testing.assert_allclose(st0[n], st1[n], rtol=1e-4, atol=1e-6,
                                    err_msg=n)
-
-
-def test_pack_small_state_memo_releases_dead_scope_buffers():
-    """The packed-buffer reuse memo must hold the scope's unpacked views as
-    WEAK refs: once the scope (the strong owner) is dropped, every memo
-    entry — and with it the packed device buffer — must be evicted instead
-    of riding in the executor's compile cache forever."""
-    import gc
-    import paddle_tpu as fluid
-    from paddle_tpu import flags
-
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 11
-    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
-        h = fluid.layers.fc(input=x, size=4, act="tanh")
-        loss = fluid.layers.mean(h)
-        fluid.optimizer.Momentum(learning_rate=0.01,
-                                 momentum=0.9).minimize(loss)
-    feeds = [{"x": np.random.RandomState(i).randn(2, 4).astype("float32")}
-             for i in range(4)]
-
-    with flags.flag_guard(pack_small_state=True):
-        e = fluid.Executor(fluid.CPUPlace())
-        s = fluid.Scope()
-        with fluid.scope_guard(s):
-            e.run(startup)
-            e.run(main, feed=feeds[:2], fetch_list=[loss], iters=2)
-        memos = [en[5] for en in e._compile_cache.values()
-                 if len(en) == 6 and en[3] is not None]
-        assert memos and any(memos), "pack plan produced no memoized groups"
-        with fluid.scope_guard(s):
-            # steady state: the second call reuses the memoized buffers and
-            # re-memoizes its own generation without error
-            e.run(main, feed=feeds[2:], fetch_list=[loss], iters=2)
-        assert any(memos)
-        del s
-        gc.collect()
-        gc.collect()
-        assert all(not m for m in memos), \
-            "memo still pins packed buffers after the owning scope died"

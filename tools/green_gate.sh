@@ -455,23 +455,6 @@ h = result.get("health")
 assert h is not None, result
 assert h["off_delta_ok"], h
 assert h["on_overhead_ok"], h
-# cost-guided fusion A/B (FLAGS_fuse): fused bucketed weight update must
-# be BITWISE loss-identical, collapse per-step optimizer ops >= 5x, and
-# the fused warm step must be no slower than unfused (same <=1%/0.25ms
-# jitter-floored gate as trace/overlap — NOT a raw percent compare: the
-# CPU pallas interpreter adds sub-ms constant overhead a TPU never sees)
-f = result.get("fusion")
-assert f is not None, result.get("fusion_error", result)
-assert f["loss_parity_max_abs_diff"] == 0.0, f
-assert f["parity_bitwise"], f
-assert f["optimizer_op_reduction_x"] >= 5.0, f
-assert f["op_count_after"] < f["op_count_before"], f
-assert f["buckets"] and f["plan_digest"], f
-assert f["on_delta_ok"], f
-# the cost attribution table must rank the fused update among the
-# slowest ops of the fused program (satellite: trace/costs entries)
-assert f["slowest_ops_unfused"] and f["slowest_ops_fused"], f
-assert any(r["op"].startswith("fused_") for r in f["slowest_ops_fused"]), f
 # persistent AOT cache: the warm child (same cache dir, new process) must
 # compile nothing, match the cold first loss bitwise, and have loaded
 # every executable from the L2 store the cold child populated
@@ -491,35 +474,6 @@ print("bench --dry: ok")
 '
 if [ $? -ne 0 ]; then
     echo "GATE: BENCH --dry RED — do not commit" >&2
-    exit 1
-fi
-
-# fusion regression wiring: the fusion A/B keys must flow through the
-# bench --compare engine with the right directions — a self-compare is
-# clean, and a seeded >5% fused-step-time regression (prior artifact made
-# 2x faster) MUST come back flagged on fusion.fused_step_ms. This is what
-# makes `bench.py --dry --compare PRIOR.json` catch real fusion
-# regressions in CI without re-running the whole dry suite here.
-printf '%s' "$dry_out" | JAX_PLATFORMS=cpu python -c '
-import copy, json, sys
-import bench
-result = json.loads(sys.stdin.read())
-f = result["fusion"]
-self_cmp = bench.bench_compare({"fusion": f}, {"fusion": f})
-assert not self_cmp["regressions"], self_cmp
-scored = self_cmp["keys"]
-assert "fusion.fused_step_ms" in scored, sorted(scored)
-assert scored["fusion.fused_step_ms"]["direction"] == "lower", scored
-assert "fusion.unfused_step_ms" in scored, sorted(scored)
-prior = copy.deepcopy({"fusion": f})
-prior["fusion"]["fused_step_ms"] = f["fused_step_ms"] / 2.0
-cmp = bench.bench_compare({"fusion": f}, prior, threshold=0.05)
-assert "fusion.fused_step_ms" in cmp["regressions"], cmp
-print("fusion compare wiring: ok "
-      f"({len(scored)} direction-scored fusion keys)")
-'
-if [ $? -ne 0 ]; then
-    echo "GATE: FUSION COMPARE WIRING RED — do not commit" >&2
     exit 1
 fi
 
@@ -796,16 +750,6 @@ fi
 JAX_PLATFORMS=cpu python -m paddle_tpu analyze pipeline --selftest --quiet
 if [ $? -ne 0 ]; then
     echo "GATE: ANALYZE PIPELINE SELFTEST RED — do not commit" >&2
-    exit 1
-fi
-
-# analyze fusion CLI selftest: buckets the demo training net's adam
-# update (>= 2 members, fused clone re-verified at level=full), collapses
-# the demo inference elementwise chain, and REFUSES a seeded cyclic
-# source program with PTA030 — fusion never runs on a hazardous graph
-JAX_PLATFORMS=cpu python -m paddle_tpu analyze fusion --selftest --quiet
-if [ $? -ne 0 ]; then
-    echo "GATE: ANALYZE FUSION SELFTEST RED — do not commit" >&2
     exit 1
 fi
 
