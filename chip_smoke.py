@@ -16,7 +16,9 @@ per chip, bf16 AMP with fp32 masters, Momentum; weights random from a seed):
              POST /v1/infer, compared with Executor.run on the same rows.
   kernels    the flash-attention Pallas kernels (forward, dK/dV, dQ)
              compiled by Mosaic (the lowered text must hold the TPU custom
-             calls) and compared with a float32 jax.numpy reference.
+             calls) and compared with a float32 jax.numpy reference; the
+             grouped-matmul kernels (forward, d lhs, d rhs) at reduced
+             rows with uneven groups, compared with lax.ragged_dot.
   multichip  with more than one local chip: the same ResNet-50 through
              ParallelExecutor over all of them, then one dp x mp + ZeRO-1
              step. On one chip the result says "not run: 1 device".
@@ -78,6 +80,9 @@ class Sizes:
     # op picks (ops/lm_ops.py), and a row that the kernel's default block
     # does not divide (padded queries and keys, the last key block masked)
     flash: tuple = (((2, 16, 4096, 128), 1024), ((2, 16, 1000, 128), 256))
+    # (rows, K, M, groups): the cell's gate / up and down products at an
+    # eighth of its rows, tiles as `grouped.tiles_for` picks them
+    grouped: tuple = ((8192, 2048, 1024, 64), (8192, 1024, 2048, 64))
 
 
 FULL = Sizes()
@@ -87,7 +92,8 @@ FULL = Sizes()
 TINY = Sizes(depth=18, classes=16, image=32, batch=8, k=2, chunks=4,
              distinct=2, serve_requests=8,
              first_losses_ref=(4.1299, 3.4761), first_loss_tol=0.3,
-             flash=(((1, 2, 128, 64), 64), ((1, 2, 100, 64), 32)))
+             flash=(((1, 2, 128, 64), 64), ((1, 2, 100, 64), 32)),
+             grouped=((384, 128, 256, 8),))
 
 
 def say(msg):
@@ -481,6 +487,12 @@ def _dense_attention(q, k, v, causal):
     return (p / jnp.sum(p, axis=-1, keepdims=True)) @ v
 
 
+def _rel_err(a, b):
+    """max |a - b| over max |b|, in float32."""
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
 def _flash_case(shape, block, want_mosaic):
     import jax
     import jax.numpy as jnp
@@ -515,12 +527,8 @@ def _flash_case(shape, block, want_mosaic):
         (_, ref), ref_grads = jax.jit(jax.value_and_grad(
             ref_loss, (0, 1, 2), has_aux=True))(q, k, v)
 
-    def err(a, b):
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-    fwd = err(out, ref)
-    bwd = max(err(g, r) for g, r in zip(grads, ref_grads))
+    fwd = _rel_err(out, ref)
+    bwd = max(_rel_err(g, r) for g, r in zip(grads, ref_grads))
     return {"shape": list(shape), "block": block, "mosaic": calls > 0,
             "mosaic_calls": calls,
             "fwd_err": round(fwd, 5), "grad_err": round(bwd, 5),
@@ -528,19 +536,74 @@ def _flash_case(shape, block, want_mosaic):
             and fwd <= FLASH_FWD_TOL and bwd <= FLASH_GRAD_TOL}
 
 
+# grouped_matmul: bf16 operands, float32 sums, one rounding to bf16, as
+# `lax.ragged_dot(..., preferred_element_type=bf16)` has: the two differ
+# by the order of the float32 sums, at most an ulp of bf16 (2^-8) of a
+# value. Errors are max |a - ref| over max |ref|.
+GROUPED_TOL = 1e-2
+
+
+def _grouped_case(n_rows, k, m, groups, want_mosaic):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from paddle_tpu.parallel import grouped
+
+    rs = np.random.RandomState(k)
+    # uneven groups: a few experts own most rows, some own none, and the
+    # boundaries fall inside row tiles
+    share = rs.pareto(0.7, groups) * (rs.rand(groups) > 0.1)
+    counts = np.floor(share / share.sum() * n_rows).astype(np.int32)
+    counts[np.argmax(counts)] += n_rows - counts.sum()
+    counts = jnp.asarray(counts)
+    lhs, rhs, ct = (jnp.asarray(rs.randn(*shape), jnp.bfloat16) for shape in
+                    ((n_rows, k), (groups, k, m), (n_rows, m)))
+    tiles = grouped.tiles_for(n_rows, k, m, lhs.dtype)
+
+    def value_and_grads(fn):
+        def loss(a, b):
+            out = fn(a, b)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))
+
+    ours = value_and_grads(
+        lambda a, b: grouped.grouped_matmul(a, b, counts, None, tiles))
+    # forward, d lhs and d rhs: three Mosaic calls on the chip, none off it
+    calls = ours.lower(lhs, rhs).as_text().count("@tpu_custom_call")
+    (_, out), grads = ours(lhs, rhs)
+    (_, ref), ref_grads = value_and_grads(lambda a, b: lax.ragged_dot(
+        a, b, group_sizes=counts, preferred_element_type=a.dtype))(lhs, rhs)
+
+    fwd = _rel_err(out, ref)
+    bwd = max(_rel_err(g, r) for g, r in zip(grads, ref_grads))
+    return {"shape": [n_rows, k, m, groups], "tiles": list(tiles),
+            "empty_groups": int(np.sum(np.asarray(counts) == 0)),
+            "largest_over_mean": round(float(counts.max()) * groups
+                                       / n_rows, 2),
+            "mosaic": calls > 0, "mosaic_calls": calls,
+            "fwd_err": round(fwd, 5), "grad_err": round(bwd, 5),
+            "ok": (calls >= 3 if want_mosaic else calls == 0)
+            and fwd <= GROUPED_TOL and bwd <= GROUPED_TOL}
+
+
 def phase_kernels(sizes, want_mosaic):
     t0 = time.time()
-    out = {"flash_attention": []}
+    out = {"flash_attention": [], "grouped_matmul": []}
     for shape, block in sizes.flash:
         out["flash_attention"].append(_flash_case(shape, block, want_mosaic))
         say(f"kernels: flash {out['flash_attention'][-1]}")
-    checks = {
-        "flash_attention": all(c["ok"] for c in out["flash_attention"]),
-    }
+    for shape in sizes.grouped:
+        out["grouped_matmul"].append(_grouped_case(*shape, want_mosaic))
+        say(f"kernels: grouped {out['grouped_matmul'][-1]}")
+    checks = {name: all(c["ok"] for c in cases)
+              for name, cases in out.items()}
     return dict(out, wall_s=round(time.time() - t0, 1), checks=checks,
                 mosaic_expected=want_mosaic,
                 tolerance={"flash_fwd": FLASH_FWD_TOL,
-                           "flash_grad": FLASH_GRAD_TOL})
+                           "flash_grad": FLASH_GRAD_TOL,
+                           "grouped": GROUPED_TOL})
 
 
 # ----------------------------------------------------------------- multichip

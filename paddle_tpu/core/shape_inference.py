@@ -829,6 +829,19 @@ def _moe_ffn(ctx):
     ctx.set_output_dim("ZLoss", (1,))
     ctx.set_output_dim("ExpertIds", (x[0], k))
     ctx.set_output_dim("TokensPerExpert", (r[1],))
+    if g is not None:
+        rows = x[0] * k if x[0] >= 0 else -1
+        ctx.set_output_dim("GateOut", (rows, g[2]))
+        ctx.set_output_dim("UpOut", (rows, g[2]))
+        ctx.set_output_dim("DownOut", (rows, x[1]))
+
+
+@register_infer_shape("moe_ffn_grad")
+def _moe_ffn_grad(ctx):
+    for slot in ("X", "Router", "Gate", "Up", "Down"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "@GRAD", d)
 
 
 @register_infer_shape("norm")

@@ -691,12 +691,15 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
     z = helper.create_tmp_variable("float32", shape=(1,))
     ids = helper.create_tmp_variable("int32", stop_gradient=True)
     load = helper.create_tmp_variable("int32", stop_gradient=True)
+    # the three grouped products as computed, for the backward op alone
+    products = {slot: [helper.create_tmp_variable(dtype, stop_gradient=True)]
+                for slot in ("GateOut", "UpOut", "DownOut")}
     helper.append_op(
         "moe_ffn",
         {"X": [input], "Router": [router], "Gate": [gate], "Up": [up],
          "Down": [down]},
         {"Out": [y], "AuxLoss": [aux], "ZLoss": [z], "ExpertIds": [ids],
-         "TokensPerExpert": [load]},
+         "TokensPerExpert": [load], **products},
         {"top_k": int(top_k)})
     return y, aux, z, ids, load
 

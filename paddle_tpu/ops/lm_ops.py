@@ -5,8 +5,9 @@ No reference-framework counterpart (the reference predates them); the
 equations are those of OLMoE (Muennighoff et al., arXiv:2409.02060) as the
 `transformers` OlmoeDecoderLayer computes them. Gradients come from the
 generic vjp of core/registry.py, but for `causal_attention`, whose
-backward op takes the forward's output and logsumexp (the generic vjp
-would run the flash kernel twice). `moe_ffn`'s token permutation has a
+backward op takes the forward's output and logsumexp, and `moe_ffn`,
+whose backward op takes the forward's three grouped products (the generic
+vjp would run the Pallas kernels twice). `moe_ffn`'s token permutation has a
 custom_vjp so that both directions are row gathers (the transpose of a
 gather is a scatter-add, which a TPU serialises).
 """
@@ -182,7 +183,18 @@ _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
 def moe_ffn(x, router, gate, up, down, top_k):
-    """The expert layer on tokens x [T, H]; see `moe_ffn_op`."""
+    """The expert layer on tokens x [T, H]: the five outputs of
+    `moe_ffn_op`."""
+    return _moe_ffn(x, router, gate, up, down, top_k)[0]
+
+
+def _moe_ffn(x, router, gate, up, down, top_k, products=None):
+    """The op's five outputs and the three grouped products' results
+    (gate, up, down, rows in expert order). Given those as `products` (the
+    backward op hands over what the forward left) no product is computed
+    again: the kernels then run for the gradients alone."""
+    from ..parallel.grouped import grouped_dot
+
     T, E = x.shape[0], router.shape[1]
     # router, softmax and top-k in float32 at full precision whatever the
     # compute dtype: a bf16 logit moves the discrete choice
@@ -196,20 +208,26 @@ def moe_ffn(x, router, gate, up, down, top_k):
     inv = jnp.argsort(order).astype(jnp.int32)
     counts = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
                      dtype=jnp.int32)
-    # grouped products over the rows each expert really received
+    # grouped products over the rows each expert really received: Pallas
+    # kernels on a TPU place, `lax.ragged_dot` elsewhere
+    saved = products or (None, None, None)
     xs = _dispatch(x, order, inv, top_k)                    # [T*k, H]
-    dot = functools.partial(lax.ragged_dot, group_sizes=counts,
-                            preferred_element_type=x.dtype)
-    h = jax.nn.silu(dot(xs, gate)) * dot(xs, up)
-    y = _unsort(dot(h, down), order, inv).reshape(T, top_k, -1)
+    a = grouped_dot(xs, gate, counts, saved[0])
+    b = grouped_dot(xs, up, counts, saved[1])
+    ys = grouped_dot(jax.nn.silu(a) * b, down, counts, saved[2])
+    y = _unsort(ys, order, inv).reshape(T, top_k, -1)
     o = jnp.einsum("tkh,tk->th", y.astype(F32), top_p)
     # load balance: E * sum_e (share of routing slots on e) * (mean prob of
     # e); the shares are counts and carry no gradient. z-loss: mean lse^2
     share = counts.astype(F32) / (T * top_k)
     aux = E * jnp.sum(share * jnp.mean(probs, axis=0))
-    return (o.astype(x.dtype), aux.reshape(1),
-            jnp.mean(jnp.square(lse)).reshape(1), top_e.astype(jnp.int32),
-            counts)
+    return ((o.astype(x.dtype), aux.reshape(1),
+             jnp.mean(jnp.square(lse)).reshape(1), top_e.astype(jnp.int32),
+             counts), (a, b, ys))
+
+
+_MOE_INPUTS = ("X", "Router", "Gate", "Up", "Down")
+_MOE_PRODUCTS = ("GateOut", "UpOut", "DownOut")
 
 
 @register_op("moe_ffn")
@@ -218,36 +236,94 @@ def moe_ffn_op(ctx, ins, attrs):
     Out_t = sum over the top_k experts e of p_te * Down_e(silu(Gate_e x_t)
     * Up_e x_t), p = softmax(Router x) NOT renormalised over the chosen;
     AuxLoss [1], ZLoss [1], ExpertIds [T, top_k], TokensPerExpert [E].
-    Tokens are sorted by expert and the three products are
-    `lax.ragged_dot` over the rows routed: no capacity, no dropped token,
-    no padding to a per-expert size."""
-    o, aux, z, ids, counts = moe_ffn(
-        first(ins, "X"), first(ins, "Router"), first(ins, "Gate"),
-        first(ins, "Up"), first(ins, "Down"), int(attrs.get("top_k", 1)))
+    Tokens are sorted by expert and the three products are grouped over
+    the rows routed (`parallel/grouped.py: grouped_dot`): no capacity, no
+    dropped token, no padding to a per-expert size. GateOut, UpOut [T *
+    top_k, F] and DownOut [T * top_k, H] are those products as computed,
+    kept for the backward op."""
+    (o, aux, z, ids, counts), products = _moe_ffn(
+        *(first(ins, s) for s in _MOE_INPUTS), int(attrs.get("top_k", 1)))
     return out(Out=o, AuxLoss=aux, ZLoss=z, ExpertIds=ids,
-               TokensPerExpert=counts)
+               TokensPerExpert=counts, **dict(zip(_MOE_PRODUCTS, products)))
 
 
-set_stop_gradient_outputs("moe_ffn", ["ExpertIds", "TokensPerExpert"])
+set_stop_gradient_outputs(
+    "moe_ffn", ["ExpertIds", "TokensPerExpert", *_MOE_PRODUCTS])
 
 
-# (op type, counter, whether the lowering exists on a TPU place only)
-_LOWERED = (("moe_ffn", "moe_ffn_grouped", False),
-            ("causal_attention", "flash_attention", True),
-            ("causal_attention_grad", "flash_attention_bwd", True))
+@register_grad_maker("moe_ffn")
+def _moe_ffn_grad_maker(op, gout, gin):
+    """Hand-written for the reason `causal_attention`'s is: the generic
+    vjp evaluates the forward again, XLA does not merge two Mosaic calls,
+    and the three forward kernels would run twice a step. The backward op
+    takes the products as the forward left them."""
+    inputs = {s: op.input(s) for s in _MOE_INPUTS}
+    inputs.update({s: op.output(s) for s in _MOE_PRODUCTS if op.output(s)})
+    for s in ("Out", "AuxLoss", "ZLoss"):
+        if any(gout.get(s) or []):
+            inputs[s + "@GRAD"] = [x or "" for x in gout[s]]
+    return [dict(
+        type="moe_ffn_grad", inputs=inputs,
+        outputs={s + "@GRAD": list(names) for s, names in gin.items()},
+        attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
+
+
+@register_op("moe_ffn_grad")
+def moe_ffn_grad_op(ctx, ins, attrs):
+    """The vjp of `moe_ffn`'s body at the forward's saved products (an op
+    built without them computes them here)."""
+    primals = [first(ins, s) for s in _MOE_INPUTS]
+    products = tuple(first(ins, s) for s in _MOE_PRODUCTS)
+    if any(p is None for p in products):
+        products = None
+
+    def fn(*args):
+        return _moe_ffn(*args, int(attrs.get("top_k", 1)), products)[0][:3]
+
+    outs, vjp = jax.vjp(fn, *primals)
+    cots = []
+    for o, slot in zip(outs, ("Out", "AuxLoss", "ZLoss")):
+        g = first(ins, slot + "@GRAD")
+        cots.append(jnp.zeros_like(o) if g is None
+                    else g.astype(o.dtype).reshape(o.shape))
+    return out(**{s + "@GRAD": g for s, g in zip(_MOE_INPUTS,
+                                                  vjp(tuple(cots)))})
+
+
+def _kernels_take(op, block):
+    """Whether `grouped_dot` takes this `moe_ffn`'s products to the Pallas
+    kernels, from the shapes the program states (rows it leaves open, a
+    batch dimension of -1, are taken to fit)."""
+    from ..parallel import grouped
+
+    x, gate = (block.vars[op.input(s)[0]].shape for s in ("X", "Gate"))
+    rows = x[0] * int(op.attrs.get("top_k", 1)) if x[0] > 0 else None
+    return grouped.takes(rows, gate[1], gate[2])
+
+
+# (op type, counter, whether the lowering exists on a TPU place only,
+# which of those ops count: all when None)
+_LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
+            ("moe_ffn", "grouped_matmul_kernel", True, _kernels_take),
+            ("causal_attention", "flash_attention", True, None),
+            ("causal_attention_grad", "flash_attention_bwd", True, None))
 
 
 def lowered_counts(program, device):
     """{counter: n} for the step spans and the registry: `moe_ffn` ops of
-    the program (each lowers through the grouped products) and, on a TPU
-    place, its `causal_attention` ops (each lowers through the flash
-    kernel) and `causal_attention_grad` ops (each through the two backward
-    kernels). A program without them reports none. Kept on the program
-    until that is mutated, like `bn_pool.count`."""
+    the program (each lowers through the grouped products; on a TPU place
+    those whose shapes the Pallas grouped-matmul kernels take count as
+    `grouped_matmul_kernel` too) and, on a TPU place, its
+    `causal_attention` ops (each lowers through the flash kernel) and
+    `causal_attention_grad` ops (each through the two backward kernels). A
+    program without them reports none. Kept on the program until that is
+    mutated, like `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)
     if memo is None or memo[0] != program._mutation:
-        types = [op.type for b in program.blocks for op in b.ops]
-        memo = program._lm_lowered = (
-            program._mutation, [types.count(t) for t, _, _ in _LOWERED])
-    return {name: n for n, (_, name, tpu_only) in zip(memo[1], _LOWERED)
+        ops = [(op, b) for b in program.blocks for op in b.ops]
+        memo = program._lm_lowered = (program._mutation, [
+            sum(1 for op, b in ops
+                if op.type == t and (which is None or which(op, b)))
+            for t, _, _, which in _LOWERED])
+    return {name: n for n, (_, name, tpu_only, _) in zip(memo[1], _LOWERED)
             if n and (device.platform == "tpu" or not tpu_only)}

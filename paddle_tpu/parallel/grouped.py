@@ -1,0 +1,410 @@
+"""Grouped matrix products as Pallas TPU kernels: rows of `lhs` sorted by
+group, group g's rows times its own matrix `rhs[g]`, no capacity and no
+padding of a group (a sparse-expert layer's three products,
+ops/lm_ops.py: moe_ffn).
+
+    grouped_dot(lhs [N, K], rhs [E, K, M], counts [E]) -> [N, M]
+
+is what `lax.ragged_dot` computes, and on any place but a TPU, or for
+shapes the kernels refuse, it IS `lax.ragged_dot`. On a TPU place it is
+three kernels behind one `custom_vjp`:
+
+  forward   out[rows of g] = lhs[rows of g] @ rhs[g]           (`_gmm`)
+  d lhs     the same kernel, the weight tile contracted over its LAST
+            dimension: it reads the forward's `rhs` as it lies
+  d rhs     drhs[g] = lhs[rows of g]^T @ dout[rows of g]       (`_tgmm`),
+            reading `lhs` and `dout` as stored
+
+Adapted from jax.experimental.pallas.ops.tpu.megablox (gmm / tgmm, jax
+0.9.0), not imported: the library's kernels have no name (the trace and
+the benchmark's scopes could not tell them from any other Mosaic call),
+leave VMEM at Mosaic's default scoped 16 MiB (no whole-K weight tile), and
+its `tgmm` masks BOTH operands of EVERY tile in float32 and transposes the
+float32 copy. What is dropped: sharded groups (`group_offset`),
+`existing_out`, a K remainder. XLA's own lowering of `ragged_dot`
+(`ragged-dot-none`) takes its right operand in one orientation only, so a
+training step held two bf16 copies of every expert weight, and its row
+tile (512) is not the program's to choose (PERF.md, PR 29).
+
+Rows are walked in tiles of `tm`. A tile that a group boundary crosses is
+visited once for each group that has rows in it (N / tm + boundaries
+inside tiles visits, at most N / tm + E - 1), and such a visit computes
+only the 128-row blocks of the tile that hold rows of its group, the rows
+of other groups in them masked. The list of visits (group, row tile) is made from `counts`
+on the device and handed to the kernels as scalar prefetch: their index
+maps read it, so consecutive visits of one group find that group's weight
+tile already in VMEM.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..core.places import pallas_interpret
+
+__all__ = ["grouped_dot", "grouped_matmul", "tiles_for"]
+
+# The kernels' names: Pallas puts a kernel's name on the name stack, so a
+# device trace's op_name ends `.../grouped_matmul/pallas_call`
+# (chipbench/layer_metrics/grouped_matmul_roofline.py finds them by these).
+# JAX writes the first scope opened inside a custom_vjp's backward as
+# `jvp(<scope>)`, which chipbench/scopes.py drops with all it wraps: the
+# kernels are called under a scope of their own, `_SCOPE`, for JAX to wrap,
+# and their names come after it, forward (`moe/moe_ffn/grouped/
+# grouped_matmul`) and backward (`moe/moe_ffn_grad/grouped_matmul_nt`).
+KERNELS = ("grouped_matmul", "grouped_matmul_nt", "grouped_matmul_tn")
+_SCOPE = "grouped"
+
+_VMEM_LIMIT = 96 * 2 ** 20       # of the v5e's 128 MiB
+ROW_TILES = (512, 256, 128)      # tried in this order: `tiles_for`
+_BLOCK_ROWS = 128                # of a tile that a group boundary crosses
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def on_tpu():
+    """Whether the step being traced is compiled for a TPU place."""
+    return not pallas_interpret()
+
+
+def visits(counts, n_rows, tm, empty_groups):
+    """The kernels' work list from the group sizes: (offsets [E + 1],
+    group of each visit, row tile of each visit), both [n_rows // tm + E -
+    1] int32, and the number of visits that are real (the grid's extent;
+    the lists' tails repeat the last group and tile). A row tile is visited
+    by each group that has rows in it, in group order, so the visits of a
+    tile and the visits of a group are both consecutive. With
+    `empty_groups` a group without rows gets one visit (the transposed
+    product has to write its zeros)."""
+    E = counts.shape[0]
+    tiles_m = n_rows // tm
+    ends = jnp.cumsum(counts)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    starts = offsets[:-1]
+    # tiles a group touches: from its start rounded down to its end
+    # rounded up
+    group_tiles = jnp.where(
+        counts == 0, 0, (ends + tm - 1) // tm - starts // tm)
+    if empty_groups:
+        group_tiles = jnp.where(counts == 0, 1, group_tiles)
+    n = tiles_m + E - 1
+    group_ids = jnp.repeat(jnp.arange(E, dtype=jnp.int32), group_tiles,
+                           total_repeat_length=n)
+    # a tile is visited once by the group that owns its first row, and
+    # once more for each group that starts inside it (or, empty and
+    # counted, sits inside it)
+    extra = (starts % tm != 0) & (counts != 0)
+    if empty_groups:
+        extra = extra | (counts == 0)
+    tile_visits = jnp.zeros(tiles_m, jnp.int32).at[
+        jnp.where(extra, starts // tm, tiles_m)].add(1, mode="drop") + 1
+    # an empty group at the very end of the rows sits in no tile: its visit
+    # reads the last one
+    tile_ids = jnp.repeat(jnp.arange(tiles_m, dtype=jnp.int32), tile_visits,
+                          total_repeat_length=n)
+    return (offsets, group_ids, tile_ids), group_tiles.sum()
+
+
+def _visit(offsets, group_ids, tile_ids, visit, tm):
+    """Of a visit: the first and one-past-last row of its group, the first
+    row of its tile, and whether every row of the tile is the group's."""
+    g = group_ids[visit]
+    lo, hi, row0 = offsets[g], offsets[g + 1], tile_ids[visit] * tm
+    return lo, hi, row0, (lo <= row0) & (hi >= row0 + tm)
+
+
+def _blocks_of_group(lo, hi, row0, tm, body):
+    """`body(rows, mask_of)` for each block of `_BLOCK_ROWS` rows of the
+    tile that holds rows of the group [lo, hi): `rows` the block's slice
+    of the tile, `mask_of(width)` its [rows, width] mask of the group's
+    rows. A tile a boundary crosses costs the blocks the group touches,
+    not the whole tile. One loop body, not a copy a block: the kernels'
+    code is held in HBM too (unrolled four ways it added 16 MB to the
+    OLMoE step's program, PERF.md PR 29)."""
+    n = min(tm, _BLOCK_ROWS)
+    begin = (jnp.maximum(lo, row0) - row0) // n
+    end = (jnp.minimum(hi, row0 + tm) - row0 + n - 1) // n
+
+    def block(b, carry):
+        start = pl.multiple_of(b * n, n)
+
+        def mask_of(width):
+            i = lax.broadcasted_iota(jnp.int32, (n, width), 0) + row0 + start
+            return (i >= lo) & (i < hi)
+
+        body(pl.ds(start, n), mask_of)
+        return carry
+
+    lax.fori_loop(begin, jnp.where(hi > lo, end, begin), block, None)
+
+
+def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, *acc, tm, tn,
+                tiles_k, dims):
+    """One visit: the tile's rows of the visit's group times the group's
+    weight tile. With all of K in one tile (`acc` empty) the product goes
+    straight to the out block; else through float32 sums in `acc`."""
+    visit, k_i = pl.program_id(1), pl.program_id(2)
+    lo, hi, row0, whole = _visit(offsets, group_ids, tile_ids, visit, tm)
+    if acc:
+        @pl.when(k_i == 0)
+        def _():
+            acc[0][...] = jnp.zeros((tm, tn), jnp.float32)
+
+    def rows_times_weights(rows, mask_of=None):
+        p = lax.dot_general(lhs[rows, :], rhs[...], dims,
+                            preferred_element_type=jnp.float32)
+        if acc:
+            acc[0][rows, :] += p
+
+        def store():
+            value = acc[0][rows, :] if acc else p
+            if mask_of is not None:
+                # the tile's other rows were, or will be, written by the
+                # visits of their own groups: the out block stays in VMEM
+                # between them
+                value = jnp.where(mask_of(tn), value,
+                                  out[rows, :].astype(jnp.float32))
+            out[rows, :] = value.astype(out.dtype)
+
+        if acc:
+            pl.when(k_i == tiles_k - 1)(store)
+        else:
+            store()
+
+    pl.when(whole)(lambda: rows_times_weights(pl.ds(0, tm)))
+    pl.when(jnp.logical_not(whole))(lambda: _blocks_of_group(
+        lo, hi, row0, tm, rows_times_weights))
+
+
+def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs):
+    """[N, K] x [E, K, M] -> [N, M] (`transpose_rhs`: rhs is [E, M, K],
+    contracted over its last dimension)."""
+    tm, tk, tn = tiles
+    (N, K), E = lhs.shape, rhs.shape[0]
+    M = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tiles_k, tiles_n = K // tk, M // tn
+
+    def lhs_at(n_i, v, k_i, offsets, group_ids, tile_ids):
+        return tile_ids[v], k_i
+
+    def rhs_at(n_i, v, k_i, offsets, group_ids, tile_ids):
+        if transpose_rhs:
+            return group_ids[v], n_i, k_i
+        return group_ids[v], k_i, n_i
+
+    def out_at(n_i, v, k_i, offsets, group_ids, tile_ids):
+        return tile_ids[v], n_i
+
+    rhs_block = (None, tn, tk) if transpose_rhs else (None, tk, tn)
+    item = lhs.dtype.itemsize
+    max_visits = meta[1].shape[0]
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k,
+                          dims=_NT if transpose_rhs else _NN),
+        out_shape=jax.ShapeDtypeStruct((N, M), lhs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[pl.BlockSpec((tm, tk), lhs_at),
+                      pl.BlockSpec(rhs_block, rhs_at)],
+            out_specs=pl.BlockSpec((tm, tn), out_at),
+            grid=(tiles_n, n_visits, tiles_k),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]
+            if tiles_k > 1 else []),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * N * K * M, transcendentals=0,
+            bytes_accessed=item * (
+                N * K * tiles_n + N * M
+                + K * M * (E if tiles_k == 1 else max_visits))),
+        interpret=pallas_interpret(),
+        name=KERNELS[1] if transpose_rhs else KERNELS[0],
+    )(*meta, lhs, rhs)
+
+
+def _tgmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, acc, *, tm,
+                 tk, tn):
+    """One visit: the tile's rows of the visit's group, lhs^T times rhs,
+    summed in `acc` over the group's visits and written at its last."""
+    visit = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    g = group_ids[visit]
+    lo, hi, row0, whole = _visit(offsets, group_ids, tile_ids, visit, tm)
+
+    @pl.when((visit == 0) | (group_ids[jnp.maximum(visit - 1, 0)] != g))
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    def rows_transposed_times_rows(rows, mask_of=None):
+        a, b = lhs[rows, :], rhs[rows, :]
+        # rows of other groups zeroed in ONE operand (the narrower tile):
+        # a zero row of either contributes nothing
+        if mask_of is not None and tk <= tn:
+            a = jnp.where(mask_of(tk), a, jnp.zeros_like(a))
+        elif mask_of is not None:
+            b = jnp.where(mask_of(tn), b, jnp.zeros_like(b))
+        acc[...] += lax.dot_general(a, b, _TN,
+                                    preferred_element_type=jnp.float32)
+
+    pl.when(whole)(lambda: rows_transposed_times_rows(pl.ds(0, tm)))
+    pl.when(jnp.logical_not(whole))(lambda: _blocks_of_group(
+        lo, hi, row0, tm, rows_transposed_times_rows))
+
+    @pl.when((visit == last) | (group_ids[jnp.minimum(visit + 1, last)] != g))
+    def _():
+        out[...] = acc[...].astype(out.dtype)
+
+
+def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype):
+    """[N, K], [N, M] -> [E, K, M]: group g's rows of lhs, transposed,
+    times its rows of rhs."""
+    tm, tk, tn = tiles
+    (N, K), M = lhs.shape, rhs.shape[1]
+    E = meta[0].shape[0] - 1
+    tiles_k, tiles_n = K // tk, M // tn
+
+    def lhs_at(n_i, k_i, v, offsets, group_ids, tile_ids):
+        return tile_ids[v], k_i
+
+    def rhs_at(n_i, k_i, v, offsets, group_ids, tile_ids):
+        return tile_ids[v], n_i
+
+    def out_at(n_i, k_i, v, offsets, group_ids, tile_ids):
+        return group_ids[v], k_i, n_i
+
+    item = lhs.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn),
+        out_shape=jax.ShapeDtypeStruct((E, K, M), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[pl.BlockSpec((tm, tk), lhs_at),
+                      pl.BlockSpec((tm, tn), rhs_at)],
+            out_specs=pl.BlockSpec((None, tk, tn), out_at),
+            grid=(tiles_n, tiles_k, n_visits),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * N * K * M, transcendentals=0,
+            bytes_accessed=item * (N * K * tiles_n + N * M * tiles_k)
+            + E * K * M * jnp.dtype(out_dtype).itemsize),
+        interpret=pallas_interpret(),
+        name=KERNELS[2],
+    )(*meta, lhs, rhs)
+
+
+def _product(lhs, rhs, counts, tiles):
+    if tiles is None:
+        return lax.ragged_dot(lhs, rhs, group_sizes=counts,
+                              preferred_element_type=lhs.dtype)
+    tm, fwd, _, _ = tiles
+    with jax.named_scope(_SCOPE):
+        meta, n = visits(counts, lhs.shape[0], tm, False)
+        return _gmm(lhs, rhs, meta, n, (tm,) + fwd, False)
+
+
+def _gradients(lhs, rhs, counts, tiles, g):
+    if tiles is None:
+        return jax.vjp(lambda a, b: _product(a, b, counts, None),
+                       lhs, rhs)[1](g)
+    tm, _, dlhs, drhs = tiles
+    with jax.named_scope(_SCOPE):
+        meta, n = visits(counts, lhs.shape[0], tm, False)
+        d_lhs = _gmm(g, rhs, meta, n, (tm,) + dlhs, True)
+        meta, n = visits(counts, lhs.shape[0], tm, True)
+        return d_lhs, _tgmm(lhs, g, meta, n, (tm,) + drhs, rhs.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(lhs, rhs, counts, out, tiles):
+    """`grouped_dot` with its lowering named: `tiles` = (tm, (tk, tn) of
+    the forward, of d lhs, of d rhs) for the kernels (`tiles_for`; N a
+    multiple of tm, K and M of their tiles), None for `lax.ragged_dot`."""
+    return _product(lhs, rhs, counts, tiles) if out is None else out
+
+
+def _matmul_fwd(lhs, rhs, counts, out, tiles):
+    return grouped_matmul(lhs, rhs, counts, out, tiles), (lhs, rhs, counts)
+
+
+def _matmul_bwd(tiles, res, g):
+    lhs, rhs, counts = res
+    return (*_gradients(lhs, rhs, counts, tiles, g.astype(lhs.dtype)),
+            None, None)
+
+
+grouped_matmul.defvjp(_matmul_fwd, _matmul_bwd)
+
+
+def _largest_tile(dim, most):
+    """The largest multiple of 128 that divides `dim` and is <= `most`."""
+    for t in range(min(dim, most) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return None
+
+
+def takes(n_rows, k, m):
+    """Whether the kernels take [n_rows, k] x [E, k, m] and its two
+    gradients: K and M multiples of 128 (a lane tile), the rows a multiple
+    of the smallest row tile (`n_rows` None: not known yet, taken to
+    fit)."""
+    return k % 128 == 0 and m % 128 == 0 and (
+        n_rows is None or n_rows % ROW_TILES[-1] == 0)
+
+
+def tiles_for(n_rows, k, m, dtype):
+    """(tm, (tk, tn) forward, (tk, tn) of d lhs, (tk, tn) of d rhs) for
+    [n_rows, k] x [E, k, m] in `dtype`, or None where the kernels do not
+    take the shapes (`takes`). The rule, from two sweeps on the v5e at
+    [65536, 2048] x [64, 2048, 1024] and [65536, 1024] x [64, 1024, 2048]
+    bf16 (tools/grouped_sweep.py; PERF.md, PR 29; ms a call of the
+    forward, a seeded step's real group sizes / even groups;
+    `lax.ragged_dot` 2.96 / 2.04):
+
+      K and M tiles: the whole dimension up to 4 KiB of a row (2048 bf16).
+        With all of K in one tile a group's weight tile keeps its block
+        index from visit to visit and is fetched once a group, and the
+        product needs no float32 scratch; a K tile of 1024 fetches the
+        weights again every visit (2.52 against 1.95 at tm 256, 2.72 at
+        512), a narrower M tile reads the rows once more for each (2.08
+        at 512, 2.51 at 256).
+      row tile: the longest of 512, 256, 128 that divides the rows. Long
+        tiles use the MXU better (even groups: 1.49 at 512, 1.74 at 256,
+        1.92 at 128; 1024 no better, 1.50). With a boundary tile costing
+        a whole masked visit a group, 256 was best on real groups (1.95;
+        2.24 at 512, 2.01 at 128, 2.94 at 1024); walked in blocks of 128
+        rows, only those the group touches (`_blocks_of_group`), 512 is
+        (1.81; 1.86 at 256, 1.84 at 1024; blocks of 256: 1.90). The
+        number of groups no longer enters: a boundary costs a block, not
+        a tile.
+    """
+    if not takes(n_rows, k, m):
+        return None
+    tm = next(t for t in ROW_TILES if n_rows % t == 0)
+    wide = 4096 // jnp.dtype(dtype).itemsize
+    tk, tn = _largest_tile(k, wide), _largest_tile(m, wide)
+    return tm, (tk, tn), (tn, tk), (tk, tn)
+
+
+def grouped_dot(lhs, rhs, counts, out=None):
+    """lhs [N, K] (rows sorted by group), rhs [E, K, M], counts [E] int32
+    (they sum to N) -> [N, M] in lhs's dtype, float32 accumulation. The
+    Pallas kernels on a TPU place for shapes they take, `lax.ragged_dot`
+    otherwise (as `_plain_causal_attention` is for attention). `out`: the
+    product as an earlier call left it; it is returned as it is and the
+    call only carries the gradients (a backward op that was handed the
+    forward's result computes nothing twice)."""
+    tiles = None
+    if on_tpu() and lhs.dtype == rhs.dtype:
+        tiles = tiles_for(lhs.shape[0], lhs.shape[1], rhs.shape[2],
+                          lhs.dtype)
+    return grouped_matmul(lhs, rhs, counts.astype(jnp.int32), out, tiles)

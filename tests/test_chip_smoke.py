@@ -66,7 +66,10 @@ def test_rehearsal_kernels_are_interpreted_off_the_chip(rehearsal):
     assert kernels["mosaic_expected"] is False
     assert all(kernels["checks"].values()), kernels["checks"]
     assert not any(c["mosaic"] for c in kernels["flash_attention"])
-    assert kernels["checks"].keys() == {"flash_attention"}
+    assert kernels["checks"].keys() == {"flash_attention", "grouped_matmul"}
+    case, = kernels["grouped_matmul"]
+    assert not case["mosaic"] and case["largest_over_mean"] > 1.5
+    assert case["tiles"][0] == 128       # the longest that divides 384 rows
 
 
 def test_rehearsal_multichip_phase_on_the_virtual_mesh(rehearsal):

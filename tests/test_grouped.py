@@ -1,0 +1,291 @@
+"""The grouped-matmul Pallas kernels (paddle_tpu/parallel/grouped.py) in
+interpret mode on the CPU, against `lax.ragged_dot`; `moe_ffn` through
+either lowering; the fall-back for shapes the kernels refuse."""
+
+import functools
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+import paddle_tpu as fluid
+from paddle_tpu.ops import lm_ops
+from paddle_tpu.parallel import grouped
+
+N, K, M, TM = 256, 128, 256, 64
+
+# group sizes over 256 rows walked in row tiles of 64
+LAYOUTS = {
+    "even": [64, 64, 64, 64],
+    "one_expert_owns_every_row": [0, 256, 0, 0],
+    "empty_experts_first_middle_last": [0, 100, 0, 156, 0],
+    "boundaries_inside_tiles": [3, 5, 7, 9, 11, 13, 15, 193],
+    "a_group_exactly_one_tile": [32, 32, 64, 128],
+    "two_boundaries_in_one_tile": [70, 10, 20, 156],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _both(layout, dtype, block_rows):
+    """(kernels, ragged_dot): each (out, d lhs, d rhs) as float32 numpy.
+    The forward has all of K in one tile (no float32 scratch), d lhs two
+    K tiles (sums in scratch); `block_rows` 16 walks a tile that a
+    boundary crosses in four blocks, 128 as one."""
+    was, grouped._BLOCK_ROWS = grouped._BLOCK_ROWS, block_rows
+    try:
+        return _compute(layout, dtype)
+    finally:
+        grouped._BLOCK_ROWS = was
+
+
+def _compute(layout, dtype):
+    counts = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    rs = np.random.default_rng(len(layout))
+    lhs, rhs, g = (jnp.asarray(rs.standard_normal(s), dtype) for s in (
+        (N, K), (len(counts), K, M), (N, M)))
+    tiles = (TM, (128, 128), (128, 128), (128, 128))
+    results = []
+    for t in (tiles, None):
+        out, vjp = jax.vjp(lambda a, b: grouped.grouped_matmul(
+            a, b, counts, None, t), lhs, rhs)
+        results.append([np.asarray(x, np.float32) for x in (out, *vjp(g))])
+    return results
+
+
+@pytest.mark.parametrize("which", ["forward", "d_lhs", "d_rhs"])
+@pytest.mark.parametrize("block_rows", [16, 128])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_kernels_match_ragged_dot(layout, dtype, block_rows, which):
+    """Forward (`_gmm`), d lhs (the same kernel, the weight contracted
+    over its last dimension) and d rhs (`_tgmm`) equal `lax.ragged_dot`
+    and its vjp: to float32 rounding in float32, to one bf16 rounding of
+    the float32 sums in bf16. An empty group's d rhs is zeros, written."""
+    i = ["forward", "d_lhs", "d_rhs"].index(which)
+    ours, ref = (r[i] for r in _both(layout, dtype, block_rows))
+    assert ours.shape == ref.shape and np.all(np.isfinite(ours))
+    tol = 2e-6 if dtype == "float32" else 2 ** -8
+    assert np.max(np.abs(ours - ref)) <= tol * np.max(np.abs(ref))
+    if which == "d_rhs":
+        for e, c in enumerate(LAYOUTS[layout]):
+            assert c or not ours[e].any()
+
+
+@pytest.mark.parametrize("tm", [32, 64, 128])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_visits_cover_every_group_once_per_tile(layout, tm):
+    """The work list: each (group, tile) pair with rows in common appears
+    exactly once, groups and tiles both in order, N / tm + boundaries
+    inside tiles visits; with `empty_groups` each empty group once more."""
+    counts = LAYOUTS[layout]
+    ends = np.cumsum(counts)
+    want = [(g, t) for g, (lo, hi) in enumerate(zip(ends - counts, ends))
+            for t in range(N // tm) if lo < hi and lo < (t + 1) * tm > t * tm < hi]
+    (offsets, gids, tids), n = grouped.visits(
+        jnp.asarray(counts, jnp.int32), N, tm, False)
+    assert list(np.asarray(offsets)) == [0, *ends]
+    assert list(zip(np.asarray(gids)[:n], np.asarray(tids)[:n])) == want
+    (_, gids, tids), n_t = grouped.visits(
+        jnp.asarray(counts, jnp.int32), N, tm, True)
+    assert int(n_t) == len(want) + counts.count(0) <= len(gids)
+    assert list(np.asarray(gids)[:n_t]) == sorted(
+        [g for g, _ in want] + [g for g, c in enumerate(counts) if not c])
+    assert np.all(np.diff(np.asarray(tids)[:n_t]) >= 0)
+
+
+def test_a_given_product_is_not_computed_again():
+    """`out=`: the value is returned as it is and the call carries the
+    gradients alone: the vjp holds the two backward kernels and no
+    forward one."""
+    counts = jnp.asarray(LAYOUTS["even"], jnp.int32)
+    lhs, rhs = jnp.ones((N, K)), jnp.ones((4, K, M))
+    tiles = (TM, (128, 128), (128, 128), (128, 128))
+    saved = grouped.grouped_matmul(lhs, rhs, counts, None, tiles)
+
+    def grads(a, b, out):
+        return jax.vjp(lambda x, y: grouped.grouped_matmul(
+            x, y, counts, out, tiles), a, b)[1](jnp.ones((N, M)))
+
+    text = str(jax.make_jaxpr(grads)(lhs, rhs, saved))
+    assert text.count("pallas_call") == 2
+    assert str(jax.make_jaxpr(lambda a, b: grads(a, b, None))(
+        lhs, rhs)).count("pallas_call") == 3
+    for ours, ref in zip(grads(lhs, rhs, saved), grads(lhs, rhs, None)):
+        np.testing.assert_array_equal(ours, ref)
+
+
+@pytest.mark.parametrize("n_rows,k,m,dtype,want", [
+    (65536, 2048, 1024, "bfloat16", "kernels"),
+    (65536, 1024, 2048, "bfloat16", "kernels"),
+    (1024, 256, 384, "float32", "kernels"),
+    (65536 + 128, 2048, 1024, "bfloat16", "kernels"),
+    (65536 + 64, 2048, 1024, "bfloat16", None),     # rows no tile divides
+    (65536, 2048 + 64, 1024, "bfloat16", None),     # K not of 128
+    (65536, 2048, 1000, "bfloat16", None),          # M not of 128
+])
+def test_tiles_are_chosen_from_the_shapes(n_rows, k, m, dtype, want):
+    tiles = grouped.tiles_for(n_rows, k, m, dtype)
+    assert grouped.takes(n_rows, k, m) == (want is not None)
+    if want is None:
+        assert tiles is None
+        return
+    tm, fwd, dlhs, drhs = tiles
+    assert n_rows % tm == 0 and tm in grouped.ROW_TILES
+    for (tk, tn), (kk, mm) in zip((fwd, dlhs, drhs),
+                                  ((k, m), (m, k), (k, m))):
+        assert kk % tk == 0 and mm % tn == 0 and tk % 128 == 0 == tn % 128
+    # the forward and d lhs keep the whole of a contraction this short in
+    # one tile: a group's weight tile is then fetched once, not per visit
+    item = jnp.dtype(dtype).itemsize
+    if k * item <= 4096:
+        assert fwd[0] == k
+    if m * item <= 4096:
+        assert dlhs[0] == m
+
+
+def _moe_operands(T, H, F, E, dtype, seed=0):
+    rs = np.random.default_rng(seed)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(rs.standard_normal(shape) * scale, dtype)
+
+    return (draw(T, H), draw(H, E).astype(jnp.float32),
+            draw(E, H, F, scale=H ** -0.5), draw(E, H, F, scale=H ** -0.5),
+            draw(E, F, H, scale=F ** -0.5))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_moe_ffn_equal_between_the_two_lowerings(monkeypatch, dtype):
+    """`moe_ffn`'s five outputs, and the gradients of Out, AuxLoss and
+    ZLoss to its five inputs, through the kernels (interpreted) and
+    through `lax.ragged_dot`: one body, `grouped_dot` the only
+    difference."""
+    T, H, F, E, k = 64, 128, 256, 8, 2          # 128 routed rows
+    args = _moe_operands(T, H, F, E, dtype)
+
+    def run():
+        def fn(*a):
+            return lm_ops.moe_ffn(*a, k)[:3]
+
+        outs, vjp = jax.vjp(fn, *args)
+        cots = (jnp.ones_like(outs[0]), jnp.ones((1,)), jnp.ones((1,)))
+        return lm_ops.moe_ffn(*args, k), vjp(cots)
+
+    calls = []
+    real = grouped.tiles_for
+    monkeypatch.setattr(grouped, "tiles_for",
+                        lambda *a: calls.append(a) or real(*a))
+    plain, plain_grads = run()
+    assert calls == []                          # not a TPU place
+    monkeypatch.setattr(grouped, "on_tpu", lambda: True)
+    kern, kern_grads = run()
+    assert calls and all(real(*a) is not None for a in calls)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for a, b in zip(plain, kern):
+        if a.dtype.kind == "i":
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       rtol=tol, atol=tol)
+    for a, b in zip(plain_grads, kern_grads):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(a)), 1e-6)
+
+
+def test_rows_no_tile_divides_fall_back_to_ragged_dot(monkeypatch):
+    """On a TPU place, 100 tokens x top-2 = 200 rows: no row tile divides
+    them, `grouped_dot` is `lax.ragged_dot` (no Pallas call in the trace)
+    and the result is the plain one."""
+    monkeypatch.setattr(grouped, "on_tpu", lambda: True)
+    args = _moe_operands(100, 128, 256, 8, "float32")
+    text = str(jax.make_jaxpr(lambda *a: lm_ops.moe_ffn(*a, 2))(*args))
+    assert "pallas_call" not in text and "ragged_dot" in text
+    taken = str(jax.make_jaxpr(lambda *a: lm_ops.moe_ffn(*a, 2))(
+        *_moe_operands(64, 128, 256, 8, "float32")))
+    assert taken.count("pallas_call") == 3 and "ragged_dot" not in taken
+
+
+def _moe_program(tokens, hidden, width, experts=8, top_k=2):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[tokens, hidden],
+                              dtype="float32", append_batch_size=False)
+        y = fluid.layers.moe_ffn(x, experts, width, top_k)[0]
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(
+            fluid.layers.mean(y))
+    return main
+
+
+@pytest.mark.parametrize("tokens,hidden,width,kernel", [
+    (64, 128, 256, True),
+    (-1, 128, 256, True),       # rows left open: taken to fit
+    (100, 128, 256, False),     # 200 rows: no row tile divides them
+    (64, 96, 256, False),       # K not a multiple of 128
+    (64, 128, 200, False),      # M not a multiple of 128
+])
+def test_counter_follows_the_shapes(tokens, hidden, width, kernel):
+    """`grouped_matmul_kernel` counts a `moe_ffn` op on a TPU place only,
+    and only where the kernels take its shapes; `moe_ffn_grouped` counts
+    it everywhere."""
+    prog = _moe_program(tokens, hidden, width)
+    tpu, cpu = (SimpleNamespace(platform=p) for p in ("tpu", "cpu"))
+    assert lm_ops.lowered_counts(prog, cpu) == {"moe_ffn_grouped": 1}
+    want = {"moe_ffn_grouped": 1}
+    if kernel:
+        want["grouped_matmul_kernel"] = 1
+    assert lm_ops.lowered_counts(prog, tpu) == want
+
+
+def test_backward_op_takes_the_forward_products():
+    """The program's `moe_ffn_grad` op reads GateOut / UpOut / DownOut of
+    its forward op, and a training step through the Executor gives the
+    gradients the generic vjp gives."""
+    prog = _moe_program(64, 128, 256)
+    ops = {op.type: op for op in prog.global_block().ops}
+    fwd, bwd = ops["moe_ffn"], ops["moe_ffn_grad"]
+    for slot in ("GateOut", "UpOut", "DownOut"):
+        assert bwd.input(slot) == fwd.output(slot) != []
+    assert sorted(s for s in bwd.outputs) == [
+        "Down@GRAD", "Gate@GRAD", "Router@GRAD", "Up@GRAD"]
+    args = _moe_operands(64, 128, 256, 8, "float32", seed=3)
+    mean = 1.0 / (64 * 128)
+    ins = {s: [a] for s, a in zip(lm_ops._MOE_INPUTS, args)}
+    saved = lm_ops._moe_ffn(*args, 2)[1]
+    got = lm_ops.moe_ffn_grad_op(None, dict(
+        ins, **{s: [p] for s, p in zip(lm_ops._MOE_PRODUCTS, saved)},
+        **{"Out@GRAD": [jnp.full((64, 128), mean)]}), {"top_k": 2})
+    want = jax.grad(lambda *a: jnp.mean(lm_ops.moe_ffn(*a, 2)[0]),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    for slot, w in zip(lm_ops._MOE_INPUTS, want):
+        np.testing.assert_allclose(got[slot + "@GRAD"][0], w, rtol=1e-5,
+                                   atol=1e-7)
+    # an op built without the saved products computes them itself
+    again = lm_ops.moe_ffn_grad_op(None, dict(
+        ins, **{"Out@GRAD": [jnp.full((64, 128), mean)]}), {"top_k": 2})
+    for slot in lm_ops._MOE_INPUTS:
+        np.testing.assert_allclose(again[slot + "@GRAD"][0],
+                                   got[slot + "@GRAD"][0], rtol=1e-6,
+                                   atol=1e-8)
+
+
+def test_ragged_dot_is_the_reference_semantics():
+    """What both lowerings compute, written out: group g's rows times
+    rhs[g]."""
+    counts = LAYOUTS["boundaries_inside_tiles"]
+    rs = np.random.default_rng(1)
+    lhs = rs.standard_normal((N, K)).astype(np.float32)
+    rhs = rs.standard_normal((len(counts), K, M)).astype(np.float32)
+    want = np.concatenate([
+        lhs[lo:lo + c] @ rhs[g] for g, (lo, c) in enumerate(
+            zip(np.cumsum(counts) - counts, counts))])
+    got = grouped.grouped_dot(jnp.asarray(lhs), jnp.asarray(rhs),
+                              jnp.asarray(counts, jnp.int32))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        lax.ragged_dot(jnp.asarray(lhs), jnp.asarray(rhs),
+                       jnp.asarray(counts, jnp.int32)), want, rtol=1e-4,
+        atol=1e-4)

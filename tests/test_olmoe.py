@@ -167,6 +167,17 @@ def test_lowered_counts_by_place():
         "moe_ffn_grouped": layers}
     assert lm_ops.lowered_counts(built["test_prog"], tpu) == {
         "moe_ffn_grouped": layers, "flash_attention": layers}
+    # SMALL's widths are no multiples of 128: the products stay
+    # `lax.ragged_dot` there; at the published widths the Pallas grouped
+    # kernels take them, on a TPU place only
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           "olmoe_1b_7b.json")) as f:
+        full = builder.build(fluid, json.load(f), 11)
+    assert lm_ops.lowered_counts(full["prog"], tpu) == {
+        "moe_ffn_grouped": 1, "grouped_matmul_kernel": 1,
+        "flash_attention": 1, "flash_attention_bwd": 1}
+    assert lm_ops.lowered_counts(full["prog"], cpu) == {
+        "moe_ffn_grouped": 1}
 
 
 def test_scan_of_k_steps_runs_and_counts_its_lowerings():
