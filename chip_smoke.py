@@ -83,6 +83,18 @@ class Sizes:
     # (rows, K, M, groups): the cell's gate / up and down products at an
     # eighth of its rows, tiles as `grouped.tiles_for` picks them
     grouped: tuple = ((8192, 2048, 1024, 64), (8192, 1024, 2048, 64))
+    # overrides of chipbench/configs/xing4_0_29b_a4b.json for the
+    # share_model phase: a small model of that kind (latent attention with
+    # keys of 192 and values of 128, 4 residual streams, 2 of 8 experts
+    # held, a shared expert, the module; 1 dense + 1 expert layer) at
+    # sizes both kernel families take:
+    # 512 tokens x top-2 = 1024 rows, K = 256 and 128
+    share_model: dict = dataclasses.field(default_factory=lambda: dict(
+        hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+        num_hidden_layers=2, n_routed_experts=2, num_experts_per_tok=2,
+        num_attention_heads=2, num_key_value_heads=2, q_lora_rank=128,
+        kv_lora_rank=128, vocab_size=512, sequence_length=512,
+        deployment=dict(n_routed_experts=8, first_expert=2)))
 
 
 FULL = Sizes()
@@ -93,7 +105,8 @@ TINY = Sizes(depth=18, classes=16, image=32, batch=8, k=2, chunks=4,
              distinct=2, serve_requests=8,
              first_losses_ref=(4.1299, 3.4761), first_loss_tol=0.3,
              flash=(((1, 2, 128, 64), 64), ((1, 2, 100, 64), 32)),
-             grouped=((384, 128, 256, 8),))
+             grouped=((384, 128, 256, 8),),
+             share_model=dict(FULL.share_model, sequence_length=128))
 
 
 def say(msg):
@@ -606,6 +619,81 @@ def phase_kernels(sizes, want_mosaic):
                            "grouped": GROUPED_TOL})
 
 
+# --------------------------------------------------------------- share_model
+SHARE_LOSS_TOL = 0.02       # bf16 AMP against the float32 reference
+
+
+def phase_share_model(fluid, sizes, place, device):
+    """One training step of a small `models/xing4.py` under bf16 AMP on
+    the place, through the Executor: both kernel families engaged on the
+    chip (the flash kernels at keys of 192 and values of 128, the grouped
+    kernels over the held groups' rows), both losses against the plain
+    float32 reference on the same weights, the router's counts and the
+    rows the products took."""
+    import jax.numpy as jnp
+    from chipbench.configs import xing4_0_29b_a4b as builder
+    from paddle_tpu import amp
+    from paddle_tpu.core import executor_core
+
+    t0 = time.time()
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "chipbench", "configs",
+                           "xing4_0_29b_a4b.json")) as f:
+        cfg = json.load(f)
+    cfg.update(sizes.share_model)
+    S, k = cfg["sequence_length"], cfg["num_experts_per_tok"]
+    first, held = cfg["deployment"]["first_expert"], cfg["n_routed_experts"]
+    rs = np.random.RandomState(7)
+    feed = {"tokens": rs.randint(0, cfg["vocab_size"], (1, S)).astype(
+                np.int32),
+            "labels": rs.randint(0, cfg["vocab_size"], (1, S)).astype(
+                np.int32)}
+    built = builder.build(fluid, cfg, 7)
+    lowered = executor_core.lowered_counts(built["prog"], device)
+    _, load_var, rows_var = built["routing"][0]
+    scope = fluid.Scope()
+    amp.enable("bfloat16")
+    try:
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(place)
+            exe.run(built["startup"])
+            w0 = {p.name: np.asarray(scope.find_var(p.name), np.float32)
+                  for p in built["prog"].global_block().all_parameters()}
+            loss, ce, ce_mtp, load, rows = exe.run(
+                built["prog"], feed=feed,
+                fetch_list=[built["loss"], built["ce"], built["ce_mtp"],
+                            load_var, rows_var])
+    finally:
+        amp.disable()
+    ref_loss, rest, _ = builder.reference.loss_and_grads(
+        cfg, {n: jnp.asarray(v) for n, v in w0.items()},
+        jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]))
+    got = [float(np.asarray(v).reshape(-1)[0]) for v in (loss, ce, ce_mtp)]
+    want = [float(ref_loss), float(rest[0]), float(rest[1])]
+    errs = [abs(a - b) / abs(b) for a, b in zip(got, want)]
+    load = np.asarray(load).reshape(-1)
+    on_chip = device.platform == "tpu"
+    kernels = {n: lowered.get(n, 0) for n in (
+        "grouped_matmul_kernel", "flash_attention", "flash_attention_bwd")}
+    checks = {
+        "losses_match_reference": max(errs) <= SHARE_LOSS_TOL,
+        "every_token_routed": int(load.sum()) == k * S,
+        "products_took_the_held_rows": int(np.asarray(rows).reshape(-1)[0])
+        == int(load[first:first + held].sum()),
+        # two expert blocks (a layer and the module), each lowered with a
+        # share; three attentions (the dense layer's too)
+        "lowered_as_a_share": lowered.get("moe_ffn_held_experts") == 2,
+        "kernel_families_engaged": (
+            kernels == {"grouped_matmul_kernel": 2, "flash_attention": 3,
+                        "flash_attention_bwd": 3}) if on_chip
+        else not any(kernels.values())}
+    return {"losses": got, "reference": want, "rel_err": errs,
+            "tokens_per_expert": load.tolist(),
+            "rows_held": int(np.asarray(rows).reshape(-1)[0]),
+            "lowered": lowered, "tolerance": SHARE_LOSS_TOL,
+            "wall_s": round(time.time() - t0, 1), "checks": checks}
+
+
 # ----------------------------------------------------------------- multichip
 def _distinct_devices(arr):
     return len({s.device for s in arr.addressable_shards})
@@ -737,6 +825,9 @@ def run_phases(fluid, sizes, device, rehearsal):
             del trained
         say("phase kernels")
         phases["kernels"] = phase_kernels(sizes, want_mosaic)
+        say("phase share_model")
+        phases["share_model"] = phase_share_model(fluid, sizes, place,
+                                                  device)
         if n > 1:
             say("phase multichip")
             phases["multichip"] = phase_multichip(fluid, sizes, log, n)

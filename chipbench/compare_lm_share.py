@@ -1,0 +1,381 @@
+"""The comparison that decides `correct` for a language model that holds
+one chip's SHARE of each layer, several blocks deep, with a
+multi-token-prediction module: the system under test against the
+configuration's plain float32 reference (which is given the same share),
+at the published widths, on the device the cell runs on, outside the
+window, on a seeded row of the cell's own traffic. What is compared is a
+second build of the same program, in a scope of its own, run for ONE step
+with the gradients fetched, and its inference clone (the logits): the
+same ops, lowerings and kernels as the timed program, not its executable.
+The K-step scan the window times is held by the cell's own checks alone
+(losses finite, every token routed, the rows the products took), so an
+update that went wrong only inside the scanned step would pass here.
+
+`compare_lm.py` stays as it is for the one-layer configuration it was made
+for (it reads one (balance, z-loss) pair, indexes an expert by its
+published id, and its limits were set one layer deep); this file takes its
+`routing_report` and its small helpers. Compared on one row of tokens:
+
+* routing of EVERY expert layer (the module's too): the experts the system
+  chose against the reference's top-k of score + bias, the training step
+  and the inference program each apart. Where a set differs, every
+  exchanged expert must lie within ROUTING_MARGIN (relative, in score +
+  bias) of the reference's k-th. Flips compound with depth (a token
+  routed otherwise in layer 1 arrives otherwise at layer 2), so each
+  layer is judged on the tokens every layer before it routed alike:
+  their share with any difference is limited by ROUTING_FLIP_MAX;
+* logits of the main head and of the module's head, per token, over the
+  tokens routed as the reference routed them in every layer: rms error
+  relative to the rms logit, and the largest error relative to the
+  largest |logit|;
+* both cross-entropies and the whole loss;
+* the global gradient norm the clip computed, and its scale;
+* gradient cosine, norm ratio and first AdamW update of a sampled
+  parameter of each kind (`sampled_params`: the head and the embedding,
+  each used twice; W_qa, W_kvb, W_o; the router; one held expert's three
+  matrices, the busiest held expert of the first expert layer by the
+  reference's routing; the shared expert's; Phi_res and alpha of a mixer;
+  the module's projection; a norm scale).
+
+* first-hand, before any routing: the first mixer's HRes and HPost and
+  the per-row scale of the norm that follows it, against the reference on
+  the embedding itself, and the column sums of HRes. These hold the FIRST
+  block's mixer and norm only: a bf16 mixer or norm in a later block
+  alone passes (PERF.md section 7 row 27);
+* what the grouped kernels wrote, in every expert layer of the inference
+  program: the rows of the down product (`DownOut`, rows in expert order,
+  the held experts' first) that are not all zero are exactly `RowsHeld`,
+  and that is the number of choices the fetched `ExpertIds` put on the
+  held experts. A kernel that skipped a held row, or a count that named
+  rows no product visited, shows here (a row written past the groups
+  does not: `grouped_dot` zeroes those itself);
+* each router's bias after the step: moved by the configuration's speed
+  towards an even load of that step's own choices, exactly.
+
+The limits, each from two readings (PERF.md section 6 has the table with
+every number): the largest reading of the system as the configuration
+states it over the builder's seeds ("stated"), and the SYSTEM one
+precision below (`python -m chipbench.lower_precision_lm_share` on the
+chip: the mixers and Sinkhorn in bf16, the router, the norms' statistics,
+the master weights, then all). Six blocks deep the stated system's spread
+over seeds (4-8% of tokens routed otherwise in each expert layer) is wider
+than what a bf16 mixer, router or norm adds to logits and losses, so:
+
+* MIXER_TOL, SINKHORN_TOL, NORM_SCALE_TOL hold the mixers and the norm
+  statistics first-hand on the first block (float32 reads 1e-6 to 1e-4
+  there, bf16 some 1e-3);
+* GRAD_LIMITS by kind of parameter hold the router: with a bf16 router its
+  own gradient's cosine falls to 0.3-0.8 (stated 0.98), the held
+  expert's to 0.82 (0.995), the embedding's to 0.994 (0.9993);
+* UPDATE_TOL 0.1 of a step holds the master weights (bf16: hundreds of
+  steps); at the cell's learning rate of 1e-6 half an ulp of a norm scale
+  is 6% of a step, so it cannot hold a missing decay term (tests/
+  test_xing4.py does, at the recipe's rate);
+* LOSS_TOL 6e-4 (stated 3.0e-4 | all 9e-4 to 2e-3) and CLIP_SCALE_TOL 1e-5
+  (7e-8 | 2e-4 to 2e-3) hold a bf16 loss and clip;
+* LOGITS_RMS_TOL 2.5% (stated 1.86%), LOGITS_TOL 4% (2.33%),
+  ROUTING_FLIP_MAX 11% (7.6%), ROUTING_MARGIN 3% (1.8%) and
+  GLOBAL_NORM_TOL 3e-3 (1.5e-3) do not separate the precisions: they hold
+  a wrong forward, router or gradient (a dropped branch moves the logits
+  by tens of percent).
+"""
+
+import gc
+import time
+
+import numpy as np
+
+from chipbench.compare_lm import (_clip_vars, _cos_ratio, _rel, _scalar,
+                                  routing_report)
+from chipbench.harness import memory_peak
+
+ROUTING_MARGIN = 0.03
+ROUTING_FLIP_MAX = 0.11
+LOGITS_TOL = 0.04
+LOGITS_RMS_TOL = 0.025
+LOSS_TOL = 6e-4
+GLOBAL_NORM_TOL = 3e-3
+CLIP_SCALE_TOL = 1e-5
+UPDATE_TOL = 0.1
+MIXER_TOL = 5e-4
+SINKHORN_TOL = 1e-4
+NORM_SCALE_TOL = 3e-4
+# gradient cosine at least, norm ratio within, by kind of parameter: what
+# the discrete routing touches directly (the router, the one held expert
+# sampled) moves with the near-ties; a mixer's scalar has 3 elements
+GRAD_LIMITS = {"router": (0.95, 0.10), "expert": (0.98, 0.04),
+               "phi_res": (0.99, 0.06), "alpha": (0.0, 0.25)}
+GRAD_LIMITS_ELSE = (0.997, 0.01)
+
+
+def _products(prog):
+    """(DownOut, RowsHeld) names of every `moe_ffn` of the program."""
+    return [(op.output("DownOut")[0], op.output("RowsHeld")[0])
+            for op in prog.global_block().ops if op.type == "moe_ffn"]
+
+
+def _first_mixer(prog):
+    """Names of the first mixer's HRes and HPost and of the norm's output
+    that follows it (the first sublayer's input, normed): what the mixers
+    and a norm compute on the embedding itself, before any routing."""
+    ops = prog.global_block().ops
+    mix = next(op for op in ops if op.type == "mhc_mix")
+    norm = next(op for op in ops if op.type == "rms_norm"
+                and op.input("X") == mix.output("U"))
+    return [mix.output("HRes")[0], mix.output("HPost")[0],
+            norm.output("Y")[0]]
+
+
+def system_side(fluid, cfg, builder, place, seed, tokens, labels):
+    """What the system computes on the row, as numpy: the weights the
+    startup program drew (`w0`, every parameter), the inference program's
+    logits (both heads) and routing, the training step's losses, routing,
+    global norm, clip scale, clipped gradients and updated weights of the
+    sampled parameters. Its scope is gone when this returns."""
+    built = builder.build(fluid, cfg, seed, for_compare=True)
+    picks = builder.sampled_params(cfg)
+    gnorm_var, scale_var = _clip_vars(built["prog"])
+    feed = {built["token_feed"]: tokens, built["label_feed"]: labels}
+    ids_vars = [r[0] for r in built["routing"]]
+    first = _first_mixer(built["prog"])
+    products = _products(built["test_prog"])
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(place)
+        exe.run(built["startup"])
+        w0 = {p.name: np.asarray(scope.find_var(p.name), np.float32)
+              for p in built["prog"].global_block().all_parameters()}
+        evaled = exe.run(built["test_prog"], feed=feed,
+                         fetch_list=[built["logits"], built["mtp_logits"]]
+                         + ids_vars + [n for pair in products for n in pair])
+        # rows of each layer's down product the kernels wrote, rows held
+        n_ids = 2 + len(ids_vars)
+        rows_written = [
+            (int(np.any(np.asarray(down) != 0, axis=1).sum()),
+             int(np.asarray(held).reshape(-1)[0]))
+            for down, held in zip(evaled[n_ids::2], evaled[n_ids + 1::2])]
+        evaled = evaled[:n_ids]
+        fetched = exe.run(
+            built["prog"], feed=feed,
+            fetch_list=[built["loss"], built["ce"], built["ce_mtp"],
+                        gnorm_var, scale_var] + ids_vars + first
+            + [n + "@GRAD_clipped" for n in picks.values()])
+        w1 = {k: np.asarray(scope.find_var(n)).astype(np.float32)
+              for k, n in picks.items()}
+        # each router's bias before and after the step, in the order of
+        # `routing`
+        biases = [(w0[op.input("Bias")[0]],
+                   np.asarray(scope.find_var(op.input("Bias")[0])))
+                  for op in built["prog"].global_block().ops
+                  if op.type == "moe_ffn"]
+    n_layers = len(ids_vars)
+    got = dict(zip(("loss", "ce", "ce_mtp", "gnorm", "scale"),
+                   (_scalar(v) for v in fetched[:5])))
+    got.update(
+        w0=w0, w1=w1, logits=np.asarray(evaled[0], np.float32),
+        mtp_logits=np.asarray(evaled[1], np.float32),
+        ids_eval=[np.asarray(v) for v in evaled[2:]],
+        rows_written=rows_written, biases=biases,
+        ids=[np.asarray(v) for v in fetched[5:5 + n_layers]],
+        first_mixer=[np.asarray(v).astype(np.float32)
+                     for v in fetched[5 + n_layers:8 + n_layers]],
+        clipped={k: np.asarray(v).astype(np.float32)
+                 for k, v in zip(picks, fetched[8 + n_layers:])})
+    del scope, exe, fetched, evaled, built
+    gc.collect()
+    return got
+
+
+def reference_side(cfg, builder, w0, tokens, labels):
+    """The plain reference on the same weights and row, as numpy."""
+    import jax.numpy as jnp
+
+    ref, picks = builder.reference, builder.sampled_params(cfg)
+    loss, (ce, ce_mtp, logits, mtp, routing), grads = ref.loss_and_grads(
+        cfg, {k: jnp.asarray(v) for k, v in w0.items()},
+        jnp.asarray(tokens), jnp.asarray(labels))
+    gnorm = float(jnp.sqrt(sum(jnp.sum(g * g) for g in grads.values())))
+    T = tokens.size
+    # the first mixer and the norm after it, on the embedding itself
+    import jax
+
+    p = "xing.l0.attn_"
+    with jax.default_matmul_precision(ref.PRECISION):
+        x0 = jnp.broadcast_to(
+            jnp.asarray(w0["xing.embed"])[tokens.reshape(-1)][:, None, :],
+            (T, cfg["hc_mult"], cfg["hidden_size"]))
+        wj = {k: jnp.asarray(v) for k, v in w0.items()
+              if k.startswith(p)}
+        pre, post, res = ref.mixers(x0, wj, p + "mhc_", cfg)
+        normed = ref.rms_norm(jnp.einsum("tn,tnc->tc", pre, x0),
+                              wj[p + "norm"], cfg["rms_norm_eps"])
+    return dict(loss=float(loss), ce=float(ce), ce_mtp=float(ce_mtp),
+                gnorm=gnorm,
+                routing=[(np.asarray(b), np.asarray(t)) for b, t in routing],
+                logits=np.asarray(logits).reshape(T, -1),
+                mtp_logits=np.asarray(mtp).reshape(T, -1),
+                first_mixer=[np.asarray(v) for v in (res, post, normed)],
+                grads={k: np.asarray(grads[n]) for k, n in picks.items()})
+
+
+def _logits_errors(got, ref, same):
+    diff = got - ref
+    err = np.abs(diff).max(axis=1) / np.abs(ref).max()
+    return (float(err[same].max()),
+            float(np.sqrt(np.mean(np.square(diff[same])))
+                  / np.sqrt(np.mean(np.square(ref[same])))))
+
+
+def _routing_by_layer(ids, routing_ref):
+    """Each expert layer's report over the tokens that all earlier layers
+    routed as the reference did (a token routed otherwise upstream arrives
+    as another token: its choice here says nothing); and the tokens every
+    layer routed alike."""
+    alike = np.ones(ids[0].shape[0], bool)
+    reports = []
+    for ids_l, (biased, top) in zip(ids, routing_ref):
+        rep, same = routing_report(ids_l[alike], biased[alike], top[alike],
+                                   ROUTING_MARGIN)
+        rep["tokens_alike_before"] = int(alike.sum())
+        reports.append(rep)
+        alike[alike] = same
+    return reports, alike
+
+
+def judge(cfg, builder, got, ref):
+    """The report: every number, the limits, which of them `failed`."""
+    picks = builder.sampled_params(cfg)
+    # the inference program and the training step are two compiled
+    # programs: near-ties need not fall the same way in both
+    route, _ = _routing_by_layer(got["ids"], ref["routing"])
+    route_eval, same = _routing_by_layer(got["ids_eval"], ref["routing"])
+    main_max, main_rms = _logits_errors(got["logits"], ref["logits"], same)
+    mtp_max, mtp_rms = _logits_errors(got["mtp_logits"], ref["mtp_logits"],
+                                      same)
+    # the busiest HELD expert of the first expert layer, by the reference
+    first = cfg["deployment"]["first_expert"]
+    counts = np.bincount(ref["routing"][0][1].ravel(),
+                         minlength=cfg["deployment"]["n_routed_experts"])
+    expert = int(counts[first:first + cfg["n_routed_experts"]].argmax())
+    # [rows of DownOut not all zero, RowsHeld, choices on the held experts]
+    # of each expert layer of the inference program
+    rows = [[written, held, int(((ids >= first) & (
+        ids < first + cfg["n_routed_experts"])).sum())]
+        for (written, held), ids in zip(got["rows_written"],
+                                        got["ids_eval"])]
+    o = cfg["optimizer"]
+    eps = o["epsilon"] / np.sqrt(1.0 - o["beta2"])
+    # `noaux_tc`: after the step a router's bias has moved by the speed
+    # towards an even load, by the step's own choices; exactly, in float32
+    n_all = cfg["deployment"]["n_routed_experts"]
+    bias_moved = []
+    for (before, after), ids in zip(got["biases"], got["ids"]):
+        load = np.bincount(ids.ravel(), minlength=n_all).astype(np.float64)
+        want = before + np.float32(o["router_bias_update_speed"]) \
+            * np.sign(load.mean() - load).astype(np.float32)
+        bias_moved.append(bool(np.array_equal(after, want)))
+    by_param = {}
+    for key, name in picks.items():
+        g_hat, g_ref = got["clipped"][key], ref["grads"][key]
+        a, b = got["w0"][name], got["w1"][key]
+        if key.startswith("expert_"):
+            g_hat, g_ref, a, b = (v[expert] for v in (g_hat, g_ref, a, b))
+        cos, ratio = _cos_ratio(g_hat / got["scale"], g_ref)
+        decay = o["weight_decay"] if builder.reference.decays(name) else 0.0
+        want = -o["learning_rate"] * (g_hat / (np.abs(g_hat) + eps)
+                                      + decay * a)
+        kind = "expert" if key.startswith("expert_") else key
+        cos_min, ratio_tol = GRAD_LIMITS.get(kind, GRAD_LIMITS_ELSE)
+        by_param[key] = {
+            "grad_cos": cos, "grad_norm_ratio": ratio,
+            "grad_ok": bool(cos is not None and cos >= cos_min
+                            and abs(ratio - 1.0) <= ratio_tol),
+            "update_err": float(np.abs((b - a) - want).max()
+                                / np.abs(want).max())}
+    (h_res, h_post, y), (h_res_ref, h_post_ref, y_ref) = (
+        got["first_mixer"], ref["first_mixer"])
+    # the norm's statistic is one factor a row: the row's projection on the
+    # reference's, less 1 (the bf16 rounding of the elements averages out)
+    row_scale = np.sum(y * y_ref, axis=1) / np.sum(y_ref * y_ref, axis=1)
+    report = {
+        "first_mixer_err": float(max(np.abs(h_res - h_res_ref).max(),
+                                     np.abs(h_post - h_post_ref).max())),
+        "sinkhorn_column_err": float(np.abs(h_res.sum(axis=1) - 1.0).max()),
+        "first_norm_scale_err": float(np.sqrt(np.mean(
+            np.square(row_scale - 1.0)))),
+        "product_rows_written_held_chosen": rows,
+        "router_bias_moved_by_the_rule": bias_moved,
+        "config": cfg["name"], "rows": int(cfg["reference"]["rows"]),
+        "expert": first + expert, "reference": cfg["reference"]["file"],
+        "routing": route, "routing_inference": route_eval,
+        "tokens_routed_alike_everywhere": float(same.mean()),
+        "logits_err_max": main_max, "logits_err_rms": main_rms,
+        "mtp_logits_err_max": mtp_max, "mtp_logits_err_rms": mtp_rms,
+        "train_loss": [got["loss"], ref["loss"]],
+        "train_loss_err": _rel(got["loss"], ref["loss"]),
+        "cross_entropy": [got["ce"], ref["ce"]],
+        "cross_entropy_err": _rel(got["ce"], ref["ce"]),
+        "mtp_cross_entropy": [got["ce_mtp"], ref["ce_mtp"]],
+        "mtp_cross_entropy_err": _rel(got["ce_mtp"], ref["ce_mtp"]),
+        "global_grad_norm": [got["gnorm"], ref["gnorm"]],
+        "global_grad_norm_err": _rel(got["gnorm"], ref["gnorm"]),
+        "clip_scale": got["scale"],
+        "clip_scale_err": _rel(got["scale"], min(
+            1.0, o["clip_global_norm"] / got["gnorm"])),
+        "by_param": by_param,
+        "limits": {"routing_margin": ROUTING_MARGIN,
+                   "routing_flip_max": ROUTING_FLIP_MAX,
+                   "logits": LOGITS_TOL, "logits_rms": LOGITS_RMS_TOL,
+                   "loss": LOSS_TOL, "grad_by_kind": GRAD_LIMITS,
+                   "grad_else": GRAD_LIMITS_ELSE,
+                   "global_grad_norm": GLOBAL_NORM_TOL,
+                   "update": UPDATE_TOL, "clip_scale": CLIP_SCALE_TOL,
+                   "first_mixer": MIXER_TOL, "sinkhorn": SINKHORN_TOL,
+                   "first_norm_scale": NORM_SCALE_TOL},
+    }
+    worst = {k: [f(v[k] for v in by_param.values() if v[k] is not None)
+                 for f in (min, max)]
+             for k in ("grad_cos", "grad_norm_ratio", "update_err")}
+    report["worst"] = worst
+    held = {
+        "routing": all(
+            r["ok"] and r["flipped_share"] <= ROUTING_FLIP_MAX
+            for r in route + route_eval),
+        "logits": bool(np.isfinite(main_max) and np.isfinite(mtp_max)
+                       and max(main_max, mtp_max) <= LOGITS_TOL
+                       and max(main_rms, mtp_rms) <= LOGITS_RMS_TOL),
+        "loss": max(report["train_loss_err"], report["cross_entropy_err"],
+                    report["mtp_cross_entropy_err"]) <= LOSS_TOL,
+        "global_grad_norm": report["global_grad_norm_err"]
+        <= GLOBAL_NORM_TOL,
+        "clip_scale": report["clip_scale_err"] <= CLIP_SCALE_TOL,
+        "gradients": all(v["grad_ok"] for v in by_param.values()),
+        "update": worst["update_err"][1] <= UPDATE_TOL,
+        "mixers": report["first_mixer_err"] <= MIXER_TOL
+        and report["sinkhorn_column_err"] <= SINKHORN_TOL,
+        "norms": report["first_norm_scale_err"] <= NORM_SCALE_TOL,
+        "product_rows": len(rows) == len(got["ids_eval"])
+        and all(w == h == c for w, h, c in rows),
+        "router_bias": len(bias_moved) == len(got["ids"])
+        and all(bias_moved),
+    }
+    report["failed"] = sorted(k for k, v in held.items() if not v)
+    report["ok"] = not report["failed"]
+    return report
+
+
+def against_reference(fluid, cfg, builder, place, seed, tokens, labels):
+    """`tokens`, `labels`: int32 [rows, S] of the cell's traffic. Returns
+    a report with `ok` and every number. The system's scope is freed
+    before the reference runs, and the caller builds the timed program
+    after this returns: `device_peak_bytes` says how high the comparison
+    pushed the device's memory."""
+    import jax
+
+    t0 = time.perf_counter()
+    got = system_side(fluid, cfg, builder, place, seed, tokens, labels)
+    ref = reference_side(cfg, builder, got["w0"], tokens, labels)
+    report = judge(cfg, builder, got, ref)
+    report["device_peak_bytes"] = int(memory_peak(jax.local_devices()))
+    report["seconds"] = time.perf_counter() - t0
+    return report

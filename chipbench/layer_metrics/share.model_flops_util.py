@@ -1,0 +1,22 @@
+"""Model FLOP/s utilization of a training cell whose model holds one
+chip's share of each layer: operations the forward and backward passes
+need for a token (`chipbench/costs_share.py`: latent attention at its two
+head sizes over half the square, the mixers, the HELD experts of each
+block over the rows they really received (the mean over the window's
+steps and expert blocks of `RowsHeld`: operations are linear in the rows),
+the shared expert, both heads; nothing recomputed is counted) x tokens/s of the window, over chips x the table's
+bf16 peak."""
+
+from chipbench import costs_share
+
+
+def read(obs):
+    by_layer = obs.get("held_rows_by_layer")
+    if not obs.get("rate_items_per_s") or not by_layer:
+        return None
+    cfg = obs["cfg"]
+    rows = sum(map(sum, by_layer)) / (len(by_layer) * len(by_layer[0]))
+    per_token = costs_share.train_flops_per_token(
+        cfg, cfg["sequence_length"], rows / obs["tokens_per_step"])
+    return (100.0 * per_token * obs["rate_items_per_s"]
+            / (obs["chips"] * obs["peaks"]["bf16_flops_per_s"]))

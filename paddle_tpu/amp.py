@@ -51,6 +51,9 @@ WHITE_LIST = frozenset({
     # and accumulate in float32; rms_norm and rotary_embedding stay neutral
     # (dtype-preserving, float32 inside, like layer_norm)
     "causal_attention", "moe_ffn",
+    # the residual path of n streams: the state and the sublayers' outputs
+    # flow in the compute dtype, the mixers themselves are float32 inside
+    "mhc_expand", "mhc_mix", "mhc_update",
 })
 
 # Input slots of a white-list op that are NOT cast down: moe_ffn's router
@@ -58,7 +61,11 @@ WHITE_LIST = frozenset({
 # flips the discrete choice between near-tied experts) and the incoming
 # gradients of its two float32 scalar losses.
 FLOAT32_SLOTS = {
-    "moe_ffn": frozenset({"Router", "AuxLoss@GRAD", "ZLoss@GRAD"}),
+    "moe_ffn": frozenset({"Router", "Bias", "AuxLoss@GRAD", "ZLoss@GRAD"}),
+    # the mixers' parameters and the per-token mixing matrices they make
+    "mhc_mix": frozenset({"PhiPre", "PhiPost", "PhiRes", "Alpha", "BPre",
+                          "BPost", "BRes", "HPost@GRAD", "HRes@GRAD"}),
+    "mhc_update": frozenset({"HRes", "HPost"}),
 }
 
 # Ops whose bf16 inputs are cast UP to float32 (numerics-sensitive math,

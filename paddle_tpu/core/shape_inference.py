@@ -787,11 +787,14 @@ def _causal_attention(ctx):
     if q is None:
         return
     ctx.enforce(len(q) == 4, f"Q must be [B, S, H, D], got {q}")
-    for name, other in (("K", k), ("V", v)):
-        if other is not None:
-            ctx.enforce(_shapes_match(q, other),
-                        f"{name}{other} must match Q{q} (no grouped KV)")
-    ctx.set_output_dim("Out", q)
+    if k is not None:
+        ctx.enforce(_shapes_match(q, k),
+                    f"K{k} must match Q{q} (no grouped KV)")
+    if v is not None:
+        # the values may be narrower or wider than the keys
+        ctx.enforce(len(v) == 4 and _shapes_match(q[:3], v[:3]),
+                    f"V{v} must be [B, S, H, Dv] of Q{q}")
+    ctx.set_output_dim("Out", q if v is None else v)
     ctx.set_output_dim("Lse", (q[0], q[2], q[1]))
 
 
@@ -801,7 +804,7 @@ def _causal_attention_grad(ctx):
     for slot in ("Q", "K", "V"):
         d = ctx.input_dim(slot)
         if d is not None:
-            if g is not None:
+            if g is not None and slot == "V":
                 ctx.enforce(_shapes_match(d, g),
                             f"Out@GRAD{g} must match {slot}{d}")
             ctx.set_output_dim(slot + "@GRAD", d)
@@ -818,9 +821,17 @@ def _moe_ffn(ctx):
                 f"Router{r} must be [H={x[1]}, E]")
     k = ctx.attr("top_k", 1)
     ctx.enforce(0 < k <= r[1], f"top_k {k} out of range for {r[1]} experts")
+    held = ctx.attr("held_experts", 0) or r[1]
+    ctx.enforce(0 <= ctx.attr("first_expert", 0)
+                and ctx.attr("first_expert", 0) + held <= r[1],
+                f"held experts [{ctx.attr('first_expert', 0)}, +{held}) "
+                f"outside the router's {r[1]}")
+    b = ctx.input_dim("Bias")
+    if b is not None:
+        ctx.enforce(tuple(b) == (r[1],), f"Bias{b} must be [E={r[1]}]")
     if g is not None and u is not None and d is not None:
-        ctx.enforce(len(g) == 3 and g[0] == r[1] and _dim_match(g[1], x[1]),
-                    f"Gate{g} must be [E={r[1]}, H={x[1]}, F]")
+        ctx.enforce(len(g) == 3 and g[0] == held and _dim_match(g[1], x[1]),
+                    f"Gate{g} must be [E'={held}, H={x[1]}, F]")
         ctx.enforce(tuple(u) == tuple(g), f"Up{u} must match Gate{g}")
         ctx.enforce(tuple(d) == (g[0], g[2], g[1]),
                     f"Down{d} must be [E, F, H] of Gate{g}")
@@ -829,6 +840,7 @@ def _moe_ffn(ctx):
     ctx.set_output_dim("ZLoss", (1,))
     ctx.set_output_dim("ExpertIds", (x[0], k))
     ctx.set_output_dim("TokensPerExpert", (r[1],))
+    ctx.set_output_dim("RowsHeld", (1,))
     if g is not None:
         rows = x[0] * k if x[0] >= 0 else -1
         ctx.set_output_dim("GateOut", (rows, g[2]))
@@ -842,6 +854,45 @@ def _moe_ffn_grad(ctx):
         d = ctx.input_dim(slot)
         if d is not None:
             ctx.set_output_dim(slot + "@GRAD", d)
+
+
+@register_infer_shape("mhc_mix")
+def _mhc_mix(ctx):
+    x = ctx.input_dim("X")
+    if x is None:
+        return
+    ctx.enforce(len(x) == 3, f"X must be [n, T, C], got {x}")
+    n, width = x[0], x[0] * x[2]
+    for slot, want in (("PhiPre", (width, n)), ("PhiPost", (width, n)),
+                       ("PhiRes", (width, n * n)), ("Alpha", (3,)),
+                       ("BPre", (n,)), ("BPost", (n,)), ("BRes", (n * n,))):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.enforce(tuple(d) == want, f"{slot}{d} must be {want}")
+    ctx.set_output_dim("U", (x[1], x[2]))
+    ctx.set_output_dim("HPost", (x[1], n))
+    ctx.set_output_dim("HRes", (x[1], n, n))
+
+
+@register_infer_shape("mhc_expand")
+def _mhc_expand(ctx):
+    x = ctx.input_dim("X")
+    if x is None:
+        return
+    ctx.enforce(len(x) == 2, f"X must be [T, C], got {x}")
+    ctx.set_output_dim("Out", (ctx.attr("streams", 1), x[0], x[1]))
+
+
+@register_infer_shape("mhc_update")
+def _mhc_update(ctx):
+    x, y = ctx.input_dim("X"), ctx.input_dim("Y")
+    if x is None:
+        return
+    ctx.enforce(len(x) == 3, f"X must be [n, T, C], got {x}")
+    if y is not None:
+        ctx.enforce(len(y) == 2 and _dim_match(y[1], x[2]),
+                    f"Y{y} must be [T, C={x[2]}]")
+    ctx.set_output_dim("Out", x)
 
 
 @register_infer_shape("norm")

@@ -19,7 +19,7 @@ __all__ = [
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
-    "moe_ffn", "beam_search_decode", "conv2d_transpose", "sequence_expand",
+    "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_first_step", "sequence_last_step", "dropout",
     "l2_normalize", "matmul", "topk", "warpctc", "sequence_reshape",
@@ -648,60 +648,153 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
     return y
 
 
-def rotary_embedding(input, theta=10000.0, name=None):
+def rotary_embedding(input, theta=10000.0, scaling=None, name=None):
     """Rotary position embedding (Su et al., arXiv:2104.09864) of
-    [B, S, H, D] in the `rotate_half` convention; position = index in S."""
+    [B, S, H, D] in the `rotate_half` convention; position = index in S.
+    `scaling`: a config's `rope_scaling` of type yarn (factor, beta_fast,
+    beta_slow, original_max_position_embeddings): YaRN's frequencies."""
     helper = LayerHelper("rotary_embedding", **locals())
     y = helper.create_tmp_variable(input.dtype, shape=input.shape)
-    helper.append_op("rotary_embedding", {"X": [input]}, {"Out": [y]},
-                     {"theta": float(theta)})
+    attrs = {"theta": float(theta)}
+    if scaling:
+        attrs.update(
+            scaling_factor=float(scaling["factor"]),
+            beta_fast=float(scaling.get("beta_fast", 32)),
+            beta_slow=float(scaling.get("beta_slow", 1)),
+            original_max_position=int(
+                scaling["original_max_position_embeddings"]))
+    helper.append_op("rotary_embedding", {"X": [input]}, {"Out": [y]}, attrs)
     return y
 
 
-def causal_attention(q, k, v, name=None):
-    """softmax(Q K^T / sqrt(D) + causal mask) V on [B, S, H, D]; on a TPU
-    place the flash kernel of parallel/flash.py (no [S, S] scores in HBM)."""
+def causal_attention(q, k, v, scale=None, name=None):
+    """softmax(Q K^T * scale + causal mask) V on Q, K [B, S, H, D] and V
+    [B, S, H, Dv] (`scale` None: 1 / sqrt(D)); on a TPU place the flash
+    kernel of parallel/flash.py (no [S, S] scores in HBM)."""
     helper = LayerHelper("causal_attention", **locals())
-    y = helper.create_tmp_variable(q.dtype, shape=q.shape)
+    y = helper.create_tmp_variable(q.dtype, shape=v.shape)
     lse = helper.create_tmp_variable("float32", stop_gradient=True)
     helper.append_op("causal_attention", {"Q": [q], "K": [k], "V": [v]},
-                     {"Out": [y], "Lse": [lse]})
+                     {"Out": [y], "Lse": [lse]},
+                     {} if scale is None else {"scale": float(scale)})
     return y
 
 
 def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
-            gate_attr=None, up_attr=None, down_attr=None, name=None):
+            gate_attr=None, up_attr=None, down_attr=None, name=None,
+            score_func="softmax", norm_topk=False, routed_scale=1.0,
+            bias_attr=None, held=None):
     """A layer of `num_experts` SwiGLU experts of width `expert_size` on
-    tokens [T, H], each token through its `top_k` by router probability
-    (not renormalised), grouped matmuls over the rows really routed.
-    Returns (out, load-balance loss [1], router z-loss [1],
-    expert ids [T, top_k], tokens per expert [num_experts])."""
+    tokens [T, H], each token through its `top_k` by router score,
+    grouped matmuls over the rows really routed. The defaults are OLMoE's
+    (softmax scores, not renormalised); `score_func` "sigmoid",
+    `norm_topk`, `routed_scale` and `bias_attr` (a [num_experts] bias
+    added to the scores for the choice alone, persistable, not trained)
+    are the `noaux_tc` router's. `held` = (first expert, count): the layer
+    holds those experts' weights only, one share of a layer whose experts
+    lie on several chips; the router keeps `num_experts` outputs.
+    Returns (out, load-balance loss [1], router z-loss [1], expert ids
+    [T, top_k], tokens per expert [num_experts]) and, with `held`, the
+    rows the held experts received [1]."""
     helper = LayerHelper("moe_ffn", **locals())
     dtype = helper.input_dtype()
     hidden = int(input.shape[-1])
+    first, n_held = held or (0, num_experts)
     router, gate, up, down = (
         helper.create_parameter(attr=ParamAttr.to_attr(a), shape=shape,
                                 dtype=dtype)
         for a, shape in ((router_attr, [hidden, num_experts]),
-                         (gate_attr, [num_experts, hidden, expert_size]),
-                         (up_attr, [num_experts, hidden, expert_size]),
-                         (down_attr, [num_experts, expert_size, hidden])))
+                         (gate_attr, [n_held, hidden, expert_size]),
+                         (up_attr, [n_held, hidden, expert_size]),
+                         (down_attr, [n_held, expert_size, hidden])))
+    inputs = {"X": [input], "Router": [router], "Gate": [gate], "Up": [up],
+              "Down": [down]}
+    attrs = {"top_k": int(top_k)}
+    if bias_attr is not None:
+        bias_attr = ParamAttr.to_attr(bias_attr)
+        bias_attr.trainable = False
+        inputs["Bias"] = [helper.create_parameter(
+            attr=bias_attr, shape=[num_experts], dtype="float32",
+            default_initializer=Constant(0.0))]
+    if score_func != "softmax" or norm_topk or routed_scale != 1.0:
+        attrs.update(score_func=score_func, norm_topk=bool(norm_topk),
+                     routed_scale=float(routed_scale))
     y = helper.create_tmp_variable(dtype, shape=input.shape)
     aux = helper.create_tmp_variable("float32", shape=(1,))
     z = helper.create_tmp_variable("float32", shape=(1,))
     ids = helper.create_tmp_variable("int32", stop_gradient=True)
     load = helper.create_tmp_variable("int32", stop_gradient=True)
+    outputs = {"Out": [y], "AuxLoss": [aux], "ZLoss": [z],
+               "ExpertIds": [ids], "TokensPerExpert": [load]}
+    if held:
+        attrs.update(first_expert=int(first), held_experts=int(n_held))
+        rows = helper.create_tmp_variable("int32", stop_gradient=True)
+        outputs["RowsHeld"] = [rows]
     # the three grouped products as computed, for the backward op alone
-    products = {slot: [helper.create_tmp_variable(dtype, stop_gradient=True)]
-                for slot in ("GateOut", "UpOut", "DownOut")}
+    outputs.update(
+        {slot: [helper.create_tmp_variable(dtype, stop_gradient=True)]
+         for slot in ("GateOut", "UpOut", "DownOut")})
+    helper.append_op("moe_ffn", inputs, outputs, attrs)
+    return (y, aux, z, ids, load) + ((rows,) if held else ())
+
+
+def mhc_mix(x, epsilon=1e-6, sinkhorn_iters=20, clamp=(-30.0, 30.0),
+            prefix=None, name=None):
+    """The mixers of a residual path of n streams before one sublayer
+    (manifold-constrained hyper-connections, arXiv:2512.24880; the op's
+    doc has the equations): x [n, T, C], stream-major -> (the sublayer's
+    input [T, C], HPost [T, n], HRes [T, n, n] doubly stochastic). Parameters
+    `<prefix>phi_pre`, `phi_post`, `phi_res` (normal, std 0.02), `alpha`
+    [3] (0.01), `b_pre`, `b_post`, `b_res` (normal, std 1: the streams
+    start as copies, and equal mixers would keep them so), float32."""
+    helper = LayerHelper("mhc_mix", **locals())
+    n, T, C = int(x.shape[0]), x.shape[1], int(x.shape[2])
+    prefix = prefix or helper.name + "."
+
+    def param(suffix, shape, init):
+        return helper.create_parameter(
+            attr=ParamAttr(name=prefix + suffix, initializer=init),
+            shape=shape, dtype="float32")
+
+    params = {
+        "PhiPre": param("phi_pre", [n * C, n], Normal(0.0, 0.02)),
+        "PhiPost": param("phi_post", [n * C, n], Normal(0.0, 0.02)),
+        "PhiRes": param("phi_res", [n * C, n * n], Normal(0.0, 0.02)),
+        "Alpha": param("alpha", [3], Constant(0.01)),
+        "BPre": param("b_pre", [n], Normal(0.0, 1.0)),
+        "BPost": param("b_post", [n], Normal(0.0, 1.0)),
+        "BRes": param("b_res", [n * n], Normal(0.0, 1.0))}
+    u = helper.create_tmp_variable(x.dtype, shape=(T, C))
+    h_post = helper.create_tmp_variable("float32", shape=(T, n))
+    h_res = helper.create_tmp_variable("float32", shape=(T, n, n))
     helper.append_op(
-        "moe_ffn",
-        {"X": [input], "Router": [router], "Gate": [gate], "Up": [up],
-         "Down": [down]},
-        {"Out": [y], "AuxLoss": [aux], "ZLoss": [z], "ExpertIds": [ids],
-         "TokensPerExpert": [load], **products},
-        {"top_k": int(top_k)})
-    return y, aux, z, ids, load
+        "mhc_mix", dict({"X": [x]}, **{k: [v] for k, v in params.items()}),
+        {"U": [u], "HPost": [h_post], "HRes": [h_res]},
+        {"epsilon": float(epsilon), "sinkhorn_iters": int(sinkhorn_iters),
+         "clamp_min": float(clamp[0]), "clamp_max": float(clamp[1])})
+    return u, h_post, h_res
+
+
+def mhc_expand(x, streams, name=None):
+    """x [T, C] copied into the `streams` streams of a residual path
+    [streams, T, C]."""
+    helper = LayerHelper("mhc_expand", **locals())
+    o = helper.create_tmp_variable(
+        x.dtype, shape=(int(streams), x.shape[0], x.shape[1]))
+    helper.append_op("mhc_expand", {"X": [x]}, {"Out": [o]},
+                     {"streams": int(streams)})
+    return o
+
+
+def mhc_update(x, h_res, h_post, y, name=None):
+    """The residual path's step around a sublayer: out_i = sum_j h_res_ij
+    x_j + h_post_i y on x [n, T, C], y [T, C]."""
+    helper = LayerHelper("mhc_update", **locals())
+    o = helper.create_tmp_variable(x.dtype, shape=x.shape)
+    helper.append_op("mhc_update", {"X": [x], "HRes": [h_res],
+                                    "HPost": [h_post], "Y": [y]},
+                     {"Out": [o]})
+    return o
 
 
 def conv2d_transpose(input, num_filters, output_size=None, filter_size=None,

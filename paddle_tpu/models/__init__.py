@@ -4,7 +4,11 @@ Each module exposes get_model(args) -> (avg_cost, inference_program,
 optimizer, train_reader, test_reader, batch_acc). args needs .batch_size and
 .data_set ("cifar10" | "flowers" | ...). `olmoe` (a decoder language model
 with sparse experts) is built from a configuration instead: `olmoe.olmoe(tokens,
-cfg)`, `olmoe.olmoe_loss`, `olmoe.optimizer`.
+cfg)`, `olmoe.olmoe_loss`, `olmoe.optimizer`; so is `xing4` (latent
+attention, a residual path of several streams, held and shared experts, a
+multi-token-prediction module, as one chip's share of each layer):
+`xing4.xing4(tokens, next_tokens, cfg)`, `xing4.xing4_loss`,
+`xing4.optimizer`.
 """
 
 from . import mnist
@@ -14,9 +18,10 @@ from . import se_resnext
 from . import stacked_dynamic_lstm
 from . import machine_translation
 from . import olmoe
+from . import xing4
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
-           "machine_translation", "olmoe"]
+           "machine_translation", "olmoe", "xing4"]
 
 
 def get_model(name):

@@ -13,6 +13,7 @@ gather is a scatter-add, which a TPU serialises).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -37,14 +38,42 @@ def rms_norm_op(ctx, ins, attrs):
     return out(Y=(y * scale.astype(F32)).astype(x.dtype))
 
 
+def rotary_frequencies(D, theta, factor=1.0, beta_fast=32.0, beta_slow=1.0,
+                       original_max_position=4096):
+    """The D / 2 angular frequencies theta^(-2i/D), float32. With `factor`
+    > 1 YaRN's (Peng et al., arXiv:2309.00071, as DeepSeek-V3's modelling
+    code computes them): a pair that turns more than `beta_fast` times
+    over the original context keeps its frequency, one that turns fewer
+    than `beta_slow` times has it divided by `factor`, and a linear ramp
+    over the pair index lies between."""
+    i = jnp.arange(0, D, 2, dtype=jnp.float32)
+    freq = 1.0 / (float(theta) ** (i / D))
+    if factor <= 1.0:
+        return freq
+
+    def pair_of(turns):
+        return (D * math.log(original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(float(theta))))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), D - 1)
+    ramp = jnp.clip((jnp.arange(D // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freq / factor * ramp + freq * (1.0 - ramp)
+
+
 @register_op("rotary_embedding")
 def rotary_embedding_op(ctx, ins, attrs):
     """X [B, S, H, D] -> Out: each head's two halves rotated by the angle
-    position * theta^(-2i/D) (`rotate_half`), position = index along S."""
+    position * frequency_i (`rotate_half`), position = index along S;
+    the frequencies are theta^(-2i/D), or YaRN's where `scaling_factor` >
+    1 (`rotary_frequencies`)."""
     x = first(ins, "X")
     S, D = x.shape[1], x.shape[3]
-    inv_freq = 1.0 / (attrs.get("theta", 10000.0)
-                      ** (jnp.arange(0, D, 2, dtype=F32) / D))
+    inv_freq = rotary_frequencies(
+        D, attrs.get("theta", 10000.0), attrs.get("scaling_factor", 1.0),
+        attrs.get("beta_fast", 32.0), attrs.get("beta_slow", 1.0),
+        attrs.get("original_max_position", 4096)).astype(F32)
     ang = jnp.arange(S, dtype=F32)[:, None] * inv_freq[None, :]
     ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
     xf = x.astype(F32)
@@ -58,13 +87,13 @@ def on_tpu():
     return places.trace_device().platform == "tpu"
 
 
-def _plain_causal_attention(q, k, v):
-    """softmax(Q K^T / sqrt(D) + mask) V on [B, H, S, D], float32 scores,
-    and their logsumexp [B, H, S]: the lowering for places without
-    Mosaic."""
+def _plain_causal_attention(q, k, v, scale=None):
+    """softmax(Q K^T * scale + mask) V on Q, K [B, H, S, D] and V [B, H,
+    S, Dv] (`scale` None: 1 / sqrt(D)), float32 scores, and their
+    logsumexp [B, H, S]: the lowering for places without Mosaic."""
     S, D = q.shape[2], q.shape[3]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=F32) / (D ** 0.5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=F32)
+    s = s / (D ** 0.5) if scale is None else s * scale
     mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
     s = jnp.where(mask, s, -jnp.inf)
     lse = jax.nn.logsumexp(s, axis=-1)
@@ -93,19 +122,22 @@ def _heads_first(ins, *slots):
 
 @register_op("causal_attention")
 def causal_attention_op(ctx, ins, attrs):
-    """Q, K, V [B, S, H, D] -> Out [B, S, H, D], causal over the whole row,
-    scale 1/sqrt(D), and Lse [B, H, S] (the scores' logsumexp, kept for the
-    backward). On a TPU place the Pallas flash kernel (parallel/flash.py),
-    which never writes the [S, S] scores to HBM; elsewhere the plain
-    composition."""
+    """Q, K [B, S, H, D], V [B, S, H, Dv] (Dv may differ from D: latent
+    attention's keys carry a rotary part its values lack) -> Out [B, S, H,
+    Dv], causal over the whole row, and Lse [B, H, S] (the scores'
+    logsumexp, kept for the backward). The scores are scaled by the attr
+    `scale`, 1/sqrt(D) where it is 0. On a TPU place the Pallas flash
+    kernel (parallel/flash.py), which never writes the [S, S] scores to
+    HBM; elsewhere the plain composition."""
     q, k, v = _heads_first(ins, "Q", "K", "V")
+    scale = float(attrs.get("scale", 0.0)) or None
     if on_tpu():
         from ..parallel.flash import flash_attention_fwd
 
-        o, lse = flash_attention_fwd(q, k, v, causal=True,
+        o, lse = flash_attention_fwd(q, k, v, causal=True, scale=scale,
                                      **FLASH_FWD_BLOCKS)
     else:
-        o, lse = _plain_causal_attention(q, k, v)
+        o, lse = _plain_causal_attention(q, k, v, scale)
     return out(Out=jnp.swapaxes(o, 1, 2), Lse=lse)
 
 
@@ -133,14 +165,16 @@ def causal_attention_grad_op(ctx, ins, attrs):
     saved output and logsumexp; elsewhere the vjp of the plain
     composition."""
     q, k, v, o, do = _heads_first(ins, "Q", "K", "V", "Out", "Out@GRAD")
+    scale = float(attrs.get("scale", 0.0)) or None
     if on_tpu():
         from ..parallel.flash import flash_attention_bwd
 
         grads = flash_attention_bwd(q, k, v, o, first(ins, "Lse"),
                                     do.astype(q.dtype), causal=True,
-                                    **FLASH_BWD_BLOCKS)
+                                    scale=scale, **FLASH_BWD_BLOCKS)
     else:
-        _, vjp = jax.vjp(lambda *a: _plain_causal_attention(*a)[0], q, k, v)
+        _, vjp = jax.vjp(
+            lambda *a: _plain_causal_attention(*a, scale)[0], q, k, v)
         grads = vjp(do.astype(q.dtype))
     dq, dk, dv = (jnp.swapaxes(g, 1, 2) for g in grads)
     return out(**{"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv})
@@ -182,73 +216,130 @@ def _unsort_bwd(order, g):
 _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
+class Routing:
+    """How `moe_ffn` scores, chooses and weighs, and which experts the
+    layer holds; static, from the op's attributes. The defaults are
+    OLMoE's: softmax scores, the k largest, their scores as weights, every
+    expert held."""
+
+    def __init__(self, attrs, n_experts):
+        self.top_k = int(attrs.get("top_k", 1))
+        self.score_func = attrs.get("score_func", "softmax")
+        self.norm_topk = bool(attrs.get("norm_topk", False))
+        self.scale = float(attrs.get("routed_scale", 1.0))
+        self.first = int(attrs.get("first_expert", 0))
+        self.held = int(attrs.get("held_experts", 0)) or n_experts
+        self.all_held = self.first == 0 and self.held == n_experts
+
+
 def moe_ffn(x, router, gate, up, down, top_k):
-    """The expert layer on tokens x [T, H]: the five outputs of
-    `moe_ffn_op`."""
-    return _moe_ffn(x, router, gate, up, down, top_k)[0]
+    """The expert layer on tokens x [T, H], every expert held, OLMoE's
+    routing: `moe_ffn_op`'s outputs Out, AuxLoss, ZLoss, ExpertIds,
+    TokensPerExpert."""
+    return _moe_ffn(x, router, None, gate, up, down,
+                    Routing({"top_k": top_k}, router.shape[1]))[0][:5]
 
 
-def _moe_ffn(x, router, gate, up, down, top_k, products=None):
-    """The op's five outputs and the three grouped products' results
+def _moe_ffn(x, router, bias, gate, up, down, r, products=None):
+    """The op's six outputs and the three grouped products' results
     (gate, up, down, rows in expert order). Given those as `products` (the
     backward op hands over what the forward left) no product is computed
     again: the kernels then run for the gradients alone."""
     from ..parallel.grouped import grouped_dot
 
-    T, E = x.shape[0], router.shape[1]
-    # router, softmax and top-k in float32 at full precision whatever the
+    T, E, top_k = x.shape[0], router.shape[1], r.top_k
+    # router, scores and top-k in float32 at full precision whatever the
     # compute dtype: a bf16 logit moves the discrete choice
     logits = jnp.dot(x.astype(F32), router.astype(F32),
                      precision=lax.Precision.HIGHEST)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    top_p, top_e = lax.top_k(probs, top_k)                  # [T, k]
+    if r.score_func == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jnp.exp(logits - lse[:, None])
+    if bias is None:
+        top_p, top_e = lax.top_k(probs, top_k)              # [T, k]
+    else:
+        # chosen by score + bias, weighed by the score alone; the bias is
+        # not trained through the loss
+        _, top_e = lax.top_k(probs + lax.stop_gradient(bias.astype(F32)),
+                             top_k)
+        top_p = jnp.take_along_axis(probs, top_e, axis=1)
+    if r.norm_topk:
+        top_p = top_p / (jnp.sum(top_p, axis=1, keepdims=True) + 1e-20)
+    if r.scale != 1.0:
+        top_p = top_p * r.scale
     flat_e = top_e.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    if r.all_held:
+        key = flat_e
+    else:
+        # rows of the held experts first, in expert order; the others'
+        # rows after them, where no product visits them
+        local = flat_e - r.first
+        is_held = (local >= 0) & (local < r.held)
+        key = jnp.where(is_held, local, r.held)
+        top_p = jnp.where(is_held.reshape(T, top_k), top_p, 0.0)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     inv = jnp.argsort(order).astype(jnp.int32)
     counts = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
                      dtype=jnp.int32)
+    held_counts = counts if r.all_held else jnp.sum(
+        key[:, None] == jnp.arange(r.held)[None, :], axis=0,
+        dtype=jnp.int32)
     # grouped products over the rows each expert really received: Pallas
     # kernels on a TPU place, `lax.ragged_dot` elsewhere
     saved = products or (None, None, None)
+    past = not r.all_held
     xs = _dispatch(x, order, inv, top_k)                    # [T*k, H]
-    a = grouped_dot(xs, gate, counts, saved[0])
-    b = grouped_dot(xs, up, counts, saved[1])
-    ys = grouped_dot(jax.nn.silu(a) * b, down, counts, saved[2])
+    a = grouped_dot(xs, gate, held_counts, saved[0], past)
+    b = grouped_dot(xs, up, held_counts, saved[1], past)
+    ys = grouped_dot(jax.nn.silu(a) * b, down, held_counts, saved[2], past)
     y = _unsort(ys, order, inv).reshape(T, top_k, -1)
     o = jnp.einsum("tkh,tk->th", y.astype(F32), top_p)
-    # load balance: E * sum_e (share of routing slots on e) * (mean prob of
-    # e); the shares are counts and carry no gradient. z-loss: mean lse^2
+    # load balance: E * sum_e (share of routing slots on e) * (mean score
+    # of e); the shares are counts and carry no gradient. z-loss: mean
+    # lse^2
     share = counts.astype(F32) / (T * top_k)
     aux = E * jnp.sum(share * jnp.mean(probs, axis=0))
     return ((o.astype(x.dtype), aux.reshape(1),
              jnp.mean(jnp.square(lse)).reshape(1), top_e.astype(jnp.int32),
-             counts), (a, b, ys))
+             counts, jnp.sum(held_counts).reshape(1)), (a, b, ys))
 
 
-_MOE_INPUTS = ("X", "Router", "Gate", "Up", "Down")
+_MOE_INPUTS = ("X", "Router", "Bias", "Gate", "Up", "Down")
+_MOE_TRAINED = tuple(s for s in _MOE_INPUTS if s != "Bias")
 _MOE_PRODUCTS = ("GateOut", "UpOut", "DownOut")
 
 
 @register_op("moe_ffn")
 def moe_ffn_op(ctx, ins, attrs):
-    """X [T, H], Router [H, E], Gate / Up [E, H, F], Down [E, F, H] ->
-    Out_t = sum over the top_k experts e of p_te * Down_e(silu(Gate_e x_t)
-    * Up_e x_t), p = softmax(Router x) NOT renormalised over the chosen;
-    AuxLoss [1], ZLoss [1], ExpertIds [T, top_k], TokensPerExpert [E].
-    Tokens are sorted by expert and the three products are grouped over
-    the rows routed (`parallel/grouped.py: grouped_dot`): no capacity, no
+    """X [T, H], Router [H, E], Gate / Up [E', H, F], Down [E', F, H] ->
+    Out_t = sum over the top_k experts e of w_te * Down_e(silu(Gate_e x_t)
+    * Up_e x_t); AuxLoss [1], ZLoss [1], ExpertIds [T, top_k],
+    TokensPerExpert [E], RowsHeld [1]. Attributes, defaults OLMoE's:
+    `score_func` softmax | sigmoid over the router's E logits; the chosen
+    are the top_k by score, plus Bias [E] where that input is given (it
+    takes no gradient); w is the chosen scores, divided by their sum with
+    `norm_topk`, times `routed_scale`. The layer holds the E' =
+    `held_experts` experts from `first_expert` on (0: all E): a chosen
+    expert it does not hold adds nothing to Out, the router still scores
+    all E and TokensPerExpert counts all E; RowsHeld is how many of the T
+    * top_k rows the held experts received. Tokens are sorted by expert,
+    the held ones first, and the three products are grouped over the rows
+    routed to them (`parallel/grouped.py: grouped_dot`): no capacity, no
     dropped token, no padding to a per-expert size. GateOut, UpOut [T *
     top_k, F] and DownOut [T * top_k, H] are those products as computed,
     kept for the backward op."""
-    (o, aux, z, ids, counts), products = _moe_ffn(
-        *(first(ins, s) for s in _MOE_INPUTS), int(attrs.get("top_k", 1)))
+    args = [first(ins, s) for s in _MOE_INPUTS]
+    (o, aux, z, ids, counts, rows), products = _moe_ffn(
+        *args, Routing(attrs, args[1].shape[1]))
     return out(Out=o, AuxLoss=aux, ZLoss=z, ExpertIds=ids,
-               TokensPerExpert=counts, **dict(zip(_MOE_PRODUCTS, products)))
+               TokensPerExpert=counts, RowsHeld=rows,
+               **dict(zip(_MOE_PRODUCTS, products)))
 
 
 set_stop_gradient_outputs(
-    "moe_ffn", ["ExpertIds", "TokensPerExpert", *_MOE_PRODUCTS])
+    "moe_ffn", ["ExpertIds", "TokensPerExpert", "RowsHeld", *_MOE_PRODUCTS])
 
 
 @register_grad_maker("moe_ffn")
@@ -257,14 +348,15 @@ def _moe_ffn_grad_maker(op, gout, gin):
     vjp evaluates the forward again, XLA does not merge two Mosaic calls,
     and the three forward kernels would run twice a step. The backward op
     takes the products as the forward left them."""
-    inputs = {s: op.input(s) for s in _MOE_INPUTS}
+    inputs = {s: op.input(s) for s in _MOE_INPUTS if op.input(s)}
     inputs.update({s: op.output(s) for s in _MOE_PRODUCTS if op.output(s)})
     for s in ("Out", "AuxLoss", "ZLoss"):
         if any(gout.get(s) or []):
             inputs[s + "@GRAD"] = [x or "" for x in gout[s]]
     return [dict(
         type="moe_ffn_grad", inputs=inputs,
-        outputs={s + "@GRAD": list(names) for s, names in gin.items()},
+        outputs={s + "@GRAD": list(names) for s, names in gin.items()
+                 if s != "Bias"},
         attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
 
 
@@ -272,13 +364,15 @@ def _moe_ffn_grad_maker(op, gout, gin):
 def moe_ffn_grad_op(ctx, ins, attrs):
     """The vjp of `moe_ffn`'s body at the forward's saved products (an op
     built without them computes them here)."""
-    primals = [first(ins, s) for s in _MOE_INPUTS]
+    primals = [first(ins, s) for s in _MOE_TRAINED]
+    bias = first(ins, "Bias")
+    routing = Routing(attrs, primals[1].shape[1])
     products = tuple(first(ins, s) for s in _MOE_PRODUCTS)
     if any(p is None for p in products):
         products = None
 
-    def fn(*args):
-        return _moe_ffn(*args, int(attrs.get("top_k", 1)), products)[0][:3]
+    def fn(x, router, *weights):
+        return _moe_ffn(x, router, bias, *weights, routing, products)[0][:3]
 
     outs, vjp = jax.vjp(fn, *primals)
     cots = []
@@ -286,8 +380,222 @@ def moe_ffn_grad_op(ctx, ins, attrs):
         g = first(ins, slot + "@GRAD")
         cots.append(jnp.zeros_like(o) if g is None
                     else g.astype(o.dtype).reshape(o.shape))
-    return out(**{s + "@GRAD": g for s, g in zip(_MOE_INPUTS,
+    return out(**{s + "@GRAD": g for s, g in zip(_MOE_TRAINED,
                                                   vjp(tuple(cots)))})
+
+
+# ------------------------------------------------------- mhc_mix, mhc_update
+def sinkhorn(m, iters, eps):
+    """`iters` rounds of row then column normalisation of the positive
+    [..., n, n] matrices m, `eps` in the denominators: towards the doubly
+    stochastic matrix with m's pattern (Sinkhorn & Knopp, 1967)."""
+    for _ in range(int(iters)):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+_MHC_PARAMS = ("PhiPre", "PhiPost", "PhiRes", "Alpha", "BPre", "BPost",
+               "BRes")
+
+
+def _three_bf16(w):
+    """A float32 matrix as three bfloat16 ones side by side (the value,
+    what rounding it lost, what rounding that lost): their sum carries 24
+    bits, so a product of bfloat16 rows with them, accumulated in float32
+    and summed over the three, is the product with the float32 matrix. The
+    rows are never widened: a [T, n * C] state in float32 is twice the
+    state."""
+    parts, rest = [], w.astype(jnp.float32)
+    for _ in range(3):
+        parts.append(rest.astype(jnp.bfloat16))
+        rest = rest - parts[-1].astype(jnp.float32)
+    return jnp.concatenate(parts, axis=1)
+
+
+def _sum_of_three(p):
+    k = p.shape[1] // 3
+    return p[:, :k] + p[:, k:2 * k] + p[:, 2 * k:]
+
+
+def _exact_dot(a, b, dims):
+    """dot_general(a, b) to float32's precision, float32 out; b's free
+    dimension is its last. Where a is bfloat16 and b float32 (the state
+    under AMP against the mixers' float32 matrices, or against their
+    float32 gradients) a stays as it is and b is `_three_bf16`; otherwise
+    one product at the highest precision in the mixers' own dtype
+    (float32, or bfloat16 in a study one precision down)."""
+    if (a.dtype == jnp.bfloat16 and b.dtype == jnp.float32
+            and F32 == jnp.float32):
+        return _sum_of_three(lax.dot_general(
+            a, _three_bf16(b), dims, preferred_element_type=jnp.float32))
+    return lax.dot_general(a.astype(F32), b.astype(F32), dims,
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _mixers(proj, inv_rms, alpha, b_pre, b_post, b_res, n, opts):
+    """The per-token coefficients from the raw projections [T, 2n + n*n]
+    and the state's inverse rms [T, 1]: HPre [T, n], HPost [T, n], HRes
+    [T, n, n]; a few values a token, all in the mixers' dtype."""
+    eps, iters, lo, hi = opts
+    proj = proj.astype(F32) * inv_rms.astype(F32)
+    h_pre = jax.nn.sigmoid(alpha[0] * proj[:, :n] + b_pre)
+    h_post = 2.0 * jax.nn.sigmoid(alpha[1] * proj[:, n:2 * n] + b_post)
+    res = jnp.clip(alpha[2] * proj[:, 2 * n:] + b_res, lo, hi)
+    h_res = sinkhorn(jnp.exp(res).reshape(-1, n, n), iters, eps)
+    return h_pre, h_post, h_res
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _mhc_mix(x, phi, alpha, b_pre, b_post, b_res, opts):
+    return _mhc_mix_fwd(x, phi, alpha, b_pre, b_post, b_res, opts)[0]
+
+
+def _mhc_mix_fwd(x, phi, alpha, b_pre, b_post, b_res, opts):
+    """x [n, T, C] (the streams are the slow dimension: each is a plain
+    [T, C] array to the compiler, where [T, n, C] put 4 rows in tiles of
+    8 or 16), phi [n, C, 2n + n*n]."""
+    n, T, C = x.shape
+    mean_sq = sum(jnp.sum(jnp.square(x[i].astype(F32)), axis=-1,
+                          keepdims=True) for i in range(n)) / (n * C)
+    inv_rms = lax.rsqrt(mean_sq + opts[0])
+    # the norm is one factor a row: applied to the 2n + n * n projections,
+    # not to the n * C values
+    proj = sum(_exact_dot(x[i], phi[i], (((1,), (0,)), ((), ())))
+               for i in range(n))
+    h_pre, h_post, h_res = _mixers(proj, inv_rms, alpha, b_pre, b_post,
+                                   b_res, n, opts)
+    u = sum(h_pre[:, i, None] * x[i].astype(F32) for i in range(n))
+    return ((u.astype(x.dtype), h_post.astype(jnp.float32),
+             h_res.astype(jnp.float32)),
+            (x, phi, alpha, b_pre, b_post, b_res, proj, inv_rms))
+
+
+def _mhc_mix_bwd(opts, saved, cots):
+    """By hand, so that the state is read in its own dtype and nothing of
+    its size is written but its gradient: the coefficients' part is the
+    vjp of `_mixers` on a few values a token; a stream's gradient is one
+    elementwise pass over HPre_i dU, the projections' gradient times
+    Phi_i^T and the rms's share of x_i."""
+    x, phi, alpha, b_pre, b_post, b_res, proj, inv_rms = saved
+    d_u, d_post, d_res = cots
+    n, T, C = x.shape
+    (h_pre, _, _), small_vjp = jax.vjp(
+        lambda *a: _mixers(*a, n, opts), proj, inv_rms, alpha.astype(F32),
+        b_pre.astype(F32), b_post.astype(F32), b_res.astype(F32))
+    d_uf = d_u.astype(F32)
+    d_pre = jnp.stack([jnp.sum(d_uf * x[i].astype(F32), axis=-1)
+                       for i in range(n)], axis=1)
+    d_proj, d_inv, d_alpha, d_bpre, d_bpost, d_bres = small_vjp(
+        (d_pre.astype(h_pre.dtype), d_post.astype(F32), d_res.astype(F32)))
+    d_proj = d_proj.astype(jnp.float32)
+    # d inv_rms / d x = -inv_rms^3 x / (n C)
+    of_rms = (-d_inv.astype(jnp.float32) * inv_rms.astype(jnp.float32) ** 3
+              / (n * C))
+    d_x, d_phi = [], []
+    for i in range(n):
+        d_phi.append(_exact_dot(x[i], d_proj, (((0,), (0,)), ((), ()))))
+        through = lax.dot_general(
+            d_proj.astype(x.dtype), phi[i].astype(x.dtype),
+            (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST)
+        d_x.append((h_pre.astype(jnp.float32)[:, i, None] * d_uf
+                    + through.astype(jnp.float32)
+                    + of_rms * x[i].astype(jnp.float32)).astype(x.dtype))
+    return (jnp.stack(d_x), jnp.stack(d_phi).astype(phi.dtype),
+            d_alpha.astype(alpha.dtype), d_bpre.astype(b_pre.dtype),
+            d_bpost.astype(b_post.dtype), d_bres.astype(b_res.dtype))
+
+
+_mhc_mix.defvjp(_mhc_mix_fwd, _mhc_mix_bwd)
+
+
+@register_op("mhc_mix")
+def mhc_mix_op(ctx, ins, attrs):
+    """The three mixers of a residual path of n streams (Xie et al., mHC:
+    Manifold-Constrained Hyper-Connections, arXiv:2512.24880) before one
+    sublayer. X [n, T, C], stream-major; with x~ = RMSNorm over all n * C
+    values of a token (no learned scale, `epsilon`), laid side by side
+    stream after stream:
+
+        HPre  [T, n]    = sigmoid(Alpha[0] * x~ PhiPre + BPre)
+        HPost [T, n]    = 2 sigmoid(Alpha[1] * x~ PhiPost + BPost)
+        HRes  [T, n, n] = Sinkhorn(exp(clip(Alpha[2] * mat(x~ PhiRes) +
+                          BRes, clamp_min, clamp_max)))
+        U     [T, C]    = sum_i HPre_i x_i      (the sublayer's input)
+
+    PhiPre, PhiPost [n * C, n], PhiRes [n * C, n * n], Alpha [3], BPre,
+    BPost [n], BRes [n * n]. Everything but U in float32 whatever X's
+    dtype (the projections to float32's precision, `_exact_dot`: a
+    per-token matrix that multiplies the whole state); U in X's dtype."""
+    x = first(ins, "X")
+    phi_pre, phi_post, phi_res, alpha, b_pre, b_post, b_res = (
+        first(ins, s) for s in _MHC_PARAMS)
+    n, _, C = x.shape
+    opts = (float(attrs.get("epsilon", 1e-6)),
+            int(attrs.get("sinkhorn_iters", 20)),
+            float(attrs.get("clamp_min", -30.0)),
+            float(attrs.get("clamp_max", 30.0)))
+    phi = jnp.concatenate([phi_pre, phi_post, phi_res], axis=1)
+    u, h_post, h_res = _mhc_mix(x, phi.reshape(n, C, -1), alpha, b_pre,
+                                b_post, b_res, opts)
+    return out(U=u, HPost=h_post, HRes=h_res)
+
+
+@register_op("mhc_expand")
+def mhc_expand_op(ctx, ins, attrs):
+    """X [T, C] -> Out [n, T, C]: the n streams of a residual path start
+    as copies of the embedding (attr `streams`)."""
+    x = first(ins, "X")
+    return out(Out=jnp.broadcast_to(x[None], (int(attrs["streams"]),)
+                                    + x.shape))
+
+
+@jax.custom_vjp
+def _mhc_update(x, h_res, h_post, y):
+    return _mhc_update_fwd(x, h_res, h_post, y)[0]
+
+
+def _mhc_update_fwd(x, h_res, h_post, y):
+    n = x.shape[0]
+    yf = y.astype(F32)
+    h_res, h_post = h_res.astype(F32), h_post.astype(F32)
+    o = jnp.stack([
+        sum(h_res[:, i, j, None] * x[j].astype(F32) for j in range(n))
+        + h_post[:, i, None] * yf for i in range(n)])
+    return o.astype(x.dtype), (x, h_res, h_post, y)
+
+
+def _mhc_update_bwd(saved, g):
+    """By hand: each stream a plain [T, C] pass. Through the generic vjp
+    of the forward the step spent 2.5 ms an update where this takes 0.2
+    (v5e, 4096 x 4 x 3584 bf16; tools/mhc_sweep.py, PERF.md PR 30)."""
+    x, h_res, h_post, y = saved
+    n = x.shape[0]
+    gf = [g[i].astype(F32) for i in range(n)]
+    xf = [x[j].astype(F32) for j in range(n)]
+    yf = y.astype(F32)
+    d_x = jnp.stack([sum(h_res[:, i, j, None] * gf[i] for i in range(n))
+                     for j in range(n)]).astype(x.dtype)
+    d_y = sum(h_post[:, i, None] * gf[i] for i in range(n)).astype(y.dtype)
+    d_res = jnp.stack([jnp.stack([jnp.sum(gf[i] * xf[j], axis=-1)
+                                  for j in range(n)], axis=-1)
+                       for i in range(n)], axis=1)
+    d_post = jnp.stack([jnp.sum(gf[i] * yf, axis=-1) for i in range(n)],
+                       axis=-1)
+    return (d_x, d_res.astype(jnp.float32), d_post.astype(jnp.float32), d_y)
+
+
+_mhc_update.defvjp(_mhc_update_fwd, _mhc_update_bwd)
+
+
+@register_op("mhc_update")
+def mhc_update_op(ctx, ins, attrs):
+    """The residual path's step around one sublayer: X [n, T, C], HRes [T,
+    n, n], HPost [T, n], Y [T, C] (the sublayer's output) -> Out_i = sum_j
+    HRes_ij X_j + HPost_i Y, summed in float32, in X's dtype."""
+    return out(Out=_mhc_update(*(first(ins, s)
+                                 for s in ("X", "HRes", "HPost", "Y"))))
 
 
 def _kernels_take(op, block):
@@ -301,23 +609,32 @@ def _kernels_take(op, block):
     return grouped.takes(rows, gate[1], gate[2])
 
 
+def _holds_a_share(op, block):
+    """A `moe_ffn` that holds some of the experts its router scores."""
+    return bool(op.attrs.get("held_experts", 0)) and (
+        op.attrs["held_experts"]
+        < block.vars[op.input("Router")[0]].shape[1])
+
+
 # (op type, counter, whether the lowering exists on a TPU place only,
 # which of those ops count: all when None)
 _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("moe_ffn", "grouped_matmul_kernel", True, _kernels_take),
             ("causal_attention", "flash_attention", True, None),
-            ("causal_attention_grad", "flash_attention_bwd", True, None))
+            ("causal_attention_grad", "flash_attention_bwd", True, None),
+            ("moe_ffn", "moe_ffn_held_experts", False, _holds_a_share))
 
 
 def lowered_counts(program, device):
     """{counter: n} for the step spans and the registry: `moe_ffn` ops of
     the program (each lowers through the grouped products; on a TPU place
     those whose shapes the Pallas grouped-matmul kernels take count as
-    `grouped_matmul_kernel` too) and, on a TPU place, its
-    `causal_attention` ops (each lowers through the flash kernel) and
-    `causal_attention_grad` ops (each through the two backward kernels). A
-    program without them reports none. Kept on the program until that is
-    mutated, like `bn_pool.count`."""
+    `grouped_matmul_kernel` too; those that hold a share of their experts
+    as `moe_ffn_held_experts`) and, on a TPU place, its `causal_attention`
+    ops (each lowers through the flash kernel) and `causal_attention_grad`
+    ops (each through the two backward kernels). A program without them
+    reports none. Kept on the program until that is mutated, like
+    `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)
     if memo is None or memo[0] != program._mutation:
         ops = [(op, b) for b in program.blocks for op in b.ops]

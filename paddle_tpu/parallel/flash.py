@@ -135,8 +135,8 @@ def _fwd_padded(q, k, v, scale, causal, block_q, block_k):
     """Pad S to block multiples; padded KEYS are neutralized by extending D
     with a bias channel (q gains a 1, real keys a 0, padded keys -BIG), so
     their scores vanish under exp without any in-kernel mask plumbing."""
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    B, H, Sq, _ = q.shape
+    Sk, Dv = k.shape[2], v.shape[3]
     pad_q = (-Sq) % block_q
     pad_k = (-Sk) % block_k
     qw, kw, vw = q, k, v
@@ -156,8 +156,8 @@ def _fwd_padded(q, k, v, scale, causal, block_q, block_k):
     Dk = qw.shape[-1]
     out, lse = _flash_fwd(
         qw.reshape(BH, Sq + pad_q, Dk), kw.reshape(BH, Sk + pad_k, Dk),
-        vw.reshape(BH, Sk + pad_k, D), scale, causal, block_q, block_k)
-    out = out.reshape(B, H, Sq + pad_q, D)[:, :, :Sq]
+        vw.reshape(BH, Sk + pad_k, Dv), scale, causal, block_q, block_k)
+    out = out.reshape(B, H, Sq + pad_q, Dv)[:, :, :Sq]
     lse = lse[:, 0, :].reshape(B, H, Sq + pad_q)[:, :, :Sq]
     return out, lse
 
@@ -347,7 +347,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dq_ref, dq_scr, *,
 
 def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
                kv_len):
-    """q, do [BH, Sq, D], k, v [BH, Sk, D], lse, delta [BH, Sq] float32
+    """q [BH, Sq, D], k [BH, Sk, D], v [BH, Sk, Dv], do [BH, Sq, Dv] (Dv
+    may differ from D, as in the forward), lse, delta [BH, Sq] float32
     (Sq % block_q == 0, Sk % block_k == 0; keys at and past `kv_len`, if
     given, are padding) -> dq, dk, dv. Two kernels: dK/dV with the query
     blocks innermost, dQ with the key blocks innermost, each recomputing
@@ -355,7 +356,7 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
     causal frontier is skipped, and its index maps name the block of the
     nearest visited step, so it moves nothing either."""
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
     if causal:
         # dK/dV: the first query block that sees key block j (skipped steps
@@ -385,18 +386,19 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, q_of(j, i), 0)),
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, q_of(j, i), 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_q, Dv),
+                         lambda b, j, i: (b, q_of(j, i), 0)),
             pl.BlockSpec((1, 2, block_q), lambda b, j, i: (b, 0, q_of(j, i))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=params,
         interpret=pallas_interpret(),
     )(q, k, v, do, ld)
@@ -407,8 +409,9 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, k_of(i, j), 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, k_of(i, j), 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, Dv),
+                         lambda b, i, j: (b, k_of(i, j), 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 2), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),

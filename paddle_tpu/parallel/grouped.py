@@ -301,44 +301,59 @@ def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype):
     )(*meta, lhs, rhs)
 
 
-def _product(lhs, rhs, counts, tiles):
+def _rows_of_groups(x, counts):
+    """x with the rows past the groups' last set to zero: the kernels
+    never visit them, so what the result holds there is whatever the
+    buffer held (`lax.ragged_dot` writes zeros itself)."""
+    rows = lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+    return jnp.where(rows < jnp.sum(counts), x, jnp.zeros((), x.dtype))
+
+
+def _product(lhs, rhs, counts, tiles, rows_past):
     if tiles is None:
         return lax.ragged_dot(lhs, rhs, group_sizes=counts,
                               preferred_element_type=lhs.dtype)
     tm, fwd, _, _ = tiles
     with jax.named_scope(_SCOPE):
         meta, n = visits(counts, lhs.shape[0], tm, False)
-        return _gmm(lhs, rhs, meta, n, (tm,) + fwd, False)
+        out = _gmm(lhs, rhs, meta, n, (tm,) + fwd, False)
+        return _rows_of_groups(out, counts) if rows_past else out
 
 
-def _gradients(lhs, rhs, counts, tiles, g):
+def _gradients(lhs, rhs, counts, tiles, rows_past, g):
     if tiles is None:
-        return jax.vjp(lambda a, b: _product(a, b, counts, None),
+        return jax.vjp(lambda a, b: _product(a, b, counts, None, rows_past),
                        lhs, rhs)[1](g)
     tm, _, dlhs, drhs = tiles
     with jax.named_scope(_SCOPE):
         meta, n = visits(counts, lhs.shape[0], tm, False)
         d_lhs = _gmm(g, rhs, meta, n, (tm,) + dlhs, True)
+        if rows_past:
+            d_lhs = _rows_of_groups(d_lhs, counts)
         meta, n = visits(counts, lhs.shape[0], tm, True)
         return d_lhs, _tgmm(lhs, g, meta, n, (tm,) + drhs, rhs.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def grouped_matmul(lhs, rhs, counts, out, tiles):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def grouped_matmul(lhs, rhs, counts, out, tiles, rows_past=False):
     """`grouped_dot` with its lowering named: `tiles` = (tm, (tk, tn) of
     the forward, of d lhs, of d rhs) for the kernels (`tiles_for`; N a
-    multiple of tm, K and M of their tiles), None for `lax.ragged_dot`."""
-    return _product(lhs, rhs, counts, tiles) if out is None else out
+    multiple of tm, K and M of their tiles), None for `lax.ragged_dot`;
+    `rows_past`: the groups may end before the rows do."""
+    if out is not None:
+        return out
+    return _product(lhs, rhs, counts, tiles, rows_past)
 
 
-def _matmul_fwd(lhs, rhs, counts, out, tiles):
-    return grouped_matmul(lhs, rhs, counts, out, tiles), (lhs, rhs, counts)
+def _matmul_fwd(lhs, rhs, counts, out, tiles, rows_past):
+    return (grouped_matmul(lhs, rhs, counts, out, tiles, rows_past),
+            (lhs, rhs, counts))
 
 
-def _matmul_bwd(tiles, res, g):
+def _matmul_bwd(tiles, rows_past, res, g):
     lhs, rhs, counts = res
-    return (*_gradients(lhs, rhs, counts, tiles, g.astype(lhs.dtype)),
-            None, None)
+    return (*_gradients(lhs, rhs, counts, tiles, rows_past,
+                        g.astype(lhs.dtype)), None, None)
 
 
 grouped_matmul.defvjp(_matmul_fwd, _matmul_bwd)
@@ -370,7 +385,12 @@ def tiles_for(n_rows, k, m, dtype):
     forward, a seeded step's real group sizes / even groups;
     `lax.ragged_dot` 2.96 / 2.04):
 
-      K and M tiles: the whole dimension up to 4 KiB of a row (2048 bf16).
+      K and M tiles: the whole dimension up to 8 KiB of a row (4096 bf16;
+        4 KiB until PR 30, which met K = 3584: [16384, 3584] x [8, 3584,
+        1024] over the 2052 rows the 8 groups held, forward and both
+        gradients, 1.005 ms with K whole against 1.031 split in two, and
+        the same to 0.03 ms for the row tiles 512, 256, 128; `lax.
+        ragged_dot` 1.07; tools/grouped_share_sweep.py, PERF.md PR 30).
         With all of K in one tile a group's weight tile keeps its block
         index from visit to visit and is fetched once a group, and the
         product needs no float32 scratch; a K tile of 1024 fetches the
@@ -390,21 +410,27 @@ def tiles_for(n_rows, k, m, dtype):
     if not takes(n_rows, k, m):
         return None
     tm = next(t for t in ROW_TILES if n_rows % t == 0)
-    wide = 4096 // jnp.dtype(dtype).itemsize
+    wide = 8192 // jnp.dtype(dtype).itemsize
     tk, tn = _largest_tile(k, wide), _largest_tile(m, wide)
     return tm, (tk, tn), (tn, tk), (tk, tn)
 
 
-def grouped_dot(lhs, rhs, counts, out=None):
+def grouped_dot(lhs, rhs, counts, out=None, rows_past=False):
     """lhs [N, K] (rows sorted by group), rhs [E, K, M], counts [E] int32
-    (they sum to N) -> [N, M] in lhs's dtype, float32 accumulation. The
-    Pallas kernels on a TPU place for shapes they take, `lax.ragged_dot`
-    otherwise (as `_plain_causal_attention` is for attention). `out`: the
-    product as an earlier call left it; it is returned as it is and the
-    call only carries the gradients (a backward op that was handed the
-    forward's result computes nothing twice)."""
+    -> [N, M] in lhs's dtype, float32 accumulation. The counts sum to N,
+    or, with `rows_past`, to the R <= N rows the groups hold (a layer that
+    holds some of its experts sorts their rows first): rows from R on
+    belong to no group, add nothing to d rhs, and come out zero in the
+    product and in d lhs; the kernels' grids end with the groups' last
+    visit, so the work follows R, not N. The Pallas kernels on a TPU place for
+    shapes they take, `lax.ragged_dot` otherwise (as
+    `_plain_causal_attention` is for attention). `out`: the product as an
+    earlier call left it; it is returned as it is and the call only
+    carries the gradients (a backward op that was handed the forward's
+    result computes nothing twice)."""
     tiles = None
     if on_tpu() and lhs.dtype == rhs.dtype:
         tiles = tiles_for(lhs.shape[0], lhs.shape[1], rhs.shape[2],
                           lhs.dtype)
-    return grouped_matmul(lhs, rhs, counts.astype(jnp.int32), out, tiles)
+    return grouped_matmul(lhs, rhs, counts.astype(jnp.int32), out, tiles,
+                          bool(rows_past))

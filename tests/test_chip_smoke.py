@@ -42,7 +42,7 @@ def test_last_stdout_line_is_the_verdict_and_nothing_else(rehearsal, capsys):
     assert verdict == {"ok": True, "device": {
         "platform": "cpu", "kind": "cpu", "count": len(jax.devices())}}
     assert report["phases"].keys() == {"train", "serve", "kernels",
-                                       "multichip"}
+                                       "share_model", "multichip"}
 
 
 def test_rehearsal_train_phase(rehearsal):
@@ -70,6 +70,17 @@ def test_rehearsal_kernels_are_interpreted_off_the_chip(rehearsal):
     case, = kernels["grouped_matmul"]
     assert not case["mosaic"] and case["largest_over_mean"] > 1.5
     assert case["tiles"][0] == 128       # the longest that divides 384 rows
+
+
+def test_rehearsal_share_model_phase(rehearsal):
+    """The small share-holding model's step ran under bf16 AMP against
+    its float32 reference; off the chip neither kernel family engages."""
+    share = rehearsal["phases"]["share_model"]
+    assert all(share["checks"].values()), share["checks"]
+    assert share["lowered"]["moe_ffn_held_experts"] == 2
+    assert "grouped_matmul_kernel" not in share["lowered"]
+    assert sum(share["tokens_per_expert"]) == 2 * 128
+    assert max(share["rel_err"]) <= chip_smoke.SHARE_LOSS_TOL
 
 
 def test_rehearsal_multichip_phase_on_the_virtual_mesh(rehearsal):

@@ -121,6 +121,9 @@ def test_a_given_product_is_not_computed_again():
     (65536, 2048, 1024, "bfloat16", "kernels"),
     (65536, 1024, 2048, "bfloat16", "kernels"),
     (1024, 256, 384, "float32", "kernels"),
+    (16384, 3584, 1024, "bfloat16", "kernels"),     # K whole at 7 KiB a row
+    (16384, 1024, 3584, "bfloat16", "kernels"),
+    (16384, 7168, 1024, "bfloat16", "kernels"),     # 14 KiB a row: split
     (65536 + 128, 2048, 1024, "bfloat16", "kernels"),
     (65536 + 64, 2048, 1024, "bfloat16", None),     # rows no tile divides
     (65536, 2048 + 64, 1024, "bfloat16", None),     # K not of 128
@@ -140,9 +143,11 @@ def test_tiles_are_chosen_from_the_shapes(n_rows, k, m, dtype, want):
     # the forward and d lhs keep the whole of a contraction this short in
     # one tile: a group's weight tile is then fetched once, not per visit
     item = jnp.dtype(dtype).itemsize
-    if k * item <= 4096:
+    if k * item <= 8192:
         assert fwd[0] == k
-    if m * item <= 4096:
+    else:
+        assert fwd[0] * item <= 8192
+    if m * item <= 8192:
         assert dlhs[0] == m
 
 
@@ -253,20 +258,22 @@ def test_backward_op_takes_the_forward_products():
         "Down@GRAD", "Gate@GRAD", "Router@GRAD", "Up@GRAD"]
     args = _moe_operands(64, 128, 256, 8, "float32", seed=3)
     mean = 1.0 / (64 * 128)
-    ins = {s: [a] for s, a in zip(lm_ops._MOE_INPUTS, args)}
-    saved = lm_ops._moe_ffn(*args, 2)[1]
+    ins = {s: [a] for s, a in zip(lm_ops._MOE_TRAINED, args)}
+    x, router, *weights = args
+    saved = lm_ops._moe_ffn(x, router, None, *weights,
+                            lm_ops.Routing({"top_k": 2}, 8))[1]
     got = lm_ops.moe_ffn_grad_op(None, dict(
         ins, **{s: [p] for s, p in zip(lm_ops._MOE_PRODUCTS, saved)},
         **{"Out@GRAD": [jnp.full((64, 128), mean)]}), {"top_k": 2})
     want = jax.grad(lambda *a: jnp.mean(lm_ops.moe_ffn(*a, 2)[0]),
                     argnums=(0, 1, 2, 3, 4))(*args)
-    for slot, w in zip(lm_ops._MOE_INPUTS, want):
+    for slot, w in zip(lm_ops._MOE_TRAINED, want):
         np.testing.assert_allclose(got[slot + "@GRAD"][0], w, rtol=1e-5,
                                    atol=1e-7)
     # an op built without the saved products computes them itself
     again = lm_ops.moe_ffn_grad_op(None, dict(
         ins, **{"Out@GRAD": [jnp.full((64, 128), mean)]}), {"top_k": 2})
-    for slot in lm_ops._MOE_INPUTS:
+    for slot in lm_ops._MOE_TRAINED:
         np.testing.assert_allclose(again[slot + "@GRAD"][0],
                                    got[slot + "@GRAD"][0], rtol=1e-6,
                                    atol=1e-8)
@@ -289,3 +296,83 @@ def test_ragged_dot_is_the_reference_semantics():
         lax.ragged_dot(jnp.asarray(lhs), jnp.asarray(rhs),
                        jnp.asarray(counts, jnp.int32)), want, rtol=1e-4,
         atol=1e-4)
+
+
+PARTIAL = {
+    "groups_end_inside_a_tile": [40, 0, 30, 27],
+    "groups_end_on_a_tile": [64, 10, 54, 0],
+    "one_row": [0, 1, 0, 0],
+    "no_rows": [0, 0, 0, 0],
+    "nearly_all": [100, 100, 50, 5],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", sorted(PARTIAL))
+def test_groups_may_end_before_the_rows_do(layout, dtype):
+    """`rows_past`: the groups hold the first R <= N rows (a layer that
+    holds some of its experts sorts their rows first). The product and
+    d lhs are `lax.ragged_dot`'s on those rows and ZERO past them,
+    whatever the rows of lhs past them hold (here NaN: no visit computes
+    with them), and d rhs sums the groups' rows alone (the rows past them
+    hold other values than the reference's: in the groups' last tile they
+    meet zeros)."""
+    counts = jnp.asarray(PARTIAL[layout], jnp.int32)
+    R = int(counts.sum())
+    rs = np.random.default_rng(R)
+    lhs, rhs, g = (jnp.asarray(rs.standard_normal(s), dtype) for s in (
+        (N, K), (len(counts), K, M), (N, M)))
+    tiles = (TM, (128, 128), (128, 128), (128, 128))
+    out = grouped.grouped_matmul(lhs.at[R:].set(jnp.nan), rhs, counts, None,
+                                 tiles, True)
+    _, vjp = jax.vjp(lambda a, b: grouped.grouped_matmul(
+        a, b, counts, None, tiles, True), lhs.at[R:].multiply(-3.0), rhs)
+    d_lhs, d_rhs = vjp(g.at[R:].multiply(7.0))
+    ref, ref_vjp = jax.vjp(lambda a, b: grouped.grouped_matmul(
+        a, b, counts, None, None, True), lhs, rhs)
+    ref_dl, ref_dr = ref_vjp(g)
+    tol = 2e-6 if dtype == "float32" else 2 ** -8
+    for ours, want in ((out, ref), (d_lhs, ref_dl), (d_rhs, ref_dr)):
+        ours, want = (np.asarray(v, np.float32) for v in (ours, want))
+        assert np.all(np.isfinite(ours))
+        assert np.max(np.abs(ours - want)) <= tol * max(
+            np.max(np.abs(want)), 1.0)
+    assert not np.asarray(out, np.float32)[R:].any()
+    assert not np.asarray(d_lhs, np.float32)[R:].any()
+
+
+@pytest.mark.parametrize("first,held", [(0, 2), (2, 2), (5, 3), (0, 8)])
+def test_moe_ffn_holding_a_share_equals_the_dense_sum(monkeypatch, first,
+                                                       held):
+    """`moe_ffn` told to hold experts [first, +held) of 8, sigmoid scores,
+    a bias in the choice, renormalised and scaled weights, through the
+    kernels (interpreted) and through `lax.ragged_dot`: both are the dense
+    sum over the held experts of w_te E_e(x_t); the counts are over all 8
+    experts and the rows held are the held experts' counts."""
+    T, H, F, E, k = 64, 128, 128, 8, 2
+    x, router, gate, up, down = _moe_operands(T, H, F, E, "float32", seed=3)
+    bias = jnp.asarray(np.random.default_rng(5).normal(0, 0.2, E),
+                       jnp.float32)
+    attrs = dict(top_k=k, score_func="sigmoid", norm_topk=True,
+                 routed_scale=2.0, first_expert=first, held_experts=held)
+    sl = slice(first, first + held)
+    routing = lm_ops.Routing(attrs, E)
+    results = []
+    for on_chip in (True, False):
+        monkeypatch.setattr(grouped, "on_tpu", lambda on_chip=on_chip: on_chip)
+        (o, _, _, ids, counts, rows), _ = lm_ops._moe_ffn(
+            x, router, bias, gate[sl], up[sl], down[sl], routing)
+        results.append(np.asarray(o))
+    scores = jax.nn.sigmoid(x @ router)
+    _, top = lax.top_k(scores + bias, k)
+    w = jnp.take_along_axis(scores, top, 1)
+    w = w / w.sum(1, keepdims=True) * 2.0
+    dense = jnp.zeros((T, H))
+    for e in range(first, first + held):
+        y = (jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e]
+        dense += y * jnp.sum(jnp.where(top == e, w, 0.0), 1, keepdims=True)
+    for got in results:
+        assert np.max(np.abs(got - dense)) <= 2e-5 * np.max(np.abs(dense))
+    np.testing.assert_array_equal(np.sort(ids, 1), np.sort(top, 1))
+    assert int(counts.sum()) == T * k
+    assert int(rows[0]) == int(counts[sl].sum())

@@ -260,8 +260,18 @@ def _olmoe_step(one_chip, monkeypatch, rows=2):
     """The one-layer OLMoE training step of the `olmoe_1b_7b` configuration
     (published widths, `rows` rows of 4096 tokens, bf16 AMP, AdamW, global
     clip) as the Executor lowers it on a TPU place, compiled for one v5e
-    chip. The place is the CPU here, so the test steers the two questions
-    the lowering asks of it."""
+    chip."""
+    return _lm_step(one_chip, monkeypatch, "olmoe_1b_7b", rows,
+                    lambda built: [built["routing"][0][1].name])
+
+
+def _lm_step(one_chip, monkeypatch, config, rows, fetches):
+    """A language-model configuration's training step (published widths,
+    `rows` rows of its sequence length, bf16 AMP, AdamW, global clip) as
+    the Executor lowers it on a TPU place, compiled for one v5e chip. The
+    place is the CPU here, so the test steers the two questions the
+    lowering asks of it."""
+    import importlib
     import json
 
     import jax
@@ -275,10 +285,9 @@ def _olmoe_step(one_chip, monkeypatch, rows=2):
     import sys
     if repo not in sys.path:
         sys.path.insert(0, repo)
-    from chipbench.configs import olmoe_1b_7b as builder
-
+    builder = importlib.import_module("chipbench.configs." + config)
     with open(os.path.join(repo, "chipbench", "configs",
-                           "olmoe_1b_7b.json")) as f:
+                           config + ".json")) as f:
         cfg = json.load(f)
     monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
     monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
@@ -298,8 +307,7 @@ def _olmoe_step(one_chip, monkeypatch, rows=2):
              for n in ("tokens", "labels")}
     rng = jax.ShapeDtypeStruct((2,), np.uint32, sharding=one_chip)
     step = executor_core.build_step_fn(
-        built["prog"], [built["loss"].name, built["routing"][0][1].name],
-        sorted(mut))
+        built["prog"], [built["loss"].name] + fetches(built), sorted(mut))
     amp.enable("bfloat16")
     try:
         return cfg, jax.jit(step, donate_argnums=(0,)).lower(
@@ -372,3 +380,61 @@ def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert held < 15.5e9, held
+
+
+def _custom_calls(text):
+    calls = [m.group(1) for m in map(_INSTR.match, text.splitlines())
+             if m and 'custom_call_target="tpu_custom_call"' in m.group(4)]
+    return sorted(re.sub(r"\.\d+$", "", n) for n in calls)
+
+
+def test_xing_step_runs_both_kernel_families_over_its_share(
+        one_chip, no_compile_cache, monkeypatch):
+    """The `xing4_0_29b_a4b` step at 1 x 4096 tokens (6 blocks) compiles for one v5e chip with the flash kernels at
+    queries and keys of 192 and values of 128 over the 4 held heads (a
+    forward, dK/dV and dQ a block) and the grouped kernels over the 8
+    held groups (nine an expert block), at K = 3584 and K = 1024; no XLA `ragged-dot`, no [S, S] scores, no
+    [T, 64, .] tensor (every token through every expert of the router's
+    width), and it fits the chip: the compiler itself refuses a step
+    past 15.75 GiB."""
+    cfg, compiled = _lm_step(
+        one_chip, monkeypatch, "xing4_0_29b_a4b", 1,
+        lambda built: [built["routing"][0][1].name]
+        + [r[2].name for r in built["routing"]])
+    text = compiled.as_text()
+    assert _custom_calls(text) == (
+        ["causal_attention"] * 6 + ["causal_attention_grad"] * 12
+        + ["grouped_matmul"] * 15 + ["grouped_matmul_nt"] * 15
+        + ["grouped_matmul_tn"] * 15)
+    assert ragged_dots(text) == []
+    arrays = {(dt, tuple(int(d) for d in dims.split(",") if d))
+              for dt, dims in _ARRAY.findall(text)}
+    shapes = {s for _, s in arrays}
+    S, T = cfg["sequence_length"], cfg["sequence_length"]
+    assert [s for s in shapes if len(s) >= 2 and s[-2:] == (S, S)] == []
+    # the kernels' operands: 4 heads of 192 and of 128; the held experts'
+    # stacked matrices in bf16, in one orientation each
+    assert (4, S, 192) in shapes and (4, S, 128) in shapes
+    assert (8, 3584, 1024) in shapes and (8, 1024, 3584) in shapes
+    k = cfg["num_experts_per_tok"]
+    assert (T * k, 1024) in shapes and (T * k, 3584) in shapes
+    assert [s for s in shapes if len(s) >= 3 and 64 in s[-3:]
+            and s[-1] in (1024, 3584) and T in s] == []
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 12.6e9 < held < 16.4e9, held
+
+
+def test_olmoe_kernel_counts_are_unchanged(one_chip, no_compile_cache,
+                                           monkeypatch):
+    """What this file asserts of the OLMoE step above, at one row (a
+    quicker compile): `moe_ffn`'s and `causal_attention`'s new attributes
+    at their defaults lower to the same twelve kernels."""
+    _, compiled = _olmoe_step(one_chip, monkeypatch, rows=1)
+    text = compiled.as_text()
+    assert _custom_calls(text) == (
+        ["causal_attention"] + ["causal_attention_grad"] * 2
+        + ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
+        + ["grouped_matmul_tn"] * 3)
+    assert ragged_dots(text) == []
