@@ -155,8 +155,8 @@ class StepRecord:
         lowered: {counter: how many ops (or op pairs) of this step's
         program are lowered through that path}, from
         `executor_core.lowered_counts`: `fused_bn_global_pool` (always),
-        `moe_ffn_grouped`, `grouped_matmul_kernel`, `flash_attention`,
-        `flash_attention_bwd` (where there are any). The
+        `moe_ffn_grouped`, `grouped_matmul_kernel`, `grouped_mlp_epilogues`,
+        `flash_attention`, `flash_attention_bwd` (where there are any). The
         step span carries the numbers; the registry counts them once per
         program prepared (compiled, or loaded from the persistent store)."""
         self.cache = "hit" if hit else "miss"

@@ -245,7 +245,7 @@ def _moe_ffn(x, router, bias, gate, up, down, r, products=None):
     (gate, up, down, rows in expert order). Given those as `products` (the
     backward op hands over what the forward left) no product is computed
     again: the kernels then run for the gradients alone."""
-    from ..parallel.grouped import grouped_dot
+    from ..parallel.grouped import grouped_mlp
 
     T, E, top_k = x.shape[0], router.shape[1], r.top_k
     # router, scores and top-k in float32 at full precision whatever the
@@ -288,12 +288,9 @@ def _moe_ffn(x, router, bias, gate, up, down, r, products=None):
         dtype=jnp.int32)
     # grouped products over the rows each expert really received: Pallas
     # kernels on a TPU place, `lax.ragged_dot` elsewhere
-    saved = products or (None, None, None)
-    past = not r.all_held
     xs = _dispatch(x, order, inv, top_k)                    # [T*k, H]
-    a = grouped_dot(xs, gate, held_counts, saved[0], past)
-    b = grouped_dot(xs, up, held_counts, saved[1], past)
-    ys = grouped_dot(jax.nn.silu(a) * b, down, held_counts, saved[2], past)
+    ys, a, b = grouped_mlp(xs, gate, up, down, held_counts, products,
+                           not r.all_held)
     y = _unsort(ys, order, inv).reshape(T, top_k, -1)
     o = jnp.einsum("tkh,tk->th", y.astype(F32), top_p)
     # load balance: E * sum_e (share of routing slots on e) * (mean score
@@ -326,10 +323,12 @@ def moe_ffn_op(ctx, ins, attrs):
     all E and TokensPerExpert counts all E; RowsHeld is how many of the T
     * top_k rows the held experts received. Tokens are sorted by expert,
     the held ones first, and the three products are grouped over the rows
-    routed to them (`parallel/grouped.py: grouped_dot`): no capacity, no
+    routed to them (`parallel/grouped.py: grouped_mlp`): no capacity, no
     dropped token, no padding to a per-expert size. GateOut, UpOut [T *
     top_k, F] and DownOut [T * top_k, H] are those products as computed,
-    kept for the backward op."""
+    kept for the backward op; where the layer holds a share of its experts
+    DownOut is zero past the rows they received (RowsHeld), GateOut and
+    UpOut hold there whatever their buffers held on a TPU place."""
     args = [first(ins, s) for s in _MOE_INPUTS]
     (o, aux, z, ids, counts, rows), products = _moe_ffn(
         *args, Routing(attrs, args[1].shape[1]))
@@ -598,15 +597,18 @@ def mhc_update_op(ctx, ins, attrs):
                                  for s in ("X", "HRes", "HPost", "Y"))))
 
 
-def _kernels_take(op, block):
+def _kernels_take(op, block, whole_mlp=False):
     """Whether `grouped_dot` takes this `moe_ffn`'s products to the Pallas
     kernels, from the shapes the program states (rows it leaves open, a
-    batch dimension of -1, are taken to fit)."""
+    batch dimension of -1, are taken to fit); `whole_mlp`: whether
+    `grouped_mlp` runs them with the element-wise work between the
+    products in their epilogues."""
     from ..parallel import grouped
 
     x, gate = (block.vars[op.input(s)[0]].shape for s in ("X", "Gate"))
     rows = x[0] * int(op.attrs.get("top_k", 1)) if x[0] > 0 else None
-    return grouped.takes(rows, gate[1], gate[2])
+    takes = grouped.mlp_takes if whole_mlp else grouped.takes
+    return takes(rows, gate[1], gate[2])
 
 
 def _holds_a_share(op, block):
@@ -620,6 +622,8 @@ def _holds_a_share(op, block):
 # which of those ops count: all when None)
 _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("moe_ffn", "grouped_matmul_kernel", True, _kernels_take),
+            ("moe_ffn", "grouped_mlp_epilogues", True,
+             functools.partial(_kernels_take, whole_mlp=True)),
             ("causal_attention", "flash_attention", True, None),
             ("causal_attention_grad", "flash_attention_bwd", True, None),
             ("moe_ffn", "moe_ffn_held_experts", False, _holds_a_share))
@@ -629,12 +633,14 @@ def lowered_counts(program, device):
     """{counter: n} for the step spans and the registry: `moe_ffn` ops of
     the program (each lowers through the grouped products; on a TPU place
     those whose shapes the Pallas grouped-matmul kernels take count as
-    `grouped_matmul_kernel` too; those that hold a share of their experts
-    as `moe_ffn_held_experts`) and, on a TPU place, its `causal_attention`
-    ops (each lowers through the flash kernel) and `causal_attention_grad`
-    ops (each through the two backward kernels). A program without them
-    reports none. Kept on the program until that is mutated, like
-    `bn_pool.count`."""
+    `grouped_matmul_kernel` too, and as `grouped_mlp_epilogues` where
+    `grouped_mlp` runs them with SiLU * up, its backward and the sum of
+    the two d xs products in their epilogues; those that hold a share of
+    their experts as `moe_ffn_held_experts`) and, on a TPU place, its
+    `causal_attention` ops (each lowers through the flash kernel) and
+    `causal_attention_grad` ops (each through the two backward kernels).
+    A program without them reports none. Kept on the program until that
+    is mutated, like `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)
     if memo is None or memo[0] != program._mutation:
         ops = [(op, b) for b in program.blocks for op in b.ops]

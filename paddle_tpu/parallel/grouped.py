@@ -15,16 +15,38 @@ three kernels behind one `custom_vjp`:
   d rhs     drhs[g] = lhs[rows of g]^T @ dout[rows of g]       (`_tgmm`),
             reading `lhs` and `dout` as stored
 
+    grouped_mlp(xs, gate, up, down, counts) -> ys, a, b
+
+is the layer's whole MLP, ys = (silu(xs @ gate[g]) * (xs @ up[g])) @
+down[g]: the same nine kernels a step behind ONE `custom_vjp`, with the
+element-wise work between the products done on the tiles the kernels hold
+in VMEM (PR 31): `_gmm` takes row-tiled side operands and stores an
+epilogue of its float32 product (SiLU * up behind the up product; SiLU's
+backward behind the d h product, whose own result is never written; the
+second d xs product added onto the first, in place), `_tgmm` forms its
+lhs tile silu(a) * b in front of d down. Composed of three `grouped_dot`s
+those were XLA passes over [N, F] and [N, H] in HBM between the kernels:
+at [65536, 2048] x [64, 2048, 1024] bf16 and a seeded step's group sizes
+(tools/grouped_sweep.py epilogues; ms, the kernel alone / with the work in
+it / alone and then the pass): up 1.78 / 1.86 / 2.55, d h 1.78 / 1.89 /
+2.99, second d xs 1.82 / 1.85 / 3.04, d down 1.96 / 2.15 / 2.74; holding 8
+of 64 experts at [16384, 3584] (tools/grouped_share_sweep.py --epilogues)
+0.16 / 0.18 / 0.32, 0.16 / 0.18 / 0.42, 0.18 / 0.19 / 0.71, 0.21 / 0.22 /
+0.37 (PERF.md, PR 31). The VPU work is not hidden under the MXU: with
+even groups, where the plain kernels run at 184 TFLOP/s, each pays 0.16
+to 0.49 ms for it, still less than the pass.
+
 Adapted from jax.experimental.pallas.ops.tpu.megablox (gmm / tgmm, jax
 0.9.0), not imported: the library's kernels have no name (the trace and
 the benchmark's scopes could not tell them from any other Mosaic call),
 leave VMEM at Mosaic's default scoped 16 MiB (no whole-K weight tile), and
 its `tgmm` masks BOTH operands of EVERY tile in float32 and transposes the
-float32 copy. What is dropped: sharded groups (`group_offset`),
-`existing_out`, a K remainder. XLA's own lowering of `ragged_dot`
-(`ragged-dot-none`) takes its right operand in one orientation only, so a
-training step held two bf16 copies of every expert weight, and its row
-tile (512) is not the program's to choose (PERF.md, PR 29).
+float32 copy. What is dropped: sharded groups (`group_offset`), a K
+remainder (`existing_out` came back in PR 31 as the "add" epilogue). XLA's
+own lowering of `ragged_dot` (`ragged-dot-none`) takes its right operand
+in one orientation only, so a training step held two bf16 copies of every
+expert weight, and its row tile (512) is not the program's to choose
+(PERF.md, PR 29).
 
 Rows are walked in tiles of `tm`. A tile that a group boundary crosses is
 visited once for each group that has rows in it (N / tm + boundaries
@@ -46,7 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.places import pallas_interpret
 
-__all__ = ["grouped_dot", "grouped_matmul", "tiles_for"]
+__all__ = ["grouped_dot", "grouped_matmul", "grouped_mlp", "tiles_for"]
 
 # The kernels' names: Pallas puts a kernel's name on the name stack, so a
 # device trace's op_name ends `.../grouped_matmul/pallas_call`
@@ -143,11 +165,38 @@ def _blocks_of_group(lo, hi, row0, tm, body):
     lax.fori_loop(begin, jnp.where(hi > lo, end, begin), block, None)
 
 
-def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, *acc, tm, tn,
-                tiles_k, dims):
+def _silu_mul(p, a):
+    """The up product's epilogue: b = p, h = silu(a) * p."""
+    return p, jax.nn.silu(a) * p
+
+
+def _silu_mul_grad(p, a, b):
+    """The d h product's epilogue, p = d h: d a = p * b * silu'(a), d b =
+    p * silu(a)."""
+    s = jax.nn.sigmoid(a)
+    return p * b * (s * (1.0 + a * (1.0 - s))), p * (a * s)
+
+
+def _add(p, existing):
+    return (existing + p,)
+
+
+# epilogues of `_gmm`: what is stored from the float32 product tile p and
+# the side tiles (widened to float32), and how many tiles of each
+_EPILOGUES = {None: (lambda p: (p,), 0, 1), "silu_mul": (_silu_mul, 1, 2),
+              "silu_mul_grad": (_silu_mul_grad, 2, 2), "add": (_add, 1, 1)}
+
+
+def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, *refs, tm, tn,
+                tiles_k, dims, epilogue):
     """One visit: the tile's rows of the visit's group times the group's
     weight tile. With all of K in one tile (`acc` empty) the product goes
-    straight to the out block; else through float32 sums in `acc`."""
+    straight to the out blocks; else through float32 sums in `acc`. What
+    is stored is the `epilogue` of the float32 product and the side tiles
+    (`refs`: the sides, the outs, `acc`)."""
+    values_of, n_sides, n_outs = _EPILOGUES[epilogue]
+    sides, outs = refs[:n_sides], refs[n_sides:n_sides + n_outs]
+    acc = refs[n_sides + n_outs:]
     visit, k_i = pl.program_id(1), pl.program_id(2)
     lo, hi, row0, whole = _visit(offsets, group_ids, tile_ids, visit, tm)
     if acc:
@@ -162,14 +211,17 @@ def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, *acc, tm, tn,
             acc[0][rows, :] += p
 
         def store():
-            value = acc[0][rows, :] if acc else p
-            if mask_of is not None:
-                # the tile's other rows were, or will be, written by the
-                # visits of their own groups: the out block stays in VMEM
-                # between them
-                value = jnp.where(mask_of(tn), value,
-                                  out[rows, :].astype(jnp.float32))
-            out[rows, :] = value.astype(out.dtype)
+            values = values_of(
+                acc[0][rows, :] if acc else p,
+                *(s[rows, :].astype(jnp.float32) for s in sides))
+            for out, value in zip(outs, values):
+                if mask_of is not None:
+                    # the tile's other rows were, or will be, written by
+                    # the visits of their own groups: the out block stays
+                    # in VMEM between them
+                    value = jnp.where(mask_of(tn), value,
+                                      out[rows, :].astype(jnp.float32))
+                out[rows, :] = value.astype(out.dtype)
 
         if acc:
             pl.when(k_i == tiles_k - 1)(store)
@@ -181,13 +233,20 @@ def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, *acc, tm, tn,
         lo, hi, row0, tm, rows_times_weights))
 
 
-def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs):
+def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs, epilogue=None,
+         sides=()):
     """[N, K] x [E, K, M] -> [N, M] (`transpose_rhs`: rhs is [E, M, K],
-    contracted over its last dimension)."""
+    contracted over its last dimension). `epilogue` and its `sides` [N, M],
+    read by row tiles as the out is written: "silu_mul" (a) -> (the
+    product, silu(a) * product); "silu_mul_grad" (a, b) -> (d a, d b) of
+    silu(a) * b, the product being its cotangent; "add" (existing) ->
+    existing + product, written over `existing` in place. All taken on the
+    float32 product and rounded once."""
     tm, tk, tn = tiles
     (N, K), E = lhs.shape, rhs.shape[0]
     M = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tiles_k, tiles_n = K // tk, M // tn
+    n_outs = _EPILOGUES[epilogue][2]
 
     def lhs_at(n_i, v, k_i, offsets, group_ids, tile_ids):
         return tile_ids[v], k_i
@@ -203,15 +262,18 @@ def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs):
     rhs_block = (None, tn, tk) if transpose_rhs else (None, tk, tn)
     item = lhs.dtype.itemsize
     max_visits = meta[1].shape[0]
-    return pl.pallas_call(
+    out_shape = jax.ShapeDtypeStruct((N, M), lhs.dtype)
+    outs = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k,
-                          dims=_NT if transpose_rhs else _NN),
-        out_shape=jax.ShapeDtypeStruct((N, M), lhs.dtype),
+                          dims=_NT if transpose_rhs else _NN,
+                          epilogue=epilogue),
+        out_shape=[out_shape] * n_outs,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[pl.BlockSpec((tm, tk), lhs_at),
-                      pl.BlockSpec(rhs_block, rhs_at)],
-            out_specs=pl.BlockSpec((tm, tn), out_at),
+                      pl.BlockSpec(rhs_block, rhs_at)]
+            + [pl.BlockSpec((tm, tn), out_at)] * len(sides),
+            out_specs=[pl.BlockSpec((tm, tn), out_at)] * n_outs,
             grid=(tiles_n, n_visits, tiles_k),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]
             if tiles_k > 1 else []),
@@ -219,19 +281,25 @@ def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs):
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=2 * N * K * M, transcendentals=0,
+            flops=2 * N * K * M,
+            transcendentals=N * M if "silu" in (epilogue or "") else 0,
             bytes_accessed=item * (
-                N * K * tiles_n + N * M
+                N * K * tiles_n + N * M * (len(sides) + n_outs)
                 + K * M * (E if tiles_k == 1 else max_visits))),
+        # operands are counted with the three scalar-prefetch lists
+        input_output_aliases={5: 0} if epilogue == "add" else {},
         interpret=pallas_interpret(),
         name=KERNELS[1] if transpose_rhs else KERNELS[0],
-    )(*meta, lhs, rhs)
+    )(*meta, lhs, rhs, *sides)
+    return outs[0] if n_outs == 1 else outs
 
 
-def _tgmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, acc, *, tm,
-                 tk, tn):
+def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs):
     """One visit: the tile's rows of the visit's group, lhs^T times rhs,
-    summed in `acc` over the group's visits and written at its last."""
+    summed in `acc` over the group's visits and written at its last.
+    `refs`: lhs, or the two tiles a, b that lhs = silu(a) * b is formed
+    from here; rhs, out, `acc`."""
+    *lhs, rhs, out, acc = refs
     visit = pl.program_id(2)
     last = pl.num_programs(2) - 1
     g = group_ids[visit]
@@ -242,10 +310,13 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, acc, *, tm,
         acc[...] = jnp.zeros_like(acc)
 
     def rows_transposed_times_rows(rows, mask_of=None):
-        a, b = lhs[rows, :], rhs[rows, :]
-        # rows of other groups zeroed in ONE operand (the narrower tile):
-        # a zero row of either contributes nothing
-        if mask_of is not None and tk <= tn:
+        a, b = lhs[0][rows, :], rhs[rows, :]
+        if len(lhs) == 2:
+            a = (jax.nn.silu(a.astype(jnp.float32))
+                 * lhs[1][rows, :].astype(jnp.float32)).astype(a.dtype)
+        # rows of other groups zeroed in ONE operand: a zero row of either
+        # contributes nothing
+        if mask_of is not None and mask_lhs:
             a = jnp.where(mask_of(tk), a, jnp.zeros_like(a))
         elif mask_of is not None:
             b = jnp.where(mask_of(tn), b, jnp.zeros_like(b))
@@ -261,11 +332,16 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, out, acc, *, tm,
         out[...] = acc[...].astype(out.dtype)
 
 
-def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype):
+def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None):
     """[N, K], [N, M] -> [E, K, M]: group g's rows of lhs, transposed,
-    times its rows of rhs."""
+    times its rows of rhs. `lhs` a pair (a, b): lhs is silu(a) * b, formed
+    from the two tiles in VMEM. `mask_lhs`: which operand has the rows of
+    other groups zeroed in a tile a boundary crosses; the narrower tile
+    unless said (rows past the groups must be FINITE in the other one: a
+    zero row times a NaN is a NaN)."""
     tm, tk, tn = tiles
-    (N, K), M = lhs.shape, rhs.shape[1]
+    lhs = lhs if isinstance(lhs, tuple) else (lhs,)
+    (N, K), M = lhs[0].shape, rhs.shape[1]
     E = meta[0].shape[0] - 1
     tiles_k, tiles_n = K // tk, M // tn
 
@@ -278,14 +354,16 @@ def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype):
     def out_at(n_i, k_i, v, offsets, group_ids, tile_ids):
         return group_ids[v], k_i, n_i
 
-    item = lhs.dtype.itemsize
+    item = rhs.dtype.itemsize
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn),
+        functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn,
+                          mask_lhs=tk <= tn if mask_lhs is None
+                          else mask_lhs),
         out_shape=jax.ShapeDtypeStruct((E, K, M), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            in_specs=[pl.BlockSpec((tm, tk), lhs_at),
-                      pl.BlockSpec((tm, tn), rhs_at)],
+            in_specs=[pl.BlockSpec((tm, tk), lhs_at)] * len(lhs)
+            + [pl.BlockSpec((tm, tn), rhs_at)],
             out_specs=pl.BlockSpec((None, tk, tn), out_at),
             grid=(tiles_n, tiles_k, n_visits),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
@@ -293,12 +371,14 @@ def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype):
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=2 * N * K * M, transcendentals=0,
-            bytes_accessed=item * (N * K * tiles_n + N * M * tiles_k)
+            flops=2 * N * K * M,
+            transcendentals=N * K * tiles_n if len(lhs) == 2 else 0,
+            bytes_accessed=item * (
+                N * K * tiles_n * len(lhs) + N * M * tiles_k)
             + E * K * M * jnp.dtype(out_dtype).itemsize),
         interpret=pallas_interpret(),
         name=KERNELS[2],
-    )(*meta, lhs, rhs)
+    )(*meta, *lhs, rhs)
 
 
 def _rows_of_groups(x, counts):
@@ -406,6 +486,14 @@ def tiles_for(n_rows, k, m, dtype):
         (1.81; 1.86 at 256, 1.84 at 1024; blocks of 256: 1.90). The
         number of groups no longer enters: a boundary costs a block, not
         a tile.
+      with an epilogue (`grouped_mlp`, PR 31) the same tiles: its side
+        and out tiles are row tiles of the out width, 1 to 3.5 MB each at
+        the two cells' shapes, and the widest kernel (the second d xs
+        product at M = 3584) asks about 51 MiB of the 96 (`mlp_takes`).
+        An epilogue taken 128 or 64 rows at a time from float32 scratch,
+        for a shorter kernel, was SLOWER than on the whole 512-row
+        product at once (up 2.17 and 2.16 against 1.90 ms, d h 2.37 and
+        2.29 against 1.94).
     """
     if not takes(n_rows, k, m):
         return None
@@ -434,3 +522,125 @@ def grouped_dot(lhs, rhs, counts, out=None, rows_past=False):
                           lhs.dtype)
     return grouped_matmul(lhs, rhs, counts.astype(jnp.int32), out, tiles,
                           bool(rows_past))
+
+
+def _vmem_bytes(tm, tk, tn, item, n_sides, n_outs):
+    """What a `_gmm` call asks of VMEM: every block twice (the pipeline's
+    two buffers), the side and out blocks beside the rows and the
+    weights, and a float32 tile each for the product, the widened sides
+    and the values stored."""
+    tiles = n_sides + n_outs
+    return (2 * item * (tm * tk + tk * tn + tiles * tm * tn)
+            + 4 * tm * tn * (1 + tiles))
+
+
+def mlp_takes(n_rows, h, f):
+    """Whether `grouped_mlp` runs as the nine kernels with their
+    epilogues: shapes the kernels take (`takes`), and the two widest fit
+    VMEM with the tiles `tiles_for` gives a 2- or a 4-byte dtype: the d h
+    product (rows of H in, the whole-K weight tile, a, b in and d a, d b
+    out) and the second d xs product (the existing tile of H in, its sum
+    out)."""
+    if not takes(n_rows, h, f):
+        return False
+    for dtype in (jnp.bfloat16, jnp.float32):
+        tm, (th, tf), _, _ = tiles_for(n_rows or ROW_TILES[0], h, f, dtype)
+        item = jnp.dtype(dtype).itemsize
+        if max(_vmem_bytes(tm, th, tf, item, 2, 2),
+               _vmem_bytes(tm, tf, th, item, 1, 1)) > _VMEM_LIMIT:
+            return False
+    return True
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _mlp(xs, gate, up, down, counts, saved, tiles, rows_past):
+    return _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past)[0]
+
+
+def _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past):
+    """-> (ys, a, b); nine kernels a step with `_mlp_bwd`, and between
+    them no element-wise pass over [N, F] or [N, H] in HBM but the zeroing
+    of ys and d xs under `rows_past`."""
+    if saved is None:
+        (tm, up_fwd, _, _), (_, down_fwd, _, _) = tiles
+        with jax.named_scope(_SCOPE):
+            meta, n = visits(counts, xs.shape[0], tm, False)
+            a = _gmm(xs, gate, meta, n, (tm,) + up_fwd, False)
+            b, h = _gmm(xs, up, meta, n, (tm,) + up_fwd, False, "silu_mul",
+                        (a,))
+            ys = _gmm(h, down, meta, n, (tm,) + down_fwd, False)
+            saved = (a, b, _rows_of_groups(ys, counts) if rows_past else ys)
+    a, b, ys = saved
+    return (ys, a, b), (xs, gate, up, down, counts, a, b)
+
+
+def _mlp_bwd(tiles, rows_past, res, cots):
+    xs, gate, up, down, counts, a, b = res
+    (tm, _, up_dlhs, up_drhs), (_, _, down_dlhs, down_drhs) = tiles
+    d_ys = cots[0].astype(xs.dtype)
+    with jax.named_scope(_SCOPE):
+        meta, n = visits(counts, xs.shape[0], tm, False)
+        d_a, d_b = _gmm(d_ys, down, meta, n, (tm,) + down_dlhs, True,
+                        "silu_mul_grad", (a, b))
+        d_xs = _gmm(d_a, gate, meta, n, (tm,) + up_dlhs, True)
+        d_xs = _gmm(d_b, up, meta, n, (tm,) + up_dlhs, True, "add",
+                    (d_xs,))
+        if rows_past:
+            d_xs = _rows_of_groups(d_xs, counts)
+        meta, n = visits(counts, xs.shape[0], tm, True)
+        # the operand masked in a boundary tile is the one whose rows past
+        # the groups no kernel wrote
+        d_down = _tgmm((a, b), d_ys, meta, n, (tm,) + down_drhs,
+                       down.dtype, mask_lhs=True)
+        d_gate = _tgmm(xs, d_a, meta, n, (tm,) + up_drhs, gate.dtype,
+                       mask_lhs=False)
+        d_up = _tgmm(xs, d_b, meta, n, (tm,) + up_drhs, up.dtype,
+                     mask_lhs=False)
+    return d_xs, d_gate, d_up, d_down, None, None
+
+
+_mlp.defvjp(_mlp_fwd, _mlp_bwd)
+
+
+def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False):
+    """A sparse-expert layer's MLP over rows sorted by group: xs [N, H],
+    gate / up [E, H, F], down [E, F, H], counts [E] -> (ys [N, H], a, b
+    [N, F]):
+
+        a = xs @ gate[g]   b = xs @ up[g]   ys = (silu(a) * b) @ down[g]
+
+    `saved`: (a, b, ys) as an earlier call left them; they are returned as
+    they are and the call only carries the gradients. `counts`,
+    `rows_past`: as `grouped_dot` takes them.
+
+    Off a TPU place, and for shapes `mlp_takes` refuses, this IS three
+    `grouped_dot`s and `jax.nn.silu`. On a TPU place it is one
+    `custom_vjp` over the same nine kernels, the element-wise work
+    between the products done on the tiles the kernels hold in VMEM (on
+    the float32 sums, rounded once):
+
+      forward   a = G(xs, gate);  b, h = G(xs, up) with h = silu(a) * b
+                written beside b;  ys = G(h, down)
+      backward  d a, d b = NT(d ys, down), silu's backward taken on the
+                d h tile, which is never written;  d xs = NT(d a, gate),
+                then NT(d b, up) added onto it in place;  d down = TN(h,
+                d ys) with h formed again from the a and b tiles;  d gate
+                = TN(xs, d a);  d up = TN(xs, d b)
+
+    No gradient flows through the a and b it returns (an op's saved
+    outputs). With `rows_past`, ys and d xs are zero past the groups'
+    rows; a, b (and h, d a, d b inside) hold there whatever their buffers
+    held: kernels alone read them, on visited rows."""
+    counts = counts.astype(jnp.int32)
+    H, F = gate.shape[1:]
+    if (on_tpu() and xs.dtype == gate.dtype == up.dtype == down.dtype
+            and mlp_takes(xs.shape[0], H, F)):
+        tiles = (tiles_for(xs.shape[0], H, F, xs.dtype),
+                 tiles_for(xs.shape[0], F, H, xs.dtype))
+        return _mlp(xs, gate, up, down, counts, saved, tiles,
+                    bool(rows_past))
+    saved = saved or (None, None, None)
+    a = grouped_dot(xs, gate, counts, saved[0], rows_past)
+    b = grouped_dot(xs, up, counts, saved[1], rows_past)
+    return grouped_dot(jax.nn.silu(a) * b, down, counts, saved[2],
+                       rows_past), a, b

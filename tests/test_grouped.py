@@ -117,6 +117,31 @@ def test_a_given_product_is_not_computed_again():
         np.testing.assert_array_equal(ours, ref)
 
 
+def test_a_given_mlp_is_not_computed_again(monkeypatch):
+    """`grouped_mlp(saved=)`: a, b and ys are returned as they are and the
+    vjp holds the six backward kernels alone; without them, all nine."""
+    monkeypatch.setattr(grouped, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped, "ROW_TILES", (TM,))
+    counts = jnp.asarray(LAYOUTS["two_boundaries_in_one_tile"], jnp.int32)
+    xs, gate, up, down, g = _mlp_operands(4, "float32")
+
+    def grads(saved, *w):
+        out, vjp = jax.vjp(lambda *w: grouped.grouped_mlp(
+            *w, counts, saved)[0], *w)
+        return vjp(g)
+
+    ys, a, b = grouped.grouped_mlp(xs, gate, up, down, counts)
+    text = str(jax.make_jaxpr(grads)((a, b, ys), xs, gate, up, down))
+    assert text.count("pallas_call") == 6
+    assert str(jax.make_jaxpr(lambda *w: grads(None, *w))(
+        xs, gate, up, down)).count("pallas_call") == 9
+    for got in grouped.grouped_mlp(xs, gate, up, down, counts, (a, b, ys)):
+        assert got is a or got is b or got is ys
+    for ours, ref in zip(grads((a, b, ys), xs, gate, up, down),
+                         grads(None, xs, gate, up, down)):
+        np.testing.assert_array_equal(ours, ref)
+
+
 @pytest.mark.parametrize("n_rows,k,m,dtype,want", [
     (65536, 2048, 1024, "bfloat16", "kernels"),
     (65536, 1024, 2048, "bfloat16", "kernels"),
@@ -234,14 +259,15 @@ def _moe_program(tokens, hidden, width, experts=8, top_k=2):
 ])
 def test_counter_follows_the_shapes(tokens, hidden, width, kernel):
     """`grouped_matmul_kernel` counts a `moe_ffn` op on a TPU place only,
-    and only where the kernels take its shapes; `moe_ffn_grouped` counts
-    it everywhere."""
+    and only where the kernels take its shapes, `grouped_mlp_epilogues`
+    with it (these widths fit VMEM); `moe_ffn_grouped` counts it
+    everywhere."""
     prog = _moe_program(tokens, hidden, width)
     tpu, cpu = (SimpleNamespace(platform=p) for p in ("tpu", "cpu"))
     assert lm_ops.lowered_counts(prog, cpu) == {"moe_ffn_grouped": 1}
     want = {"moe_ffn_grouped": 1}
     if kernel:
-        want["grouped_matmul_kernel"] = 1
+        want["grouped_matmul_kernel"] = want["grouped_mlp_epilogues"] = 1
     assert lm_ops.lowered_counts(prog, tpu) == want
 
 
@@ -376,3 +402,164 @@ def test_moe_ffn_holding_a_share_equals_the_dense_sum(monkeypatch, first,
     np.testing.assert_array_equal(np.sort(ids, 1), np.sort(top, 1))
     assert int(counts.sum()) == T * k
     assert int(rows[0]) == int(counts[sl].sum())
+
+
+# ------------------------------------------------------------ grouped_mlp
+H, F = 256, 256
+MLP_LAYOUTS = dict(LAYOUTS, **{"past_" + k: v for k, v in PARTIAL.items()})
+MLP_OUTPUTS = ["ys", "d_xs", "d_gate", "d_up", "d_down"]
+
+
+def _mlp_operands(n_groups, dtype, seed=0):
+    rs = np.random.default_rng(seed)
+    return [jnp.asarray(rs.standard_normal(shape) * scale, dtype)
+            for shape, scale in (
+                ((N, H), 1.0), ((n_groups, H, F), H ** -0.5),
+                ((n_groups, H, F), H ** -0.5), ((n_groups, F, H), F ** -0.5),
+                ((N, H), 1.0))]
+
+
+def _composition(xs, gate, up, down, counts, rows_past):
+    """What `grouped_mlp` replaces, -> (ys, a, b): three `grouped_dot`s
+    (`lax.ragged_dot` here) and `jax.nn.silu`, joined by autodiff."""
+    a = grouped.grouped_dot(xs, gate, counts, None, rows_past)
+    b = grouped.grouped_dot(xs, up, counts, None, rows_past)
+    return grouped.grouped_dot(jax.nn.silu(a) * b, down, counts, None,
+                               rows_past), a, b
+
+
+@functools.lru_cache(maxsize=None)
+def _mlp_both(layout, dtype, whole_k):
+    """(kernels, composition): each (ys, d xs, d gate, d up, d down) as
+    float32 numpy. `whole_k`: every contraction in one tile (the product
+    goes straight to the epilogue), else in two (through float32 sums in
+    scratch)."""
+    counts = jnp.asarray(MLP_LAYOUTS[layout], jnp.int32)
+    past = layout.startswith("past_")
+    xs, gate, up, down, g = _mlp_operands(len(counts), dtype, len(layout))
+    t = (256, 256) if whole_k else (128, 128)
+    tiles = ((TM, t, t, t),) * 2
+    results = []
+    for fn in (lambda *w: grouped._mlp(*w, counts, None, tiles, past)[0],
+               lambda *w: _composition(*w, counts, past)[0]):
+        out, vjp = jax.vjp(fn, xs, gate, up, down)
+        results.append([np.asarray(x, np.float32) for x in (out, *vjp(g))])
+    return results
+
+
+# every layout in float32 with whole contractions; bf16, and contractions
+# split in two, over one layout of each kind (nine interpreted kernels a
+# case cost the CPU ~9 s)
+_OF_EACH_KIND = ["boundaries_inside_tiles", "empty_experts_first_middle_last",
+                 "one_expert_owns_every_row", "past_groups_end_inside_a_tile"]
+MLP_CASES = ([(layout, "float32", True) for layout in sorted(MLP_LAYOUTS)]
+             + [(layout, "bfloat16", True) for layout in _OF_EACH_KIND]
+             + [(layout, "float32", False) for layout in _OF_EACH_KIND[:3]]
+             + [(_OF_EACH_KIND[3], "bfloat16", False)])
+
+
+@pytest.mark.parametrize("which", MLP_OUTPUTS)
+@pytest.mark.parametrize(
+    "layout,dtype,whole_k", MLP_CASES,
+    ids=["-".join((c[0], c[1], "whole_k" if c[2] else "split_k"))
+         for c in MLP_CASES])
+def test_mlp_matches_the_composition(layout, dtype, whole_k, which):
+    """`grouped_mlp`'s nine kernels with their epilogues (SiLU * up behind
+    the up product, its backward behind d h, the second d xs product added
+    onto the first, h formed in front of d down) give ys and all four
+    gradients of three `grouped_dot`s + `jax.nn.silu`: to float32 rounding
+    in float32; in bf16 to the rounding of a chain of three bf16 products
+    (h is rounded once from the float32 sums here, from a rounded b
+    there). With groups that end before the rows, ys and d xs are zero
+    past them."""
+    i = MLP_OUTPUTS.index(which)
+    ours, ref = (r[i] for r in _mlp_both(layout, dtype, whole_k))
+    assert ours.shape == ref.shape and np.all(np.isfinite(ours))
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    assert np.max(np.abs(ours - ref)) <= tol * max(np.max(np.abs(ref)), 1e-3)
+    if layout.startswith("past_") and which in ("ys", "d_xs"):
+        assert not ours[sum(MLP_LAYOUTS[layout]):].any()
+
+
+@pytest.mark.parametrize("layout,dtype", [
+    (layout, "float32") for layout in sorted(PARTIAL)]
+    + [("groups_end_inside_a_tile", "bfloat16")])
+def test_mlp_reads_no_row_past_the_groups(layout, dtype):
+    """With `rows_past` the saved a and b hold anything past the groups'
+    rows (no kernel wrote them; here NaN), and so do h, d a and d b made
+    from them: every gradient is finite and the one the composition gives
+    from zeros there."""
+    counts = jnp.asarray(PARTIAL[layout], jnp.int32)
+    R = int(counts.sum())
+    xs, gate, up, down, g = _mlp_operands(len(counts), dtype, R)
+    tiles = ((TM, (128, 128), (128, 128), (128, 128)),) * 2
+    ys, a, b = grouped._mlp(xs, gate, up, down, counts, None, tiles, True)
+    saved = (a.at[R:].set(jnp.nan), b.at[R:].set(jnp.nan), ys)
+    got = jax.vjp(lambda *w: grouped._mlp(
+        *w, counts, saved, tiles, True)[0], xs, gate, up, down)[1](g)
+    want = jax.vjp(lambda *w: _composition(*w, counts, True)[0],
+                   xs, gate, up, down)[1](g)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for ours, ref in zip(got, want):
+        ours, ref = (np.asarray(v, np.float32) for v in (ours, ref))
+        assert np.all(np.isfinite(ours))
+        assert np.max(np.abs(ours - ref)) <= tol * max(np.max(np.abs(ref)),
+                                                       1e-3)
+    assert not np.asarray(got[0], np.float32)[R:].any()
+
+
+@pytest.mark.parametrize("tk", [128, 256], ids=["split_k", "whole_k"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_a_product_is_added_onto_an_existing_one(layout, dtype, tk):
+    """`_gmm(..., "add", (existing,))`: the tile stored is existing +
+    product, from the float32 sums, over `existing` in place, in tiles a
+    boundary crosses too: the sum of two d lhs products with no pass to
+    add them."""
+    counts = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    rs = np.random.default_rng(len(layout))
+    g1, g2, w1, w2 = (jnp.asarray(rs.standard_normal(s), dtype) for s in (
+        (N, F), (N, F), (len(counts), H, F), (len(counts), H, F)))
+    meta, n = grouped.visits(counts, N, TM, False)
+    first = grouped._gmm(g1, w1, meta, n, (TM, tk, 128), True)
+    both = grouped._gmm(g2, w2, meta, n, (TM, tk, 128), True, "add",
+                        (first,))
+    want = sum(np.asarray(lax.ragged_dot(
+        g, jnp.swapaxes(w, 1, 2), counts,
+        preferred_element_type=jnp.float32), np.float32)
+        for g, w in ((g1, w1), (g2, w2)))
+    tol = 2e-6 if dtype == "float32" else 2 ** -7   # two roundings in bf16
+    assert np.max(np.abs(np.asarray(both, np.float32) - want)) \
+        <= tol * np.max(np.abs(want))
+
+
+@pytest.mark.parametrize("n_rows,h,f,products,whole_mlp", [
+    (65536, 2048, 1024, True, True),        # the olmoe_1b_7b cell
+    (16384, 3584, 1024, True, True),        # the xing4_0_29b_a4b cell
+    (None, 128, 256, True, True),
+    (65536, 4096, 4096, True, False),       # d h alone asks 144 MiB of VMEM
+    (65536, 2048 + 64, 1024, False, False),
+    (65536 + 64, 2048, 1024, False, False),
+])
+def test_mlp_kernels_are_taken_where_they_fit(monkeypatch, n_rows, h, f,
+                                              products, whole_mlp):
+    """`mlp_takes`: the shapes `takes` accepts, less those whose widest
+    kernel with its side tiles passes the VMEM limit; there `grouped_mlp`
+    is the composition of three `grouped_dot`s it was before."""
+    assert grouped.takes(n_rows, h, f) == products
+    assert grouped.mlp_takes(n_rows, h, f) == whole_mlp
+    if n_rows is not None:
+        return
+    monkeypatch.setattr(grouped, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped, "ROW_TILES", (TM,))
+    counts = jnp.asarray(LAYOUTS["even"], jnp.int32)
+    w = _mlp_operands(4, "float32")[:4]
+
+    def lowered(fn):
+        return str(jax.make_jaxpr(fn)(*w))
+
+    taken = lowered(lambda *w: grouped.grouped_mlp(*w, counts))
+    monkeypatch.setattr(grouped, "_VMEM_LIMIT", 2 ** 20)
+    refused = lowered(lambda *w: grouped.grouped_mlp(*w, counts))
+    assert refused == lowered(
+        lambda *w: _composition(*w, counts, False)) != taken

@@ -175,7 +175,8 @@ def test_lowered_counts_by_place():
         full = builder.build(fluid, json.load(f), 11)
     assert lm_ops.lowered_counts(full["prog"], tpu) == {
         "moe_ffn_grouped": 1, "grouped_matmul_kernel": 1,
-        "flash_attention": 1, "flash_attention_bwd": 1}
+        "grouped_mlp_epilogues": 1, "flash_attention": 1,
+        "flash_attention_bwd": 1}
     assert lm_ops.lowered_counts(full["prog"], cpu) == {
         "moe_ffn_grouped": 1}
 

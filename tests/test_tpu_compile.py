@@ -30,12 +30,18 @@ def shape_bytes(shape):
                * _BYTES[dt] for dt, dims in _ARRAY.findall(shape))
 
 
-def fusions(hlo_text):
+_ELEMENTWISE = {"add", "subtract", "multiply", "divide", "select", "convert",
+                "maximum", "minimum", "negate", "exponential", "logistic"}
+
+
+def fusions(hlo_text, unfused=False):
     """The fusion instructions of `compiled.as_text()` that run as device
     operations (those nested inside another fusion's computation are part
-    of it): name, kind, result shape (layouts dropped), bytes written (the
-    result) and read (the operands, looked up where they are defined)."""
-    shapes, out, inside, fused = {}, [], None, set()
+    of it): name, kind, result shape (layouts dropped), op_name, bytes
+    written (the result) and read (the operands, looked up where they are
+    defined; their shapes as `operand_shapes`). `unfused`: the element-wise
+    instructions XLA left outside every fusion as well (kind None)."""
+    shapes, out, inside, nested = {}, [], None, set()
     for line in hlo_text.splitlines():
         head = _COMPUTATION.match(line)
         if head:
@@ -45,18 +51,20 @@ def fusions(hlo_text):
         if not m:
             continue
         name, shape, opcode, rest = m.groups()
-        shapes[name] = shape
-        if opcode == "fusion":
+        shapes[name] = re.sub(r"\{[^}]*\}", "", shape)
+        nested.update(re.findall(r"(?:calls|to_apply)=%([^\s,]+)", rest))
+        if opcode == "fusion" or (unfused and opcode in _ELEMENTWISE):
             kind = re.search(r"kind=(\w+)", rest)
-            fused.add(re.search(r"calls=%([^\s,]+)", rest).group(1))
+            op_name = re.search(r'op_name="([^"]*)"', rest)
             out.append(dict(
                 name=name, kind=kind.group(1) if kind else None,
-                shape=re.sub(r"\{[^}]*\}", "", shape), inside=inside,
-                write=shape_bytes(shape),
+                shape=shapes[name], inside=inside, write=shape_bytes(shape),
+                op_name=op_name.group(1) if op_name else "",
                 operands=re.findall(r"%([^\s,()]+)", rest.split(")", 1)[0])))
-    out = [f for f in out if f["inside"] not in fused]
+    out = [f for f in out if f["inside"] not in nested]
     for f in out:
-        f["read"] = sum(shape_bytes(shapes.get(o, "")) for o in f["operands"])
+        f["operand_shapes"] = [shapes.get(o, "") for o in f["operands"]]
+        f["read"] = sum(shape_bytes(s) for s in f["operand_shapes"])
     return out
 
 
@@ -256,6 +264,55 @@ def test_grouped_kernels_compile_for_v5e(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 20
 
 
+# what `fusions(text, unfused=True)` under the scopes `moe_ffn` and
+# `moe_ffn_grad` read and wrote in the OLMoE step below at commit 54f7845
+# (PR 30): the gathers, the combine, the weights' three casts, SiLU * up
+# (0.40 GB), its backward (0.67) and `add_any` of two d xs products (0.81)
+_PARENT_MOE_PASSES = 7_612_436_708
+
+
+@pytest.mark.parametrize("n,e,h,f,rows_past", [
+    (65536, 64, 2048, 1024, False), (16384, 8, 3584, 1024, True)],
+    ids=["olmoe_1b_7b", "xing4_0_29b_a4b"])
+def test_grouped_mlp_compiles_for_v5e(one_chip, no_compile_cache,
+                                      monkeypatch, n, e, h, f, rows_past):
+    """Mosaic takes `grouped_mlp`'s nine kernels at both cells' shapes
+    with their side tiles (the widest, d h at K = 3584: rows of 3.5 MB, a
+    whole-K weight tile of 7 MB, a, b in and d a, d b out, all twice):
+    nine custom calls, the second d xs product written over the first (no
+    copy of it), and no pass over the rows between them but the zeroing
+    of ys and d xs where the groups end before the rows."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import grouped
+
+    monkeypatch.setattr(grouped, "pallas_interpret", lambda: False)
+    bf = jnp.bfloat16
+
+    def sds(shape, dt=bf):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(xs, gate, up, down, counts, g):
+        (ys, a, b), vjp = jax.vjp(lambda *w: grouped.grouped_mlp(
+            *w, counts, None, rows_past), xs, gate, up, down)
+        return ys, a, b, vjp((g, jnp.zeros_like(a), jnp.zeros_like(b)))
+
+    assert grouped.mlp_takes(n, h, f)
+    compiled = jax.jit(fn).lower(
+        sds((n, h)), sds((e, h, f)), sds((e, h, f)), sds((e, f, h)),
+        sds((e,), jnp.int32), sds((n, h))).compile()
+    text = compiled.as_text()
+    assert _custom_calls(text) == (
+        ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
+        + ["grouped_matmul_tn"] * 3)
+    over_rows = [f_ for f_ in fusions(text, unfused=True)
+                 if "bf16[%d," % n in f_["shape"]]
+    assert len(over_rows) == (1 if rows_past else 0)
+    assert not [m.group(1) for m in map(_INSTR.match, text.splitlines())
+                if m and m.group(3) == "copy"
+                and m.group(2).startswith("bf16[%d," % n)]
+
+
 def _olmoe_step(one_chip, monkeypatch, rows=2):
     """The one-layer OLMoE training step of the `olmoe_1b_7b` configuration
     (published widths, `rows` rows of 4096 tokens, bf16 AMP, AdamW, global
@@ -376,6 +433,23 @@ def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
               if m and m.group(3) in ("copy", "copy-start", "transpose")
               and re.match(r"\(?bf16\[%d,\d+,\d+\]" % E, m.group(2))]
     assert copies == []
+    # the expert MLP's element-wise work rides in the kernels (PR 31):
+    # outside them nothing under the op's two scopes touches a [T * k, F]
+    # array (SiLU * up and its backward) or takes two [T * k, H] arrays
+    # (the sum of the two d xs products), and those scopes' passes move
+    # 1.8 GB less than at the parent (the same rule on commit 54f7845)
+    passes = [f for f in fusions(text, unfused=True)
+              if re.search(r"/moe_ffn(_grad)?(/|$)", f["op_name"])]
+    rows_f, rows_h = ("bf16[%d,%d]" % (T * k, w) for w in (F, H))
+    assert [f["name"] for f in passes
+            if rows_f in f["shape"] or rows_f in f["operand_shapes"]] == []
+    assert [f["name"] for f in passes
+            if f["operand_shapes"].count(rows_h) > 1] == []
+    moved = sum(f["read"] + f["write"] for f in passes)
+    print("bytes a step the passes under moe_ffn / moe_ffn_grad move: "
+          "%.3f GB at the parent, %.3f GB now" % (_PARENT_MOE_PASSES / 1e9,
+                                                  moved / 1e9))
+    assert _PARENT_MOE_PASSES - moved >= 1.8e9
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)

@@ -9,6 +9,11 @@ this printed.
 
     chiprun -- python tools/grouped_share_sweep.py
     python tools/grouped_share_sweep.py --compile-only   # a described v5e
+
+`--epilogues` (PR 31) times instead, at these shapes and group sizes, the
+kernels of `grouped_mlp` that carry element-wise work with it, without it,
+and without it followed by the XLA pass it replaces
+(`grouped_sweep.epilogue_rows`), into chiprun_out/pr31/.
 """
 
 import argparse
@@ -26,6 +31,7 @@ def main():
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=3001)
+    ap.add_argument("--epilogues", action="store_true")
     args = ap.parse_args()
     if args.compile_only:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -50,6 +56,16 @@ def main():
     # 4096 tokens x 4 choices over 64 experts, 8 of them held
     chosen = rs.integers(0, 64, N)
     counts = np.bincount(chosen[chosen < E], minlength=E).astype(np.int32)
+    if args.epilogues:
+        from grouped_sweep import epilogue_rows     # beside this file
+
+        rows = epilogue_rows(N, E, 3584, 1024, counts.tolist(), args.calls,
+                             rows_past=True)
+        os.makedirs(os.path.join(REPO, "chiprun_out", "pr31"), exist_ok=True)
+        with open(os.path.join(REPO, "chiprun_out", "pr31",
+                               "share_epilogues.json"), "w") as f:
+            json.dump(rows, f, indent=1)
+        return
     results = []
     for shape_key, (K, M) in (("gate_up", (3584, 1024)),
                               ("down", (1024, 3584))):

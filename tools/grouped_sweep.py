@@ -15,6 +15,15 @@ module's `_BLOCK_ROWS` is set per variant; host clock around `--calls`
 back-to-back calls ending in block_until_ready) and writes
 chiprun_out/pr29/<--out>. `--compile-only` compiles every variant for a described v5e instead (no
 chip, no time): what Mosaic refuses shows here first.
+
+`epilogues` (PR 31) times the four kernels of `grouped_mlp` that carry
+element-wise work (SiLU * up behind the up product, its backward behind
+the d h product, the second d xs product added onto the first, and h
+formed in front of d down) with it, without it, and without it followed
+by the XLA pass it replaces, and writes chiprun_out/pr31/epilogues.json:
+
+    chiprun -- sh -c 'python tools/grouped_sweep.py counts --seed 2901 &&
+                      python tools/grouped_sweep.py epilogues'
 """
 
 import argparse
@@ -26,6 +35,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 OUT = os.path.join(REPO, "chiprun_out", "pr29")
+REPS = 8        # runs of a kernel inside one dispatch: `epilogue_rows`
 
 
 def real_counts(seed):
@@ -179,9 +189,129 @@ def sweep(args):
                    "counts": real, "rows": rows}, f, indent=1)
 
 
+def epilogue_rows(N, E, H, F, counts, calls, rows_past=False):
+    """One row a kernel of `grouped_mlp` that carries element-wise work:
+    ms a call of the kernel `plain`, `with` the work in it, and of
+    `plain_and_pass`, the plain kernel followed by the XLA pass the work
+    replaces; [N, H] rows, E experts of width F, bf16, the tiles
+    `tiles_for` chooses."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from paddle_tpu.parallel import grouped
+    from paddle_tpu.parallel.grouped import _gmm, _tgmm
+
+    bf = jnp.bfloat16
+    (tm, up_fwd, up_dlhs, _), (_, _, down_dlhs, down_drhs) = (
+        grouped.tiles_for(N, H, F, bf), grouped.tiles_for(N, F, H, bf))
+    key = jax.random.PRNGKey(0)
+
+    def draw(i, shape, scale=1.0):
+        return (jax.random.normal(jax.random.fold_in(key, i), shape)
+                * scale).astype(bf)
+
+    o = dict(xs=draw(0, (N, H)), d_ys=draw(1, (N, H)), a=draw(2, (N, F)),
+             b=draw(3, (N, F)), d_b=draw(4, (N, F)),
+             up=draw(5, (E, H, F), H ** -.5),
+             down=draw(6, (E, F, H), F ** -.5),
+             counts=jnp.asarray(counts, jnp.int32))
+
+    def meta(o, empty_groups=False):
+        return grouped.visits(o["counts"], N, tm, empty_groups)
+
+    def silu_mul(a, b):
+        return jax.nn.silu(a) * b
+
+    def up(o, *epilogue):
+        return _gmm(o["xs"], o["up"], *meta(o), (tm,) + up_fwd, False,
+                    *epilogue)
+
+    def d_h(o, *epilogue):
+        return _gmm(o["d_ys"], o["down"], *meta(o), (tm,) + down_dlhs, True,
+                    *epilogue)
+
+    def d_xs(o, *epilogue):
+        return _gmm(o["d_b"], o["up"], *meta(o), (tm,) + up_dlhs, True,
+                    *epilogue)
+
+    def d_down(o, lhs):
+        return _tgmm(lhs, o["d_ys"], *meta(o, True), (tm,) + down_drhs, bf,
+                     mask_lhs=True)
+
+    # the kernel plain, with the work in it, plain and then the pass that
+    # work replaces; `ex` is the first d xs product, each call's result
+    # given to the next
+    variants = {
+        "up_forward": (
+            lambda o, ex: up(o),
+            lambda o, ex: up(o, "silu_mul", (o["a"],)),
+            lambda o, ex: silu_mul(o["a"], up(o))),
+        "d_h": (
+            lambda o, ex: d_h(o),
+            lambda o, ex: d_h(o, "silu_mul_grad", (o["a"], o["b"])),
+            lambda o, ex: jax.vjp(silu_mul, o["a"], o["b"])[1](d_h(o))),
+        "second_d_xs": (
+            lambda o, ex: d_xs(o),
+            lambda o, ex: d_xs(o, "add", (ex,)),
+            lambda o, ex: ex + d_xs(o)),
+        "d_down": (
+            lambda o, ex: d_down(o, o["a"]),
+            lambda o, ex: d_down(o, (o["a"], o["b"])),
+            lambda o, ex: d_down(o, silu_mul(o["a"], o["b"]))),
+    }
+    rows = []
+    for name, fns in variants.items():
+        row = {"kernel": name, "rows": N, "rows_held": int(sum(counts))}
+        if rows_past:
+            row["rows_past"] = True
+        gives = name == "second_d_xs"
+        for what, fn in zip(("plain", "with", "plain_and_pass"), fns):
+            def runs(ex, o, fn=fn):
+                # REPS runs a dispatch (one costs the host ~0.2 ms, as
+                # long as a kernel at the share's shapes takes), the group
+                # sizes rotated from run to run so that none is the one
+                # before it
+                def again(_, carry):
+                    c, out = carry
+                    return jnp.roll(c, 1), fn(dict(o, counts=c),
+                                              out if gives else ex)
+
+                return lax.fori_loop(0, REPS - 1, again, (
+                    jnp.roll(o["counts"], 1), fn(o, ex)))[1]
+
+            jf = jax.jit(runs, donate_argnums=(0,) if gives else ())
+            ex = jnp.zeros((N, H), bf)
+            out = jax.block_until_ready(jf(ex, o))
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = jf(out if gives else ex, o)
+            jax.block_until_ready(out)
+            row[what + "_ms"] = (
+                time.perf_counter() - t0) / (calls * REPS) * 1e3
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    return rows
+
+
+def epilogues(args):
+    import jax
+
+    dev = jax.devices()[0]
+    assert dev.platform == "tpu", dev
+    with open(args.counts) as f:
+        real = json.load(f)["counts"]
+    out = {"device": dev.device_kind, "calls": args.calls, "counts": real}
+    for name, counts in (("real", real), ("even", [65536 // 64] * 64)):
+        out[name] = epilogue_rows(65536, 64, 2048, 1024, counts, args.calls)
+    os.makedirs(os.path.join(REPO, "chiprun_out", "pr31"), exist_ok=True)
+    with open(os.path.join(REPO, "chiprun_out", "pr31", "epilogues.json"),
+              "w") as f:
+        json.dump(out, f, indent=1)
+
+
 if __name__ == "__main__":
     p = argparse.ArgumentParser()
-    p.add_argument("phase", choices=["counts", "sweep"])
+    p.add_argument("phase", choices=["counts", "sweep", "epilogues"])
     p.add_argument("--seed", type=int, default=2901)
     p.add_argument("--calls", type=int, default=20)
     p.add_argument("--tm", type=int, nargs="+", default=[128, 256, 512, 1024])
@@ -196,4 +326,5 @@ if __name__ == "__main__":
                         "does not copy chiprun_out/ to the next call)")
     p.add_argument("--compile-only", action="store_true")
     a = p.parse_args()
-    real_counts(a.seed) if a.phase == "counts" else sweep(a)
+    {"counts": lambda: real_counts(a.seed), "sweep": lambda: sweep(a),
+     "epilogues": lambda: epilogues(a)}[a.phase]()
