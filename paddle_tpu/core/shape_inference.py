@@ -778,6 +778,10 @@ def _rotary_embedding(ctx):
     ctx.enforce(len(x) == 4, f"X must be [B, S, H, D], got {x}")
     ctx.enforce(x[-1] == -1 or x[-1] % 2 == 0,
                 f"head size {x[-1]} must be even (two rotated halves)")
+    r = int(ctx.attr("rotary_dim") or 0)
+    ctx.enforce(r % 2 == 0 and (x[-1] == -1 or 0 <= r <= x[-1]),
+                f"rotary_dim {r} must be even and at most the head size "
+                f"{x[-1]}")
     ctx.set_output_dim("Out", x)
 
 
@@ -788,13 +792,20 @@ def _causal_attention(ctx):
         return
     ctx.enforce(len(q) == 4, f"Q must be [B, S, H, D], got {q}")
     if k is not None:
-        ctx.enforce(_shapes_match(q, k),
-                    f"K{k} must match Q{q} (no grouped KV)")
+        # grouped-query heads: a whole group of query heads a key/value head
+        ctx.enforce(len(k) == 4 and _shapes_match(q[:2] + q[3:],
+                                                  k[:2] + k[3:])
+                    and (q[2] < 0 or k[2] < 0 or q[2] % k[2] == 0),
+                    f"K{k} must be [B, S, Hkv, D] of Q{q}, H a multiple "
+                    "of Hkv")
     if v is not None:
         # the values may be narrower or wider than the keys
-        ctx.enforce(len(v) == 4 and _shapes_match(q[:3], v[:3]),
-                    f"V{v} must be [B, S, H, Dv] of Q{q}")
-    ctx.set_output_dim("Out", q if v is None else v)
+        ctx.enforce(len(v) == 4 and _shapes_match(
+            (q if k is None else k)[:3], v[:3]),
+            f"V{v} must be [B, S, Hkv, Dv] of K{k}")
+    ctx.enforce(int(ctx.attr("window") or 0) >= 0,
+                "window must be >= 0 (0: the whole row)")
+    ctx.set_output_dim("Out", q if v is None else tuple(q[:3]) + (v[3],))
     ctx.set_output_dim("Lse", (q[0], q[2], q[1]))
 
 
@@ -805,8 +816,9 @@ def _causal_attention_grad(ctx):
         d = ctx.input_dim(slot)
         if d is not None:
             if g is not None and slot == "V":
-                ctx.enforce(_shapes_match(d, g),
-                            f"Out@GRAD{g} must match {slot}{d}")
+                ctx.enforce(_shapes_match(d[:2] + d[3:], g[:2] + g[3:]),
+                            f"Out@GRAD{g} must match {slot}{d} but in "
+                            "its heads")
             ctx.set_output_dim(slot + "@GRAD", d)
 
 

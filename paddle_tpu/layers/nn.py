@@ -648,14 +648,20 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
     return y
 
 
-def rotary_embedding(input, theta=10000.0, scaling=None, name=None):
+def rotary_embedding(input, theta=10000.0, scaling=None, rotary_dim=None,
+                     name=None):
     """Rotary position embedding (Su et al., arXiv:2104.09864) of
     [B, S, H, D] in the `rotate_half` convention; position = index in S.
     `scaling`: a config's `rope_scaling` of type yarn (factor, beta_fast,
-    beta_slow, original_max_position_embeddings): YaRN's frequencies."""
+    beta_slow, original_max_position_embeddings): YaRN's frequencies; its
+    `attention_factor`, where given, scales cos and sin. `rotary_dim` R <
+    D: the first R numbers of each head are rotated, the rest pass (a
+    config's `partial_rotary_factor` x D)."""
     helper = LayerHelper("rotary_embedding", **locals())
     y = helper.create_tmp_variable(input.dtype, shape=input.shape)
     attrs = {"theta": float(theta)}
+    if rotary_dim and int(rotary_dim) != int(input.shape[-1]):
+        attrs["rotary_dim"] = int(rotary_dim)
     if scaling:
         attrs.update(
             scaling_factor=float(scaling["factor"]),
@@ -663,20 +669,30 @@ def rotary_embedding(input, theta=10000.0, scaling=None, name=None):
             beta_slow=float(scaling.get("beta_slow", 1)),
             original_max_position=int(
                 scaling["original_max_position_embeddings"]))
+        if scaling.get("attention_factor"):
+            attrs["attention_factor"] = float(scaling["attention_factor"])
     helper.append_op("rotary_embedding", {"X": [input]}, {"Out": [y]}, attrs)
     return y
 
 
-def causal_attention(q, k, v, scale=None, name=None):
-    """softmax(Q K^T * scale + causal mask) V on Q, K [B, S, H, D] and V
-    [B, S, H, Dv] (`scale` None: 1 / sqrt(D)); on a TPU place the flash
-    kernel of parallel/flash.py (no [S, S] scores in HBM)."""
+def causal_attention(q, k, v, scale=None, window=None, name=None):
+    """softmax(Q K^T * scale + causal mask) V on Q [B, S, H, D], K [B, S,
+    Hkv, D] and V [B, S, Hkv, Dv] (`scale` None: 1 / sqrt(D)); H a
+    multiple of Hkv: grouped-query heads, H / Hkv consecutive query heads
+    read one key/value head. `window` W: a sliding window, query i sees
+    the keys j with 0 <= i - j < W (itself among them). On a TPU place
+    the flash kernels of parallel/flash.py (no [S, S] scores in HBM, K
+    and V read in place by every head of a group, blocks outside the band
+    skipped)."""
     helper = LayerHelper("causal_attention", **locals())
-    y = helper.create_tmp_variable(q.dtype, shape=v.shape)
+    y = helper.create_tmp_variable(
+        q.dtype, shape=tuple(q.shape[:3]) + (v.shape[3],))
     lse = helper.create_tmp_variable("float32", stop_gradient=True)
+    attrs = {} if scale is None else {"scale": float(scale)}
+    if window:
+        attrs["window"] = int(window)
     helper.append_op("causal_attention", {"Q": [q], "K": [k], "V": [v]},
-                     {"Out": [y], "Lse": [lse]},
-                     {} if scale is None else {"scale": float(scale)})
+                     {"Out": [y], "Lse": [lse]}, attrs)
     return y
 
 

@@ -8,7 +8,10 @@ cfg)`, `olmoe.olmoe_loss`, `olmoe.optimizer`; so is `xing4` (latent
 attention, a residual path of several streams, held and shared experts, a
 multi-token-prediction module, as one chip's share of each layer):
 `xing4.xing4(tokens, next_tokens, cfg)`, `xing4.xing4_loss`,
-`xing4.optimizer`.
+`xing4.optimizer`; and `laguna` (window and full attention layers of
+different head counts, grouped-query heads, per-head output gates, held
+and shared experts, as one chip's share): `laguna.laguna(tokens, cfg)`,
+`laguna.laguna_loss`, `laguna.optimizer`.
 """
 
 from . import mnist
@@ -19,9 +22,10 @@ from . import stacked_dynamic_lstm
 from . import machine_translation
 from . import olmoe
 from . import xing4
+from . import laguna
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
-           "machine_translation", "olmoe", "xing4"]
+           "machine_translation", "olmoe", "xing4", "laguna"]
 
 
 def get_model(name):

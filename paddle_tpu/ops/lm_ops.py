@@ -67,19 +67,30 @@ def rotary_embedding_op(ctx, ins, attrs):
     """X [B, S, H, D] -> Out: each head's two halves rotated by the angle
     position * frequency_i (`rotate_half`), position = index along S;
     the frequencies are theta^(-2i/D), or YaRN's where `scaling_factor` >
-    1 (`rotary_frequencies`)."""
+    1 (`rotary_frequencies`). With `rotary_dim` R < D the first R numbers
+    of each head are rotated (their two halves, frequencies theta^(-2i/R))
+    and the other D - R pass untouched; `attention_factor` scales cos and
+    sin (YaRN's, on the rotated part alone)."""
     x = first(ins, "X")
     S, D = x.shape[1], x.shape[3]
+    R = int(attrs.get("rotary_dim", 0)) or D
     inv_freq = rotary_frequencies(
-        D, attrs.get("theta", 10000.0), attrs.get("scaling_factor", 1.0),
+        R, attrs.get("theta", 10000.0), attrs.get("scaling_factor", 1.0),
         attrs.get("beta_fast", 32.0), attrs.get("beta_slow", 1.0),
         attrs.get("original_max_position", 4096)).astype(F32)
     ang = jnp.arange(S, dtype=F32)[:, None] * inv_freq[None, :]
     ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    factor = float(attrs.get("attention_factor", 1.0))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(F32)
-    x1, x2 = xf[..., :D // 2], xf[..., D // 2:]
+    x1, x2 = xf[..., :R // 2], xf[..., R // 2:R]
     rot = jnp.concatenate([-x2, x1], axis=-1)
-    return out(Out=(xf * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype))
+    y = xf[..., :R] * cos + rot * sin
+    if R < D:
+        y = jnp.concatenate([y, xf[..., R:]], axis=-1)
+    return out(Out=y.astype(x.dtype))
 
 
 def on_tpu():
@@ -87,14 +98,20 @@ def on_tpu():
     return places.trace_device().platform == "tpu"
 
 
-def _plain_causal_attention(q, k, v, scale=None):
-    """softmax(Q K^T * scale + mask) V on Q, K [B, H, S, D] and V [B, H,
-    S, Dv] (`scale` None: 1 / sqrt(D)), float32 scores, and their
-    logsumexp [B, H, S]: the lowering for places without Mosaic."""
+def _plain_causal_attention(q, k, v, scale=None, window=None):
+    """softmax(Q K^T * scale + mask) V on Q [B, H, S, D], K [B, Hkv, S, D]
+    and V [B, Hkv, S, Dv] (`scale` None: 1 / sqrt(D); H / Hkv consecutive
+    query heads read one key/value head; `window`: query i sees the keys
+    0 <= i - j < window), float32 scores, and their logsumexp [B, H, S]:
+    the lowering for places without Mosaic."""
     S, D = q.shape[2], q.shape[3]
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=F32)
     s = s / (D ** 0.5) if scale is None else s * scale
-    mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+    back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    mask = back >= 0 if window is None else (back >= 0) & (back < window)
     s = jnp.where(mask, s, -jnp.inf)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
@@ -111,9 +128,31 @@ def _plain_causal_attention(q, k, v, scale=None):
 # ms at (256, 256), 4.12 at (512, 512), 3.75 at (1024, 1024) (dK/dV 2.11,
 # dQ 1.80), 3.94 at (1024, 512), 4.02 at (512, 1024), 4.14-4.19 with a
 # 2048 side; the lax.scan they replaced took 14.85. Shorter rows shrink
-# the blocks (flash.normalize_blocks).
+# the blocks (flash.normalize_blocks). Both sweeps were of the whole
+# triangle with one key/value head a query head; a layer with grouped-query
+# heads and no window takes the same pair.
 FLASH_FWD_BLOCKS = dict(block_q=1024, block_k=1024)
 FLASH_BWD_BLOCKS = dict(block_q=1024, block_k=1024)
+# A window layer's blocks follow its band, whose key blocks alone the
+# kernels' grids run over: the largest power of two not above the window,
+# between 128 and 1024. Swept on the v5e at [4, 8 on 1, 8192, 128] bf16, a
+# band of 512 (tools/flash_window_sweep.py; PERF.md, PR 32; ms forward /
+# backward): 3.09 / 3.85 at (512, 512), where a query block visits 2 key
+# blocks and both carry a mask; 5.48 / 5.19 at (256, 256) (3 blocks, but a
+# visit has ~1.4 us of fixed cost and a 256 x 256 step computes for 0.4);
+# 11.3 / 10.3 at (128, 128); 3.19 / 6.12 at (1024, 1024) (four times the
+# band's pairs); 3.78 / 4.77 at (256, 512), 5.90 / 4.63 at (512, 256).
+
+
+def flash_blocks(window, backward=False):
+    """The (block_q, block_k) keyword arguments of the flash kernels for a
+    `causal_attention` with this window (0 or None: the whole triangle)."""
+    if not window:
+        return FLASH_BWD_BLOCKS if backward else FLASH_FWD_BLOCKS
+    side = 128
+    while side * 2 <= min(window, 1024):
+        side *= 2
+    return dict(block_q=side, block_k=side)
 
 
 def _heads_first(ins, *slots):
@@ -122,22 +161,28 @@ def _heads_first(ins, *slots):
 
 @register_op("causal_attention")
 def causal_attention_op(ctx, ins, attrs):
-    """Q, K [B, S, H, D], V [B, S, H, Dv] (Dv may differ from D: latent
-    attention's keys carry a rotary part its values lack) -> Out [B, S, H,
-    Dv], causal over the whole row, and Lse [B, H, S] (the scores'
-    logsumexp, kept for the backward). The scores are scaled by the attr
-    `scale`, 1/sqrt(D) where it is 0. On a TPU place the Pallas flash
-    kernel (parallel/flash.py), which never writes the [S, S] scores to
-    HBM; elsewhere the plain composition."""
+    """Q [B, S, H, D], K [B, S, Hkv, D], V [B, S, Hkv, Dv] (Dv may differ
+    from D: latent attention's keys carry a rotary part its values lack;
+    H a multiple of Hkv: grouped-query heads, H / Hkv consecutive query
+    heads read one key/value head) -> Out [B, S, H, Dv] and Lse [B, H, S]
+    (the scores' logsumexp, kept for the backward). Causal over the whole
+    row, or with the attr `window` W > 0 over a band: query i sees the
+    keys j with 0 <= i - j < W, itself among them. The scores are scaled
+    by the attr `scale`, 1/sqrt(D) where it is 0. On a TPU place the
+    Pallas flash kernel (parallel/flash.py), which never writes the [S, S]
+    scores to HBM, reads K and V in place for every head of a group and
+    skips the blocks outside the band (blocks: `flash_blocks`); elsewhere
+    the plain composition."""
     q, k, v = _heads_first(ins, "Q", "K", "V")
     scale = float(attrs.get("scale", 0.0)) or None
+    window = int(attrs.get("window", 0)) or None
     if on_tpu():
         from ..parallel.flash import flash_attention_fwd
 
         o, lse = flash_attention_fwd(q, k, v, causal=True, scale=scale,
-                                     **FLASH_FWD_BLOCKS)
+                                     window=window, **flash_blocks(window))
     else:
-        o, lse = _plain_causal_attention(q, k, v, scale)
+        o, lse = _plain_causal_attention(q, k, v, scale, window)
     return out(Out=jnp.swapaxes(o, 1, 2), Lse=lse)
 
 
@@ -166,15 +211,18 @@ def causal_attention_grad_op(ctx, ins, attrs):
     composition."""
     q, k, v, o, do = _heads_first(ins, "Q", "K", "V", "Out", "Out@GRAD")
     scale = float(attrs.get("scale", 0.0)) or None
+    window = int(attrs.get("window", 0)) or None
     if on_tpu():
         from ..parallel.flash import flash_attention_bwd
 
         grads = flash_attention_bwd(q, k, v, o, first(ins, "Lse"),
                                     do.astype(q.dtype), causal=True,
-                                    scale=scale, **FLASH_BWD_BLOCKS)
+                                    scale=scale, window=window,
+                                    **flash_blocks(window, backward=True))
     else:
         _, vjp = jax.vjp(
-            lambda *a: _plain_causal_attention(*a, scale)[0], q, k, v)
+            lambda *a: _plain_causal_attention(*a, scale, window)[0],
+            q, k, v)
         grads = vjp(do.astype(q.dtype))
     dq, dk, dv = (jnp.swapaxes(g, 1, 2) for g in grads)
     return out(**{"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv})
@@ -618,6 +666,46 @@ def _holds_a_share(op, block):
         < block.vars[op.input("Router")[0]].shape[1])
 
 
+def _has_window(op, block):
+    return bool(op.attrs.get("window", 0))
+
+
+def _has_head_groups(op, block):
+    """A `causal_attention` (or its grad) whose K has fewer heads than its
+    Q."""
+    q, k = (block.vars[op.input(s)[0]].shape for s in ("Q", "K"))
+    return q[2] != k[2]
+
+
+def window_blocks(program):
+    """(visited, of a full causal grid): score blocks the flash kernels of
+    the program's window layers compute a step, forward, dK/dV and dQ,
+    against those the same kernels with the same blocks would compute over
+    the whole triangle. Static, from the shapes and the blocks the kernels
+    are built with (`flash_blocks`, `flash.normalize_blocks`,
+    `flash.blocks_visited`); (0, 0) for a program without a window
+    layer."""
+    from ..parallel import flash
+
+    visited = whole = 0
+    for block in program.blocks:
+        for op in block.ops:
+            if op.type not in ("causal_attention", "causal_attention_grad") \
+                    or not _has_window(op, block):
+                continue
+            S = int(block.vars[op.input("Q")[0]].shape[1])
+            window = int(op.attrs["window"])
+            back = op.type.endswith("_grad")
+            bq, bk = flash.normalize_blocks(
+                **flash_blocks(window, back), Sq=S, Sk=S)
+            heads = int(block.vars[op.input("Q")[0]].shape[2])
+            n = heads * (2 if back else 1)          # dK/dV and dQ
+            visited += n * flash.blocks_visited(
+                S, S, bq, bk, window if window < S else None)
+            whole += n * flash.blocks_visited(S, S, bq, bk)
+    return visited, whole
+
+
 # (op type, counter, whether the lowering exists on a TPU place only,
 # which of those ops count: all when None)
 _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
@@ -626,7 +714,11 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
              functools.partial(_kernels_take, whole_mlp=True)),
             ("causal_attention", "flash_attention", True, None),
             ("causal_attention_grad", "flash_attention_bwd", True, None),
-            ("moe_ffn", "moe_ffn_held_experts", False, _holds_a_share))
+            ("moe_ffn", "moe_ffn_held_experts", False, _holds_a_share),
+            ("causal_attention", "flash_attention_window", True,
+             _has_window),
+            ("causal_attention", "flash_attention_head_groups", True,
+             _has_head_groups))
 
 
 def lowered_counts(program, device):
@@ -637,7 +729,9 @@ def lowered_counts(program, device):
     `grouped_mlp` runs them with SiLU * up, its backward and the sum of
     the two d xs products in their epilogues; those that hold a share of
     their experts as `moe_ffn_held_experts`) and, on a TPU place, its
-    `causal_attention` ops (each lowers through the flash kernel) and
+    `causal_attention` ops (each lowers through the flash kernel; those
+    with a window count as `flash_attention_window` too, those whose K has
+    fewer heads than their Q as `flash_attention_head_groups`) and
     `causal_attention_grad` ops (each through the two backward kernels).
     A program without them reports none. Kept on the program until that
     is mutated, like `bn_pool.count`."""

@@ -26,6 +26,19 @@ diagonal (or hold padded keys) pay for the mask. On the v5e at [2, 16, 4096,
 lax.scan of float32 einsums over the whole square that they replaced took
 14.85 (PERF.md, PR 27).
 
+Grouped-query heads (PR 32): k, v [B, Hkv, S, .] with H a multiple of Hkv
+are read IN PLACE by the H / Hkv consecutive query heads of a group (an
+index map `h -> h // group`, no repeated copy), and the dK/dV kernel sums
+over the group inside: its innermost axis runs over (head of the group,
+query block). A sliding window (causal only: query i sees the keys 0 <= i
+- j < window): the innermost axis of each kernel then runs over the BAND's
+blocks alone, from the first block the row sees (`_band_extents`; a grid
+step costs ~0.3 us even when it computes nothing, and a visit ~1.4 us
+beside its arithmetic), and the mask is applied only where the diagonal or
+the band's far edge crosses a block. Without a window and with one
+key/value head a query head the kernels lower as they did before either
+existed (tests/test_flash_attention.py holds the grids and index maps).
+
 On a TPU place Mosaic compiles the kernels; on any other place (CPU tests)
 they run in Pallas interpret mode (core.places.pallas_interpret).
 """
@@ -39,37 +52,51 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.places import pallas_interpret
 
-__all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd"]
+__all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd",
+           "blocks_visited"]
+
+# The kernels' names: Pallas puts a kernel's name on the name stack, so a
+# device trace's op_name ends `.../causal_attention/flash_fwd/pallas_call`
+# (forward), `.../causal_attention_grad/flash_dkv/...` and `.../flash_dq/...`
+KERNELS = ("flash_fwd", "flash_dkv", "flash_dq")
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-            scale, causal, block_q, block_k, nk):
+            scale, causal, block_q, block_k, nk, window=None, n_keys=None):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = ki = pl.program_id(2)
+    if window is not None:
+        # the grid's last axis runs over the band's key blocks alone
+        ki = _first_key_block(qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _accumulate():
+    def _accumulate(masked=causal):
         q = q_ref[0]                   # [bq, D]
         k = k_ref[0]                   # [bk, D]
         v = v_ref[0]                   # [bk, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
+        if masked:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = keep & (q_pos - k_pos < window)
+            s = jnp.where(keep, s, -jnp.inf)
 
         m_prev = m_scr[:]              # [bq]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)  # masked rows
-        p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - m_safe[:, None]))
+        p = jnp.exp(s - m_safe[:, None])
+        if masked or window is None:
+            p = jnp.where(jnp.isneginf(s), 0.0, p)
         corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
         l_scr[:] = corr * l_scr[:] + jnp.sum(p, axis=1)
         acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
@@ -77,13 +104,17 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
-    if causal:
+    if window is not None:
+        # a band: the mask on its two edges alone
+        _for_block(_accumulate, qi, ki, block_q, block_k, True, None, window,
+                   ki < n_keys)
+    elif causal:
         # skip k-blocks entirely above the causal frontier (half the grid)
         pl.when(qi * block_q + block_q - 1 >= ki * block_k)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l[:, None]).astype(o_ref.dtype)
@@ -94,23 +125,116 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         lse_ref[0] = jnp.broadcast_to(lse[None, :], (8, lse.shape[0]))
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    """q [BH, Sq, D] (Sq % block_q == 0), k/v [BH, Sk, D] (Sk % block_k
-    == 0) -> (out [BH, Sq, D], lse [BH, Sq])."""
+def _first_key_block(i, block_q, block_k, window):
+    """The first key block query block i of a band sees."""
+    return jnp.maximum(i * block_q - (window - 1), 0) // block_k
+
+
+def _first_query_block(j, block_q, block_k):
+    """The first query block that sees key block j."""
+    return (j * block_k) // block_q
+
+
+def _key_blocks_seen(i, block_q, block_k, nk, window):
+    """(first, last) key block that query block i computes with, as Python
+    ints: up to the diagonal, and with `window` from the band's far edge."""
+    first = 0 if window is None else max(
+        i * block_q - (window - 1), 0) // block_k
+    return first, min((i * block_q + block_q - 1) // block_k, nk - 1)
+
+
+def _band_extents(block_q, block_k, nq, nk, window):
+    """(key blocks a query block of the band sees at most, query blocks
+    that see a key block at most): the extents of the kernels' innermost
+    grid axes, which run over the band alone. On the v5e a grid step costs
+    ~0.3 us whether it computes or not: at [4, 8, 8192, 128] and 256 x 256
+    blocks a forward over the whole (32 x 32) grid with the band's 93
+    blocks computed took 10.0 ms, 3.4 at 1024 x 1024 (PERF.md, PR 32)."""
+    n_k = max(last - first + 1 for first, last in (
+        _key_blocks_seen(i, block_q, block_k, nk, window)
+        for i in range(nq)))
+    n_q = max(min((j * block_k + block_k + window - 2) // block_q, nq - 1)
+              - (j * block_k) // block_q + 1 for j in range(nk))
+    return max(n_k, 1), max(n_q, 1)
+
+
+def _band(block_q, block_k, nq, nk, causal, window):
+    """(q_of(j, i), k_of(i, j)): the query block the dK/dV kernel's operands
+    name at step i of key block j, and the key block the dQ kernel's (and a
+    window's forward kernel's) name at step j of query block i. Without a
+    window the steps run over every block and one above the diagonal names
+    the block of the nearest visited step, so it moves nothing. With one
+    they run over the band alone (`_band_extents`), from its first block,
+    and a step past its last names the last."""
+    if not causal:
+        return (lambda j, i: i), (lambda i, j: j)
+    if window is None:
+        def q_of(j, i):
+            return jnp.minimum(jnp.maximum(i, (j * block_k) // block_q),
+                               nq - 1)
+
+        def k_of(i, j):
+            return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+    else:
+        def q_of(j, i):
+            last = jnp.minimum(
+                (j * block_k + block_k + window - 2) // block_q, nq - 1)
+            return jnp.minimum(
+                _first_query_block(j, block_q, block_k) + i, last)
+
+        def k_of(i, j):
+            last = jnp.minimum((i * block_q + block_q - 1) // block_k,
+                               nk - 1)
+            return jnp.minimum(
+                _first_key_block(i, block_q, block_k, window) + j, last)
+
+    return q_of, k_of
+
+
+def blocks_visited(Sq, Sk, block_q, block_k, window=None):
+    """How many (query block, key block) steps of a causal grid over [Sq,
+    Sk] compute something: those the diagonal and, with `window`, the
+    band's far edge leave. Static, from the shapes a kernel is built
+    with; the forward, the dK/dV and the dQ kernel visit the same set."""
+    nq, nk = -(-Sq // block_q), -(-Sk // block_k)
+    return sum(max(last - first + 1, 0) for first, last in (
+        _key_blocks_seen(i, block_q, block_k, nk, window)
+        for i in range(nq)))
+
+
+def _of_head(group):
+    """Index of the key/value head that query head b (batch and heads
+    folded, heads fastest) reads: `group` consecutive query heads share
+    one."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
+    """q [BH, Sq, D] (Sq % block_q == 0), k/v [BHkv, Sk, D] (Sk % block_k
+    == 0; BH a multiple of BHkv: that many consecutive query heads read one
+    key/value head, in place) -> (out [BH, Sq, D], lse [BH, Sq])."""
     BH, Sq, Dq = q.shape  # Dq may carry the +1 padding-mask channel
     Sk = k.shape[1]
     Dv = v.shape[-1]
     nq, nk = Sq // block_q, Sk // block_k
-    kernel = functools.partial(
-        _kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, nk=nk)
+    kv = _of_head(BH // k.shape[0])
+    # without a window the forward names key block j itself, visited or not
+    _, k_of = _band(block_q, block_k, nq, nk, window is not None, window)
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k)
+    if window is not None:
+        n_keys, nk = nk, _band_extents(block_q, block_k, nq, nk, window)[0]
+        static.update(causal=True, window=window, n_keys=n_keys)
+    kernel = functools.partial(_kernel, nk=nk, **static)
     return pl.pallas_call(
         kernel,
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, Dq), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dq), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dq),
+                         lambda b, i, j: (kv(b), k_of(i, j), 0)),
+            pl.BlockSpec((1, block_k, Dv),
+                         lambda b, i, j: (kv(b), k_of(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
@@ -128,13 +252,24 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
+        name=KERNELS[0],
     )(q, k, v)
 
 
-def _fwd_padded(q, k, v, scale, causal, block_q, block_k):
+def _folded(x, pad=0):
+    """[B, H, S, ...] -> [B * H, S + pad, ...], the new rows zero."""
+    x = x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    return x
+
+
+def _fwd_padded(q, k, v, scale, causal, block_q, block_k, window=None):
     """Pad S to block multiples; padded KEYS are neutralized by extending D
     with a bias channel (q gains a 1, real keys a 0, padded keys -BIG), so
-    their scores vanish under exp without any in-kernel mask plumbing."""
+    their scores vanish under exp without any in-kernel mask plumbing.
+    k, v [B, Hkv, Sk, .]: H / Hkv consecutive query heads read one
+    key/value head."""
     B, H, Sq, _ = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
     pad_q = (-Sq) % block_q
@@ -152,11 +287,8 @@ def _fwd_padded(q, k, v, scale, causal, block_q, block_k):
             jnp.zeros((), q.dtype), -BIG)
         kw = jnp.concatenate(
             [kw, jnp.broadcast_to(maskch, kw.shape[:3] + (1,))], axis=-1)
-    BH = B * H
-    Dk = qw.shape[-1]
-    out, lse = _flash_fwd(
-        qw.reshape(BH, Sq + pad_q, Dk), kw.reshape(BH, Sk + pad_k, Dk),
-        vw.reshape(BH, Sk + pad_k, Dv), scale, causal, block_q, block_k)
+    out, lse = _flash_fwd(_folded(qw), _folded(kw), _folded(vw), scale,
+                          causal, block_q, block_k, window)
     out = out.reshape(B, H, Sq + pad_q, Dv)[:, :, :Sq]
     lse = lse[:, 0, :].reshape(B, H, Sq + pad_q)[:, :, :Sq]
     return out, lse
@@ -184,7 +316,7 @@ def normalize_blocks(block_q, block_k, Sq, Sk):
 
 
 def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=256, block_k=256):
+                    block_q=256, block_k=256, window=None):
     """Exact attention [B, H, S, D] -> [B, H, S, D]; differentiable.
 
     Defaults (256, 256) measured fastest on a v5e chip at S=1024 D=128 —
@@ -192,47 +324,67 @@ def flash_attention(q, k, v, causal=False, scale=None,
     instead of the dense [S, S] score matrix (S >= 16k runs comfortably).
     The backward kernels take the same blocks; at S=4096 both directions
     are fastest at (1024, 1024) (ops/lm_ops.py carries the sweeps). Blocks
-    auto-shrink for short sequences."""
-    scale, block_q, block_k = _resolve(q, k, scale, block_q, block_k)
-    return _flash(q, k, v, scale, bool(causal), block_q, block_k)
+    auto-shrink for short sequences.
+
+    k, v [B, Hkv, S, .] with H a multiple of Hkv: grouped-query heads, H /
+    Hkv consecutive query heads read one key/value head in place (no
+    repeated copy) and its dK, dV are summed over them inside the dK/dV
+    kernel. `window` (causal only): query i sees the keys j with 0 <= i -
+    j < window, itself among them; the blocks outside the band are
+    skipped as those above the diagonal are."""
+    scale, block_q, block_k, window = _resolve(q, k, scale, block_q,
+                                               block_k, causal, window)
+    return _flash(q, k, v, scale, bool(causal), block_q, block_k, window)
 
 
-def _resolve(q, k, scale, block_q, block_k):
+def _resolve(q, k, scale, block_q, block_k, causal=True, window=None):
     block_q, block_k = normalize_blocks(block_q, block_k,
                                         q.shape[2], k.shape[2])
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    return float(scale), int(block_q), int(block_k)
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                         "key/value heads: not a whole group each")
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError("a window is a causal band of >= 1 positions")
+        # a band as long as the row is the triangle: the kernels as they
+        # are without one
+        window = int(window) if int(window) < k.shape[2] else None
+    return float(scale), int(block_q), int(block_k), window
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None,
-                        block_q=256, block_k=256):
+                        block_q=256, block_k=256, window=None):
     """The forward kernel alone: (out [B, H, S, D], lse [B, H, S]). For a
     caller that keeps `lse` itself and calls `flash_attention_bwd` later
     (an op whose backward is another op), so the kernel runs once."""
-    scale, block_q, block_k = _resolve(q, k, scale, block_q, block_k)
-    return _fwd_padded(q, k, v, scale, bool(causal), block_q, block_k)
+    scale, block_q, block_k, window = _resolve(q, k, scale, block_q,
+                                               block_k, causal, window)
+    return _fwd_padded(q, k, v, scale, bool(causal), block_q, block_k,
+                       window)
 
 
 def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
-                        block_q=1024, block_k=1024):
+                        block_q=1024, block_k=1024, window=None):
     """(dq, dk, dv) from the saved output and logsumexp of
-    `flash_attention_fwd` with the same q, k, v, causal and scale. The
-    blocks are the backward kernels' own; the default is the fastest of a
-    sweep on the v5e at [2, 16, 4096, 128] bf16 causal (ops/lm_ops.py)."""
-    scale, block_q, block_k = _resolve(q, k, scale, block_q, block_k)
-    return _flash_vjp_bwd(scale, bool(causal), block_q, block_k,
+    `flash_attention_fwd` with the same q, k, v, causal, scale and window.
+    The blocks are the backward kernels' own; the default is the fastest of
+    a sweep on the v5e at [2, 16, 4096, 128] bf16 causal (ops/lm_ops.py)."""
+    scale, block_q, block_k, window = _resolve(q, k, scale, block_q,
+                                               block_k, causal, window)
+    return _flash_vjp_bwd(scale, bool(causal), block_q, block_k, window,
                           (q, k, v, out, lse), do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_k):
-    out, _ = _fwd_padded(q, k, v, scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_k, window=None):
+    out, _ = _fwd_padded(q, k, v, scale, causal, block_q, block_k, window)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k):
-    out, lse = _fwd_padded(q, k, v, scale, causal, block_q, block_k)
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
+    out, lse = _fwd_padded(q, k, v, scale, causal, block_q, block_k, window)
     return out, (q, k, v, out, lse)
 
 
@@ -251,10 +403,11 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len):
+def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len, window=None):
     """p = exp(s - lse) on one float32 score block; where `masked`, zero
-    for the pairs above the diagonal and for padded keys. Queries run
-    along `q_axis` of the block and keys along the other."""
+    for the pairs above the diagonal, for those `window` or more positions
+    back, and for padded keys. Queries run along `q_axis` of the block and
+    keys along the other."""
     p = jnp.exp(s - lse)
     if not masked:
         return p
@@ -263,21 +416,34 @@ def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len):
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
         keep = q_pos >= k_pos if keep is None else keep & (q_pos >= k_pos)
+        if window is not None:
+            keep = keep & (q_pos - k_pos < window)
     return jnp.where(keep, p, 0.0)
 
 
-def _for_block(accumulate, qi, ki, block_q, block_k, causal, kv_len):
+def _for_block(accumulate, qi, ki, block_q, block_k, causal, kv_len,
+               window=None, inside=True):
     """Run `accumulate(masked)` for the score block (qi, ki): not at all if
-    the block lies wholly above the causal frontier, and with the mask only
-    if some pair of it carries no weight (the block crosses the diagonal, or
-    holds keys at or past `kv_len`, which are padding)."""
+    the block lies wholly above the causal frontier or, with `window`,
+    wholly behind the band; and with the mask only if some pair of it
+    carries no weight (the block crosses the diagonal or the band's far
+    edge, or holds keys at or past `kv_len`, which are padding). `inside`:
+    whether the step's block exists at all (a band's grid axis runs a
+    fixed number of steps from the band's first block, past the array's
+    end for the last rows)."""
     if not causal and kv_len is None:
         accumulate(False)
         return
-    visited, edge = True, False
+    visited, edge = inside, False
     if causal:
-        visited = qi * block_q + block_q - 1 >= ki * block_k
+        visited = inside & (qi * block_q + block_q - 1 >= ki * block_k)
         edge = qi * block_q < ki * block_k + block_k - 1
+        if window is not None:
+            # the nearest pair is inside the band; the farthest is not
+            visited = visited & (
+                qi * block_q - (ki * block_k + block_k - 1) < window)
+            edge = edge | (qi * block_q + block_q - 1 - ki * block_k
+                           >= window)
     if kv_len is not None:
         edge = edge | ((ki + 1) * block_k > kv_len)
     pl.when(visited & edge)(lambda: accumulate(True))
@@ -286,15 +452,21 @@ def _for_block(accumulate, qi, ki, block_q, block_k, causal, kv_len):
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dk_ref, dv_ref,
                 dk_scr, dv_scr, *, scale, causal, block_q, block_k, nq,
-                kv_len):
-    """One key block against the query blocks at or below it. Scores are
-    held transposed, [bk, bq], so that every product is a plain one (no
-    block is transposed on its way into the MXU) and the per-query `lse`
-    and `delta` broadcast along sublanes from their lane-major rows."""
+                kv_len, window=None, group=1, n_queries=None):
+    """One key block against the query blocks at or below it (within the
+    band, with `window`), of each of the `group` query heads that read
+    it: the innermost axis runs over (head of the group, query block).
+    Scores are held transposed, [bk, bq], so that every product is a plain
+    one (no block is transposed on its way into the MXU) and the per-query
+    `lse` and `delta` broadcast along sublanes from their lane-major
+    rows."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = step if group == 1 else step % nq
+    if window is not None:
+        qi = _first_query_block(ki, block_q, block_k) + qi
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -303,15 +475,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dk_ref, dv_ref,
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         st = _dot(k, q, _NT) * scale                       # [bk, bq]
         pt = _weights(st, ld_ref[0, 0:1, :], masked, qi * block_q,
-                      ki * block_k, 1, causal, kv_len)
+                      ki * block_k, 1, causal, kv_len, window)
         dv_scr[:] += _dot(pt.astype(do.dtype), do, _NN)
         dpt = _dot(v, do, _NT)
         dst = pt * (dpt - ld_ref[0, 1:2, :])
         dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len)
+    _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len, window,
+               True if window is None else qi < n_queries)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finish():
         # ds = p (dp - delta) scale: the scale once, on the float32 sum
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
@@ -319,13 +492,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dk_ref, dv_ref,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dq_ref, dq_scr, *,
-               scale, causal, block_q, block_k, nk, kv_len):
-    """One query block against the key blocks at or below the diagonal;
-    scores [bq, bk], `lse` and `delta` as columns."""
+               scale, causal, block_q, block_k, nk, kv_len, window=None,
+               n_keys=None):
+    """One query block against the key blocks at or below the diagonal
+    (within the band, with `window`); scores [bq, bk], `lse` and `delta`
+    as columns."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = ki = pl.program_id(2)
+    if window is not None:
+        ki = _first_key_block(qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -333,63 +510,74 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dq_ref, dq_scr, *,
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = _dot(q, k, _NT) * scale                        # [bq, bk]
         p = _weights(s, ld_ref[0, :, 0:1], masked, qi * block_q,
-                     ki * block_k, 0, causal, kv_len)
+                     ki * block_k, 0, causal, kv_len, window)
         dp = _dot(do, v, _NT)
         ds = p * (dp - ld_ref[0, :, 1:2])
         dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len)
+    _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len, window,
+               True if window is None else ki < n_keys)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
-               kv_len):
-    """q [BH, Sq, D], k [BH, Sk, D], v [BH, Sk, Dv], do [BH, Sq, Dv] (Dv
-    may differ from D, as in the forward), lse, delta [BH, Sq] float32
-    (Sq % block_q == 0, Sk % block_k == 0; keys at and past `kv_len`, if
-    given, are padding) -> dq, dk, dv. Two kernels: dK/dV with the query
-    blocks innermost, dQ with the key blocks innermost, each recomputing
-    its score block in VMEM from the saved logsumexp. A step above the
-    causal frontier is skipped, and its index maps name the block of the
-    nearest visited step, so it moves nothing either."""
+               kv_len, window=None):
+    """q [BH, Sq, D], k [BHkv, Sk, D], v [BHkv, Sk, Dv], do [BH, Sq, Dv]
+    (Dv may differ from D, as in the forward; BH / BHkv consecutive query
+    heads read one key/value head), lse, delta [BH, Sq] float32 (Sq %
+    block_q == 0, Sk % block_k == 0; keys at and past `kv_len`, if given,
+    are padding) -> dq, dk, dv. Two kernels: dK/dV with the query blocks
+    (of every query head of the group) innermost, dQ with the key blocks
+    innermost, each recomputing its score block in VMEM from the saved
+    logsumexp. Without a window a step above the causal frontier is
+    skipped, and its index maps name the block of the nearest visited
+    step, so it moves nothing either; with one the innermost axes run over
+    the band's blocks alone, and a step past its last block names that
+    block and computes nothing (`_band`, `_band_extents`)."""
     BH, Sq, D = q.shape
     Sk, Dv = k.shape[1], v.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
-    if causal:
-        # dK/dV: the first query block that sees key block j (skipped steps
-        # come first); dQ: the last key block query block i sees (last)
-        def q_of(j, i):
-            return jnp.minimum(jnp.maximum(i, (j * block_k) // block_q),
-                               nq - 1)
-
-        def k_of(i, j):
-            return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+    group = BH // k.shape[0]
+    kv = _of_head(group)
+    q_of, k_of = _band(block_q, block_k, nq, nk, causal, window)
+    if group == 1:
+        def q_at(b, j, i):
+            return b, q_of(j, i)
     else:
-        def q_of(j, i):
-            return i
-
-        def k_of(i, j):
-            return j
+        # step i of key/value head b: the (i % steps_q)-th query block of
+        # the group's head i // steps_q
+        def q_at(b, j, i):
+            return b * group + i // steps_q, q_of(j, i % steps_q)
     static = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, kv_len=kv_len)
+    # the innermost axes: every block, or with a window the band's alone
+    steps_k, steps_q, band_k, band_q = nk, nq, {}, {}
+    if window is not None:
+        static["window"] = window
+        steps_k, steps_q = _band_extents(block_q, block_k, nq, nk, window)
+        band_k, band_q = {"n_keys": nk}, {"n_queries": nq}
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=_BWD_VMEM_LIMIT)
     ld = jnp.stack([lse, delta], axis=1)                   # [BH, 2, Sq]
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, nq=nq, **static),
-        grid=(BH, nk, nq),
+        functools.partial(_dkv_kernel, nq=steps_q, **static, **band_q,
+                          **({} if group == 1 else {"group": group})),
+        grid=(k.shape[0], nk, group * steps_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, q_of(j, i), 0)),
+            pl.BlockSpec((1, block_q, D),
+                         lambda b, j, i: (*q_at(b, j, i), 0)),
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, Dv),
-                         lambda b, j, i: (b, q_of(j, i), 0)),
-            pl.BlockSpec((1, 2, block_q), lambda b, j, i: (b, 0, q_of(j, i))),
+                         lambda b, j, i: (*q_at(b, j, i), 0)),
+            pl.BlockSpec((1, 2, block_q),
+                         lambda b, j, i: (q_at(b, j, i)[0], 0,
+                                          q_at(b, j, i)[1])),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
@@ -401,16 +589,18 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
                         pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=params,
         interpret=pallas_interpret(),
+        name=KERNELS[1],
     )(q, k, v, do, ld)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nk=nk, **static),
-        grid=(BH, nq, nk),
+        functools.partial(_dq_kernel, nk=steps_k, **static, **band_k),
+        grid=(BH, nq, steps_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, k_of(i, j), 0)),
+            pl.BlockSpec((1, block_k, D),
+                         lambda b, i, j: (kv(b), k_of(i, j), 0)),
             pl.BlockSpec((1, block_k, Dv),
-                         lambda b, i, j: (b, k_of(i, j), 0)),
+                         lambda b, i, j: (kv(b), k_of(i, j), 0)),
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 2), lambda b, i, j: (b, i, 0)),
         ],
@@ -419,32 +609,26 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=params,
         interpret=pallas_interpret(),
+        name=KERNELS[2],
     )(q, k, v, do, jnp.swapaxes(ld, 1, 2))
     return dq, dk, dv
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, res, do):
+def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, do):
     """Pad as `_fwd_padded` does and run the two backward kernels. A
     padded key gets no weight (the kernels mask keys past Sk); a padded
     query row carries dO = 0 and delta = 0, so with any finite lse it adds
     nothing to dK or dV, and its dQ row is cut off."""
     q, k, v, out, lse = res
-    B, H, Sq, _ = q.shape
-    Sk = k.shape[2]
+    Sq, Sk = q.shape[2], k.shape[2]
     pad_q = (-Sq) % block_q
     pad_k = (-Sk) % block_k
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
-
-    def rows(x, pad):
-        x = x.reshape((B * H,) + x.shape[2:])
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        return x
-
     dq, dk, dv = _flash_bwd(
-        rows(q, pad_q), rows(k, pad_k), rows(v, pad_k),
-        rows(do.astype(q.dtype), pad_q), rows(lse, pad_q), rows(delta, pad_q),
-        scale, causal, block_q, block_k, Sk if pad_k else None)
+        _folded(q, pad_q), _folded(k, pad_k), _folded(v, pad_k),
+        _folded(do.astype(q.dtype), pad_q), _folded(lse, pad_q),
+        _folded(delta, pad_q), scale, causal, block_q, block_k,
+        Sk if pad_k else None, window)
     return (dq[:, :Sq].reshape(q.shape), dk[:, :Sk].reshape(k.shape),
             dv[:, :Sk].reshape(v.shape))
 

@@ -177,3 +177,184 @@ def test_ring_flash_matches_dense(causal):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=1e-4)
 
+
+
+# ------------------------------------------------ window, grouped-query heads
+def _dense_band(q, k, v, window=None, scale=None):
+    """The plain composition: k, v [B, Hkv, S, .] repeated for the query
+    heads of each group, the [S, S] mask written out (j <= i, and with
+    `window` i - j < window: the token itself counts)."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    S, Sk = q.shape[2], k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    s = s / np.sqrt(q.shape[-1]) if scale is None else s * scale
+    i, j = jnp.arange(S)[:, None], jnp.arange(Sk)[None, :]
+    keep = j <= i
+    if window is not None:
+        keep = keep & (i - j < window)
+    p = jax.nn.softmax(jnp.where(keep[None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def _band_case(S, heads, kv_heads, d=16, dv=None, seed=0, dtype=np.float32):
+    rng = np.random.RandomState(seed)
+    dv = dv or d
+    return tuple(jnp.asarray(rng.randn(*shape).astype(dtype)) for shape in (
+        (2, heads, S, d), (2, kv_heads, S, d), (2, kv_heads, S, dv),
+        (2, heads, S, dv)))
+
+
+# window against a block of 32: none, smaller than the block, equal to it,
+# two and a half blocks, longer than the row
+WINDOWS = [None, 8, 32, 80, 1000]
+# (query heads, key/value heads): groups of 1, 6 and 8
+HEADS = [(2, 2), (6, 1), (8, 1), (4, 2)]
+
+
+@pytest.mark.parametrize("which", ["forward", "dkv", "dq"])
+@pytest.mark.parametrize("S", [96, 100])      # 100: rows no block divides
+@pytest.mark.parametrize("heads", HEADS, ids=lambda h: f"{h[0]}over{h[1]}")
+@pytest.mark.parametrize("window", WINDOWS, ids=lambda w: f"w{w}")
+def test_band_and_head_groups_match_the_plain_composition(window, heads, S,
+                                                          which):
+    q, k, v, cot = _band_case(S, *heads)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                               window=window)
+
+    def plain(q, k, v):
+        return _dense_band(q, k, v, window)
+
+    if which == "forward":
+        got, want = [flash(q, k, v)], [plain(q, k, v)]
+    else:
+        got, want = (_grads(f, q, k, v, cot) for f in (flash, plain))
+        pick = slice(1, 3) if which == "dkv" else slice(0, 1)
+        got, want = got[pick], want[pick]
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-3)
+
+
+@pytest.mark.parametrize("blocks", [(32, 64), (64, 32), (16, 32)])
+@pytest.mark.parametrize("window", [24, 40])
+def test_band_with_unequal_blocks(window, blocks):
+    q, k, v, cot = _band_case(128, 6, 1, seed=3)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=blocks[0],
+                               block_k=blocks[1], window=window)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(_dense_band(q, k, v, window)),
+        atol=5e-5, rtol=1e-3)
+    for a, b in zip(_grads(flash, q, k, v, cot),
+                    _grads(lambda *a: _dense_band(*a, window), q, k, v, cot)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-3)
+
+
+@pytest.mark.parametrize("window,heads", [(None, (4, 4)), (24, (4, 4)),
+                                          (24, (6, 1))])
+def test_keys_wider_than_values_still_pass(window, heads):
+    """Latent attention's case: keys of 24 numbers, values of 16."""
+    q, k, v, cot = _band_case(96, *heads, d=24, dv=16, seed=5)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=0.2, block_q=32,
+                               block_k=32, window=window)
+
+    def plain(q, k, v):
+        return _dense_band(q, k, v, window, scale=0.2)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(plain(q, k, v)), atol=5e-5,
+                               rtol=1e-3)
+    for a, b in zip(_grads(flash, q, k, v, cot),
+                    _grads(plain, q, k, v, cot)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-3)
+
+
+def _calls(fn, *args):
+    """The pallas_call equations of `fn`'s jaxpr: (name, grid, in block
+    index at a few grid points, kernel's static parameters)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_without_window_and_group_the_grid_and_index_maps_are_as_before(
+        causal):
+    """A call with no window and as many key/value heads as query heads
+    builds what it built before windows and head groups existed: the grids
+    (B * H, blocks, blocks), no parameter of the new kind in any kernel,
+    and index maps that read, written out here as they stood: the forward
+    key block j; dK/dV the query block min(max(i, j bk // bq), nq - 1); dQ
+    the key block min(j, (i bq + bq - 1) // bk)."""
+    from paddle_tpu.parallel import flash
+
+    B, H, S, D, bq, bk = 2, 3, 128, 16, 32, 64
+    nq, nk = S // bq, S // bk
+    q, k, v, cot = (jnp.ones((B, H, S, D), jnp.float32),) * 4
+    calls = _calls(
+        lambda q, k, v: _grads(lambda *a: flash_attention(
+            *a, causal=causal, block_q=bq, block_k=bk), q, k, v, cot),
+        q, k, v)
+    assert [c.params["grid_mapping"].grid for c in calls] == [
+        (B * H, nq, nk), (B * H, nk, nq), (B * H, nq, nk)]
+    for c in calls:
+        text = str(c.params["jaxpr"])
+        assert "window" not in text and "group" not in text
+    q_of, k_of = flash._band(bq, bk, nq, nk, causal, None)
+    kv = flash._of_head(1)
+    for b in range(B * H):
+        assert kv(b) == b
+    for i in range(nq):
+        for j in range(nk):
+            if causal:
+                assert int(q_of(j, i)) == min(max(i, j * bk // bq), nq - 1)
+                assert int(k_of(i, j)) == min(j, (i * bq + bq - 1) // bk)
+            else:
+                assert (q_of(j, i), k_of(i, j)) == (i, j)
+    # the forward names key block j itself, visited or not
+    fwd_maps = calls[0].params["grid_mapping"].block_mappings
+    for i in range(nq):
+        for j in range(nk):
+            idx = jax.core.eval_jaxpr(
+                fwd_maps[1].index_map_jaxpr.jaxpr,
+                fwd_maps[1].index_map_jaxpr.consts, 4, i, j)
+            assert [int(x) for x in idx] == [4, j, 0]
+
+
+@pytest.mark.parametrize("S,block,window,want", [
+    (8192, 1024, None, 36), (8192, 1024, 512, 15), (8192, 512, 512, 31),
+    (8192, 256, 512, 93), (8192, 128, 512, 310), (100, 32, 8, 7),
+    (100, 32, 1000, 10)])
+def test_blocks_visited_counts_the_band(S, block, window, want):
+    """The count the window kernels' grids are held to: the steps that the
+    kernels' own `_for_block` predicate lets compute."""
+    from paddle_tpu.parallel import flash
+
+    assert flash.blocks_visited(S, S, block, block, window) == want
+    n = -(-S // block)
+    seen = 0
+    for i in range(n):
+        for j in range(n):
+            visited = i * block + block - 1 >= j * block
+            if window is not None:
+                visited &= i * block - (j * block + block - 1) < window
+            seen += visited
+    assert seen == want

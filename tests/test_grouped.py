@@ -149,6 +149,8 @@ def test_a_given_mlp_is_not_computed_again(monkeypatch):
     (16384, 3584, 1024, "bfloat16", "kernels"),     # K whole at 7 KiB a row
     (16384, 1024, 3584, "bfloat16", "kernels"),
     (16384, 7168, 1024, "bfloat16", "kernels"),     # 14 KiB a row: split
+    (65536, 2048, 512, "bfloat16", "kernels"),      # experts of width 512:
+    (65536, 512, 2048, "bfloat16", "kernels"),      # gate / up, and down
     (65536 + 128, 2048, 1024, "bfloat16", "kernels"),
     (65536 + 64, 2048, 1024, "bfloat16", None),     # rows no tile divides
     (65536, 2048 + 64, 1024, "bfloat16", None),     # K not of 128
@@ -563,3 +565,68 @@ def test_mlp_kernels_are_taken_where_they_fit(monkeypatch, n_rows, h, f,
     refused = lowered(lambda *w: grouped.grouped_mlp(*w, counts))
     assert refused == lowered(
         lambda *w: _composition(*w, counts, False)) != taken
+
+
+# ------------------------------------------- experts of width 512, 32 held
+# 32 groups over 512 rows, walked in tiles of 64: most tiles hold several
+# groups, five groups are empty (first, last, two in a row), one fills a
+# tile exactly, and the list ends 50 rows before the rows do
+SMALL_GROUPS = [0, 9, 23, 64, 5, 17, 0, 0, 31, 12, 7, 60, 3, 26, 11, 19,
+                8, 22, 1, 30, 14, 6, 27, 10, 4, 16, 13, 2, 18, 2, 2, 0]
+WIDTH = 512
+
+
+@functools.lru_cache(maxsize=None)
+def _mlp_of_small_groups(past):
+    counts = np.asarray(SMALL_GROUPS, np.int32)
+    assert len(counts) == 32 and counts.sum() == 512 - 50
+    if not past:
+        counts[3] += 50
+    counts = jnp.asarray(counts)
+    rs = np.random.default_rng(32)
+    n = int(counts.shape[0])
+    xs, gate, up, down, g = (
+        jnp.asarray(rs.standard_normal(shape) * scale, jnp.float32)
+        for shape, scale in (((512, WIDTH), 1.0), ((n, WIDTH, WIDTH), .04),
+                             ((n, WIDTH, WIDTH), .04),
+                             ((n, WIDTH, WIDTH), .04), ((512, WIDTH), 1.0)))
+    t = (WIDTH, WIDTH)
+    tiles = ((TM, t, t, t),) * 2
+    results = []
+    for fn in (lambda *w: grouped._mlp(*w, counts, None, tiles, past)[0],
+               lambda *w: _composition(*w, counts, past)[0]):
+        out, vjp = jax.vjp(fn, xs, gate, up, down)
+        results.append([np.asarray(x, np.float32) for x in (out, *vjp(g))])
+    return results
+
+
+@pytest.mark.parametrize("which", MLP_OUTPUTS)
+@pytest.mark.parametrize("past", [False, True],
+                         ids=["every_row_grouped", "a_partial_group_list"])
+def test_mlp_at_width_512_over_32_small_groups(past, which):
+    """`grouped_mlp`'s kernels at N = 512 (gate, up) and K = 512 (down)
+    with whole contractions, over 32 groups of 0 to 64 rows: many
+    boundaries a tile, empty groups, and with `past` a group list that
+    ends before the rows do."""
+    i = MLP_OUTPUTS.index(which)
+    ours, ref = (r[i] for r in _mlp_of_small_groups(past))
+    assert ours.shape == ref.shape and np.all(np.isfinite(ours))
+    assert np.max(np.abs(ours - ref)) <= 1e-5 * max(np.max(np.abs(ref)),
+                                                     1e-3)
+    if past and which in ("ys", "d_xs"):
+        assert not ours[512 - 50:].any()
+    if which in ("d_gate", "d_up", "d_down"):
+        empty = [e for e, c in enumerate(SMALL_GROUPS) if c == 0]
+        assert not ours[empty].any() and ours[1].any()
+
+
+def test_width_512_takes_the_kernels_with_their_epilogues():
+    """[65536, 2048] x [32, 2048, 512] and back, the `laguna_xs_2` cell's
+    grouped products: whole contractions, the longest row tile, and the
+    two widest kernels inside the VMEM limit."""
+    assert grouped.mlp_takes(65536, 2048, 512)
+    for dtype in ("bfloat16", "float32"):
+        assert grouped.tiles_for(65536, 2048, 512, dtype) == (
+            512, (2048, 512), (512, 2048), (2048, 512))
+    assert grouped.tiles_for(65536, 512, 2048, "bfloat16") == (
+        512, (512, 2048), (2048, 512), (512, 2048))

@@ -413,10 +413,18 @@ def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
     calls = [m.group(1) for m in map(_INSTR.match, text.splitlines())
              if m and 'custom_call_target="tpu_custom_call"' in m.group(4)]
     names = sorted(re.sub(r"\.\d+$", "", n) for n in calls)
-    assert names == (["causal_attention"] + ["causal_attention_grad"] * 2
+    assert names == (["flash_dkv", "flash_dq", "flash_fwd"]
                      + ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
                      + ["grouped_matmul_tn"] * 3)
     assert ragged_dots(text) == []
+    # the flash kernels' names (PR 32) come after the op's scope, which is
+    # what the attention readers of chipbench find them by
+    flash_ops = {re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
+                 for ln in text.splitlines() if re.match(r"\s*%flash_", ln)}
+    assert flash_ops == {
+        "attn/causal_attention/flash_fwd/pallas_call",
+        "attn/causal_attention_grad/flash_dkv/pallas_call",
+        "attn/causal_attention_grad/flash_dq/pallas_call"}
     # what chipbench/scopes.py will make of the kernels' events: their
     # names come AFTER the component JAX wraps in jvp(...) and the reader
     # drops, so they are in the scope key in both directions
@@ -477,7 +485,7 @@ def test_xing_step_runs_both_kernel_families_over_its_share(
         + [r[2].name for r in built["routing"]])
     text = compiled.as_text()
     assert _custom_calls(text) == (
-        ["causal_attention"] * 6 + ["causal_attention_grad"] * 12
+        ["flash_dkv"] * 6 + ["flash_dq"] * 6 + ["flash_fwd"] * 6
         + ["grouped_matmul"] * 15 + ["grouped_matmul_nt"] * 15
         + ["grouped_matmul_tn"] * 15)
     assert ragged_dots(text) == []
@@ -508,7 +516,93 @@ def test_olmoe_kernel_counts_are_unchanged(one_chip, no_compile_cache,
     _, compiled = _olmoe_step(one_chip, monkeypatch, rows=1)
     text = compiled.as_text()
     assert _custom_calls(text) == (
-        ["causal_attention"] + ["causal_attention_grad"] * 2
+        ["flash_dkv", "flash_dq", "flash_fwd"]
         + ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
         + ["grouped_matmul_tn"] * 3)
     assert ragged_dots(text) == []
+
+
+@pytest.mark.parametrize("window,heads,blocks", [
+    (512, (8, 1), (256, 256)), (512, (8, 1), (512, 512)),
+    (512, (8, 1), (128, 128)), (None, (6, 1), (1024, 1024))],
+    ids=["band_256", "band_512", "band_128", "group_of_6"])
+def test_flash_band_and_head_groups_compile_for_v5e(
+        one_chip, no_compile_cache, monkeypatch, window, heads, blocks):
+    """Mosaic takes the three kernels at the `laguna_xs_2` cell's shapes:
+    a band of 512 over a row of 8192 at each block size swept, 8 query
+    heads on one key/value head read in place, and the triangle with 6 on
+    one: a forward, a dK/dV and a dQ custom call, no loop, and no copy of
+    K or V the size of the query heads'."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import flash
+
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    q_shape, k_shape = (1, heads[0], 8192, 128), (1, heads[1], 8192, 128)
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def both(q, k, v, do):
+        o, lse = flash.flash_attention_fwd(
+            q, k, v, causal=True, window=window, block_q=blocks[0],
+            block_k=blocks[1])
+        return o, flash.flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True, window=window,
+            block_q=blocks[0], block_k=blocks[1])
+
+    compiled = jax.jit(both).lower(sds(q_shape), sds(k_shape), sds(k_shape),
+                                   sds(q_shape)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert not any(m.group(3) == "while"
+                   for m in map(_INSTR.match, text.splitlines()) if m)
+    # q, o, do, dq and their float32 companions; never K or V repeated
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 3 * heads[0] * 8192 * 128 * 4
+
+
+def test_laguna_step_runs_window_and_full_kernels_over_its_share(
+        one_chip, no_compile_cache, monkeypatch):
+    """The `laguna_xs_2` step at 1 x 8192 tokens (5 layers) compiles for
+    one v5e chip with the flash kernels at both head counts (6 and 8 query
+    heads on the one key/value head held: a forward, dK/dV and dQ a
+    layer) and the grouped kernels over the 32 held groups of width 512
+    (nine a sparse layer); no XLA `ragged-dot`, no [S, S] scores, no K or
+    V repeated for the query heads, and it fits the chip: the compiler
+    itself refuses a step past 15.75 GiB."""
+    cfg, compiled = _lm_step(
+        one_chip, monkeypatch, "laguna_xs_2", 1,
+        lambda built: [built["routing"][0][1].name]
+        + [r[2].name for r in built["routing"]])
+    text = compiled.as_text()
+    assert _custom_calls(text) == (
+        ["flash_dkv"] * 5 + ["flash_dq"] * 5 + ["flash_fwd"] * 5
+        + ["grouped_matmul"] * 12 + ["grouped_matmul_nt"] * 12
+        + ["grouped_matmul_tn"] * 12)
+    assert ragged_dots(text) == []
+    shapes = {tuple(int(d) for d in dims.split(",") if d)
+              for _, dims in _ARRAY.findall(text)}
+    S = cfg["sequence_length"]
+    # [S, S] here is also [tokens, the dense MLP's width]: no such array
+    # under either attention scope
+    assert [ln for ln in text.splitlines()
+            if "%d,%d]" % (S, S) in ln and "/attn_" in ln] == []
+    flash_ops = {re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
+                 for ln in text.splitlines() if re.match(r"\s*%flash_", ln)}
+    assert flash_ops == {
+        scope + "/" + kernel + "/pallas_call"
+        for scope in ("attn_full", "attn_window") for kernel in (
+            "causal_attention/flash_fwd", "causal_attention_grad/flash_dkv",
+            "causal_attention_grad/flash_dq")}
+    assert (6, S, 128) in shapes and (8, S, 128) in shapes
+    assert (1, S, 128) in shapes
+    assert (32, 2048, 512) in shapes and (32, 512, 2048) in shapes
+    k = cfg["num_experts_per_tok"]
+    assert (S * k, 512) in shapes and (S * k, 2048) in shapes
+    assert [s for s in shapes if len(s) >= 3 and 256 in s[-3:]
+            and s[-1] in (512, 2048) and S in s] == []
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 8.65e9 < held < 16.4e9, held
