@@ -854,9 +854,14 @@ def _moe_ffn(ctx):
     ctx.set_output_dim("TokensPerExpert", (r[1],))
     ctx.set_output_dim("RowsHeld", (1,))
     if g is not None:
+        from ..ops.lm_ops import row_bound
+
+        # gate and up over the rows the layer's bound leaves (all of them
+        # where every expert is held), down over all
         rows = x[0] * k if x[0] >= 0 else -1
-        ctx.set_output_dim("GateOut", (rows, g[2]))
-        ctx.set_output_dim("UpOut", (rows, g[2]))
+        bounded = row_bound(rows, held, r[1]) if rows >= 0 else -1
+        ctx.set_output_dim("GateOut", (bounded, g[2]))
+        ctx.set_output_dim("UpOut", (bounded, g[2]))
         ctx.set_output_dim("DownOut", (rows, x[1]))
 
 

@@ -229,19 +229,52 @@ def causal_attention_grad_op(ctx, ins, attrs):
 
 
 # ----------------------------------------------------------------- moe_ffn
+def _first_rows(a, rows):
+    return a if a.shape[0] <= rows else a[:rows]
+
+
+def _sum_of_choices(table, inv, k, weights=None):
+    """[T, H] float32: the sum over token t's k choices j of (weights[t,
+    j] x) the row of `table` that holds the choice, row inv[t * k + j] of
+    the sorted rows. A table of all T * k rows: one gather and a sum over
+    k (what a layer that holds every expert has always run). A table of
+    the first B sorted rows only (`row_bound`): a choice whose row lies
+    past it adds zero, and the gathers are taken a choice at a time, T
+    rows each: [T * k, H] -> [T, k, H] is a relayout in HBM where k rows
+    do not fill a tile, and XLA does not fuse it into the gather (v5e, ms
+    forward / d x: 0.89 / 0.87 against 0.31 / 0.27 at [4096, 4, 3584]
+    from 4,096 rows, 1.01 / 0.88 against 1.02 / 0.91 at [8192, 8, 2048]
+    from 16,384; a scatter-add of the B rows 1.35 and 1.94;
+    tools/combine_sweep.py, PERF.md PR 33)."""
+    n = inv.shape[0]
+    if table.shape[0] >= n:
+        y = table[inv].reshape(n // k, k, -1)
+        if weights is None:
+            return y.sum(axis=1)
+        return jnp.einsum("tkh,tk->th", y.astype(F32), weights)
+    inv = inv.reshape(n // k, k)
+    total = 0.0
+    for j in range(k):
+        rows = jnp.take(table, inv[:, j], axis=0, mode="fill",
+                        fill_value=0).astype(F32)
+        total = total + (rows if weights is None
+                         else rows * weights[:, j, None])
+    return total
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _dispatch(x, order, inv, k):
-    """Rows of x [T, H] in expert order: slot s holds token order[s] // k."""
+    """Rows of x [T, H] in expert order: slot s holds token order[s] // k.
+    `order` may be the first B of the T * k sorted slots."""
     return x[order // k]
 
 
 def _dispatch_fwd(x, order, inv, k):
-    return x[order // k], (inv, x.shape[0])
+    return x[order // k], inv
 
 
-def _dispatch_bwd(k, res, g):
-    inv, T = res
-    return g[inv].reshape(T, k, -1).sum(axis=1).astype(g.dtype), None, None
+def _dispatch_bwd(k, inv, g):
+    return _sum_of_choices(g, inv, k).astype(g.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -264,6 +297,38 @@ def _unsort_bwd(order, g):
 _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
+@jax.custom_vjp
+def _combine(ys, top_p, order, inv):
+    """Out_t = sum over token t's k choices of top_p[t, j] * (its row of
+    ys), float32 sums, in ys's dtype. ys [B, H] holds the first B sorted
+    rows, zero past the held experts' (a choice whose row lies past B adds
+    nothing); order [B], inv [T * k]."""
+    return _combine_fwd(ys, top_p, order, inv)[0]
+
+
+def _combine_fwd(ys, top_p, order, inv):
+    o = _sum_of_choices(ys, inv, top_p.shape[1], top_p)
+    return o.astype(ys.dtype), (ys, top_p, order, inv)
+
+
+def _combine_bwd(res, g):
+    """By hand, so that nothing of T * k rows is made but the weights'
+    gradient [T * k]: d ys and the weights' gradient come from a B-row
+    gather of d Out."""
+    ys, top_p, order, inv = res
+    g = g[order // top_p.shape[1]].astype(F32)              # [B, H]
+    d_ys = g * top_p.reshape(-1)[order][:, None]
+    d_w = jnp.sum(ys.astype(F32) * g, axis=1)
+    # back in token order: a scatter of the B sorted slots (each written
+    # once; 0.1 ms for 16,384 where a gather of all 65,536 took 0.47)
+    d_w = jnp.zeros(inv.shape, F32).at[order].set(d_w, unique_indices=True)
+    return (d_ys.astype(ys.dtype),
+            d_w.reshape(top_p.shape).astype(top_p.dtype), None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 class Routing:
     """How `moe_ffn` scores, chooses and weighs, and which experts the
     layer holds; static, from the op's attributes. The defaults are
@@ -280,6 +345,30 @@ class Routing:
         self.all_held = self.first == 0 and self.held == n_experts
 
 
+# The even-load share of the rows, times this, bounds the rows a layer
+# that holds a share of its experts moves around its products.
+ROW_BOUND_FACTOR = 2
+
+
+def row_bound(n_rows, held, n_experts):
+    """The static row count B that a `moe_ffn` holding `held` of
+    `n_experts` experts works on, of its n_rows = top_k x tokens sorted
+    choice rows: ROW_BOUND_FACTOR times the share an even load would send
+    to the held experts, rounded up to the grouped kernels' longest row
+    tile (so `grouped.takes` accepts B wherever it accepts the tile),
+    never above n_rows and n_rows itself where every expert is held. From
+    the shapes alone. A step whose held experts received more rows
+    (`RowsHeld` > B) runs the same body over all n_rows: nothing is
+    dropped."""
+    from ..parallel.grouped import ROW_TILES
+
+    if held >= n_experts:
+        return n_rows
+    tile = ROW_TILES[0]
+    even = ROW_BOUND_FACTOR * n_rows * held
+    return min(n_rows, -(-even // (n_experts * tile)) * tile)
+
+
 def moe_ffn(x, router, gate, up, down, top_k):
     """The expert layer on tokens x [T, H], every expert held, OLMoE's
     routing: `moe_ffn_op`'s outputs Out, AuxLoss, ZLoss, ExpertIds,
@@ -288,13 +377,11 @@ def moe_ffn(x, router, gate, up, down, top_k):
                     Routing({"top_k": top_k}, router.shape[1]))[0][:5]
 
 
-def _moe_ffn(x, router, bias, gate, up, down, r, products=None):
-    """The op's six outputs and the three grouped products' results
-    (gate, up, down, rows in expert order). Given those as `products` (the
-    backward op hands over what the forward left) no product is computed
-    again: the kernels then run for the gradients alone."""
-    from ..parallel.grouped import grouped_mlp
-
+def _route(x, router, bias, r):
+    """The router's part of the layer: (the chosen experts' weights top_p
+    [T, k], AuxLoss, ZLoss), which carry gradients, and (ExpertIds,
+    TokensPerExpert, the rows each held expert received, the sort by held
+    expert `order` and its inverse `inv` [T * k]), which do not."""
     T, E, top_k = x.shape[0], router.shape[1], r.top_k
     # router, scores and top-k in float32 at full precision whatever the
     # compute dtype: a bf16 logit moves the discrete choice
@@ -312,7 +399,13 @@ def _moe_ffn(x, router, bias, gate, up, down, r, products=None):
         # not trained through the loss
         _, top_e = lax.top_k(probs + lax.stop_gradient(bias.astype(F32)),
                              top_k)
-        top_p = jnp.take_along_axis(probs, top_e, axis=1)
+        # the chosen scores by comparison, not `take_along_axis`: a gather
+        # of T * k scalars took a v5e 0.67 ms, and 0.76 again in the
+        # backward op, of a Laguna layer's 7.6 outside its kernels
+        # (PERF.md PR 33); one non-zero term a sum, so the same numbers
+        top_p = jnp.sum(jnp.where(
+            top_e[:, :, None] == jnp.arange(E)[None, None, :],
+            probs[:, None, :], 0.0), axis=2)
     if r.norm_topk:
         top_p = top_p / (jnp.sum(top_p, axis=1, keepdims=True) + 1e-20)
     if r.scale != 1.0:
@@ -334,21 +427,77 @@ def _moe_ffn(x, router, bias, gate, up, down, r, products=None):
     held_counts = counts if r.all_held else jnp.sum(
         key[:, None] == jnp.arange(r.held)[None, :], axis=0,
         dtype=jnp.int32)
-    # grouped products over the rows each expert really received: Pallas
-    # kernels on a TPU place, `lax.ragged_dot` elsewhere
-    xs = _dispatch(x, order, inv, top_k)                    # [T*k, H]
-    ys, a, b = grouped_mlp(xs, gate, up, down, held_counts, products,
-                           not r.all_held)
-    y = _unsort(ys, order, inv).reshape(T, top_k, -1)
-    o = jnp.einsum("tkh,tk->th", y.astype(F32), top_p)
     # load balance: E * sum_e (share of routing slots on e) * (mean score
     # of e); the shares are counts and carry no gradient. z-loss: mean
     # lse^2
     share = counts.astype(F32) / (T * top_k)
     aux = E * jnp.sum(share * jnp.mean(probs, axis=0))
-    return ((o.astype(x.dtype), aux.reshape(1),
-             jnp.mean(jnp.square(lse)).reshape(1), top_e.astype(jnp.int32),
-             counts, jnp.sum(held_counts).reshape(1)), (a, b, ys))
+    return ((top_p, aux.reshape(1), jnp.mean(jnp.square(lse)).reshape(1)),
+            (top_e.astype(jnp.int32), counts, held_counts, order, inv))
+
+
+def _experts(x, top_p, gate, up, down, held_counts, order, inv, r, rows,
+             products=None):
+    """Out [T, H] and the three grouped products (gate, up, down) over the
+    first `rows` of the T * k sorted choice rows: the dispatch, the MLP
+    (Pallas kernels on a TPU place, `lax.ragged_dot` elsewhere) and the
+    combine. `rows` is static; every row a held expert received must lie
+    among them (`_held_rows_take`). Given `products` (the backward op
+    hands over what the forward left) no product is computed again: the
+    kernels then run for the gradients alone."""
+    from ..parallel.grouped import grouped_mlp
+
+    T, top_k = top_p.shape
+    order = _first_rows(order, rows)
+    if products is not None:
+        # DownOut keeps all the rows
+        products = (*products[:2], _first_rows(products[2], rows))
+    xs = _dispatch(x, order, inv, top_k)                    # [rows, H]
+    ys, a, b = grouped_mlp(xs, gate, up, down, held_counts, products,
+                           not r.all_held)
+    if r.all_held:
+        y = _unsort(ys, order, inv).reshape(T, top_k, -1)
+        o = jnp.einsum("tkh,tk->th", y.astype(F32), top_p).astype(x.dtype)
+    else:
+        o = _combine(ys, top_p, order, inv)
+    return o, (a, b, ys)
+
+
+def _held_rows_take(r, n_rows, n_experts, held_counts, body):
+    """`body(B)` where the held experts' rows fit the layer's row bound B,
+    `body(n_rows)` in a step where they do not, chosen on the device; with
+    no bound below n_rows (every expert held, or so large a share)
+    `body(n_rows)` alone and no `cond`. `body` gives results of the same
+    shapes for either count."""
+    bound = row_bound(n_rows, r.held, n_experts)
+    if bound >= n_rows:
+        return body(n_rows)
+    return lax.cond(jnp.sum(held_counts) <= bound,
+                    lambda: body(bound), lambda: body(n_rows))
+
+
+def _moe_ffn(x, router, bias, gate, up, down, r):
+    """The op's six outputs and the three grouped products' results
+    (gate, up, down; rows in expert order) as the op leaves them: where
+    the layer has a row bound B below its T * k rows (`row_bound`), gate
+    and up of B rows and down of all T * k, zero past the held experts'
+    rows."""
+    (top_p, aux, z), (top_e, counts, held_counts, order, inv) = _route(
+        x, router, bias, r)
+    n_rows, E = order.shape[0], router.shape[1]
+    bound = row_bound(n_rows, r.held, E)
+
+    def body(rows):
+        o, (a, b, ys) = _experts(x, top_p, gate, up, down, held_counts,
+                                 order, inv, r, rows)
+        if rows < n_rows:
+            ys = jnp.concatenate(
+                [ys, jnp.zeros((n_rows - rows, ys.shape[1]), ys.dtype)])
+        return o, (_first_rows(a, bound), _first_rows(b, bound), ys)
+
+    o, products = _held_rows_take(r, n_rows, E, held_counts, body)
+    return ((o, aux, z, top_e, counts, jnp.sum(held_counts).reshape(1)),
+            products)
 
 
 _MOE_INPUTS = ("X", "Router", "Bias", "Gate", "Up", "Down")
@@ -372,11 +521,28 @@ def moe_ffn_op(ctx, ins, attrs):
     * top_k rows the held experts received. Tokens are sorted by expert,
     the held ones first, and the three products are grouped over the rows
     routed to them (`parallel/grouped.py: grouped_mlp`): no capacity, no
-    dropped token, no padding to a per-expert size. GateOut, UpOut [T *
-    top_k, F] and DownOut [T * top_k, H] are those products as computed,
-    kept for the backward op; where the layer holds a share of its experts
-    DownOut is zero past the rows they received (RowsHeld), GateOut and
-    UpOut hold there whatever their buffers held on a TPU place."""
+    dropped token, no padding to a per-expert size.
+
+    A layer that holds a share of its experts moves a BOUNDED number of
+    rows around its products: B = `row_bound`(T * top_k, E', E), twice
+    the rows an even load would send it, from the shapes alone. The
+    dispatch gathers the first B sorted rows, the products and their
+    epilogues run over the RowsHeld <= B of them, and the combine reads a
+    table of B rows (a choice whose row lies past B adds nothing: it is
+    no held expert's). In a step where RowsHeld > B (`lax.cond`, from the
+    routing just computed) the same body runs over all T * top_k rows:
+    nothing is dropped, and every output is what the full-size path
+    gives for every RowsHeld in [0, T * top_k]. A layer that holds every
+    expert has no bound and no `cond`.
+
+    GateOut, UpOut and DownOut are the products as computed, kept for
+    the backward op. Every expert held: [T * top_k, F], [T * top_k, H].
+    A share held: DownOut [T * top_k, H] is zero past the rows the held
+    experts received (its rows that are not all zero are RowsHeld, in
+    either branch); GateOut and UpOut are [B, F] and hold past those rows
+    whatever their buffers held on a TPU place, and after an overflow
+    step only their first B rows, which the backward op does not read:
+    it computes the products again over all the rows."""
     args = [first(ins, s) for s in _MOE_INPUTS]
     (o, aux, z, ids, counts, rows), products = _moe_ffn(
         *args, Routing(attrs, args[1].shape[1]))
@@ -410,25 +576,38 @@ def _moe_ffn_grad_maker(op, gout, gin):
 @register_op("moe_ffn_grad")
 def moe_ffn_grad_op(ctx, ins, attrs):
     """The vjp of `moe_ffn`'s body at the forward's saved products (an op
-    built without them computes them here)."""
-    primals = [first(ins, s) for s in _MOE_TRAINED]
-    bias = first(ins, "Bias")
-    routing = Routing(attrs, primals[1].shape[1])
+    built without them computes them here): the router's part once, the
+    experts' part over the rows the forward op took, chosen as it chose
+    (the bounded rows from the saved products; after an overflow all the
+    rows, the products computed again)."""
+    x, router, gate, up, down = (first(ins, s) for s in _MOE_TRAINED)
+    r = Routing(attrs, router.shape[1])
     products = tuple(first(ins, s) for s in _MOE_PRODUCTS)
     if any(p is None for p in products):
         products = None
+    (top_p, aux, z), route_vjp, (_, _, held_counts, order, inv) = jax.vjp(
+        lambda x, router: _route(x, router, first(ins, "Bias"), r),
+        x, router, has_aux=True)
+    d_o, d_aux, d_z = (
+        jnp.zeros_like(o) if g is None else g.astype(o.dtype).reshape(o.shape)
+        for o, g in zip((x, aux, z), (first(ins, s + "@GRAD")
+                                      for s in ("Out", "AuxLoss", "ZLoss"))))
 
-    def fn(x, router, *weights):
-        return _moe_ffn(x, router, bias, *weights, routing, products)[0][:3]
+    def gradients(rows):
+        # the saved gate and up products are of the rows the forward op
+        # took: after an overflow they are computed again
+        saved = products if products and products[0].shape[0] == rows \
+            else None
+        return jax.vjp(
+            lambda *a: _experts(*a, held_counts, order, inv, r, rows,
+                                saved)[0],
+            x, top_p, gate, up, down)[1](d_o)
 
-    outs, vjp = jax.vjp(fn, *primals)
-    cots = []
-    for o, slot in zip(outs, ("Out", "AuxLoss", "ZLoss")):
-        g = first(ins, slot + "@GRAD")
-        cots.append(jnp.zeros_like(o) if g is None
-                    else g.astype(o.dtype).reshape(o.shape))
-    return out(**{s + "@GRAD": g for s, g in zip(_MOE_TRAINED,
-                                                  vjp(tuple(cots)))})
+    d_x, d_top_p, *d_weights = _held_rows_take(
+        r, order.shape[0], router.shape[1], held_counts, gradients)
+    d_x_routed, d_router = route_vjp((d_top_p, d_aux, d_z))
+    return out(**{s + "@GRAD": g for s, g in zip(
+        _MOE_TRAINED, (d_x + d_x_routed, d_router, *d_weights))})
 
 
 # ------------------------------------------------------- mhc_mix, mhc_update
@@ -666,6 +845,18 @@ def _holds_a_share(op, block):
         < block.vars[op.input("Router")[0]].shape[1])
 
 
+def _has_row_bound(op, block):
+    """A `moe_ffn` that holds a share of its experts and works on fewer
+    rows than top_k x tokens (`row_bound`; rows the program leaves open
+    are taken to be many)."""
+    if not _holds_a_share(op, block):
+        return False
+    tokens = block.vars[op.input("X")[0]].shape[0]
+    rows = tokens * int(op.attrs.get("top_k", 1)) if tokens > 0 else 2 ** 30
+    return row_bound(rows, op.attrs["held_experts"], block.vars[
+        op.input("Router")[0]].shape[1]) < rows
+
+
 def _has_window(op, block):
     return bool(op.attrs.get("window", 0))
 
@@ -718,7 +909,8 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("causal_attention", "flash_attention_window", True,
              _has_window),
             ("causal_attention", "flash_attention_head_groups", True,
-             _has_head_groups))
+             _has_head_groups),
+            ("moe_ffn", "moe_ffn_row_bound", False, _has_row_bound))
 
 
 def lowered_counts(program, device):
@@ -728,7 +920,9 @@ def lowered_counts(program, device):
     `grouped_matmul_kernel` too, and as `grouped_mlp_epilogues` where
     `grouped_mlp` runs them with SiLU * up, its backward and the sum of
     the two d xs products in their epilogues; those that hold a share of
-    their experts as `moe_ffn_held_experts`) and, on a TPU place, its
+    their experts as `moe_ffn_held_experts`, and as `moe_ffn_row_bound`
+    where that share gives them a row bound below top_k x tokens:
+    `row_bound`) and, on a TPU place, its
     `causal_attention` ops (each lowers through the flash kernel; those
     with a window count as `flash_attention_window` too, those whose K has
     fewer heads than their Q as `flash_attention_head_groups`) and

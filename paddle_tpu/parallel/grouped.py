@@ -235,6 +235,21 @@ def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, *refs, tm, tn,
 
 def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs, epilogue=None,
          sides=()):
+    return _gmm_call(lhs, rhs, meta, n_visits, sides, tiles, transpose_rhs,
+                     epilogue, pallas_interpret())
+
+
+# The kernel calls stand under an inlined `jit`: a call is traced once for
+# each (shapes, tiles, epilogue) and its equations are bound at every call
+# site as if traced there (the same names in the device trace). A step
+# makes the same call once a sparse layer and once a branch of each
+# layer's `cond` (lm_ops: `moe_ffn`), and tracing a Pallas kernel's body is
+# the expensive part of lowering a step on the chip's host: a Laguna step
+# of 84 grouped kernels traced for 17.3 s against the 9.5 s of the 36
+# before the `cond`, and for 9.6 s with this (PERF.md, PR 33).
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8), inline=True)
+def _gmm_call(lhs, rhs, meta, n_visits, sides, tiles, transpose_rhs,
+              epilogue, interpret):
     """[N, K] x [E, K, M] -> [N, M] (`transpose_rhs`: rhs is [E, M, K],
     contracted over its last dimension). `epilogue` and its `sides` [N, M],
     read by row tiles as the out is written: "silu_mul" (a) -> (the
@@ -288,7 +303,7 @@ def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs, epilogue=None,
                 + K * M * (E if tiles_k == 1 else max_visits))),
         # operands are counted with the three scalar-prefetch lists
         input_output_aliases={5: 0} if epilogue == "add" else {},
-        interpret=pallas_interpret(),
+        interpret=interpret,
         name=KERNELS[1] if transpose_rhs else KERNELS[0],
     )(*meta, lhs, rhs, *sides)
     return outs[0] if n_outs == 1 else outs
@@ -333,6 +348,14 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs):
 
 
 def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None):
+    lhs = lhs if isinstance(lhs, tuple) else (lhs,)
+    return _tgmm_call(lhs, rhs, meta, n_visits, tiles, jnp.dtype(out_dtype),
+                      mask_lhs, pallas_interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7), inline=True)
+def _tgmm_call(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs,
+               interpret):
     """[N, K], [N, M] -> [E, K, M]: group g's rows of lhs, transposed,
     times its rows of rhs. `lhs` a pair (a, b): lhs is silu(a) * b, formed
     from the two tiles in VMEM. `mask_lhs`: which operand has the rows of
@@ -340,7 +363,6 @@ def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None):
     unless said (rows past the groups must be FINITE in the other one: a
     zero row times a NaN is a NaN)."""
     tm, tk, tn = tiles
-    lhs = lhs if isinstance(lhs, tuple) else (lhs,)
     (N, K), M = lhs[0].shape, rhs.shape[1]
     E = meta[0].shape[0] - 1
     tiles_k, tiles_n = K // tk, M // tn
@@ -376,7 +398,7 @@ def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None):
             bytes_accessed=item * (
                 N * K * tiles_n * len(lhs) + N * M * tiles_k)
             + E * K * M * jnp.dtype(out_dtype).itemsize),
-        interpret=pallas_interpret(),
+        interpret=interpret,
         name=KERNELS[2],
     )(*meta, *lhs, rhs)
 

@@ -28,6 +28,19 @@ LAYOUTS = {
 }
 
 
+def _kernel_calls(fn, *args):
+    """How many Pallas kernels a run of `fn(*args)` calls: the
+    `pallas_call` equations of its jaxpr, those of a jaxpr that several
+    call sites share (the kernels are traced once a signature, under
+    `jax.jit`) counted at each site."""
+    def count(jaxpr):
+        return sum(1 if eqn.primitive.name == "pallas_call" else sum(
+            count(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
 @functools.lru_cache(maxsize=None)
 def _both(layout, dtype, block_rows):
     """(kernels, ragged_dot): each (out, d lhs, d rhs) as float32 numpy.
@@ -109,10 +122,8 @@ def test_a_given_product_is_not_computed_again():
         return jax.vjp(lambda x, y: grouped.grouped_matmul(
             x, y, counts, out, tiles), a, b)[1](jnp.ones((N, M)))
 
-    text = str(jax.make_jaxpr(grads)(lhs, rhs, saved))
-    assert text.count("pallas_call") == 2
-    assert str(jax.make_jaxpr(lambda a, b: grads(a, b, None))(
-        lhs, rhs)).count("pallas_call") == 3
+    assert _kernel_calls(grads, lhs, rhs, saved) == 2
+    assert _kernel_calls(lambda a, b: grads(a, b, None), lhs, rhs) == 3
     for ours, ref in zip(grads(lhs, rhs, saved), grads(lhs, rhs, None)):
         np.testing.assert_array_equal(ours, ref)
 
@@ -131,10 +142,9 @@ def test_a_given_mlp_is_not_computed_again(monkeypatch):
         return vjp(g)
 
     ys, a, b = grouped.grouped_mlp(xs, gate, up, down, counts)
-    text = str(jax.make_jaxpr(grads)((a, b, ys), xs, gate, up, down))
-    assert text.count("pallas_call") == 6
-    assert str(jax.make_jaxpr(lambda *w: grads(None, *w))(
-        xs, gate, up, down)).count("pallas_call") == 9
+    assert _kernel_calls(grads, (a, b, ys), xs, gate, up, down) == 6
+    assert _kernel_calls(lambda *w: grads(None, *w),
+                         xs, gate, up, down) == 9
     for got in grouped.grouped_mlp(xs, gate, up, down, counts, (a, b, ys)):
         assert got is a or got is b or got is ys
     for ours, ref in zip(grads((a, b, ys), xs, gate, up, down),
@@ -236,9 +246,10 @@ def test_rows_no_tile_divides_fall_back_to_ragged_dot(monkeypatch):
     args = _moe_operands(100, 128, 256, 8, "float32")
     text = str(jax.make_jaxpr(lambda *a: lm_ops.moe_ffn(*a, 2))(*args))
     assert "pallas_call" not in text and "ragged_dot" in text
-    taken = str(jax.make_jaxpr(lambda *a: lm_ops.moe_ffn(*a, 2))(
-        *_moe_operands(64, 128, 256, 8, "float32")))
-    assert taken.count("pallas_call") == 3 and "ragged_dot" not in taken
+    taken = _moe_operands(64, 128, 256, 8, "float32")
+    assert _kernel_calls(lambda *a: lm_ops.moe_ffn(*a, 2), *taken) == 3
+    assert "ragged_dot" not in str(jax.make_jaxpr(
+        lambda *a: lm_ops.moe_ffn(*a, 2))(*taken))
 
 
 def _moe_program(tokens, hidden, width, experts=8, top_k=2):
@@ -404,6 +415,197 @@ def test_moe_ffn_holding_a_share_equals_the_dense_sum(monkeypatch, first,
     np.testing.assert_array_equal(np.sort(ids, 1), np.sort(top, 1))
     assert int(counts.sum()) == T * k
     assert int(rows[0]) == int(counts[sl].sum())
+
+
+# ------------------------------------------------------------ row bound
+@pytest.mark.parametrize("n_rows,held,n_experts,want", [
+    (65536, 32, 256, 16384),    # the Laguna cell: top-8 of 8192 tokens
+    (16384, 8, 64, 4096),       # the Xing cell: top-4 of 4096 tokens
+    (65536, 64, 64, 65536),     # OLMoE: every expert held, no bound
+    (128, 2, 8, 128),           # a tile is longer than the rows
+    (4096, 3, 8, 3072),         # twice three eighths
+    (4096, 5, 8, 4096),         # twice the share passes the rows
+    (5000, 1, 16, 1024),        # 625 rows: up to two tiles
+])
+def test_row_bound_is_a_function_of_the_shapes(n_rows, held, n_experts,
+                                               want):
+    """Twice the even-load share, rounded up to the kernels' longest row
+    tile, never above the rows, the rows themselves where all are held."""
+    got = lm_ops.row_bound(n_rows, held, n_experts)
+    assert got == want
+    assert got <= n_rows
+    assert got == n_rows or got % grouped.ROW_TILES[0] == 0
+    assert got >= min(n_rows, 2 * n_rows * held // n_experts)
+    if got < n_rows:
+        assert grouped.takes(got, 128, 128)
+
+
+BOUND_T, BOUND_K, BOUND_E = 64, 2, 8            # 128 choice rows
+
+
+def _routed_so_that(held_rows, first, held, score_func, seed):
+    """Operands of a share-holding `moe_ffn` whose router sends exactly
+    `held_rows` of the 128 choices to the held experts: the logits are
+    chosen and the router solved from them (64 tokens of width 128)."""
+    T, k, E, H, F = BOUND_T, BOUND_K, BOUND_E, 128, 128
+    x, _, gate, up, down = _moe_operands(T, H, F, E, "float32", seed=seed)
+    rs = np.random.default_rng(seed)
+    inside = list(range(first, first + held))
+    outside = [e for e in range(E) if e not in inside]
+    logits = rs.normal(-4.0, 0.3, (T, E))
+    for slot in range(T * k):
+        t, j = divmod(slot, k)
+        pool = inside if slot < held_rows else outside
+        logits[t, pool[(t + j) % len(pool)]] = 4.0 + 0.5 * j
+    router = np.linalg.lstsq(np.asarray(x, np.float64), logits,
+                             rcond=None)[0]
+    bias = jnp.asarray(rs.normal(0, 0.05, E), jnp.float32)
+    sl = slice(first, first + held)
+    attrs = dict(top_k=k, score_func=score_func, first_expert=first,
+                 held_experts=held)
+    if score_func == "sigmoid":
+        attrs.update(norm_topk=True, routed_scale=2.0)
+    ins = {"X": [x], "Router": [jnp.asarray(router, jnp.float32)],
+           "Bias": [bias], "Gate": [gate[sl]], "Up": [up[sl]],
+           "Down": [down[sl]]}
+    return ins, attrs
+
+
+def _step_of(ins, attrs, seed):
+    """Forward op, then the backward op on its saved products."""
+    fwd = lm_ops.moe_ffn_op(None, ins, attrs)
+    rs = np.random.default_rng(seed + 100)
+    cots = {"Out@GRAD": [jnp.asarray(rs.standard_normal(
+        fwd["Out"][0].shape), jnp.float32)],
+        "AuxLoss@GRAD": [jnp.full((1,), 0.7)],
+        "ZLoss@GRAD": [jnp.full((1,), 0.3)]}
+    bwd = lm_ops.moe_ffn_grad_op(None, dict(
+        ins, **{s: fwd[s] for s in lm_ops._MOE_PRODUCTS}, **cots), attrs)
+    return fwd, bwd, cots
+
+
+def _dense_loss(x, router, gate, up, down, bias, attrs, cots):
+    """sum(Out * d Out) + 0.7 AuxLoss + 0.3 ZLoss with Out as the dense
+    sum over the held experts: what the op's gradients are gradients of."""
+    T, k, E = BOUND_T, BOUND_K, BOUND_E
+    first, held = attrs["first_expert"], attrs["held_experts"]
+    logits = jnp.dot(x, router, precision=lax.Precision.HIGHEST)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    scores = (jax.nn.sigmoid(logits) if attrs["score_func"] == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    _, top = lax.top_k(scores + bias, k)
+    w = jnp.take_along_axis(scores, top, 1)
+    if attrs.get("norm_topk"):
+        w = w / (w.sum(1, keepdims=True) + 1e-20) * attrs["routed_scale"]
+    o = jnp.zeros_like(x)
+    for e in range(held):
+        y = jnp.dot(jax.nn.silu(jnp.dot(x, gate[e], precision="highest"))
+                    * jnp.dot(x, up[e], precision="highest"), down[e],
+                    precision="highest")
+        o += y * jnp.sum(jnp.where(top == first + e, w, 0.0), 1,
+                         keepdims=True)
+    share = jnp.sum(top.reshape(-1)[:, None] == jnp.arange(E)[None, :],
+                    axis=0) / (T * k)
+    aux = E * jnp.sum(lax.stop_gradient(share) * jnp.mean(scores, axis=0))
+    return (jnp.sum(o * cots["Out@GRAD"][0]) + 0.7 * aux
+            + 0.3 * jnp.mean(jnp.square(lse)))
+
+
+# (rows the held experts receive, named against the bound B of the case)
+BOUND_ROWS = {"none": lambda B: 0, "under": lambda B: B - 23,
+              "at": lambda B: B, "one_over": lambda B: B + 1,
+              "every_row": lambda B: BOUND_T * BOUND_K}
+
+
+# through `lax.ragged_dot` every case; through the kernels, interpreted,
+# one case of each row count
+BOUND_CASES = [(first, held, score_func, rows_case, False)
+               for first, held in [(0, 2), (2, 2), (5, 3)]
+               for score_func in ("sigmoid", "softmax")
+               for rows_case in sorted(BOUND_ROWS)] + [
+    (2, 2, "sigmoid", rows_case, True) for rows_case in sorted(BOUND_ROWS)]
+
+
+@pytest.mark.parametrize(
+    "first,held,score_func,rows_case,kernels", BOUND_CASES,
+    ids=["-".join([f"{c[0]}+{c[1]}", c[2], c[3],
+                   "kernels" if c[4] else "ragged_dot"])
+         for c in BOUND_CASES])
+def test_a_row_bound_gives_the_full_size_path(monkeypatch, first, held,
+                                              score_func, rows_case,
+                                              kernels):
+    """`moe_ffn` + `moe_ffn_grad` of a layer that holds a share of its 8
+    experts, with a row bound B below its 128 choice rows (row tile 32: B
+    = 64 for 2 held, 96 for 3), against the same ops with no bound, for
+    RowsHeld = 0, under B, B, B + 1 (the overflow branch) and all 128 (a
+    router that sends every choice to held experts): every output, the
+    five gradients, and `DownOut`'s non-zero rows = RowsHeld in each. The
+    full-size path's own gradients are held to the dense sum's."""
+    monkeypatch.setattr(grouped, "ROW_TILES", (32,))
+    monkeypatch.setattr(grouped, "on_tpu", lambda: kernels)
+    N = BOUND_T * BOUND_K
+    B = lm_ops.row_bound(N, held, BOUND_E)
+    assert B == {2: 64, 3: 96}[held]
+    R = BOUND_ROWS[rows_case](B)
+    seed = 7 + first
+    ins, attrs = _routed_so_that(R, first, held, score_func, seed)
+    fwd, bwd, cots = _step_of(ins, attrs, seed)
+    assert int(fwd["RowsHeld"][0][0]) == R
+    assert fwd["GateOut"][0].shape == fwd["UpOut"][0].shape == (B, 128)
+    down_out = np.asarray(fwd["DownOut"][0])
+    assert down_out.shape == (N, 128)
+    assert int(np.any(down_out != 0, axis=1).sum()) == R
+    assert not down_out[R:].any()
+
+    monkeypatch.setattr(lm_ops, "row_bound", lambda n, *_: n)
+    full, full_bwd, _ = _step_of(ins, attrs, seed)
+    assert full["GateOut"][0].shape == (N, 128)
+    np.testing.assert_array_equal(full["DownOut"][0], down_out)
+    for slot in ("ExpertIds", "TokensPerExpert", "RowsHeld", "AuxLoss",
+                 "ZLoss"):
+        # the same sums in the same order: bit for bit
+        np.testing.assert_array_equal(fwd[slot][0], full[slot][0])
+    # Out and the gradients: the k choices of a token are summed a choice
+    # at a time from a bounded table, in one reduction from the full one
+    got, want = np.asarray(fwd["Out"][0]), np.asarray(full["Out"][0])
+    assert np.max(np.abs(got - want)) <= 1e-6 * np.max(np.abs(want))
+    for slot in lm_ops._MOE_TRAINED:
+        got, want = (np.asarray(g[slot + "@GRAD"][0])
+                     for g in (bwd, full_bwd))
+        assert np.max(np.abs(got - want)) <= 1e-6 * np.max(np.abs(want))
+
+    args = [ins[s][0] for s in lm_ops._MOE_TRAINED]
+    dense = jax.grad(_dense_loss, argnums=(0, 1, 2, 3, 4))(
+        *args, ins["Bias"][0], attrs, cots)
+    for slot, want in zip(lm_ops._MOE_TRAINED, dense):
+        got = np.asarray(full_bwd[slot + "@GRAD"][0])
+        assert np.max(np.abs(got - want)) <= 2e-4 * max(
+            np.max(np.abs(want)), 1e-6), slot
+
+
+def test_a_layer_that_holds_every_expert_has_no_bound_and_no_cond():
+    """OLMoE's layer: no `cond` in the forward or the backward op; a
+    share-holding layer with a bound below its rows has one in each."""
+    args = _moe_operands(64, 128, 128, 8, "float32")
+    ins = {s: [a] for s, a in zip(lm_ops._MOE_TRAINED, args)}
+
+    def both(ins, attrs):
+        fwd = jax.make_jaxpr(lambda: lm_ops.moe_ffn_op(None, ins, attrs))()
+        bwd = jax.make_jaxpr(lambda: lm_ops.moe_ffn_grad_op(None, dict(
+            ins, **{"Out@GRAD": [jnp.ones((64, 128))]}), attrs))()
+        return str(fwd), str(bwd)
+
+    for text in both(ins, {"top_k": 2}):
+        assert " cond[" not in text
+    share = dict(ins, Gate=[args[2][:1]], Up=[args[3][:1]],
+                 Down=[args[4][:1]])
+    attrs = {"top_k": 2, "first_expert": 3, "held_experts": 1}
+    for text in both(share, attrs):     # 128 rows: one tile of 512 covers
+        assert " cond[" not in text
+    import unittest.mock
+    with unittest.mock.patch.object(grouped, "ROW_TILES", (32,)):
+        for text in both(share, attrs):
+            assert text.count(" cond[") == 1
 
 
 # ------------------------------------------------------------ grouped_mlp

@@ -271,7 +271,9 @@ def test_lowered_counts_name_windows_and_head_groups(small, place):
 
     prog = small["builder"].build(fluid, small["cfg"], 5)["prog"]
     got = lm_ops.lowered_counts(prog, types.SimpleNamespace(platform=place))
-    want = {"moe_ffn_grouped": 3, "moe_ffn_held_experts": 3}
+    # the program leaves the tokens open: the rows are taken to be many
+    want = {"moe_ffn_grouped": 3, "moe_ffn_held_experts": 3,
+            "moe_ffn_row_bound": 3}
     if place == "tpu":
         want.update(flash_attention=4, flash_attention_bwd=4,
                     flash_attention_window=2, flash_attention_head_groups=4)
@@ -333,27 +335,54 @@ def _program_part(build, weights, feed):
         exe.run(startup)
         for p in prog.global_block().all_parameters():
             scope.set_var(p.name, np.asarray(weights[p.name]))
-        got, = exe.run(prog, feed={"u": feed}, fetch_list=[out])
-    return np.asarray(got)
+        got = exe.run(prog, feed={"u": feed}, fetch_list=list(
+            out if isinstance(out, (list, tuple)) else [out]))
+    return np.asarray(got[0]) if len(got) == 1 else [
+        np.asarray(g) for g in got]
 
 
-@pytest.fixture(scope="module")
-def expert_parts():
+def _held_rows_and_all(y, routing):
+    return [y, routing[2]]
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["as_drawn", "a_router_that_overflows_the_row_bound"])
+def expert_parts(request):
+    """The second time every token chooses expert 0 (its bias raised by
+    10), and the expert layer's row bound is 64 of the 128 choice rows (a
+    row tile of 16 instead of 512, which covers any bound at this size):
+    the first chip receives more than 64 and takes the overflow branch,
+    the other three stay within the bound."""
+    from unittest import mock
     from paddle_tpu.models import laguna
+    from paddle_tpu.ops import lm_ops
+    from paddle_tpu.parallel import grouped
 
     cfg, ref, w, u = _uncut()
+    tiles = grouped.ROW_TILES
+    if request.param:
+        tiles = (16,)
+        w = dict(w, **{"laguna.l1.router_bias":
+                       w["laguna.l1.router_bias"].at[0].add(10.0)})
     flat = u.reshape(T, 64)
     with jax.default_matmul_precision("highest"):
         part_all, shared, _ = ref.experts(flat, w, "laguna.l1.", cfg)
-    parts = []
-    for chip in range(4):
-        c, ws = ref.share_of(cfg, w, chip, 4)
-        got = _program_part(
-            lambda x, c=c: laguna.experts(x, c, "laguna.l1.")[0], ws,
-            np.asarray(flat))
-        with jax.default_matmul_precision("highest"):
-            want, _, _ = ref.experts(flat, ws, "laguna.l1.", c)
-        parts.append((got, np.asarray(want)))
+    parts, rows = [], []
+    with mock.patch.object(grouped, "ROW_TILES", tiles):
+        bound = lm_ops.row_bound(2 * T, 4, 16)
+        for chip in range(4):
+            c, ws = ref.share_of(cfg, w, chip, 4)
+            got, held = _program_part(
+                lambda x, c=c: _held_rows_and_all(
+                    *laguna.experts(x, c, "laguna.l1.")), ws,
+                np.asarray(flat))
+            with jax.default_matmul_precision("highest"):
+                want, _, _ = ref.experts(flat, ws, "laguna.l1.", c)
+            parts.append((got, np.asarray(want)))
+            rows.append(int(held[0]))
+    assert sum(rows) == 2 * T
+    assert bound == (64 if request.param else 2 * T)
+    assert (rows[0] > 64) == request.param and 0 < max(rows[1:]) <= 64
     return np.asarray(part_all), np.asarray(shared), parts
 
 

@@ -470,15 +470,86 @@ def _custom_calls(text):
     return sorted(re.sub(r"\.\d+$", "", n) for n in calls)
 
 
+def _cond_branches(text, scope):
+    """[(instruction lines of the overflow branch, of the bounded branch)]
+    of the `lax.cond`s whose branches hold instructions of the Fluid op
+    `scope` (branch_0 is the false one: the overflow)."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = _COMPUTATION.match(ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(ln)
+    found = []
+    for lines in comps.values():
+        for ln in lines:
+            if " conditional(" not in ln:
+                continue
+            names = re.search(r"branch_computations=\{([^}]*)\}", ln)
+            names = ([n.strip().lstrip("%") for n in names.group(1).split(",")]
+                     if names else re.search(
+                         r"true_computation=%(\S+?), false_computation=%(\S+?)"
+                         r"[,\s]", ln).groups()[::-1])
+            branches = [comps[n] for n in names]
+            if any("/%s/cond/branch_" % scope in b for br in branches
+                   for b in br):
+                found.append(tuple(branches))
+    return found
+
+
+def _arrays_of_rows(lines, rows, width):
+    """Names of the instructions in `lines` that WRITE an array of `rows`
+    rows of `width` (fusions, gathers, selects, pads, kernels; not the
+    parameters and tuple elements that only hand one on)."""
+    made = []
+    for ln in lines:
+        m = _INSTR.match(ln)
+        if m and m.group(3) not in ("parameter", "get-tuple-element",
+                                    "bitcast", "tuple") and re.search(
+                r"\[%d,%d(,\d+)?\]|\[\d+,%d,%d\]" % (rows, width, rows // 8,
+                                                       width), m.group(2)):
+            made.append(m.group(1))
+    return made
+
+
+def _bounded_branches_move_the_bound_s_rows(text, rows, bound, width,
+                                            layers):
+    """Each sparse layer has a `cond` in `moe_ffn` and one in
+    `moe_ffn_grad`. The bounded branch of the forward writes all `rows`
+    rows of `width` once (`DownOut`'s zero tail behind the down
+    product), that of the backward never: the dispatch, the zeroing and d
+    ys are over `bound` rows and both combines gather [tokens, width] a
+    choice; the overflow branches move all `rows`."""
+    for scope, most in (("moe_ffn", 1), ("moe_ffn_grad", 0)):
+        conds = _cond_branches(text, scope)
+        assert len(conds) == layers, (scope, len(conds))
+        for overflow, bounded in conds:
+            full = _arrays_of_rows(bounded, rows, width)
+            assert len(full) <= most, (scope, full)
+            assert all("concatenate" in ln for ln in bounded
+                       if any("%" + n + " = " in ln for n in full)), full
+            assert len(_arrays_of_rows(bounded, bound, width)) >= 3
+            assert len(_arrays_of_rows(overflow, rows, width)) >= 4
+
+
 def test_xing_step_runs_both_kernel_families_over_its_share(
         one_chip, no_compile_cache, monkeypatch):
     """The `xing4_0_29b_a4b` step at 1 x 4096 tokens (6 blocks) compiles for one v5e chip with the flash kernels at
     queries and keys of 192 and values of 128 over the 4 held heads (a
     forward, dK/dV and dQ a block) and the grouped kernels over the 8
-    held groups (nine an expert block), at K = 3584 and K = 1024; no XLA `ragged-dot`, no [S, S] scores, no
+    held groups, at K = 3584 and K = 1024: nine an expert block in the
+    branch that works on the row bound's 4,096 of the 16,384 choice rows
+    and twelve in the overflow branch over all of them (the backward op
+    computes the three forward products again there), of which a step
+    runs one; no XLA `ragged-dot`, no [S, S] scores, no
     [T, 64, .] tensor (every token through every expert of the router's
-    width), and it fits the chip: the compiler itself refuses a step
-    past 15.75 GiB."""
+    width); inside the bounded branches nothing writes an array of all
+    16,384 rows of 3584 but the zero tail of `DownOut` (both combines
+    gather [4096, 3584] a choice); and it fits the chip: the compiler
+    itself refuses a step past 15.75 GiB."""
     cfg, compiled = _lm_step(
         one_chip, monkeypatch, "xing4_0_29b_a4b", 1,
         lambda built: [built["routing"][0][1].name]
@@ -486,9 +557,11 @@ def test_xing_step_runs_both_kernel_families_over_its_share(
     text = compiled.as_text()
     assert _custom_calls(text) == (
         ["flash_dkv"] * 6 + ["flash_dq"] * 6 + ["flash_fwd"] * 6
-        + ["grouped_matmul"] * 15 + ["grouped_matmul_nt"] * 15
-        + ["grouped_matmul_tn"] * 15)
+        + ["grouped_matmul"] * (15 + 30) + ["grouped_matmul_nt"] * 30
+        + ["grouped_matmul_tn"] * 30)
     assert ragged_dots(text) == []
+    k, T = cfg["num_experts_per_tok"], cfg["sequence_length"]
+    _bounded_branches_move_the_bound_s_rows(text, T * k, 4096, 3584, 5)
     arrays = {(dt, tuple(int(d) for d in dims.split(",") if d))
               for dt, dims in _ARRAY.findall(text)}
     shapes = {s for _, s in arrays}
@@ -498,8 +571,8 @@ def test_xing_step_runs_both_kernel_families_over_its_share(
     # stacked matrices in bf16, in one orientation each
     assert (4, S, 192) in shapes and (4, S, 128) in shapes
     assert (8, 3584, 1024) in shapes and (8, 1024, 3584) in shapes
-    k = cfg["num_experts_per_tok"]
     assert (T * k, 1024) in shapes and (T * k, 3584) in shapes
+    assert (4096, 1024) in shapes and (4096, 3584) in shapes
     assert [s for s in shapes if len(s) >= 3 and 64 in s[-3:]
             and s[-1] in (1024, 3584) and T in s] == []
     mem = compiled.memory_analysis()
@@ -567,10 +640,15 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
     """The `laguna_xs_2` step at 1 x 8192 tokens (5 layers) compiles for
     one v5e chip with the flash kernels at both head counts (6 and 8 query
     heads on the one key/value head held: a forward, dK/dV and dQ a
-    layer) and the grouped kernels over the 32 held groups of width 512
-    (nine a sparse layer); no XLA `ragged-dot`, no [S, S] scores, no K or
-    V repeated for the query heads, and it fits the chip: the compiler
-    itself refuses a step past 15.75 GiB."""
+    layer) and the grouped kernels over the 32 held groups of width 512:
+    nine a sparse layer in the branch that works on the row bound's
+    16,384 of the 65,536 choice rows and twelve in the overflow branch
+    over all of them (the backward op computes the three forward products
+    again there), of which a step runs one; no XLA `ragged-dot`, no [S,
+    S] scores, no K or V repeated for the query heads; inside the bounded
+    branches nothing writes an array of all 65,536 rows of 2048 but the
+    zero tail of `DownOut`; and it fits the chip: the compiler itself
+    refuses a step past 15.75 GiB."""
     cfg, compiled = _lm_step(
         one_chip, monkeypatch, "laguna_xs_2", 1,
         lambda built: [built["routing"][0][1].name]
@@ -578,12 +656,13 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
     text = compiled.as_text()
     assert _custom_calls(text) == (
         ["flash_dkv"] * 5 + ["flash_dq"] * 5 + ["flash_fwd"] * 5
-        + ["grouped_matmul"] * 12 + ["grouped_matmul_nt"] * 12
-        + ["grouped_matmul_tn"] * 12)
+        + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
+        + ["grouped_matmul_tn"] * 24)
     assert ragged_dots(text) == []
+    S, k = cfg["sequence_length"], cfg["num_experts_per_tok"]
+    _bounded_branches_move_the_bound_s_rows(text, S * k, 16384, 2048, 4)
     shapes = {tuple(int(d) for d in dims.split(",") if d)
               for _, dims in _ARRAY.findall(text)}
-    S = cfg["sequence_length"]
     # [S, S] here is also [tokens, the dense MLP's width]: no such array
     # under either attention scope
     assert [ln for ln in text.splitlines()
@@ -598,8 +677,8 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
     assert (6, S, 128) in shapes and (8, S, 128) in shapes
     assert (1, S, 128) in shapes
     assert (32, 2048, 512) in shapes and (32, 512, 2048) in shapes
-    k = cfg["num_experts_per_tok"]
     assert (S * k, 512) in shapes and (S * k, 2048) in shapes
+    assert (16384, 512) in shapes and (16384, 2048) in shapes
     assert [s for s in shapes if len(s) >= 3 and 256 in s[-3:]
             and s[-1] in (512, 2048) and S in s] == []
     mem = compiled.memory_analysis()

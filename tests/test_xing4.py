@@ -265,7 +265,9 @@ def test_lowered_counts_name_the_share_and_the_kernels_alone(small, place):
 
     prog = small["builder"].build(fluid, small["cfg"], 5)["prog"]
     got = lm_ops.lowered_counts(prog, types.SimpleNamespace(platform=place))
-    want = {"moe_ffn_grouped": 3, "moe_ffn_held_experts": 3}
+    # the program leaves the tokens open: the rows are taken to be many
+    want = {"moe_ffn_grouped": 3, "moe_ffn_held_experts": 3,
+            "moe_ffn_row_bound": 3}
     if place == "tpu":
         want.update(flash_attention=4, flash_attention_bwd=4)
     assert got == want
@@ -300,31 +302,57 @@ def _program_part(build, weights, feed):
         exe.run(startup)
         for p in prog.global_block().all_parameters():
             scope.set_var(p.name, np.asarray(weights[p.name]))
-        got, = exe.run(prog, feed={"u": feed}, fetch_list=[out])
-    return np.asarray(got)
+        got = exe.run(prog, feed={"u": feed}, fetch_list=list(
+            out if isinstance(out, (list, tuple)) else [out]))
+    return np.asarray(got[0]) if len(got) == 1 else [
+        np.asarray(g) for g in got]
 
 
 EXPERT_SHARES = [(0, 2), (2, 2), (4, 2), (6, 2)]
 HEAD_SHARES = [(0, 2), (2, 2)]
 
 
-@pytest.fixture(scope="module")
-def expert_parts():
+def _held_rows_and_all(y, routing):
+    return [y, routing[2]]
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["as_drawn", "a_router_that_overflows_the_row_bound"])
+def expert_parts(request):
+    """The second time every token chooses expert 0 (its bias raised by
+    10), and the expert layer's row bound is 64 of the 128 choice rows (a
+    row tile of 16 instead of 512, which covers any bound at this size):
+    the first share receives more than 64 and takes the overflow branch,
+    the other three stay within the bound."""
+    from unittest import mock
     from paddle_tpu.models import xing4
+    from paddle_tpu.ops import lm_ops
+    from paddle_tpu.parallel import grouped
 
     cfg, ref, w, u = _uncut()
+    tiles = grouped.ROW_TILES
+    if request.param:
+        tiles = (16,)
+        w = dict(w, **{"xing.l1.router_bias":
+                       w["xing.l1.router_bias"].at[0].add(10.0)})
     flat = u.reshape(64, 64)
     with jax.default_matmul_precision("highest"):
         part_all, shared, _ = ref.experts(flat, w, "xing.l1.", cfg)
-    parts = []
-    for first, n in EXPERT_SHARES:
-        c, ws = ref.share_of(cfg, w, first, n, 0, 4)
-        got = _program_part(
-            lambda x, c=c: xing4.experts(x, c, "xing.l1.")[0], ws,
-            np.asarray(flat))
-        with jax.default_matmul_precision("highest"):
-            want, _, _ = ref.experts(flat, ws, "xing.l1.", c)
-        parts.append((got, np.asarray(want)))
+    parts, rows = [], []
+    with mock.patch.object(grouped, "ROW_TILES", tiles):
+        bound = lm_ops.row_bound(128, 2, 8)
+        for first, n in EXPERT_SHARES:
+            c, ws = ref.share_of(cfg, w, first, n, 0, 4)
+            got, held = _program_part(
+                lambda x, c=c: _held_rows_and_all(
+                    *xing4.experts(x, c, "xing.l1.")), ws, np.asarray(flat))
+            with jax.default_matmul_precision("highest"):
+                want, _, _ = ref.experts(flat, ws, "xing.l1.", c)
+            parts.append((got, np.asarray(want)))
+            rows.append(int(held[0]))
+    assert sum(rows) == 128
+    assert bound == (64 if request.param else 128)
+    assert (rows[0] > 64) == request.param and 0 < max(rows[1:]) <= 64
     return np.asarray(part_all), np.asarray(shared), parts
 
 
