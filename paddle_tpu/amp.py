@@ -91,6 +91,13 @@ BLACK_LIST = frozenset({
     "exp", "log", "sqrt", "reciprocal", "pow", "softplus",
 })
 
+# The casts the policy inserts are lowered under this named scope inside
+# the op's own (`moe/moe_ffn/cast`): where a cast is a pass of its own, as
+# the float32 -> bf16 casts of an expert layer's weights are, a device
+# trace names it (a cast XLA fuses into its consumer carries the
+# consumer's name).
+CAST_SCOPE = "cast"
+
 _state = {
     "enabled": False,
     "dtype": "bfloat16",
@@ -163,6 +170,7 @@ def _base_type(op_type):
 def _cast_value(v, target, only_from=None):
     """Cast a float array (or SeqTensor data) to `target`; ints/bools and
     None pass through. `only_from` restricts which source dtypes convert."""
+    import jax
     import jax.numpy as jnp
     from .core.registry import SeqTensor
 
@@ -185,7 +193,8 @@ def _cast_value(v, target, only_from=None):
         return v
     if name == target:
         return v
-    return jnp.asarray(v).astype(target)
+    with jax.named_scope(CAST_SCOPE):
+        return jnp.asarray(v).astype(target)
 
 
 def apply_policy(op_type, ins):

@@ -19,7 +19,9 @@ from .core.framework import (
     Parameter,
     Variable,
     OpRole,
+    OP_NAMESCOPE_ATTR_NAME,
     OP_ROLE_ATTR_NAME,
+    OWNER_NAMESCOPE_ATTR_NAME,
     OP_ROLE_VAR_ATTR_NAME,
     grad_var_name,
 )
@@ -162,10 +164,14 @@ def _append_backward_ops(block, target_names, no_grad, grad_map, checkpoint_segm
                 attrs = dict(d.get("attrs") or {})
                 attrs[OP_ROLE_ATTR_NAME] = OpRole.Backward
                 block.append_op(d["type"], d.get("inputs"), d.get("outputs"), attrs)
+            # the sum that adds this op's contribution to a gradient names
+            # the op's scopes (a device trace reads `sum(<scopes>)`)
+            scope = op.attrs.get(OP_NAMESCOPE_ATTR_NAME)
+            scoped = {OWNER_NAMESCOPE_ATTR_NAME: scope} if scope else {}
             for canonical, parts in pending_sums:
                 block.append_op(
                     "sum", {"X": parts}, {"Out": [canonical]},
-                    {OP_ROLE_ATTR_NAME: OpRole.Backward},
+                    {OP_ROLE_ATTR_NAME: OpRole.Backward, **scoped},
                 )
 
         # role-var bookkeeping for param grads (transpiler/PE rely on this)

@@ -39,10 +39,6 @@ Commands:
                      bucket, then either expose the stdlib HTTP frontend
                      (POST /v1/infer, GET /healthz /stats /metrics) or
                      fire N synthetic requests and print stats JSON.
-  trace ops --model-dir DIR
-                     compile the model once with tracing + HLO cost
-                     analysis on and print the slowest-ops table (HLO
-                     cost attributed back to ProgramDesc ops).
   trace summary DIR  summarize a flight-recorder dump directory (span
                      counts per name, traces, slowest spans).
   trace dump [--out DIR] [--selftest]
@@ -1335,47 +1331,6 @@ def _cmd_trace(args):
 
     from . import trace
 
-    if args.trace_action == "ops":
-        import numpy as np
-
-        from . import flags
-        from .core.places import CPUPlace, TPUPlace
-        from .core.scope import Scope, scope_guard
-        from .executor import Executor
-        from .io import load_inference_model
-
-        flags.set("monitor", True)
-        flags.set("monitor_hlo_cost", True)
-        flags.set("trace", True)
-        place = CPUPlace() if args.place == "cpu" else TPUPlace(0)
-        exe = Executor(place)
-        scope = Scope()
-        try:
-            with scope_guard(scope):
-                program, feed_names, fetch_targets = load_inference_model(
-                    args.model_dir, exe)
-        except (OSError, ValueError) as e:
-            print(f"cannot load inference model: {e}", file=sys.stderr)
-            return 1
-        feed = {}
-        for name in feed_names:
-            var = program.global_block().var(name)
-            shape = [args.batch if d is None or d < 0 else d
-                     for d in var.shape]
-            feed[name] = np.zeros(shape, dtype=var.dtype)
-        with scope_guard(scope):
-            exe.run(program, feed=feed, fetch_list=fetch_targets)
-        report = trace.slowest_ops(batch_size=args.batch, top=args.top)
-        if report is None:
-            print("no compile recorded — nothing to attribute",
-                  file=sys.stderr)
-            return 1
-        if args.json:
-            print(json.dumps(report, indent=2))
-        else:
-            print(trace.format_ops_table(report))
-        return 0
-
     if args.trace_action == "summary":
         try:
             loaded = trace.load_dump(args.dir)
@@ -1688,20 +1643,8 @@ def main(argv=None):
                         "(FLAGS_compile_cache_dir): warmup loads "
                         "executables compiled by earlier processes")
 
-    tr = sub.add_parser("trace", help="flight-recorder dumps and per-op "
-                                      "cost attribution")
+    tr = sub.add_parser("trace", help="flight-recorder dumps")
     trsub = tr.add_subparsers(dest="trace_action", required=True)
-    tro = trsub.add_parser("ops", help="compile a saved model once and "
-                                       "print the slowest-ops table")
-    tro.add_argument("--model-dir", required=True,
-                     help="save_inference_model directory")
-    tro.add_argument("--place", default="cpu", choices=["tpu", "cpu"])
-    tro.add_argument("--batch", type=int, default=1,
-                     help="batch size substituted for dynamic dims")
-    tro.add_argument("--top", type=int, default=10,
-                     help="rows in the table")
-    tro.add_argument("--json", action="store_true",
-                     help="emit the report as JSON")
     trs = trsub.add_parser("summary", help="summarize a flight-recorder "
                                            "dump directory")
     trs.add_argument("dir", help="dump directory (holds manifest.json)")

@@ -22,7 +22,7 @@ import numpy as np
 from . import registry
 from .registry import SeqTensor
 from . import dtypes
-from .framework import OP_NAMESCOPE_ATTR_NAME
+from .framework import OP_NAMESCOPE_ATTR_NAME, OWNER_NAMESCOPE_ATTR_NAME
 from .. import amp, flags
 from ..ops import bn_pool, lm_ops
 
@@ -103,7 +103,7 @@ def env_get(env, name, allow_missing=False):
 def run_ops(ops, env, ctx):
     # offered every op of a traced block, says whether it lowered the op
     pairs = None if ctx.eager else bn_pool.Lowering(
-        ops, ctx, _run_one_op, _bind_outputs)
+        ops, ctx, _run_one_op, _bind_outputs, _device_scope)
     for op in ops:
         if pairs is None or not pairs.offer(op, env, ctx):
             _run_one_op(op, env, ctx)
@@ -111,15 +111,25 @@ def run_ops(ops, env, ctx):
 
 
 def _device_scope(op, ctx):
-    """An op appended under `fluid.name_scope` / `framework.op_scope` is
-    lowered under jax.named_scope(`<scopes>/<op type>`), so the operations
-    of a device trace carry the Fluid op they came from, forward and
-    backward (`<type>_grad`). Named scopes are metadata: the lowered
-    StableHLO is the same text."""
-    scopes = op.attrs.get(OP_NAMESCOPE_ATTR_NAME)
-    if ctx.eager or not scopes:
+    """Every op of a traced step is lowered under jax.named_scope(`<scopes>/
+    <op type>`), so each operation of a device trace carries the Fluid op
+    it came from, forward and backward (`<type>_grad`): `<scopes>` are the
+    `fluid.name_scope`s / `framework.op_scope`s the op was appended under,
+    and an op appended under none lowers under its type alone. An op that
+    names an owner's scopes (an optimizer's update its parameter's, a
+    gradient `sum` those of the op it sums for) carries them inside ONE
+    path component, their `/` written `.`: `optimizer/momentum(stage1.
+    block0.conv1)`, so a reader that matches whole components finds
+    `optimizer` and finds neither `stage1` nor `block0`. Named scopes are
+    metadata: the lowered StableHLO is the same
+    text, and so is the persistent cache's key."""
+    if ctx.eager:
         return contextlib.nullcontext()
-    return jax.named_scope(f"{scopes}/{op.type}")
+    scopes = op.attrs.get(OP_NAMESCOPE_ATTR_NAME)
+    name = f"{scopes}/{op.type}" if scopes else op.type
+    owner = op.attrs.get(OWNER_NAMESCOPE_ATTR_NAME)
+    return jax.named_scope(
+        f"{name}({owner.replace('/', '.')})" if owner else name)
 
 
 def lowered_counts(program, device):

@@ -53,6 +53,12 @@ OP_ROLE_VAR_ATTR_NAME = "op_role_var"
 # appended under; a traced step emits the op's lowering under
 # jax.named_scope of it, so device traces carry it (executor_core)
 OP_NAMESCOPE_ATTR_NAME = "op_namescope"
+# the scopes of what an op works for, where those are not its own: on an
+# optimizer's update op the scopes its parameter was made in, on the `sum`
+# backward.py appends the scopes of the op whose gradient it adds up. A
+# traced step writes them inside the op's own path component,
+# `optimizer/momentum(stage1.block0.conv1)`, `sum(mhc)`
+OWNER_NAMESCOPE_ATTR_NAME = "owner_namescope"
 _name_scopes = []
 
 
@@ -184,6 +190,8 @@ class Parameter(Variable):
         self.regularizer = kwargs.pop("regularizer", None)
         self.gradient_clip_attr = kwargs.pop("gradient_clip_attr", None)
         self.do_model_average = kwargs.pop("do_model_average", None)
+        # the `name_scope`s / `op_scope`s the layer that made it was built in
+        self.name_scope = "/".join(_name_scopes)
         super().__init__(block, shape=shape, dtype=dtype, **kwargs)
 
 

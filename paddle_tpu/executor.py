@@ -27,7 +27,6 @@ from . import health as _health
 from .resilience import chaos as _chaos
 from .resilience import watchdog as _watchdog
 from . import trace as _trace
-from .trace import costs as _trace_costs
 
 __all__ = ["Executor", "FetchFuture", "global_scope", "scope_guard",
            "fetch_var"]
@@ -183,15 +182,13 @@ def stack_multi_step_feeds(program, feed, iters, wire=None):
     return vals
 
 
-def lap_call(mon, was_miss, build_s, fp, program):
+def lap_call(mon, was_miss, build_s, fp):
     """Close the call of the compiled step (both executors): `dispatch`
     (enqueue time under async dispatch) on a hit or an L2 load, `compile`
     on a miss, whose first call holds the XLA compile."""
     call_s = mon.lap("compile" if was_miss else "dispatch")
-    if was_miss:
-        if mon.monitored:
-            monitor.record_compile(fp, wall_s=build_s + call_s)
-        _trace_costs.register_program(fp, program)
+    if was_miss and mon.monitored:
+        monitor.record_compile(fp, wall_s=build_s + call_s)
 
 
 class FetchFuture:
@@ -539,7 +536,7 @@ class Executor:
             hstats = fetches[-1]
             fetches = fetches[:-1]
         if mon is not None:
-            lap_call(mon, was_miss, build_s, fp, program)
+            lap_call(mon, was_miss, build_s, fp)
         # write back BEFORE any nan check can raise: mut_state was donated,
         # so skipping this would leave the scope holding deleted buffers
         for n, v in new_mut.items():
@@ -696,7 +693,7 @@ class Executor:
             hstats = fetches[-1]
             fetches = fetches[:-1]
         if mon is not None:
-            lap_call(mon, was_miss, build_s, fp, program)
+            lap_call(mon, was_miss, build_s, fp)
         for n, v in new_mut.items():
             scope.set_var(n, v)
         if hstats is not None:

@@ -21,6 +21,7 @@ under `attn/`, `moe/`, `lm_head/` or `embed/` in a device trace.
 """
 
 import paddle_tpu as fluid
+from paddle_tpu.core.framework import op_scope
 
 # the paper's coefficients for the load-balancing and router z losses
 AUX_LOSS_COEF = 0.01
@@ -107,11 +108,13 @@ def olmoe_loss(out, labels, aux_coef=AUX_LOSS_COEF, z_coef=Z_LOSS_COEF):
             out["logits"], fluid.layers.reshape(labels, [-1, 1])))
     # `sum`, not elementwise_add: that one is on AMP's white list and would
     # round the float32 loss to bf16
-    terms = [fluid.layers.reshape(ce, [1])]
-    for balance, z in out["aux"]:
-        terms += [fluid.layers.scale(balance, scale=aux_coef),
-                  fluid.layers.scale(z, scale=z_coef)]
-    return fluid.layers.sums(terms), ce
+    # (`op_scope`: the ops' device-trace scope alone, no name changes)
+    with op_scope("loss"):
+        terms = [fluid.layers.reshape(ce, [1])]
+        for balance, z in out["aux"]:
+            terms += [fluid.layers.scale(balance, scale=aux_coef),
+                      fluid.layers.scale(z, scale=z_coef)]
+        return fluid.layers.sums(terms), ce
 
 
 def decays(name):

@@ -17,6 +17,12 @@ framework's own API.
 import numpy as np
 
 import paddle_tpu as fluid
+from paddle_tpu.core.framework import op_scope
+
+# Device-trace scopes (`op_scope`: the ops' `op_namescope` alone, no name
+# gets a prefix): `stem`, `stage<s>/block<b>/<role>` with a whole
+# conv_bn_layer (conv2d + batch_norm + act) under one role, the residual
+# add under the block, `head`. docs/observability.md.
 
 
 def conv_bn_layer(input, ch_out, filter_size, stride, padding, act="relu",
@@ -37,26 +43,37 @@ def shortcut(input, ch_out, stride, layout="NCHW"):
 
 
 def basicblock(input, ch_out, stride, layout="NCHW"):
-    short = shortcut(input, ch_out, stride, layout)
-    conv1 = conv_bn_layer(input, ch_out, 3, stride, 1, layout=layout)
-    conv2 = conv_bn_layer(conv1, ch_out, 3, 1, 1, act=None, layout=layout)
+    with op_scope("shortcut"):
+        short = shortcut(input, ch_out, stride, layout)
+    with op_scope("conv1"):
+        conv1 = conv_bn_layer(input, ch_out, 3, stride, 1, layout=layout)
+    with op_scope("conv2"):
+        conv2 = conv_bn_layer(conv1, ch_out, 3, 1, 1, act=None,
+                              layout=layout)
     return fluid.layers.elementwise_add(x=short, y=conv2, act="relu")
 
 
 def bottleneck(input, ch_out, stride, layout="NCHW"):
-    short = shortcut(input, ch_out * 4, stride, layout)
-    conv1 = conv_bn_layer(input, ch_out, 1, stride, 0, layout=layout)
-    conv2 = conv_bn_layer(conv1, ch_out, 3, stride=1, padding=1,
-                          layout=layout)
-    conv3 = conv_bn_layer(conv2, ch_out * 4, 1, 1, 0, act=None,
-                          layout=layout)
+    with op_scope("shortcut"):
+        short = shortcut(input, ch_out * 4, stride, layout)
+    with op_scope("conv1"):
+        conv1 = conv_bn_layer(input, ch_out, 1, stride, 0, layout=layout)
+    with op_scope("conv2"):
+        conv2 = conv_bn_layer(conv1, ch_out, 3, stride=1, padding=1,
+                              layout=layout)
+    with op_scope("conv3"):
+        conv3 = conv_bn_layer(conv2, ch_out * 4, 1, 1, 0, act=None,
+                              layout=layout)
     return fluid.layers.elementwise_add(x=short, y=conv3, act="relu")
 
 
-def layer_warp(block_func, input, ch_out, count, stride, layout="NCHW"):
-    res_out = block_func(input, ch_out, stride, layout)
-    for _ in range(1, count):
-        res_out = block_func(res_out, ch_out, 1, layout)
+def layer_warp(block_func, input, ch_out, count, stride, layout="NCHW",
+               stage=1):
+    res_out = input
+    for b in range(count):
+        with op_scope(f"stage{stage}/block{b}"):
+            res_out = block_func(res_out, ch_out, stride if b == 0 else 1,
+                                 layout)
     return res_out
 
 
@@ -72,33 +89,37 @@ def resnet_imagenet(input, class_dim, depth=50, layout="NCHW"):
         152: ([3, 8, 36, 3], bottleneck),
     }
     stages, block_func = cfg[depth]
-    conv1 = conv_bn_layer(input, ch_out=64, filter_size=7, stride=2,
-                          padding=3, layout=layout)
-    pool1 = fluid.layers.pool2d(
-        input=conv1, pool_type="avg", pool_size=3, pool_stride=2,
-        data_format=layout)
-    res1 = layer_warp(block_func, pool1, 64, stages[0], 1, layout)
-    res2 = layer_warp(block_func, res1, 128, stages[1], 2, layout)
-    res3 = layer_warp(block_func, res2, 256, stages[2], 2, layout)
-    res4 = layer_warp(block_func, res3, 512, stages[3], 2, layout)
-    pool2 = fluid.layers.pool2d(
-        input=res4, pool_size=7, pool_type="avg", pool_stride=1,
-        global_pooling=True, data_format=layout)
-    out = fluid.layers.fc(input=pool2, size=class_dim, act="softmax")
+    with op_scope("stem"):
+        conv1 = conv_bn_layer(input, ch_out=64, filter_size=7, stride=2,
+                              padding=3, layout=layout)
+        pool1 = fluid.layers.pool2d(
+            input=conv1, pool_type="avg", pool_size=3, pool_stride=2,
+            data_format=layout)
+    res1 = layer_warp(block_func, pool1, 64, stages[0], 1, layout, 1)
+    res2 = layer_warp(block_func, res1, 128, stages[1], 2, layout, 2)
+    res3 = layer_warp(block_func, res2, 256, stages[2], 2, layout, 3)
+    res4 = layer_warp(block_func, res3, 512, stages[3], 2, layout, 4)
+    with op_scope("head"):
+        pool2 = fluid.layers.pool2d(
+            input=res4, pool_size=7, pool_type="avg", pool_stride=1,
+            global_pooling=True, data_format=layout)
+        out = fluid.layers.fc(input=pool2, size=class_dim, act="softmax")
     return out
 
 
 def resnet_cifar10(input, class_dim, depth=32):
     assert (depth - 2) % 6 == 0
     n = (depth - 2) // 6
-    conv1 = conv_bn_layer(
-        input=input, ch_out=16, filter_size=3, stride=1, padding=1)
-    res1 = layer_warp(basicblock, conv1, 16, n, 1)
-    res2 = layer_warp(basicblock, res1, 32, n, 2)
-    res3 = layer_warp(basicblock, res2, 64, n, 2)
-    pool = fluid.layers.pool2d(
-        input=res3, pool_size=8, pool_type="avg", pool_stride=1)
-    out = fluid.layers.fc(input=pool, size=class_dim, act="softmax")
+    with op_scope("stem"):
+        conv1 = conv_bn_layer(
+            input=input, ch_out=16, filter_size=3, stride=1, padding=1)
+    res1 = layer_warp(basicblock, conv1, 16, n, 1, stage=1)
+    res2 = layer_warp(basicblock, res1, 32, n, 2, stage=2)
+    res3 = layer_warp(basicblock, res2, 64, n, 2, stage=3)
+    with op_scope("head"):
+        pool = fluid.layers.pool2d(
+            input=res3, pool_size=8, pool_type="avg", pool_stride=1)
+        out = fluid.layers.fc(input=pool, size=class_dim, act="softmax")
     return out
 
 
@@ -118,8 +139,9 @@ def get_model(args):
     input = fluid.layers.data(name="data", shape=dshape, dtype="float32")
     label = fluid.layers.data(name="label", shape=[1], dtype="int64")
     predict = model(input, class_dim)
-    cost = fluid.layers.cross_entropy(input=predict, label=label)
-    avg_cost = fluid.layers.mean(cost)
+    with op_scope("head"):
+        cost = fluid.layers.cross_entropy(input=predict, label=label)
+        avg_cost = fluid.layers.mean(cost)
     batch_acc = fluid.layers.accuracy(input=predict, label=label)
 
     inference_program = fluid.default_main_program().clone(for_test=True)

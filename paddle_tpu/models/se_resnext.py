@@ -8,6 +8,10 @@ expressed with grouped conv2d; the SE gate is a global-pool -> fc -> fc
 """
 
 import paddle_tpu as fluid
+from paddle_tpu.core.framework import op_scope
+
+# Device-trace scopes, as models/resnet.py has them: `stem`,
+# `stage<s>/block<b>/<conv0 | conv1 | conv2 | se | shortcut>`, `head`.
 
 
 def conv_bn_layer(input, num_filters, filter_size, stride=1, groups=1,
@@ -38,12 +42,17 @@ def shortcut(input, ch_out, stride):
 
 def bottleneck_block(input, num_filters, stride, cardinality,
                      reduction_ratio):
-    conv0 = conv_bn_layer(input, num_filters, 1, act="relu")
-    conv1 = conv_bn_layer(conv0, num_filters, 3, stride=stride,
-                          groups=cardinality, act="relu")
-    conv2 = conv_bn_layer(conv1, num_filters * 2, 1, act=None)
-    scale = squeeze_excitation(conv2, num_filters * 2, reduction_ratio)
-    short = shortcut(input, num_filters * 2, stride)
+    with op_scope("conv0"):
+        conv0 = conv_bn_layer(input, num_filters, 1, act="relu")
+    with op_scope("conv1"):
+        conv1 = conv_bn_layer(conv0, num_filters, 3, stride=stride,
+                              groups=cardinality, act="relu")
+    with op_scope("conv2"):
+        conv2 = conv_bn_layer(conv1, num_filters * 2, 1, act=None)
+    with op_scope("se"):
+        scale = squeeze_excitation(conv2, num_filters * 2, reduction_ratio)
+    with op_scope("shortcut"):
+        short = shortcut(input, num_filters * 2, stride)
     return fluid.layers.elementwise_add(x=short, y=scale, act="relu")
 
 
@@ -58,20 +67,23 @@ def se_resnext(input, class_dim, depth=50):
     reduction_ratio = 16
     num_filters = [128, 256, 512, 1024]
 
-    conv = conv_bn_layer(input, 64, 7, stride=2, act="relu")
-    conv = fluid.layers.pool2d(
-        input=conv, pool_size=3, pool_stride=2, pool_padding=1,
-        pool_type="max")
+    with op_scope("stem"):
+        conv = conv_bn_layer(input, 64, 7, stride=2, act="relu")
+        conv = fluid.layers.pool2d(
+            input=conv, pool_size=3, pool_stride=2, pool_padding=1,
+            pool_type="max")
     for block in range(len(depth_cfg)):
         for i in range(depth_cfg[block]):
-            conv = bottleneck_block(
-                conv, num_filters[block],
-                2 if i == 0 and block != 0 else 1,
-                cardinality, reduction_ratio)
-    pool = fluid.layers.pool2d(
-        input=conv, pool_type="avg", global_pooling=True)
-    drop = fluid.layers.dropout(x=pool, dropout_prob=0.2)
-    return fluid.layers.fc(input=drop, size=class_dim, act="softmax")
+            with op_scope(f"stage{block + 1}/block{i}"):
+                conv = bottleneck_block(
+                    conv, num_filters[block],
+                    2 if i == 0 and block != 0 else 1,
+                    cardinality, reduction_ratio)
+    with op_scope("head"):
+        pool = fluid.layers.pool2d(
+            input=conv, pool_type="avg", global_pooling=True)
+        drop = fluid.layers.dropout(x=pool, dropout_prob=0.2)
+        return fluid.layers.fc(input=drop, size=class_dim, act="softmax")
 
 
 def get_model(args):
@@ -80,8 +92,9 @@ def get_model(args):
     input = fluid.layers.data(name="data", shape=dshape, dtype="float32")
     label = fluid.layers.data(name="label", shape=[1], dtype="int64")
     predict = se_resnext(input, class_dim)
-    cost = fluid.layers.cross_entropy(input=predict, label=label)
-    avg_cost = fluid.layers.mean(cost)
+    with op_scope("head"):
+        cost = fluid.layers.cross_entropy(input=predict, label=label)
+        avg_cost = fluid.layers.mean(cost)
     batch_acc = fluid.layers.accuracy(input=predict, label=label)
 
     inference_program = fluid.default_main_program().clone(for_test=True)

@@ -51,6 +51,7 @@ predicting t_{i+2}; loss = CE + lambda CE_mtp.
 import math
 
 import paddle_tpu as fluid
+from paddle_tpu.core.framework import op_scope
 from paddle_tpu.layer_helper import LayerHelper
 
 INIT_STD = 0.02
@@ -259,10 +260,12 @@ def xing4_loss(out, labels, mtp_coef=MTP_LOSS_COEF):
         kept, _ = L.split(L.reshape(per_token, [-1, S]), [S - 1, 1], dim=1)
         ce_mtp = L.mean(kept)
     # `sums`, not elementwise_add: that one is on AMP's white list and
-    # would round the float32 loss to bf16
-    return (L.sums([L.reshape(ce, [1]),
-                    L.scale(L.reshape(ce_mtp, [1]), scale=mtp_coef)]),
-            ce, ce_mtp)
+    # would round the float32 loss to bf16 (`op_scope`: the ops'
+    # device-trace scope alone, no name changes)
+    with op_scope("loss"):
+        return (L.sums([L.reshape(ce, [1]),
+                        L.scale(L.reshape(ce_mtp, [1]), scale=mtp_coef)]),
+                ce, ce_mtp)
 
 
 def balance_routers(program, speed):

@@ -143,12 +143,17 @@ class Lowering:
     """What executor_core.run_ops offers every op of one traced block.
     `run_op(op, env, ctx, attrs=None) -> outs` runs an op's kernel and
     binds its outputs; `bind(op, outs, env, ctx)` binds outputs computed
-    here."""
+    here; `scope(op, ctx)` is the named scope `run_op` lowers an op under,
+    for what is computed here in its place: the sums and the gate's
+    gradient carry the scope of the `elementwise_mul_grad` that offered
+    them, the pair's dX, dScale and dBias that of the `batch_norm_grad`,
+    and the pooled mean that of the `batch_norm` (it is a slot of that
+    kernel's call; only its cast carries the pool's)."""
 
-    def __init__(self, ops, ctx, run_op, bind):
+    def __init__(self, ops, ctx, run_op, bind, scope):
         # a trace in test mode takes no batch statistics: nothing to ride
         self.pool_of = {} if ctx.is_test else match(ops)
-        self.run_op, self.bind = run_op, bind
+        self.run_op, self.bind, self.scope = run_op, bind, scope
         self.pooled = set()   # id of the pool ops written with their norm
         self.by_key = {}      # _bn_key -> _Pair, for its batch_norm_grad
         self.by_value = {}    # id(Y, or a part of Y's gradient) -> _Pair
@@ -176,8 +181,10 @@ class Lowering:
                      outs["SavedMean"][0], outs["SavedVariance"][0],
                      outs["SampleSum"][0])
         # in the dtype and shape pool2d would have given it
-        pooled = amp.apply_policy(
-            "pool2d", {"X": [outs["PooledY"][0].astype(y.dtype)]})["X"][0]
+        with self.scope(pool, ctx):
+            pooled = amp.apply_policy(
+                "pool2d",
+                {"X": [outs["PooledY"][0].astype(y.dtype)]})["X"][0]
         self.bind(pool, {"Out": [pooled.reshape(pair.nc)]}, env, ctx)
         self.pooled.add(id(pool))
         self.by_key[_bn_key(bn)] = self.by_value[id(y)] = pair
@@ -204,12 +211,15 @@ class Lowering:
             pair.refuse()  # a second multiply of Y, or not a gate
             return True
         pair.gate, pair.d_out, pair.dy_gate = gate, d_out, dy_gate
-        pair.sums = pair.sample_sums(d_out)
         self.by_value[id(dy_gate)] = pair
         generic = (outs.get("Y@GRAD") or [None])[0]
+        with self.scope(op, ctx):
+            pair.sums = pair.sample_sums(d_out)
+            if generic is not None:
+                generic = pair.gate_grad(*pair.sums).astype(
+                    generic.dtype).reshape(generic.shape)
         if generic is not None:
-            self.bind(op, {"Y@GRAD": [pair.gate_grad(*pair.sums).astype(
-                generic.dtype).reshape(generic.shape)]}, env, ctx)
+            self.bind(op, {"Y@GRAD": [generic]}, env, ctx)
         return True
 
     def _pool_grad(self, op, env, ctx):
@@ -250,7 +260,8 @@ class Lowering:
                 and env.get(op.input("Y@GRAD")[0]) is pair.dy \
                 and {s for s, n in op.outputs.items() if any(n)} \
                 <= {"X@GRAD", "Scale@GRAD", "Bias@GRAD"}:
-            dx, d_scale, d_bias = pair.grad()
+            with self.scope(op, ctx):
+                dx, d_scale, d_bias = pair.grad()
             self.bind(op, {"X@GRAD": [dx], "Scale@GRAD": [d_scale],
                            "Bias@GRAD": [d_bias]}, env, ctx)
         else:

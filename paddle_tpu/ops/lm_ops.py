@@ -229,6 +229,24 @@ def causal_attention_grad_op(ctx, ins, attrs):
 
 
 # ----------------------------------------------------------------- moe_ffn
+# The parts of the layer's lowering outside its kernels, each under a
+# `jax.named_scope` of its own inside the op's, forward and backward, so a
+# device trace tells them apart (`moe/moe_ffn/dispatch`, `moe/moe_ffn_grad/
+# combine`; chipbench/layer_metrics/expert_{route,move,cast}_share.py):
+# ROUTE the router's products, scores, `top_k`, the chosen scores and both
+# argsorts; DISPATCH the row gathers into expert order, their backward and
+# the zeroing of `ys` / `d xs` past the groups (`parallel/grouped.py`);
+# COMBINE the gather a choice, the weights, the sums, `DownOut`'s zero
+# tail and the weights' gradient; `cast` is amp's (the float32 -> bf16
+# casts of gate / up / down: `amp.CAST_SCOPE`).
+ROUTE, DISPATCH, COMBINE = "route", "dispatch", "combine"
+# JAX writes the first scope opened inside a function it differentiates as
+# `jvp(<scope>)`, which chipbench/scopes.py drops with all it wraps: the
+# backward op differentiates under this one, for JAX to wrap, and the
+# parts' names come after it (as `grouped._SCOPE`).
+_VJP = "vjp"
+
+
 def _first_rows(a, rows):
     return a if a.shape[0] <= rows else a[:rows]
 
@@ -377,6 +395,7 @@ def moe_ffn(x, router, gate, up, down, top_k):
                     Routing({"top_k": top_k}, router.shape[1]))[0][:5]
 
 
+@jax.named_scope(ROUTE)
 def _route(x, router, bias, r):
     """The router's part of the layer: (the chosen experts' weights top_p
     [T, k], AuxLoss, ZLoss), which carry gradients, and (ExpertIds,
@@ -452,14 +471,17 @@ def _experts(x, top_p, gate, up, down, held_counts, order, inv, r, rows,
     if products is not None:
         # DownOut keeps all the rows
         products = (*products[:2], _first_rows(products[2], rows))
-    xs = _dispatch(x, order, inv, top_k)                    # [rows, H]
+    with jax.named_scope(DISPATCH):
+        xs = _dispatch(x, order, inv, top_k)                # [rows, H]
     ys, a, b = grouped_mlp(xs, gate, up, down, held_counts, products,
                            not r.all_held)
-    if r.all_held:
-        y = _unsort(ys, order, inv).reshape(T, top_k, -1)
-        o = jnp.einsum("tkh,tk->th", y.astype(F32), top_p).astype(x.dtype)
-    else:
-        o = _combine(ys, top_p, order, inv)
+    with jax.named_scope(COMBINE):
+        if r.all_held:
+            y = _unsort(ys, order, inv).reshape(T, top_k, -1)
+            o = jnp.einsum("tkh,tk->th", y.astype(F32),
+                           top_p).astype(x.dtype)
+        else:
+            o = _combine(ys, top_p, order, inv)
     return o, (a, b, ys)
 
 
@@ -490,10 +512,11 @@ def _moe_ffn(x, router, bias, gate, up, down, r):
     def body(rows):
         o, (a, b, ys) = _experts(x, top_p, gate, up, down, held_counts,
                                  order, inv, r, rows)
-        if rows < n_rows:
-            ys = jnp.concatenate(
-                [ys, jnp.zeros((n_rows - rows, ys.shape[1]), ys.dtype)])
-        return o, (_first_rows(a, bound), _first_rows(b, bound), ys)
+        with jax.named_scope(COMBINE):
+            if rows < n_rows:
+                ys = jnp.concatenate(
+                    [ys, jnp.zeros((n_rows - rows, ys.shape[1]), ys.dtype)])
+            return o, (_first_rows(a, bound), _first_rows(b, bound), ys)
 
     o, products = _held_rows_take(r, n_rows, E, held_counts, body)
     return ((o, aux, z, top_e, counts, jnp.sum(held_counts).reshape(1)),
@@ -585,8 +608,10 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     products = tuple(first(ins, s) for s in _MOE_PRODUCTS)
     if any(p is None for p in products):
         products = None
+    differentiated = jax.named_scope(_VJP)
     (top_p, aux, z), route_vjp, (_, _, held_counts, order, inv) = jax.vjp(
-        lambda x, router: _route(x, router, first(ins, "Bias"), r),
+        differentiated(
+            lambda x, router: _route(x, router, first(ins, "Bias"), r)),
         x, router, has_aux=True)
     d_o, d_aux, d_z = (
         jnp.zeros_like(o) if g is None else g.astype(o.dtype).reshape(o.shape)
@@ -599,15 +624,18 @@ def moe_ffn_grad_op(ctx, ins, attrs):
         saved = products if products and products[0].shape[0] == rows \
             else None
         return jax.vjp(
-            lambda *a: _experts(*a, held_counts, order, inv, r, rows,
-                                saved)[0],
+            differentiated(
+                lambda *a: _experts(*a, held_counts, order, inv, r, rows,
+                                    saved)[0]),
             x, top_p, gate, up, down)[1](d_o)
 
     d_x, d_top_p, *d_weights = _held_rows_take(
         r, order.shape[0], router.shape[1], held_counts, gradients)
     d_x_routed, d_router = route_vjp((d_top_p, d_aux, d_z))
+    with jax.named_scope(COMBINE):
+        d_x = d_x + d_x_routed
     return out(**{s + "@GRAD": g for s, g in zip(
-        _MOE_TRAINED, (d_x + d_x_routed, d_router, *d_weights))})
+        _MOE_TRAINED, (d_x, d_router, *d_weights))})
 
 
 # ------------------------------------------------------- mhc_mix, mhc_update

@@ -11,6 +11,7 @@ XLA computation — weight update fusion comes for free.
 import math
 
 from .core.framework import (
+    OWNER_NAMESCOPE_ATTR_NAME,
     Parameter,
     Variable,
     default_main_program,
@@ -150,7 +151,12 @@ class Optimizer:
                 if param_and_grad[1] is None or not param_and_grad[0].trainable:
                     continue
                 with program.optimized_guard(param_and_grad):
-                    optimize_ops.append(self._append_optimize_op(block, param_and_grad))
+                    op = self._append_optimize_op(block, param_and_grad)
+                # ... and the layer whose parameter it updates
+                if param_and_grad[0].name_scope:
+                    op.attrs[OWNER_NAMESCOPE_ATTR_NAME] = \
+                        param_and_grad[0].name_scope
+                optimize_ops.append(op)
             self._finish_update(block)
         return optimize_ops
 

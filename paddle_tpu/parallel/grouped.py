@@ -73,13 +73,18 @@ __all__ = ["grouped_dot", "grouped_matmul", "grouped_mlp", "tiles_for"]
 # The kernels' names: Pallas puts a kernel's name on the name stack, so a
 # device trace's op_name ends `.../grouped_matmul/pallas_call`
 # (chipbench/layer_metrics/grouped_matmul_roofline.py finds them by these).
-# JAX writes the first scope opened inside a custom_vjp's backward as
+# JAX writes the first scope opened inside a function it differentiates as
 # `jvp(<scope>)`, which chipbench/scopes.py drops with all it wraps: the
-# kernels are called under a scope of their own, `_SCOPE`, for JAX to wrap,
-# and their names come after it, forward (`moe/moe_ffn/grouped/
-# grouped_matmul`) and backward (`moe/moe_ffn_grad/grouped_matmul_nt`).
+# kernels are called under a scope of their own, `_SCOPE`, for JAX to wrap
+# where nothing else stands in front, and their names come after it. Under
+# `moe_ffn_grad` the op's own `vjp` scope is the wrapped one (`lm_ops._VJP`),
+# so the keys read `moe/moe_ffn/grouped/grouped_matmul` forward and
+# `moe/moe_ffn_grad/grouped/grouped_matmul_nt` backward.
 KERNELS = ("grouped_matmul", "grouped_matmul_nt", "grouped_matmul_tn")
 _SCOPE = "grouped"
+# the zeroing passes are filed with the row gathers around the kernels
+# (`ops/lm_ops.py: DISPATCH`): what moving a bounded number of rows costs
+ZEROING = "dispatch"
 
 _VMEM_LIMIT = 96 * 2 ** 20       # of the v5e's 128 MiB
 ROW_TILES = (512, 256, 128)      # tried in this order: `tiles_for`
@@ -407,8 +412,9 @@ def _rows_of_groups(x, counts):
     """x with the rows past the groups' last set to zero: the kernels
     never visit them, so what the result holds there is whatever the
     buffer held (`lax.ragged_dot` writes zeros itself)."""
-    rows = lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
-    return jnp.where(rows < jnp.sum(counts), x, jnp.zeros((), x.dtype))
+    with jax.named_scope(ZEROING):
+        rows = lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+        return jnp.where(rows < jnp.sum(counts), x, jnp.zeros((), x.dtype))
 
 
 def _product(lhs, rhs, counts, tiles, rows_past):

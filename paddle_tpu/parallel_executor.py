@@ -694,7 +694,7 @@ class ParallelExecutor:
                     res = monitor.measure_replica_ms(leaf, mon.t_lap)
                     if res is not None:
                         replica_ms, replica_ids = res
-            lap_call(mon, was_miss, build_s, fp, program)
+            lap_call(mon, was_miss, build_s, fp)
         for n, v in new_mut.items():
             scope.set_var(n, v)
         if hstats is not None:

@@ -17,9 +17,7 @@ hang". Three hot paths are instrumented end to end:
             slot_wait, ticket_wait, lock_wait, upstream_wait, stack,
             transfer, next), each recorded where the work happens — a
             decode worker's own stamps ride its ack.
-  compiles  compile phases carry the cache fingerprint; costs.py joins
-            the fingerprint's HLO cost totals back onto ProgramDesc ops
-            for the slowest-ops table (`paddle_tpu trace ops`).
+  compiles  compile phases carry the cache fingerprint.
 
 Spans land in an in-memory flight recorder (recorder.py): per-thread
 fixed-size rings, dumped (spans.jsonl + chrome trace.json +
@@ -32,9 +30,7 @@ instrumentation site, no allocation — same deal as FLAGS_monitor.
 See docs/observability.md.
 """
 
-from .costs import (attribute_costs, format_ops_table, op_costs,
-                    register_program, registered_fingerprints,
-                    slowest_ops)
+from .costs import op_costs
 from .export import CHROME_PID, FORMAT, chrome_events, load_dump, write_dump
 from .recorder import (append, dump, last_dump, maybe_dump, reset,
                        snapshot)
@@ -49,7 +45,6 @@ __all__ = [
     "append", "snapshot", "reset", "dump", "maybe_dump", "last_dump",
     # dump formats
     "FORMAT", "CHROME_PID", "chrome_events", "write_dump", "load_dump",
-    # per-op cost attribution
-    "register_program", "registered_fingerprints", "op_costs",
-    "attribute_costs", "slowest_ops", "format_ops_table",
+    # analytic per-op estimates (the planners' weights)
+    "op_costs",
 ]

@@ -1,29 +1,13 @@
-"""Per-op compile cost attribution: HLO totals mapped back to ProgramDesc.
-
-jax's cost_analysis (monitor.compile_probe) reports ONE aggregate FLOP
-count per compiled step — true but unactionable when the question is
-"which layer do I shard / fuse / shrink". XLA destroys op identity, so
-the mapping back is analytic: estimate each ProgramDesc op's FLOPs from
-its operand/result shapes (the standard 2*M*K*N-style counts the HLO
-total itself is built from), then scale every estimate so they sum to
-the measured HLO total. Shares are exact under the estimator; absolute
-FLOPs inherit the HLO measurement.
-
-Executors register the Program behind each compile-cache fingerprint
-(register_program, weakref — attribution must not extend program
-lifetime), so slowest_ops() can join monitor.compile_info()'s measured
-totals with the op graph after the fact: the `paddle_tpu trace ops`
-table, and the slowest_ops block in flight-recorder manifests.
+"""Analytic per-op FLOP estimates of a ProgramDesc, from operand / result
+shapes (the standard 2*M*K*N-style counts): what `parallel/autoshard`,
+`parallel/pipeline` and `analysis/schedule` weigh ops by before anything
+has run. An estimate, never a measurement: where a step's device time
+goes by op is read from a device trace, whose operations carry the Fluid
+op they came from (docs/observability.md, "The Fluid op in a device
+trace").
 """
 
-import weakref
-
-from .. import monitor
-
-__all__ = ["register_program", "registered_fingerprints", "op_costs",
-           "attribute_costs", "slowest_ops", "format_ops_table"]
-
-_programs = {}  # fingerprint -> weakref.ref(Program)
+__all__ = ["op_costs"]
 
 # ops that move/bookkeep but do no arithmetic worth attributing
 _FREE_OPS = frozenset((
@@ -41,23 +25,6 @@ _ELEM_WEIGHTS = {
     "softmax_with_cross_entropy": 10.0, "sigmoid_cross_entropy_with_logits":
     8.0, "swish": 4.0, "gelu": 8.0, "elu": 3.0, "selu": 3.0,
 }
-
-
-def register_program(fingerprint, program):
-    """Remember (weakly) which Program a compile-cache fingerprint was
-    built from; called by the executors alongside record_compile."""
-    if fingerprint is None or program is None:
-        return
-    try:
-        _programs[str(fingerprint)] = weakref.ref(program)
-    except TypeError:
-        pass
-
-
-def registered_fingerprints():
-    """Fingerprints whose Program is still alive."""
-    return [fp for fp, ref in list(_programs.items())
-            if ref() is not None]
 
 
 def _numel(shape, batch):
@@ -174,79 +141,3 @@ def op_costs(program, batch_size=1):
         rows.append({"index": i, "op": op.type,
                      "out": outs[0] if outs else "", "flops_est": est})
     return rows
-
-
-def attribute_costs(program, total_flops=None, batch_size=1):
-    """Per-op attribution, most expensive first. Each row carries
-    `share` (of the analytic total — exact under the estimator) and
-    `flops` (share scaled onto the measured HLO total when given, else
-    the raw estimate)."""
-    rows = op_costs(program, batch_size=batch_size)
-    est_total = sum(r["flops_est"] for r in rows) or 1.0
-    scale = (float(total_flops) / est_total) if total_flops else 1.0
-    for r in rows:
-        r["share"] = r["flops_est"] / est_total
-        r["flops"] = r["flops_est"] * scale
-    rows.sort(key=lambda r: -r["flops_est"])
-    return rows
-
-
-def slowest_ops(fingerprint=None, batch_size=1, top=10):
-    """The slowest-ops report joining a registered Program with its
-    measured compile info: {"fingerprint", "total_flops", "wall_s",
-    "measured", "ops": [...top rows...]}. Picks the registered
-    fingerprint with the largest measured FLOPs when none is named;
-    None when nothing usable is registered."""
-    info = monitor.compile_info()
-    live = {fp: ref() for fp, ref in _programs.items()
-            if ref() is not None}
-    if not live:
-        return None
-    if fingerprint is None:
-        def measured(fp):
-            return info.get(fp, {}).get("flops") or 0.0
-        fingerprint = max(live, key=measured)
-    fingerprint = str(fingerprint)
-    program = live.get(fingerprint)
-    if program is None:
-        return None
-    ci = info.get(fingerprint, {})
-    total = ci.get("flops")
-    rows = attribute_costs(program, total_flops=total,
-                           batch_size=batch_size)
-    return {
-        "fingerprint": fingerprint,
-        "total_flops": total,
-        "wall_s": ci.get("wall_s"),
-        "measured": total is not None,
-        "ops": [dict(r) for r in rows[:max(1, int(top))]],
-    }
-
-
-def _fmt_flops(v):
-    for unit, div in (("G", 1e9), ("M", 1e6), ("K", 1e3)):
-        if abs(v) >= div:
-            return f"{v / div:.2f}{unit}"
-    return f"{v:.0f}"
-
-
-def format_ops_table(report):
-    """Human-readable slowest-ops table from a slowest_ops() report."""
-    if not report:
-        return "no compiled program registered (run a step first)"
-    src = "HLO cost analysis" if report["measured"] \
-        else "analytic estimate (no HLO total measured)"
-    lines = [f"fingerprint {report['fingerprint']}  "
-             f"total_flops="
-             f"{_fmt_flops(report['total_flops'] or 0.0)}  [{src}]"]
-    if report.get("wall_s") is not None:
-        lines[0] += f"  compile_wall_s={report['wall_s']:.3f}"
-    lines.append(f"{'#':>3} {'op':<28}{'output':<28}"
-                 f"{'flops':>10}{'share':>8}{'cum':>8}")
-    cum = 0.0
-    for i, r in enumerate(report["ops"], 1):
-        cum += r["share"]
-        lines.append(f"{i:>3} {r['op']:<28}{r['out'][:27]:<28}"
-                     f"{_fmt_flops(r['flops']):>10}"
-                     f"{r['share']:>8.1%}{cum:>8.1%}")
-    return "\n".join(lines)

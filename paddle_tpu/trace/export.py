@@ -30,8 +30,6 @@ Manifest schema (format "paddle_tpu.trace/1"):
     traces      distinct trace_ids in the snapshot
     names       {span name: count}
     files       {"spans": "spans.jsonl", "chrome": "trace.json"}
-    slowest_ops per-op compile cost attribution (costs.slowest_ops()
-                report) when a profiled compile was available, else null
 """
 
 import json
@@ -84,8 +82,7 @@ def chrome_events(spans, t0=None, pid=CHROME_PID,
     return events
 
 
-def write_dump(path, spans, reason="manual", dropped=0, buffers=0,
-               slowest_ops=None):
+def write_dump(path, spans, reason="manual", dropped=0, buffers=0):
     """Materialize one dump directory at `path`; returns the path."""
     os.makedirs(path, exist_ok=True)
     with open(os.path.join(path, "spans.jsonl"), "w") as f:
@@ -110,7 +107,6 @@ def write_dump(path, spans, reason="manual", dropped=0, buffers=0,
         "traces": len({s["trace"] for s in spans}),
         "names": names,
         "files": {"spans": "spans.jsonl", "chrome": "trace.json"},
-        "slowest_ops": slowest_ops,
     }
     with open(os.path.join(path, "manifest.json"), "w") as f:
         json.dump(manifest, f, indent=2)

@@ -137,9 +137,8 @@ def dump(reason="manual", out_dir=None):
     """Write the flight recorder to <out_dir>/trace_<reason>_<n>/
     (out_dir defaults to FLAGS_trace_dump_dir, then cwd) and return the
     directory path. Format: export.write_dump (spans.jsonl + chrome
-    trace.json + manifest.json, with the slowest-ops table when compile
-    cost attribution is available)."""
-    from . import costs, export
+    trace.json + manifest.json)."""
+    from . import export
 
     reason = _REASON_RE.sub("_", str(reason)) or "manual"
     spans, dropped = snapshot()
@@ -149,12 +148,8 @@ def dump(reason="manual", out_dir=None):
         seq = _dump_seq[0]
         buffers = len(_rings)
     path = os.path.join(base, f"trace_{reason}_{seq}")
-    try:
-        slowest = costs.slowest_ops()
-    except Exception:
-        slowest = None
     export.write_dump(path, spans, reason=reason, dropped=dropped,
-                      buffers=buffers, slowest_ops=slowest)
+                      buffers=buffers)
     _last_dump[0] = path
     monitor.registry().counter(
         "trace_dumps_total",

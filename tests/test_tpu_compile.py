@@ -187,6 +187,88 @@ def test_se_squeeze_has_no_pass_of_its_own(one_chip, no_compile_cache,
     assert len(reduction_passes(together)) == 2
 
 
+def _resnet50_step(one_chip):
+    """The ResNet-50 training step of the `resnet50` configuration (batch
+    128, bf16 AMP, Momentum) as the Executor lowers it, compiled for one
+    v5e chip."""
+    import importlib
+    import json
+
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu import amp
+    from paddle_tpu.core import executor_core
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    import sys
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    builder = importlib.import_module("chipbench.configs.resnet50")
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "resnet50.json")) as f:
+        cfg = json.load(f)
+    built = builder.build(fluid, cfg, 7)
+    gb = built["prog"].global_block()
+    wrote = {n for op in gb.ops for n in op.output_arg_names()}
+    state = {n: jax.ShapeDtypeStruct(tuple(v.shape), np.dtype(v.dtype),
+                                     sharding=one_chip)
+             for n, v in gb.vars.items() if v.persistable}
+    mut = {n: s for n, s in state.items() if n in wrote}
+    const = {n: s for n, s in state.items() if n not in wrote}
+    size, batch = cfg["image_size"], cfg["batch_per_chip"]
+    feeds = {"data_u8": jax.ShapeDtypeStruct(
+                 (batch, size, size, cfg["channels"]), np.uint8,
+                 sharding=one_chip),
+             "label": jax.ShapeDtypeStruct((batch, 1), np.int32,
+                                           sharding=one_chip)}
+    rng = jax.ShapeDtypeStruct((2,), np.uint32, sharding=one_chip)
+    step = executor_core.build_step_fn(built["prog"], [built["loss"].name],
+                                       sorted(mut))
+    amp.enable(cfg["amp"])
+    try:
+        return jax.jit(step, donate_argnums=(0,)).lower(
+            mut, const, feeds, rng).compile()
+    finally:
+        amp.disable()
+
+
+def test_resnet50_step_compiles_the_same_with_and_without_scopes(
+        one_chip, no_compile_cache, monkeypatch):
+    """The model's block scopes, the default scope of every op and the
+    owner's scope on the updates are metadata to the chip's compiler too:
+    the v5e executable of the ResNet-50 step has the same instructions,
+    memory and code size with all of them off; with them on, every
+    convolution fusion names a layer of the model."""
+    import contextlib
+
+    from paddle_tpu.core import executor_core
+    from paddle_tpu.models import resnet
+
+    def measure(compiled):
+        text = compiled.as_text()
+        mem = compiled.memory_analysis()
+        return (len([ln for ln in text.splitlines() if _INSTR.match(ln)]),
+                mem.temp_size_in_bytes, mem.argument_size_in_bytes,
+                mem.output_size_in_bytes,
+                mem.generated_code_size_in_bytes), text
+
+    scoped, text = measure(_resnet50_step(one_chip))
+    named = [ln for ln in text.splitlines()
+             if "kind=kOutput" in ln and "op_name=" in ln]
+    assert named and all(re.search(
+        r'op_name="jit\(step\)/(stem|head|stage\d/block\d|optimizer/'
+        r'momentum\((stem|head|stage\d\.block\d)|sum\()', ln)
+        for ln in named if "convolution" in ln), [
+            ln[:200] for ln in named if "convolution" in ln][:3]
+    monkeypatch.setattr(resnet, "op_scope",
+                        lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(executor_core, "_device_scope",
+                        lambda op, ctx: contextlib.nullcontext())
+    plain, text = measure(_resnet50_step(one_chip))
+    assert "stage1" not in text
+    assert plain == scoped
+
+
 @pytest.mark.parametrize("q_shape,k_shape,dtype,causal,blocks", [
     ((2, 16, 4096, 128), (2, 16, 4096, 128), "bfloat16", True, (1024, 1024)),
     ((2, 16, 4096, 128), (2, 16, 4096, 128), "bfloat16", True, (512, 1024)),
@@ -433,9 +515,9 @@ def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
                 if re.match(r"\s*%grouped_matmul", ln)}
     assert {n.split("/", 1)[1] for n in op_names} == {
         "moe/moe_ffn/grouped/grouped_matmul/pallas_call",
-        "moe/moe_ffn_grad/transpose(moe/moe_ffn_grad)/jvp(grouped)/"
+        "moe/moe_ffn_grad/transpose(moe/moe_ffn_grad)/jvp(vjp)/grouped/"
         "grouped_matmul_nt/pallas_call",
-        "moe/moe_ffn_grad/transpose(moe/moe_ffn_grad)/jvp(grouped)/"
+        "moe/moe_ffn_grad/transpose(moe/moe_ffn_grad)/jvp(vjp)/grouped/"
         "grouped_matmul_tn/pallas_call"}
     copies = [m.group(2) for m in map(_INSTR.match, text.splitlines())
               if m and m.group(3) in ("copy", "copy-start", "transpose")

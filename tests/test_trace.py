@@ -269,26 +269,15 @@ def test_executor_emits_step_and_phase_spans():
     assert "dispatch" in hit_phases and "fetch_readback" in hit_phases
 
 
-def test_slowest_ops_attributes_hlo_cost_to_program_ops():
-    main, startup, loss = _tiny_program(size=8)
-    feed = {"x": np.ones((4, 8), np.float32)}
-    scope = fluid.Scope()
-    with _traced(monitor_hlo_cost=True), fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        exe.run(main, feed=feed, fetch_list=[loss])
-        fp = monitor.last_step()["fingerprint"]
-        report = trace.slowest_ops(fingerprint=fp, batch_size=4)
-    assert report is not None
-    assert report["fingerprint"] == fp
-    assert fp in trace.registered_fingerprints()
-    ops = report["ops"]
-    assert ops and ops[0]["op"] == "mul"        # fc matmul dominates
-    flops = [o["flops"] for o in ops]
-    assert flops == sorted(flops, reverse=True)
-    assert abs(sum(o["share"] for o in ops) - 1.0) < 1e-6
-    table = trace.format_ops_table(report)
-    assert "mul" in table and "share" in table
+def test_op_costs_estimates_program_ops_from_shapes():
+    """What is left of trace/costs.py: the planners' analytic weights. The
+    fc matmul of the tiny program dominates, and nothing is measured (no
+    step ran)."""
+    main, _, _ = _tiny_program(size=8)
+    rows = trace.op_costs(main, batch_size=4)
+    assert [r["index"] for r in rows] == list(range(len(rows)))
+    assert max(rows, key=lambda r: r["flops_est"])["op"] == "mul"
+    assert not hasattr(trace, "slowest_ops")
 
 
 # ---------------------------------------------------------------------------
