@@ -25,6 +25,7 @@ follows `mul`), so the backward pass mirrors the forward dtype flow and
 parameter gradients are upcast exactly once, at the optimizer/sum boundary.
 """
 
+import collections
 import contextlib
 
 import numpy as np
@@ -67,6 +68,19 @@ FLOAT32_SLOTS = {
                           "BPost", "BRes", "HPost@GRAD", "HRes@GRAD"}),
     "mhc_update": frozenset({"HRes", "HPost"}),
 }
+
+# Input slots of a white-list op whose value the lowering hands to a Pallas
+# call as it stands. Such a call fuses no producer, so the policy's cast of
+# a float32 master read through one of these is a pass of its own through
+# HBM, every step. A parameter read so is kept in the compute dtype beside
+# its master instead, where its update op is its only writer: the update
+# writes the copy from the value it has just computed (`LOW_OUT`,
+# optimizer.py), and the step hands the op the copy (`kept_copy`).
+KERNEL_SLOTS = {
+    "moe_ffn": frozenset({"Gate", "Up", "Down"}),
+}
+# the update op's output that holds ParamOut in the compute dtype
+LOW_OUT = "ParamLowOut"
 
 # Ops whose bf16 inputs are cast UP to float32 (numerics-sensitive math,
 # gradient accumulation, every optimizer/state update, metrics).
@@ -132,6 +146,10 @@ def is_enabled():
     return _state["enabled"]
 
 
+def compute_dtype():
+    return _state["dtype"]
+
+
 def fingerprint():
     """Hashable policy signature — part of every executor compile-cache key
     (a cached fp32 step must not be reused after enabling bf16). Sorted
@@ -195,6 +213,74 @@ def _cast_value(v, target, only_from=None):
         return v
     with jax.named_scope(CAST_SCOPE):
         return jnp.asarray(v).astype(target)
+
+
+def kernel_slots(op_type):
+    """The slots of `KERNEL_SLOTS` in which the policy as it stands casts
+    what an op of this type reads down to the compute dtype: none with
+    the policy off or the op off the white list."""
+    base = _base_type(op_type)
+    if not _state["enabled"] or base not in _state["white"]:
+        return ()
+    return KERNEL_SLOTS.get(base, ())
+
+
+def kernel_read_params(program):
+    """Names `program` reads through a slot of `KERNEL_SLOTS` and none of
+    its ops writes: the parameters whose update, appended now with the
+    policy on, keeps a copy in the compute dtype. None with the policy
+    off."""
+    read, written = set(), set()
+    for block in program.blocks:
+        for op in block.ops:
+            written.update(op.output_arg_names())
+            for slot in kernel_slots(op.type):
+                read.update(n for n in op.inputs.get(slot, ()) if n)
+    return frozenset(read - written)
+
+
+def kept_copies(program):
+    """({parameter: its kept copy} a step of `program` may read, the names
+    of all the copies its update ops write). A step reads the copy of a
+    parameter whose update op is its only writer in the program: after any
+    other write the copy would not be the cast of the master. Read off the
+    update ops, kept on the program until that is mutated (as
+    `lm_ops.lowered_counts`)."""
+    memo = getattr(program, "_kept_copies", None)
+    if memo is None or memo[0] != program._mutation:
+        ops = [op for b in program.blocks for op in b.ops]
+        writers = collections.Counter(
+            n for op in ops for n in op.output_arg_names())
+        declared = {op.input("Param")[0]: op.output(LOW_OUT)[0]
+                    for op in ops if op.outputs.get(LOW_OUT)}
+        memo = program._kept_copies = (
+            program._mutation,
+            {p: c for p, c in declared.items() if writers[p] == 1},
+            frozenset(declared.values()))
+    return memo[1], memo[2]
+
+
+def reads_kept_copies(op):
+    """Whether a step lowered under the policy as it stands hands `op` a
+    kept copy in every slot of `KERNEL_SLOTS` (`kept_copy`)."""
+    slots = kernel_slots(op.type)
+    program = op.block.program
+    kept, gvars = kept_copies(program)[0], program.global_block().vars
+    return bool(slots) and all(
+        n in kept and gvars[kept[n]].dtype == _state["dtype"]
+        for slot in slots for n in op.input(slot))
+
+
+def kept_copy(value, copy):
+    """What an op receives in a slot of `kernel_slots`: `copy`, the kept
+    copy of the parameter whose master `value` is, where `apply_policy`
+    would cast `value` to just that (the copy is there, in the policy's
+    compute dtype); `value` otherwise, for the policy to cast as ever."""
+    if copy is None or str(copy.dtype) != _state["dtype"] \
+            or str(getattr(value, "dtype", None)) != "float32" \
+            or copy.shape != value.shape:
+        return value
+    return copy
 
 
 def apply_policy(op_type, ins):

@@ -125,6 +125,16 @@ def _append_backward_ops(block, target_names, no_grad, grad_map, checkpoint_segm
                     consumed.add(n)
         pre_seen = set()  # in-place vars already assigned a @PRE by THIS op
         pending_sums = []  # (out_name, [parts])
+
+        def another(v, suffix):
+            """A fresh name for one more contribution to v's gradient,
+            declared (the verifier's PTA008 wants every name an op writes
+            declared; `_create_grad_vars` sees only the names `grad_map`
+            ends with)."""
+            name = unique_name.generate(grad_var_name(v) + suffix)
+            _declare_like(block, name, v)
+            return name
+
         for slot, outs in gin.items():
             names = op.inputs[slot]
             for i, o in enumerate(outs):
@@ -134,7 +144,7 @@ def _append_backward_ops(block, target_names, no_grad, grad_map, checkpoint_segm
                     if v in grad_map and v in consumed and v not in pre_seen:
                         # first occurrence: the old entry (grad w.r.t. the
                         # post-op value) was consumed via gout — REPLACE
-                        fresh = unique_name.generate(canonical + "@PRE")
+                        fresh = another(v, "@PRE")
                         outs[i] = fresh
                         grad_map[v] = fresh
                         pre_seen.add(v)
@@ -142,13 +152,13 @@ def _append_backward_ops(block, target_names, no_grad, grad_map, checkpoint_segm
                         # same op reads v through another slot too: its
                         # cotangents still SUM — into a fresh name, since
                         # `canonical` may be the live post-op grad
-                        fresh = unique_name.generate(canonical + "@PRE")
-                        total = unique_name.generate(canonical + "@PRE")
+                        fresh = another(v, "@PRE")
+                        total = another(v, "@PRE")
                         outs[i] = fresh
                         pending_sums.append((total, [grad_map[v], fresh]))
                         grad_map[v] = total
                     elif v in grad_map:
-                        fresh = unique_name.generate(canonical + "@RENAME")
+                        fresh = another(v, "@RENAME")
                         outs[i] = fresh
                         pending_sums.append((canonical, [grad_map[v], fresh]))
                         grad_map[v] = canonical
@@ -189,16 +199,20 @@ def _append_backward_ops(block, target_names, no_grad, grad_map, checkpoint_segm
     return grad_map
 
 
+def _declare_like(block, name, fwd_name):
+    fwd = block.vars.get(fwd_name)
+    block.create_var(
+        name=name,
+        shape=fwd.shape if fwd is not None else None,
+        dtype=fwd.dtype if fwd is not None else "float32",
+        lod_level=fwd.lod_level if fwd is not None else 0,
+    )
+
+
 def _create_grad_vars(block, grad_map):
     for fwd_name, g_name in grad_map.items():
         if g_name not in block.vars:
-            fwd = block.vars.get(fwd_name)
-            block.create_var(
-                name=g_name,
-                shape=fwd.shape if fwd is not None else None,
-                dtype=fwd.dtype if fwd is not None else "float32",
-                lod_level=fwd.lod_level if fwd is not None else 0,
-            )
+            _declare_like(block, g_name, fwd_name)
 
 
 def append_backward(loss, parameter_list=None, no_grad_set=None, callbacks=None,

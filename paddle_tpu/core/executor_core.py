@@ -63,7 +63,8 @@ class OpContext:
     execution (control flow), test-mode flag."""
 
     def __init__(self, rng=None, is_test=False, eager=False, scope=None, feed=None,
-                 fetch_sink=None, place=None, constraints=None):
+                 fetch_sink=None, place=None, constraints=None,
+                 kept_copies=None):
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         self.is_test = is_test
         self.eager = eager
@@ -75,6 +76,9 @@ class OpContext:
         # lowered as with_sharding_constraint at the producing op's output
         # (trace mode only; eager/host ops never see device layouts)
         self.constraints = constraints or {}
+        # {parameter: its kept low-precision copy} (amp.kept_copies): an op
+        # that would have the policy cast the master gets the copy
+        self.kept_copies = kept_copies or {}
 
     def next_rng(self):
         self._rng, sub = jax.random.split(self._rng)
@@ -153,11 +157,16 @@ def _run_one_op(op, env, ctx, attrs=None):
     # declaration-only inputs (e.g. listen_and_serv's recv buffers) are
     # resolved lazily by the kernel itself
     lazy = getattr(op_def, "lazy_inputs", False)
+    # the slots in which a parameter's kept copy stands in for the cast
+    kept_slots = amp.kernel_slots(op.type) if ctx.kept_copies else ()
     for slot, names in op.inputs.items():
         ins[slot] = [
             None if n == "" else env_get(env, n, allow_missing=lazy)
             for n in names
         ]
+        if slot in kept_slots:
+            ins[slot] = [amp.kept_copy(v, env.get(ctx.kept_copies.get(n)))
+                         for n, v in zip(names, ins[slot])]
     try:
         if ctx.eager and _profiler_enabled():
             from .. import profiler
@@ -224,6 +233,35 @@ def _apply_sharding_constraint(v, named_sharding):
 # ---------------------------------------------------------------------------
 # Compiled path
 # ---------------------------------------------------------------------------
+def refresh_kept_copies(program, scope):
+    """Before a step's state is gathered from `scope`: every kept copy the
+    step may read (amp.kept_copies) is the cast of the master the step is
+    about to be given. The scope remembers which value of the master each
+    copy was cast from; a master that is another value now (set_var, a
+    load, a restored checkpoint, another program's update) or a copy that
+    is not there (a fresh scope) gets the one cast the step would
+    otherwise have made. After the step `note_kept_copies` remembers the
+    masters it wrote: a training loop pays a dict lookup a copy."""
+    kept = amp.kept_copies(program)[0]
+    for param, copy in kept.items():
+        master = scope.find_var(param)
+        if master is None or (scope.cast_from.get(copy) is master
+                              and scope.has_var(copy)):
+            continue
+        dtype = dtypes.to_jnp(program.global_block().vars[copy].dtype)
+        scope.set_var(copy, jnp.asarray(master).astype(dtype))
+        scope.cast_from[copy] = master if isinstance(master, jax.Array) \
+            else None  # a host array can change in place: cast it again
+
+
+def note_kept_copies(program, scope, new_state):
+    """After a step wrote `new_state` back: its update ops wrote master
+    and copy together."""
+    for param, copy in amp.kept_copies(program)[0].items():
+        if param in new_state and copy in new_state:
+            scope.cast_from[copy] = new_state[param]
+
+
 def collect_state_names(program, scope):
     """Persistable vars the block reads or writes and that exist in scope."""
     gb = program.global_block()
@@ -294,13 +332,15 @@ def build_step_fn(program, fetch_names, state_out_names, is_test=False,
     ops = dead_code_eliminate(
         program.global_block().ops, list(fetch_names) + list(state_out_names)
     )
+    kept = amp.kept_copies(program)[0]
 
     def step(mut_state, const_state, feeds, rng):
         env = {}
         env.update(const_state)
         env.update(mut_state)
         env.update(feeds)
-        ctx = OpContext(rng=rng, is_test=is_test, constraints=constraints)
+        ctx = OpContext(rng=rng, is_test=is_test, constraints=constraints,
+                        kept_copies=kept)
         run_ops(ops, env, ctx)
         fetches = [env_get(env, n) for n in fetch_names]
         new_mut = {n: env[n] for n in state_out_names if n in env}

@@ -13,6 +13,9 @@ from .lod_tensor import LoDTensor
 class Scope:
     def __init__(self, parent=None):
         self._vars = {}
+        # {a kept low-precision copy's name: the value of its master it was
+        # cast from} (executor_core.refresh_kept_copies)
+        self.cast_from = {}
         self.parent = parent
         self.kids = []
 

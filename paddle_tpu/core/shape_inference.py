@@ -23,6 +23,8 @@ backend in at program-build time.
 
 import math
 
+from .. import amp
+
 _contracts = {}
 
 
@@ -655,6 +657,7 @@ def _optimizer(ctx):
     if p is None:
         return
     ctx.set_output_dim("ParamOut", p)
+    ctx.set_output_dim(amp.LOW_OUT, p)   # the kept copy, where there is one
     for slot in _OPT_STATE_SLOTS[ctx.op.type]:
         s = ctx.input_dim(slot)
         if s is not None:

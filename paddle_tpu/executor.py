@@ -432,6 +432,7 @@ class Executor:
         feed_vals = self._feed_values(program, feed, wire=wire)
         if mon is not None:
             mon.lap("feed_encode")
+        executor_core.refresh_kept_copies(program, scope)
         state_names, state_out_names = executor_core.collect_state_names(program, scope)
         if mon is not None:
             mon.lap("state_gather")
@@ -541,6 +542,7 @@ class Executor:
         # so skipping this would leave the scope holding deleted buffers
         for n, v in new_mut.items():
             scope.set_var(n, v)
+        executor_core.note_kept_copies(program, scope, new_mut)
         if t0 is not None:  # FLAGS_benchmark: synchronize + report
             jax.block_until_ready((fetches, new_mut))
             import sys
@@ -586,6 +588,7 @@ class Executor:
         feed_vals = self._stack_feeds(program, feed, iters, wire=wire)
         if mon is not None:
             mon.lap("feed_encode")
+        executor_core.refresh_kept_copies(program, scope)
         state_names, state_out_names = executor_core.collect_state_names(
             program, scope)
         missing = [n for n in state_out_names if not scope.has_var(n)]
@@ -696,6 +699,7 @@ class Executor:
             lap_call(mon, was_miss, build_s, fp)
         for n, v in new_mut.items():
             scope.set_var(n, v)
+        executor_core.note_kept_copies(program, scope, new_mut)
         if hstats is not None:
             _health.on_step(step0, iters, hstats, fetch_names, fetches,
                             mon=mon, kind="executor")

@@ -54,6 +54,19 @@ def _clone_var_in_block_(block, var):
     )
 
 
+def stored_vars(program, predicate):
+    """The program's variables `predicate` takes, less the low-precision
+    copies its update ops keep beside their masters (amp.kept_copies):
+    derived state, neither written nor read back. The step that next
+    reads a copy casts it from the master it finds in the scope
+    (executor_core.refresh_kept_copies)."""
+    from . import amp
+
+    derived = amp.kept_copies(program)[1]
+    return [v for v in filter(predicate, program.list_vars())
+            if v.name not in derived]
+
+
 def save_vars(executor, dirname, main_program=None, vars=None, predicate=None,
               filename=None):
     """reference io.py:63."""
@@ -63,7 +76,7 @@ def save_vars(executor, dirname, main_program=None, vars=None, predicate=None,
         save_vars(
             executor,
             dirname=dirname,
-            vars=list(filter(predicate, main_program.list_vars())),
+            vars=stored_vars(main_program, predicate),
             filename=filename,
         )
     else:
@@ -110,7 +123,7 @@ def load_vars(executor, dirname, main_program=None, vars=None, predicate=None,
         load_vars(
             executor,
             dirname=dirname,
-            vars=list(filter(predicate, main_program.list_vars())),
+            vars=stored_vars(main_program, predicate),
             filename=filename,
         )
     else:

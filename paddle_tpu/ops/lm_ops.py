@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import amp
 from ..core import places
 from ..core.registry import (register_grad_maker,
                              set_stop_gradient_outputs)
@@ -938,7 +939,9 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
              _has_window),
             ("causal_attention", "flash_attention_head_groups", True,
              _has_head_groups),
-            ("moe_ffn", "moe_ffn_row_bound", False, _has_row_bound))
+            ("moe_ffn", "moe_ffn_row_bound", False, _has_row_bound),
+            ("moe_ffn", "moe_ffn_kept_copies", False,
+             lambda op, block: amp.reads_kept_copies(op)))
 
 
 def lowered_counts(program, device):
@@ -950,17 +953,22 @@ def lowered_counts(program, device):
     the two d xs products in their epilogues; those that hold a share of
     their experts as `moe_ffn_held_experts`, and as `moe_ffn_row_bound`
     where that share gives them a row bound below top_k x tokens:
-    `row_bound`) and, on a TPU place, its
+    `row_bound`; those all three of whose expert weights the step reads
+    from the low-precision copies their updates keep, not from a cast of
+    the float32 masters, as `moe_ffn_kept_copies`: `amp.kept_copy`) and,
+    on a TPU place, its
     `causal_attention` ops (each lowers through the flash kernel; those
     with a window count as `flash_attention_window` too, those whose K has
     fewer heads than their Q as `flash_attention_head_groups`) and
     `causal_attention_grad` ops (each through the two backward kernels).
     A program without them reports none. Kept on the program until that
-    is mutated, like `bn_pool.count`."""
+    is mutated or the mixed-precision policy changes, like
+    `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)
-    if memo is None or memo[0] != program._mutation:
+    key = (program._mutation, amp.is_enabled(), amp.compute_dtype())
+    if memo is None or memo[0] != key:
         ops = [(op, b) for b in program.blocks for op in b.ops]
-        memo = program._lm_lowered = (program._mutation, [
+        memo = program._lm_lowered = (key, [
             sum(1 for op, b in ops
                 if op.type == t and (which is None or which(op, b)))
             for t, _, _, which in _LOWERED])

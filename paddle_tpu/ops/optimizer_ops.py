@@ -8,6 +8,8 @@ forward+backward+update runs as one TPU program.
 import jax.numpy as jnp
 from jax import lax
 
+from .. import amp
+from ..core import dtypes
 from ..core.registry import register_op
 from .util import first, out
 
@@ -65,7 +67,15 @@ def adam_op(ctx, ins, attrs):
     wd = attrs.get("weight_decay", 0.0)
     if wd:
         p_out = p_out - (lr * wd) * p.astype(jnp.float32)
-    return out(ParamOut=p_out.astype(p.dtype), Moment1Out=m1o, Moment2Out=m2o)
+    p_out = p_out.astype(p.dtype)
+    outs = out(ParamOut=p_out, Moment1Out=m1o, Moment2Out=m2o)
+    # the low-precision copy kept beside a master that a kernel reads
+    # (amp.KERNEL_SLOTS): written from the value just computed, in the
+    # same pass, bit for bit the cast the next step would have made
+    if attrs.get("low_dtype"):
+        outs[amp.LOW_OUT] = [
+            p_out.astype(dtypes.to_jnp(attrs["low_dtype"]))]
+    return outs
 
 
 @register_op("adamax")

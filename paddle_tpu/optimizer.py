@@ -19,7 +19,7 @@ from .core.framework import (
     op_scope,
 )
 from .backward import append_backward
-from . import unique_name
+from . import amp, unique_name
 from .clip import append_gradient_clip_ops, error_clip_callback
 from .regularizer import append_regularization_ops
 
@@ -102,7 +102,11 @@ class Optimizer:
     def _create_accumulators(self, block, parameters):
         pass
 
-    def _add_accumulator(self, name, param, dtype="float32", fill_value=0.0, shape=None):
+    def _add_accumulator(self, name, param, dtype="float32", fill_value=0.0,
+                         shape=None, cast_of_param=False):
+        """A persistable variable of `param`'s shape beside it, filled
+        with `fill_value` by the startup program, or, `cast_of_param`,
+        with the initialised parameter cast to `dtype` there."""
         if name in self._accumulators and param.name in self._accumulators[name]:
             raise Exception(f"Accumulator {name} already exists for parameter {param.name}")
         self._accumulators.setdefault(name, {})
@@ -116,12 +120,17 @@ class Optimizer:
         startup.global_block().create_var(
             name=var_name, shape=shape, dtype=dtype, persistable=True
         )
-        startup.global_block().append_op(
-            "fill_constant",
-            {},
-            {"Out": [var_name]},
-            {"shape": shape, "value": float(fill_value), "dtype": dtype},
-        )
+        if cast_of_param:
+            startup.global_block().append_op(
+                "cast", {"X": [param.name]}, {"Out": [var_name]},
+                {"in_dtype": param.dtype, "out_dtype": dtype})
+        else:
+            startup.global_block().append_op(
+                "fill_constant",
+                {},
+                {"Out": [var_name]},
+                {"shape": shape, "value": float(fill_value), "dtype": dtype},
+            )
         self._accumulators[name][param.name] = var
         return var
 
@@ -280,6 +289,7 @@ class AdamOptimizer(Optimizer):
 
     _moment1_acc_str = "moment1"
     _moment2_acc_str = "moment2"
+    _low_copy_acc_str = "low_copy"
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8,
                  weight_decay=0.0, apply_decay_param_fun=None, **kwargs):
@@ -319,13 +329,25 @@ class AdamOptimizer(Optimizer):
 
         self._beta1_pow_acc = global_acc("beta1_pow_acc", self._beta1)
         self._beta2_pow_acc = global_acc("beta2_pow_acc", self._beta2)
+        # a parameter that the program, built with the mixed-precision
+        # policy on, reads through a slot whose value goes to a kernel as it
+        # stands (amp.KERNEL_SLOTS), and that nothing has written, keeps a
+        # copy in the policy's compute dtype beside the float32 master: the
+        # update about to be appended is its only writer, and writes both
+        kernel_read = amp.kernel_read_params(main)
         for p in parameters:
             self._add_accumulator(self._moment1_acc_str, p)
             self._add_accumulator(self._moment2_acc_str, p)
+            if p.name in kernel_read and p.dtype == "float32":
+                self._add_accumulator(
+                    self._low_copy_acc_str, p, dtype=amp.compute_dtype(),
+                    cast_of_param=True)
 
     def _append_optimize_op(self, block, param_and_grad):
         moment1 = self._get_accumulator(self._moment1_acc_str, param_and_grad[0])
         moment2 = self._get_accumulator(self._moment2_acc_str, param_and_grad[0])
+        low = self._accumulators.get(self._low_copy_acc_str, {}).get(
+            param_and_grad[0].name)
         return block.append_op(
             "adam",
             {
@@ -341,8 +363,10 @@ class AdamOptimizer(Optimizer):
                 "ParamOut": [param_and_grad[0]],
                 "Moment1Out": [moment1],
                 "Moment2Out": [moment2],
+                **({} if low is None else {amp.LOW_OUT: [low]}),
             },
-            self._adam_attrs(param_and_grad[0]),
+            {**self._adam_attrs(param_and_grad[0]),
+             **({} if low is None else {"low_dtype": low.dtype})},
         )
 
     def _adam_attrs(self, param):

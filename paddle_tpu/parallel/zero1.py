@@ -34,7 +34,7 @@ tooling. The manifest records the shard layout under "zero1".
 
 import numpy as np
 
-from .. import flags
+from .. import amp, flags
 from ..core.framework import VarType
 from ..optimizer import ZERO1_SHARDABLE_SLOTS
 
@@ -217,6 +217,10 @@ def build_plan(program, parts, axis=DP_AXIS):
             continue
         if getattr(pvar, "sharding", None) is not None:
             skip("param carries a user set_sharding rule (mp-parallel)")
+            continue
+        if op.outputs.get(amp.LOW_OUT):
+            skip("the update keeps a low-precision copy of the whole "
+                 "param beside it (amp.KERNEL_SLOTS)")
             continue
         if gvar is not None and (
                 gvar.type == VarType.SELECTED_ROWS

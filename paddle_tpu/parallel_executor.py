@@ -504,6 +504,7 @@ class ParallelExecutor:
             # stacking + device_put onto the mesh (the h2d link for feeds)
             mon.lap("feed_encode")
 
+        executor_core.refresh_kept_copies(program, scope)
         state_names, state_out_names = executor_core.collect_state_names(program, scope)
         if mon is not None:
             mon.lap("state_gather")
@@ -697,6 +698,7 @@ class ParallelExecutor:
             lap_call(mon, was_miss, build_s, fp)
         for n, v in new_mut.items():
             scope.set_var(n, v)
+        executor_core.note_kept_copies(program, scope, new_mut)
         if hstats is not None:
             _health.on_step(step0, iters, hstats, fetch_names, fetches,
                             mon=mon, kind="parallel_executor")

@@ -226,16 +226,16 @@ class CheckpointManager:
     def snapshot_vars(self, scope=None, program=None):
         """{name: host ndarray} of the program's checkpoint vars currently
         in scope — the step-gap cost of a save."""
+        from .. import io as io_mod
         from ..core.framework import default_main_program
         from ..core.scope import global_scope
 
         scope = scope if scope is not None else global_scope()
         program = program if program is not None else default_main_program()
-        pred = self._pred()
         snap = {}
-        for var in program.list_vars():
-            if not pred(var):
-                continue
+        # less the low-precision copies kept beside their masters: the
+        # step that next reads one casts it from the restored master
+        for var in io_mod.stored_vars(program, self._pred()):
             v = scope.find_var(var.name)
             if v is None:
                 continue

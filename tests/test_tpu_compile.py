@@ -431,7 +431,13 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
     monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
     monkeypatch.setattr(grouped, "pallas_interpret", lambda: False)
-    built = builder.build(fluid, cfg, 7)
+    # the policy on before the build, as the cells have it: the optimizer
+    # then keeps the bf16 copies of the expert weights
+    amp.enable("bfloat16")
+    try:
+        built = builder.build(fluid, cfg, 7)
+    finally:
+        amp.disable()
     gb = built["prog"].global_block()
     wrote = {n for op in gb.ops for n in op.output_arg_names()}
     read = {n for op in gb.ops for n in op.input_arg_names()}
@@ -544,6 +550,38 @@ def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert held < 15.5e9, held
+
+
+def _expert_weights_come_cast_from_their_update(compiled, name, weights):
+    """`weights`: {an expert weight's [E', ., .] shape: how many the step
+    has}. No operation of the step writes a bf16 array of such a shape but
+    the `adam` fusion that writes the same weight's float32 master and its
+    two moments, one a weight (the kept copy is a result of the one pass
+    over the state: `amp.KERNEL_SLOTS`); in particular no standalone
+    `convert` does (the policy's cast, a pass of its own before a Pallas
+    kernel). Prints what the step holds; returns arguments + temporaries +
+    code, the bytes the chip must have."""
+    found = {}
+    for f in fusions(compiled.as_text(), unfused=True):
+        for shape in weights:
+            dims = "[%s]" % ",".join(map(str, shape))
+            if "bf16" + dims in f["shape"]:
+                assert f["kind"] == "kLoop" \
+                    and "/optimizer/adam(" in f["op_name"] \
+                    and f["shape"].count("f32" + dims) == 3, (
+                        f["name"], f["shape"], f["op_name"])
+                found[shape] = found.get(shape, 0) + 1
+    assert found == weights, found
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    code = mem.generated_code_size_in_bytes
+    print("%s step on a v5e: arguments %.3f GB + temporaries %.3f GB + "
+          "code %.3f GB = %.3f GB" % (
+              name, (held - mem.temp_size_in_bytes) / 1e9,
+              mem.temp_size_in_bytes / 1e9, code / 1e9,
+              (held + code) / 1e9))
+    return held + code
 
 
 def _custom_calls(text):
@@ -661,6 +699,12 @@ def test_xing_step_runs_both_kernel_families_over_its_share(
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 12.6e9 < held < 16.4e9, held
+    # every expert block's three (4 + the prediction module's), and the
+    # step with its code under what the chip holds (16.9 GB): 15.87 GB,
+    # the parent's 16.30 less the casts' temporaries XLA kept apart
+    assert _expert_weights_come_cast_from_their_update(
+        compiled, "xing4_0_29b_a4b",
+        {(8, 3584, 1024): 10, (8, 1024, 3584): 5}) < 16.9e9
 
 
 def test_olmoe_kernel_counts_are_unchanged(one_chip, no_compile_cache,
@@ -675,6 +719,11 @@ def test_olmoe_kernel_counts_are_unchanged(one_chip, no_compile_cache,
         + ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
         + ["grouped_matmul_tn"] * 3)
     assert ragged_dots(text) == []
+    # 10.196 GB + 0.040 of code at one row, the parent's to the byte: the
+    # copies take the place of the casts' temporaries
+    assert _expert_weights_come_cast_from_their_update(
+        compiled, "olmoe_1b_7b", {(64, 2048, 1024): 2, (64, 1024, 2048): 1}
+    ) < 10.4e9
 
 
 @pytest.mark.parametrize("window,heads,blocks", [
@@ -767,3 +816,6 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 8.65e9 < held < 16.4e9, held
+    assert _expert_weights_come_cast_from_their_update(
+        compiled, "laguna_xs_2",
+        {(32, 2048, 512): 8, (32, 512, 2048): 4}) < 16.9e9
