@@ -1,0 +1,495 @@
+"""The comparison that decides `correct` for a language model whose router
+reads the layer's INPUT (before the attention norm and attention), whose
+experts are gated by ReLU, whose layers alternate between full attention
+without positions and a sliding window with rotary positions, and that
+holds one chip's SHARE of each layer (`smallthinker_21b_a3b`): the system
+under test against the configuration's plain float32 reference (which is
+given the same share), at the published widths, on the device the cell
+runs on, outside the window, on the rows the cell's own window starts
+with. As in `compare_lm_window_share` (whose `system_side` and
+`_branch_errors`, and `compare_lm`'s and `compare_lm_share`'s helpers, this
+file imports, not copies) two objects are set against the reference: (1)
+THE EXECUTABLE THE WINDOW TIMES, its losses of steps 0 and 1 against the
+reference's first step and its second after its own update (every trained
+weight by AdamW behind the global clip, each router's stand-in bias by the
+rule at its layer's speed); (2) a second build of the same program run for
+ONE step with the gradients fetched, and its inference clone.
+
+Compared on one row of 8192 tokens:
+
+* FIRST-HAND: the attention branch of layer 0 (full, NO rotary, 7 query
+  heads on one key/value head) and of layer 1 (window 4096, rotary), the
+  system's output against the reference's ON THE SAME normed input, and
+  the window branch against the reference's at 4095 and 4097: the stated
+  band must fit best;
+* that input itself against the reference's FROM THE TOKENS: the rms of
+  its per-row scale error (the norm statistic);
+* LAYER 0'S CHOSEN EXPERTS ARE THE REFERENCE'S for at least
+  `EARLY_ROUTE_SAME_MIN` of the tokens, in the training step and in the
+  inference program: both sides read float32 products of the SAME float32
+  embedding rows, so nothing but the order of float32 sums can flip a
+  choice. This is the check that the router reads the layer's input: a
+  router that reads the normed state after attention (the study's planted
+  variant `router_after_attention`) chooses otherwise for most tokens;
+* routing of layers 1-3, each judged on the tokens every layer before it
+  routed alike: the share flipped, and every exchanged expert within
+  `ROUTING_MARGIN` logit-spreads of the reference's k-th (r + b);
+* logits per token over the tokens routed alike everywhere; the loss; the
+  global gradient norm and the clip's scale;
+* gradient cosine, norm ratio and first AdamW update of a sampled
+  parameter of each kind (`sampled_params`): the router of layer 0 and
+  the embedding both hold the term that travels through `RouterInput`
+  (`router_input_term`: with that path cut in the reference the
+  embedding's cosine falls to the figure reported beside it);
+* every layer's `DownOut`: its non-zero rows are `RowsHeld` = the choices
+  on the held experts;
+* each router's bias after the step: moved by ITS layer's speed towards an
+  even load of that step's own choices, exactly.
+
+The limits, each from two readings: the largest the system gave as the
+configuration states it over the builder's seeds on the chip ("stated"),
+and the SYSTEM one precision below (`python -m
+chipbench.lower_precision_lm_early_route_share`, on the chip: the router,
+the norms' statistics, the master weights, then all in bf16; its planted
+`router_after_attention` and `band_off_by_one`): every variant comes out
+not `correct` on both of the study's seeds, `stated` correct on all 14. The
+readings stand beside each constant below; PERF.md section 6, PR 36.
+"""
+
+import gc
+import time
+
+import numpy as np
+
+from chipbench.compare_lm import _cos_ratio, _rel
+from chipbench.compare_lm_share import _logits_errors as _errors_over
+from chipbench.compare_lm_window_share import _branch_errors, system_side
+from chipbench.harness import memory_peak
+
+# READINGS (my chip runs, PR 36): "stated" = the largest (for a floor the
+# smallest) over 14 seeds of the system as the configuration states it (the
+# cell's twelve runs: 2147483999, 1900000213, 2100456789, 2147484101,
+# 77770003, 2147485007, and from the committed files 2147487001,
+# 2147487002, 1234567891, 2000000011, 987654321, 2147480077; the study's
+# `stated`, seeds 11 and 12) | the study's variants, seeds 11 / 12. A limit that both readings pass is said
+# to be coarse: it holds a mechanism, not a precision.
+# stated 1.0 (every token, training and inference, all 14) | `router`
+# 0.9491 / 0.9833, `router_after_attention` 0.0 / 0.0
+EARLY_ROUTE_SAME_MIN = 0.999
+# COARSE: stated 7.96% of a layer's tokens (42 readings of layers 1-3:
+# 2.4-8.0%) | `router` 8.6 / 6.5%, `all` 9.6 / 6.7%: a near-tie falls on
+# either side whatever the precision. `compare_lm_window_share`'s accepted
+# limit; what says that a flip WAS a near-tie is the margin
+ROUTING_FLIP_MAX = 0.14
+# COARSE: of the token's logit spread (std over experts of r + b); stated
+# 0.043 | `router` 0.069 / 0.039, `all` 0.086 / 0.053; an exchanged expert
+# that was no neighbour of the k-th reads ~1
+ROUTING_MARGIN = 0.15
+# COARSE: stated 0.0131 max, 0.00782 rms | `all` 0.0181, 0.0126 / 0.0112,
+# 0.0097; `router_after_attention` inf (no token routed alike)
+LOGITS_TOL = 0.03
+LOGITS_RMS_TOL = 0.02
+# the accepted share comparisons' limit: stated 1.32e-4 (the one step),
+# 1.39e-4 (the timed scan's steps 0 and 1) | `all` 2.7e-4 / 2.2e-3,
+# `router_after_attention` 1.2e-3 / 2.9e-3; the timed scan's second loss
+# had the first step carried nothing: 4.4e-3 - 6.0e-3
+LOSS_TOL = 6e-4
+# stated 1.24e-3 (2e-5 - 1.24e-3 over the 14) | `router` 3.6e-4 / 1.1e-2,
+# `all` 1.8e-3 / 1.6e-2, `router_after_attention` 1.9e-2 / 1.0e-2
+GLOBAL_NORM_TOL = 4e-3
+# stated 7.6e-8 | `all` 2.4e-4 / 2.7e-3 (a bf16 norm of the gradients)
+CLIP_SCALE_TOL = 1e-5
+# stated 0.0585 (a norm scale: a step of 1e-6 is 17 float32 ulps of 1.0,
+# so rounding alone reads up to 0.06; every matrix <= 0.0073) | `masters`
+# 242 / 242 (a bf16 master cannot hold the step)
+UPDATE_TOL = 0.1
+# stated 0.0057 max, 0.0058 rms (full; window 0.0048, 0.0035) | the same in
+# every variant: the branch is bf16 as stated. What holds the band is the
+# fit: the reference's branch at 4096 lies nearer than at 4095 and 4097 in
+# all 14 (0.003328 against 0.003344 / 0.003344 on the first), and `band_off_by_one`'s
+# fits 4097 best (0.003439 against 0.003456 at 4096)
+ATTENTION_RMS_TOL = 0.015
+ATTENTION_TOL = 0.015
+# by first-hand layer (`FIRST_HAND`): layer 0's input is the norm of the
+# float32 embedding, layer 1's lies behind a bf16 layer. Layer 0: stated
+# 0.0 | `norms` 1.49e-3 / 1.64e-3. Layer 1: stated 2.26e-4 (1.4e-4 -
+# 2.3e-4 over the 14) | `norms` 1.58e-3 / 1.53e-3, `router` 1.21e-2 /
+# 9.9e-3: the limit near their geometric mean
+NORM_SCALE_TOL = {0: 1e-5, 1: 6e-4}
+# gradient cosine at least, norm ratio within, by kind of parameter; only
+# `router` (and `all`) moves them. router (layer 0): stated 0.99984, 1.0%
+# | 0.931, 6.8% / 0.970, 25.8%. router_window: stated 0.99907, 1.96% |
+# 0.873, 9.4% / 0.937, 11.8%. expert (gate, up, down of one held expert):
+# stated 0.99988, 0.58% | 0.950, 7.7% / 0.965, 18.0%
+GRAD_LIMITS = {"router": (0.995, 0.03), "router_window": (0.99, 0.06),
+               "expert": (0.995, 0.03)}
+# every other sampled parameter: stated 0.99990 (W_q of a full layer),
+# 0.96% | `router` 0.948, 4.3% / 0.971, 4.6%. The embedding's cosine,
+# which holds the term through `RouterInput`: stated 0.99996 | against the
+# reference with that path cut 0.9909 - 0.9997 by seed (routers at std
+# 0.02 send little back: only some seeds would show a lost path here; the
+# CPU tests hold it with routers at std 0.5)
+GRAD_LIMITS_ELSE = (0.998, 0.03)
+# the layers whose attention branch is compared first-hand: the full layer
+# without positions and the first window layer; no bf16 routing lies
+# before either (layer 0's is exact)
+FIRST_HAND = (0, 1)
+P = "smallthinker."
+
+
+def _logits_errors(got, ref, same):
+    """`compare_lm_share._logits_errors`; infinite where no token was
+    routed alike everywhere (a planted router): nothing to compare."""
+    if not same.any():
+        return float("inf"), float("inf")
+    return _errors_over(got, ref, same)
+
+
+def _window_layer(cfg):
+    return next(i for i in FIRST_HAND if cfg["sliding_window_layout"][i])
+
+
+def reference_branches(cfg, builder, w0, tokens, attention_inputs,
+                       layers=FIRST_HAND):
+    """The reference's attention branches of `layers` on the normed inputs
+    the system itself fed its own, [T, C] each."""
+    import jax.numpy as jnp
+
+    return [np.asarray(builder.reference.attention_branch(
+        cfg, {k: jnp.asarray(v) for k, v in w0.items()
+              if k.startswith(f"{P}l{i}.w_")}, i,
+        jnp.asarray(u).reshape(tokens.shape + (-1,)))).reshape(
+            tokens.size, -1) for i, u in zip(layers, attention_inputs)]
+
+
+def reference_band_neighbours(cfg, builder, w0, tokens, attention_inputs):
+    """{window: the reference's branch of the first-hand WINDOW layer, on
+    the system's own input, had its band been one position shorter or
+    longer}: the system's branch must lie nearer the reference's at the
+    stated window than at either neighbour."""
+    W, i = int(cfg["sliding_window_size"]), _window_layer(cfg)
+    u = attention_inputs[FIRST_HAND.index(i)]
+    return {w: reference_branches(dict(cfg, sliding_window_size=w), builder,
+                                  w0, tokens, [u], layers=(i,))[0]
+            for w in (W - 1, W + 1)}
+
+
+def reference_inputs(cfg, builder, w0, tokens):
+    """The normed inputs of `FIRST_HAND`'s attention branches as the
+    reference computes them FROM THE TOKENS, [T, C] each."""
+    import jax
+    import jax.numpy as jnp
+
+    ref, eps = builder.reference, cfg["rms_norm_eps"]
+    last = max(FIRST_HAND)
+    before = (P + "embed", f"{P}l{last}.attn_norm") + tuple(
+        f"{P}l{i}." for i in range(last))
+    w = {k: jnp.asarray(v) for k, v in w0.items() if k.startswith(before)}
+
+    def inputs(w_, t):
+        x, found = w_[P + "embed"][t], {}
+        for i in range(last + 1):
+            if i in FIRST_HAND:
+                found[i] = ref.rms_norm(x, w_[f"{P}l{i}.attn_norm"], eps)
+            if i < last:
+                x, _ = ref.layer(x, w_, i, cfg)
+        return [found[i] for i in FIRST_HAND]
+
+    with jax.default_matmul_precision(ref.PRECISION):
+        return [np.asarray(u).reshape(tokens.size, -1)
+                for u in jax.jit(inputs)(w, jnp.asarray(tokens))]
+
+
+def _biases(names):
+    return sorted((k for k in names if k.endswith("router_bias")),
+                  key=lambda k: int(k.split(".")[1][1:]))
+
+
+def reference_second_step(cfg, builder, wj, grads, routing, tokens, labels):
+    """The reference's loss on the rows of step 1 after ITS OWN first step
+    (the first AdamW update of every trained weight behind the global
+    clip, each router's bias moved by its layer's speed on the reference's
+    own choices), and the loss on the same rows had the first step left
+    the state as it was: (loss, loss with nothing carried)."""
+    import jax
+    import jax.numpy as jnp
+
+    ref, o = builder.reference, cfg["optimizer"]
+    delta, _ = ref.adamw_first_update(
+        cfg, wj, grads, epsilon=o["epsilon"] / np.sqrt(1.0 - o["beta2"]))
+    w1 = dict(wj)
+    for name in list(delta):
+        w1[name] = wj[name] + delta.pop(name)
+    for name, (_, chosen), speed in zip(
+            _biases(wj), routing, o["router_bias_update_speed_by_layer"]):
+        w1[name] = ref.balance_step(cfg, wj[name], chosen, speed)
+    with jax.default_matmul_precision(ref.PRECISION):
+        loss = jax.jit(lambda w_, t, l: ref.loss_fn(cfg, w_, t, l)[0])
+        t, l = jnp.asarray(tokens), jnp.asarray(labels)
+        return float(loss(w1, t, l)), float(loss(wj, t, l))
+
+
+def reference_without_the_router_s_path(cfg, builder, wj, tokens, labels):
+    """The embedding's gradient of a reference whose routers' logits carry
+    no gradient back into x (`stop_gradient` on the router's input): what
+    the embedding's gradient would be without the term that travels
+    through `RouterInput`."""
+    import jax
+
+    ref = builder.reference
+    real = ref.route
+    ref.route = lambda x_in, w_, p, c: real(
+        jax.lax.stop_gradient(x_in), w_, p, c)
+    try:
+        _, _, grads = ref.loss_and_grads(cfg, wj, tokens, labels)
+    finally:
+        ref.route = real
+    return np.asarray(grads[P + "embed"])
+
+
+def reference_side(cfg, builder, w0, tokens, labels, attention_inputs):
+    """The plain reference on the same weights and rows, as numpy;
+    `tokens` may hold the rows of a second step behind those of the first
+    (`cfg["reference"]["rows"]`): `reference_second_step`."""
+    import jax.numpy as jnp
+
+    ref, picks = builder.reference, builder.sampled_params(cfg)
+    rows = int(cfg["reference"]["rows"])
+    first, then = (tokens[:rows], labels[:rows]), (tokens[rows:2 * rows],
+                                                    labels[rows:2 * rows])
+    wj = {k: jnp.asarray(v) for k, v in w0.items()}
+    t0, l0 = jnp.asarray(first[0]), jnp.asarray(first[1])
+    loss, (logits, routing), grads = ref.loss_and_grads(cfg, wj, t0, l0)
+    gnorm = float(jnp.sqrt(sum(jnp.sum(g * g) for g in grads.values())))
+    T = first[0].size
+    side = dict(
+        loss=float(loss), gnorm=gnorm,
+        routing=[(np.asarray(b), np.asarray(t)) for b, t in routing],
+        logits=np.asarray(logits).reshape(T, -1),
+        grads={k: np.asarray(grads[n]) for k, n in picks.items()})
+    del logits
+    if len(then[0]):
+        side["second_step"] = reference_second_step(
+            cfg, builder, wj, grads, routing, *then)
+    del grads
+    side["embedding_grad_without_router_path"] = \
+        reference_without_the_router_s_path(cfg, builder, wj, t0, l0)
+    del wj
+    side["attention"] = reference_branches(cfg, builder, w0, first[0],
+                                           attention_inputs)
+    side["attention_band"] = reference_band_neighbours(
+        cfg, builder, w0, first[0], attention_inputs)
+    side["attention_inputs"] = reference_inputs(cfg, builder, w0, first[0])
+    return side
+
+
+def routing_report(ids_sys, biased_ref, top_ref, margin):
+    """Tokens whose chosen set differs from the reference's, and whether
+    every exchanged expert is a neighbour of the reference's threshold:
+    its reference r + b within `margin` x the token's logit spread (the
+    std of its r + b over the experts) of the k-th largest. Logits, not
+    probabilities: a relative gap of numbers around zero says nothing."""
+    k, E = top_ref.shape[1], biased_ref.shape[1]
+    chosen_sys = np.zeros(biased_ref.shape, bool)
+    np.put_along_axis(chosen_sys, ids_sys, True, axis=1)
+    chosen_ref = np.zeros(biased_ref.shape, bool)
+    np.put_along_axis(chosen_ref, top_ref, True, axis=1)
+    differs = chosen_sys ^ chosen_ref
+    kth = np.sort(biased_ref, axis=1)[:, E - k][:, None]
+    gap = np.abs(biased_ref - kth) / biased_ref.std(axis=1, keepdims=True)
+    flipped = differs.any(axis=1)
+    worst = float(np.where(differs, gap, 0.0).max()) if flipped.any() else 0.0
+    return {"tokens": int(len(flipped)),
+            "flipped_share": float(flipped.mean()) if len(flipped) else 1.0,
+            "worst_gap_in_spreads": worst,
+            "ok": bool(len(flipped) and worst <= margin)}, ~flipped
+
+
+def _routing_by_layer(ids, routing_ref):
+    """Each layer's report over the tokens that all earlier layers routed
+    as the reference did, and the tokens every layer routed alike."""
+    alike = np.ones(ids[0].shape[0], bool)
+    reports = []
+    for ids_l, (biased, top) in zip(ids, routing_ref):
+        rep, same = routing_report(ids_l[alike], biased[alike], top[alike],
+                                   ROUTING_MARGIN)
+        rep["tokens_alike_before"] = int(alike.sum())
+        reports.append(rep)
+        alike[alike] = same
+    return reports, alike
+
+
+def judge(cfg, builder, got, ref, timed=None):
+    """The report: every number, the limits, which of them `failed`.
+    `timed`: {"losses": the losses of steps 0 and 1 as the TIMED
+    executable fetched them}, where `ref` holds a second step."""
+    picks = builder.sampled_params(cfg)
+    route, _ = _routing_by_layer(got["ids"], ref["routing"])
+    route_eval, same = _routing_by_layer(got["ids_eval"], ref["routing"])
+    main_max, main_rms = _logits_errors(got["logits"], ref["logits"], same)
+    first = cfg["deployment"]["first_expert"]
+    held_n = cfg["moe_num_primary_experts"]
+    n_all = cfg["deployment"]["moe_num_primary_experts"]
+    counts = np.bincount(ref["routing"][0][1].ravel(), minlength=n_all)
+    expert = int(counts[first:first + held_n].argmax())
+    rows = [[written, held, int(((ids >= first)
+                                 & (ids < first + held_n)).sum())]
+            for (written, held), ids in zip(got["rows_written"],
+                                            got["ids_eval"])]
+    o = cfg["optimizer"]
+    eps = o["epsilon"] / np.sqrt(1.0 - o["beta2"])
+    bias_moved = []
+    for (before, after), ids, speed in zip(
+            got["biases"], got["ids"],
+            o["router_bias_update_speed_by_layer"]):
+        load = np.bincount(ids.ravel(), minlength=n_all).astype(np.float64)
+        want = before + np.float32(speed) * np.sign(
+            load.mean() - load).astype(np.float32)
+        bias_moved.append(bool(np.array_equal(after, want)))
+    by_param = {}
+    for key, name in picks.items():
+        g_hat, g_ref = got["clipped"][key], ref["grads"][key]
+        a, b = got["w0"][name], got["w1"][key]
+        if key.startswith("expert_"):
+            g_hat, g_ref, a, b = (v[expert] for v in (g_hat, g_ref, a, b))
+        cos, ratio = _cos_ratio(g_hat / got["scale"], g_ref)
+        decay = o["weight_decay"] if builder.reference.decays(name) else 0.0
+        want = -o["learning_rate"] * (g_hat / (np.abs(g_hat) + eps)
+                                      + decay * a)
+        kind = "expert" if key.startswith("expert_") else key
+        cos_min, ratio_tol = GRAD_LIMITS.get(kind, GRAD_LIMITS_ELSE)
+        by_param[key] = {
+            "grad_cos": cos, "grad_norm_ratio": ratio,
+            "grad_ok": bool(cos is not None and cos >= cos_min
+                            and abs(ratio - 1.0) <= ratio_tol),
+            "update_err": float(np.abs((b - a) - want).max()
+                                / np.abs(want).max())}
+    # the embedding's gradient against the reference's with the path
+    # through the routers' input cut: how far below the limit a system
+    # that lost `RouterInput`'s gradient would read
+    cut_cos, cut_ratio = _cos_ratio(
+        got["clipped"]["embedding"] / got["scale"],
+        ref["embedding_grad_without_router_path"])
+    kinds = {i: "window" if cfg["sliding_window_layout"][i] else "full"
+             for i in FIRST_HAND}
+    attention = {
+        kinds[i]: _branch_errors(o_sys, o_ref)
+        for i, (_, o_sys), o_ref in zip(FIRST_HAND, got["attention"],
+                                        ref["attention"])}
+    W = int(cfg["sliding_window_size"])
+    o_win = got["attention"][FIRST_HAND.index(_window_layer(cfg))][1]
+    band = {w: _branch_errors(o_win, o_ref)[1]
+            for w, o_ref in ref["attention_band"].items()}
+    band[W] = attention["window"][1]
+    inputs = {}
+    for i, (y, _), y_ref in zip(FIRST_HAND, got["attention"],
+                                ref["attention_inputs"]):
+        row_scale = np.sum(y * y_ref, axis=1) / np.sum(y_ref * y_ref, axis=1)
+        inputs[kinds[i]] = (
+            _branch_errors(y, y_ref)[1],
+            float(np.sqrt(np.mean(np.square(row_scale - 1.0)))))
+    steps = {}
+    if timed is not None and "second_step" in ref:
+        after, unmoved = ref["second_step"]
+        steps = {"loss_timed_reference": [
+                     [float(timed["losses"][0]), ref["loss"]],
+                     [float(timed["losses"][1]), after]],
+                 "second_loss_had_nothing_carried": unmoved}
+        steps["err"] = [_rel(a, b) for a, b in steps["loss_timed_reference"]]
+        steps["err_had_nothing_carried"] = _rel(unmoved, after)
+    early = [1.0 - r[0]["flipped_share"] for r in (route, route_eval)]
+    report = {
+        "attention_branch_err_max_rms": attention,
+        "window_branch_err_rms_by_reference_window": {
+            str(w): band[w] for w in sorted(band)},
+        "attention_input_err_rms_rowscale": inputs,
+        "timed_steps": steps,
+        "layer_0_choices_same_share_train_inference": early,
+        "product_rows_written_held_chosen": rows,
+        "router_bias_moved_by_the_rule": bias_moved,
+        "router_input_term": {
+            "embedding_cos_ratio_against_reference": [
+                by_param["embedding"]["grad_cos"],
+                by_param["embedding"]["grad_norm_ratio"]],
+            "against_reference_with_the_path_cut": [cut_cos, cut_ratio]},
+        "config": cfg["name"], "rows": int(cfg["reference"]["rows"]),
+        "expert": first + expert, "reference": cfg["reference"]["file"],
+        "routing": route, "routing_inference": route_eval,
+        "tokens_routed_alike_everywhere": float(same.mean()),
+        "logits_err_max": main_max, "logits_err_rms": main_rms,
+        "train_loss": [got["loss"], ref["loss"]],
+        "train_loss_err": _rel(got["loss"], ref["loss"]),
+        "global_grad_norm": [got["gnorm"], ref["gnorm"]],
+        "global_grad_norm_err": _rel(got["gnorm"], ref["gnorm"]),
+        "clip_scale": got["scale"],
+        "clip_scale_err": _rel(got["scale"], min(
+            1.0, o["clip_global_norm"] / got["gnorm"])),
+        "by_param": by_param,
+        "limits": {"early_route_same_min": EARLY_ROUTE_SAME_MIN,
+                   "routing_margin": ROUTING_MARGIN,
+                   "routing_flip_max": ROUTING_FLIP_MAX,
+                   "logits": LOGITS_TOL, "logits_rms": LOGITS_RMS_TOL,
+                   "loss": LOSS_TOL, "grad_by_kind": GRAD_LIMITS,
+                   "grad_else": GRAD_LIMITS_ELSE,
+                   "global_grad_norm": GLOBAL_NORM_TOL,
+                   "update": UPDATE_TOL, "clip_scale": CLIP_SCALE_TOL,
+                   "attention": ATTENTION_TOL,
+                   "attention_rms": ATTENTION_RMS_TOL,
+                   "norm_scale": NORM_SCALE_TOL},
+    }
+    worst = {k: [f(v[k] for v in by_param.values() if v[k] is not None)
+                 for f in (min, max)]
+             for k in ("grad_cos", "grad_norm_ratio", "update_err")}
+    report["worst"] = worst
+    held = {
+        "attention": len(attention) == len(FIRST_HAND) and all(
+            np.isfinite(mx) and mx <= ATTENTION_TOL
+            and rms <= ATTENTION_RMS_TOL for mx, rms in attention.values())
+        and band[W] < min(band[W - 1], band[W + 1]),
+        "norms": len(inputs) == len(FIRST_HAND) and all(
+            np.isfinite(scale) and scale <= NORM_SCALE_TOL[i]
+            for i, (_, scale) in zip(FIRST_HAND, inputs.values())),
+        "early_route": min(early) >= EARLY_ROUTE_SAME_MIN,
+        "routing": all(
+            r["ok"] and r["flipped_share"] <= ROUTING_FLIP_MAX
+            for r in route[1:] + route_eval[1:]),
+        "logits": bool(np.isfinite(main_max) and main_max <= LOGITS_TOL
+                       and main_rms <= LOGITS_RMS_TOL),
+        "loss": report["train_loss_err"] <= LOSS_TOL,
+        "global_grad_norm": report["global_grad_norm_err"]
+        <= GLOBAL_NORM_TOL,
+        "clip_scale": report["clip_scale_err"] <= CLIP_SCALE_TOL,
+        "gradients": all(v["grad_ok"] for v in by_param.values()),
+        "update": worst["update_err"][1] <= UPDATE_TOL,
+        "product_rows": len(rows) == len(got["ids_eval"])
+        and all(w == h == c for w, h, c in rows),
+        "router_bias": len(bias_moved) == len(got["ids"])
+        and all(bias_moved),
+    }
+    if timed is not None:
+        held["timed_steps"] = len(steps.get("err", ())) == 2 and all(
+            np.isfinite(e) and e <= LOSS_TOL for e in steps["err"])
+    report["failed"] = sorted(k for k, v in held.items() if not v)
+    report["ok"] = not report["failed"]
+    return report
+
+
+def against_reference(fluid, cfg, builder, place, seed, tokens, labels,
+                      timed=None):
+    """`tokens`, `labels`: int32 [2 x rows, S], the rows of the cell's own
+    steps 0 and 1; `timed`: as `judge` takes it. Returns a report with
+    `ok` and every number. The caller has freed the timed program's scope;
+    the system's scope here is freed before the reference runs."""
+    import jax
+
+    t0 = time.perf_counter()
+    rows = int(cfg["reference"]["rows"])
+    got = system_side(fluid, cfg, builder, place, seed, tokens[:rows],
+                      labels[:rows])
+    gc.collect()
+    ref = reference_side(cfg, builder, got["w0"], tokens, labels,
+                         [u for u, _ in got["attention"]])
+    report = judge(cfg, builder, got, ref, timed)
+    report["device_peak_bytes"] = int(memory_peak(jax.local_devices()))
+    report["seconds"] = time.perf_counter() - t0
+    return report

@@ -59,10 +59,13 @@ WHITE_LIST = frozenset({
 
 # Input slots of a white-list op that are NOT cast down: moe_ffn's router
 # weight (router matmul, softmax and top-k run in float32: a bf16 logit
-# flips the discrete choice between near-tied experts) and the incoming
+# flips the discrete choice between near-tied experts), the rows the router
+# reads where the program names them apart from the experts' input (they
+# arrive as they are: float32 embedding rows stay float32) and the incoming
 # gradients of its two float32 scalar losses.
 FLOAT32_SLOTS = {
-    "moe_ffn": frozenset({"Router", "Bias", "AuxLoss@GRAD", "ZLoss@GRAD"}),
+    "moe_ffn": frozenset({"Router", "RouterInput", "Bias", "AuxLoss@GRAD",
+                          "ZLoss@GRAD"}),
     # the mixers' parameters and the per-token mixing matrices they make
     "mhc_mix": frozenset({"PhiPre", "PhiPost", "PhiRes", "Alpha", "BPre",
                           "BPost", "BRes", "HPost@GRAD", "HRes@GRAD"}),

@@ -699,16 +699,24 @@ def causal_attention(q, k, v, scale=None, window=None, name=None):
 def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
             gate_attr=None, up_attr=None, down_attr=None, name=None,
             score_func="softmax", norm_topk=False, routed_scale=1.0,
-            bias_attr=None, held=None):
-    """A layer of `num_experts` SwiGLU experts of width `expert_size` on
+            bias_attr=None, held=None, router_input=None, activation="silu"):
+    """A layer of `num_experts` gated experts of width `expert_size` on
     tokens [T, H], each token through its `top_k` by router score,
     grouped matmuls over the rows really routed. The defaults are OLMoE's
-    (softmax scores, not renormalised); `score_func` "sigmoid",
-    `norm_topk`, `routed_scale` and `bias_attr` (a [num_experts] bias
-    added to the scores for the choice alone, persistable, not trained)
-    are the `noaux_tc` router's. `held` = (first expert, count): the layer
-    holds those experts' weights only, one share of a layer whose experts
-    lie on several chips; the router keeps `num_experts` outputs.
+    (softmax scores, not renormalised, SwiGLU experts, the router reading
+    `input`); `score_func` "sigmoid", `norm_topk`, `routed_scale` and
+    `bias_attr` (a [num_experts] bias for the choice alone, persistable,
+    not trained; added to sigmoid scores, and to the LOGITS of softmax
+    scores) are the `noaux_tc` router's. `held` = (first expert, count):
+    the layer holds those experts' weights only, one share of a layer whose
+    experts lie on several chips; the router keeps `num_experts` outputs.
+    `router_input` [T, H]: the variable the router scores where that is not
+    `input` (a router placed before attention reads the layer's input while
+    the experts read the normed state after it): the op gets it as
+    `RouterInput`, the router's gradient flows to it alone and the experts'
+    to `input` alone; None (or `input` itself) appends the op as it has
+    always been. `activation` "silu" | "relu": what gates an expert,
+    act(x Gate_e) * (x Up_e), in the grouped kernels' epilogues.
     Returns (out, load-balance loss [1], router z-loss [1], expert ids
     [T, top_k], tokens per expert [num_experts]) and, with `held`, the
     rows the held experts received [1]."""
@@ -726,6 +734,13 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
     inputs = {"X": [input], "Router": [router], "Gate": [gate], "Up": [up],
               "Down": [down]}
     attrs = {"top_k": int(top_k)}
+    if router_input is not None and router_input is not input:
+        inputs["RouterInput"] = [router_input]
+    if activation not in ("silu", "relu"):
+        raise ValueError(f"moe_ffn: activation {activation!r} is neither "
+                         "'silu' nor 'relu'")
+    if activation != "silu":
+        attrs["activation"] = activation
     if bias_attr is not None:
         bias_attr = ParamAttr.to_attr(bias_attr)
         bias_attr.trainable = False

@@ -11,7 +11,11 @@ multi-token-prediction module, as one chip's share of each layer):
 `xing4.optimizer`; and `laguna` (window and full attention layers of
 different head counts, grouped-query heads, per-head output gates, held
 and shared experts, as one chip's share): `laguna.laguna(tokens, cfg)`,
-`laguna.laguna_loss`, `laguna.optimizer`.
+`laguna.laguna_loss`, `laguna.optimizer`; and `smallthinker` (every layer
+sparse with ReLU-gated experts routed by the layer's INPUT, before
+attention; full attention layers without positions beside sliding-window
+layers with rotary; as one chip's share): `smallthinker.smallthinker(tokens,
+cfg)`, `smallthinker.smallthinker_loss`, `smallthinker.optimizer`.
 """
 
 from . import mnist
@@ -23,9 +27,10 @@ from . import machine_translation
 from . import olmoe
 from . import xing4
 from . import laguna
+from . import smallthinker
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
-           "machine_translation", "olmoe", "xing4", "laguna"]
+           "machine_translation", "olmoe", "xing4", "laguna", "smallthinker"]
 
 
 def get_model(name):

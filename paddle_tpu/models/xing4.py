@@ -274,15 +274,21 @@ def balance_routers(program, speed):
     fewer than the mean of the step's choices and falls by it for one
     that received more (DeepSeek-V3, arXiv:2412.19437, section 2.1.2:
     b_e += speed * sign(mean load - load_e)). The bias takes no gradient;
-    this is all that moves it. Call after `minimize`, and after the
-    inference clone is taken. float32 throughout: none of these ops is on
-    AMP's white list (`elementwise_sub` is, and would round the counts)."""
+    this is all that moves it. `speed`: one number, or one a router in
+    the program's order (routers whose logits spread differently). Call
+    after `minimize`, and after the inference clone is taken. float32
+    throughout: none of these ops is on AMP's white list
+    (`elementwise_sub` is, and would round the counts)."""
     L = fluid.layers
     block = program.global_block()
     routers = [op for op in block.ops
                if op.type == "moe_ffn" and op.input("Bias")]
+    speeds = list(speed) if isinstance(speed, (list, tuple)) \
+        else [speed] * len(routers)
+    if len(speeds) != len(routers):
+        raise ValueError(f"{len(speeds)} speeds for {len(routers)} routers")
     with fluid.name_scope("router_bias"):
-        for op in routers:
+        for op, speed in zip(routers, speeds):
             bias = block.var(op.input("Bias")[0])
             load = L.cast(block.var(op.output("TokensPerExpert")[0]),
                           "float32")

@@ -349,10 +349,10 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 class Routing:
-    """How `moe_ffn` scores, chooses and weighs, and which experts the
-    layer holds; static, from the op's attributes. The defaults are
-    OLMoE's: softmax scores, the k largest, their scores as weights, every
-    expert held."""
+    """How `moe_ffn` scores, chooses and weighs, which experts the layer
+    holds and what gates them; static, from the op's attributes. The
+    defaults are OLMoE's: softmax scores, the k largest, their scores as
+    weights, every expert held, SiLU on the gate."""
 
     def __init__(self, attrs, n_experts):
         self.top_k = int(attrs.get("top_k", 1))
@@ -362,6 +362,7 @@ class Routing:
         self.first = int(attrs.get("first_expert", 0))
         self.held = int(attrs.get("held_experts", 0)) or n_experts
         self.all_held = self.first == 0 and self.held == n_experts
+        self.activation = attrs.get("activation", "silu")
 
 
 # The even-load share of the rows, times this, bounds the rows a layer
@@ -398,7 +399,8 @@ def moe_ffn(x, router, gate, up, down, top_k):
 
 @jax.named_scope(ROUTE)
 def _route(x, router, bias, r):
-    """The router's part of the layer: (the chosen experts' weights top_p
+    """The router's part of the layer on the rows x it reads (the op's
+    `RouterInput`, `X` where it has none): (the chosen experts' weights top_p
     [T, k], AuxLoss, ZLoss), which carry gradients, and (ExpertIds,
     TokensPerExpert, the rows each held expert received, the sort by held
     expert `order` and its inverse `inv` [T * k]), which do not."""
@@ -416,8 +418,11 @@ def _route(x, router, bias, r):
         top_p, top_e = lax.top_k(probs, top_k)              # [T, k]
     else:
         # chosen by score + bias, weighed by the score alone; the bias is
-        # not trained through the loss
-        _, top_e = lax.top_k(probs + lax.stop_gradient(bias.astype(F32)),
+        # not trained through the loss. Softmax scores lie near 1 / E, where
+        # any usable step of a bias swamps them: there the bias joins the
+        # LOGITS (the order of softmax(logits + b), a softmax prior)
+        chosen_by = logits if r.score_func == "softmax" else probs
+        _, top_e = lax.top_k(chosen_by + lax.stop_gradient(bias.astype(F32)),
                              top_k)
         # the chosen scores by comparison, not `take_along_axis`: a gather
         # of T * k scalars took a v5e 0.67 ms, and 0.76 again in the
@@ -475,7 +480,7 @@ def _experts(x, top_p, gate, up, down, held_counts, order, inv, r, rows,
     with jax.named_scope(DISPATCH):
         xs = _dispatch(x, order, inv, top_k)                # [rows, H]
     ys, a, b = grouped_mlp(xs, gate, up, down, held_counts, products,
-                           not r.all_held)
+                           not r.all_held, r.activation)
     with jax.named_scope(COMBINE):
         if r.all_held:
             y = _unsort(ys, order, inv).reshape(T, top_k, -1)
@@ -499,14 +504,14 @@ def _held_rows_take(r, n_rows, n_experts, held_counts, body):
                     lambda: body(bound), lambda: body(n_rows))
 
 
-def _moe_ffn(x, router, bias, gate, up, down, r):
+def _moe_ffn(x, router, bias, gate, up, down, r, router_x=None):
     """The op's six outputs and the three grouped products' results
     (gate, up, down; rows in expert order) as the op leaves them: where
     the layer has a row bound B below its T * k rows (`row_bound`), gate
     and up of B rows and down of all T * k, zero past the held experts'
-    rows."""
+    rows. `router_x`: the rows the router reads where they are not x."""
     (top_p, aux, z), (top_e, counts, held_counts, order, inv) = _route(
-        x, router, bias, r)
+        x if router_x is None else router_x, router, bias, r)
     n_rows, E = order.shape[0], router.shape[1]
     bound = row_bound(n_rows, r.held, E)
 
@@ -526,6 +531,9 @@ def _moe_ffn(x, router, bias, gate, up, down, r):
 
 _MOE_INPUTS = ("X", "Router", "Bias", "Gate", "Up", "Down")
 _MOE_TRAINED = tuple(s for s in _MOE_INPUTS if s != "Bias")
+# the rows the router reads, where the program names another variable than
+# X for them
+_ROUTER_INPUT = "RouterInput"
 _MOE_PRODUCTS = ("GateOut", "UpOut", "DownOut")
 
 
@@ -537,8 +545,18 @@ def moe_ffn_op(ctx, ins, attrs):
     TokensPerExpert [E], RowsHeld [1]. Attributes, defaults OLMoE's:
     `score_func` softmax | sigmoid over the router's E logits; the chosen
     are the top_k by score, plus Bias [E] where that input is given (it
-    takes no gradient); w is the chosen scores, divided by their sum with
-    `norm_topk`, times `routed_scale`. The layer holds the E' =
+    takes no gradient; beside sigmoid scores it is added to the scores,
+    beside softmax scores to the logits, where a step of usable size does
+    not swamp scores near 1 / E); w is the chosen scores, divided by their
+    sum with `norm_topk`, times `routed_scale`. `activation` silu | relu
+    is what gates an expert: silu(Gate_e x) or relu(Gate_e x) times Up_e x.
+    RouterInput [T, H], where given, is what the router scores INSTEAD of
+    X (a router placed before attention reads the layer's input, the
+    experts the normed state after it): the routing then depends on no
+    output of the ops between the two variables, X's gradient is the
+    experts' alone and RouterInput's the router's alone. Without it the
+    router reads X and X's gradient is the sum, as it has always been.
+    The layer holds the E' =
     `held_experts` experts from `first_expert` on (0: all E): a chosen
     expert it does not hold adds nothing to Out, the router still scores
     all E and TokensPerExpert counts all E; RowsHeld is how many of the T
@@ -569,7 +587,8 @@ def moe_ffn_op(ctx, ins, attrs):
     it computes the products again over all the rows."""
     args = [first(ins, s) for s in _MOE_INPUTS]
     (o, aux, z, ids, counts, rows), products = _moe_ffn(
-        *args, Routing(attrs, args[1].shape[1]))
+        *args, Routing(attrs, args[1].shape[1]),
+        first(ins, _ROUTER_INPUT))
     return out(Out=o, AuxLoss=aux, ZLoss=z, ExpertIds=ids,
                TokensPerExpert=counts, RowsHeld=rows,
                **dict(zip(_MOE_PRODUCTS, products)))
@@ -585,7 +604,8 @@ def _moe_ffn_grad_maker(op, gout, gin):
     vjp evaluates the forward again, XLA does not merge two Mosaic calls,
     and the three forward kernels would run twice a step. The backward op
     takes the products as the forward left them."""
-    inputs = {s: op.input(s) for s in _MOE_INPUTS if op.input(s)}
+    inputs = {s: op.input(s) for s in (*_MOE_INPUTS, _ROUTER_INPUT)
+              if op.input(s)}
     inputs.update({s: op.output(s) for s in _MOE_PRODUCTS if op.output(s)})
     for s in ("Out", "AuxLoss", "ZLoss"):
         if any(gout.get(s) or []):
@@ -605,6 +625,7 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     (the bounded rows from the saved products; after an overflow all the
     rows, the products computed again)."""
     x, router, gate, up, down = (first(ins, s) for s in _MOE_TRAINED)
+    router_x = first(ins, _ROUTER_INPUT)
     r = Routing(attrs, router.shape[1])
     products = tuple(first(ins, s) for s in _MOE_PRODUCTS)
     if any(p is None for p in products):
@@ -613,7 +634,7 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     (top_p, aux, z), route_vjp, (_, _, held_counts, order, inv) = jax.vjp(
         differentiated(
             lambda x, router: _route(x, router, first(ins, "Bias"), r)),
-        x, router, has_aux=True)
+        x if router_x is None else router_x, router, has_aux=True)
     d_o, d_aux, d_z = (
         jnp.zeros_like(o) if g is None else g.astype(o.dtype).reshape(o.shape)
         for o, g in zip((x, aux, z), (first(ins, s + "@GRAD")
@@ -633,10 +654,15 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     d_x, d_top_p, *d_weights = _held_rows_take(
         r, order.shape[0], router.shape[1], held_counts, gradients)
     d_x_routed, d_router = route_vjp((d_top_p, d_aux, d_z))
-    with jax.named_scope(COMBINE):
-        d_x = d_x + d_x_routed
-    return out(**{s + "@GRAD": g for s, g in zip(
-        _MOE_TRAINED, (d_x, d_router, *d_weights))})
+    grads = dict(zip(_MOE_TRAINED, (d_x, d_router, *d_weights)))
+    if router_x is None:
+        with jax.named_scope(COMBINE):
+            grads["X"] = d_x + d_x_routed
+    else:
+        # the router's term goes to the variable the router read, the
+        # experts' alone to X
+        grads[_ROUTER_INPUT] = d_x_routed
+    return out(**{s + "@GRAD": g for s, g in grads.items()})
 
 
 # ------------------------------------------------------- mhc_mix, mhc_update
@@ -941,7 +967,11 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
              _has_head_groups),
             ("moe_ffn", "moe_ffn_row_bound", False, _has_row_bound),
             ("moe_ffn", "moe_ffn_kept_copies", False,
-             lambda op, block: amp.reads_kept_copies(op)))
+             lambda op, block: amp.reads_kept_copies(op)),
+            ("moe_ffn", "moe_ffn_router_input", False,
+             lambda op, block: bool(op.input(_ROUTER_INPUT))),
+            ("moe_ffn", "moe_ffn_relu", False,
+             lambda op, block: op.attrs.get("activation") == "relu"))
 
 
 def lowered_counts(program, device):
@@ -955,7 +985,9 @@ def lowered_counts(program, device):
     where that share gives them a row bound below top_k x tokens:
     `row_bound`; those all three of whose expert weights the step reads
     from the low-precision copies their updates keep, not from a cast of
-    the float32 masters, as `moe_ffn_kept_copies`: `amp.kept_copy`) and,
+    the float32 masters, as `moe_ffn_kept_copies`: `amp.kept_copy`; those
+    whose router reads another variable than `X` as `moe_ffn_router_input`,
+    those whose experts are gated by ReLU as `moe_ffn_relu`) and,
     on a TPU place, its
     `causal_attention` ops (each lowers through the flash kernel; those
     with a window count as `flash_attention_window` too, those whose K has

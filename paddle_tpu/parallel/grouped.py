@@ -58,6 +58,7 @@ maps read it, so consecutive visits of one group find that group's weight
 tile already in VMEM.
 """
 
+import contextlib
 import functools
 
 import jax
@@ -182,6 +183,17 @@ def _silu_mul_grad(p, a, b):
     return p * b * (s * (1.0 + a * (1.0 - s))), p * (a * s)
 
 
+def _relu_mul(p, a):
+    """`_silu_mul` for experts gated by ReLU: b = p, h = relu(a) * p."""
+    return p, jnp.maximum(a, 0.0) * p
+
+
+def _relu_mul_grad(p, a, b):
+    """`_silu_mul_grad` for ReLU, p = d h: d a = p * b where a > 0, d b =
+    p * relu(a). No transcendental."""
+    return jnp.where(a > 0.0, p * b, 0.0), p * jnp.maximum(a, 0.0)
+
+
 def _add(p, existing):
     return (existing + p,)
 
@@ -189,7 +201,20 @@ def _add(p, existing):
 # epilogues of `_gmm`: what is stored from the float32 product tile p and
 # the side tiles (widened to float32), and how many tiles of each
 _EPILOGUES = {None: (lambda p: (p,), 0, 1), "silu_mul": (_silu_mul, 1, 2),
-              "silu_mul_grad": (_silu_mul_grad, 2, 2), "add": (_add, 1, 1)}
+              "silu_mul_grad": (_silu_mul_grad, 2, 2), "add": (_add, 1, 1),
+              "relu_mul": (_relu_mul, 1, 2),
+              "relu_mul_grad": (_relu_mul_grad, 2, 2)}
+# what gates an expert (`grouped_mlp`'s `activation`), on float32 tiles
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": lambda a: jnp.maximum(a, 0.0)}
+
+
+def _named(epilogue):
+    """The scope a kernel with this epilogue is called under: ReLU's name
+    is on the op_name's path (`.../grouped/relu_mul/grouped_matmul`);
+    SiLU's, the kernels' first, stand where they have always stood."""
+    if epilogue in ("relu_mul", "relu_mul_grad"):
+        return jax.named_scope(epilogue)
+    return contextlib.nullcontext()
 
 
 def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, *refs, tm, tn,
@@ -240,8 +265,9 @@ def _gmm_kernel(offsets, group_ids, tile_ids, lhs, rhs, *refs, tm, tn,
 
 def _gmm(lhs, rhs, meta, n_visits, tiles, transpose_rhs, epilogue=None,
          sides=()):
-    return _gmm_call(lhs, rhs, meta, n_visits, sides, tiles, transpose_rhs,
-                     epilogue, pallas_interpret())
+    with _named(epilogue):
+        return _gmm_call(lhs, rhs, meta, n_visits, sides, tiles,
+                         transpose_rhs, epilogue, pallas_interpret())
 
 
 # The kernel calls stand under an inlined `jit`: a call is traced once for
@@ -260,8 +286,9 @@ def _gmm_call(lhs, rhs, meta, n_visits, sides, tiles, transpose_rhs,
     read by row tiles as the out is written: "silu_mul" (a) -> (the
     product, silu(a) * product); "silu_mul_grad" (a, b) -> (d a, d b) of
     silu(a) * b, the product being its cotangent; "add" (existing) ->
-    existing + product, written over `existing` in place. All taken on the
-    float32 product and rounded once."""
+    existing + product, written over `existing` in place; "relu_mul" and
+    "relu_mul_grad" the same two for relu(a) * b. All taken on the float32
+    product and rounded once."""
     tm, tk, tn = tiles
     (N, K), E = lhs.shape, rhs.shape[0]
     M = rhs.shape[1] if transpose_rhs else rhs.shape[2]
@@ -314,11 +341,12 @@ def _gmm_call(lhs, rhs, meta, n_visits, sides, tiles, transpose_rhs,
     return outs[0] if n_outs == 1 else outs
 
 
-def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs):
+def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs,
+                 activation):
     """One visit: the tile's rows of the visit's group, lhs^T times rhs,
     summed in `acc` over the group's visits and written at its last.
-    `refs`: lhs, or the two tiles a, b that lhs = silu(a) * b is formed
-    from here; rhs, out, `acc`."""
+    `refs`: lhs, or the two tiles a, b that lhs = `activation`(a) * b is
+    formed from here; rhs, out, `acc`."""
     *lhs, rhs, out, acc = refs
     visit = pl.program_id(2)
     last = pl.num_programs(2) - 1
@@ -332,7 +360,7 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs):
     def rows_transposed_times_rows(rows, mask_of=None):
         a, b = lhs[0][rows, :], rhs[rows, :]
         if len(lhs) == 2:
-            a = (jax.nn.silu(a.astype(jnp.float32))
+            a = (_ACTIVATIONS[activation](a.astype(jnp.float32))
                  * lhs[1][rows, :].astype(jnp.float32)).astype(a.dtype)
         # rows of other groups zeroed in ONE operand: a zero row of either
         # contributes nothing
@@ -352,21 +380,24 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs):
         out[...] = acc[...].astype(out.dtype)
 
 
-def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None):
+def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None,
+          activation="silu"):
     lhs = lhs if isinstance(lhs, tuple) else (lhs,)
-    return _tgmm_call(lhs, rhs, meta, n_visits, tiles, jnp.dtype(out_dtype),
-                      mask_lhs, pallas_interpret())
+    with _named(activation + "_mul" if len(lhs) == 2 else None):
+        return _tgmm_call(lhs, rhs, meta, n_visits, tiles,
+                          jnp.dtype(out_dtype), mask_lhs, pallas_interpret(),
+                          activation)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7), inline=True)
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8), inline=True)
 def _tgmm_call(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs,
-               interpret):
+               interpret, activation):
     """[N, K], [N, M] -> [E, K, M]: group g's rows of lhs, transposed,
-    times its rows of rhs. `lhs` a pair (a, b): lhs is silu(a) * b, formed
-    from the two tiles in VMEM. `mask_lhs`: which operand has the rows of
-    other groups zeroed in a tile a boundary crosses; the narrower tile
-    unless said (rows past the groups must be FINITE in the other one: a
-    zero row times a NaN is a NaN)."""
+    times its rows of rhs. `lhs` a pair (a, b): lhs is `activation`(a) *
+    b, formed from the two tiles in VMEM. `mask_lhs`: which operand has the
+    rows of other groups zeroed in a tile a boundary crosses; the narrower
+    tile unless said (rows past the groups must be FINITE in the other
+    one: a zero row times a NaN is a NaN)."""
     tm, tk, tn = tiles
     (N, K), M = lhs[0].shape, rhs.shape[1]
     E = meta[0].shape[0] - 1
@@ -385,7 +416,7 @@ def _tgmm_call(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs,
     return pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=tm, tk=tk, tn=tn,
                           mask_lhs=tk <= tn if mask_lhs is None
-                          else mask_lhs),
+                          else mask_lhs, activation=activation),
         out_shape=jax.ShapeDtypeStruct((E, K, M), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -399,7 +430,8 @@ def _tgmm_call(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs,
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=2 * N * K * M,
-            transcendentals=N * K * tiles_n if len(lhs) == 2 else 0,
+            transcendentals=N * K * tiles_n
+            if len(lhs) == 2 and activation == "silu" else 0,
             bytes_accessed=item * (
                 N * K * tiles_n * len(lhs) + N * M * tiles_k)
             + E * K * M * jnp.dtype(out_dtype).itemsize),
@@ -580,12 +612,15 @@ def mlp_takes(n_rows, h, f):
     return True
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _mlp(xs, gate, up, down, counts, saved, tiles, rows_past):
-    return _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _mlp(xs, gate, up, down, counts, saved, tiles, rows_past,
+         activation="silu"):
+    return _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past,
+                    activation)[0]
 
 
-def _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past):
+def _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past,
+             activation):
     """-> (ys, a, b); nine kernels a step with `_mlp_bwd`, and between
     them no element-wise pass over [N, F] or [N, H] in HBM but the zeroing
     of ys and d xs under `rows_past`."""
@@ -594,22 +629,22 @@ def _mlp_fwd(xs, gate, up, down, counts, saved, tiles, rows_past):
         with jax.named_scope(_SCOPE):
             meta, n = visits(counts, xs.shape[0], tm, False)
             a = _gmm(xs, gate, meta, n, (tm,) + up_fwd, False)
-            b, h = _gmm(xs, up, meta, n, (tm,) + up_fwd, False, "silu_mul",
-                        (a,))
+            b, h = _gmm(xs, up, meta, n, (tm,) + up_fwd, False,
+                        activation + "_mul", (a,))
             ys = _gmm(h, down, meta, n, (tm,) + down_fwd, False)
             saved = (a, b, _rows_of_groups(ys, counts) if rows_past else ys)
     a, b, ys = saved
     return (ys, a, b), (xs, gate, up, down, counts, a, b)
 
 
-def _mlp_bwd(tiles, rows_past, res, cots):
+def _mlp_bwd(tiles, rows_past, activation, res, cots):
     xs, gate, up, down, counts, a, b = res
     (tm, _, up_dlhs, up_drhs), (_, _, down_dlhs, down_drhs) = tiles
     d_ys = cots[0].astype(xs.dtype)
     with jax.named_scope(_SCOPE):
         meta, n = visits(counts, xs.shape[0], tm, False)
         d_a, d_b = _gmm(d_ys, down, meta, n, (tm,) + down_dlhs, True,
-                        "silu_mul_grad", (a, b))
+                        activation + "_mul_grad", (a, b))
         d_xs = _gmm(d_a, gate, meta, n, (tm,) + up_dlhs, True)
         d_xs = _gmm(d_b, up, meta, n, (tm,) + up_dlhs, True, "add",
                     (d_xs,))
@@ -619,7 +654,7 @@ def _mlp_bwd(tiles, rows_past, res, cots):
         # the operand masked in a boundary tile is the one whose rows past
         # the groups no kernel wrote
         d_down = _tgmm((a, b), d_ys, meta, n, (tm,) + down_drhs,
-                       down.dtype, mask_lhs=True)
+                       down.dtype, mask_lhs=True, activation=activation)
         d_gate = _tgmm(xs, d_a, meta, n, (tm,) + up_drhs, gate.dtype,
                        mask_lhs=False)
         d_up = _tgmm(xs, d_b, meta, n, (tm,) + up_drhs, up.dtype,
@@ -630,16 +665,20 @@ def _mlp_bwd(tiles, rows_past, res, cots):
 _mlp.defvjp(_mlp_fwd, _mlp_bwd)
 
 
-def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False):
+def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False,
+                activation="silu"):
     """A sparse-expert layer's MLP over rows sorted by group: xs [N, H],
     gate / up [E, H, F], down [E, F, H], counts [E] -> (ys [N, H], a, b
     [N, F]):
 
-        a = xs @ gate[g]   b = xs @ up[g]   ys = (silu(a) * b) @ down[g]
+        a = xs @ gate[g]   b = xs @ up[g]   ys = (act(a) * b) @ down[g]
 
-    `saved`: (a, b, ys) as an earlier call left them; they are returned as
-    they are and the call only carries the gradients. `counts`,
-    `rows_past`: as `grouped_dot` takes them.
+    with act = `activation`, "silu" or "relu" (below the kernels are
+    written for silu: relu's run the `relu_mul` / `relu_mul_grad`
+    epilogues in the same places, under a scope of that name). `saved`:
+    (a, b, ys) as an earlier call left them; they are returned as they are
+    and the call only carries the gradients. `counts`, `rows_past`: as
+    `grouped_dot` takes them.
 
     Off a TPU place, and for shapes `mlp_takes` refuses, this IS three
     `grouped_dot`s and `jax.nn.silu`. On a TPU place it is one
@@ -666,9 +705,9 @@ def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False):
         tiles = (tiles_for(xs.shape[0], H, F, xs.dtype),
                  tiles_for(xs.shape[0], F, H, xs.dtype))
         return _mlp(xs, gate, up, down, counts, saved, tiles,
-                    bool(rows_past))
+                    bool(rows_past), activation)
     saved = saved or (None, None, None)
     a = grouped_dot(xs, gate, counts, saved[0], rows_past)
     b = grouped_dot(xs, up, counts, saved[1], rows_past)
-    return grouped_dot(jax.nn.silu(a) * b, down, counts, saved[2],
-                       rows_past), a, b
+    return grouped_dot(_ACTIVATIONS[activation](a) * b, down, counts,
+                       saved[2], rows_past), a, b

@@ -239,6 +239,36 @@ def test_band_and_head_groups_match_the_plain_composition(window, heads, S,
                                    rtol=1e-3)
 
 
+@pytest.mark.parametrize("which", ["forward", "dkv", "dq"])
+@pytest.mark.parametrize("window", [128, 130, None],
+                         ids=["half_the_row", "four_blocks_and_two", "w_none"])
+def test_a_wide_band_and_a_group_of_seven(window, which):
+    """The `smallthinker_21b_a3b` cell's regime at a small size: a band of
+    half the row (4 key blocks of 32 a query block, of which the inner ones
+    carry no mask at all, where a band of one block has a mask on both),
+    7 query heads on one key/value head, and the same heads over the whole
+    triangle (its full layers)."""
+    q, k, v, cot = _band_case(256, 7, 1, seed=5)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                               window=window)
+
+    def plain(q, k, v):
+        return _dense_band(q, k, v, window)
+
+    if which == "forward":
+        got, want = [flash(q, k, v)], [plain(q, k, v)]
+    else:
+        got, want = (_grads(f, q, k, v, cot) for f in (flash, plain))
+        pick = slice(1, 3) if which == "dkv" else slice(0, 1)
+        got, want = got[pick], want[pick]
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-3)
+
+
 @pytest.mark.parametrize("blocks", [(32, 64), (64, 32), (16, 32)])
 @pytest.mark.parametrize("window", [24, 40])
 def test_band_with_unequal_blocks(window, blocks):
@@ -342,7 +372,7 @@ def test_without_window_and_group_the_grid_and_index_maps_are_as_before(
 @pytest.mark.parametrize("S,block,window,want", [
     (8192, 1024, None, 36), (8192, 1024, 512, 15), (8192, 512, 512, 31),
     (8192, 256, 512, 93), (8192, 128, 512, 310), (100, 32, 8, 7),
-    (100, 32, 1000, 10)])
+    (100, 32, 1000, 10), (8192, 1024, 4096, 30), (8192, 512, 4096, 108)])
 def test_blocks_visited_counts_the_band(S, block, window, want):
     """The count the window kernels' grids are held to: the steps that the
     kernels' own `_for_block` predicate lets compute."""

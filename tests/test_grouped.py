@@ -161,6 +161,9 @@ def test_a_given_mlp_is_not_computed_again(monkeypatch):
     (16384, 7168, 1024, "bfloat16", "kernels"),     # 14 KiB a row: split
     (65536, 2048, 512, "bfloat16", "kernels"),      # experts of width 512:
     (65536, 512, 2048, "bfloat16", "kernels"),      # gate / up, and down
+    (24576, 2560, 768, "bfloat16", "kernels"),      # K 2560 / F 768, the
+    (24576, 768, 2560, "bfloat16", "kernels"),      # row bound's rows
+    (49152, 2560, 768, "float32", "kernels"),       # 10 KiB a row: split
     (65536 + 128, 2048, 1024, "bfloat16", "kernels"),
     (65536 + 64, 2048, 1024, "bfloat16", None),     # rows no tile divides
     (65536, 2048 + 64, 1024, "bfloat16", None),     # K not of 128
@@ -832,3 +835,179 @@ def test_width_512_takes_the_kernels_with_their_epilogues():
             512, (2048, 512), (512, 2048), (2048, 512))
     assert grouped.tiles_for(65536, 512, 2048, "bfloat16") == (
         512, (512, 2048), (2048, 512), (512, 2048))
+
+
+# ------------------------------------------------- experts gated by ReLU
+def _relu_composition(xs, gate, up, down, counts, rows_past):
+    a = grouped.grouped_dot(xs, gate, counts, None, rows_past)
+    b = grouped.grouped_dot(xs, up, counts, None, rows_past)
+    return grouped.grouped_dot(jnp.maximum(a, 0) * b, down, counts, None,
+                               rows_past), a, b
+
+
+@functools.lru_cache(maxsize=None)
+def _relu_mlp_both(layout, dtype, whole_k):
+    """`_mlp_both` with `activation="relu"`: the `relu_mul` /
+    `relu_mul_grad` epilogues and the relu(a) * b tile in front of d
+    down, interpreted, against three `grouped_dot`s and `jnp.maximum`."""
+    counts = jnp.asarray(MLP_LAYOUTS[layout], jnp.int32)
+    past = layout.startswith("past_")
+    xs, gate, up, down, g = _mlp_operands(len(counts), dtype, len(layout))
+    t = (256, 256) if whole_k else (128, 128)
+    tiles = ((TM, t, t, t),) * 2
+    results = []
+    for fn in (lambda *w: grouped._mlp(*w, counts, None, tiles, past,
+                                       "relu")[0],
+               lambda *w: _relu_composition(*w, counts, past)[0]):
+        out, vjp = jax.vjp(fn, xs, gate, up, down)
+        results.append([np.asarray(x, np.float32) for x in (out, *vjp(g))])
+    return results
+
+
+RELU_CASES = [("empty_experts_first_middle_last", "float32", True),
+              ("boundaries_inside_tiles", "float32", False),
+              ("past_groups_end_inside_a_tile", "float32", True),
+              ("empty_experts_first_middle_last", "bfloat16", True)]
+
+
+@pytest.mark.parametrize("which", MLP_OUTPUTS)
+@pytest.mark.parametrize(
+    "layout,dtype,whole_k", RELU_CASES,
+    ids=["-".join((c[0], c[1], "whole_k" if c[2] else "split_k"))
+         for c in RELU_CASES])
+def test_relu_mlp_matches_the_composition(layout, dtype, whole_k, which):
+    """ReLU-gated experts through the same nine kernels: ys and all four
+    gradients of three `grouped_dot`s + relu, at ragged group lists with
+    empty groups, with boundaries inside tiles and with groups that end
+    before the rows do; and it is another function than SiLU's."""
+    i = MLP_OUTPUTS.index(which)
+    ours, ref = (r[i] for r in _relu_mlp_both(layout, dtype, whole_k))
+    assert ours.shape == ref.shape and np.all(np.isfinite(ours))
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    assert np.max(np.abs(ours - ref)) <= tol * max(np.max(np.abs(ref)), 1e-3)
+    if layout.startswith("past_") and which in ("ys", "d_xs"):
+        assert not ours[sum(MLP_LAYOUTS[layout]):].any()
+    if layout in MLP_LAYOUTS and (layout, dtype, whole_k) in MLP_CASES:
+        silu = _mlp_both(layout, dtype, whole_k)[0][i]
+        assert np.max(np.abs(silu - ours)) > 1e-2 * np.max(np.abs(ours))
+
+
+@pytest.mark.parametrize("which", MLP_OUTPUTS)
+def test_relu_mlp_at_k_2560_f_768_tiles(which):
+    """The `smallthinker_21b_a3b` cell's widths with the tiles `tiles_for`
+    gives them in bf16 (K 2560 and F 768 each whole in one tile), over a
+    ragged group list with an empty group, `rows_past` as the cell has it
+    (16 of 64 experts held)."""
+    i = MLP_OUTPUTS.index(which)
+    ours, ref = (r[i] for r in _relu_at_the_cell_s_widths())
+    assert ours.shape == ref.shape and np.all(np.isfinite(ours))
+    assert np.max(np.abs(ours - ref)) <= 2e-2 * max(np.max(np.abs(ref)), 1e-3)
+    if which in ("ys", "d_xs"):
+        assert not ours[200:].any() and ours[:200].any()
+    if which in ("d_gate", "d_up", "d_down"):
+        assert not ours[1].any() and ours[0].any()
+
+
+@functools.lru_cache(maxsize=None)
+def _relu_at_the_cell_s_widths():
+    h, f, n = 2560, 768, 256
+    counts = jnp.asarray([90, 0, 110], jnp.int32)     # 200 of the 256 rows
+    rs = np.random.default_rng(7)
+    xs, gate, up, down, g = (
+        jnp.asarray(rs.standard_normal(shape) * scale, jnp.bfloat16)
+        for shape, scale in (((n, h), 1.0), ((3, h, f), h ** -0.5),
+                             ((3, h, f), h ** -0.5), ((3, f, h), f ** -0.5),
+                             ((n, h), 1.0)))
+    tiles = (grouped.tiles_for(n, h, f, "bfloat16"),
+             grouped.tiles_for(n, f, h, "bfloat16"))
+    assert tiles[0] == (256, (2560, 768), (768, 2560), (2560, 768))
+    assert tiles[1] == (256, (768, 2560), (2560, 768), (768, 2560))
+    results = []
+    for fn in (lambda *w: grouped._mlp(*w, counts, None, tiles, True,
+                                       "relu")[0],
+               lambda *w: _relu_composition(*w, counts, True)[0]):
+        out, vjp = jax.vjp(fn, xs, gate, up, down)
+        results.append([np.asarray(x, np.float32) for x in (out, *vjp(g))])
+    return results
+
+
+def test_k_2560_f_768_takes_the_kernels_with_their_epilogues():
+    """[24576, 2560] x [16, 2560, 768] and back (the row bound's rows of
+    the `smallthinker_21b_a3b` cell) and the overflow's [49152, .]: whole
+    contractions in bf16, the longest row tile, inside the VMEM limit."""
+    for rows in (24576, 49152):
+        assert grouped.mlp_takes(rows, 2560, 768)
+        assert grouped.tiles_for(rows, 2560, 768, "bfloat16") == (
+            512, (2560, 768), (768, 2560), (2560, 768))
+        assert grouped.tiles_for(rows, 768, 2560, "bfloat16") == (
+            512, (768, 2560), (2560, 768), (768, 2560))
+    assert max(grouped._vmem_bytes(512, 2560, 768, 2, 2, 2),
+               grouped._vmem_bytes(512, 768, 2560, 2, 1, 1)) \
+        < grouped._VMEM_LIMIT // 2
+
+
+def test_relu_epilogues_hold_no_transcendental():
+    """What changes in the kernels' cost estimate: a ReLU gate has no
+    exponential. (That the epilogue's name stands on the kernels' op_name
+    is held where op_names exist: tests/test_tpu_compile.py.)"""
+    counts = jnp.asarray([100, 0, 156], jnp.int32)
+    xs, gate, up, down, _ = _mlp_operands(3, "float32")
+    tiles = ((TM, (256, 256), (256, 256), (256, 256)),) * 2
+
+    def text(activation):
+        return str(jax.make_jaxpr(lambda *w: jax.vjp(
+            lambda *v: grouped._mlp(*v, counts, None, tiles, False,
+                                    activation)[0], *w)[1](w[0]))(
+                xs, gate, up, down))
+
+    relu, silu = text("relu"), text("silu")
+    assert "logistic" not in relu and "logistic" in silu
+
+
+@pytest.mark.parametrize("model", ["olmoe", "xing4", "laguna"])
+def test_defaults_lower_to_the_text_they_lowered_to(model):
+    """`moe_ffn` as each accepted model builds it (no `router_input`, no
+    `activation`): the op has no `RouterInput` slot and no `activation`
+    attribute, and its lowering, forward and backward, is text for text
+    that of the op given `activation="silu"` outright and that of a
+    router told to read X itself: nothing of the new paths is traced."""
+    from paddle_tpu.ops import lm_ops
+
+    attrs = {"olmoe": {"top_k": 2},
+             "xing4": {"top_k": 2, "score_func": "sigmoid",
+                       "norm_topk": True, "routed_scale": 2.5,
+                       "first_expert": 2, "held_experts": 2},
+             "laguna": {"top_k": 2, "score_func": "sigmoid",
+                        "norm_topk": True, "routed_scale": 2.5,
+                        "first_expert": 4, "held_experts": 4}}[model]
+    x, router, gate, up, down = _moe_operands(64, 32, 16, 8, "float32")
+    held = attrs.get("held_experts", 8)
+    ins = {"X": [x], "Router": [router], "Gate": [gate[:held]],
+           "Up": [up[:held]], "Down": [down[:held]]}
+    if model != "olmoe":
+        ins["Bias"] = [jnp.linspace(-0.1, 0.1, 8)]
+
+    def text(ins, attrs):
+        def both(x, router, gate, up, down):
+            ins_ = dict(ins, X=[x], Router=[router], Gate=[gate], Up=[up],
+                        Down=[down])
+            if "RouterInput" in ins:
+                ins_["RouterInput"] = [x]
+            outs = lm_ops.moe_ffn_op(None, ins_, attrs)
+            grads = lm_ops.moe_ffn_grad_op(None, dict(
+                ins_, **{"Out@GRAD": [x]},
+                **{s: outs[s] for s in ("GateOut", "UpOut", "DownOut")}),
+                attrs)
+            return outs["Out"][0], [grads[s + "@GRAD"][0] for s in (
+                "X", "Router", "Gate", "Up", "Down")]
+
+        return str(jax.make_jaxpr(both)(
+            x, router, ins["Gate"][0], ins["Up"][0], ins["Down"][0]))
+
+    plain = text(ins, attrs)
+    assert text(ins, dict(attrs, activation="silu")) == plain
+    assert text(ins, dict(attrs, activation="relu")) != plain
+    # a RouterInput that is X itself computes the same numbers through
+    # another graph (two gradients where one sum was): the layer never
+    # appends it (tests/test_smallthinker.py), and the text shows why
+    assert text(dict(ins, RouterInput=[x]), attrs) != plain

@@ -728,15 +728,22 @@ def test_olmoe_kernel_counts_are_unchanged(one_chip, no_compile_cache,
 
 @pytest.mark.parametrize("window,heads,blocks", [
     (512, (8, 1), (256, 256)), (512, (8, 1), (512, 512)),
-    (512, (8, 1), (128, 128)), (None, (6, 1), (1024, 1024))],
-    ids=["band_256", "band_512", "band_128", "group_of_6"])
+    (512, (8, 1), (128, 128)), (None, (6, 1), (1024, 1024)),
+    (4096, (7, 1), (1024, 1024)), (4096, (7, 1), (512, 512)),
+    (None, (7, 1), (1024, 1024))],
+    ids=["band_256", "band_512", "band_128", "group_of_6",
+         "wide_band_1024_group_of_7", "wide_band_512_group_of_7",
+         "group_of_7"])
 def test_flash_band_and_head_groups_compile_for_v5e(
         one_chip, no_compile_cache, monkeypatch, window, heads, blocks):
     """Mosaic takes the three kernels at the `laguna_xs_2` cell's shapes:
     a band of 512 over a row of 8192 at each block size swept, 8 query
     heads on one key/value head read in place, and the triangle with 6 on
-    one: a forward, a dK/dV and a dQ custom call, no loop, and no copy of
-    K or V the size of the query heads'."""
+    one; and at the `smallthinker_21b_a3b` cell's: a band of 4096 (4-5 key
+    blocks a query block at 1024 x 1024, of which the inner ones carry no
+    mask; 8-9 at 512 x 512) and the triangle, 7 query heads on one
+    key/value head: a forward, a dK/dV and a dQ custom call, no loop, and
+    no copy of K or V the size of the query heads'."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.parallel import flash
@@ -819,3 +826,62 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
     assert _expert_weights_come_cast_from_their_update(
         compiled, "laguna_xs_2",
         {(32, 2048, 512): 8, (32, 512, 2048): 4}) < 16.9e9
+
+
+def test_smallthinker_step_routes_early_and_gates_by_relu(
+        one_chip, no_compile_cache, monkeypatch):
+    """The `smallthinker_21b_a3b` step at 1 x 8192 tokens (4 layers, each
+    sparse) compiles for one v5e chip with the flash kernels at 7 query
+    heads on the one key/value head held (a forward, dK/dV and dQ a
+    layer: one full layer, three with a band of 4096) and the grouped
+    kernels over the 16 held groups at K 2560 / F 768: nine a layer in
+    the branch that works on the row bound's 24,576 of the 49,152 choice
+    rows and twelve in the overflow branch; the kernels that carry ReLU
+    stand under `relu_mul` / `relu_mul_grad` on their op_name (the up
+    product and d down's lhs, the d h product), and nothing under a
+    `silu` name; no XLA `ragged-dot`; the step reads kept bf16 copies of
+    the twelve expert matrices; and it fits the chip with room: arguments
+    + temporaries + code under 15.5 GB."""
+    cfg, compiled = _lm_step(
+        one_chip, monkeypatch, "smallthinker_21b_a3b", 1,
+        lambda built: [built["routing"][0][1].name]
+        + [r[2].name for r in built["routing"]])
+    text = compiled.as_text()
+    assert _custom_calls(text) == (
+        ["flash_dkv"] * 4 + ["flash_dq"] * 4 + ["flash_fwd"] * 4
+        + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
+        + ["grouped_matmul_tn"] * 24)
+    assert ragged_dots(text) == []
+    S, k = cfg["sequence_length"], cfg["moe_num_active_primary_experts"]
+    _bounded_branches_move_the_bound_s_rows(text, S * k, 24576, 2560, 4)
+    kernels = [re.search(r'op_name="([^"]*)"', ln).group(1)
+               for ln in text.splitlines()
+               if re.match(r"\s*%grouped_matmul", ln)]
+    assert len(kernels) == 84
+
+    def under(scope):
+        return sum(("/" + scope + "/") in name for name in kernels)
+
+    # per layer: forward up (bounded, overflow, and the overflow's
+    # backward computing the products again) 3; d down's lhs formed from a
+    # and b 2; the d h product 2
+    assert under("relu_mul") == 4 * 5 and under("relu_mul_grad") == 4 * 2
+    assert not any("silu" in name for name in kernels)
+    assert all("/moe/moe_ffn" in name for name in kernels)
+    flash_ops = {re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
+                 for ln in text.splitlines() if re.match(r"\s*%flash_", ln)}
+    assert flash_ops == {
+        scope + "/" + kernel + "/pallas_call"
+        for scope in ("attn_full", "attn_window") for kernel in (
+            "causal_attention/flash_fwd", "causal_attention_grad/flash_dkv",
+            "causal_attention_grad/flash_dq")}
+    shapes = {tuple(int(d) for d in dims.split(",") if d)
+              for _, dims in _ARRAY.findall(text)}
+    assert (7, S, 128) in shapes and (1, S, 128) in shapes
+    assert (16, 2560, 768) in shapes and (16, 768, 2560) in shapes
+    assert (24576, 768) in shapes and (24576, 2560) in shapes
+    assert [ln for ln in text.splitlines()
+            if "%d,%d]" % (S, S) in ln and "/attn_" in ln] == []
+    assert _expert_weights_come_cast_from_their_update(
+        compiled, "smallthinker_21b_a3b",
+        {(16, 2560, 768): 8, (16, 768, 2560): 4}) < 15.5e9
