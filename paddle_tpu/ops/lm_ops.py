@@ -923,6 +923,19 @@ def _has_head_groups(op, block):
     return q[2] != k[2]
 
 
+def _grad_by_row_tiles(op, block):
+    """A dense `lookup_table_grad` whose rows the row-tile kernel sums
+    (`parallel/row_sum.py: takes`; ids the program leaves open, a batch
+    dimension of -1, are taken to fit; ragged ids are not)."""
+    from ..parallel import row_sum
+
+    w, ids = (block.vars[op.input(s)[0]] for s in ("W", "Ids"))
+    if op.attrs.get("is_sparse", False) or ids.lod_level or len(w.shape) != 2:
+        return False
+    n_ids = math.prod(ids.shape) if min(ids.shape) > 0 else None
+    return row_sum.takes(w.shape[0], w.shape[1], n_ids, w.dtype)
+
+
 def window_blocks(program):
     """(visited, of a full causal grid): score blocks the flash kernels of
     the program's window layers compute a step, forward, dK/dV and dQ,
@@ -971,7 +984,9 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("moe_ffn", "moe_ffn_router_input", False,
              lambda op, block: bool(op.input(_ROUTER_INPUT))),
             ("moe_ffn", "moe_ffn_relu", False,
-             lambda op, block: op.attrs.get("activation") == "relu"))
+             lambda op, block: op.attrs.get("activation") == "relu"),
+            ("lookup_table_grad", "lookup_table_grad_tiled", True,
+             _grad_by_row_tiles))
 
 
 def lowered_counts(program, device):
@@ -988,7 +1003,8 @@ def lowered_counts(program, device):
     the float32 masters, as `moe_ffn_kept_copies`: `amp.kept_copy`; those
     whose router reads another variable than `X` as `moe_ffn_router_input`,
     those whose experts are gated by ReLU as `moe_ffn_relu`) and,
-    on a TPU place, its
+    on a TPU place, its dense `lookup_table_grad` ops whose table the
+    row-tile kernel writes (`lookup_table_grad_tiled`: `row_sum.takes`), its
     `causal_attention` ops (each lowers through the flash kernel; those
     with a window count as `flash_attention_window` too, those whose K has
     fewer heads than their Q as `flash_attention_head_groups`) and

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op, register_grad_maker, SeqTensor
 from ..core.selected_rows import SelectedRows, SparseTable
+from ..parallel import row_sum
 from .util import first, many, out
 
 
@@ -57,9 +58,15 @@ def lookup_table_grad_op(ctx, ins, attrs):
         values = gd.reshape((rows.shape[0],) + gd.shape[idx.ndim:])
         return out(**{"W@GRAD": SelectedRows(rows, values, height)})
     dim = w.shape[1:]
+    rows = gd.reshape((-1,) + gd.shape[idx.ndim:])
+    if (lengths is None and len(dim) == 1 and row_sum.on_tpu()
+            and row_sum.takes(height, dim[0], rows.shape[0], w.dtype)):
+        # a table too large for the chip's fast memory: the rows summed by
+        # id a tile of the table at a time (parallel/row_sum.py)
+        return out(**{"W@GRAD": row_sum.sum_rows_by_id(
+            idx.reshape(-1), rows, height)})
     dense = jnp.zeros((height,) + tuple(dim), gd.dtype)
-    dense = dense.at[idx.reshape(-1)].add(
-        gd.reshape((-1,) + gd.shape[idx.ndim:]))
+    dense = dense.at[idx.reshape(-1)].add(rows)
     return out(**{"W@GRAD": dense.astype(w.dtype)})
 
 

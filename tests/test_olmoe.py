@@ -147,7 +147,9 @@ def test_lowered_counts_by_place():
     """The training program holds one `causal_attention` and its grad op
     a layer: on a TPU place both count as lowered through the flash
     kernels, on any other place neither does; the inference clone has no
-    backward to count."""
+    backward to count. At the published widths the embedding's 412 MB
+    gradient counts as written by the row-tile kernel (PR 38), on a TPU
+    place only; SMALL's table is far under its threshold."""
     from types import SimpleNamespace
 
     from chipbench.configs import olmoe_1b_7b as builder
@@ -176,7 +178,7 @@ def test_lowered_counts_by_place():
     assert lm_ops.lowered_counts(full["prog"], tpu) == {
         "moe_ffn_grouped": 1, "grouped_matmul_kernel": 1,
         "grouped_mlp_epilogues": 1, "flash_attention": 1,
-        "flash_attention_bwd": 1}
+        "flash_attention_bwd": 1, "lookup_table_grad_tiled": 1}
     assert lm_ops.lowered_counts(full["prog"], cpu) == {
         "moe_ffn_grouped": 1}
 

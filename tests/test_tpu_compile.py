@@ -418,7 +418,7 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     from paddle_tpu import amp
     from paddle_tpu.core import executor_core
     from paddle_tpu.ops import lm_ops
-    from paddle_tpu.parallel import flash, grouped
+    from paddle_tpu.parallel import flash, grouped, row_sum
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     import sys
@@ -431,6 +431,7 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
     monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
     monkeypatch.setattr(grouped, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(row_sum, "pallas_interpret", lambda: False)
     # the policy on before the build, as the cells have it: the optimizer
     # then keeps the bf16 copies of the expert weights
     amp.enable("bfloat16")
@@ -503,8 +504,12 @@ def test_olmoe_step_writes_no_scores_and_no_all_experts_tensor(
     names = sorted(re.sub(r"\.\d+$", "", n) for n in calls)
     assert names == (["flash_dkv", "flash_dq", "flash_fwd"]
                      + ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
-                     + ["grouped_matmul_tn"] * 3)
+                     + ["grouped_matmul_tn"] * 3 + ["row_tile_sum"])
     assert ragged_dots(text) == []
+    # the embedding's gradient (PR 38): 412 MB do not fit `S(1)`, so the
+    # row-tile kernel writes the table and XLA scatters nothing into it
+    assert _embedding_gradients(text, cfg["vocab_size"], H) == (
+        ["embed/lookup_table_grad/row_tile_sum/pallas_call"], [])
     # the flash kernels' names (PR 32) come after the op's scope, which is
     # what the attention readers of chipbench find them by
     flash_ops = {re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
@@ -588,6 +593,21 @@ def _custom_calls(text):
     calls = [m.group(1) for m in map(_INSTR.match, text.splitlines())
              if m and 'custom_call_target="tpu_custom_call"' in m.group(4)]
     return sorted(re.sub(r"\.\d+$", "", n) for n in calls)
+
+
+def _embedding_gradients(text, V, H):
+    """What forms the [V, H] gradient of the embedding in a compiled step:
+    (the op_names of the row-tile kernel's calls, the result shapes with
+    their layouts of XLA's scatters of that table). PR 38: the kernel
+    where the table is too large for `S(1)`, XLA's sorted scatter with its
+    result in `S(1)` where it is not."""
+    kernels = sorted(
+        re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
+        for ln in text.splitlines() if re.match(r"\s*%row_tile_sum", ln))
+    scatters = [m.group(2) for m in map(_INSTR.match, text.splitlines())
+                if m and m.group(3) == "scatter"
+                and m.group(2).startswith("f32[%d,%d]" % (V, H))]
+    return kernels, scatters
 
 
 def _cond_branches(text, scope):
@@ -678,8 +698,13 @@ def test_xing_step_runs_both_kernel_families_over_its_share(
     assert _custom_calls(text) == (
         ["flash_dkv"] * 6 + ["flash_dq"] * 6 + ["flash_fwd"] * 6
         + ["grouped_matmul"] * (15 + 30) + ["grouped_matmul_nt"] * 30
-        + ["grouped_matmul_tn"] * 30)
+        + ["grouped_matmul_tn"] * 30 + ["row_tile_sum"] * 2)
     assert ragged_dots(text) == []
+    # the embedding is read twice (the prediction module): two gradients
+    # of 235 MB, each by the row-tile kernel (PR 38)
+    assert _embedding_gradients(text, cfg["vocab_size"], 3584) == (
+        ["embed/lookup_table_grad/row_tile_sum/pallas_call",
+         "mtp/embed/lookup_table_grad/row_tile_sum/pallas_call"], [])
     k, T = cfg["num_experts_per_tok"], cfg["sequence_length"]
     _bounded_branches_move_the_bound_s_rows(text, T * k, 4096, 3584, 5)
     arrays = {(dt, tuple(int(d) for d in dims.split(",") if d))
@@ -717,7 +742,7 @@ def test_olmoe_kernel_counts_are_unchanged(one_chip, no_compile_cache,
     assert _custom_calls(text) == (
         ["flash_dkv", "flash_dq", "flash_fwd"]
         + ["grouped_matmul"] * 3 + ["grouped_matmul_nt"] * 3
-        + ["grouped_matmul_tn"] * 3)
+        + ["grouped_matmul_tn"] * 3 + ["row_tile_sum"])
     assert ragged_dots(text) == []
     # 10.196 GB + 0.040 of code at one row, the parent's to the byte: the
     # copies take the place of the casts' temporaries
@@ -797,6 +822,12 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
         + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
         + ["grouped_matmul_tn"] * 24)
     assert ragged_dots(text) == []
+    # why `row_sum.takes` has a threshold (PR 38): this table's 103 MB are
+    # the one gradient XLA assigns to the chip's fast memory, where its
+    # sorted scatter costs 0.13 us a row; no kernel here
+    kernels, scatters = _embedding_gradients(text, cfg["vocab_size"], 2048)
+    assert kernels == [] and len(scatters) == 1
+    assert "S(1)" in scatters[0] and "indices_are_sorted=true" in text
     S, k = cfg["sequence_length"], cfg["num_experts_per_tok"]
     _bounded_branches_move_the_bound_s_rows(text, S * k, 16384, 2048, 4)
     shapes = {tuple(int(d) for d in dims.split(",") if d)
@@ -850,8 +881,11 @@ def test_smallthinker_step_routes_early_and_gates_by_relu(
     assert _custom_calls(text) == (
         ["flash_dkv"] * 4 + ["flash_dq"] * 4 + ["flash_fwd"] * 4
         + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
-        + ["grouped_matmul_tn"] * 24)
+        + ["grouped_matmul_tn"] * 24 + ["row_tile_sum"])
     assert ragged_dots(text) == []
+    # the 389 MB gradient of the embedding by the row-tile kernel (PR 38)
+    assert _embedding_gradients(text, cfg["vocab_size"], 2560) == (
+        ["embed/lookup_table_grad/row_tile_sum/pallas_call"], [])
     S, k = cfg["sequence_length"], cfg["moe_num_active_primary_experts"]
     _bounded_branches_move_the_bound_s_rows(text, S * k, 24576, 2560, 4)
     kernels = [re.search(r'op_name="([^"]*)"', ln).group(1)
