@@ -52,6 +52,9 @@ WHITE_LIST = frozenset({
     # and accumulate in float32; rms_norm and rotary_embedding stay neutral
     # (dtype-preserving, float32 inside, like layer_norm)
     "causal_attention", "moe_ffn",
+    # the gated short convolution between two `fc` products: bf16 in and
+    # out, its gates and taps float32 inside (ops/lm_ops.py: short_conv)
+    "short_conv",
     # the residual path of n streams: the state and the sublayers' outputs
     # flow in the compute dtype, the mixers themselves are float32 inside
     "mhc_expand", "mhc_mix", "mhc_update",
@@ -70,6 +73,8 @@ FLOAT32_SLOTS = {
     "mhc_mix": frozenset({"PhiPre", "PhiPost", "PhiRes", "Alpha", "BPre",
                           "BPost", "BRes", "HPost@GRAD", "HRes@GRAD"}),
     "mhc_update": frozenset({"HRes", "HPost"}),
+    # the taps: a [L, C] float32 master read as it is
+    "short_conv": frozenset({"Filter"}),
 }
 
 # Input slots of a white-list op whose value the lowering hands to a Pallas

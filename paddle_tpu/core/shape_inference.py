@@ -825,6 +825,31 @@ def _causal_attention_grad(ctx):
             ctx.set_output_dim(slot + "@GRAD", d)
 
 
+@register_infer_shape("short_conv")
+def _short_conv(ctx):
+    x, f = ctx.input_dim("X"), ctx.input_dim("Filter")
+    if x is None:
+        return
+    seq_len = int(ctx.attr("seq_len") or 0)
+    ctx.enforce(len(x) == 2 and (x[1] < 0 or x[1] % 3 == 0),
+                f"X must be [T, 3C] (the thirds B, C, z), got {x}")
+    ctx.enforce(seq_len > 0 and (x[0] < 0 or x[0] % seq_len == 0),
+                f"seq_len {seq_len} must divide X's {x[0]} tokens")
+    if f is not None:
+        ctx.enforce(len(f) == 2 and f[0] >= 1
+                    and (x[1] < 0 or _dim_match(f[1], x[1] // 3)),
+                    f"Filter{f} must be [L, C] of X{x}")
+    ctx.set_output_dim("Out", (x[0], x[1] // 3 if x[1] > 0 else -1))
+
+
+@register_infer_shape("short_conv_grad")
+def _short_conv_grad(ctx):
+    for slot in ("X", "Filter"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "@GRAD", d)
+
+
 @register_infer_shape("moe_ffn")
 def _moe_ffn(ctx):
     x, r = ctx.input_dim("X"), ctx.input_dim("Router")

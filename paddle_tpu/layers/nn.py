@@ -19,7 +19,7 @@ __all__ = [
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
-    "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
+    "short_conv", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_first_step", "sequence_last_step", "dropout",
     "l2_normalize", "matmul", "topk", "warpctc", "sequence_reshape",
@@ -696,10 +696,36 @@ def causal_attention(q, k, v, scale=None, window=None, name=None):
     return y
 
 
+def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None):
+    """The operator of a gated short-convolution layer (LFM2's): `input`
+    [T, 3C], the input projection's output, its thirds B, C, z side by
+    side, T = rows x `seq_len` tokens -> [T, C] = C * conv(B * z), conv a
+    causal depth-wise convolution of `kernel_size` taps along a row's
+    tokens (zero before a row's first token: rows are separate
+    sequences), no bias. The taps are one parameter [kernel_size, C],
+    float32 under AMP; the gates and the taps' sum are float32 whatever
+    the input's dtype, rounded once on the output."""
+    helper = LayerHelper("short_conv", **locals())
+    dtype = helper.input_dtype()
+    width = int(input.shape[-1])
+    if width % 3:
+        raise ValueError(f"short_conv: the input's last dimension {width} "
+                         "is not three thirds B, C, z")
+    taps = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(kernel_size), width // 3],
+        dtype=dtype)
+    y = helper.create_tmp_variable(
+        dtype, shape=tuple(input.shape[:-1]) + (width // 3,))
+    helper.append_op("short_conv", {"X": [input], "Filter": [taps]},
+                     {"Out": [y]}, {"seq_len": int(seq_len)})
+    return y
+
+
 def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
             gate_attr=None, up_attr=None, down_attr=None, name=None,
             score_func="softmax", norm_topk=False, routed_scale=1.0,
-            bias_attr=None, held=None, router_input=None, activation="silu"):
+            bias_attr=None, held=None, router_input=None, activation="silu",
+            norm_eps=None):
     """A layer of `num_experts` gated experts of width `expert_size` on
     tokens [T, H], each token through its `top_k` by router score,
     grouped matmuls over the rows really routed. The defaults are OLMoE's
@@ -717,6 +743,8 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
     to `input` alone; None (or `input` itself) appends the op as it has
     always been. `activation` "silu" | "relu": what gates an expert,
     act(x Gate_e) * (x Up_e), in the grouped kernels' epilogues.
+    `norm_eps`: what `norm_topk` adds to the sum it divides by (None: the
+    op's 1e-20, and the op is appended as it has always been).
     Returns (out, load-balance loss [1], router z-loss [1], expert ids
     [T, top_k], tokens per expert [num_experts]) and, with `held`, the
     rows the held experts received [1]."""
@@ -741,6 +769,8 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
                          "'silu' nor 'relu'")
     if activation != "silu":
         attrs["activation"] = activation
+    if norm_eps is not None:
+        attrs["norm_eps"] = float(norm_eps)
     if bias_attr is not None:
         bias_attr = ParamAttr.to_attr(bias_attr)
         bias_attr.trainable = False

@@ -15,7 +15,12 @@ and shared experts, as one chip's share): `laguna.laguna(tokens, cfg)`,
 sparse with ReLU-gated experts routed by the layer's INPUT, before
 attention; full attention layers without positions beside sliding-window
 layers with rotary; as one chip's share): `smallthinker.smallthinker(tokens,
-cfg)`, `smallthinker.smallthinker_loss`, `smallthinker.optimizer`.
+cfg)`, `smallthinker.smallthinker_loss`, `smallthinker.optimizer`; and
+`lfm2` (gated short convolutions in most layers beside grouped-query
+attention with per-head QK norm in the rest, a dense SwiGLU first and
+sigmoid-routed experts with the model's own bias after, the head the
+embedding table itself; as one chip's share of the experts and of the
+vocabulary): `lfm2.lfm2(tokens, cfg)`, `lfm2.lfm2_loss`, `lfm2.optimizer`.
 """
 
 from . import mnist
@@ -28,9 +33,11 @@ from . import olmoe
 from . import xing4
 from . import laguna
 from . import smallthinker
+from . import lfm2
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
-           "machine_translation", "olmoe", "xing4", "laguna", "smallthinker"]
+           "machine_translation", "olmoe", "xing4", "laguna", "smallthinker",
+           "lfm2"]
 
 
 def get_model(name):

@@ -1,11 +1,12 @@
 """Ops of a decoder language model with sparse experts: rms_norm,
-rotary_embedding, causal_attention, moe_ffn.
+rotary_embedding, causal_attention, short_conv, moe_ffn.
 
 No reference-framework counterpart (the reference predates them); the
 equations are those of OLMoE (Muennighoff et al., arXiv:2409.02060) as the
 `transformers` OlmoeDecoderLayer computes them. Gradients come from the
 generic vjp of core/registry.py, but for `causal_attention`, whose
-backward op takes the forward's output and logsumexp, and `moe_ffn`,
+backward op takes the forward's output and logsumexp, `short_conv`, whose
+backward op reads the forward's inputs alone, and `moe_ffn`,
 whose backward op takes the forward's three grouped products (the generic
 vjp would run the Pallas kernels twice). `moe_ffn`'s token permutation has a
 custom_vjp so that both directions are row gathers (the transpose of a
@@ -229,6 +230,153 @@ def causal_attention_grad_op(ctx, ins, attrs):
     return out(**{"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv})
 
 
+# -------------------------------------------------------------- short_conv
+# The parts of the op's lowering, each under a `jax.named_scope` inside the
+# op's own, forward and backward (`conv/short_conv/short_conv/taps`,
+# `conv/short_conv/short_conv_grad/filter_grad`) where XLA lowers the plain
+# form (a Pallas kernel stands there under its own name instead): GATE the
+# element-wise gates (C * c, d X's thirds), TAPS the L shifted
+# multiply-adds along the token axis (in the backward: of d conv, towards
+# the tokens after), FILTER_GRAD the reduction over the tokens of d conv
+# times the shifted v, a tap.
+GATE, TAPS, FILTER_GRAD = "gate", "taps", "filter_grad"
+
+
+def _whole(results):
+    """The op's results as arrays of their own: without this XLA splits
+    the last gate off the op and forms it again inside each consumer (the
+    output projection's product and that product's gradient each read the
+    float32 convolution and X: 168 MB where Out is 34), and a device trace
+    files that time under the consumers (v5e compile, PERF.md PR 39)."""
+    return lax.optimization_barrier(results)
+
+
+def _rows(x, seq_len):
+    """X [T, 3C] -> [rows, S, 3C]: rows of `seq_len` tokens."""
+    return x.reshape(-1, seq_len, x.shape[1])
+
+
+def _shifted(a, L, past):
+    """The L windows [rows, S, .] of a [rows, S, .] one tap apart: window j
+    holds a_{t - (L - 1 - j)} (`past`) or a_{t + (L - 1 - j)} (not `past`:
+    the transpose, which the backward applies to d c); what lies outside a
+    row is zero: rows are separate sequences. Slices of ONE padded array
+    in its own dtype, so that XLA reads it once where it fuses them."""
+    S = a.shape[1]
+    ap = jnp.pad(a, ((0, 0), (L - 1, 0) if past else (0, L - 1), (0, 0)))
+    return [ap[:, (j if past else L - 1 - j):][:, :S] for j in range(L)]
+
+
+def _gated(window, C):
+    """v = B * z [rows, S, C] float32 of a window of X's rows."""
+    return window[..., :C].astype(F32) * window[..., 2 * C:].astype(F32)
+
+
+def short_conv(x, w, seq_len):
+    """Out [T, C] = C * causal_depthwise_conv_L(B * z) of X [T, 3C] = [B | C
+    | z] and the taps w [L, C] (w[L - 1] weighs the token itself): L
+    shifted multiply-adds along the token axis of [rows, S, C], channels
+    last as the input projection leaves them; the gates and the sum in
+    float32, one rounding to X's dtype. The shifts are windows of X
+    itself and v = B * z is formed a window at a time: one pass that reads
+    X and writes Out (shifting a stored v costs a float32 [T, C] written
+    and read again: v5e compile, PERF.md PR 39)."""
+    L, C = w.shape[0], x.shape[1] // 3
+    xr, wf = _rows(x, seq_len), w.astype(F32)
+    windows = _shifted(xr, L, past=True)
+    with jax.named_scope(TAPS):
+        conv = sum(wf[j] * _gated(windows[j], C) for j in range(L))
+    with jax.named_scope(GATE):
+        out_ = xr[..., C:2 * C].astype(F32) * conv
+        return _whole(out_.reshape(x.shape[0], C).astype(x.dtype))
+
+
+def short_conv_grad(x, w, d_out, seq_len):
+    """(d X [T, 3C] in X's dtype, d Filter [L, C] float32) from X, the taps
+    and d Out alone: v = B * z and its convolution are formed again (three
+    multiply-adds an element) and never kept, the three thirds of d X are
+    written once, side by side, and d Filter is a float32 reduction over
+    the tokens of [T, C] products, a tap."""
+    L, C = w.shape[0], x.shape[1] // 3
+    xr, wf = _rows(x, seq_len), w.astype(F32)
+    g = d_out.reshape(xr.shape[0], seq_len, C)
+    c = xr[..., C:2 * C]
+    v = [_gated(win, C) for win in _shifted(xr, L, past=True)]
+    with jax.named_scope(TAPS):
+        d_c = g.astype(F32) * sum(wf[j] * v[j] for j in range(L))
+        # d conv = d Out * C of the tokens after: windows of both factors
+        d_v = sum(wf[j] * (gw.astype(F32) * cw.astype(F32))
+                  for j, (gw, cw) in enumerate(zip(
+                      _shifted(g, L, past=False), _shifted(c, L, past=False))))
+    with jax.named_scope(GATE):
+        d_x = jnp.concatenate([d_v * xr[..., 2 * C:].astype(F32), d_c,
+                               d_v * xr[..., :C].astype(F32)], axis=-1)
+    with jax.named_scope(FILTER_GRAD):
+        d_conv = g.astype(F32) * c.astype(F32)
+        d_w = jnp.stack([jnp.sum((v[j] * d_conv).astype(jnp.float32),
+                                 axis=(0, 1)) for j in range(L)])
+    return _whole((d_x.reshape(x.shape).astype(x.dtype), d_w))
+
+
+@register_op("short_conv")
+def short_conv_op(ctx, ins, attrs):
+    """The operator of a gated short-convolution layer (LFM2's, Liquid AI):
+    X [T, 3C], the input projection's output as the product leaves it
+    (channels last; the thirds B, C, z side by side), T = rows x `seq_len`
+    tokens; Filter [L, C], depth-wise taps, Filter[L - 1] on the token
+    itself -> Out [T, C] = C * c, c_t = sum_j Filter[j] (B * z)_{t - (L - 1
+    - j)}, zero before a row's first token (rows of a batch are separate
+    sequences). The gates and the L-tap sum in float32, one rounding to
+    X's dtype on Out. On a TPU place the Pallas kernel of
+    parallel/short_conv.py where it takes the shapes (one pass: X read
+    once, Out written); elsewhere L shifted multiply-adds along the token
+    axis: no transpose to [rows, C, S], no grouped convolution."""
+    x, w = first(ins, "X"), first(ins, "Filter")
+    seq_len = int(attrs["seq_len"])
+    if _conv_kernels_take(x, w, seq_len):
+        from ..parallel.short_conv import short_conv_fwd
+
+        return out(Out=short_conv_fwd(x, w, seq_len))
+    return out(Out=short_conv(x, w, seq_len))
+
+
+def _conv_kernels_take(x, w, seq_len):
+    """Whether this trace hands `short_conv` to the Pallas kernels: a TPU
+    place, shapes they take, and the op's inner precision the stated one
+    (a study one precision down runs the plain form)."""
+    from ..parallel import short_conv as kernels
+
+    return on_tpu() and F32 == jnp.float32 and kernels.takes(
+        x.shape[0], x.shape[1] // 3, seq_len, w.shape[0], x.dtype)
+
+
+@register_grad_maker("short_conv")
+def _short_conv_grad_maker(op, gout, gin):
+    """Hand-written: the generic vjp of the shifted slices keeps a copy of
+    v = B * z a tap for the backward; this one reads X, Filter and d Out."""
+    return [dict(
+        type="short_conv_grad",
+        inputs={"X": op.input("X"), "Filter": op.input("Filter"),
+                "Out@GRAD": [x or "" for x in gout.get("Out", [])]},
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in ("X", "Filter")},
+        attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
+
+
+@register_op("short_conv_grad")
+def short_conv_grad_op(ctx, ins, attrs):
+    """d X [T, 3C] and d Filter [L, C] (float32) of `short_conv`: the
+    backward kernel where the forward took its kernel, else the plain
+    form's."""
+    x, w = first(ins, "X"), first(ins, "Filter")
+    seq_len = int(attrs["seq_len"])
+    if _conv_kernels_take(x, w, seq_len):
+        from ..parallel.short_conv import short_conv_bwd as grad
+    else:
+        grad = short_conv_grad
+    d_x, d_w = grad(x, w, first(ins, "Out@GRAD"), seq_len)
+    return out(**{"X@GRAD": d_x, "Filter@GRAD": d_w.astype(w.dtype)})
+
+
 # ----------------------------------------------------------------- moe_ffn
 # The parts of the layer's lowering outside its kernels, each under a
 # `jax.named_scope` of its own inside the op's, forward and backward, so a
@@ -363,6 +511,7 @@ class Routing:
         self.held = int(attrs.get("held_experts", 0)) or n_experts
         self.all_held = self.first == 0 and self.held == n_experts
         self.activation = attrs.get("activation", "silu")
+        self.norm_eps = float(attrs.get("norm_eps", 1e-20))
 
 
 # The even-load share of the rows, times this, bounds the rows a layer
@@ -432,7 +581,7 @@ def _route(x, router, bias, r):
             top_e[:, :, None] == jnp.arange(E)[None, None, :],
             probs[:, None, :], 0.0), axis=2)
     if r.norm_topk:
-        top_p = top_p / (jnp.sum(top_p, axis=1, keepdims=True) + 1e-20)
+        top_p = top_p / (jnp.sum(top_p, axis=1, keepdims=True) + r.norm_eps)
     if r.scale != 1.0:
         top_p = top_p * r.scale
     flat_e = top_e.reshape(-1)
@@ -548,7 +697,8 @@ def moe_ffn_op(ctx, ins, attrs):
     takes no gradient; beside sigmoid scores it is added to the scores,
     beside softmax scores to the logits, where a step of usable size does
     not swamp scores near 1 / E); w is the chosen scores, divided by their
-    sum with `norm_topk`, times `routed_scale`. `activation` silu | relu
+    sum (plus `norm_eps`, 1e-20 unless given) with `norm_topk`, times
+    `routed_scale`. `activation` silu | relu
     is what gates an expert: silu(Gate_e x) or relu(Gate_e x) times Up_e x.
     RouterInput [T, H], where given, is what the router scores INSTEAD of
     X (a router placed before attention reads the layer's input, the
@@ -936,6 +1086,33 @@ def _grad_by_row_tiles(op, block):
     return row_sum.takes(w.shape[0], w.shape[1], n_ids, w.dtype)
 
 
+def _conv_kernel_takes(op, block):
+    """Whether the Pallas kernels take this `short_conv` (or its grad),
+    from the shapes the program states (tokens it leaves open, a batch
+    dimension of -1, are taken to be whole rows)."""
+    from ..parallel import short_conv as kernels
+
+    x, w = (block.vars[op.input(s)[0]] for s in ("X", "Filter"))
+    seq_len = int(op.attrs["seq_len"])
+    tokens = x.shape[0] if x.shape[0] > 0 else seq_len
+    low = amp.compute_dtype() if amp.is_enabled() else x.dtype
+    return kernels.takes(tokens, x.shape[1] // 3, seq_len, w.shape[0], low)
+
+
+def _reads_a_tied_table(op, block):
+    """A `lookup_table` whose W a `matmul` of the block reads transposed as
+    its Y, or such a `matmul`: one parameter [V, C] that is the embedding
+    and the head (its gradient is the sum of the two readers')."""
+    def readers(t, slot):
+        return {o.input(slot)[0] for o in block.ops if o.type == t
+                and (t != "matmul" or o.attrs.get("transpose_Y"))}
+
+    if op.type == "matmul":
+        return bool(op.attrs.get("transpose_Y")) and op.input("Y")[0] \
+            in readers("lookup_table", "W")
+    return op.input("W")[0] in readers("matmul", "Y")
+
+
 def window_blocks(program):
     """(visited, of a full causal grid): score blocks the flash kernels of
     the program's window layers compute a step, forward, dK/dV and dQ,
@@ -986,7 +1163,15 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("moe_ffn", "moe_ffn_relu", False,
              lambda op, block: op.attrs.get("activation") == "relu"),
             ("lookup_table_grad", "lookup_table_grad_tiled", True,
-             _grad_by_row_tiles))
+             _grad_by_row_tiles),
+            ("short_conv", "short_conv_gated", False, None),
+            ("short_conv_grad", "short_conv_grad_by_hand", False, None),
+            ("short_conv", "short_conv_kernel", True, _conv_kernel_takes),
+            ("short_conv_grad", "short_conv_grad_kernel", True,
+             _conv_kernel_takes),
+            ("lookup_table", "tied_table_lookup", False,
+             _reads_a_tied_table),
+            ("matmul", "tied_table_head", False, _reads_a_tied_table))
 
 
 def lowered_counts(program, device):
@@ -1009,7 +1194,14 @@ def lowered_counts(program, device):
     with a window count as `flash_attention_window` too, those whose K has
     fewer heads than their Q as `flash_attention_head_groups`) and
     `causal_attention_grad` ops (each through the two backward kernels).
-    A program without them reports none. Kept on the program until that
+    Its `short_conv` ops (`short_conv_gated`) and `short_conv_grad` ops,
+    each the hand-written backward (`short_conv_grad_by_hand`); on a TPU
+    place those whose shapes the Pallas kernels of parallel/short_conv.py
+    take count as `short_conv_kernel` / `short_conv_grad_kernel` too, the
+    others lower as L shifted multiply-adds along the token axis; the
+    two readers of a TIED table, a parameter a `lookup_table` reads as W
+    and a `matmul` reads transposed as Y (`tied_table_lookup`,
+    `tied_table_head`). A program without them reports none. Kept on the program until that
     is mutated or the mixed-precision policy changes, like
     `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)

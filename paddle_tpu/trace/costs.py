@@ -74,6 +74,12 @@ def _estimate(block, op, batch):
                 per_out *= max(1, int(d) if d is not None and d > 0 else 1)
             return out_elems * per_out
         return 2.0 * out_elems
+    if t == "short_conv":
+        # B * z, L multiply-adds a channel, C * c: 2 L + 2 an output
+        fs = op.input("Filter")
+        f_shape = _shape_of(block, fs[0], batch) if fs else None
+        taps = int(f_shape[0]) if f_shape else 3
+        return out_elems * (2.0 * taps + 2.0)
     if t in ("pool2d", "pool3d"):
         k = op.attrs.get("ksize") or []
         kk = 1.0

@@ -269,6 +269,42 @@ def test_a_wide_band_and_a_group_of_seven(window, which):
                                    rtol=1e-3)
 
 
+@pytest.mark.parametrize("which", ["forward", "dkv", "dq"])
+@pytest.mark.parametrize("S,dtype", [(128, "float32"), (100, "float32"),
+                                     (128, "bfloat16")],
+                         ids=["two_blocks", "padded", "bf16"])
+def test_heads_of_64_thirty_two_on_eight(S, dtype, which):
+    """The `lfm2_8b_a1b` cell's head shape at short rows: 32 query heads on
+    8 key/value heads (groups of 4) of D = 64, half a lane tile, over the
+    whole triangle: the forward kernel and both backward kernels against
+    the plain composition."""
+    import ml_dtypes
+
+    np_dtype = np.float32 if dtype == "float32" else ml_dtypes.bfloat16
+    q, k, v, cot = _band_case(S, 32, 8, d=64, seed=9)
+    q, k, v, cot = (t.astype(np_dtype) for t in (q, k, v, cot))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+
+    def plain(q, k, v):
+        f = (t.astype(jnp.float32) for t in (q, k, v))
+        return _dense_band(*f, None).astype(q.dtype)
+
+    if which == "forward":
+        got, want = [flash(q, k, v)], [plain(q, k, v)]
+    else:
+        got, want = (_grads(f, q, k, v, cot) for f in (flash, plain))
+        pick = slice(1, 3) if which == "dkv" else slice(0, 1)
+        got, want = got[pick], want[pick]
+    tol = dict(atol=5e-5, rtol=1e-3) if dtype == "float32" \
+        else dict(atol=0.15, rtol=0.05)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol)
+
+
 @pytest.mark.parametrize("blocks", [(32, 64), (64, 32), (16, 32)])
 @pytest.mark.parametrize("window", [24, 40])
 def test_band_with_unequal_blocks(window, blocks):

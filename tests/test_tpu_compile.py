@@ -418,7 +418,7 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     from paddle_tpu import amp
     from paddle_tpu.core import executor_core
     from paddle_tpu.ops import lm_ops
-    from paddle_tpu.parallel import flash, grouped, row_sum
+    from paddle_tpu.parallel import flash, grouped, row_sum, short_conv
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     import sys
@@ -432,6 +432,7 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
     monkeypatch.setattr(grouped, "pallas_interpret", lambda: False)
     monkeypatch.setattr(row_sum, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(short_conv, "pallas_interpret", lambda: False)
     # the policy on before the build, as the cells have it: the optimizer
     # then keeps the bf16 copies of the expert weights
     amp.enable("bfloat16")
@@ -919,3 +920,135 @@ def test_smallthinker_step_routes_early_and_gates_by_relu(
     assert _expert_weights_come_cast_from_their_update(
         compiled, "smallthinker_21b_a3b",
         {(16, 2560, 768): 8, (16, 768, 2560): 4}) < 15.5e9
+
+
+@pytest.mark.parametrize("blocks", [(1024, 1024), (512, 512)],
+                         ids=["blocks_1024", "blocks_512"])
+def test_flash_heads_of_64_compile_for_v5e(one_chip, no_compile_cache,
+                                           monkeypatch, blocks):
+    """Mosaic takes the three kernels at the `lfm2_8b_a1b` cell's head
+    shape, [1, 32 on 8, 8192, 64] bf16 over the whole triangle: a block's
+    last dimension is half a lane tile and the score product contracts
+    over 64. A forward, a dK/dV and a dQ custom call, no loop, and no copy
+    of K or V the size of the query heads'."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import flash
+
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    q_shape, k_shape = (1, 32, 8192, 64), (1, 8, 8192, 64)
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def both(q, k, v, do):
+        o, lse = flash.flash_attention_fwd(
+            q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1])
+        return o, flash.flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True, block_q=blocks[0],
+            block_k=blocks[1])
+
+    compiled = jax.jit(both).lower(sds(q_shape), sds(k_shape), sds(k_shape),
+                                   sds(q_shape)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert not any(m.group(3) == "while"
+                   for m in map(_INSTR.match, text.splitlines()) if m)
+    # q, o, do, dq and their float32 companions; never K or V repeated.
+    # A head of 64 fills half a lane tile: the temporaries (302 MB) are
+    # those of heads of 128, not half of them
+    assert 3 * 32 * 8192 * 64 * 4 \
+        < compiled.memory_analysis().temp_size_in_bytes \
+        < 3 * 32 * 8192 * 128 * 4
+
+
+@pytest.mark.parametrize("kind", ["forward", "backward"])
+def test_short_conv_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                            monkeypatch, kind):
+    """Mosaic takes the two kernels of `parallel/short_conv.py` at the
+    `lfm2_8b_a1b` cell's shape, X [8192, 6144] bf16 with 3 float32 taps:
+    one custom call each, no temporary of an activation's size beside it
+    (the sublane rotations, the halo views and the float32 accumulator of
+    d Filter live in VMEM)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import short_conv
+
+    monkeypatch.setattr(short_conv, "pallas_interpret", lambda: False)
+    S, C = 8192, 2048
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    x, w, g = (sds((S, 3 * C), "bfloat16"), sds((3, C), "float32"),
+               sds((S, C), "bfloat16"))
+    if kind == "forward":
+        compiled = jax.jit(
+            lambda x, w: short_conv.short_conv_fwd(x, w, S)).lower(
+                x, w).compile()
+    else:
+        compiled = jax.jit(
+            lambda x, w, g: short_conv.short_conv_bwd(x, w, g, S)).lower(
+                x, w, g).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_lfm2_step_runs_conv_kernels_flash_at_64_and_a_tied_table(
+        one_chip, no_compile_cache, monkeypatch):
+    """The `lfm2_8b_a1b` step at 1 x 8192 tokens (5 layers: conv + dense,
+    attention + experts, three conv + experts) compiles for one v5e chip
+    with the conv kernels (a forward and a backward a conv layer, named
+    under `conv/short_conv/`), the flash kernels at 32 query heads on 8
+    key/value heads of 64 (a forward, dK/dV and dQ), the grouped kernels
+    over the 8 held groups at K 2048 / F 1792 (nine a sparse layer in the
+    branch that works on the row bound's 16,384 of the 32,768 choice rows
+    and twelve in the overflow branch), the tied table's lookup gradient
+    by the row-tile kernel (134 MB, PR 38) and NO XLA convolution (no
+    grouped convolution of 2048 feature groups, no transpose to [rows, C,
+    S]); the step reads kept bf16 copies of the twelve expert matrices;
+    and it fits the chip: arguments + temporaries + code under 13 GB."""
+    cfg, compiled = _lm_step(
+        one_chip, monkeypatch, "lfm2_8b_a1b", 1,
+        lambda built: [built["routing"][0][1].name]
+        + [r[2].name for r in built["routing"]])
+    text = compiled.as_text()
+    assert _custom_calls(text) == (
+        ["flash_dkv", "flash_dq", "flash_fwd"]
+        + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
+        + ["grouped_matmul_tn"] * 24 + ["row_tile_sum"]
+        + ["short_conv_bwd"] * 4 + ["short_conv_fwd"] * 4)
+    assert ragged_dots(text) == []
+    assert _embedding_gradients(text, cfg["vocab_size"], 2048) == (
+        ["embed/lookup_table_grad/row_tile_sum/pallas_call"], [])
+    S, k = cfg["sequence_length"], cfg["num_experts_per_tok"]
+    _bounded_branches_move_the_bound_s_rows(text, S * k, 16384, 2048, 4)
+    assert "feature_group_count=2048" not in text
+    names = {re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
+             for ln in text.splitlines()
+             if re.match(r"\s*%(flash_|short_conv_)", ln)}
+    assert names == {
+        "attn/causal_attention/flash_fwd/pallas_call",
+        "attn/causal_attention_grad/flash_dkv/pallas_call",
+        "attn/causal_attention_grad/flash_dq/pallas_call",
+        "conv/short_conv/short_conv/short_conv_fwd/pallas_call",
+        "conv/short_conv/short_conv_grad/short_conv_bwd/pallas_call"}
+    shapes = {tuple(int(d) for d in dims.split(",") if d)
+              for _, dims in _ARRAY.findall(text)}
+    assert (32, S, 64) in shapes and (8, S, 64) in shapes
+    assert (8, 2048, 1792) in shapes and (8, 1792, 2048) in shapes
+    assert (16384, 1792) in shapes and (16384, 2048) in shapes
+    # no [rows, C, S] layout of the conv's channels, no [S, S] scores
+    assert (1, 2048, S) not in shapes and (2048, S) not in shapes
+    assert [ln for ln in text.splitlines()
+            if "%d,%d]" % (S, S) in ln and "/attn/" in ln] == []
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # the file's `arithmetic`: 6.80 GB of arguments (weights, two moments,
+    # the kept copies), ~4.9 GB of temporaries (gradients, activations)
+    assert 11.0e9 < held < 12.6e9, held
+    assert _expert_weights_come_cast_from_their_update(
+        compiled, "lfm2_8b_a1b",
+        {(8, 2048, 1792): 8, (8, 1792, 2048): 4}) < 13.0e9
