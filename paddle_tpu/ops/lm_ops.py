@@ -429,19 +429,70 @@ def _sum_of_choices(table, inv, k, weights=None):
     return total
 
 
+def _by_token(order, rows_held, T, k, H):
+    """The first B sorted slots by the TILE of tokens each belongs to, for
+    `_sum_by_token`: slot s holds a row of token order[s] // k, and a slot
+    at or past `rows_held` no held expert's row: it gets the token T, which
+    the kernel skips. A counting sort over the few tiles, no `lax.sort`:
+    a slot's place is its tile's start plus its rank among the tile's
+    slots (a running count down the [B, tiles] membership), and ONE scatter
+    of B scalars, each packing (slot, choice t * k + j), puts slots,
+    choices and with them the tokens in that order; inside a tile the
+    slots keep their own order, and the kernel asks for no more. (A sort
+    that carries its payloads does the same on the chip 0.7 ms a step
+    faster and costs every run 16-22 s of XLA's compile, 126 -> 142-149 s
+    of set-up; a gather of B scalars costs 0.17 ms: PERF.md PR 40.) (The
+    slots and the choices, each with the kernel's tail; the tokens.)"""
+    from ..parallel import row_sum
+
+    R, C = row_sum.tiles_for(H)
+    B = order.shape[0]
+    slot = jnp.arange(B, dtype=jnp.int32)
+    tile = jnp.where(slot < rows_held, order // (k * R), T // R)
+    member = tile[:, None] == jnp.arange(T // R + 1)[None, :]
+    rank = jnp.cumsum(member.astype(jnp.int32), axis=0)
+    start = jnp.cumsum(rank[-1]) - rank[-1]
+    place = jnp.sum(jnp.where(member, rank - 1 + start[None, :], 0), axis=1)
+    # 15 bits of slot, 16 of choice: `row_sum.takes_choices`
+    packed = jnp.zeros((B,), jnp.int32).at[place].set(
+        (slot << 16) | order, unique_indices=True)
+    slots, choices = packed >> 16, packed & 0xFFFF
+    token = jnp.where(slots < rows_held, choices // k, T)
+    return row_sum.with_tail(slots, C), token, row_sum.with_tail(choices, C)
+
+
+def _sum_by_token(table, by_token, T, weights=None):
+    """`_sum_of_choices` on a bounded table as ONE sum of its B rows by
+    token (`_by_token`; weights [T * k], a choice's): a B-row gather into
+    the tiles' order and the row-tile kernel (`parallel/row_sum.py`),
+    which writes each [R, H] tile of the [T, H] result once, in the
+    table's dtype. The same float32 sums; a token's up to k rows are added
+    in sorted-row order, not in choice order."""
+    from ..parallel import row_sum
+
+    slots, token, choices = by_token
+    return row_sum.sum_sorted_rows(
+        token, table[slots], T, row_sum.tiles_for(table.shape[1]),
+        weights=None if weights is None else weights[choices],
+        out_dtype=table.dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inv, k):
+def _dispatch(x, order, inv, k, by_token=None):
     """Rows of x [T, H] in expert order: slot s holds token order[s] // k.
     `order` may be the first B of the T * k sorted slots."""
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inv, k):
-    return x[order // k], inv
+def _dispatch_fwd(x, order, inv, k, by_token):
+    return x[order // k], (inv, by_token)
 
 
-def _dispatch_bwd(k, inv, g):
-    return _sum_of_choices(g, inv, k).astype(g.dtype), None, None
+def _dispatch_bwd(k, res, g):
+    inv, by_token = res
+    d_x = _sum_of_choices(g, inv, k).astype(g.dtype) if by_token is None \
+        else _sum_by_token(g, by_token, inv.shape[0] // k)
+    return d_x, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -465,17 +516,21 @@ _unsort.defvjp(_unsort_fwd, _unsort_bwd)
 
 
 @jax.custom_vjp
-def _combine(ys, top_p, order, inv):
+def _combine(ys, top_p, order, inv, by_token=None):
     """Out_t = sum over token t's k choices of top_p[t, j] * (its row of
     ys), float32 sums, in ys's dtype. ys [B, H] holds the first B sorted
     rows, zero past the held experts' (a choice whose row lies past B adds
-    nothing); order [B], inv [T * k]."""
-    return _combine_fwd(ys, top_p, order, inv)[0]
+    nothing); order [B], inv [T * k]; `by_token`: `_by_token`'s where the
+    B rows are summed by token, None where the choices are gathered."""
+    return _combine_fwd(ys, top_p, order, inv, by_token)[0]
 
 
-def _combine_fwd(ys, top_p, order, inv):
-    o = _sum_of_choices(ys, inv, top_p.shape[1], top_p)
-    return o.astype(ys.dtype), (ys, top_p, order, inv)
+def _combine_fwd(ys, top_p, order, inv, by_token):
+    if by_token is None:
+        o = _sum_of_choices(ys, inv, top_p.shape[1], top_p).astype(ys.dtype)
+    else:
+        o = _sum_by_token(ys, by_token, top_p.shape[0], top_p.reshape(-1))
+    return o, (ys, top_p, order, inv)
 
 
 def _combine_bwd(res, g):
@@ -490,7 +545,7 @@ def _combine_bwd(res, g):
     # once; 0.1 ms for 16,384 where a gather of all 65,536 took 0.47)
     d_w = jnp.zeros(inv.shape, F32).at[order].set(d_w, unique_indices=True)
     return (d_ys.astype(ys.dtype),
-            d_w.reshape(top_p.shape).astype(top_p.dtype), None, None)
+            d_w.reshape(top_p.shape).astype(top_p.dtype), None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -619,6 +674,7 @@ def _experts(x, top_p, gate, up, down, held_counts, order, inv, r, rows,
     among them (`_held_rows_take`). Given `products` (the backward op
     hands over what the forward left) no product is computed again: the
     kernels then run for the gradients alone."""
+    from ..parallel import row_sum
     from ..parallel.grouped import grouped_mlp
 
     T, top_k = top_p.shape
@@ -626,8 +682,14 @@ def _experts(x, top_p, gate, up, down, held_counts, order, inv, r, rows,
     if products is not None:
         # DownOut keeps all the rows
         products = (*products[:2], _first_rows(products[2], rows))
+    by_token = None
     with jax.named_scope(DISPATCH):
-        xs = _dispatch(x, order, inv, top_k)                # [rows, H]
+        if row_sum.on_tpu() and row_sum.takes_choices(
+                T, top_k, x.shape[1], rows, x.dtype):
+            # the sort that the combine and the dispatch's backward share
+            by_token = _by_token(order, jnp.sum(held_counts), T, top_k,
+                                 x.shape[1])
+        xs = _dispatch(x, order, inv, top_k, by_token)      # [rows, H]
     ys, a, b = grouped_mlp(xs, gate, up, down, held_counts, products,
                            not r.all_held, r.activation)
     with jax.named_scope(COMBINE):
@@ -636,7 +698,7 @@ def _experts(x, top_p, gate, up, down, held_counts, order, inv, r, rows,
             o = jnp.einsum("tkh,tk->th", y.astype(F32),
                            top_p).astype(x.dtype)
         else:
-            o = _combine(ys, top_p, order, inv)
+            o = _combine(ys, top_p, order, inv, by_token)
     return o, (a, b, ys)
 
 
@@ -1062,6 +1124,24 @@ def _has_row_bound(op, block):
         op.input("Router")[0]].shape[1]) < rows
 
 
+def _sums_rows_by_token(op, block):
+    """A `moe_ffn` with a row bound whose bounded sums (the combine, the
+    dispatch's backward) the row-tile kernel takes by token
+    (`parallel/row_sum.py: takes_choices`; tokens the program leaves open,
+    a batch dimension of -1, are taken to be 1024, which every row tile
+    divides and whose bound fits whatever the held share)."""
+    from ..parallel import row_sum
+
+    if not _has_row_bound(op, block):
+        return False
+    x = block.vars[op.input("X")[0]]
+    T, k = x.shape[0] if x.shape[0] > 0 else 1024, int(op.attrs["top_k"])
+    bound = row_bound(T * k, op.attrs["held_experts"], block.vars[
+        op.input("Router")[0]].shape[1])
+    low = amp.compute_dtype() if amp.is_enabled() else x.dtype
+    return row_sum.takes_choices(T, k, x.shape[1], bound, low)
+
+
 def _has_window(op, block):
     return bool(op.attrs.get("window", 0))
 
@@ -1164,6 +1244,7 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
              lambda op, block: op.attrs.get("activation") == "relu"),
             ("lookup_table_grad", "lookup_table_grad_tiled", True,
              _grad_by_row_tiles),
+            ("moe_ffn", "moe_ffn_rows_by_token", True, _sums_rows_by_token),
             ("short_conv", "short_conv_gated", False, None),
             ("short_conv_grad", "short_conv_grad_by_hand", False, None),
             ("short_conv", "short_conv_kernel", True, _conv_kernel_takes),
@@ -1187,7 +1268,9 @@ def lowered_counts(program, device):
     from the low-precision copies their updates keep, not from a cast of
     the float32 masters, as `moe_ffn_kept_copies`: `amp.kept_copy`; those
     whose router reads another variable than `X` as `moe_ffn_router_input`,
-    those whose experts are gated by ReLU as `moe_ffn_relu`) and,
+    those whose experts are gated by ReLU as `moe_ffn_relu`; on a TPU place
+    those whose bounded sums the row-tile kernel takes by token as
+    `moe_ffn_rows_by_token`: `row_sum.takes_choices`) and,
     on a TPU place, its dense `lookup_table_grad` ops whose table the
     row-tile kernel writes (`lookup_table_grad_tiled`: `row_sum.takes`), its
     `causal_attention` ops (each lowers through the flash kernel; those

@@ -446,11 +446,16 @@ def test_row_bound_is_a_function_of_the_shapes(n_rows, held, n_experts,
 BOUND_T, BOUND_K, BOUND_E = 64, 2, 8            # 128 choice rows
 
 
-def _routed_so_that(held_rows, first, held, score_func, seed):
+BOUND_DIMS = (BOUND_T, BOUND_K, BOUND_E, 128, 128)     # T, k, E, H, F
+
+
+def _routed_so_that(held_rows, first, held, score_func, seed,
+                    dims=BOUND_DIMS):
     """Operands of a share-holding `moe_ffn` whose router sends exactly
-    `held_rows` of the 128 choices to the held experts: the logits are
-    chosen and the router solved from them (64 tokens of width 128)."""
-    T, k, E, H, F = BOUND_T, BOUND_K, BOUND_E, 128, 128
+    `held_rows` of the T * k choices to the held experts: the logits are
+    chosen and the router solved from them (64 tokens of width 128 unless
+    `dims` says otherwise)."""
+    T, k, E, H, F = dims
     x, _, gate, up, down = _moe_operands(T, H, F, E, "float32", seed=seed)
     rs = np.random.default_rng(seed)
     inside = list(range(first, first + held))
@@ -487,10 +492,11 @@ def _step_of(ins, attrs, seed):
     return fwd, bwd, cots
 
 
-def _dense_loss(x, router, gate, up, down, bias, attrs, cots):
+def _dense_loss(x, router, gate, up, down, bias, attrs, cots,
+                dims=BOUND_DIMS):
     """sum(Out * d Out) + 0.7 AuxLoss + 0.3 ZLoss with Out as the dense
     sum over the held experts: what the op's gradients are gradients of."""
-    T, k, E = BOUND_T, BOUND_K, BOUND_E
+    T, k, E = dims[:3]
     first, held = attrs["first_expert"], attrs["held_experts"]
     logits = jnp.dot(x, router, precision=lax.Precision.HIGHEST)
     lse = jax.nn.logsumexp(logits, axis=-1)
@@ -582,6 +588,80 @@ def test_a_row_bound_gives_the_full_size_path(monkeypatch, first, held,
         *args, ins["Bias"][0], attrs, cots)
     for slot, want in zip(lm_ops._MOE_TRAINED, dense):
         got = np.asarray(full_bwd[slot + "@GRAD"][0])
+        assert np.max(np.abs(got - want)) <= 2e-4 * max(
+            np.max(np.abs(want)), 1e-6), slot
+
+
+# (cell, top_k, experts, held, H): the four share-holding cells' expert
+# layers with the experts cut so that held / experts stays the cell's (the
+# row bound is the cell's share of the choice rows: a half, a quarter, a
+# half, a quarter = the tokens themselves), 64 tokens, experts of 128
+BY_TOKEN_SHAPES = [("smallthinker_21b_a3b", 6, 24, 6, 2560),
+                   ("laguna_xs_2", 8, 64, 8, 2048),
+                   ("lfm2_8b_a1b", 4, 16, 4, 2048),
+                   ("xing4_0_29b_a4b", 4, 32, 4, 3584)]
+
+
+@pytest.mark.parametrize("rows_case", ["under", "one_over"])
+@pytest.mark.parametrize("cell,k,E,held,H", BY_TOKEN_SHAPES,
+                         ids=[c[0] for c in BY_TOKEN_SHAPES])
+def test_bounded_rows_summed_by_token_give_the_layer_s_gradients(
+        monkeypatch, cell, k, E, held, H, rows_case):
+    """`moe_ffn` + `moe_ffn_grad` with the bounded sums taken by token
+    through the row-tile kernel (PR 40: the place steered to a TPU for
+    `row_sum` alone, the kernel interpreted), at each share-holding
+    cell's top_k, held share and row width, in a step whose held experts'
+    rows fit the bound (the kernel form runs) and in one where they do
+    not (the `cond`'s other branch: the full table, no kernel): every
+    output and gradient against the same ops with the k gathers, and the
+    gradients against `jax.grad` of the plain dense sum."""
+    from paddle_tpu.parallel import row_sum
+
+    T, F, first = 64, 128, 1
+    dims = (T, k, E, H, F)
+    monkeypatch.setattr(grouped, "ROW_TILES", (32,))
+    N = T * k
+    B = lm_ops.row_bound(N, held, E)
+    assert B < N and B == 2 * N * held // E
+    R = {"under": B - 23, "one_over": B + 1}[rows_case]
+    ins, attrs = _routed_so_that(R, first, held, "sigmoid", 11, dims)
+    want_fwd, want_bwd, cots = _step_of(ins, attrs, 11)
+    assert int(want_fwd["RowsHeld"][0][0]) == R
+
+    calls = []
+    real = row_sum.sum_sorted_rows
+
+    def interpreted(token, rows, V, tiles, interpret=None, **kw):
+        calls.append((rows.shape, V, "weights" in kw and
+                      kw["weights"] is not None))
+        return real(token, rows, V, tiles, interpret=True, **kw)
+
+    monkeypatch.setattr(row_sum, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(row_sum, "sum_sorted_rows", interpreted)
+    monkeypatch.setattr(row_sum, "tiles_for", lambda H: (32, 16))
+    monkeypatch.setattr(row_sum, "takes_choices",
+                        lambda T, k, H, rows, dtype: rows < T * k)
+    fwd, bwd, _ = _step_of(ins, attrs, 11)
+    # traced in both branches of both ops' `cond`s: the combine in the
+    # forward op and in the backward op's vjp, and the dispatch's backward
+    assert set(calls) == {((B + 16, H), T, True), ((B + 16, H), T, False)}
+    for slot in ("ExpertIds", "TokensPerExpert", "RowsHeld", "AuxLoss",
+                 "ZLoss", *lm_ops._MOE_PRODUCTS):
+        np.testing.assert_array_equal(fwd[slot][0], want_fwd[slot][0])
+    got, want = np.asarray(fwd["Out"][0]), np.asarray(want_fwd["Out"][0])
+    assert np.max(np.abs(got - want)) <= 1e-6 * np.max(np.abs(want))
+    if rows_case == "one_over":
+        # the overflow branch is the parent's: to the bit
+        np.testing.assert_array_equal(got, want)
+    for slot in lm_ops._MOE_TRAINED:
+        got, want = (np.asarray(g[slot + "@GRAD"][0])
+                     for g in (bwd, want_bwd))
+        assert np.max(np.abs(got - want)) <= 1e-6 * np.max(np.abs(want))
+    args = [ins[s][0] for s in lm_ops._MOE_TRAINED]
+    dense = jax.grad(_dense_loss, argnums=(0, 1, 2, 3, 4))(
+        *args, ins["Bias"][0], attrs, cots, dims)
+    for slot, want in zip(lm_ops._MOE_TRAINED, dense):
+        got = np.asarray(bwd[slot + "@GRAD"][0])
         assert np.max(np.abs(got - want)) <= 2e-4 * max(
             np.max(np.abs(want)), 1e-6), slot
 

@@ -213,3 +213,162 @@ def test_the_word_embedding_models_keep_the_scatter():
                for op in prog.global_block().ops)
     assert "lookup_table_grad_tiled" not in lm_ops.lowered_counts(
         prog, types.SimpleNamespace(platform="tpu"))
+
+
+# ------------------------------------------- the bounded sums of `moe_ffn`
+# (PR 40: `lm_ops._sum_by_token`, the kernel's second caller)
+BT, BK = 96, 4                  # tokens, choices a token: 384 sorted slots
+BB = 192                        # the row bound: the first 192 of them
+HELD = {"none": 0, "one": 1, "under": BB - 37, "all": BB}
+
+
+def _bounded(rs, held, H, dtype):
+    """A layer's routing with `held` of its choice rows on held experts:
+    (table [B, H] of `dtype` with rows past `held` left as whatever a
+    buffer held, its zeroed copy, weights [T, k] zero off the held rows,
+    order [B], inv [T * k])."""
+    N = BT * BK
+    order = rs.permutation(N).astype(np.int32)
+    inv = np.argsort(order).astype(np.int32)
+    dirty = rs.standard_normal((BB, H)).astype(np.float32)
+    clean = dirty * (np.arange(BB)[:, None] < held)
+    w = rs.random(N).astype(np.float32)
+    w[order[held:]] = 0.0
+    return (jnp.asarray(dirty, dtype), jnp.asarray(clean, dtype),
+            jnp.asarray(w.reshape(BT, BK)), jnp.asarray(order[:BB]),
+            jnp.asarray(inv))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("H", [2048, 2560, 3584])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["combine", "dispatch_bwd"])
+@pytest.mark.parametrize("held", sorted(HELD))
+def test_rows_summed_by_token_are_the_sum_of_choices(held, weighted, H,
+                                                     dtype, monkeypatch):
+    """The B bounded rows summed by token through the kernel against the
+    k gathers of T rows (`_sum_of_choices`), for RowsHeld 0, 1, under B
+    and B, with the combine's weights and without (the dispatch's
+    backward), at the three row widths of the share-holding cells. A slot
+    at or past RowsHeld has the id T, which the kernel skips: the rows
+    there are never read, whatever they hold. Float32 sums of the same
+    terms in another order: a few ulps of the terms apart in float32, to
+    the bit after the cast to bf16 but for a rounding tie."""
+    monkeypatch.setattr(row_sum, "tiles_for", lambda H: (32, 16))
+    rs = np.random.default_rng(zlib.crc32(
+        f"{held}{weighted}{H}{dtype}".encode()))
+    R = HELD[held]
+    dirty, clean, w, order, inv = _bounded(rs, R, H, dtype)
+    by_token = lm_ops._by_token(order, R, BT, BK, H)
+    slots, token, choices = (np.asarray(a) for a in by_token)
+    assert slots.shape == choices.shape == (BB + 16,)
+    assert (token[:R] < BT).all() and (token[R:] == BT).all()
+    # a tile's tokens in one run, its slots in their own order inside it
+    assert (np.diff(token // 32) >= 0).all()
+    assert all((np.diff(slots[:R][token[:R] // 32 == t]) > 0).all()
+               for t in range(BT // 32))
+    np.testing.assert_array_equal(choices[:BB], np.asarray(order)[slots[:BB]])
+    np.testing.assert_array_equal(token[:R], choices[:R] // BK)
+    got = lm_ops._sum_by_token(
+        dirty, by_token, BT, w.reshape(-1) if weighted else None)
+    want = lm_ops._sum_of_choices(clean, inv, BK, w if weighted else None)
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (BT, H)
+    got, want32 = np.asarray(got, np.float32), np.asarray(want)
+    if dtype == "float32":
+        # up to k terms of size ~1: a few float32 ulps of the terms
+        assert np.all(np.abs(got - want32) <= 2e-6)
+    else:
+        want = np.asarray(want.astype(dtype), np.float32)
+        assert np.mean(got != want) < 1e-3
+        assert np.all(np.abs(got - want) <= np.abs(want) * 2.0 ** -7 + 2e-6)
+    if R == 0:
+        assert not got.any()
+
+
+def test_a_float32_out_and_a_narrow_out_hold_the_same_sums():
+    """The kernel's two homes of the float32 sums: the output block where
+    the result is float32, a block of scratch where it is bf16."""
+    rs = np.random.default_rng(11)
+    ids = np.sort(rs.integers(0, 200, 300)).astype(np.int32)
+    rows = jnp.asarray(rs.standard_normal((300 + C, H)), jnp.bfloat16)
+    w = jnp.asarray(rs.random(300 + C), jnp.float32)
+    wide, narrow = (row_sum.sum_sorted_rows(
+        jnp.asarray(ids), rows, 200, (R, C), interpret=True, weights=w,
+        out_dtype=dt) for dt in (jnp.float32, jnp.bfloat16))
+    np.testing.assert_array_equal(np.asarray(wide.astype(jnp.bfloat16)),
+                                  np.asarray(narrow))
+    want = np.zeros((200, H), np.float32)
+    np.add.at(want, ids, np.asarray(rows[:300], np.float32)
+              * np.asarray(w)[:300, None])
+    np.testing.assert_allclose(np.asarray(wide), want, rtol=1e-6, atol=1e-6)
+
+
+# (configuration, tokens, top_k, H, row bound B, held, experts, takes): the
+# five token cells' expert layers as their cells run them
+CELL_LAYERS = [("smallthinker_21b_a3b", 8192, 6, 2560, 24576, 16, 64, True),
+               ("laguna_xs_2", 8192, 8, 2048, 16384, 32, 256, True),
+               ("lfm2_8b_a1b", 8192, 4, 2048, 16384, 8, 32, False),
+               ("xing4_0_29b_a4b", 4096, 4, 3584, 4096, 8, 64, False),
+               ("olmoe_1b_7b", 8192, 8, 2048, 65536, 64, 64, False)]
+
+
+@pytest.mark.parametrize("config,T,k,H,B,held,E,takes", CELL_LAYERS,
+                         ids=[c[0] for c in CELL_LAYERS])
+def test_takes_choices_at_the_cells_shapes(config, T, k, H, B, held, E,
+                                           takes):
+    """The rule's truth table (tools/combine_sweep.py set it, PR 40): from
+    the shapes alone; a layer that holds every expert has no bounded
+    table, and neither has an overflow step's full-size branch."""
+    assert lm_ops.row_bound(T * k, held, E) == B
+    for dtype in ("bfloat16", jnp.float32):
+        assert row_sum.takes_choices(T, k, H, B, dtype) == takes
+    assert not row_sum.takes_choices(T, k, H, T * k, "bfloat16")
+    assert not row_sum.takes_choices(T, 4, H, B, "bfloat16")
+    assert not row_sum.takes_choices(T, k, H + 64, B, "bfloat16")
+    assert not row_sum.takes_choices(T, k, H, B, "float16")
+
+
+def test_takes_choices_refuses_what_the_kernel_cannot_hold():
+    assert row_sum.takes_choices(8192, 6, 2560, 24576, "bfloat16")
+    # more slots than SMEM holds beside their weights
+    assert not row_sum.takes_choices(32768, 6, 2560, 98304, "bfloat16")
+    # no tile of such rows fits VMEM
+    assert not row_sum.takes_choices(8192, 6, 2 ** 17, 24576, "bfloat16")
+    # a choice t * k + j has 16 bits beside its slot
+    assert row_sum.takes_choices(8192, 8, 2048, 16384, "bfloat16")
+    assert not row_sum.takes_choices(8320, 8, 2048, 16384, "bfloat16")
+    assert row_sum.takes_choices(8192, 5, 2560, 8192, "bfloat16")
+
+
+@pytest.mark.parametrize("config", [c[0] for c in CELL_LAYERS]
+                         + ["resnet50", "se_resnext50"])
+@pytest.mark.parametrize("place", ["tpu", "cpu"])
+def test_lowered_counts_name_the_layers_summed_by_token(config, place):
+    """The seven configurations' programs at their published widths, built
+    under the cells' policy: `moe_ffn_rows_by_token` counts the sparse
+    layers whose bounded sums take the kernel on a TPU place (as
+    `takes_choices` says of their shapes), and nothing anywhere else."""
+    import importlib
+    import json
+    import os
+
+    from paddle_tpu import amp
+
+    builder = importlib.import_module("chipbench.configs." + config)
+    with open(os.path.join(os.path.dirname(builder.__file__),
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    amp.enable("bfloat16")
+    try:
+        prog = builder.build(fluid, cfg, 1)["prog"]
+        got = lm_ops.lowered_counts(
+            prog, types.SimpleNamespace(platform=place))
+    finally:
+        amp.disable()
+    layers = {c[0]: c for c in CELL_LAYERS}.get(config)
+    sparse = sum(op.type == "moe_ffn" for op in prog.global_block().ops)
+    want = sparse if place == "tpu" and layers and layers[-1] else 0
+    assert got.get("moe_ffn_rows_by_token", 0) == want
+    assert ("moe_ffn_rows_by_token" in got) == bool(want)
+    if config == "smallthinker_21b_a3b":
+        assert sparse == 4

@@ -364,8 +364,9 @@ def test_lowered_counts_name_the_early_router_and_relu(small, place):
 def test_lowered_counts_at_the_published_widths_under_the_policy():
     """The cell's program, built under bf16 AMP: the Pallas kernels take
     K 2560 / F 768 with their epilogues, the step reads kept bf16
-    copies of all twelve expert matrices, and the embedding's 389 MB
-    gradient is the row-tile kernel's (PR 38)."""
+    copies of all twelve expert matrices, the embedding's 389 MB
+    gradient is the row-tile kernel's (PR 38) and so are the four layers'
+    bounded sums (PR 40)."""
     from chipbench.configs import smallthinker_21b_a3b as builder
     from paddle_tpu import amp
     from paddle_tpu.ops import lm_ops
@@ -382,7 +383,7 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
         flash_attention=4, flash_attention_bwd=4, flash_attention_window=3,
         flash_attention_head_groups=4, moe_ffn_held_experts=4,
         moe_ffn_row_bound=4, moe_ffn_kept_copies=4, moe_ffn_router_input=4,
-        moe_ffn_relu=4, lookup_table_grad_tiled=1)
+        moe_ffn_relu=4, lookup_table_grad_tiled=1, moe_ffn_rows_by_token=4)
     visited, whole = lm_ops.window_blocks(prog)
     assert 0.6 * whole < visited < whole     # a band of 4096 in rows of 8192
 

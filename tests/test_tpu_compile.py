@@ -602,13 +602,20 @@ def _embedding_gradients(text, V, H):
     their layouts of XLA's scatters of that table). PR 38: the kernel
     where the table is too large for `S(1)`, XLA's sorted scatter with its
     result in `S(1)` where it is not."""
-    kernels = sorted(
-        re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
-        for ln in text.splitlines() if re.match(r"\s*%row_tile_sum", ln))
+    kernels = [name for name in _row_tile_sums(text)
+               if "/lookup_table_grad/" in name]
     scatters = [m.group(2) for m in map(_INSTR.match, text.splitlines())
                 if m and m.group(3) == "scatter"
                 and m.group(2).startswith("f32[%d,%d]" % (V, H))]
     return kernels, scatters
+
+
+def _row_tile_sums(text):
+    """The op_names (after the jit's own component) of the row-tile
+    kernel's calls in a compiled step, sorted."""
+    return sorted(
+        re.search(r'op_name="([^"]*)"', ln).group(1).split("/", 1)[1]
+        for ln in text.splitlines() if re.match(r"\s*%row_tile_sum", ln))
 
 
 def _cond_branches(text, scope):
@@ -662,8 +669,9 @@ def _bounded_branches_move_the_bound_s_rows(text, rows, bound, width,
     `moe_ffn_grad`. The bounded branch of the forward writes all `rows`
     rows of `width` once (`DownOut`'s zero tail behind the down
     product), that of the backward never: the dispatch, the zeroing and d
-    ys are over `bound` rows and both combines gather [tokens, width] a
-    choice; the overflow branches move all `rows`."""
+    ys are over `bound` rows and the bounded sums gather [tokens, width] a
+    choice or, summed by token (PR 40), `bound` rows and a chunk's tail
+    once; the overflow branches move all `rows`."""
     for scope, most in (("moe_ffn", 1), ("moe_ffn_grad", 0)):
         conds = _cond_branches(text, scope)
         assert len(conds) == layers, (scope, len(conds))
@@ -702,7 +710,9 @@ def test_xing_step_runs_both_kernel_families_over_its_share(
         + ["grouped_matmul_tn"] * 30 + ["row_tile_sum"] * 2)
     assert ragged_dots(text) == []
     # the embedding is read twice (the prediction module): two gradients
-    # of 235 MB, each by the row-tile kernel (PR 38)
+    # of 235 MB, each by the row-tile kernel (PR 38); the expert blocks'
+    # bounded sums keep their gathers: four choices a token
+    # (`row_sum.takes_choices`, PR 40)
     assert _embedding_gradients(text, cfg["vocab_size"], 3584) == (
         ["embed/lookup_table_grad/row_tile_sum/pallas_call",
          "mtp/embed/lookup_table_grad/row_tile_sum/pallas_call"], [])
@@ -821,13 +831,15 @@ def test_laguna_step_runs_window_and_full_kernels_over_its_share(
     assert _custom_calls(text) == (
         ["flash_dkv"] * 5 + ["flash_dq"] * 5 + ["flash_fwd"] * 5
         + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
-        + ["grouped_matmul_tn"] * 24)
+        + ["grouped_matmul_tn"] * 24 + ["row_tile_sum"] * 8)
     assert ragged_dots(text) == []
     # why `row_sum.takes` has a threshold (PR 38): this table's 103 MB are
     # the one gradient XLA assigns to the chip's fast memory, where its
-    # sorted scatter costs 0.13 us a row; no kernel here
+    # sorted scatter costs 0.13 us a row; no kernel there. The eight calls
+    # are the four sparse layers' bounded sums by token (PR 40)
     kernels, scatters = _embedding_gradients(text, cfg["vocab_size"], 2048)
     assert kernels == [] and len(scatters) == 1
+    assert all(n.startswith("moe/moe_ffn") for n in _row_tile_sums(text))
     assert "S(1)" in scatters[0] and "indices_are_sorted=true" in text
     S, k = cfg["sequence_length"], cfg["num_experts_per_tok"]
     _bounded_branches_move_the_bound_s_rows(text, S * k, 16384, 2048, 4)
@@ -882,11 +894,18 @@ def test_smallthinker_step_routes_early_and_gates_by_relu(
     assert _custom_calls(text) == (
         ["flash_dkv"] * 4 + ["flash_dq"] * 4 + ["flash_fwd"] * 4
         + ["grouped_matmul"] * (12 + 24) + ["grouped_matmul_nt"] * 24
-        + ["grouped_matmul_tn"] * 24 + ["row_tile_sum"])
+        + ["grouped_matmul_tn"] * 24 + ["row_tile_sum"] * (1 + 8))
     assert ragged_dots(text) == []
-    # the 389 MB gradient of the embedding by the row-tile kernel (PR 38)
+    # the 389 MB gradient of the embedding by the row-tile kernel (PR 38),
+    # as it was before the kernel had a second caller
     assert _embedding_gradients(text, cfg["vocab_size"], 2560) == (
         ["embed/lookup_table_grad/row_tile_sum/pallas_call"], [])
+    # the bounded sums by token (PR 40): a layer's combine and the
+    # dispatch's backward, in the bounded branch of each op's `cond`
+    assert [n for n in _row_tile_sums(text) if n.startswith("moe/")] == [
+        "moe/moe_ffn/cond/branch_1_fun/combine/row_tile_sum/pallas_call"
+    ] * 4 + ["moe/moe_ffn_grad/cond/branch_1_fun/transpose(jvp(vjp))/"
+             "dispatch/row_tile_sum/pallas_call"] * 4
     S, k = cfg["sequence_length"], cfg["moe_num_active_primary_experts"]
     _bounded_branches_move_the_bound_s_rows(text, S * k, 24576, 2560, 4)
     kernels = [re.search(r'op_name="([^"]*)"', ln).group(1)
