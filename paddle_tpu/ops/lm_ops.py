@@ -1193,14 +1193,27 @@ def _reads_a_tied_table(op, block):
     return op.input("W")[0] in readers("matmul", "Y")
 
 
+def _flash_grid(op, block, backward=False):
+    """(S, block_q, block_k, window or None) the flash kernels of a
+    `causal_attention` / `causal_attention_grad` op are built with: from
+    the row length the program states and `flash_blocks`, as the op's
+    lowering calls them (a band as long as the row is the triangle)."""
+    from ..parallel import flash
+
+    S = int(block.vars[op.input("Q")[0]].shape[1])
+    window = int(op.attrs.get("window", 0)) or None
+    bq, bk = flash.normalize_blocks(**flash_blocks(window, backward),
+                                    Sq=S, Sk=S)
+    return S, bq, bk, window if window and window < S else None
+
+
 def window_blocks(program):
     """(visited, of a full causal grid): score blocks the flash kernels of
     the program's window layers compute a step, forward, dK/dV and dQ,
     against those the same kernels with the same blocks would compute over
     the whole triangle. Static, from the shapes and the blocks the kernels
-    are built with (`flash_blocks`, `flash.normalize_blocks`,
-    `flash.blocks_visited`); (0, 0) for a program without a window
-    layer."""
+    are built with (`_flash_grid`, `flash.blocks_visited`); (0, 0) for a
+    program without a window layer."""
     from ..parallel import flash
 
     visited = whole = 0
@@ -1209,21 +1222,30 @@ def window_blocks(program):
             if op.type not in ("causal_attention", "causal_attention_grad") \
                     or not _has_window(op, block):
                 continue
-            S = int(block.vars[op.input("Q")[0]].shape[1])
-            window = int(op.attrs["window"])
             back = op.type.endswith("_grad")
-            bq, bk = flash.normalize_blocks(
-                **flash_blocks(window, back), Sq=S, Sk=S)
+            S, bq, bk, window = _flash_grid(op, block, back)
             heads = int(block.vars[op.input("Q")[0]].shape[2])
             n = heads * (2 if back else 1)          # dK/dV and dQ
-            visited += n * flash.blocks_visited(
-                S, S, bq, bk, window if window < S else None)
+            visited += n * flash.blocks_visited(S, S, bq, bk, window)
             whole += n * flash.blocks_visited(S, S, bq, bk)
     return visited, whole
 
 
+def _forward_blocks(count):
+    """`flash.<count>` (`blocks_visited`, `blocks_masked`) of the grid the
+    forward kernel of a `causal_attention` op runs a head."""
+    def of(op, block):
+        from ..parallel import flash
+
+        S, bq, bk, window = _flash_grid(op, block)
+        return getattr(flash, count)(S, S, bq, bk, window)
+
+    return of
+
+
 # (op type, counter, whether the lowering exists on a TPU place only,
-# which of those ops count: all when None)
+# how much each of those ops counts: 1 when None, else what this returns,
+# a truth value or a number)
 _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("moe_ffn", "grouped_matmul_kernel", True, _kernels_take),
             ("moe_ffn", "grouped_mlp_epilogues", True,
@@ -1252,7 +1274,11 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
              _conv_kernel_takes),
             ("lookup_table", "tied_table_lookup", False,
              _reads_a_tied_table),
-            ("matmul", "tied_table_head", False, _reads_a_tied_table))
+            ("matmul", "tied_table_head", False, _reads_a_tied_table),
+            ("causal_attention", "flash_fwd_masked_blocks", True,
+             _forward_blocks("blocks_masked")),
+            ("causal_attention", "flash_fwd_visited_blocks", True,
+             _forward_blocks("blocks_visited")))
 
 
 def lowered_counts(program, device):
@@ -1284,7 +1310,11 @@ def lowered_counts(program, device):
     others lower as L shifted multiply-adds along the token axis; the
     two readers of a TIED table, a parameter a `lookup_table` reads as W
     and a `matmul` reads transposed as Y (`tied_table_lookup`,
-    `tied_table_head`). A program without them reports none. Kept on the program until that
+    `tied_table_head`). On a TPU place the score blocks the forward kernels
+    of its `causal_attention` ops visit a head and those of them that pay
+    for the mask, summed over the ops (`flash_fwd_visited_blocks`,
+    `flash_fwd_masked_blocks`: `flash.blocks_visited`, `.blocks_masked`).
+    A program without them reports none. Kept on the program until that
     is mutated or the mixed-precision policy changes, like
     `bn_pool.count`."""
     memo = getattr(program, "_lm_lowered", None)
@@ -1292,8 +1322,8 @@ def lowered_counts(program, device):
     if memo is None or memo[0] != key:
         ops = [(op, b) for b in program.blocks for op in b.ops]
         memo = program._lm_lowered = (key, [
-            sum(1 for op, b in ops
-                if op.type == t and (which is None or which(op, b)))
+            sum(1 if which is None else int(which(op, b))
+                for op, b in ops if op.type == t)
             for t, _, _, which in _LOWERED])
     return {name: n for n, (_, name, tpu_only, _) in zip(memo[1], _LOWERED)
             if n and (device.platform == "tpu" or not tpu_only)}

@@ -20,11 +20,13 @@ delta), all float32; p and ds are rounded to the operands' dtype only as
 they enter the MXU) and writes nothing of the square to HBM. Causal: blocks
 above the diagonal are skipped and their index maps name a block already
 resident, so a skipped step moves nothing; only blocks that cross the
-diagonal (or hold padded keys) pay for the mask. On the v5e at [2, 16, 4096,
-128] bf16 causal, 1024 x 1024 blocks: forward 2.24 ms, backward 3.75 ms
-(dK/dV 2.11 = 130 TFLOP/s over the triangle, dQ 1.80 = 114), where the
-lax.scan of float32 einsums over the whole square that they replaced took
-14.85 (PERF.md, PR 27).
+diagonal (or hold padded keys) pay for the mask, in the forward (since PR
+41) as in the backward kernels. On the v5e at [2, 16, 4096, 128] bf16
+causal, 1024 x 1024 blocks: forward 1.50 ms (2.16 until PR 41, with the
+mask on every block and its running max and normaliser as 1-D scratch),
+backward 3.75 ms (dK/dV 2.11 = 130 TFLOP/s over the triangle, dQ 1.80 =
+114), where the lax.scan of float32 einsums over the whole square that
+they replaced took 14.85 (PERF.md, PRs 27 and 41).
 
 Grouped-query heads (PR 32): k, v [B, Hkv, S, .] with H a multiple of Hkv
 are read IN PLACE by the H / Hkv consecutive query heads of a group (an
@@ -53,7 +55,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.places import pallas_interpret
 
 __all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd",
-           "blocks_visited"]
+           "blocks_visited", "blocks_masked"]
 
 # The kernels' names: Pallas puts a kernel's name on the name stack, so a
 # device trace's op_name ends `.../causal_attention/flash_fwd/pallas_call`
@@ -61,8 +63,31 @@ __all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd",
 KERNELS = ("flash_fwd", "flash_dkv", "flash_dq")
 
 
+# The running max and normaliser of the forward live in VMEM as [block_q,
+# _LANES] float32, every lane of a row holding the row's value: what a
+# reduction along a score block's rows gives, broadcast over one lane tile
+# (the upstream Pallas TPU flash kernel keeps them so).
+_LANES = 128
+
+
+def _across(x, n):
+    """x [rows, _LANES], every lane of a row the same -> [rows, n]."""
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return x[:, :n] if n < _LANES else jnp.broadcast_to(
+        x[:, :1], (x.shape[0], n))
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             scale, causal, block_q, block_k, nk, window=None, n_keys=None):
+    """One query block against the key blocks it sees: the streaming
+    softmax, float32. A block that no edge crosses (`_for_block`) pays
+    neither for the mask nor for the guards of an empty row: all its scores
+    are finite, so its row maxima are, and `exp(m_prev - m_new)` is 0 for a
+    row that has seen nothing yet (m_prev = -inf), not NaN. m and l stay in
+    the layout the row reductions give and `s - m`, `acc * corr` consume
+    (`_LANES`): kept as 1-D [block_q] scratch, the relayout into lanes and
+    back at every step cost a third of the kernel (PERF.md, PR 41)."""
     qi = pl.program_id(1)
     step = ki = pl.program_id(2)
     if window is not None:
@@ -71,16 +96,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(step == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _accumulate(masked=causal):
+    def _accumulate(masked):
         q = q_ref[0]                   # [bq, D]
         k = k_ref[0]                   # [bk, D]
-        v = v_ref[0]                   # [bk, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        v = v_ref[0]                   # [bk, Dv]
+        s = _dot(q, k, _NT) * scale    # [bq, bk]
         if masked:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -91,38 +115,35 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                 keep = keep & (q_pos - k_pos < window)
             s = jnp.where(keep, s, -jnp.inf)
 
-        m_prev = m_scr[:]              # [bq]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)  # masked rows
-        p = jnp.exp(s - m_safe[:, None])
-        if masked or window is None:
+        m_prev = m_scr[...]            # [bq, _LANES]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_safe = m_new
+        if masked:                     # a row of the block may be empty
+            m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        p = jnp.exp(s - _across(m_safe, block_k))
+        corr = jnp.exp(m_prev - m_safe)
+        if masked:
             p = jnp.where(jnp.isneginf(s), 0.0, p)
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_safe))
-        l_scr[:] = corr * l_scr[:] + jnp.sum(p, axis=1)
-        acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+            corr = jnp.where(jnp.isneginf(m_prev), 0.0, corr)
+        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _across(corr, acc_scr.shape[1]) \
+            + _dot(p.astype(v.dtype), v, _NN)
+        m_scr[...] = m_new
 
-    if window is not None:
-        # a band: the mask on its two edges alone
-        _for_block(_accumulate, qi, ki, block_q, block_k, True, None, window,
-                   ki < n_keys)
-    elif causal:
-        # skip k-blocks entirely above the causal frontier (half the grid)
-        pl.when(qi * block_q + block_q - 1 >= ki * block_k)(_accumulate)
-    else:
-        _accumulate()
+    # the mask where the diagonal or the band's far edge crosses, and
+    # nowhere else; padded keys are finite scores (`_fwd_padded`), not masked
+    _for_block(_accumulate, qi, ki, block_q, block_k, causal, None, window,
+               True if window is None else ki < n_keys)
 
     @pl.when(step == nk - 1)
     def _finish():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l[:, None]).astype(o_ref.dtype)
-        lse = jnp.where(
-            jnp.isneginf(m_scr[:]), -jnp.inf, m_scr[:] + jnp.log(l))
-        # lse rides in an [8, block_q] tile: Mosaic requires the last two
-        # block dims to be (8, 128)-aligned, so broadcast over 8 sublanes
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], (8, lse.shape[0]))
+        m, l = m_scr[...], jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / _across(l, acc_scr.shape[1])).astype(
+            o_ref.dtype)
+        lse = jnp.where(jnp.isneginf(m), -jnp.inf, m + jnp.log(l))
+        # lse rides in an [8, block_q] tile (Mosaic requires the last two
+        # block dims to be (8, 128)-aligned): the one relayout a query block
+        lse_ref[0] = lse.T[:8]
 
 
 def _first_key_block(i, block_q, block_k, window):
@@ -202,6 +223,19 @@ def blocks_visited(Sq, Sk, block_q, block_k, window=None):
         for i in range(nq)))
 
 
+def blocks_masked(Sq, Sk, block_q, block_k, window=None, kv_len=None):
+    """How many of those steps pay for the mask (`accumulate(True)`): the
+    blocks the diagonal or, with `window`, the band's far edge crosses, and
+    with `kv_len` those that hold padded keys. Static, as `blocks_visited`;
+    the same set for the forward, the dK/dV and the dQ kernel wherever no
+    key is padded (the backward kernels mask a padded block by `kv_len`, the
+    forward neutralises it through `_fwd_padded`'s bias channel: no
+    `kv_len`). 8 of the 36 blocks of 1024 x 1024 a row of 8192 visits."""
+    nq, nk = -(-Sq // block_q), -(-Sk // block_k)
+    return sum(all(_crossed(i, j, block_q, block_k, kv_len, window))
+               for i in range(nq) for j in range(nk))
+
+
 def _of_head(group):
     """Index of the key/value head that query head b (batch and heads
     folded, heads fastest) reads: `group` consecutive query heads share
@@ -245,8 +279,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
             jax.ShapeDtypeStruct((BH, 8, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -421,31 +455,41 @@ def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len, window=None):
     return jnp.where(keep, p, 0.0)
 
 
+def _crossed(qi, ki, block_q, block_k, kv_len=None, window=None):
+    """(visited, edge) of the causal score block (qi, ki), for grid indices
+    in a kernel and for Python ints alike: whether any pair of it carries
+    weight (the block does not lie wholly above the diagonal nor, with
+    `window`, wholly behind the band), and whether some pair of it carries
+    none (the block crosses the diagonal or the band's far edge, or holds
+    keys at or past `kv_len`, which are padding)."""
+    visited = qi * block_q + block_q - 1 >= ki * block_k
+    edge = qi * block_q < ki * block_k + block_k - 1
+    if window is not None:
+        # the nearest pair is inside the band; the farthest is not
+        visited = visited & (
+            qi * block_q - (ki * block_k + block_k - 1) < window)
+        edge = edge | (qi * block_q + block_q - 1 - ki * block_k >= window)
+    if kv_len is not None:
+        edge = edge | ((ki + 1) * block_k > kv_len)
+    return visited, edge
+
+
 def _for_block(accumulate, qi, ki, block_q, block_k, causal, kv_len,
                window=None, inside=True):
     """Run `accumulate(masked)` for the score block (qi, ki): not at all if
     the block lies wholly above the causal frontier or, with `window`,
     wholly behind the band; and with the mask only if some pair of it
-    carries no weight (the block crosses the diagonal or the band's far
-    edge, or holds keys at or past `kv_len`, which are padding). `inside`:
-    whether the step's block exists at all (a band's grid axis runs a
-    fixed number of steps from the band's first block, past the array's
-    end for the last rows)."""
+    carries no weight (`_crossed`). `inside`: whether the step's block
+    exists at all (a band's grid axis runs a fixed number of steps from the
+    band's first block, past the array's end for the last rows)."""
     if not causal and kv_len is None:
         accumulate(False)
         return
-    visited, edge = inside, False
     if causal:
-        visited = inside & (qi * block_q + block_q - 1 >= ki * block_k)
-        edge = qi * block_q < ki * block_k + block_k - 1
-        if window is not None:
-            # the nearest pair is inside the band; the farthest is not
-            visited = visited & (
-                qi * block_q - (ki * block_k + block_k - 1) < window)
-            edge = edge | (qi * block_q + block_q - 1 - ki * block_k
-                           >= window)
-    if kv_len is not None:
-        edge = edge | ((ki + 1) * block_k > kv_len)
+        visited, edge = _crossed(qi, ki, block_q, block_k, kv_len, window)
+        visited = inside & visited
+    else:
+        visited, edge = inside, (ki + 1) * block_k > kv_len
     pl.when(visited & edge)(lambda: accumulate(True))
     pl.when(visited & jnp.logical_not(edge))(lambda: accumulate(False))
 

@@ -206,8 +206,10 @@ def _band_case(S, heads, kv_heads, d=16, dv=None, seed=0, dtype=np.float32):
 
 
 # window against a block of 32: none, smaller than the block, equal to it,
-# two and a half blocks, longer than the row
-WINDOWS = [None, 8, 32, 80, 1000]
+# two and a half blocks, longer than the row; two blocks (PR 41): the first
+# block a query block visits leaves its last row empty, and the next one
+# carries no mask (the forward's unguarded path takes m_prev = -inf)
+WINDOWS = [None, 8, 32, 80, 1000, 64]
 # (query heads, key/value heads): groups of 1, 6 and 8
 HEADS = [(2, 2), (6, 1), (8, 1), (4, 2)]
 
@@ -271,8 +273,8 @@ def test_a_wide_band_and_a_group_of_seven(window, which):
 
 @pytest.mark.parametrize("which", ["forward", "dkv", "dq"])
 @pytest.mark.parametrize("S,dtype", [(128, "float32"), (100, "float32"),
-                                     (128, "bfloat16")],
-                         ids=["two_blocks", "padded", "bf16"])
+                                     (128, "bfloat16"), (256, "float32")],
+                         ids=["two_blocks", "padded", "bf16", "four_blocks"])
 def test_heads_of_64_thirty_two_on_eight(S, dtype, which):
     """The `lfm2_8b_a1b` cell's head shape at short rows: 32 query heads on
     8 key/value heads (groups of 4) of D = 64, half a lane tile, over the
@@ -424,3 +426,173 @@ def test_blocks_visited_counts_the_band(S, block, window, want):
                 visited &= i * block - (j * block + block - 1) < window
             seen += visited
     assert seen == want
+
+
+# ------------------------- masked and unmasked blocks in one forward (PR 41)
+def _plain_out_lse(q, k, v, causal=True, window=None, scale=None):
+    """Out and the scores' logsumexp by the plain composition, float32;
+    k, v [B, Hkv, Sk, .] repeated for the query heads of each group."""
+    group = q.shape[1] // k.shape[1]
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    s = s / np.sqrt(q.shape[-1]) if scale is None else s * scale
+    if causal:
+        i = jnp.arange(q.shape[2])[:, None]
+        j = jnp.arange(k.shape[2])[None, :]
+        keep = j <= i
+        if window is not None:
+            keep = keep & (i - j < window)
+        s = jnp.where(keep[None, None], s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v), lse
+
+
+# (id, query heads, key/value heads, Sq, Sk, D, Dv, block, causal, window,
+# scale, dtype): every case but the last two puts blocks of BOTH kinds in
+# one forward, the masked ones the diagonal (or the band's far edge)
+# crosses and the unmasked ones under it
+BOTH_KINDS = [
+    ("d64_four_blocks", 4, 4, 128, 128, 64, 64, 32, True, None, None,
+     "float32"),
+    ("d128_four_blocks", 2, 2, 128, 128, 128, 128, 32, True, None, None,
+     "float32"),
+    ("keys_192_values_128", 2, 2, 128, 128, 192, 128, 32, True, None, 0.1,
+     "float32"),
+    ("group_of_4", 8, 2, 128, 128, 64, 64, 32, True, None, None, "float32"),
+    ("group_of_7", 7, 1, 128, 128, 32, 32, 32, True, None, None, "float32"),
+    ("d64_bf16", 8, 2, 128, 128, 64, 64, 32, True, None, None, "bfloat16"),
+    ("d128_bf16", 2, 2, 128, 128, 128, 128, 32, True, None, None,
+     "bfloat16"),
+    # Sk no multiple of the block: the last key block holds padded keys,
+    # which the bias channel neutralises (finite scores on the unguarded
+    # path where the diagonal does not cross it; the ones before it plain)
+    ("padded_keys_causal", 2, 2, 100, 100, 32, 32, 32, True, None, None,
+     "float32"),
+    ("padded_keys_non_causal", 2, 2, 72, 100, 32, 32, 32, False, None, None,
+     "float32"),
+    ("padded_keys_group_bf16", 4, 1, 100, 100, 64, 64, 32, True, None, None,
+     "bfloat16"),
+    # a band of two blocks' length: the first block a query block visits
+    # holds keys for every row but the LAST, whose band starts with the
+    # next block, and that next block carries no mask at all: a row whose
+    # m_prev is still -inf goes into the unguarded path (exp(-inf) = 0, no
+    # NaN); the test below asserts that the case is that one
+    ("band_empty_row_then_unmasked", 2, 1, 128, 128, 32, 32, 16, True, 32,
+     None, "float32"),
+    ("band_empty_row_then_unmasked_bf16", 6, 1, 128, 128, 64, 64, 32, True,
+     64, None, "bfloat16"),
+    ("non_causal_four_blocks", 2, 2, 128, 128, 64, 64, 32, False, None, None,
+     "float32"),
+    ("non_causal_cross_lengths_bf16", 2, 2, 64, 128, 128, 128, 32, False,
+     None, None, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("case", BOTH_KINDS, ids=lambda c: c[0])
+def test_forward_out_and_lse_with_masked_and_unmasked_blocks(case):
+    """`Out` and `Lse` of the forward kernel alone against the plain
+    composition where one forward runs both of its bodies: the masked one
+    with the guards of an empty row, the unmasked one without."""
+    import ml_dtypes
+
+    from paddle_tpu.parallel import flash
+
+    (_, heads, kv_heads, Sq, Sk, D, Dv, block, causal, window, scale,
+     dtype) = case
+    bq, bk = block if isinstance(block, tuple) else (block, block)
+    rng = np.random.RandomState(41)
+    np_dtype = np.float32 if dtype == "float32" else ml_dtypes.bfloat16
+    q, k, v = (jnp.asarray(rng.randn(*shape).astype(np_dtype))
+               for shape in ((2, heads, Sq, D), (2, kv_heads, Sk, D),
+                             (2, kv_heads, Sk, Dv)))
+    if causal:
+        # both bodies run, or with padded keys in a masked last block
+        # alone the case is not what its name says
+        masked = flash.blocks_masked(Sq, Sk, bq, bk, window)
+        assert 0 < masked < flash.blocks_visited(Sq, Sk, bq, bk, window)
+    if window is not None:
+        # the last query block: its first visited key block leaves its
+        # last row empty and the block after that one is unmasked
+        i = Sq // bq - 1
+        first = max(i * bq - (window - 1), 0) // bk
+        assert Sq - 1 - (first * bk + bk - 1) >= window
+        assert flash._crossed(i, first, bq, bk, None, window) == (True, True)
+        assert flash._crossed(i, first + 1, bq, bk, None, window) == (
+            True, False)
+    out, lse = flash.flash_attention_fwd(
+        q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
+        window=window)
+    want_out, want_lse = _plain_out_lse(q, k, v, causal, window, scale)
+    assert out.shape == want_out.shape and out.dtype == q.dtype
+    assert lse.shape == want_lse.shape and lse.dtype == jnp.float32
+    assert not np.isnan(np.asarray(out, np.float32)).any()
+    assert np.isfinite(np.asarray(lse)).all()
+    tol = dict(atol=2e-5, rtol=1e-4) if dtype == "float32" \
+        else dict(atol=3e-2, rtol=5e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want_out), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=2e-5 if dtype == "float32" else 2e-2,
+                               rtol=1e-5 if dtype == "float32" else 1e-2)
+
+
+@pytest.mark.parametrize("S,block,window,masked,visited", [
+    (8192, 1024, None, 8, 36),          # LFM2, SmallThinker, Laguna: full
+    (4096, 1024, None, 4, 10),          # OLMoE, Xing
+    (8192, 1024, 4096, 12, 30),         # SmallThinker's band
+    (8192, 512, 512, 31, 31),           # Laguna's band: every block
+    (128, 32, None, 4, 10), (100, 32, None, 4, 10), (128, 16, 33, 14, 21)])
+def test_blocks_masked_is_the_set_all_three_kernels_mask(S, block, window,
+                                                         masked, visited):
+    """`blocks_masked` counts what `_for_block` hands `accumulate(True)`,
+    and the forward, the dK/dV and the dQ kernel go through `_for_block`
+    with the same arguments where no key is padded: 8 of 36 at the
+    8k cells' full layers, 4 of 10 at the 4k cells'."""
+    from paddle_tpu.parallel import flash
+
+    assert flash.blocks_masked(S, S, block, block, window) == masked
+    assert flash.blocks_visited(S, S, block, block, window) == visited
+    # the predicate written out: a visited block that holds a pair above
+    # the diagonal or one `window` or more back
+    n, crossed = -(-S // block), 0
+    for i in range(n):
+        for j in range(n):
+            rows = np.arange(i * block, (i + 1) * block)[:, None]
+            cols = np.arange(j * block, (j + 1) * block)[None, :]
+            keep = cols <= rows
+            if window is not None:
+                keep &= rows - cols < window
+            crossed += bool(keep.any() and not keep.all())
+    assert crossed == masked
+    # padded keys: the backward kernels mask a block that holds them by
+    # `kv_len`, the forward does not (its channel); in a square causal grid
+    # only the diagonal's last block holds any
+    assert flash.blocks_masked(S, S, block, block, window, kv_len=S) == masked
+    assert (flash.blocks_masked(160, 100, 32, 32),
+            flash.blocks_masked(160, 100, 32, 32, kv_len=100)) == (4, 5)
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["triangle", "band_40"])
+def test_the_three_kernels_mask_the_same_blocks(window, monkeypatch):
+    """Traced, the three kernels' bodies call `_for_block` with the same
+    block sizes, `causal`, `window` and no `kv_len` (no key is padded):
+    the set `blocks_masked` counts is one set."""
+    from paddle_tpu.parallel import flash
+
+    seen = []
+    real = flash._for_block
+
+    def spy(accumulate, qi, ki, block_q, block_k, causal, kv_len,
+            window=None, inside=True):
+        seen.append((block_q, block_k, causal, kv_len, window))
+        return real(accumulate, qi, ki, block_q, block_k, causal, kv_len,
+                    window, inside)
+
+    monkeypatch.setattr(flash, "_for_block", spy)
+    q, k, v, cot = _band_case(128, 4, 2, seed=2)
+    jax.make_jaxpr(lambda q, k, v: _grads(
+        lambda *a: flash_attention(*a, causal=True, block_q=32, block_k=32,
+                                   window=window), q, k, v, cot))(q, k, v)
+    assert len(seen) == 3 and len(set(seen)) == 1, seen
+    assert seen[0] == (32, 32, True, None, window)

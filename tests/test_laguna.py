@@ -276,7 +276,8 @@ def test_lowered_counts_name_windows_and_head_groups(small, place):
             "moe_ffn_row_bound": 3}
     if place == "tpu":
         want.update(flash_attention=4, flash_attention_bwd=4,
-                    flash_attention_window=2, flash_attention_head_groups=4)
+                    flash_attention_window=2, flash_attention_head_groups=4,
+                    flash_fwd_visited_blocks=4, flash_fwd_masked_blocks=4)
     assert got == want
 
 
@@ -295,6 +296,15 @@ def test_window_blocks_are_counted_from_the_shapes():
     assert visited == n * flash.blocks_visited(8192, 8192, side, side, 512)
     assert whole == n * flash.blocks_visited(8192, 8192, side, side)
     assert side == 512 and 0 < visited < 0.25 * whole
+    # the forward's blocks a head, and those that pay for the mask (PR 41):
+    # two full layers at 1024 x 1024 (36, the diagonal's 8), three bands of
+    # 512 at 512 x 512 (31, every one crossed by an edge)
+    import types
+    got = lm_ops.lowered_counts(prog, types.SimpleNamespace(platform="tpu"))
+    assert got["flash_fwd_visited_blocks"] == 2 * 36 + 3 * 31
+    assert got["flash_fwd_masked_blocks"] == 2 * 8 + 3 * 31
+    assert "flash_fwd_masked_blocks" not in lm_ops.lowered_counts(
+        prog, types.SimpleNamespace(platform="cpu"))
     assert lm_ops.flash_blocks(None) == lm_ops.FLASH_FWD_BLOCKS
     assert lm_ops.flash_blocks(0, True) == lm_ops.FLASH_BWD_BLOCKS
     # a program with no window layer has nothing to count

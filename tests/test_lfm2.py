@@ -493,7 +493,8 @@ def test_lowered_counts_name_the_conv_and_the_tie(small, place):
             "tied_table_head": 1}
     if place == "tpu":
         want.update(flash_attention=1, flash_attention_bwd=1,
-                    flash_attention_head_groups=1)
+                    flash_attention_head_groups=1,
+                    flash_fwd_visited_blocks=1, flash_fwd_masked_blocks=1)
     assert got == want
     # the inference clone has no backward
     test = lm_ops.lowered_counts(small["built"]["test_prog"],
@@ -527,7 +528,10 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
         moe_ffn_row_bound=4, moe_ffn_kept_copies=4,
         lookup_table_grad_tiled=1, short_conv_gated=4,
         short_conv_grad_by_hand=4, short_conv_kernel=4,
-        short_conv_grad_kernel=4, tied_table_lookup=1, tied_table_head=1)
+        short_conv_grad_kernel=4, tied_table_lookup=1, tied_table_head=1,
+        # the one attention layer's forward, 1024 x 1024 blocks over a row
+        # of 8192: the mask on the diagonal's 8 of 36 (PR 41; 36 before)
+        flash_fwd_visited_blocks=36, flash_fwd_masked_blocks=8)
     assert row_sum.takes(16384, 2048, 8192, "float32")
     assert lm_ops.window_blocks(prog) == (0, 0)
 

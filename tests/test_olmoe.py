@@ -162,13 +162,16 @@ def test_lowered_counts_by_place():
     tpu, cpu = SimpleNamespace(platform="tpu"), SimpleNamespace(
         platform="cpu")
     layers = cfg["num_hidden_layers"]
+    # SMALL's rows fit one block: the forward visits it a layer, masked
+    blocks = {"flash_fwd_visited_blocks": layers,
+              "flash_fwd_masked_blocks": layers}
     assert lm_ops.lowered_counts(built["prog"], tpu) == {
         "moe_ffn_grouped": layers, "flash_attention": layers,
-        "flash_attention_bwd": layers}
+        "flash_attention_bwd": layers, **blocks}
     assert lm_ops.lowered_counts(built["prog"], cpu) == {
         "moe_ffn_grouped": layers}
     assert lm_ops.lowered_counts(built["test_prog"], tpu) == {
-        "moe_ffn_grouped": layers, "flash_attention": layers}
+        "moe_ffn_grouped": layers, "flash_attention": layers, **blocks}
     # SMALL's widths are no multiples of 128: the products stay
     # `lax.ragged_dot` there; at the published widths the Pallas grouped
     # kernels take them, on a TPU place only
@@ -178,7 +181,9 @@ def test_lowered_counts_by_place():
     assert lm_ops.lowered_counts(full["prog"], tpu) == {
         "moe_ffn_grouped": 1, "grouped_matmul_kernel": 1,
         "grouped_mlp_epilogues": 1, "flash_attention": 1,
-        "flash_attention_bwd": 1, "lookup_table_grad_tiled": 1}
+        "flash_attention_bwd": 1, "lookup_table_grad_tiled": 1,
+        # rows of 4096 at 1024 x 1024: the mask on the diagonal's 4 of 10
+        "flash_fwd_visited_blocks": 10, "flash_fwd_masked_blocks": 4}
     assert lm_ops.lowered_counts(full["prog"], cpu) == {
         "moe_ffn_grouped": 1}
 

@@ -356,8 +356,10 @@ def test_lowered_counts_name_the_early_router_and_relu(small, place):
             "moe_ffn_row_bound": 4, "moe_ffn_router_input": 4,
             "moe_ffn_relu": 4}
     if place == "tpu":
+        # a row of the small size is one block a layer, the diagonal's
         want.update(flash_attention=4, flash_attention_bwd=4,
-                    flash_attention_window=3, flash_attention_head_groups=4)
+                    flash_attention_window=3, flash_attention_head_groups=4,
+                    flash_fwd_visited_blocks=4, flash_fwd_masked_blocks=4)
     assert got == want
 
 
@@ -383,7 +385,12 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
         flash_attention=4, flash_attention_bwd=4, flash_attention_window=3,
         flash_attention_head_groups=4, moe_ffn_held_experts=4,
         moe_ffn_row_bound=4, moe_ffn_kept_copies=4, moe_ffn_router_input=4,
-        moe_ffn_relu=4, lookup_table_grad_tiled=1, moe_ffn_rows_by_token=4)
+        moe_ffn_relu=4, lookup_table_grad_tiled=1, moe_ffn_rows_by_token=4,
+        # the forward's blocks of 1024 x 1024 a head (PR 41): the full
+        # layer visits 36 and masks the diagonal's 8, each of the three
+        # bands of 4096 visits 30 and masks 8 + the far edge's 4
+        flash_fwd_visited_blocks=36 + 3 * 30,
+        flash_fwd_masked_blocks=8 + 3 * 12)
     visited, whole = lm_ops.window_blocks(prog)
     assert 0.6 * whole < visited < whole     # a band of 4096 in rows of 8192
 

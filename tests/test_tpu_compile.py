@@ -981,6 +981,52 @@ def test_flash_heads_of_64_compile_for_v5e(one_chip, no_compile_cache,
         < 3 * 32 * 8192 * 128 * 4
 
 
+@pytest.mark.parametrize("heads,d,dv,S,window", [
+    ((32, 8), 64, 64, 8192, None), ((7, 1), 128, 128, 8192, None),
+    ((4, 4), 192, 128, 4096, None), ((7, 1), 128, 128, 8192, 4096),
+    ((2, 2), 128, 128, 1000, None)],
+    ids=["d64_32_on_8", "d128_7_on_1", "keys_192_values_128",
+         "d128_band_4096", "d128_padded_keys"])
+def test_flash_forward_alone_compiles_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch, heads, d, dv, S,
+                                              window):
+    """Mosaic takes the forward kernel ALONE at 1024 x 1024 blocks, bf16,
+    with its running max and normaliser as [block_q, 128] float32 scratch
+    (PR 41) and both of its bodies, the masked one and the unguarded one:
+    at heads of 64 (half a lane tile: the accumulator's rescale takes the
+    first 64 lanes of the correction), of 128, with keys of 192 beside
+    values of 128, over a band, and with padded keys (a bias channel makes
+    the contraction 129 wide). One custom call, no loop, `Out` and `Lse`
+    the only results, nothing of a score block's size outside VMEM."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import flash
+
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash.flash_attention_fwd(q, k, v, causal=True, window=window,
+                                         block_q=1024, block_k=1024)
+
+    operands = (sds((1, heads[0], S, d)), sds((1, heads[1], S, d)),
+                sds((1, heads[1], S, dv)))
+    compiled = jax.jit(fwd).lower(*operands).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not any(m.group(3) == "while"
+                   for m in map(_INSTR.match, text.splitlines()) if m)
+    out, lse = jax.eval_shape(fwd, *operands)
+    assert out.shape == (1, heads[0], S, dv) and out.dtype == jnp.bfloat16
+    assert lse.shape == (1, heads[0], S) and lse.dtype == jnp.float32
+    # the padded operands, the folded results and the [8, S] tile of lse
+    # at most: a float32 score block a head would be 4 S^2 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < heads[0] * S * 1024 * 4
+
+
 @pytest.mark.parametrize("kind", ["forward", "backward"])
 def test_short_conv_kernels_compile_for_v5e(one_chip, no_compile_cache,
                                             monkeypatch, kind):

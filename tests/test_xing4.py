@@ -269,7 +269,8 @@ def test_lowered_counts_name_the_share_and_the_kernels_alone(small, place):
     want = {"moe_ffn_grouped": 3, "moe_ffn_held_experts": 3,
             "moe_ffn_row_bound": 3}
     if place == "tpu":
-        want.update(flash_attention=4, flash_attention_bwd=4)
+        want.update(flash_attention=4, flash_attention_bwd=4,
+                    flash_fwd_visited_blocks=4, flash_fwd_masked_blocks=4)
     assert got == want
 
 
