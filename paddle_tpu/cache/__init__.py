@@ -26,6 +26,7 @@ monitor-registry counters additionally tick when FLAGS_monitor is on
 
 import os
 import pickle
+import time
 from collections import OrderedDict
 
 import jax
@@ -178,7 +179,7 @@ class CompileCache:
             "misses": self.misses,
             "evictions": self.evictions,
             "l2": {
-                "enabled": self.l2_enabled(),
+                "enabled": self._l2_enabled(),
                 "dir": flags.get("compile_cache_dir") or None,
                 "hits": self.l2_hits,
                 "misses": self.l2_misses,
@@ -191,22 +192,46 @@ class CompileCache:
             },
         }
 
+    # -- a miss of L1: L2, then fresh -----------------------------------
+    def load_or_build(self, key, *, content, program, build, devices,
+                      extra=(), use_cache=True, mon=None):
+        """What an executor does with a key `get` did not find: the stored
+        executable of the key's content digest loaded onto `devices` and
+        guarded through its first call, or `build(aot)` — the caller's
+        closure, handed the export hook for
+        executor_core.compile_step_fn(aot=...), None when nothing is to be
+        exported; then `put`. Returns (callable, level, seconds): level
+        "l2" for a loaded executable, None for a fresh one. `content` is
+        the key's process-stable part (executor_core.step_key) and `extra`
+        the caller's device / mesh context; `use_cache=False` builds fresh
+        and touches neither level."""
+        t0 = time.perf_counter()
+        digest = stable_digest(
+            program, content,
+            extra=(("kind", self.kind),) + tuple(extra)) \
+            if use_cache and self._l2_enabled() else None
+        loaded = self._l2_load(digest, devices, mon=mon) \
+            if digest is not None else None
+        if loaded is not None:
+            # warm start (a restarted process, a fleet replica, an elastic
+            # re-join): deserialized instead of compiled
+            value, level = self._guard_l2(
+                loaded, lambda: build(None), mon=mon), "l2"
+        else:
+            value, level = build(self._aot_sink(digest)), None
+        seconds = time.perf_counter() - t0
+        if use_cache:
+            self.put(key, value, mon=mon)
+        return value, level, seconds
+
     # -- L2 ------------------------------------------------------------
-    def l2_enabled(self):
+    def _l2_enabled(self):
         return bool(flags.get("compile_cache_dir"))
 
     def store(self):
         return default_store()
 
-    def l2_digest(self, program, key_tail, extra=()):
-        """Stable store key for one L1 key: its content part
-        (executor_core.step_key) + the executor kind + the caller's
-        device/mesh context."""
-        return stable_digest(
-            program, key_tail,
-            extra=(("kind", self.kind),) + tuple(extra))
-
-    def l2_load(self, digest, devices, mon=None):
+    def _l2_load(self, digest, devices, mon=None):
         """Deserialize one stored executable into a callable Compiled,
         loaded onto `devices` — the executor's own device assignment, in
         mesh order. Without it jax loads onto every device of the default
@@ -252,7 +277,7 @@ class CompileCache:
         blob = service.fetch_blob(digest, wait_s=0.0)
         if blob is None:
             if service.try_lease(digest):
-                # our lease: compile here; aot_sink publishes the blob
+                # our lease: compile here; _aot_sink publishes the blob
                 self.l2_remote_misses += 1
                 _l2_count("remote_misses", self.kind)
                 return None
@@ -288,13 +313,13 @@ class CompileCache:
                 mon.extra = {}
             mon.extra["cache_l2_fallback"] = reason or "fallback"
 
-    def aot_sink(self, digest, meta=None):
+    def _aot_sink(self, digest, meta=None):
         """Export callback for executor_core.compile_step_fn(aot=...):
         receives the freshly AOT-compiled executable once, right after its
         first execution is set up, and serializes it into the store. None
         when L2 is off (compile_step_fn then skips the AOT detour). Export
         failures are swallowed — a cache write must never fail the step."""
-        if digest is None or not self.l2_enabled():
+        if digest is None or not self._l2_enabled():
             return None
 
         def sink(compiled_exe):
@@ -325,7 +350,7 @@ class CompileCache:
 
         return sink
 
-    def guard_l2(self, loaded, rebuild, mon=None):
+    def _guard_l2(self, loaded, rebuild, mon=None):
         """Wrap a deserialized executable so a latent incompatibility the
         header checks can't see (aval/sharding/device-assignment drift)
         surfaces on the FIRST call — jax validates arguments before

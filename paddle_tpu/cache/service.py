@@ -4,11 +4,11 @@ The service itself lives on the elastic master (parallel/master.py
 ``compiled_put`` / ``compiled_get`` / ``compiled_lease``): an in-memory
 blob table keyed by the L2 content digest, plus single-flight compile
 leases. This module is the executor-side client, wired into
-CompileCache.l2_load's miss path (cache/__init__.py):
+CompileCache._l2_load's miss path (cache/__init__.py):
 
     L2 miss -> fetch_blob(digest, wait=0)        peer already compiled?
             -> try_lease(digest)                 no: race for the lease
-               granted      -> compile HERE; aot_sink publishes the blob
+               granted      -> compile HERE; _aot_sink publishes the blob
                not granted  -> fetch_blob(digest, wait=WAIT_S)
                                (park until the leaseholder publishes;
                                 a dead leaseholder's lease expires and

@@ -379,8 +379,6 @@ def compile_step_fn(step, donate_state=True, donate_feeds=False,
     AOT signature (jax validates args BEFORE dispatch, so nothing has
     been donated yet) the call retreats to the retracing jit for good."""
     donate = (0,) if donate_state else ()
-    if not donate_feeds and probe is None and aot is None:
-        return jax.jit(step, donate_argnums=donate)
     compiled = jax.jit(
         step, donate_argnums=donate + ((2,) if donate_feeds else ()))
     probed = [probe is None]
@@ -481,7 +479,7 @@ def step_key(program, feed_vals, fetch_names, state_names, *, iters=None,
     step, K = the K-step scan), the wire spec, feed donation, the health
     plan, and the caller's `extra` entries (ParallelExecutor: zero1 /
     overlap / autoshard / pipeline). It is built from sorted tuples of
-    primitives only, so CompileCache.l2_digest can take it as it is.
+    primitives only, so CompileCache.load_or_build can digest it as it is.
 
     The in-memory (L1) key is identity + content. A trace-affecting input
     that is not in here is a silently reused executable: add it here, and

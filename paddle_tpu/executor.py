@@ -7,6 +7,7 @@ host-side ops (save/load/print/readers/listen_and_serv) run in the eager
 interpret mode, matching the reference's op-by-op Executor semantics.
 """
 
+import collections
 import time
 
 import numpy as np
@@ -182,13 +183,239 @@ def stack_multi_step_feeds(program, feed, iters, wire=None):
     return vals
 
 
-def lap_call(mon, was_miss, build_s, fp):
-    """Close the call of the compiled step (both executors): `dispatch`
-    (enqueue time under async dispatch) on a hit or an L2 load, `compile`
-    on a miss, whose first call holds the XLA compile."""
-    call_s = mon.lap("compile" if was_miss else "dispatch")
-    if was_miss and mon.monitored:
-        monitor.record_compile(fp, wall_s=build_s + call_s)
+def to_host(value):
+    if isinstance(value, SeqTensor):
+        return executor_core.value_to_lod_tensor(value)
+    return value
+
+
+def step_rng(program, step0, iters=None):
+    """The rng of the step numbered `step0`: folded on the host for one
+    step; (base, step0) for a scan, whose step i folds base at step0 + i
+    itself (executor_core.build_multi_step_fn) — the stream of `iters`
+    sequential calls. step0 rides as a traced array: a Python int would
+    bake into the computation and compile again every call."""
+    base = jax.random.PRNGKey(program.random_seed)
+    if iters is None:
+        return jax.random.fold_in(base, step0)
+    return base, jnp.asarray(step0, jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# The compiled-step path of Executor.run and ParallelExecutor.run, in call
+# order: begin_step, [the caller's feed values], prepare_step, split_state,
+# [the caller's rng and its call of step.compiled], finish_step, end_step.
+# Between them the monitor's laps tile the step.
+# ---------------------------------------------------------------------------
+def begin_step(kind, feed, iters, donate_feeds, fetch_list):
+    """The head of run(): the step's record, the pull from a
+    datapipe.DataPipe (anything with next_feed(); the step's `feed_wait`
+    lap), the transfer engine's markers off the chunk, the donation gate
+    and the fetch names. The record is None when FLAGS_monitor and
+    FLAGS_trace are both off (two flag reads), and every telemetry site
+    gates on `mon is not None`; its laps tile the step: each closes the
+    stretch since the one before under the phase's name.
+    Returns (mon, pipe, feed, iters, wire, donate_feeds, fetch_names)."""
+    _apply_debug_nans()
+    monitored = monitor.enabled()
+    mon = monitor.step_begin(kind, monitored) \
+        if monitored or _trace.enabled() else None
+    pipe = feed if hasattr(feed, "next_feed") else None
+    if pipe is not None:
+        if iters is None:
+            iters = getattr(pipe, "feed_iters", None)
+        feed = pipe.next_feed()
+        if mon is not None:
+            mon.lap("feed_wait")
+    from .datapipe.transfer import pop_markers
+    feed, wire, chunk_donate = pop_markers(feed)
+    if donate_feeds is None:
+        donate_feeds = chunk_donate
+    # under debug_nans jax re-runs the step op by op and needs its inputs
+    donate_feeds = bool(donate_feeds) \
+        and bool(flags.get("donate_feed_buffers")) \
+        and not flags.get("debug_nans")
+    fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                   for v in fetch_list or []]
+    return mon, pipe, feed, iters, wire, donate_feeds, fetch_names
+
+
+def end_step(mon, outs, pipe, iters, async_fetch, return_numpy, **replica):
+    """The end of run(): the `write_back` lap (scope.set_var over the
+    state, health and NaN checks, leaving the watchdog and the device
+    scope or mesh), futures or the host read-back, and the record closed.
+    `replica`: the ParallelExecutor's replica_ms / replica_ids."""
+    if mon is not None:
+        mon.lap("write_back")
+    if async_fetch:
+        outs = [FetchFuture(o) for o in outs]
+    elif return_numpy:
+        outs = [as_numpy(o) for o in outs]
+        if mon is not None:
+            mon.lap("fetch_readback")
+    if mon is not None:
+        monitor.step_end(mon, iters=iters, datapipe=pipe, **replica)
+    return outs
+
+
+def _wire_var_dtypes(program, wire):
+    gb = program.global_block()
+    out = {}
+    for n in wire:
+        var = gb.vars.get(n)
+        if var is not None and var.dtype is not None:
+            out[n] = var.dtype
+    return out
+
+
+# one prepared step: what split_state and finish_step need of prepare_step
+_Step = collections.namedtuple(
+    "_Step", "compiled program scope fetch_names state_names "
+             "state_out_names iters hplan mon kind was_miss build_s fp")
+
+
+def prepare_step(cache, program, scope, feed_vals, fetch_names, *, iters,
+                 wire, donate_feeds, mon, kind, devices, l2_extra,
+                 use_cache=True, key_extra=(), verify=None,
+                 constraints=None):
+    """The compiled step of `program` for these feeds, fetches and the
+    state `scope` holds: from `cache`, or built and stored there.
+
+    devices: the executor's device assignment in mesh order (a loaded
+    executable is bound to it). The ParallelExecutor's own: `key_extra`
+    (step_key's extra), `verify` (ensure_verified's mesh_axes / zplan /
+    aplan), `constraints` (a callable giving build_step_fn's, asked only
+    when a step is built). `l2_extra()` gives the device context folded
+    into the persistent digest, asked on a miss."""
+    # the refresh first: it may put a copy into the scope that the names
+    # must include
+    executor_core.refresh_kept_copies(program, scope)
+    state_names, state_out_names = executor_core.collect_state_names(
+        program, scope)
+    if mon is not None:
+        mon.lap("state_gather")
+    # health sees the program as resolved, so under zero1 the plan pairs
+    # the canonical param with its reduce-scattered [N, shard] grad —
+    # shard-local reductions, no regather (health/stats.py)
+    hplan = _health.plan_if_enabled(program)
+    ident, content = executor_core.step_key(
+        program, feed_vals, fetch_names, state_names, iters=iters,
+        wire=wire, donate_feeds=donate_feeds, health=hplan, extra=key_extra)
+    cache_key = ident + content
+    compiled = cache.get(cache_key) if use_cache else None
+    fp = None
+    if mon is not None:
+        fp = monitor.fingerprint_of(cache_key)
+        mon.lap("cache_lookup")
+    build_s, level = 0.0, "l1"
+    if compiled is None:
+        # FLAGS_verify: static checks ride the compile-cache MISS path
+        # only (memoized per program+mutation+config), so the enabled
+        # flag's steady-state cost is this one dict lookup
+        analysis.ensure_verified(
+            program, feed_names=list(feed_vals),
+            fetch_names=list(fetch_names),
+            donate_state=not flags.get("debug_nans"), context=kind,
+            **(verify or {}))
+
+        def build(aot):
+            step = executor_core.build_step_fn(
+                program,
+                list(fetch_names) + hplan.fetch_names
+                if hplan is not None else fetch_names,
+                state_out_names,
+                constraints=constraints() if constraints is not None
+                else None)
+            if wire is not None:
+                # decode INSIDE the per-step fn: a scan slices the compact
+                # [K, ...] wire chunk and each iteration casts/scales only
+                # its own step's slice — the full-width tensor never
+                # exists as [K, ...] in device memory
+                step = wire.wrap_step(
+                    step, var_dtypes=_wire_var_dtypes(program, wire))
+            if hplan is not None:
+                # fold the appended grad fetches into one [4]-stat leaf
+                # per param INSIDE the jit and BEFORE the scan wraps them:
+                # a scan stacks tiny stats, never raw [K, ...] gradients
+                # (health/stats.py)
+                step = hplan.wrap_step(step, len(fetch_names))
+            if iters is not None:
+                step = executor_core.build_multi_step_fn(step, iters)
+            probe = monitor.compile_probe(fp) \
+                if mon is not None and mon.monitored \
+                and flags.get("monitor_hlo_cost") else None
+            # under debug_nans the trap fires INSIDE compiled() before
+            # the scope write-back; donated buffers would already be
+            # deleted, wrecking both the scope and jax's op-by-op
+            # re-run — so trade the in-place update away while the
+            # sanitizer is on
+            return executor_core.compile_step_fn(
+                step, donate_state=not flags.get("debug_nans"),
+                donate_feeds=donate_feeds, probe=probe, aot=aot)
+
+        compiled, level, build_s = cache.load_or_build(
+            cache_key, content=content, program=program, build=build,
+            devices=devices, extra=l2_extra(), use_cache=use_cache, mon=mon)
+        if mon is not None:
+            # a miss compiles inside the first call as well (async
+            # dispatch): both stretches are the `compile` phase
+            mon.lap("cache_load" if level == "l2" else "compile")
+    if mon is not None:
+        mon.mark_cache(level is not None, fingerprint=fp, level=level,
+                       lowered=executor_core.lowered_counts(
+                           program, devices[0]))
+    return _Step(compiled, program, scope, fetch_names, state_names,
+                 state_out_names, iters, hplan, mon, kind, level is None,
+                 build_s, fp)
+
+
+def split_state(step, place=None):
+    """The scope's values of the step's state as (mut_state, const_state):
+    what the step writes, which the jit donates, and what it only reads.
+    place(name, value): the ParallelExecutor's placement onto its mesh."""
+    if step.iters is not None:
+        missing = [n for n in step.state_out_names
+                   if not step.scope.has_var(n)]
+        if missing:
+            raise ValueError(
+                f"iters > 1 needs every written persistable var in scope "
+                f"before the scan (the carry structure is fixed); missing: "
+                f"{missing}. Run the startup program (or one plain "
+                f"exe.run) first.")
+    mut_state, const_state = {}, {}
+    out_set = set(step.state_out_names)
+    for n in step.state_names:
+        v = step.scope.find_var(n)
+        if isinstance(v, LoDTensor):
+            v = executor_core.feed_to_tracevalue(v)
+        if place is not None:
+            v = place(n, v)
+        (mut_state if n in out_set else const_state)[n] = v
+    return mut_state, const_state
+
+
+def finish_step(step, fetches, new_mut, step0):
+    """After the call of step.compiled: the health leaf off the fetches,
+    the call's lap — `dispatch` (enqueue time under async dispatch) on a
+    hit or a load from the persistent store, `compile` on a miss, whose
+    first call holds the XLA compile — the state written back, the health
+    hook. Returns the caller's fetches."""
+    mon, hstats = step.mon, None
+    if step.hplan is not None:
+        hstats, fetches = fetches[-1], fetches[:-1]
+    if mon is not None:
+        call_s = mon.lap("compile" if step.was_miss else "dispatch")
+        if step.was_miss and mon.monitored:
+            monitor.record_compile(step.fp, wall_s=step.build_s + call_s)
+    # write back BEFORE any nan check can raise: mut_state was donated,
+    # so skipping this would leave the scope holding deleted buffers
+    for n, v in new_mut.items():
+        step.scope.set_var(n, v)
+    executor_core.note_kept_copies(step.program, step.scope, new_mut)
+    if hstats is not None:
+        _health.on_step(step0, step.iters, hstats, step.fetch_names,
+                        fetches, mon=mon, kind=step.kind)
+    return fetches
 
 
 class FetchFuture:
@@ -311,77 +538,37 @@ class Executor:
             program = default_main_program()
         if scope is None:
             scope = global_scope()
-        # the per-step flag reads (FLAGS_monitor, FLAGS_trace); mon stays
-        # None when both are off and every telemetry site below is gated on
-        # `mon is not None`. Its laps tile the step: each closes the
-        # stretch since the one before under the phase's name.
-        monitored = monitor.enabled()
-        mon = monitor.step_begin("executor", monitored) \
-            if monitored or _trace.enabled() else None
-        pipe = feed if hasattr(feed, "next_feed") else None
-        if pipe is not None:  # datapipe.DataPipe (duck-typed)
-            if iters is None:
-                iters = getattr(pipe, "feed_iters", None)
-            feed = pipe.next_feed()
-            if mon is not None:
-                mon.lap("feed_wait")
+        mon, pipe, feed, iters, wire, donate_feeds, fetch_names = \
+            begin_step("executor", feed, iters, donate_feeds, fetch_list)
         if isinstance(feed, (list, tuple)) and iters is None:
             iters = len(feed)  # length consistency checked in the helper
         feed = feed if feed is not None else {}
-        from .datapipe.transfer import pop_markers
-        feed, wire, chunk_donate = pop_markers(feed)
-        if donate_feeds is None:
-            donate_feeds = chunk_donate
-        donate_feeds = bool(donate_feeds) \
-            and bool(flags.get("donate_feed_buffers"))
-        fetch_list = fetch_list or []
-        fetch_names = [
-            v.name if isinstance(v, Variable) else str(v) for v in fetch_list
-        ]
-
-        _apply_debug_nans()
         # fault-injection hook (no-op unless a ChaosMonkey is installed);
         # fires BEFORE the dispatch so donated feed buffers are untouched
         # when an injected transient error reaches the retry layer
         _chaos.on_run("executor")
         with _watchdog.armed("executor"), self._device_scope():
-            if iters is not None:
-                # ANY explicit iters (including 1) means "feeds carry a
-                # leading [K] axis, fetches come back stacked [K, ...]" —
-                # routing K=1 to the plain path would feed the stacked
-                # array with its bogus leading axis straight into the ops
-                if iters < 1:
-                    raise ValueError(f"iters must be >= 1, got {iters}")
-                if _program_has_host_ops(program):
-                    raise ValueError(
-                        "iters requires a fully compilable program "
-                        "(host-side ops like readers/save/print run "
-                        "step-by-step)")
-                outs = self._run_compiled_multi(
+            # ANY explicit iters (including 1) means "feeds carry a
+            # leading [K] axis, fetches come back stacked [K, ...]" —
+            # routing K=1 to the plain path would feed the stacked
+            # array with its bogus leading axis straight into the ops
+            if iters is not None and iters < 1:
+                raise ValueError(f"iters must be >= 1, got {iters}")
+            if not _program_has_host_ops(program):
+                outs = self._run_compiled(
                     program, scope, feed, fetch_names, use_program_cache,
                     iters, wire=wire, donate_feeds=donate_feeds, mon=mon)
-            elif _program_has_host_ops(program):
+            elif iters is not None:
+                raise ValueError(
+                    "iters requires a fully compilable program "
+                    "(host-side ops like readers/save/print run "
+                    "step-by-step)")
+            else:
                 if mon is not None:
                     mon.kind = "executor_eager"
                 outs = self._run_eager(program, scope, feed, fetch_names,
                                        wire=wire, mon=mon)
-            else:
-                outs = self._run_compiled(
-                    program, scope, feed, fetch_names, use_program_cache,
-                    wire=wire, donate_feeds=donate_feeds, mon=mon)
-        if mon is not None:
-            # scope.set_var over the state, health and NaN checks, and
-            # leaving the watchdog and the device scope
-            mon.lap("write_back")
-        if async_fetch:
-            outs = [FetchFuture(o) for o in outs]
-        elif return_numpy:
-            outs = [as_numpy(o) for o in outs]
-            if mon is not None:
-                mon.lap("fetch_readback")
-        if mon is not None:
-            monitor.step_end(mon, iters=iters, datapipe=pipe)
-        return outs
+        return end_step(mon, outs, pipe, iters, async_fetch, return_numpy)
 
     # ------------------------------------------------------------------
     def _feed_values(self, program, feed, wire=None, decode_eager=False):
@@ -406,313 +593,75 @@ class Executor:
             vals[name] = tv
         return vals
 
-    def _wire_var_dtypes(self, program, wire):
-        gb = program.global_block()
-        out = {}
-        for n in wire:
-            var = gb.vars.get(n)
-            if var is not None and var.dtype is not None:
-                out[n] = var.dtype
-        return out
-
-    def _rng_for(self, program):
+    def _rng_for(self, program, iters=None):
+        """The next step's rng (the next `iters` steps' for a scan) of the
+        program's own stream; the counter is the program's step number
+        (trainer.py restores it with a checkpoint)."""
         key = id(program)
-        step = self._step_counter.get(key, 0)
-        self._step_counter[key] = step + 1
-        return jax.random.fold_in(jax.random.PRNGKey(program.random_seed), step)
+        step0 = self._step_counter.get(key, 0)
+        self._step_counter[key] = step0 + (1 if iters is None else iters)
+        return step_rng(program, step0, iters)
 
     # ------------------------------------------------------------------
-    def _cache_store(self, cache_key, entry, mon=None):
-        """Insert a compile-cache entry; cache.CompileCache owns the
-        FLAGS_compile_cache_cap true-LRU eviction and its counters."""
-        self._compile_cache.put(cache_key, entry, mon=mon)
-
     def _run_compiled(self, program, scope, feed, fetch_names, use_cache,
-                      wire=None, donate_feeds=False, mon=None):
-        feed_vals = self._feed_values(program, feed, wire=wire)
+                      iters=None, wire=None, donate_feeds=False, mon=None):
+        feed_vals = self._feed_values(program, feed, wire=wire) \
+            if iters is None \
+            else stack_multi_step_feeds(program, feed, iters, wire=wire)
         if mon is not None:
             mon.lap("feed_encode")
-        executor_core.refresh_kept_copies(program, scope)
-        state_names, state_out_names = executor_core.collect_state_names(program, scope)
-        if mon is not None:
-            mon.lap("state_gather")
-        if flags.get("debug_nans"):
-            donate_feeds = False  # re-run needs the inputs (see below)
-        hplan = _health.plan_if_enabled(program)
-        ident, content = executor_core.step_key(
-            program, feed_vals, fetch_names, state_names, wire=wire,
-            donate_feeds=donate_feeds, health=hplan)
-        cache_key = ident + content
-        entry = self._compile_cache.get(cache_key) if use_cache else None
-        fp = None
-        if mon is not None:
-            fp = monitor.fingerprint_of(cache_key)
-            mon.lap("cache_lookup")
-        build_s = 0.0
-        was_miss = entry is None
-        level = "l1" if entry is not None else None
-        if entry is None:
-            # FLAGS_verify: static checks ride the compile-cache MISS path
-            # only (memoized per program+mutation+config), so the enabled
-            # flag's steady-state cost is this one dict lookup
-            analysis.ensure_verified(
-                program, feed_names=list(feed_vals),
-                fetch_names=list(fetch_names),
-                donate_state=not flags.get("debug_nans"),
-                context="executor")
-            tb = time.perf_counter()
-            cache_obj = self._compile_cache
-            digest = cache_obj.l2_digest(
-                program, content, extra=self._l2_extra()) \
-                if use_cache and cache_obj.l2_enabled() else None
-
-            def _fresh(export_digest=None):
-                built_fetch = (list(fetch_names) + hplan.fetch_names
-                               if hplan is not None else fetch_names)
-                step = executor_core.build_step_fn(
-                    program, built_fetch, state_out_names)
-                if wire is not None:
-                    step = wire.wrap_step(
-                        step,
-                        var_dtypes=self._wire_var_dtypes(program, wire))
-                if hplan is not None:
-                    # fold the appended grad fetches into one [4]-stat leaf
-                    # per param INSIDE the jit (health/stats.py)
-                    step = hplan.wrap_step(step, len(fetch_names))
-                probe = monitor.compile_probe(fp) \
-                    if mon is not None and mon.monitored \
-                    and flags.get("monitor_hlo_cost") else None
-                # under debug_nans the trap fires INSIDE compiled() before
-                # the scope write-back; donated buffers would already be
-                # deleted, wrecking both the scope and jax's op-by-op
-                # re-run — so trade the in-place update away while the
-                # sanitizer is on
-                return executor_core.compile_step_fn(
-                    step, donate_state=not flags.get("debug_nans"),
-                    donate_feeds=donate_feeds, probe=probe,
-                    aot=cache_obj.aot_sink(export_digest))
-
-            loaded = cache_obj.l2_load(
-                digest, [jax_device_for(self.place)], mon=mon) \
-                if digest is not None else None
-            if loaded is not None:
-                # warm start: deserialized from FLAGS_compile_cache_dir
-                # instead of compiling; a first-call signature mismatch
-                # falls back to a fresh compile (guard_l2)
-                compiled = cache_obj.guard_l2(loaded, _fresh, mon=mon)
-                was_miss = False
-                level = "l2"
-            else:
-                compiled = _fresh(digest)
-            build_s = time.perf_counter() - tb
-            entry = (compiled, state_names, state_out_names)
-            if use_cache:
-                self._cache_store(cache_key, entry, mon=mon)
-            if mon is not None:
-                # a miss compiles inside the first call as well (async
-                # dispatch): both stretches are the `compile` phase
-                mon.lap("cache_load" if level == "l2" else "compile")
-        if mon is not None:
-            mon.mark_cache(not was_miss, fingerprint=fp, level=level,
-                           lowered=executor_core.lowered_counts(
-                               program, jax_device_for(self.place)))
-        compiled, state_names, state_out_names = entry
-
-        mut_state = {}
-        const_state = {}
-        out_set = set(state_out_names)
-        for n in state_names:
-            v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                v = executor_core.feed_to_tracevalue(v)
-            (mut_state if n in out_set else const_state)[n] = v
+        step = prepare_step(
+            self._compile_cache, program, scope, feed_vals, fetch_names,
+            iters=iters, wire=wire, donate_feeds=donate_feeds, mon=mon,
+            kind="executor", devices=[jax_device_for(self.place)],
+            l2_extra=self._l2_extra, use_cache=use_cache)
+        mut_state, const_state = split_state(step)
         step0 = self._step_counter.get(id(program), 0)
-        rng = self._rng_for(program)
-        t0 = time.perf_counter() if flags.get("benchmark") else None
+        rng = self._rng_for(program, iters)
+        t0 = time.perf_counter() \
+            if iters is None and flags.get("benchmark") else None
         if mon is not None:
             mon.lap("state_gather")
-        fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
-        hstats = None
-        if hplan is not None:
-            hstats = fetches[-1]
-            fetches = fetches[:-1]
-        if mon is not None:
-            lap_call(mon, was_miss, build_s, fp)
-        # write back BEFORE any nan check can raise: mut_state was donated,
-        # so skipping this would leave the scope holding deleted buffers
-        for n, v in new_mut.items():
-            scope.set_var(n, v)
-        executor_core.note_kept_copies(program, scope, new_mut)
-        if t0 is not None:  # FLAGS_benchmark: synchronize + report
-            jax.block_until_ready((fetches, new_mut))
-            import sys
-            # reference FLAGS_benchmark also reports per-op memory
-            # (executor.cc:339); XLA owns allocation here, so the
-            # equivalent debugging signal is the device's peak-HBM mark
-            mem = ""
-            try:
-                stats = jax_device_for(self.place).memory_stats() or {}
-                peak = stats.get("peak_bytes_in_use")
-                if peak is not None:
-                    mem = f" peak_hbm={peak / 1e6:.1f}MB"
-            except Exception:
-                pass
-            # the timing is a metric first, a log line second: record the
-            # fenced wall time in the monitor registry and print THAT value
-            reg = monitor.registry()
-            g = reg.gauge("benchmark_run_ms",
-                          help="FLAGS_benchmark fenced wall time per run")
-            g.set((time.perf_counter() - t0) * 1000.0)
-            reg.histogram("benchmark_run_ms_hist",
-                          help="FLAGS_benchmark fenced wall time "
-                               "distribution").observe(g.value)
-            print(f"[paddle_tpu] run: {g.value:.3f}"
-                  f" ms (fetches={len(fetches)}){mem}", file=sys.stderr)
-        if hstats is not None:
-            _health.on_step(step0, None, hstats, fetch_names, fetches,
-                            mon=mon, kind="executor")
+        fetches, new_mut = step.compiled(mut_state, const_state, feed_vals,
+                                         rng)
+        fetches = finish_step(step, fetches, new_mut, step0)
+        if t0 is not None:
+            self._report_benchmark(t0, fetches, new_mut)
         if flags.get("check_nan_inf"):
             # per-op blame isn't available inside one XLA computation; check
             # the step boundary (fetches + updated state) and name the var
             executor_core.check_values_finite(
                 list(zip(fetch_names, fetches)) + list(new_mut.items()),
-                context=" after compiled step")
-        return [self._to_host(f) for f in fetches]
+                context=" after compiled step" if iters is None
+                else f" after compiled {iters}-step scan")
+        return [to_host(f) for f in fetches]
 
-    def _stack_feeds(self, program, feed, iters, wire=None):
-        return stack_multi_step_feeds(program, feed, iters, wire=wire)
-
-    def _run_compiled_multi(self, program, scope, feed, fetch_names,
-                            use_cache, iters, wire=None, donate_feeds=False,
-                            mon=None):
-        feed_vals = self._stack_feeds(program, feed, iters, wire=wire)
-        if mon is not None:
-            mon.lap("feed_encode")
-        executor_core.refresh_kept_copies(program, scope)
-        state_names, state_out_names = executor_core.collect_state_names(
-            program, scope)
-        missing = [n for n in state_out_names if not scope.has_var(n)]
-        if missing:
-            raise ValueError(
-                f"iters > 1 needs every written persistable var in scope "
-                f"before the scan (the carry structure is fixed); missing: "
-                f"{missing}. Run the startup program (or one plain "
-                f"exe.run) first.")
-        out_set = set(state_out_names)
-        mut_state, const_state = {}, {}
-        for n in state_names:
-            v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                v = executor_core.feed_to_tracevalue(v)
-            (mut_state if n in out_set else const_state)[n] = v
-        if mon is not None:
-            mon.lap("state_gather")
-        if flags.get("debug_nans"):
-            donate_feeds = False  # the op-by-op re-run needs the inputs
-        hplan = _health.plan_if_enabled(program)
-        ident, content = executor_core.step_key(
-            program, feed_vals, fetch_names, state_names, iters=iters,
-            wire=wire, donate_feeds=donate_feeds, health=hplan)
-        cache_key = ident + content
-        entry = self._compile_cache.get(cache_key) if use_cache else None
-        fp = None
-        if mon is not None:
-            fp = monitor.fingerprint_of(cache_key)
-            mon.lap("cache_lookup")
-        build_s = 0.0
-        was_miss = entry is None
-        level = "l1" if entry is not None else None
-        if entry is None:
-            analysis.ensure_verified(
-                program, feed_names=list(feed_vals),
-                fetch_names=list(fetch_names),
-                donate_state=not flags.get("debug_nans"),
-                context="executor")
-            tb = time.perf_counter()
-            cache_obj = self._compile_cache
-            digest = cache_obj.l2_digest(
-                program, content, extra=self._l2_extra()) \
-                if use_cache and cache_obj.l2_enabled() else None
-
-            def _fresh(export_digest=None):
-                built_fetch = (list(fetch_names) + hplan.fetch_names
-                               if hplan is not None else fetch_names)
-                step = executor_core.build_step_fn(
-                    program, built_fetch, state_out_names)
-                if wire is not None:
-                    # decode INSIDE the per-step fn: the scan slices the
-                    # compact [K, ...] wire chunk and each iteration
-                    # casts/scales only its own step's slice — the
-                    # full-width tensor never exists as [K, ...] in device
-                    # memory
-                    step = wire.wrap_step(
-                        step,
-                        var_dtypes=self._wire_var_dtypes(program, wire))
-                if hplan is not None:
-                    # reduce the appended grad fetches to [4]-stat leaves
-                    # per step BEFORE the scan wraps them — the scan then
-                    # stacks tiny stats, never raw [K, ...] gradients
-                    step = hplan.wrap_step(step, len(fetch_names))
-                multi = executor_core.build_multi_step_fn(step, iters)
-                probe = monitor.compile_probe(fp) \
-                    if mon is not None and mon.monitored \
-                    and flags.get("monitor_hlo_cost") else None
-                return executor_core.compile_step_fn(
-                    multi, donate_state=not flags.get("debug_nans"),
-                    donate_feeds=donate_feeds, probe=probe,
-                    aot=cache_obj.aot_sink(export_digest))
-
-            loaded = cache_obj.l2_load(
-                digest, [jax_device_for(self.place)], mon=mon) \
-                if digest is not None else None
-            if loaded is not None:
-                compiled = cache_obj.guard_l2(loaded, _fresh, mon=mon)
-                was_miss = False
-                level = "l2"
-            else:
-                compiled = _fresh(digest)
-            build_s = time.perf_counter() - tb
-            entry = (compiled, state_names, state_out_names)
-            if use_cache:
-                self._cache_store(cache_key, entry, mon=mon)
-            if mon is not None:
-                mon.lap("cache_load" if level == "l2" else "compile")
-        if mon is not None:
-            mon.mark_cache(not was_miss, fingerprint=fp, level=level,
-                           lowered=executor_core.lowered_counts(
-                               program, jax_device_for(self.place)))
-        compiled, state_names, state_out_names = entry
-
-        key = id(program)
-        step0 = self._step_counter.get(key, 0)
-        self._step_counter[key] = step0 + iters
-        # (base, step0) so step i folds to the sequential stream's key;
-        # step0 rides as a traced array to keep the compile cache hot
-        rng = (jax.random.PRNGKey(program.random_seed),
-               jnp.asarray(step0, jnp.int32))
-        fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
-        hstats = None
-        if hplan is not None:
-            hstats = fetches[-1]
-            fetches = fetches[:-1]
-        if mon is not None:
-            lap_call(mon, was_miss, build_s, fp)
-        for n, v in new_mut.items():
-            scope.set_var(n, v)
-        executor_core.note_kept_copies(program, scope, new_mut)
-        if hstats is not None:
-            _health.on_step(step0, iters, hstats, fetch_names, fetches,
-                            mon=mon, kind="executor")
-        if flags.get("check_nan_inf"):
-            executor_core.check_values_finite(
-                list(zip(fetch_names, fetches)) + list(new_mut.items()),
-                context=f" after compiled {iters}-step scan")
-        return [self._to_host(f) for f in fetches]
-
-    def _to_host(self, value):
-        if isinstance(value, SeqTensor):
-            return executor_core.value_to_lod_tensor(value)
-        return value
+    def _report_benchmark(self, t0, fetches, new_mut):
+        """FLAGS_benchmark: synchronize + report."""
+        jax.block_until_ready((fetches, new_mut))
+        import sys
+        # reference FLAGS_benchmark also reports per-op memory
+        # (executor.cc:339); XLA owns allocation here, so the
+        # equivalent debugging signal is the device's peak-HBM mark
+        mem = ""
+        try:
+            stats = jax_device_for(self.place).memory_stats() or {}
+            peak = stats.get("peak_bytes_in_use")
+            if peak is not None:
+                mem = f" peak_hbm={peak / 1e6:.1f}MB"
+        except Exception:
+            pass
+        # the timing is a metric first, a log line second: record the
+        # fenced wall time in the monitor registry and print THAT value
+        reg = monitor.registry()
+        g = reg.gauge("benchmark_run_ms",
+                      help="FLAGS_benchmark fenced wall time per run")
+        g.set((time.perf_counter() - t0) * 1000.0)
+        reg.histogram("benchmark_run_ms_hist",
+                      help="FLAGS_benchmark fenced wall time "
+                           "distribution").observe(g.value)
+        print(f"[paddle_tpu] run: {g.value:.3f}"
+              f" ms (fetches={len(fetches)}){mem}", file=sys.stderr)
 
     # ------------------------------------------------------------------
     def run_block_eager(self, block, scope):
@@ -787,7 +736,7 @@ class Executor:
             scope.set_var(n, env[n])
         outs = []
         for n in fetch_names:
-            outs.append(self._to_host(executor_core.env_get(env, n)))
+            outs.append(to_host(executor_core.env_get(env, n)))
         return outs
 
 

@@ -28,8 +28,6 @@ Multi-node ("NCCL2 mode", num_trainers/trainer_id) maps to jax.distributed
 with a mesh spanning hosts; see parallel/distributed.py.
 """
 
-import time
-
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -39,18 +37,18 @@ from . import flags
 from . import monitor
 from .cache import CompileCache, place_jax_cache
 from .core import executor_core
-from .core.framework import Parameter, Variable, default_main_program
+from .core.framework import Parameter, default_main_program
 from .core.lod_tensor import LoDTensor
 from .core.places import accelerator_devices
 from .core.registry import SeqTensor
 from .core.scope import global_scope
-from .executor import as_numpy, lap_call, _apply_debug_nans
-from . import health as _health
+from .executor import (begin_step, end_step, finish_step, prepare_step,
+                       split_state, stack_multi_step_feeds, step_rng,
+                       to_host)
 from .parallel import autoshard as _autoshard
 from .parallel import zero1 as _zero1
 from .resilience import chaos as _chaos
 from .resilience import watchdog as _watchdog
-from . import trace as _trace
 
 __all__ = ["ParallelExecutor", "ExecutionStrategy", "BuildStrategy"]
 
@@ -180,11 +178,6 @@ class ParallelExecutor:
                 for d in self._devices)),
             ("procs", int(jax.process_count()), int(jax.process_index())),
         )
-
-    def _cache_store(self, cache_key, entry, mon=None):
-        """Insert a compile-cache entry; cache.CompileCache owns the
-        FLAGS_compile_cache_cap true-LRU eviction and its counters."""
-        self._compile_cache.put(cache_key, entry, mon=mon)
 
     # ------------------------------------------------------------------
     def _prepare_program(self, program, use_zero1, gss, dp_n):
@@ -319,61 +312,22 @@ class ParallelExecutor:
         spec = P(None, "dp") if leading_steps else P("dp")
         return jax.device_put(value, NamedSharding(self._mesh, spec))
 
-    # ------------------------------------------------------------------
-    def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True,
-            iters=None, async_fetch=False, donate_feeds=None):
-        """One data-parallel step over the mesh — or, with `iters=K`, K
-        steps inside ONE jit'd lax.scan dispatch (feeds carry a leading
-        [K] axis, batch sharded over "dp" on axis 1; fetches come back
-        stacked [K, ...]). Same contract as Executor.run(iters=K).
+    @property
+    def _dp_size(self):
+        return int(dict(self._mesh.shape).get("dp", 1))
 
-        `feed` may be a datapipe.DataPipe: the next prefetched chunk is
-        pulled here (the step's `feed_wait` phase; DataPipeError from a
-        dead decode worker propagates) and iters defaults to the pipe's
-        chunk size. Transfer-engine markers (WIRE_KEY/DONATE_KEY) riding
-        a staged chunk are honoured the same way as Executor.run: wire
-        decode fused into the compiled step, single-use chunks donated —
-        including chunks staged zero-copy from the process-pool shm ring.
-        `async_fetch=True`
-        returns FetchFuture handles instead of host arrays."""
-        _apply_debug_nans()
-        # two flag reads when monitoring and tracing are off (same
-        # contract as Executor.run); every site below gates on `mon is not
-        # None`, the registry's on `monitored`; the laps tile the step
-        monitored = monitor.enabled()
-        mon = monitor.step_begin("parallel_executor", monitored) \
-            if monitored or _trace.enabled() else None
-        feed = feed if feed is not None else feed_dict
-        pipe = feed if hasattr(feed, "next_feed") else None
-        if pipe is not None:  # datapipe.DataPipe (duck-typed)
-            if iters is None:
-                iters = getattr(pipe, "feed_iters", None)
-            feed = pipe.next_feed()
-            if mon is not None:
-                mon.lap("feed_wait")
-        from .datapipe.transfer import pop_markers
-        feed, wire, chunk_donate = pop_markers(feed)
-        if donate_feeds is None:
-            donate_feeds = chunk_donate
-        donate_feeds = bool(donate_feeds) \
-            and bool(flags.get("donate_feed_buffers")) \
-            and not flags.get("debug_nans")
-        if isinstance(feed, list) and iters is None:
-            # per-device feed list (reference feed_parallel): concatenate
-            merged = {}
-            for d in feed:
-                for k, v in d.items():
-                    arr = np.asarray(v.numpy() if isinstance(v, LoDTensor) else v)
-                    merged.setdefault(k, []).append(arr)
-            feed = {k: np.concatenate(vs, axis=0) for k, vs in merged.items()}
-        fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
-
+    def _resolve_program(self, feed, mon):
+        """The program this run compiles and the plans that shaped it:
+        (program, zplan, osched, aplan, use_zero1). Brings the scope's
+        accumulators into the layout the program expects, sets the
+        monitor's gauges of each plan, and closes a `cache_lookup` lap."""
         program, scope = self._program, self._scope
+        monitored = mon is not None and mon.monitored
         bs = self._build_strategy
         use_zero1 = bs.sharded_weight_update
         if use_zero1 is None:
             use_zero1 = bool(flags.get("zero1"))
-        dp_n = int(dict(self._mesh.shape).get("dp", 1))
+        dp_n = self._dp_size
         use_zero1 = bool(use_zero1) and dp_n >= 2
         gss = bs.gradient_scale_strategy
         # everything below (feed staging, state collection, trace, state
@@ -483,40 +437,67 @@ class ParallelExecutor:
             # program resolution: zero1 / overlap / autoshard plans
             # (memoized per program) and their digests for the cache key
             mon.lap("cache_lookup")
+        return program, zplan, osched, aplan, use_zero1
+
+    # ------------------------------------------------------------------
+    def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True,
+            iters=None, async_fetch=False, donate_feeds=None):
+        """One data-parallel step over the mesh — or, with `iters=K`, K
+        steps inside ONE jit'd lax.scan dispatch (feeds carry a leading
+        [K] axis, batch sharded over "dp" on axis 1; fetches come back
+        stacked [K, ...]). Same contract as Executor.run(iters=K).
+
+        `feed` may be a datapipe.DataPipe: the next prefetched chunk is
+        pulled here (the step's `feed_wait` phase; DataPipeError from a
+        dead decode worker propagates) and iters defaults to the pipe's
+        chunk size. Transfer-engine markers (WIRE_KEY/DONATE_KEY) riding
+        a staged chunk are honoured the same way as Executor.run: wire
+        decode fused into the compiled step, single-use chunks donated —
+        including chunks staged zero-copy from the process-pool shm ring.
+        `async_fetch=True`
+        returns FetchFuture handles instead of host arrays."""
+        mon, pipe, feed, iters, wire, donate_feeds, fetch_names = \
+            begin_step("parallel_executor",
+                       feed if feed is not None else feed_dict, iters,
+                       donate_feeds, fetch_list)
+        if isinstance(feed, list) and iters is None:
+            # per-device feed list (reference feed_parallel): concatenate
+            merged = {}
+            for d in feed:
+                for k, v in d.items():
+                    arr = np.asarray(v.numpy() if isinstance(v, LoDTensor) else v)
+                    merged.setdefault(k, []).append(arr)
+            feed = {k: np.concatenate(vs, axis=0) for k, vs in merged.items()}
+        scope = self._scope
+        program, zplan, osched, aplan, use_zero1 = \
+            self._resolve_program(feed, mon)
         feed_vals = {}
         if iters is not None:
             # shared stacking helper: list-length and leading-axis checks,
             # LoD rejection, dtype cast — the same contract as
             # Executor.run(iters=K); an empty feed list fails there too
-            from .executor import stack_multi_step_feeds
-
             for name, value in stack_multi_step_feeds(
                     program, feed if feed is not None else {},
                     iters, wire=wire).items():
                 feed_vals[name] = self._feed_sharding(
                     value, leading_steps=True)
         else:
-            feed = feed or {}
-            for name, value in feed.items():
+            for name, value in (feed or {}).items():
                 tv = executor_core.feed_to_tracevalue(value)
                 feed_vals[name] = self._feed_sharding(tv)
         if mon is not None:
             # stacking + device_put onto the mesh (the h2d link for feeds)
             mon.lap("feed_encode")
 
-        executor_core.refresh_kept_copies(program, scope)
-        state_names, state_out_names = executor_core.collect_state_names(program, scope)
-        if mon is not None:
-            mon.lap("state_gather")
-        # health sees the RESOLVED program, so under zero1 the plan pairs
-        # the canonical param with its reduce-scattered [N, shard] grad —
-        # shard-local reductions, no regather (health/stats.py)
-        hplan = _health.plan_if_enabled(program)
-        ident, content = executor_core.step_key(
-            program, feed_vals, fetch_names, state_names, iters=iters,
-            wire=wire, donate_feeds=donate_feeds, health=hplan,
-            extra=(
-                ("zero1", use_zero1, gss, dp_n),
+        step = prepare_step(
+            self._compile_cache, program, scope, feed_vals, fetch_names,
+            iters=iters, wire=wire, donate_feeds=donate_feeds, mon=mon,
+            kind="parallel_executor", devices=self._devices,
+            l2_extra=self._l2_extra,
+            key_extra=(
+                ("zero1", use_zero1,
+                 self._build_strategy.gradient_scale_strategy,
+                 self._dp_size),
                 ("overlap",
                  osched.plan.digest() if osched is not None else None),
                 ("autoshard", aplan.digest() if aplan is not None else None),
@@ -524,152 +505,23 @@ class ParallelExecutor:
                 # with each other and the source program; the (plan digest,
                 # stage, phase) tag keeps their executables from colliding
                 ("pipeline", getattr(program, "_pipeline_stage", None)),
-            ))
-        cache_key = ident + content
-        entry = self._compile_cache.get(cache_key)
-        fp = None
-        if mon is not None:
-            fp = monitor.fingerprint_of(cache_key)
-            mon.lap("cache_lookup")
-        build_s = 0.0
-        was_miss = entry is None
-        level = "l1" if entry is not None else None
-        if entry is None:
-            # FLAGS_verify on the MISS path only, with the mesh and the
-            # zero1/autoshard plans in scope so the `full` level can run
-            # the sharding checks and the per-replica peak-HBM estimate
-            analysis.ensure_verified(
-                program, feed_names=list(feed_vals),
-                fetch_names=list(fetch_names),
+            ),
+            # with the mesh and the zero1/autoshard plans in scope the
+            # `full` level of FLAGS_verify can run the sharding checks and
+            # the per-replica peak-HBM estimate
+            verify=dict(
                 mesh_axes=dict(self._mesh.shape),
                 zplan=zplan if use_zero1 and zplan.entries else None,
-                aplan=aplan,
-                donate_state=not flags.get("debug_nans"),
-                context="parallel_executor")
-            tb = time.perf_counter()
-            if iters is not None:
-                missing = [n for n in state_out_names
-                           if not scope.has_var(n)]
-                if missing:
-                    raise ValueError(
-                        f"iters > 1 needs every written persistable var in "
-                        f"scope before the scan; missing: {missing}. Run "
-                        f"the startup program first.")
-            cache_obj = self._compile_cache
-            digest = cache_obj.l2_digest(
-                program, content, extra=self._l2_extra()) \
-                if cache_obj.l2_enabled() else None
+                aplan=aplan),
+            constraints=None if aplan is None else lambda: {
+                n: NamedSharding(self._mesh, P(*s))
+                for n, s in aplan.boundary_specs().items()})
+        mut_state, const_state = split_state(
+            step, place=self._state_placer(program, aplan))
 
-            def _fresh(export_digest=None):
-                constraints = None
-                if aplan is not None:
-                    constraints = {
-                        n: NamedSharding(self._mesh, P(*s))
-                        for n, s in aplan.boundary_specs().items()}
-                built_fetch = (list(fetch_names) + hplan.fetch_names
-                               if hplan is not None else fetch_names)
-                step = executor_core.build_step_fn(
-                    program, built_fetch, state_out_names,
-                    constraints=constraints)
-                if wire is not None:
-                    # decode in the PER-STEP fn (before the scan wrapper),
-                    # so each iteration widens only its own [batch] slice
-                    gb = program.global_block()
-                    var_dtypes = {
-                        n: gb.vars[n].dtype for n in wire
-                        if n in gb.vars and gb.vars[n].dtype is not None}
-                    step = wire.wrap_step(step, var_dtypes=var_dtypes)
-                if hplan is not None:
-                    # per-step stats reduction before any scan wrapper, so
-                    # a K-step scan stacks [4]-stat leaves, not raw grads;
-                    # GSPMD lowers the reductions shard-locally on the mesh
-                    step = hplan.wrap_step(step, len(fetch_names))
-                if iters is not None:
-                    step = executor_core.build_multi_step_fn(step, iters)
-                probe = monitor.compile_probe(fp) \
-                    if monitored and flags.get("monitor_hlo_cost") else None
-                return executor_core.compile_step_fn(
-                    step, donate_state=not flags.get("debug_nans"),
-                    donate_feeds=donate_feeds, probe=probe,
-                    aot=cache_obj.aot_sink(export_digest))
-
-            loaded = cache_obj.l2_load(
-                digest, self._mesh.devices.flat, mon=mon) \
-                if digest is not None else None
-            if loaded is not None:
-                # warm start (fleet replica spin-up, resilience restore,
-                # elastic re-join): deserialized from the shared
-                # FLAGS_compile_cache_dir instead of compiling; a
-                # first-call signature mismatch rebuilds fresh (guard_l2)
-                compiled = cache_obj.guard_l2(loaded, _fresh, mon=mon)
-                was_miss = False
-                level = "l2"
-            else:
-                compiled = _fresh(digest)
-            build_s = time.perf_counter() - tb
-            entry = (compiled, state_names, state_out_names)
-            self._cache_store(cache_key, entry, mon=mon)
-            if mon is not None:
-                mon.lap("cache_load" if level == "l2" else "compile")
-        if mon is not None:
-            mon.mark_cache(not was_miss, fingerprint=fp, level=level,
-                           lowered=executor_core.lowered_counts(
-                               program, self._devices[0]))
-        compiled, state_names, state_out_names = entry
-
-        multiproc = any(
-            d.process_index != jax.process_index()
-            for d in self._mesh.devices.flat)
-
-        def place(v, desired):
-            arr = jax.numpy.asarray(v)
-            if multiproc:
-                # a committed single-device array cannot be resharded onto a
-                # cross-process mesh directly; round-trip through the host —
-                # every process holds the identical global value (same-seed
-                # startup), so device_put scatters consistent local shards
-                arr = np.asarray(arr)
-            return jax.device_put(arr, desired)
-
-        mut_state, const_state = {}, {}
-        out_set = set(state_out_names)
-        for n in state_names:
-            v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                v = executor_core.feed_to_tracevalue(v)
-            var = program.global_block().vars.get(n)
-            annotated = getattr(var, "sharding", None) is not None
-            planned = aplan is not None and bool(aplan.spec_of(n))
-            cur = getattr(v, "sharding", None)
-            on_mesh = isinstance(cur, NamedSharding) and cur.mesh == self._mesh
-            if annotated or planned:
-                # the rule (user seed or plan spec) must win over whatever
-                # placement startup left behind — but once the array already
-                # carries the desired NamedSharding (every step after the
-                # first), re-placing would all-gather the shards to host
-                desired = self._state_sharding(n, v, program=program,
-                                               plan=aplan)
-                if cur != desired:
-                    v = place(v, desired)
-            elif not on_mesh or not getattr(v, "committed", True):
-                # startup leaves single-device committed arrays; a jit over
-                # the mesh auto-transfers those in-process but REJECTS them
-                # when the mesh spans processes — re-place onto this mesh
-                v = place(v, self._state_sharding(n, v, program=program,
-                                                  plan=aplan))
-            (mut_state if n in out_set else const_state)[n] = v
-
-        base_key = jax.random.PRNGKey(program.random_seed)
         step0 = self._step
-        if iters is not None:
-            # multi-step scan folds base at step0+i internally — same rng
-            # stream as iters sequential run() calls (executor_core
-            # build_multi_step_fn); step0 traced to keep the cache hot
-            rng = (base_key, jax.numpy.asarray(self._step, jax.numpy.int32))
-            self._step += iters
-        else:
-            rng = jax.random.fold_in(base_key, self._step)
-            self._step += 1
+        self._step += 1 if iters is None else iters
+        rng = step_rng(program, step0, iters)
         # fault-injection hook (no-op without an installed ChaosMonkey),
         # before the dispatch so donated buffers are intact on a raise
         _chaos.on_run("parallel_executor")
@@ -677,32 +529,23 @@ class ParallelExecutor:
             # scope reads and (first step only) placement onto the mesh
             mon.lap("state_gather")
         with _watchdog.armed("parallel_executor"), self._mesh:
-            fetches, new_mut = compiled(mut_state, const_state, feed_vals, rng)
-        hstats = None
-        if hplan is not None:
-            hstats = fetches[-1]
-            fetches = fetches[:-1]
-        replica_ms = replica_ids = None
-        if mon is not None:
-            if monitored and flags.get("monitor_replica_skew"):
-                # fence each replica's shard of a step output in device
-                # order — stamps per-replica completion. Synchronizes the
-                # dispatch queue, hence the separate opt-in flag.
-                leaf = fetches[0] if fetches else \
-                    next(iter(new_mut.values()), None)
-                if leaf is not None:
-                    # t_lap: the stamp the call of `compiled` started at
-                    res = monitor.measure_replica_ms(leaf, mon.t_lap)
-                    if res is not None:
-                        replica_ms, replica_ids = res
-            lap_call(mon, was_miss, build_s, fp)
-        for n, v in new_mut.items():
-            scope.set_var(n, v)
-        executor_core.note_kept_copies(program, scope, new_mut)
-        if hstats is not None:
-            _health.on_step(step0, iters, hstats, fetch_names, fetches,
-                            mon=mon, kind="parallel_executor")
-        if was_miss and flags.get("verify") == "full":
+            fetches, new_mut = step.compiled(mut_state, const_state,
+                                             feed_vals, rng)
+        replica = {}
+        if mon is not None and mon.monitored \
+                and flags.get("monitor_replica_skew"):
+            # fence each replica's shard of a step output in device
+            # order — stamps per-replica completion. Synchronizes the
+            # dispatch queue, hence the separate opt-in flag.
+            leaf = fetches[0] if fetch_names else \
+                next(iter(new_mut.values()), None)
+            if leaf is not None:
+                # t_lap: the stamp the call of `compiled` started at
+                res = monitor.measure_replica_ms(leaf, mon.t_lap)
+                if res is not None:
+                    replica = dict(replica_ms=res[0], replica_ids=res[1])
+        fetches = finish_step(step, fetches, new_mut, step0)
+        if step.was_miss and flags.get("verify") == "full":
             # measured counterpart of the analysis_peak_hbm gauge: bytes
             # actually resident on one device for this step's state (the
             # estimate is gated against this within 2x in the tests)
@@ -717,24 +560,49 @@ class ParallelExecutor:
         # the donated inputs die here and not at the return, where no phase
         # would see it: some 500 arrays of four shards each are 1.8 ms
         del mut_state, const_state, feed_vals, new_mut
-        if mon is not None:
-            mon.lap("write_back")
-        outs = [
-            executor_core.value_to_lod_tensor(f) if isinstance(f, SeqTensor) else f
-            for f in fetches
-        ]
-        if async_fetch:
-            from .executor import FetchFuture
+        return end_step(mon, [to_host(f) for f in fetches], pipe, iters,
+                        async_fetch, return_numpy, **replica)
 
-            outs = [FetchFuture(o) for o in outs]
-        elif return_numpy:
-            outs = [as_numpy(o) for o in outs]
-            if mon is not None:
-                mon.lap("fetch_readback")
-        if mon is not None:
-            monitor.step_end(mon, iters=iters, datapipe=pipe,
-                             replica_ms=replica_ms, replica_ids=replica_ids)
-        return outs
+    def _state_placer(self, program, aplan):
+        """split_state's `place`: a state value as this mesh must see it."""
+        multiproc = any(
+            d.process_index != jax.process_index()
+            for d in self._mesh.devices.flat)
+
+        def put(v, desired):
+            arr = jax.numpy.asarray(v)
+            if multiproc:
+                # a committed single-device array cannot be resharded onto a
+                # cross-process mesh directly; round-trip through the host —
+                # every process holds the identical global value (same-seed
+                # startup), so device_put scatters consistent local shards
+                arr = np.asarray(arr)
+            return jax.device_put(arr, desired)
+
+        def place(n, v):
+            var = program.global_block().vars.get(n)
+            annotated = getattr(var, "sharding", None) is not None
+            planned = aplan is not None and bool(aplan.spec_of(n))
+            cur = getattr(v, "sharding", None)
+            on_mesh = isinstance(cur, NamedSharding) and cur.mesh == self._mesh
+            if annotated or planned:
+                # the rule (user seed or plan spec) must win over whatever
+                # placement startup left behind — but once the array already
+                # carries the desired NamedSharding (every step after the
+                # first), re-placing would all-gather the shards to host
+                desired = self._state_sharding(n, v, program=program,
+                                               plan=aplan)
+                if cur != desired:
+                    v = put(v, desired)
+            elif not on_mesh or not getattr(v, "committed", True):
+                # startup leaves single-device committed arrays; a jit over
+                # the mesh auto-transfers those in-process but REJECTS them
+                # when the mesh spans processes — re-place onto this mesh
+                v = put(v, self._state_sharding(n, v, program=program,
+                                                plan=aplan))
+            return v
+
+        return place
 
     def bcast_params(self):
         """reference parallel_executor.py:242 — under SPMD params live as

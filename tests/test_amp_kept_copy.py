@@ -301,11 +301,16 @@ def test_the_policy_off_or_another_update_keeps_no_copy():
         "down", "gate", "up"]
 
 
-def test_a_master_written_from_outside_the_step_is_cast_again():
+@pytest.mark.parametrize("runner", ["executor", "executor_scan",
+                                    "parallel_executor",
+                                    "parallel_executor_scan"])
+def test_a_master_written_from_outside_the_step_is_cast_again(runner):
     """`scope.set_var` on a master (a load and a restored checkpoint go
     through it, and so does another program's update): the next step reads
-    the cast of THAT value, as the step without copies does."""
-    feed = {"x": _x(1)["x"][0]}
+    the cast of THAT value, as the step without copies does. Whichever way
+    the step is run: the refresh stands once, in front of every one."""
+    iters = 2 if runner.endswith("_scan") else None
+    feed = {"x": _x(2)["x"] if iters else _x(1)["x"][0]}
     new = np.random.default_rng(1).standard_normal((8, 64, 32)).astype(
         np.float32)
     losses = []
@@ -316,14 +321,23 @@ def test_a_master_written_from_outside_the_step_is_cast_again():
                 _ignoring_copies() if ignore else contextlib.nullcontext()):
             exe = fluid.Executor(fluid.CPUPlace())
             exe.run(startup)
-            got = [exe.run(prog, feed=feed, fetch_list=[loss])[0]]
+            if runner.startswith("parallel_executor"):
+                pe = fluid.ParallelExecutor(
+                    use_cuda=False, loss_name=loss.name, main_program=prog)
+
+                def run():
+                    return pe.run([loss.name], feed=feed, iters=iters)[0]
+            else:
+                def run():
+                    return exe.run(prog, feed=feed, fetch_list=[loss],
+                                   iters=iters)[0]
+            got = [run()]
             scope.set_var("gate", jnp.asarray(new))
-            got += [exe.run(prog, feed=feed, fetch_list=[loss])[0]
-                    for _ in range(2)]
+            got += [run() for _ in range(2)]
             if not ignore:
                 _copies_are_casts(scope, prog)
         losses.append(np.asarray(got))
-    assert _same(*losses) and losses[0][0] != losses[0][1]
+    assert _same(*losses) and (losses[0][0] != losses[0][1]).all()
 
 
 @pytest.mark.parametrize("copies_saved", [False, True],

@@ -254,15 +254,39 @@ def test_zero1_flag_flips_parallel_executor_l2_key(tmp_path):
 # fallbacks: corrupt / stale entries recompile, never raise
 # ---------------------------------------------------------------------------
 
-def test_corrupt_entry_falls_back_and_self_heals(tmp_path):
+# the four ways a compiled step is run (tests/test_trace.py's list and the
+# ParallelExecutor's scan): all take the one path through the cache
+RUNNERS = ["executor", "executor_scan", "parallel_executor",
+           "parallel_executor_scan"]
+
+
+def _runner(runner, main, loss):
+    """(target, run): the Executor or ParallelExecutor named, and one step
+    of `main` on it (one scan of two where the name says so)."""
+    import jax
+
+    iters = 2 if runner.endswith("_scan") else None
+    feed = {"x": np.ones((4, 8) if iters is None else (iters, 4, 8),
+                         np.float32)}
+    if runner.startswith("parallel_executor"):
+        pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                    main_program=main,
+                                    devices=jax.devices()[:4])
+        return pe, lambda: pe.run([loss], feed=feed, iters=iters)
+    exe = fluid.Executor(fluid.CPUPlace())
+    return exe, lambda: exe.run(main, feed=feed, fetch_list=[loss],
+                                iters=iters)
+
+
+@pytest.mark.parametrize("runner", RUNNERS)
+def test_corrupt_entry_falls_back_and_self_heals(tmp_path, runner):
     main, startup, loss = _mlp()
-    feed = {"x": np.ones((4, 8), np.float32)}
     scope = fluid.Scope()
     with flags.flag_guard(compile_cache_dir=str(tmp_path), monitor=True), \
             fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        exe.run(main, feed=feed, fetch_list=[loss])
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        exe, run = _runner(runner, main, loss)
+        run()
         paths = [os.path.join(tmp_path, f) for f in os.listdir(tmp_path)
                  if f.endswith(".aot")]
         assert paths and exe.compile_cache_info()["l2"]["puts"] >= 1
@@ -270,7 +294,7 @@ def test_corrupt_entry_falls_back_and_self_heals(tmp_path):
             _flip_tail(p)
         # force the L1 miss -> L2 path a restarted process would take
         exe._compile_cache.clear()
-        out2, = exe.run(main, feed=feed, fetch_list=[loss])
+        out2, = run()
         info = exe.compile_cache_info()
         snap = monitor.registry().snapshot()
     assert np.isfinite(np.asarray(out2)).all()  # recompiled, ran clean
@@ -280,6 +304,30 @@ def test_corrupt_entry_falls_back_and_self_heals(tmp_path):
     # self-heal: the recompile re-put a valid entry over the corrupt one
     store = L2Store(str(tmp_path))
     assert any(store.get(e["digest"])[0] == "hit" for e in store.entries())
+
+
+@pytest.mark.parametrize("runner", ["executor_scan",
+                                    "parallel_executor_scan"])
+def test_a_scan_names_the_written_var_the_scope_lacks_on_a_hit_too(runner):
+    """A scan carries every persistable var the program writes, so each
+    must be in the scope before it: the error names the var and the cure,
+    from the split of the state that every call makes, also the call that
+    finds its step in the cache."""
+    main, startup, loss = _mlp()
+    weight = main.global_block().all_parameters()[0].name
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        exe, run = _runner(runner, main, loss)
+        run()
+        hits = exe.compile_cache_info()["hits"]
+        scope.erase(weight)
+        for _ in range(2):
+            with pytest.raises(ValueError, match=(
+                    "missing: .*" + weight + ".*Run the startup program "
+                    r"\(or one plain exe.run\) first")):
+                run()
+        assert exe.compile_cache_info()["hits"] == hits + 1
 
 
 def test_store_version_mismatch_is_stale(tmp_path, monkeypatch):
@@ -590,22 +638,22 @@ def test_remote_fetch_commits_to_local_l2_and_counts(tmp_path):
         svc.stop()
 
 
-def test_l2_hit_journals_as_hit_with_cache_load_phase(tmp_path):
+@pytest.mark.parametrize("runner", RUNNERS)
+def test_l2_hit_journals_as_hit_with_cache_load_phase(tmp_path, runner):
     """An L2 warm start is a cache HIT in the journal (level "l2") with
     the deserialize time attributed to a cache_load phase, not compile."""
     main, startup, loss = _mlp()
-    feed = {"x": np.ones((4, 8), np.float32)}
     journal = tmp_path / "journal.jsonl"
     scope = fluid.Scope()
     with flags.flag_guard(compile_cache_dir=str(tmp_path / "store"),
                           monitor=True,
                           monitor_journal=str(journal)), \
             fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        exe.run(main, feed=feed, fetch_list=[loss])
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+        exe, run = _runner(runner, main, loss)
+        run()
         exe._compile_cache.clear()  # simulate the fresh-process L1 miss
-        exe.run(main, feed=feed, fetch_list=[loss])
+        run()
     records = monitor.read_journal(str(journal))
     cold = records[-2]
     warm = records[-1]
