@@ -55,6 +55,10 @@ WHITE_LIST = frozenset({
     # the gated short convolution between two `fc` products: bf16 in and
     # out, its gates and taps float32 inside (ops/lm_ops.py: short_conv)
     "short_conv",
+    # the gated delta rule behind that convolution: bf16 q, k, v in and o
+    # out, g, beta, the decays and the carried state float32 inside
+    # (parallel/delta_rule.py)
+    "gated_delta_rule",
     # the residual path of n streams: the state and the sublayers' outputs
     # flow in the compute dtype, the mixers themselves are float32 inside
     "mhc_expand", "mhc_mix", "mhc_update",
@@ -75,6 +79,9 @@ FLOAT32_SLOTS = {
     "mhc_update": frozenset({"HRes", "HPost"}),
     # the taps: a [L, C] float32 master read as it is
     "short_conv": frozenset({"Filter"}),
+    # A_log, dt_bias: [Hv] float32 masters read as they are; the saved
+    # chunk-start states stay float32
+    "gated_delta_rule": frozenset({"ALog", "DtBias", "States"}),
 }
 
 # Input slots of a white-list op whose value the lowering hands to a Pallas

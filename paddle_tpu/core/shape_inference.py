@@ -831,20 +831,63 @@ def _short_conv(ctx):
     if x is None:
         return
     seq_len = int(ctx.attr("seq_len") or 0)
-    ctx.enforce(len(x) == 2 and (x[1] < 0 or x[1] % 3 == 0),
+    # gating "silu": X [T, C] whole; else the thirds B, C, z side by side
+    parts = 1 if ctx.attr("gating") else 3
+    ctx.enforce(len(x) == 2 and (x[1] < 0 or x[1] % parts == 0),
                 f"X must be [T, 3C] (the thirds B, C, z), got {x}")
     ctx.enforce(seq_len > 0 and (x[0] < 0 or x[0] % seq_len == 0),
                 f"seq_len {seq_len} must divide X's {x[0]} tokens")
     if f is not None:
         ctx.enforce(len(f) == 2 and f[0] >= 1
-                    and (x[1] < 0 or _dim_match(f[1], x[1] // 3)),
+                    and (x[1] < 0 or _dim_match(f[1], x[1] // parts)),
                     f"Filter{f} must be [L, C] of X{x}")
-    ctx.set_output_dim("Out", (x[0], x[1] // 3 if x[1] > 0 else -1))
+    ctx.set_output_dim("Out", (x[0], x[1] // parts if x[1] > 0 else -1))
 
 
 @register_infer_shape("short_conv_grad")
 def _short_conv_grad(ctx):
     for slot in ("X", "Filter"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "@GRAD", d)
+
+
+@register_infer_shape("gated_delta_rule")
+def _gated_delta_rule(ctx):
+    x, ba = ctx.input_dim("QKV"), ctx.input_dim("BA")
+    if x is None:
+        return
+    hk, hv, dk, dv = (int(ctx.attr(k) or 0) for k in (
+        "num_k_heads", "num_v_heads", "head_k_dim", "head_v_dim"))
+    seq_len, chunk = int(ctx.attr("seq_len") or 0), int(ctx.attr("chunk") or 0)
+    ctx.enforce(min(hk, hv, dk, dv, chunk) > 0 and hv % hk == 0,
+                f"{hv} value heads must be a multiple of {hk} key heads")
+    ctx.enforce(len(x) == 2 and (x[1] < 0 or x[1] == 2 * hk * dk + hv * dv),
+                f"QKV must be [T, {2 * hk * dk + hv * dv}] = [q | k | v], "
+                f"got {x}")
+    ctx.enforce(seq_len > 0 and (x[0] < 0 or x[0] % seq_len == 0),
+                f"seq_len {seq_len} must divide QKV's {x[0]} tokens")
+    if ba is not None:
+        ctx.enforce(len(ba) == 2 and (ba[1] < 0 or ba[1] == 2 * hv)
+                    and _dim_match(ba[0], x[0]),
+                    f"BA{ba} must be [T, {2 * hv}] = [b | a] of QKV{x}")
+    for slot in ("ALog", "DtBias"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.enforce(tuple(d) == (hv,), f"{slot}{d} must be [Hv={hv}]")
+    from ..parallel.delta_rule import states_shape
+
+    rows = x[0] // seq_len if x[0] > 0 else -1
+    ctx.set_output_dim("Out", (x[0], hv * dv))
+    states = states_shape(max(rows, 1), seq_len, hk, hv, dk, dv, chunk)
+    ctx.set_output_dim("States", states if rows > 0
+                       else states[:2] + (-1,) + states[3:])
+    ctx.set_output_dim("FinalState", (rows, hv, dk, dv))
+
+
+@register_infer_shape("gated_delta_rule_grad")
+def _gated_delta_rule_grad(ctx):
+    for slot in ("QKV", "BA", "ALog", "DtBias"):
         d = ctx.input_dim(slot)
         if d is not None:
             ctx.set_output_dim(slot + "@GRAD", d)

@@ -19,7 +19,7 @@ __all__ = [
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
-    "short_conv", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
+    "short_conv", "gated_delta_rule", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_first_step", "sequence_last_step", "dropout",
     "l2_normalize", "matmul", "topk", "warpctc", "sequence_reshape",
@@ -696,7 +696,8 @@ def causal_attention(q, k, v, scale=None, window=None, name=None):
     return y
 
 
-def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None):
+def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None,
+               gating=None):
     """The operator of a gated short-convolution layer (LFM2's): `input`
     [T, 3C], the input projection's output, its thirds B, C, z side by
     side, T = rows x `seq_len` tokens -> [T, C] = C * conv(B * z), conv a
@@ -704,21 +705,68 @@ def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None):
     tokens (zero before a row's first token: rows are separate
     sequences), no bias. The taps are one parameter [kernel_size, C],
     float32 under AMP; the gates and the taps' sum are float32 whatever
-    the input's dtype, rounded once on the output."""
+    the input's dtype, rounded once on the output. `gating` "silu": the
+    convolution of a Gated DeltaNet layer instead, `input` [T, C] -> [T,
+    C] = silu(conv(input)), the taps [kernel_size, C]."""
     helper = LayerHelper("short_conv", **locals())
     dtype = helper.input_dtype()
     width = int(input.shape[-1])
-    if width % 3:
+    if gating not in (None, "silu"):
+        raise ValueError(f"short_conv: gating {gating!r} is not 'silu'")
+    parts = 1 if gating else 3
+    if width % parts:
         raise ValueError(f"short_conv: the input's last dimension {width} "
                          "is not three thirds B, C, z")
     taps = helper.create_parameter(
-        attr=helper.param_attr, shape=[int(kernel_size), width // 3],
+        attr=helper.param_attr, shape=[int(kernel_size), width // parts],
         dtype=dtype)
     y = helper.create_tmp_variable(
-        dtype, shape=tuple(input.shape[:-1]) + (width // 3,))
+        dtype, shape=tuple(input.shape[:-1]) + (width // parts,))
+    attrs = {"seq_len": int(seq_len)}
+    if gating:
+        attrs["gating"] = gating
     helper.append_op("short_conv", {"X": [input], "Filter": [taps]},
-                     {"Out": [y]}, {"seq_len": int(seq_len)})
+                     {"Out": [y]}, attrs)
     return y
+
+
+def gated_delta_rule(qkv, ba, seq_len, num_k_heads, num_v_heads, head_k_dim,
+                     head_v_dim, a_log_attr=None, dt_bias_attr=None,
+                     chunk=None, epsilon=1e-6, name=None):
+    """The recurrence of a Gated DeltaNet layer (arXiv:2412.06464): `qkv`
+    [T, 2 Hk dk + Hv dv] = [q | k | v] (the convolution's output), `ba` [T,
+    2 Hv] = [b | a], T = rows x `seq_len` tokens -> (out [T, Hv dv], the
+    state behind each row's last token [rows, Hv, dk, dv] float32). q and k
+    are L2-normalised a head (q times dk^-1/2), beta = sigmoid(b), g =
+    -exp(A_log) softplus(a + dt_bias) with A_log, dt_bias two parameters
+    [Hv] (float32 under AMP), and per value head S <- exp(g_t) S; S <- S +
+    beta_t k_t (v_t - S^T k_t)^T; o_t = S^T q_t from S = 0 at a row's
+    first token, in chunks of `chunk` tokens (the lowering's own
+    `parallel.delta_rule.CHUNK` where None) with a hand-written backward
+    (ops/lm_ops.py: gated_delta_rule, parallel/delta_rule.py)."""
+    from ..parallel import delta_rule
+
+    helper = LayerHelper("gated_delta_rule", **locals())
+    dtype = qkv.dtype
+    a_log, dt_bias = (
+        helper.create_parameter(attr=ParamAttr.to_attr(a),
+                                shape=[int(num_v_heads)], dtype="float32",
+                                default_initializer=Constant(0.0))
+        for a in (a_log_attr, dt_bias_attr))
+    width = int(num_v_heads) * int(head_v_dim)
+    y = helper.create_tmp_variable(dtype, shape=(qkv.shape[0], width))
+    states = helper.create_tmp_variable("float32", stop_gradient=True)
+    last = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op(
+        "gated_delta_rule",
+        {"QKV": [qkv], "BA": [ba], "ALog": [a_log], "DtBias": [dt_bias]},
+        {"Out": [y], "States": [states], "FinalState": [last]},
+        {"seq_len": int(seq_len), "num_k_heads": int(num_k_heads),
+         "num_v_heads": int(num_v_heads), "head_k_dim": int(head_k_dim),
+         "head_v_dim": int(head_v_dim),
+         "chunk": int(chunk or delta_rule.CHUNK),
+         "epsilon": float(epsilon)})
+    return y, last
 
 
 def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,

@@ -20,7 +20,12 @@ cfg)`, `smallthinker.smallthinker_loss`, `smallthinker.optimizer`; and
 attention with per-head QK norm in the rest, a dense SwiGLU first and
 sigmoid-routed experts with the model's own bias after, the head the
 embedding table itself; as one chip's share of the experts and of the
-vocabulary): `lfm2.lfm2(tokens, cfg)`, `lfm2.lfm2_loss`, `lfm2.optimizer`.
+vocabulary): `lfm2.lfm2(tokens, cfg)`, `lfm2.lfm2_loss`, `lfm2.optimizer`;
+and `qwen3_next` (a gated delta rule's matrix-valued state behind a 4-tap
+convolution in three of four layers, output-gated grouped-query attention
+at heads of 256 in the fourth, softmax top-10 experts beside a gated shared
+expert; as one chip's share): `qwen3_next.qwen3_next(tokens, cfg)`,
+`qwen3_next.qwen3_next_loss`, `qwen3_next.optimizer`.
 """
 
 from . import mnist
@@ -34,10 +39,11 @@ from . import xing4
 from . import laguna
 from . import smallthinker
 from . import lfm2
+from . import qwen3_next
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
            "machine_translation", "olmoe", "xing4", "laguna", "smallthinker",
-           "lfm2"]
+           "lfm2", "qwen3_next"]
 
 
 def get_model(name):

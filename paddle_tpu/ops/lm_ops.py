@@ -240,6 +240,9 @@ def causal_attention_grad_op(ctx, ins, attrs):
 # the tokens after), FILTER_GRAD the reduction over the tokens of d conv
 # times the shifted v, a tap.
 GATE, TAPS, FILTER_GRAD = "gate", "taps", "filter_grad"
+# the op's attr `gating`: absent, LFM2's C * conv(B * z) on [T, 3C]; SILU,
+# silu(conv(x)) on [T, C] (Gated DeltaNet's, over its q, k and v channels)
+SILU = "silu"
 
 
 def _whole(results):
@@ -318,6 +321,42 @@ def short_conv_grad(x, w, d_out, seq_len):
     return _whole((d_x.reshape(x.shape).astype(x.dtype), d_w))
 
 
+def silu_conv(x, w, seq_len):
+    """Out [T, C] = silu(causal_depthwise_conv_L(X)) of X [T, C] and the
+    taps w [L, C] (w[L - 1] weighs the token itself), `short_conv`'s
+    variant `gating="silu"`: the L shifted multiply-adds and the SiLU in
+    float32, one rounding to X's dtype."""
+    L = w.shape[0]
+    xr, wf = _rows(x, seq_len), w.astype(F32)
+    with jax.named_scope(TAPS):
+        conv = sum(wf[j] * win.astype(F32)
+                   for j, win in enumerate(_shifted(xr, L, past=True)))
+    with jax.named_scope(GATE):
+        return _whole(jax.nn.silu(conv).reshape(x.shape).astype(x.dtype))
+
+
+def silu_conv_grad(x, w, d_out, seq_len):
+    """(d X [T, C] in X's dtype, d Filter [L, C] float32) of `silu_conv`
+    from X, the taps and d Out alone: the convolution is formed again, d
+    conv = d Out * silu'(conv), d X the taps' transpose over the tokens
+    after, d Filter a float32 reduction over the tokens, a tap."""
+    L = w.shape[0]
+    xr, wf = _rows(x, seq_len), w.astype(F32)
+    back = [win.astype(F32) for win in _shifted(xr, L, past=True)]
+    with jax.named_scope(GATE):
+        conv = sum(wf[j] * back[j] for j in range(L))
+        s = jax.nn.sigmoid(conv)
+        d_conv = d_out.reshape(xr.shape).astype(F32) \
+            * (s * (1.0 + conv * (1.0 - s)))
+    with jax.named_scope(TAPS):
+        d_x = sum(wf[j] * win for j, win in enumerate(
+            _shifted(d_conv, L, past=False)))
+    with jax.named_scope(FILTER_GRAD):
+        d_w = jnp.stack([jnp.sum(back[j] * d_conv, axis=(0, 1))
+                         for j in range(L)])
+    return _whole((d_x.reshape(x.shape).astype(x.dtype), d_w))
+
+
 @register_op("short_conv")
 def short_conv_op(ctx, ins, attrs):
     """The operator of a gated short-convolution layer (LFM2's, Liquid AI):
@@ -330,9 +369,22 @@ def short_conv_op(ctx, ins, attrs):
     X's dtype on Out. On a TPU place the Pallas kernel of
     parallel/short_conv.py where it takes the shapes (one pass: X read
     once, Out written); elsewhere L shifted multiply-adds along the token
-    axis: no transpose to [rows, C, S], no grouped convolution."""
+    axis: no transpose to [rows, C, S], no grouped convolution.
+
+    With the attr `gating` = "silu" the op is the convolution of a Gated
+    DeltaNet layer instead: X [T, C], Out [T, C] = silu(c), c_t = sum_j
+    Filter[j] x_{t - (L - 1 - j)}, the sum and the SiLU in float32, one
+    rounding; on a TPU place the variant's Pallas kernels where they take
+    the shapes (`parallel/short_conv.py: silu_conv_fwd`), L shifted
+    multiply-adds elsewhere (`silu_conv`)."""
     x, w = first(ins, "X"), first(ins, "Filter")
     seq_len = int(attrs["seq_len"])
+    if attrs.get("gating") == SILU:
+        if _silu_kernels_take(x, w, seq_len):
+            from ..parallel.short_conv import silu_conv_fwd
+
+            return out(Out=silu_conv_fwd(x, w, seq_len))
+        return out(Out=silu_conv(x, w, seq_len))
     if _conv_kernels_take(x, w, seq_len):
         from ..parallel.short_conv import short_conv_fwd
 
@@ -348,6 +400,14 @@ def _conv_kernels_take(x, w, seq_len):
 
     return on_tpu() and F32 == jnp.float32 and kernels.takes(
         x.shape[0], x.shape[1] // 3, seq_len, w.shape[0], x.dtype)
+
+
+def _silu_kernels_take(x, w, seq_len):
+    """`_conv_kernels_take` for the variant silu(conv(x))."""
+    from ..parallel import short_conv as kernels
+
+    return on_tpu() and F32 == jnp.float32 and kernels.silu_takes(
+        x.shape[0], x.shape[1], seq_len, w.shape[0], x.dtype)
 
 
 @register_grad_maker("short_conv")
@@ -369,12 +429,86 @@ def short_conv_grad_op(ctx, ins, attrs):
     form's."""
     x, w = first(ins, "X"), first(ins, "Filter")
     seq_len = int(attrs["seq_len"])
-    if _conv_kernels_take(x, w, seq_len):
+    if attrs.get("gating") == SILU:
+        if _silu_kernels_take(x, w, seq_len):
+            from ..parallel.short_conv import silu_conv_bwd as grad
+        else:
+            grad = silu_conv_grad
+    elif _conv_kernels_take(x, w, seq_len):
         from ..parallel.short_conv import short_conv_bwd as grad
     else:
         grad = short_conv_grad
     d_x, d_w = grad(x, w, first(ins, "Out@GRAD"), seq_len)
     return out(**{"X@GRAD": d_x, "Filter@GRAD": d_w.astype(w.dtype)})
+
+
+# ------------------------------------------------------- gated_delta_rule
+def _delta_shape(attrs):
+    """The keyword arguments of `parallel/delta_rule.py`'s two functions."""
+    return dict(seq_len=int(attrs["seq_len"]), hk=int(attrs["num_k_heads"]),
+                hv=int(attrs["num_v_heads"]), dk=int(attrs["head_k_dim"]),
+                dv=int(attrs["head_v_dim"]), chunk=int(attrs["chunk"]),
+                eps=float(attrs.get("epsilon", 1e-6)))
+
+
+_DELTA_INPUTS = ("QKV", "BA", "ALog", "DtBias")
+
+
+@register_op("gated_delta_rule")
+def gated_delta_rule_op(ctx, ins, attrs):
+    """The recurrence of a Gated DeltaNet layer (Yang et al.,
+    arXiv:2412.06464; Qwen3-Next's linear-attention layers). QKV [T, 2 Hk
+    dk + Hv dv] = [q | k | v], the convolution's output as it leaves it, T
+    = rows x `seq_len` tokens; BA [T, 2 Hv] = [b | a]; ALog, DtBias [Hv]
+    (float32 masters read as they are) -> Out [T, Hv dv]. Per head q and k
+    are divided by sqrt(sum of squares + `epsilon`), q times dk^-1/2 as
+    well; key head j serves the value heads (Hv / Hk) j ...; beta =
+    sigmoid(b), g = -exp(ALog) softplus(a + DtBias), float32; per value
+    head a state S [dk, dv], zero at a row's first token (rows are
+    separate sequences): S <- exp(g_t) S; S <- S + beta_t k_t (v_t - S^T
+    k_t)^T; o_t = S^T q_t. Worked in chunks of `chunk` tokens
+    (`parallel/delta_rule.py`): the in-chunk quantities as batched
+    products over all chunks, one [dk, dk] x [dk, dv] product a chunk and
+    head in a scan, the carried state float32. States [head groups, chunks,
+    rows x Hv / groups, dk, dv] float32, the state each chunk starts from,
+    is kept for the backward op; FinalState [rows, Hv, dk, dv] is the state behind each
+    row's last token."""
+    from ..parallel.delta_rule import delta_rule_fwd
+
+    o, starts, last = delta_rule_fwd(
+        *(first(ins, s) for s in _DELTA_INPUTS), **_delta_shape(attrs))
+    return out(Out=o, States=starts, FinalState=last)
+
+
+set_stop_gradient_outputs("gated_delta_rule", ["States", "FinalState"])
+
+
+@register_grad_maker("gated_delta_rule")
+def _gated_delta_rule_grad_maker(op, gout, gin):
+    """Hand-written: the generic vjp of the chunk scan would keep every
+    chunk's in-chunk quantities; this one takes the chunk-start states the
+    forward left and forms the rest again."""
+    inputs = {s: op.input(s) for s in _DELTA_INPUTS}
+    inputs["States"] = op.output("States")
+    inputs["Out@GRAD"] = [x or "" for x in gout.get("Out", [])]
+    return [dict(
+        type="gated_delta_rule_grad", inputs=inputs,
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in _DELTA_INPUTS},
+        attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
+
+
+@register_op("gated_delta_rule_grad")
+def gated_delta_rule_grad_op(ctx, ins, attrs):
+    """d QKV, d BA (in their dtypes), d ALog, d DtBias of
+    `gated_delta_rule`: the reverse recurrence of the state's cotangent
+    over the chunks, written out, from the saved chunk-start states."""
+    from ..parallel.delta_rule import delta_rule_bwd
+
+    args = [first(ins, s) for s in _DELTA_INPUTS]
+    grads = delta_rule_bwd(*args, first(ins, "States"),
+                           first(ins, "Out@GRAD"), **_delta_shape(attrs))
+    return out(**{s + "@GRAD": g.astype(a.dtype)
+                  for s, g, a in zip(_DELTA_INPUTS, grads, args)})
 
 
 # ----------------------------------------------------------------- moe_ffn
@@ -1172,11 +1306,32 @@ def _conv_kernel_takes(op, block):
     dimension of -1, are taken to be whole rows)."""
     from ..parallel import short_conv as kernels
 
+    if op.attrs.get("gating"):
+        return False
     x, w = (block.vars[op.input(s)[0]] for s in ("X", "Filter"))
     seq_len = int(op.attrs["seq_len"])
     tokens = x.shape[0] if x.shape[0] > 0 else seq_len
     low = amp.compute_dtype() if amp.is_enabled() else x.dtype
     return kernels.takes(tokens, x.shape[1] // 3, seq_len, w.shape[0], low)
+
+
+def _conv_is_silu(op, block):
+    """A `short_conv` (or its grad) of the variant silu(conv(x))."""
+    return op.attrs.get("gating") == SILU
+
+
+def _silu_kernel_takes(op, block):
+    """Whether the Pallas kernels of the variant take this `short_conv` (or
+    its grad), from the shapes the program states."""
+    from ..parallel import short_conv as kernels
+
+    if not _conv_is_silu(op, block):
+        return False
+    x, w = (block.vars[op.input(s)[0]] for s in ("X", "Filter"))
+    seq_len = int(op.attrs["seq_len"])
+    tokens = x.shape[0] if x.shape[0] > 0 else seq_len
+    low = amp.compute_dtype() if amp.is_enabled() else x.dtype
+    return kernels.silu_takes(tokens, x.shape[1], seq_len, w.shape[0], low)
 
 
 def _reads_a_tied_table(op, block):
@@ -1267,8 +1422,10 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("lookup_table_grad", "lookup_table_grad_tiled", True,
              _grad_by_row_tiles),
             ("moe_ffn", "moe_ffn_rows_by_token", True, _sums_rows_by_token),
-            ("short_conv", "short_conv_gated", False, None),
-            ("short_conv_grad", "short_conv_grad_by_hand", False, None),
+            ("short_conv", "short_conv_gated", False,
+             lambda op, block: not _conv_is_silu(op, block)),
+            ("short_conv_grad", "short_conv_grad_by_hand", False,
+             lambda op, block: not _conv_is_silu(op, block)),
             ("short_conv", "short_conv_kernel", True, _conv_kernel_takes),
             ("short_conv_grad", "short_conv_grad_kernel", True,
              _conv_kernel_takes),
@@ -1278,7 +1435,19 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("causal_attention", "flash_fwd_masked_blocks", True,
              _forward_blocks("blocks_masked")),
             ("causal_attention", "flash_fwd_visited_blocks", True,
-             _forward_blocks("blocks_visited")))
+             _forward_blocks("blocks_visited")),
+            ("short_conv", "short_conv_silu", False, _conv_is_silu),
+            ("short_conv_grad", "short_conv_silu_grad_by_hand", False,
+             _conv_is_silu),
+            ("short_conv", "short_conv_silu_kernel", True,
+             _silu_kernel_takes),
+            ("short_conv_grad", "short_conv_silu_grad_kernel", True,
+             _silu_kernel_takes),
+            ("gated_delta_rule", "delta_rule_chunked", False, None),
+            ("gated_delta_rule_grad", "delta_rule_grad_by_hand", False,
+             None),
+            ("causal_attention", "flash_attention_head_256", True,
+             lambda op, block: block.vars[op.input("Q")[0]].shape[3] >= 256))
 
 
 def lowered_counts(program, device):
@@ -1314,6 +1483,16 @@ def lowered_counts(program, device):
     of its `causal_attention` ops visit a head and those of them that pay
     for the mask, summed over the ops (`flash_fwd_visited_blocks`,
     `flash_fwd_masked_blocks`: `flash.blocks_visited`, `.blocks_masked`).
+    Its `short_conv` ops of the variant silu(conv(x)) and their grads
+    (`short_conv_silu`, `short_conv_silu_grad_by_hand`; on a TPU place
+    those whose shapes the variant's Pallas kernels take count as
+    `short_conv_silu_kernel` / `short_conv_silu_grad_kernel` too, the
+    others lower as L shifted multiply-adds), its `gated_delta_rule`
+    ops (`delta_rule_chunked`: the chunked form as batched products and one
+    scan, on every place; no Pallas kernel yet, so no `delta_rule_kernel`
+    is reported) and their grads (`delta_rule_grad_by_hand`), and on a TPU
+    place its `causal_attention` ops at heads of 256 or more
+    (`flash_attention_head_256`).
     A program without them reports none. Kept on the program until that
     is mutated or the mixed-precision policy changes, like
     `bn_pool.count`."""

@@ -33,11 +33,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.places import pallas_interpret
 
-__all__ = ["short_conv_fwd", "short_conv_bwd", "takes"]
+__all__ = ["short_conv_fwd", "short_conv_bwd", "takes", "silu_conv_fwd",
+           "silu_conv_bwd", "silu_takes"]
 
 # the kernels' names: Pallas puts them on the name stack, so a device trace
 # reads `conv/short_conv/short_conv/short_conv_fwd`
 KERNELS = ("short_conv_fwd", "short_conv_bwd")
+# the variant silu(conv(x)) of a Gated DeltaNet layer (the op's attr
+# `gating` = "silu"): X [T, C] whole, no gates
+SILU_KERNELS = ("silu_conv_fwd", "silu_conv_bwd")
 _VMEM_LIMIT = 64 * 2 ** 20       # of the v5e's 128 MiB
 _BLOCKS = (256, 128, 64, 32, 16)     # tokens a block, tried in this order
 _CHANNELS = (512, 256, 128)      # channels worked through at a time
@@ -133,9 +137,12 @@ def _bwd_kernel(x_ref, before_ref, after_ref, g_ref, g_after_ref, w_ref,
                 back[k] * d_conv, axis=0, keepdims=True)
 
 
-def _plan(x, seq_len):
-    T, C = x.shape[0], x.shape[1] // 3
-    block_s = _first_dividing(seq_len, _BLOCKS)
+def _plan(x, seq_len, parts=3, block_s=None):
+    """The grid and the index maps over X [T, parts x C] in rows of
+    `seq_len`, in blocks of `block_s` tokens (the largest of `_BLOCKS` that
+    divides a row where none is given)."""
+    T, C = x.shape[0], x.shape[1] // parts
+    block_s = block_s or _first_dividing(seq_len, _BLOCKS)
     n_blocks = seq_len // block_s
     per = block_s // _HALO            # halo views a block
     last_view = T // _HALO - 1
@@ -194,3 +201,122 @@ def short_conv_bwd(x, w, d_out, seq_len):
                    jax.ShapeDtypeStruct((L, C), jnp.float32)],
         compiler_params=_params(), interpret=pallas_interpret(),
         name=KERNELS[1])(x, x, x, g, g, w)
+
+
+# ------------------------------------------------------ the silu variant
+# Out [T, C] = silu(conv(X)), X [T, C]: the same blocks, halos and sublane
+# rotations; what differs is that d conv = d Out * silu'(conv) needs the
+# convolution of the L - 1 rows AFTER a block too, which the backward forms
+# from the halo after the block and the block's own last rows.
+def silu_takes(n_tokens, channels, seq_len, taps, dtype):
+    """`takes` for the variant; a block holds all `channels` of X, Out (and
+    in the backward d Out and d X) twice over in VMEM, so the block shrinks
+    as the channels grow."""
+    return takes(n_tokens, channels, seq_len, taps, dtype) \
+        and _silu_block(seq_len, channels) is not None
+
+
+def _silu_block(seq_len, channels):
+    # five [block, channels] bf16 arrays, double-buffered, in 40 MiB
+    fits = [b for b in _BLOCKS if 5 * 2 * 2 * b * channels <= 40 * 2 ** 20]
+    return _first_dividing(seq_len, fits)
+
+
+def _silu_grad(conv):
+    s = jax.nn.sigmoid(conv)
+    return s * (1.0 + conv * (1.0 - s))
+
+
+def _silu_fwd_kernel(x_ref, before_ref, w_ref, o_ref, *, L, C, cc):
+    first = pl.program_id(1) == 0
+    for c0 in range(0, C, cc):
+        before = jnp.where(first, 0.0,
+                           before_ref[:, c0:c0 + cc].astype(F32))
+        back = _shifts_back(x_ref[:, c0:c0 + cc].astype(F32), before, L)
+        w = w_ref[:, c0:c0 + cc].astype(F32)
+        conv = sum(w[L - 1 - k][None, :] * back[k] for k in range(L))
+        o_ref[:, c0:c0 + cc] = (conv * jax.nn.sigmoid(conv)).astype(
+            o_ref.dtype)
+
+
+def _silu_bwd_kernel(x_ref, before_ref, after_ref, g_ref, g_after_ref, w_ref,
+                     dx_ref, dw_ref, *, L, C, cc, block_s):
+    i = pl.program_id(1)
+    first, last = i == 0, i == pl.num_programs(1) - 1
+
+    @pl.when((pl.program_id(0) == 0) & first)
+    def _zero():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    n = block_s + _HALO
+    for c0 in range(0, C, cc):
+        x = x_ref[:, c0:c0 + cc].astype(F32)
+        w = w_ref[:, c0:c0 + cc].astype(F32)
+        before = jnp.where(first, 0.0,
+                           before_ref[:, c0:c0 + cc].astype(F32))
+        back = _shifts_back(x, before, L)
+        conv = sum(w[L - 1 - k][None, :] * back[k] for k in range(L))
+        d_conv = g_ref[:, c0:c0 + cc].astype(F32) * _silu_grad(conv)
+        d_x = w[L - 1][None, :] * d_conv
+        if L > 1:
+            # the halo after the block: its convolution reads the block's
+            # last rows, its d conv reaches back into the block
+            x_after = after_ref[:, c0:c0 + cc].astype(F32)
+            back_after = _shifts_back(x_after, x[block_s - _HALO:], L)
+            conv_after = sum(w[L - 1 - k][None, :] * back_after[k]
+                             for k in range(L))
+            after = jnp.where(
+                last, 0.0, g_after_ref[:, c0:c0 + cc].astype(F32)
+                * _silu_grad(conv_after))
+            both = jnp.concatenate([d_conv, after], axis=0)
+            for k in range(1, L):
+                d_x = d_x + w[L - 1 - k][None, :] * _roll(
+                    both, n - k)[:block_s]
+        dx_ref[:, c0:c0 + cc] = d_x.astype(dx_ref.dtype)
+        for k in range(L):
+            j = L - 1 - k
+            dw_ref[j:j + 1, c0:c0 + cc] += jnp.sum(
+                back[k] * d_conv, axis=0, keepdims=True)
+
+
+def _silu_plan(x, seq_len):
+    return _plan(x, seq_len, parts=1,
+                 block_s=_silu_block(seq_len, x.shape[1]))
+
+
+def silu_conv_fwd(x, w, seq_len):
+    """Out [T, C] = silu(conv(X)) in X's dtype. `silu_takes` must hold."""
+    T, C, block_s, grid, cc, block, before, _ = _silu_plan(x, seq_len)
+    L = w.shape[0]
+    return pl.pallas_call(
+        functools.partial(_silu_fwd_kernel, L=L, C=C, cc=cc),
+        grid=grid,
+        in_specs=[pl.BlockSpec((block_s, C), block),
+                  pl.BlockSpec((_HALO, C), before),
+                  pl.BlockSpec((L, C), lambda r, i: (0, 0))],
+        out_specs=pl.BlockSpec((block_s, C), block),
+        out_shape=jax.ShapeDtypeStruct((T, C), x.dtype),
+        compiler_params=_params(), interpret=pallas_interpret(),
+        name=SILU_KERNELS[0])(x, x, w)
+
+
+def silu_conv_bwd(x, w, d_out, seq_len):
+    """(d X [T, C] in X's dtype, d Filter [L, C] float32)."""
+    T, C, block_s, grid, cc, block, before, after = _silu_plan(x, seq_len)
+    L, g = w.shape[0], d_out.astype(x.dtype)
+    return pl.pallas_call(
+        functools.partial(_silu_bwd_kernel, L=L, C=C, cc=cc,
+                          block_s=block_s),
+        grid=grid,
+        in_specs=[pl.BlockSpec((block_s, C), block),
+                  pl.BlockSpec((_HALO, C), before),
+                  pl.BlockSpec((_HALO, C), after),
+                  pl.BlockSpec((block_s, C), block),
+                  pl.BlockSpec((_HALO, C), after),
+                  pl.BlockSpec((L, C), lambda r, i: (0, 0))],
+        out_specs=[pl.BlockSpec((block_s, C), block),
+                   pl.BlockSpec((L, C), lambda r, i: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((T, C), x.dtype),
+                   jax.ShapeDtypeStruct((L, C), jnp.float32)],
+        compiler_params=_params(), interpret=pallas_interpret(),
+        name=SILU_KERNELS[1])(x, x, x, g, g, w)

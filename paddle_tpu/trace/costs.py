@@ -79,7 +79,19 @@ def _estimate(block, op, batch):
         fs = op.input("Filter")
         f_shape = _shape_of(block, fs[0], batch) if fs else None
         taps = int(f_shape[0]) if f_shape else 3
+        if op.attrs.get("gating"):
+            # L multiply-adds and a SiLU (4) an output
+            return out_elems * (2.0 * taps + 4.0)
         return out_elems * (2.0 * taps + 2.0)
+    if t == "gated_delta_rule":
+        # the chunked form a token and value head: q k^T and k k^T (4 C
+        # dk), the triangular solve (C (dk + dv)), W S, Q S and K^T U (6 dk
+        # dv), P U (2 C dv)
+        a = op.attrs
+        c, dk, dv = (int(a.get(k, d)) for k, d in (
+            ("chunk", 64), ("head_k_dim", 128), ("head_v_dim", 128)))
+        return out_elems / dv * (4.0 * c * dk + c * (dk + dv)
+                                 + 6.0 * dk * dv + 2.0 * c * dv)
     if t in ("pool2d", "pool3d"):
         k = op.attrs.get("ksize") or []
         kk = 1.0
