@@ -468,16 +468,42 @@ def gated_delta_rule_op(ctx, ins, attrs):
     separate sequences): S <- exp(g_t) S; S <- S + beta_t k_t (v_t - S^T
     k_t)^T; o_t = S^T q_t. Worked in chunks of `chunk` tokens
     (`parallel/delta_rule.py`): the in-chunk quantities as batched
-    products over all chunks, one [dk, dk] x [dk, dv] product a chunk and
-    head in a scan, the carried state float32. States [head groups, chunks,
-    rows x Hv / groups, dk, dv] float32, the state each chunk starts from,
-    is kept for the backward op; FinalState [rows, Hv, dk, dv] is the state behind each
+    products over all chunks (on a TPU place, for shapes they take, in the
+    Pallas kernels of `parallel/delta_parts.py`), one [dk, dk] x [dk, dv]
+    product a chunk and head in a scan, the carried state float32. States
+    [head groups, chunks, rows x Hv / groups, dk, dv] float32 ([chunks,
+    rows x Hv, dk, dv] from the kernel path, which works all heads at
+    once), the state each chunk starts from, is kept for the backward op;
+    FinalState [rows, Hv, dk, dv] is the state behind each
     row's last token."""
-    from ..parallel.delta_rule import delta_rule_fwd
+    from ..parallel import delta_rule
 
-    o, starts, last = delta_rule_fwd(
+    qkv = first(ins, "QKV")
+    fwd = delta_rule.kernels_fwd if _delta_kernels_take(qkv, attrs) \
+        else delta_rule.delta_rule_fwd
+    o, starts, last = fwd(
         *(first(ins, s) for s in _DELTA_INPUTS), **_delta_shape(attrs))
     return out(Out=o, States=starts, FinalState=last)
+
+
+def _delta_kernels_take(qkv, attrs):
+    """Whether this trace hands the op's in-chunk work to the Pallas kernels
+    of `parallel/delta_parts.py`: a TPU place and shapes they take (the
+    forward op and its grad op decide alike: `States` is laid out by the
+    path that wrote it)."""
+    return on_tpu() and _delta_shapes_taken(qkv.shape[0], qkv.dtype, attrs)
+
+
+def _delta_shapes_taken(tokens, dtype, attrs):
+    """`delta_rule.takes` of `tokens` tokens in `dtype` under the op's
+    attrs."""
+    from ..parallel import delta_rule
+
+    shape = _delta_shape(attrs)
+    seq_len = shape.pop("seq_len")
+    shape.pop("eps")
+    return tokens % seq_len == 0 and delta_rule.takes(
+        tokens // seq_len, seq_len, dtype=dtype, **shape)
 
 
 set_stop_gradient_outputs("gated_delta_rule", ["States", "FinalState"])
@@ -502,11 +528,13 @@ def gated_delta_rule_grad_op(ctx, ins, attrs):
     """d QKV, d BA (in their dtypes), d ALog, d DtBias of
     `gated_delta_rule`: the reverse recurrence of the state's cotangent
     over the chunks, written out, from the saved chunk-start states."""
-    from ..parallel.delta_rule import delta_rule_bwd
+    from ..parallel import delta_rule
 
     args = [first(ins, s) for s in _DELTA_INPUTS]
-    grads = delta_rule_bwd(*args, first(ins, "States"),
-                           first(ins, "Out@GRAD"), **_delta_shape(attrs))
+    bwd = delta_rule.kernels_bwd if _delta_kernels_take(args[0], attrs) \
+        else delta_rule.delta_rule_bwd
+    grads = bwd(*args, first(ins, "States"), first(ins, "Out@GRAD"),
+                **_delta_shape(attrs))
     return out(**{s + "@GRAD": g.astype(a.dtype)
                   for s, g, a in zip(_DELTA_INPUTS, grads, args)})
 
@@ -1334,6 +1362,15 @@ def _silu_kernel_takes(op, block):
     return kernels.silu_takes(tokens, x.shape[1], seq_len, w.shape[0], low)
 
 
+def _delta_kernel_takes(op, block):
+    """Whether the Pallas kernels of `parallel/delta_parts.py` take this
+    `gated_delta_rule` (or its grad), from the shapes the program states."""
+    qkv = block.vars[op.input("QKV")[0]]
+    tokens = qkv.shape[0] if qkv.shape[0] > 0 else int(op.attrs["seq_len"])
+    low = amp.compute_dtype() if amp.is_enabled() else qkv.dtype
+    return _delta_shapes_taken(tokens, low, op.attrs)
+
+
 def _reads_a_tied_table(op, block):
     """A `lookup_table` whose W a `matmul` of the block reads transposed as
     its Y, or such a `matmul`: one parameter [V, C] that is the embedding
@@ -1446,6 +1483,10 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("gated_delta_rule", "delta_rule_chunked", False, None),
             ("gated_delta_rule_grad", "delta_rule_grad_by_hand", False,
              None),
+            ("gated_delta_rule", "delta_rule_kernel", True,
+             _delta_kernel_takes),
+            ("gated_delta_rule_grad", "delta_rule_grad_kernel", True,
+             _delta_kernel_takes),
             ("causal_attention", "flash_attention_head_256", True,
              lambda op, block: block.vars[op.input("Q")[0]].shape[3] >= 256))
 
@@ -1488,9 +1529,12 @@ def lowered_counts(program, device):
     those whose shapes the variant's Pallas kernels take count as
     `short_conv_silu_kernel` / `short_conv_silu_grad_kernel` too, the
     others lower as L shifted multiply-adds), its `gated_delta_rule`
-    ops (`delta_rule_chunked`: the chunked form as batched products and one
-    scan, on every place; no Pallas kernel yet, so no `delta_rule_kernel`
-    is reported) and their grads (`delta_rule_grad_by_hand`), and on a TPU
+    ops (`delta_rule_chunked`: the chunked form, one scan over the chunks,
+    on every place) and their grads (`delta_rule_grad_by_hand`; on a TPU
+    place those whose shapes the Pallas kernels of parallel/delta_parts.py
+    take, which then form the in-chunk quantities and their transpose,
+    count as `delta_rule_kernel` / `delta_rule_grad_kernel` too, the others
+    form them as batched products a head group at a time), and on a TPU
     place its `causal_attention` ops at heads of 256 or more
     (`flash_attention_head_256`).
     A program without them reports none. Kept on the program until that

@@ -33,6 +33,10 @@ float32 operands (`INVERSE_PRECISION`); the other products take operands in the
 inputs' dtype and accumulate in float32; g, beta, every decay and the
 carried state are float32.
 
+ON A TPU PLACE the in-chunk quantities and their transpose are two Pallas
+kernels (`parallel/delta_parts.py`; `kernels_fwd`, `kernels_bwd` below) for
+the shapes those take; the rest of this file is the same on both paths.
+
 THE BACKWARD is written by hand where the sequence is: from the
 chunk-start states the forward saved ([groups, chunks, rows x Hv / groups,
 dk, dv] float32: chunks leading, so neither scan transposes anything)
@@ -367,7 +371,97 @@ def delta_rule_bwd(qkv, ba, a_log, dt_bias, starts, d_out, **shape):
             d_a_log.reshape(-1), d_dt_bias.reshape(-1))
 
 
-def states_shape(rows, seq_len, hk, hv, dk, dv, chunk):
-    """The shape of `delta_rule_fwd`'s chunk-start states."""
+def states_shape(rows, seq_len, hk, hv, dk, dv, chunk, kernels=False):
+    """The shape of `delta_rule_fwd`'s chunk-start states (`kernels`: of
+    `kernels_fwd`'s, which works all heads at once)."""
+    n_chunks = -(-seq_len // chunk)
+    if kernels:
+        return (n_chunks, rows * hv, dk, dv)
     groups = _groups(hk)
-    return (groups, -(-seq_len // chunk), rows * hv // groups, dk, dv)
+    return (groups, n_chunks, rows * hv // groups, dk, dv)
+
+
+# -------------------------------------------------------- the kernel path
+# On a TPU place, for the shapes `takes` holds for, `_parts` and its
+# transpose are the two Pallas kernels of `parallel/delta_parts.py`, which
+# read q, k, v from [q | k | v], d Out and the gates [T, Hv] where they lie:
+# no split by head group, no repeat of a key head, no chunk-major copy of
+# an input. The backward's kernels also form what stands between `_parts`
+# and the scans there (Q'^T d O for the reverse scan; d Q', d N, d exp(G_C)
+# from the saved states and the scan's result). `gates`, `l2_normalized`
+# (called INSIDE the kernels, a head's [C, dk] block at a time, its vjp by
+# `jax.vjp` there too), both scans, the output product and
+# `INVERSE_PRECISION` stay the names above, looked up on this module as the
+# step is traced: a study plants its faults on them from outside
+# (`chipbench/lower_precision_lm_delta_share.py`), and
+# tests/test_qwen3_next.py holds that every plant still bites here. The
+# plain form above is what the kernels are tested against and what every
+# other place and shape runs.
+#
+# All heads are worked at once: Q', O0, N, B between the kernel and the
+# output product are 0.4 GB a layer at the `qwen3_next_80b_a3b` cell's
+# [8192 tokens, 32 value heads], and the step still fits (the window
+# closes at 15.11 GB of the compiler's 15.75, 15.09 on the plain path;
+# PERF.md, PR 44). Head groups bought nothing here (`lax.map` over 4
+# groups: 7.59 + 16.6 ms a layer against 7.82 + 16.6, first form of the
+# kernels): XLA no longer holds a group's [C, C] arrays.
+
+# whether the backward's forward kernel hands its triangular inverse to the
+# backward kernel ([N, rows x Hv, C, C] in the inputs' dtype through HBM, 67
+# MB a layer at [8192, 32 heads] in bf16) or that kernel forms it again (36
+# [C]^3 bf16 passes a head and chunk: 3.98 ms against 2.48, v5e)
+KEEPS_INVERSE = True
+
+
+def takes(rows, seq_len, hk, hv, dk, dv, chunk, dtype):
+    """Whether the kernel path takes these shapes (`delta_parts.takes`)."""
+    from . import delta_parts
+
+    return delta_parts.takes(rows, seq_len, hk, hv, dk, dv, chunk, dtype)
+
+
+def _dims(qkv, seq_len, **shape):
+    return dict(shape, seq_len=seq_len, rows=qkv.shape[0] // seq_len)
+
+
+def kernels_fwd(qkv, ba, a_log, dt_bias, **shape):
+    """`delta_rule_fwd` through the kernels; the chunk-start states [N,
+    rows x Hv, dk, dv]. `takes` must hold."""
+    from .delta_parts import delta_parts_fwd
+
+    low, dims = qkv.dtype, _dims(qkv, **shape)
+    rows, seq_len = dims["rows"], dims["seq_len"]
+    with jax.named_scope(PARTS):
+        g, beta = gates(ba, a_log, dt_bias)
+        parts = delta_parts_fwd(qkv, g, beta, **dims)
+    with jax.named_scope(STATES):
+        starts, last = _states(
+            parts["n_mat"], parts["b_mat"], parts["g_end"],
+            jnp.zeros(parts["b_mat"].shape[1:], F32))
+    with jax.named_scope(OUTPUTS):
+        o = _dot(parts["q_p"], starts, low) + parts["o0"]
+        out = _tokens_first(o, rows, seq_len).astype(low)
+    return (out, starts.astype(F32),
+            last.astype(F32).reshape((rows, -1) + last.shape[1:]))
+
+
+def kernels_bwd(qkv, ba, a_log, dt_bias, starts, d_out, **shape):
+    """`delta_rule_bwd` through the kernels, from `kernels_fwd`'s states."""
+    from .delta_parts import delta_parts_bwd, delta_parts_fwd
+
+    dims = _dims(qkv, **shape)
+    with jax.named_scope(PARTS):
+        (g, beta), gates_vjp = jax.vjp(gates, ba, a_log, dt_bias)
+        parts = delta_parts_fwd(
+            qkv, g, beta, d_out, **dims,
+            outputs=("r_mat", "n_mat", "g_end") + ("t",) * KEEPS_INVERSE)
+    with jax.named_scope(STATES):
+        left, _ = _states_transposed(
+            parts["n_mat"], parts["g_end"], parts["r_mat"],
+            jnp.zeros(starts.shape[1:], F32))
+    with jax.named_scope(PARTS):
+        d_q, d_k, d_v, d_g, d_beta = delta_parts_bwd(
+            qkv, g, beta, d_out, starts, left, parts.get("t"), **dims)
+        d_ba, d_a_log, d_dt_bias = gates_vjp((d_g, d_beta))
+    return (jnp.concatenate([d_q, d_k, d_v], axis=-1), d_ba,
+            d_a_log.astype(F32), d_dt_bias.astype(F32))

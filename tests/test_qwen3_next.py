@@ -350,6 +350,7 @@ def test_lowered_counts_name_the_delta_rule_and_the_conv(small, place):
             "delta_rule_grad_by_hand": 3}
     if place == "tpu":
         # 128 channels are a lane tile: the variant's kernels take them
+        # (heads of 16 in chunks of 8 are none: no `delta_rule_kernel`)
         want.update(flash_attention=1, flash_attention_bwd=1,
                     flash_attention_head_groups=1,
                     flash_fwd_visited_blocks=1, flash_fwd_masked_blocks=1,
@@ -365,18 +366,26 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
     """The cell's program, built under bf16 AMP: flash at heads of 256, the
     grouped kernels at K 2048 / F 512, the convolution's silu variant by
     its two kernels ([8192, 8192] in blocks of 256 tokens) and the delta
-    rule in its plain chunked form (no kernel counter)."""
+    rule's in-chunk work by its two kernels (heads of 128 in chunks of
+    128: the counters are read under the lowering's real chunk)."""
     from chipbench.configs import qwen3_next_80b_a3b as builder
     from paddle_tpu import amp
     from paddle_tpu.ops import lm_ops
 
+    from paddle_tpu.parallel import delta_rule
+
     amp.enable("bfloat16")
     try:
-        prog = builder.build(fluid, _file(), 1)["prog"]
+        with mock.patch.object(delta_rule, "CHUNK", 128):
+            prog = builder.build(fluid, _file(), 1)["prog"]
         got = lm_ops.lowered_counts(prog,
                                     types.SimpleNamespace(platform="tpu"))
+        on_cpu = lm_ops.lowered_counts(
+            prog, types.SimpleNamespace(platform="cpu"))
     finally:
         amp.disable()
+    assert "delta_rule_kernel" not in on_cpu
+    assert on_cpu["delta_rule_chunked"] == 3
     assert got == dict(
         moe_ffn_grouped=4, grouped_matmul_kernel=4, grouped_mlp_epilogues=4,
         flash_attention=1, flash_attention_bwd=1,
@@ -386,6 +395,7 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
         short_conv_silu=3, short_conv_silu_grad_by_hand=3,
         short_conv_silu_kernel=3, short_conv_silu_grad_kernel=3,
         delta_rule_chunked=3, delta_rule_grad_by_hand=3,
+        delta_rule_kernel=3, delta_rule_grad_kernel=3,
         flash_fwd_visited_blocks=36, flash_fwd_masked_blocks=8)
 
 
@@ -534,6 +544,201 @@ def test_the_op_s_gradient_is_the_hand_written_one(small):
     assert src.count("jax.vjp") == 1 and "_parts(" in src
     assert "scan" not in inspect.getsource(dr._parts)
     assert "scan" not in inspect.getsource(dr._prepared)
+
+
+# ---------------------------------------------------------- the kernel path
+def _both_paths(qkv, ba, a_log, dt_bias, cot, shape):
+    """((out, last state, the q, k and v thirds of d qkv, d [b | a], d
+    A_log, d dt_bias) of the kernel path, interpreted, the same of the
+    plain form)."""
+    from paddle_tpu.parallel import delta_rule as dr
+
+    cuts = [shape["hk"] * shape["dk"], 2 * shape["hk"] * shape["dk"]]
+
+    def run(fwd, bwd):
+        out, starts, last = jax.jit(lambda *a: fwd(*a, **shape))(
+            qkv, ba, a_log, dt_bias)
+        d_qkv, *rest = jax.jit(lambda *a: bwd(*a, **shape))(
+            qkv, ba, a_log, dt_bias, starts, cot)
+        return (out, last, *jnp.split(d_qkv, cuts, axis=-1), *rest), starts
+
+    return (run(dr.kernels_fwd, dr.kernels_bwd),
+            run(dr.delta_rule_fwd, dr.delta_rule_bwd))
+
+
+def _rms_apart(a, b):
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    return float(np.sqrt(np.mean((a - b) ** 2))
+                 / (np.sqrt(np.mean(b ** 2)) + 1e-30))
+
+
+@pytest.mark.parametrize("heads", [(1, 1), (1, 2)], ids=["1to1", "1to2"])
+@pytest.mark.parametrize("rows", [1, 2])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_kernel_path_against_the_plain_form(dtype, rows, heads):
+    """`kernels_fwd` / `kernels_bwd` (the two Pallas kernels of
+    `parallel/delta_parts.py`, interpreted) against the plain chunked form
+    on the same inputs: the output, the final state and the gradients to
+    q, k, v, [b | a], A_log and dt_bias. Float32 differs by the order of
+    sums and the triangular inverse's three bf16 passes written out (the
+    CPU runs the plain form's `Precision.HIGH` exactly); bf16 by roundings
+    of cotangents the plain vjp leaves float32."""
+    from paddle_tpu.parallel import delta_rule as dr
+
+    hk, hv = heads
+    S, d, chunk = 256, 128, 128
+    qkv, ba, a_log, dt_bias, cot = _delta_case(
+        S, rows, hk, hv, d, 0.05, jnp.dtype(dtype), 5)
+    shape = dict(seq_len=S, hk=hk, hv=hv, dk=d, dv=d, chunk=chunk, eps=1e-6)
+    assert dr.takes(rows, S, hk, hv, d, d, chunk, dtype)
+    (got, starts), (want, plain_starts) = _both_paths(
+        qkv, ba, a_log, dt_bias, cot, shape)
+    assert starts.shape == dr.states_shape(rows, S, hk, hv, d, d, chunk,
+                                           kernels=True)
+    assert starts.dtype == got[1].dtype == jnp.float32
+    assert got[0].dtype == got[2].dtype == qkv.dtype
+    # (one key head: the plain form works it as one group)
+    np.testing.assert_allclose(
+        np.asarray(starts), np.asarray(plain_starts)[0],
+        atol=2e-5 if dtype == "float32" else 2e-3)
+    tol = 5e-5 if dtype == "float32" else 0.02
+    for name, a, b in zip(("out", "last", "d_q", "d_k", "d_v", "d_ba",
+                           "d_a_log", "d_dt_bias"), got, want):
+        assert a.shape == b.shape, name
+        assert _rms_apart(a, b) < tol, (name, _rms_apart(a, b))
+
+
+@pytest.mark.parametrize("knob,value,S,heads", [
+    ("CHUNKS_A_STEP", 4, 512, (1, 2)), ("CHUNKS_A_STEP", 1, 256, (2, 4)),
+    ("KEEPS_INVERSE", False, 256, (1, 2))],
+    ids=["four_chunks_a_step", "one_chunk_a_step_two_key_heads",
+         "the_inverse_formed_again"])
+def test_the_kernel_path_s_knobs_change_no_result(knob, value, S, heads):
+    """What the sweep turns (`tools/delta_rule_sweep.py`): the chunks a grid
+    step works side by side, the backward forming the triangular inverse
+    again instead of reading it."""
+    from paddle_tpu.parallel import delta_parts
+    from paddle_tpu.parallel import delta_rule as dr
+
+    hk, hv = heads
+    d, chunk = 128, 128
+    qkv, ba, a_log, dt_bias, cot = _delta_case(S, 1, hk, hv, d, 0.05,
+                                               jnp.float32, 9)
+    shape = dict(seq_len=S, hk=hk, hv=hv, dk=d, dv=d, chunk=chunk, eps=1e-6)
+    home = dr if hasattr(dr, knob) else delta_parts
+    with mock.patch.object(home, knob, value):
+        (got, starts), (want, _) = _both_paths(qkv, ba, a_log, dt_bias, cot,
+                                               shape)
+        assert starts.shape == dr.states_shape(1, S, hk, hv, d, d, chunk,
+                                               kernels=True)
+    for name, a, b in zip(("out", "last", "d_q", "d_k", "d_v", "d_ba",
+                           "d_a_log", "d_dt_bias"), got, want):
+        assert _rms_apart(a, b) < 5e-5, (name, _rms_apart(a, b))
+
+
+@pytest.mark.parametrize("refused", ["head_of_16", "chunk_of_8",
+                                     "ragged_row", "float16"])
+def test_a_shape_the_kernels_refuse_runs_the_plain_form(monkeypatch,
+                                                        refused):
+    """On a TPU place the op hands itself to the kernels only where `takes`
+    holds; every other shape gives the plain form's results bit for bit
+    and its `States` layout."""
+    from paddle_tpu.ops import lm_ops
+    from paddle_tpu.parallel import delta_rule as dr
+
+    S, d, chunk, dtype = {"head_of_16": (32, 16, 8, "float32"),
+                          "chunk_of_8": (32, 128, 8, "float32"),
+                          "ragged_row": (200, 128, 128, "float32"),
+                          "float16": (128, 128, 128, "float16")}[refused]
+    hk, hv = 1, 2
+    assert not dr.takes(1, S, hk, hv, d, d, chunk, dtype)
+    qkv, ba, a_log, dt_bias, _ = _delta_case(S, 1, hk, hv, d, 0.05,
+                                             jnp.dtype(dtype), 6)
+    attrs = dict(seq_len=S, num_k_heads=hk, num_v_heads=hv, head_k_dim=d,
+                 head_v_dim=d, chunk=chunk)
+    monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(dr, "kernels_fwd", None)    # a call would raise
+    res = lm_ops.gated_delta_rule_op(None, {
+        "QKV": [qkv], "BA": [ba], "ALog": [a_log], "DtBias": [dt_bias]},
+        attrs)
+    want = dr.delta_rule_fwd(qkv, ba, a_log, dt_bias, seq_len=S, hk=hk,
+                             hv=hv, dk=d, dv=d, chunk=chunk, eps=1e-6)
+    for got, ref in zip((res["Out"][0], res["States"][0],
+                         res["FinalState"][0]), want):
+        assert got.shape == ref.shape
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32))
+
+
+def test_the_op_takes_the_kernels_on_a_tpu_place_and_not_elsewhere(
+        monkeypatch):
+    """Forward op and grad op decide alike (`States` is laid out by the
+    path that wrote it): the kernel path under a TPU place at a shape
+    `takes` holds for, the plain form on the CPU."""
+    from paddle_tpu.ops import lm_ops
+    from paddle_tpu.parallel import delta_rule as dr
+
+    S, d, hk, hv = 128, 128, 1, 2
+    qkv, ba, a_log, dt_bias, cot = _delta_case(S, 1, hk, hv, d, 0.05,
+                                               jnp.float32, 7)
+    ins = {"QKV": [qkv], "BA": [ba], "ALog": [a_log], "DtBias": [dt_bias]}
+    attrs = dict(seq_len=S, num_k_heads=hk, num_v_heads=hv, head_k_dim=d,
+                 head_v_dim=d, chunk=128)
+    called = []
+    for name in ("kernels_fwd", "kernels_bwd", "delta_rule_fwd",
+                 "delta_rule_bwd"):
+        real = getattr(dr, name)
+
+        def spy(*a, real=real, name=name, **kw):
+            called.append(name)
+            return real(*a, **kw)
+
+        monkeypatch.setattr(dr, name, spy)
+    for tpu, want in ((True, ["kernels_fwd", "kernels_bwd"]),
+                      (False, ["delta_rule_fwd", "delta_rule_bwd"])):
+        del called[:]
+        monkeypatch.setattr(lm_ops, "on_tpu", lambda tpu=tpu: tpu)
+        res = lm_ops.gated_delta_rule_op(None, ins, attrs)
+        lm_ops.gated_delta_rule_grad_op(
+            None, dict(ins, States=res["States"], **{"Out@GRAD": [cot]}),
+            attrs)
+        assert called == want
+
+
+def _planted(name):
+    """The study's own plant (`chipbench.lower_precision_lm_delta_share`),
+    or the probe's patch of the inverse's precision
+    (`compare_lm_delta_share.delta_ops_in_float32`)."""
+    from chipbench import lower_precision_lm_delta_share as study
+    from paddle_tpu.parallel import delta_rule as dr
+
+    if name == "inverse_highest":
+        return mock.patch.object(dr, "INVERSE_PRECISION",
+                                 jax.lax.Precision.HIGHEST)
+    return study._planted(name)
+
+
+@pytest.mark.parametrize("plant,moves_by", [
+    ("state_bf16", 1e-5), ("g_bf16", 1e-5), ("no_decay", 1e-2),
+    ("beta_one", 1e-2), ("no_qk_norm", 1e-2), ("inverse_highest", 1e-9)])
+def test_every_plant_of_the_study_bites_on_the_kernel_path(plant, moves_by):
+    """The study one precision down and the comparison's float32 probe
+    replace `_states`, `_states_transposed`, `gates`, `l2_normalized` and
+    `INVERSE_PRECISION` on `parallel/delta_rule.py` from outside: the
+    kernel path looks each up as it is traced, so the output and the
+    gradient move under every one of them (a kernel that carried the
+    state, or formed the gates, itself would make the plant a no-op and
+    the study a study of nothing)."""
+    S, d, hk, hv = 256, 128, 1, 2
+    qkv, ba, a_log, dt_bias, cot = _delta_case(S, 1, hk, hv, d, 0.05,
+                                               jnp.float32, 8)
+    shape = dict(seq_len=S, hk=hk, hv=hv, dk=d, dv=d, chunk=128, eps=1e-6)
+    (stated, _), _ = _both_paths(qkv, ba, a_log, dt_bias, cot, shape)
+    with _planted(plant):
+        (planted, _), _ = _both_paths(qkv, ba, a_log, dt_bias, cot, shape)
+    # (without the norm a chunk's inverse overflows: that moved too)
+    assert not _rms_apart(planted[0], stated[0]) <= moves_by     # out
+    assert not _rms_apart(planted[3], stated[3]) <= moves_by     # d k
 
 
 # ------------------------------------------------- the convolution's variant

@@ -45,7 +45,53 @@ def test_silu_conv_kernels_compile_for_v5e(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
-def test_qwen3_next_step_runs_the_chunked_delta_rule_and_flash_at_256(
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kind", ["forward", "forward_in_the_backward",
+                                  "backward"])
+def test_delta_parts_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                             monkeypatch, kind, dtype):
+    """Mosaic takes the delta rule's two kernels at the `qwen3_next_80b_a3b`
+    cell's shape, [8192 tokens, 16 key / 32 value heads of 128] in chunks
+    of 128, all heads at once, in bf16 (the step) and in float32 (the
+    comparison's probe): the forward as the forward op calls it, as the
+    grad op calls it (Q'^T d O, N, exp(G_C) and the inverse out), and the
+    transpose reading that inverse; one custom call each, no temporary of
+    an activation's size."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import delta_parts, delta_rule
+
+    monkeypatch.setattr(delta_parts, "pallas_interpret", lambda: False)
+    S, hk, hv, d, chunk = 8192, 16, 32, 128, delta_rule.CHUNK
+    assert chunk == 128 and delta_rule.takes(1, S, hk, hv, d, d, chunk,
+                                             dtype)
+
+    def sds(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    n, dims = S // chunk, dict(rows=1, seq_len=S, hk=hk, hv=hv, dk=d, dv=d,
+                               chunk=chunk, eps=1e-6)
+    ins = [sds(S, (2 * hk + hv) * d), sds(S, hv, dt="float32"),
+           sds(S, hv, dt="float32")]
+    d_out, state = sds(S, hv * d), sds(n, hv, d, d, dt="float32")
+    if kind == "forward":
+        compiled = jax.jit(lambda *a: delta_parts.delta_parts_fwd(
+            *a, **dims)).lower(*ins).compile()
+    elif kind == "forward_in_the_backward":
+        compiled = jax.jit(lambda *a: delta_parts.delta_parts_fwd(
+            *a, **dims, outputs=("r_mat", "n_mat", "g_end", "t"))).lower(
+                *ins, d_out).compile()
+    else:
+        compiled = jax.jit(lambda *a: delta_parts.delta_parts_bwd(
+            *a, **dims)).lower(
+                *ins, d_out, state, state,
+                sds(n, hv, chunk, chunk)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_qwen3_next_step_runs_the_delta_kernels_and_flash_at_256(
         one_chip, no_compile_cache, monkeypatch):
     """The `qwen3_next_80b_a3b` step at 1 x 8192 tokens (one period: three
     Gated DeltaNet layers and an output-gated attention layer, each with 32
@@ -53,10 +99,12 @@ def test_qwen3_next_step_runs_the_chunked_delta_rule_and_flash_at_256(
     the flash kernels at 16 query heads on 2 key/value heads of 256 (a
     forward, dK/dV and dQ), the grouped kernels over the 32 held groups at
     K 2048 / F 512, the embedding's gradient by the row-tile kernel, the
-    delta rule's chunk scan as `while` loops under `delta/delta_rule/`
-    (a loop over the head groups and the chunk scan inside it, forward and
-    reverse, a delta layer: no Pallas kernel yet), the convolution's silu
-    variant by its two kernels, no XLA convolution, no [S, S] scores; and it fits the chip."""
+    delta rule's in-chunk work by `delta_parts_fwd` (once a layer forward
+    and once more in its backward) and `delta_parts_bwd`, its chunk scan as
+    `while` loops under `delta/delta_rule/` (forward and reverse, a delta
+    layer, over all heads at once: no loop over head groups), the
+    convolution's silu variant by its two kernels, no XLA convolution, no
+    [S, S] scores; and it fits the chip's 15.75 GB."""
     cfg, compiled = base._lm_step(
         one_chip, monkeypatch, "qwen3_next_80b_a3b", 1,
         lambda built: [built["routing"][0][1].name]
@@ -68,6 +116,8 @@ def test_qwen3_next_step_runs_the_chunked_delta_rule_and_flash_at_256(
     assert calls.count("row_tile_sum") >= 1
     assert [c for c in calls if c.startswith("silu_conv")] == \
         ["silu_conv_bwd"] * 3 + ["silu_conv_fwd"] * 3
+    assert [c for c in calls if c.startswith("delta_parts")] == \
+        ["delta_parts_bwd"] * 3 + ["delta_parts_fwd"] * 6
     assert base.ragged_dots(text) == []
     assert "feature_group_count=8192" not in text
     S = cfg["sequence_length"]
@@ -83,9 +133,8 @@ def test_qwen3_next_step_runs_the_chunked_delta_rule_and_flash_at_256(
              for m in [re.search(r'op_name="[^"]*delta/delta_rule/[^"]*'
                                  r'/while"', ln)] if m
              and re.match(r"\s*%?\S+ = .* while\(", ln)]
-    # a loop over the head groups and the chunk scan inside it, forward and
-    # backward, a delta layer
-    assert len(loops) == 12, loops
+    # the chunk scan, forward and backward, a delta layer
+    assert len(loops) == 6, loops
     mem = compiled.memory_analysis()
     # the file's `arithmetic`: 8.31 GB of arguments (weights, two moments,
     # the kept copies). That the compile returned says the step fits the
