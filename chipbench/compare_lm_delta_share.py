@@ -13,11 +13,14 @@ the window, on the rows the cell's own window starts with. As in
 `compare_lm_early_route_share`'s, this file imports, not copies) two
 objects are set against the reference: (1) THE EXECUTABLE THE WINDOW TIMES,
 its losses of steps 0 and 1 against the reference's first step and its
-second after its own AdamW update, AND against the second build's own
-losses of the same two steps (`TIMED_TWIN_TOL`: at this depth and rate a
-step moves the loss by no more than the bf16 system stands from the float32
-reference, so only the system's own second step tells a carried state from
-one left as it was); (2) a second build of the same program run step by
+second after its own AdamW update, AND its losses of step 0 and of the
+LAST step of its first chunk against the second build's own, run one step
+at a time through that chunk (`TIMED_TWIN_TOL`, `TIMED_TWIN_LAST_TOL`: at
+this depth and rate a step moves the loss by no more than the bf16 system
+stands from the float32 reference, and by little more than two executables
+of one program differ at step 1, so a carried state is told from one left
+as it was at the chunk's last step, where what was not carried has grown
+by a step's worth every step; PR 48); (2) a second build of the same program run step by
 step with the gradients fetched, and its inference clone.
 
 Compared on one row of 8192 tokens:
@@ -29,8 +32,12 @@ Compared on one row of 8192 tokens:
   convolution wrote them, [b | a] as its projection did, the layer's A_log
   and dt_bias), AND THE SAME OP ONCE MORE IN FLOAT32 at full matmul
   precision on those inputs (what the op itself holds below float32:
-  `delta_precision`; a program of this check's own, not the timed step,
-  through whose bf16 products no limit tells the state's precision);
+  `delta_precision`, held by the MEDIAN AND THE UPPER QUARTILE OVER THE 32
+  VALUE HEADS of a head's rms error, since as stated one or two heads
+  carry the whole array's and a lowered precision lifts every head (PR
+  48); a program of
+  this check's own, not the timed step, through whose bf16 products no
+  limit tells the state's precision);
   `short_conv` (gating "silu") against the reference's four
   shifted slices on the op's own input;
 * FIRST-HAND BRANCHES: the delta branch of layer 0 and of layer 2 and the
@@ -77,6 +84,24 @@ from chipbench.compare_lm_share import _logits_errors as _errors_over
 from chipbench.compare_lm_share import _products
 from chipbench.compare_lm_window_share import _branch_errors
 
+# THE RULE (PR 48), for every limit set again since: a limit stands at
+# least a factor M above the standing program's worst reading over AT LEAST
+# 24 SEEDS, and at least M below the least reading of every plant that this
+# check ALONE is there to catch (a plant another check fails does not hold
+# a limit down; where no plant reads against a number, the limit is said to
+# be coarse: it holds a mechanism). Where no number has M on both sides the
+# STATISTIC was changed, not only the number. The readings are committed,
+# row by row, in `chipbench/data/limits_study.json`, and
+# `chipbench/tests/test_limits_study.py` replays them against this file:
+# every `stated` row passes, every plant row fails the check named for it,
+# and M is what the file says. The 24 seeds: PR 47's study of the standing
+# program (788aa83; nothing under `paddle_tpu/` changed since), four
+# workers side by side, `chiprun_out/pr47/q24_*.jsonl`; 14 of them were
+# `correct` under the limits as PR 43 / 44 left them. ONE EXCEPTION TO THE
+# 24: `TIMED_TWIN_LAST_TOL` rests on the SEVEN seeds of this PR's own runs
+# (no record before it followed the scan past step 1), and says so where
+# it stands; it has 5.2 on either side, not 1.4.
+M = 1.4
 # READINGS (my chip runs, PR 43): "stated" = the largest (for a floor the
 # smallest) the system gave as the configuration states it over its seeds
 # (17: 14 runs of the cell and the study's 21, 22, 23) | the study's
@@ -101,17 +126,24 @@ from chipbench.compare_lm_window_share import _branch_errors
 # layers' (each judged on the tokens every layer before routed alike) |
 # `router_bf16` 12.1, 22.4, 35.1, 35.7%: no precision shows here; the
 # planted omissions read 100%. What says that a flip WAS a near-tie is the
-# margin
-ROUTING_FLIP_MAX = 0.60
+# margin. PR 48, 24 seeds: stated worst 45.2% | the omissions 100%; M above
+# the one is 0.633, M below the other 0.714
+ROUTING_FLIP_MAX = 0.67
 # COARSE: of the token's logit spread (std over the 512 experts): stated
-# 0.246 | `router_bf16` 0.215; `no_output_gate` 1.42, `no_decay` 4.8
-ROUTING_MARGIN = 0.45
+# 0.246 | `router_bf16` 0.215; `no_output_gate` 1.42, `no_decay` 4.8.
+# PR 48: over 24 seeds the stated worst is 0.429 (a widest gap swings by
+# its nature: 0.45 left it 1.05); nearest plant 1.42; the geometric mean,
+# 1.8 above the one and below the other
+ROUTING_MARGIN = 0.78
 # COARSE, over the tokens every layer routed alike (25 - 31% of the row:
 # they still read the other tokens' states through three delta layers and
 # an attention layer): stated 0.0805 max, 0.0411 rms | `router_bf16` 0.0515,
 # 0.0325; `no_output_gate` 0.172, 0.101 (the geometric means of the two);
-# the other omissions leave no token routed alike
-LOGITS_TOL = 0.12
+# the other omissions leave no token routed alike. PR 48, 24 seeds: the
+# max reads up to 0.0885 (0.12 left it 1.36), the rms 0.0418 (1.55: kept);
+# `no_output_gate` is `attention`'s to catch (0.92 against 0.012), so it
+# does not hold this limit down, and 0.13 still lies under its 0.172
+LOGITS_TOL = 0.13
 LOGITS_RMS_TOL = 0.065
 # the accepted share comparisons' limit, against the float32 REFERENCE:
 # stated 1.0e-4 (the one step), 8.4e-5 (the timed scan's steps 0 and 1)
@@ -133,8 +165,36 @@ LOSS_TOL = 6e-4
 # 2147483311, 1234567891), 1.4e-6 and 5.0e-6 | a timed second step that
 # carried nothing stands the whole step away, 1.3e-4 - 2.3e-4 (the
 # reference's two second losses, the same eleven runs). The geometric mean
-# of 2.8e-5 and 1.3e-4
+# of 2.8e-5 and 1.3e-4.
+# PR 48: NO NUMBER HAS M ON BOTH SIDES AT STEP 1, SO THE STATISTIC CHANGED.
+# Over the 24 seeds step 1 reads up to 9.14e-5 between two executables of
+# one program (4 seeds over 6e-5: 7.59e-5, 7.90e-5, 8.28e-5, 9.14e-5; Adam's
+# first step is +-rate a weight, so which near-tied tokens change expert
+# at step 1 differs between them) where a second step that carried nothing
+# reads from 1.13e-4, and seed by seed the ratio falls to 2.0. Step 0 (the
+# same weights and rows) reads <= 8.08e-6 on all 24 and keeps this limit:
+# it holds the timed executable's forward and its feed (half a batch left
+# out reads the rows' spread, 1e-3 and more). What tells a carried state is
+# now THE LAST STEP OF THE SCAN'S FIRST CHUNK (step K - 1 = 9) against the
+# second build run K single steps: what was not carried grows by a step's
+# 2e-4 every step, what two executables differ by does not
 TIMED_TWIN_TOL = 6e-5
+# the timed scan's loss of step K - 1 against the second build's own K-th
+# single step | the same with NOTHING carried: the inference program's
+# cross-entropy of step K - 1's rows at the weights as drawn (the same
+# program's on step 0's rows stands within 4.8e-5 of the step's own loss:
+# `err_unmoved_first_against_step_0`; a scan whose body returns its state
+# unchanged reads it to four digits: `test_delta_cell.py`). SEVEN SEEDS,
+# not 24: the records before this PR hold two steps (my chip runs, PR 48,
+# `limits_study.json`: 1848000606, 1511168161, 2048000101, 1748000202,
+# 1348000303, 948000404, 2147480505). Step 9: stated 8.3e-6 - 5.80e-5 |
+# nothing carried 1.595e-3 - 2.197e-3, 27 times the worst sound reading;
+# and what two executables differ by does NOT grow along the chunk: over
+# those runs' 63 readings of steps 1 - 9 the worst is 7.2e-5 (step 4),
+# inside step 1's 9.14e-5 over the 24 seeds, which therefore stands in
+# for this reading's tail. The geometric mean of 5.8e-5 and 1.6e-3: 5.2
+# from either, 3.3 above step 1's worst of 24. Set for the traffic's K = 10
+TIMED_TWIN_LAST_TOL = 3e-4
 # stated 4.6e-4 | `taps_reversed` 4.5e-3, `no_decay` 1.3e-2,
 # `no_output_gate` 0.23; COARSE for `router_bf16` (6.4e-4)
 GLOBAL_NORM_TOL = 2e-3
@@ -180,9 +240,44 @@ DELTA_STATE_RMS_TOL = 0.008
 # `state_bf16` 5.7e-4 - 1.08e-3, `g_bf16` 1.52e-3 - 1.73e-3; final state,
 # stated 2.7e-5 - 1.44e-4 | `state_bf16` 1.66e-3 - 1.83e-3, `g_bf16`
 # 1.55e-3 - 1.75e-3; the two limits are held TOGETHER, the state's has the
-# room
-DELTA_F32_OP_RMS_TOL = 4e-4
-DELTA_F32_STATE_RMS_TOL = 5e-4
+# room.
+# PR 48: OVER 24 SEEDS THE WHOLE ARRAY'S RMS HAS NO LIMIT WITH ROOM: stated
+# reads 1.95e-5 - 6.01e-4 (output) and 1.59e-5 - 8.27e-4 (final state), six
+# seeds over the old 4e-4 / 5e-4, against `state_bf16` from 5.08e-4 / 1.66e-3
+# and `g_bf16` from 1.45e-3 / 1.53e-3 (seeds 1133040875, 2147480046,
+# 1765400321, 1511168161, through the kernels). BY VALUE HEAD the cause
+# shows: as stated ONE OR TWO of the 32 heads carry the whole reading (seed
+# 1511168161, first delta layer: head 14 reads 9.7e-4, 27 heads under
+# 2e-5; which head, and how far, goes with the seed's A_log / dt_bias
+# draw and the row's chunks), where a bf16 state or bf16 gates lift EVERY
+# head. So the precision is held by THE MEDIAN OVER THE 32 HEADS of a
+# head's rms error over that head's own reference rms, worst delta layer:
+# output, stated <= 1.29e-5 (24 seeds) | `state_bf16` >= 2.10e-4, `g_bf16`
+# >= 1.55e-3: the geometric mean, 3.9 from either; final state, stated
+# <= 1.35e-5 | `g_bf16` >= 1.55e-3, `state_bf16` >= 1.65e-3: 10 from
+# either
+DELTA_F32_OP_HEAD_MEDIAN_TOL = 5e-5
+DELTA_F32_STATE_HEAD_MEDIAN_TOL = 1.4e-4
+# A MEDIAN PASSES A LOWERED PRECISION IN UP TO 15 OF THE 32 HEADS (and the
+# whole array's bounds below lie above what `state_bf16` reads there), so
+# THE UPPER QUARTILE over the heads (`np.quantile(.., 0.75)`, worst delta
+# layer) is held beside it: nine heads lifted lift it. The same 30 seeds
+# and 10 plant rows (`limits_study.json`): output, stated 1.55e-5 - 4.49e-5
+# (as stated at most 4 heads of a layer read over 1e-4, the quartile's 8
+# never) | `state_bf16` >= 5.25e-4, `g_bf16` >= 1.72e-3: the geometric
+# mean, 3.4 from either; final state, stated <= 4.34e-5 | `state_bf16` >=
+# 1.676e-3, `g_bf16` >= 1.83e-3: 6.2 from either. What up to eight heads
+# hold below float32 is left to the whole array's bounds
+DELTA_F32_OP_HEAD_QUARTILE_TOL = 1.5e-4
+DELTA_F32_STATE_HEAD_QUARTILE_TOL = 2.7e-4
+# and the whole array's rms stays as a COARSE bound, three times the stated
+# worst of the 24 (6.01e-4, 8.27e-4; this PR's six fresh seeds read up to
+# 6.81e-4, 9.55e-4: a fresh seed read higher than two dozen had, which is
+# why no limit 1.4 above them would have stood): a fault in a few heads,
+# which a median passes; the planted precisions read inside it and are the
+# medians' to catch
+DELTA_F32_OP_RMS_TOL = 1.8e-3
+DELTA_F32_STATE_RMS_TOL = 2.5e-3
 # `short_conv` (gating "silu") against four shifted slices of the op's own
 # input: one bf16 rounding of the output as stated: 0.00166 (every reading)
 # | `taps_reversed` 1.39 - 1.41 (seeds 21, 22, 23, the kernels' taps reversed
@@ -193,8 +288,10 @@ CONV_OP_RMS_TOL = 0.0025
 # ops make most of it. Delta: stated 0.0090, 0.0085 | `no_decay` 2.4,
 # `beta_one` 0.77, `no_qk_norm` 0.92, `taps_reversed` 1.49. Attention:
 # stated 0.0045, 0.0051 | `no_output_gate` 0.92, 1.00
+# PR 48, 24 seeds: the delta branch's rms reads up to 0.00865 (0.012 left
+# it 1.39), its max 0.0098 (2.04: kept) | the plants from 0.77
 DELTA_TOL = 0.02
-DELTA_RMS_TOL = 0.012
+DELTA_RMS_TOL = 0.013
 ATTENTION_TOL = 0.012
 ATTENTION_RMS_TOL = 0.010
 # COARSE for a precision (no variant lowers the norms' statistics here; the
@@ -240,9 +337,14 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels,
     and the two delta layers' own ops (the convolution's input and output,
     [b | a], the recurrence's output and last state), the training step's
     loss, routing, global norm, clip scale, clipped gradients and updated
-    weights of the sampled parameters; with `then` = (tokens, labels) of a
-    second step, that step's loss behind the first (`loss_next`). Its scope
-    is gone when this returns."""
+    weights of the sampled parameters; with `then` = (tokens, labels) of
+    the steps behind the first, [n x rows, S], each step's loss behind the
+    one before (`losses_next`; `loss_next` the first of them) and, before
+    any step, the inference program's own cross-entropy of the LAST of
+    those steps' rows at the weights as drawn (`loss_unmoved_last`: what a
+    scan that never carried its state would read there; `loss_unmoved_first`
+    is the same program's on the first step's rows, which the step's own
+    loss checks). Its scope is gone when this returns."""
     built = builder.build(fluid, cfg, seed, for_compare=True)
     picks = builder.sampled_params(cfg)
     at = builder.first_hand_layers(cfg)
@@ -258,9 +360,10 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels,
         exe.run(built["startup"])
         w0 = {p.name: np.asarray(scope.find_var(p.name), np.float32)
               for p in built["prog"].global_block().all_parameters()}
+        eval_fetches = [built["logits"]] + branches + ids_vars + own \
+            + [n for pair in products for n in pair]
         evaled = exe.run(built["test_prog"], feed=feed,
-                         fetch_list=[built["logits"]] + branches + ids_vars
-                         + own + [n for pair in products for n in pair])
+                         fetch_list=eval_fetches)
         n_ids = 1 + len(branches) + len(ids_vars)
         own_got = [np.asarray(v, np.float32)
                    for v in evaled[n_ids:n_ids + len(own)]]
@@ -270,18 +373,29 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels,
              int(np.asarray(held).reshape(-1)[0]))
             for down, held in zip(evaled[n_ids::2], evaled[n_ids + 1::2])]
         evaled = evaled[:n_ids]
+        rows = len(tokens)
+        behind = [] if then is None else [
+            (then[0][i:i + rows], then[1][i:i + rows])
+            for i in range(0, len(then[0]), rows)]
+        unmoved = [None, None]
+        if behind:
+            # the weights as drawn on the LAST step's rows: the same
+            # inference executable (the same fetches) once more
+            unmoved = [_cross_entropy(evaled[0], labels), _cross_entropy(
+                exe.run(built["test_prog"], fetch_list=eval_fetches,
+                        feed={built["token_feed"]: behind[-1][0],
+                              built["label_feed"]: behind[-1][1]})[0],
+                behind[-1][1])]
         step_fetches = [built["loss"], gnorm_var, scale_var] + ids_vars \
             + [n + "@GRAD_clipped" for n in picks.values()]
         fetched = exe.run(built["prog"], feed=feed, fetch_list=step_fetches)
         w1 = {k: np.asarray(scope.find_var(n)).astype(np.float32)
               for k, n in picks.items()}
-        loss_next = None
-        if then is not None and len(then[0]):
-            # the same executable once more: the step behind the first
-            loss_next = _scalar(exe.run(
-                built["prog"], fetch_list=step_fetches,
-                feed={built["token_feed"]: then[0],
-                      built["label_feed"]: then[1]})[0])
+        # the same executable once more a step: each behind the one before
+        losses_next = [_scalar(exe.run(
+            built["prog"], fetch_list=step_fetches,
+            feed={built["token_feed"]: t, built["label_feed"]: l})[0])
+            for t, l in behind]
     delta_ops = {k: tuple(own_got[5 * i:5 * i + 5])
                  for i, k in enumerate(DELTA_LAYERS)}
     exact = delta_ops_in_float32(built["test_prog"], w0, at, delta_ops)
@@ -289,7 +403,9 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels,
     got = dict(zip(("loss", "gnorm", "scale"),
                    (_scalar(v) for v in fetched[:3])))
     got.update(
-        loss_next=loss_next, w0=w0, w1=w1, logits=np.asarray(evaled[0], np.float32),
+        loss_next=losses_next[0] if losses_next else None,
+        losses_next=losses_next, loss_unmoved_first=unmoved[0],
+        loss_unmoved_last=unmoved[1], w0=w0, w1=w1, logits=np.asarray(evaled[0], np.float32),
         operators={k: (np.asarray(u, np.float32), np.asarray(o, np.float32))
                    for k, u, o in zip(FIRST_HAND, evaled[1:1 + n_b:2],
                                       evaled[2:1 + n_b:2])},
@@ -303,6 +419,29 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels,
     del scope, exe, fetched, evaled, built
     gc.collect()
     return got
+
+
+def _cross_entropy(logits, labels):
+    """Mean cross-entropy of `labels` [rows, S] under `logits` [T, V], in
+    float64 on the host, a block of rows at a time."""
+    logits = np.asarray(logits).reshape(labels.size, -1)
+    labels = np.asarray(labels).reshape(-1)
+    total = 0.0
+    for i in range(0, labels.size, 1024):
+        z = logits[i:i + 1024].astype(np.float64)
+        z -= z.max(axis=1, keepdims=True)
+        total += float((np.log(np.exp(z).sum(axis=1)) - z[
+            np.arange(len(z)), labels[i:i + 1024]]).sum())
+    return total / labels.size
+
+
+def _by_head(got, ref, heads):
+    """rms error over the reference's rms, a value head at a time: of the
+    op's output [T, heads x dv] or of a state [rows, heads, dk, dv]."""
+    diff = (got.astype(np.float64) - ref).reshape(len(got), heads, -1)
+    ref = ref.astype(np.float64).reshape(len(ref), heads, -1)
+    return (np.sqrt(np.mean(diff ** 2, axis=(0, 2)))
+            / np.sqrt(np.mean(ref ** 2, axis=(0, 2)))).tolist()
 
 
 def delta_ops_in_float32(prog, w0, at, delta_ops):
@@ -541,6 +680,7 @@ def judge(cfg, builder, got, ref, timed=None):
                                    ref["operators"][k]) for k in FIRST_HAND}
     conv_ops, delta_ops, delta_states, exact_ops, exact_states = ({}, {}, {},
                                                                   {}, {})
+    by_head, heads = {}, int(cfg["linear_num_value_heads"])
     for k in DELTA_LAYERS:
         _, mixed, _, out, last = got["delta_ops"][k]
         conv_ref, out_ref, last_ref = ref["delta_ops"][k]
@@ -550,6 +690,11 @@ def judge(cfg, builder, got, ref, timed=None):
         exact_ops[k] = _branch_errors(got["delta_ops_float32"][k][0], out_ref)
         exact_states[k] = _branch_errors(got["delta_ops_float32"][k][1],
                                          last_ref)
+        by_head[k] = {
+            "op_by_head": _by_head(got["delta_ops_float32"][k][0], out_ref,
+                                   heads),
+            "state_by_head": _by_head(got["delta_ops_float32"][k][1],
+                                      last_ref, heads)}
     inputs = {}
     for k in FIRST_HAND:
         y, y_ref = got["operators"][k][0], ref["operator_inputs"][k]
@@ -565,17 +710,35 @@ def judge(cfg, builder, got, ref, timed=None):
                  "second_loss_had_nothing_carried": unmoved}
         steps["err"] = [_rel(a, b) for a, b in steps["loss_timed_reference"]]
         steps["err_had_nothing_carried"] = _rel(unmoved, after)
-        steps["loss_second_build"] = [got["loss"], got["loss_next"]]
-        steps["err_second_build"] = [
-            _rel(float(t), own) for t, own in zip(
-                timed["losses"], steps["loss_second_build"])
-            if own is not None]
+        # the second build's own steps, as many as it was run, against
+        # the timed scan's first chunk
+        own = [got["loss"]] + list(got.get("losses_next") or (
+            [got["loss_next"]] if got.get("loss_next") is not None else []))
+        scan = [float(v) for v in timed.get("chunk_losses",
+                                            timed["losses"])][:len(own)]
+        steps["loss_second_build"] = own
+        steps["loss_timed"] = scan
+        steps["err_second_build"] = [_rel(t, o) for t, o in zip(scan, own)]
+        if len(own) > 1 and got.get("loss_unmoved_last") is not None:
+            steps["last_step"] = len(own) - 1
+            steps["err_second_build_last"] = steps["err_second_build"][-1]
+            # what a scan that never carried its state would read at its
+            # last step: the weights as drawn, on that step's rows
+            steps["loss_unmoved_first_last"] = [got["loss_unmoved_first"],
+                                                got["loss_unmoved_last"]]
+            steps["err_last_had_nothing_carried"] = _rel(
+                got["loss_unmoved_last"], own[-1])
+            # the host's cross-entropy of the inference program's logits
+            # against the step's own loss, both at the weights as drawn
+            steps["err_unmoved_first_against_step_0"] = _rel(
+                got["loss_unmoved_first"], own[0])
     report = {
         "operator_branch_err_max_rms": operators,
         "delta_rule_op_err_max_rms": delta_ops,
         "delta_rule_final_state_err_max_rms": delta_states,
         "delta_rule_in_float32_op_err_max_rms": exact_ops,
         "delta_rule_in_float32_final_state_err_max_rms": exact_states,
+        "delta_rule_in_float32_err_rms_by_part": by_head,
         "conv_op_err_max_rms": conv_ops,
         "operator_input_err_rms_rowscale": inputs,
         "timed_steps": steps,
@@ -597,6 +760,7 @@ def judge(cfg, builder, got, ref, timed=None):
                    "routing_flip_max": ROUTING_FLIP_MAX,
                    "logits": LOGITS_TOL, "logits_rms": LOGITS_RMS_TOL,
                    "loss": LOSS_TOL, "timed_twin": TIMED_TWIN_TOL,
+                   "timed_twin_last": TIMED_TWIN_LAST_TOL,
                    "grad_by_kind": GRAD_LIMITS,
                    "grad_else": GRAD_LIMITS_ELSE,
                    "global_grad_norm": GLOBAL_NORM_TOL,
@@ -608,6 +772,14 @@ def judge(cfg, builder, got, ref, timed=None):
                    "delta_state_rms": DELTA_STATE_RMS_TOL,
                    "delta_float32_op_rms": DELTA_F32_OP_RMS_TOL,
                    "delta_float32_state_rms": DELTA_F32_STATE_RMS_TOL,
+                   "delta_float32_op_head_median":
+                   DELTA_F32_OP_HEAD_MEDIAN_TOL,
+                   "delta_float32_state_head_median":
+                   DELTA_F32_STATE_HEAD_MEDIAN_TOL,
+                   "delta_float32_op_head_quartile":
+                   DELTA_F32_OP_HEAD_QUARTILE_TOL,
+                   "delta_float32_state_head_quartile":
+                   DELTA_F32_STATE_HEAD_QUARTILE_TOL,
                    "conv_op_rms": CONV_OP_RMS_TOL,
                    "attention": ATTENTION_TOL,
                    "attention_rms": ATTENTION_RMS_TOL,
@@ -619,6 +791,10 @@ def judge(cfg, builder, got, ref, timed=None):
         for k in ("grad_cos", "grad_norm_ratio", "update_err")}
     report["failed"] = verdict(report, timed is not None)
     report["ok"] = not report["failed"]
+    # each number a limit of PR 48 holds beside that limit: the harness
+    # prints these last, on standard error and in the result's line
+    report["compared"] = {name: [reading, limit] for name, (reading, limit)
+                          in numbers_set_again(report).items()}
     return report
 
 
@@ -627,18 +803,74 @@ def _grad_limits(key):
                            GRAD_LIMITS_ELSE)
 
 
+def numbers_set_again(report):
+    """{the limit's name: (the reading of a `judge` report it holds, the
+    limit)} of every limit PR 48 set again, a reading the report does not
+    hold None. `verdict` holds each reading to its limit THROUGH this
+    table and `chipbench.limits_study` lays the same table over the rows
+    on record, so a limit and what it reads are spelt once."""
+    routing = report["routing"] + report["routing_inference"]
+    parts = report.get("delta_rule_in_float32_err_rms_by_part") or {}
+    steps = report.get("timed_steps") or {}
+
+    def rms(key, layers=None):
+        return max(v[1] for k, v in report[key].items()
+                   if layers is None or k in layers)
+
+    def over_heads(which, statistic):
+        return max(float(statistic(p[which])) for p in parts.values()) \
+            if parts else None
+
+    def quartile(values):
+        return np.quantile(values, 0.75)
+
+    return {
+        "ROUTING_FLIP_MAX": (max(r["flipped_share"] for r in routing),
+                             ROUTING_FLIP_MAX),
+        "ROUTING_MARGIN": (max(r["worst_gap_in_spreads"] for r in routing),
+                           ROUTING_MARGIN),
+        "LOGITS_TOL": (report["logits_err_max"], LOGITS_TOL),
+        "DELTA_RMS_TOL": (rms("operator_branch_err_max_rms", DELTA_LAYERS),
+                          DELTA_RMS_TOL),
+        "DELTA_F32_OP_RMS_TOL": (
+            rms("delta_rule_in_float32_op_err_max_rms"),
+            DELTA_F32_OP_RMS_TOL),
+        "DELTA_F32_STATE_RMS_TOL": (
+            rms("delta_rule_in_float32_final_state_err_max_rms"),
+            DELTA_F32_STATE_RMS_TOL),
+        "DELTA_F32_OP_HEAD_MEDIAN_TOL": (
+            over_heads("op_by_head", np.median),
+            DELTA_F32_OP_HEAD_MEDIAN_TOL),
+        "DELTA_F32_STATE_HEAD_MEDIAN_TOL": (
+            over_heads("state_by_head", np.median),
+            DELTA_F32_STATE_HEAD_MEDIAN_TOL),
+        "DELTA_F32_OP_HEAD_QUARTILE_TOL": (
+            over_heads("op_by_head", quartile),
+            DELTA_F32_OP_HEAD_QUARTILE_TOL),
+        "DELTA_F32_STATE_HEAD_QUARTILE_TOL": (
+            over_heads("state_by_head", quartile),
+            DELTA_F32_STATE_HEAD_QUARTILE_TOL),
+        "TIMED_TWIN_TOL": ((steps.get("err_second_build") or [None])[0],
+                           TIMED_TWIN_TOL),
+        "TIMED_TWIN_LAST_TOL": (steps.get("err_second_build_last"),
+                                TIMED_TWIN_LAST_TOL),
+    }
+
+
 def verdict(report, timed=False):
     """Which limits the numbers of a `judge` report fail, by name: the
     report's own numbers against THIS module's limits (a study's saved
-    reports can be judged again after a limit was set from them)."""
+    reports can be judged again after a limit was set from them:
+    `chipbench/tests/test_limits_study.py` does)."""
     operators = report["operator_branch_err_max_rms"]
     inputs = report["operator_input_err_rms_rowscale"]
     by_param, rows = report["by_param"], \
         report["product_rows_written_held_chosen"]
+    again = numbers_set_again(report)
 
-    def branch_held(k, tol, rms_tol):
-        mx, rms = operators[k]
-        return bool(np.isfinite(mx) and mx <= tol and rms <= rms_tol)
+    def within(*names):
+        return all(again[n][0] is not None and np.isfinite(again[n][0])
+                   and again[n][0] <= again[n][1] for n in names)
 
     def rms_held(errors, tol):
         return all(np.isfinite(rms) and rms <= tol
@@ -650,29 +882,31 @@ def verdict(report, timed=False):
                     and abs(v["grad_norm_ratio"] - 1.0) <= ratio_tol)
 
     held = {
-        "delta": all(branch_held(k, DELTA_TOL, DELTA_RMS_TOL)
-                     for k in DELTA_LAYERS),
+        "delta": all(np.isfinite(operators[k][0])
+                     and operators[k][0] <= DELTA_TOL
+                     for k in DELTA_LAYERS) and within("DELTA_RMS_TOL"),
         "delta_op": rms_held(report["delta_rule_op_err_max_rms"],
                              DELTA_OP_RMS_TOL),
         "delta_state": rms_held(report["delta_rule_final_state_err_max_rms"],
                                 DELTA_STATE_RMS_TOL),
-        "delta_precision": rms_held(
-            report["delta_rule_in_float32_op_err_max_rms"],
-            DELTA_F32_OP_RMS_TOL) and rms_held(
-            report["delta_rule_in_float32_final_state_err_max_rms"],
-            DELTA_F32_STATE_RMS_TOL),
+        "delta_precision": within(
+            "DELTA_F32_OP_RMS_TOL", "DELTA_F32_STATE_RMS_TOL",
+            "DELTA_F32_OP_HEAD_MEDIAN_TOL",
+            "DELTA_F32_STATE_HEAD_MEDIAN_TOL",
+            "DELTA_F32_OP_HEAD_QUARTILE_TOL",
+            "DELTA_F32_STATE_HEAD_QUARTILE_TOL"),
         "conv_op": rms_held(report["conv_op_err_max_rms"], CONV_OP_RMS_TOL),
-        "attention": branch_held("attention", ATTENTION_TOL,
-                                 ATTENTION_RMS_TOL),
+        "attention": bool(np.isfinite(operators["attention"][0])
+                          and operators["attention"][0] <= ATTENTION_TOL
+                          and operators["attention"][1]
+                          <= ATTENTION_RMS_TOL),
         "norms": all(np.isfinite(scale) and scale <= NORM_SCALE_TOL[k]
                      for k, (_, scale) in inputs.items()),
-        "routing": all(
-            r["tokens"] and r["worst_gap_in_spreads"] <= ROUTING_MARGIN
-            and r["flipped_share"] <= ROUTING_FLIP_MAX
-            for r in report["routing"] + report["routing_inference"]),
-        "logits": bool(np.isfinite(report["logits_err_max"])
-                       and report["logits_err_max"] <= LOGITS_TOL
-                       and report["logits_err_rms"] <= LOGITS_RMS_TOL),
+        "routing": all(r["tokens"] for r in report["routing"]
+                       + report["routing_inference"])
+        and within("ROUTING_MARGIN", "ROUTING_FLIP_MAX"),
+        "logits": within("LOGITS_TOL")
+        and report["logits_err_rms"] <= LOGITS_RMS_TOL,
         "loss": report["train_loss_err"] <= LOSS_TOL,
         "global_grad_norm": report["global_grad_norm_err"]
         <= GLOBAL_NORM_TOL,
@@ -689,10 +923,10 @@ def verdict(report, timed=False):
         steps = report["timed_steps"]
         held["timed_steps"] = len(steps.get("err", ())) == 2 and all(
             np.isfinite(e) and e <= LOSS_TOL for e in steps["err"])
-        held["timed_steps_second_build"] = len(
-            steps.get("err_second_build", ())) == 2 and all(
-            np.isfinite(e) and e <= TIMED_TWIN_TOL
-            for e in steps["err_second_build"])
+        # step 0 (the same weights, the same rows) and the chunk's LAST
+        # step; step 1 is reported and not judged (`TIMED_TWIN_LAST_TOL`)
+        held["timed_steps_second_build"] = within("TIMED_TWIN_TOL",
+                                                  "TIMED_TWIN_LAST_TOL")
     return sorted(k for k, v in held.items() if not v)
 
 
@@ -708,9 +942,11 @@ def against_reference(fluid, cfg, builder, place, seed, tokens, labels,
 
     t0 = time.perf_counter()
     rows = int(cfg["reference"]["rows"])
+    # the second build follows the timed scan through its first chunk
+    # where the kind hands that over, else through step 1
+    t_all, l_all = (timed or {}).get("chunk_rows", (tokens, labels))
     got = system_side(fluid, cfg, builder, place, seed, tokens[:rows],
-                      labels[:rows],
-                      then=(tokens[rows:2 * rows], labels[rows:2 * rows]))
+                      labels[:rows], then=(t_all[rows:], l_all[rows:]))
     gc.collect()
     ref = reference_side(cfg, builder, got["w0"], tokens, labels,
                          *own_inputs(got))

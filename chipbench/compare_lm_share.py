@@ -100,12 +100,74 @@ UPDATE_TOL = 0.1
 MIXER_TOL = 5e-4
 SINKHORN_TOL = 1e-4
 NORM_SCALE_TOL = 3e-4
+# THE RULE (PR 48; `compare_lm_delta_share` states it in full): a limit set
+# again stands at least M above the standing program's worst reading over
+# the seeds on record and at least M below the least reading of every plant
+# that this check ALONE is there to catch; where no number has M on both
+# sides the statistic changes. The readings, row by row:
+# `chipbench/data/limits_study.json` (`compare_lm_share`), replayed by
+# `chipbench/tests/test_limits_study.py`. THIS CELL HAS NO 24 SEEDS ON
+# RECORD: six of PR 47's (1672760455, 2147487211, 918273645, 2147484102,
+# 581860225, 756794881: the standing program, through the grouped kernels)
+# and this PR's own; every other limit of this file stands 1.6 and more
+# above the worst of them (the routing's flips 6.6% against 11%, the
+# logits' rms 1.55% against 2.5%) and was left as it was.
+M = 1.4
 # gradient cosine at least, norm ratio within, by kind of parameter: what
 # the discrete routing touches directly (the router, the one held expert
-# sampled) moves with the near-ties; a mixer's scalar has 3 elements
+# sampled) moves with the near-ties.
+# `w_qa`'S NORM RATIO IS REPORTED AND NOT COMPARED (PR 48): as stated it
+# reads |ratio - 1| <= 0.0107 over eleven seeds (0.0001 - 0.0061 on ten,
+# 0.0107 on 2147484102, the seed whose near-ties move every number behind
+# the first expert layer), which the 0.01 of every other kind failed, and
+# it HAS NO UPPER READING: bf16 mixers read 0.0117, a bf16 router 0.0122
+# (inside what a twelfth seed may read; `mixers` and the router's own
+# cosine catch those), the four faults of the mixers' backward <= 0.0002.
+# A limit there could only fail sound runs. Its cosine stays held as every
+# other kind's (1 - cos <= 4.2e-4 against 3e-3)
 GRAD_LIMITS = {"router": (0.95, 0.10), "expert": (0.98, 0.04),
-               "phi_res": (0.99, 0.06), "alpha": (0.0, 0.25)}
+               "w_qa": (0.997, None)}
 GRAD_LIMITS_ELSE = (0.997, 0.01)
+# THE MIXERS' `phi_res` AND `alpha` ARE JUDGED POOLED (PR 48). One sampled
+# mixer's gradient was held by (0.99, 0.06) and (0.0, 0.25): as stated
+# `xing.l1.ffn_mhc_phi_res` (norm 5e-6 - 1e-5, a thousandth of the largest
+# mixer's) reads a cosine of 0.9478 - 0.9992 and a ratio up to 1.070 over
+# the six seeds, its `alpha` (3 numbers) a ratio up to 1.376: a mixer
+# behind the first expert layer moves with that layer's near-ties. Four of
+# the twelve mixers' `phi_res` have gradients of 1e-11 - 1e-19 (the first
+# attention mixer, the last feed-forward mixer, both of the prediction
+# module), whose direction is rounding noise (cosines of 0.04 - 0.6). So
+# the gradients of ALL the mixers of a kind are laid end to end (2,752,512
+# numbers of `phi_res`, 36 of `alpha`) before the cosine and the ratio:
+# each mixer then counts by its norm (a mixer of small norm is held by
+# the others' share of the direction only: PERF.md section 7).
+# EACH LIMIT BETWEEN TWO READINGS (`limits_study.json`). Lower: the system
+# as stated against the reference, `phi_res` over 7 seeds (the records
+# before PR 48 did not fetch every mixer), `alpha` over 10. Upper: FAULTS
+# OF THE MIXERS' HAND-WRITTEN BACKWARD, planted in the reference put in
+# the program's place and held against the reference without, on the
+# chip at the cell's size, seeds 2048004811, 1748004812, 2147484813
+# (`lower_precision_lm_share --reference-faults`): d HRes with its stream
+# axes exchanged (`res_grad_transposed`), d HPre left out, d HPost left out.
+#   phi_res 1 - cos: stated <= 2.68e-3 | transposed 0.631, 0.666, 0.777:
+#     the geometric mean 0.041, 15 from either -> cosine >= 0.96
+#   phi_res ratio:   stated <= 0.0199  | transposed 0.143, 0.149, 0.239:
+#     0.06, 3.0 above the one and 2.4 below the other
+#   alpha 1 - cos:   stated <= 1.19e-3 | d HPre out 0.0150, 0.0273, 0.334;
+#     d HPost out 0.255, 0.707, 0.825: 0.005, 4.2 above, 3.0 below
+#   alpha ratio:     stated <= 0.0401  | d HPost out 0.255, 0.707, 0.825
+#     (d HPre out reads from 0.0152, inside the stated spread: the cosine's
+#     to catch): 0.10, 2.5 from either
+# The precisions do not read here (bf16 mixers: `alpha` 4.1e-4, 0.046; a
+# bf16 router 1.7e-3, 0.112, which its own cosine catches first): `mixers`
+# holds them first-hand. And ONE FAULT NO NUMBER OF THIS FILE TELLS: the
+# coefficients' path back to the state left out of d x
+# (`coefficient_path_dropped`: `through` and the rms's share) moves the
+# pooled `phi_res` by 1.5e-5 - 1.6e-3, every sampled parameter's cosine by
+# under 5e-6 and the global norm by 4e-5, all inside the stated spread: at
+# the seed's weights (`phi` drawn small) that path carries nothing
+POOLED = {"phi_res": "_mhc_phi_res", "alpha": "_mhc_alpha"}
+POOLED_LIMITS = {"phi_res": (0.96, 0.06), "alpha": (0.995, 0.10)}
 
 
 def _products(prog):
@@ -126,6 +188,72 @@ def _first_mixer(prog):
             norm.output("Y")[0]]
 
 
+def pooled_params(prog):
+    """{kind: the names of every mixer's parameter of that kind, in the
+    program's order}: what `POOLED` judges end to end."""
+    names = [p.name for p in prog.global_block().all_parameters()]
+    return {kind: [n for n in names if n.endswith(suffix)]
+            for kind, suffix in POOLED.items()}
+
+
+def pooled_gradients(got, grads_ref):
+    """{kind: {"grad_cos", "grad_norm_ratio", "elements", "by_mixer":
+    {name: [cos, ratio, the reference's norm]}}} of the mixers' gradients
+    of a kind laid end to end; `grads_ref`: {name: the reference's}."""
+    found = {}
+    for kind, suffix in POOLED.items():
+        names = [n for n in got["clipped_pooled"] if n.endswith(suffix)]
+        if not names:
+            continue
+        sys_ = [got["clipped_pooled"][n].ravel() / got["scale"]
+                for n in names]
+        ref = [np.asarray(grads_ref[n], np.float32).ravel() for n in names]
+        cos, ratio = _cos_ratio(np.concatenate(sys_), np.concatenate(ref))
+        found[kind] = {
+            "grad_cos": cos, "grad_norm_ratio": ratio,
+            "elements": int(sum(len(v) for v in ref)),
+            "by_mixer": {n: [*_cos_ratio(a, b),
+                             float(np.linalg.norm(b.astype(np.float64)))]
+                         for n, a, b in zip(names, sys_, ref)}}
+    return found
+
+
+def numbers_set_again(report):
+    """{the limit's name: (the reading of a `judge` report it holds, the
+    limit)} of every limit PR 48 set again, a reading the report does not
+    hold None; `pooled_held` holds the pooled readings through this table
+    and `chipbench.limits_study` lays it over the rows on record (as
+    `compare_lm_delta_share.numbers_set_again`)."""
+    found = {}
+    for kind, (cos_min, ratio_tol) in POOLED_LIMITS.items():
+        v = report.get(kind + "_pooled") or {}
+        cos, ratio = v.get("grad_cos"), v.get("grad_norm_ratio")
+        found[f"POOLED_LIMITS[{kind}] 1 - cos"] = (
+            None if cos is None else 1.0 - cos, 1.0 - cos_min)
+        found[f"POOLED_LIMITS[{kind}] ratio"] = (
+            None if ratio is None else abs(ratio - 1.0), ratio_tol)
+    return found
+
+
+def pooled_held(report):
+    """{kind: whether the mixers' gradients of that kind, laid end to end,
+    lie within `POOLED_LIMITS`}; a kind the report does not hold is not
+    held."""
+    again = numbers_set_again(report)
+    return {kind: all(
+        again[f"POOLED_LIMITS[{kind}] {what}"][0] is not None
+        and again[f"POOLED_LIMITS[{kind}] {what}"][0]
+        <= again[f"POOLED_LIMITS[{kind}] {what}"][1]
+        for what in ("1 - cos", "ratio")) for kind in POOLED}
+
+
+def gradients_held(report):
+    """The check `gradients`: every sampled parameter within its kind's
+    limits and every pooled kind within its own."""
+    return all(_grad_held(k, v) for k, v in report["by_param"].items()) \
+        and all(pooled_held(report).values())
+
+
 def system_side(fluid, cfg, builder, place, seed, tokens, labels):
     """What the system computes on the row, as numpy: the weights the
     startup program drew (`w0`, every parameter), the inference program's
@@ -134,6 +262,7 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels):
     sampled parameters. Its scope is gone when this returns."""
     built = builder.build(fluid, cfg, seed, for_compare=True)
     picks = builder.sampled_params(cfg)
+    pooled = pooled_params(built["prog"])
     gnorm_var, scale_var = _clip_vars(built["prog"])
     feed = {built["token_feed"]: tokens, built["label_feed"]: labels}
     ids_vars = [r[0] for r in built["routing"]]
@@ -159,7 +288,9 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels):
             built["prog"], feed=feed,
             fetch_list=[built["loss"], built["ce"], built["ce_mtp"],
                         gnorm_var, scale_var] + ids_vars + first
-            + [n + "@GRAD_clipped" for n in picks.values()])
+            + [n + "@GRAD_clipped" for n in picks.values()]
+            + [n + "@GRAD_clipped" for names in pooled.values()
+               for n in names])
         w1 = {k: np.asarray(scope.find_var(n)).astype(np.float32)
               for k, n in picks.items()}
         # each router's bias before and after the step, in the order of
@@ -180,7 +311,12 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels):
         first_mixer=[np.asarray(v).astype(np.float32)
                      for v in fetched[5 + n_layers:8 + n_layers]],
         clipped={k: np.asarray(v).astype(np.float32)
-                 for k, v in zip(picks, fetched[8 + n_layers:])})
+                 for k, v in zip(picks, fetched[8 + n_layers:])},
+        # {mixer parameter: its clipped gradient}, every mixer of a kind
+        clipped_pooled={
+            n: np.asarray(v).astype(np.float32) for n, v in zip(
+                [n for names in pooled.values() for n in names],
+                fetched[8 + n_layers + len(picks):])})
     del scope, exe, fetched, evaled, built
     gc.collect()
     return got
@@ -215,7 +351,9 @@ def reference_side(cfg, builder, w0, tokens, labels):
                 logits=np.asarray(logits).reshape(T, -1),
                 mtp_logits=np.asarray(mtp).reshape(T, -1),
                 first_mixer=[np.asarray(v) for v in (res, post, normed)],
-                grads={k: np.asarray(grads[n]) for k, n in picks.items()})
+                grads={k: np.asarray(grads[n]) for k, n in picks.items()},
+                grads_pooled={n: np.asarray(g) for n, g in grads.items()
+                              if n.endswith(tuple(POOLED.values()))})
 
 
 def _logits_errors(got, ref, same):
@@ -284,12 +422,12 @@ def judge(cfg, builder, got, ref):
         decay = o["weight_decay"] if builder.reference.decays(name) else 0.0
         want = -o["learning_rate"] * (g_hat / (np.abs(g_hat) + eps)
                                       + decay * a)
-        kind = "expert" if key.startswith("expert_") else key
-        cos_min, ratio_tol = GRAD_LIMITS.get(kind, GRAD_LIMITS_ELSE)
         by_param[key] = {
             "grad_cos": cos, "grad_norm_ratio": ratio,
-            "grad_ok": bool(cos is not None and cos >= cos_min
-                            and abs(ratio - 1.0) <= ratio_tol),
+            # a pooled kind's sampled mixer is reported here, and judged
+            # with every other mixer of its kind (`POOLED`)
+            "grad_ok": _grad_held(key, {"grad_cos": cos,
+                                        "grad_norm_ratio": ratio}),
             "update_err": float(np.abs((b - a) - want).max()
                                 / np.abs(want).max())}
     (h_res, h_post, y), (h_res_ref, h_post_ref, y_ref) = (
@@ -323,45 +461,75 @@ def judge(cfg, builder, got, ref):
         "clip_scale_err": _rel(got["scale"], min(
             1.0, o["clip_global_norm"] / got["gnorm"])),
         "by_param": by_param,
+        **{kind + "_pooled": v for kind, v in pooled_gradients(
+            got, ref["grads_pooled"]).items()},
         "limits": {"routing_margin": ROUTING_MARGIN,
                    "routing_flip_max": ROUTING_FLIP_MAX,
                    "logits": LOGITS_TOL, "logits_rms": LOGITS_RMS_TOL,
                    "loss": LOSS_TOL, "grad_by_kind": GRAD_LIMITS,
                    "grad_else": GRAD_LIMITS_ELSE,
+                   "grad_pooled": POOLED_LIMITS,
                    "global_grad_norm": GLOBAL_NORM_TOL,
                    "update": UPDATE_TOL, "clip_scale": CLIP_SCALE_TOL,
                    "first_mixer": MIXER_TOL, "sinkhorn": SINKHORN_TOL,
                    "first_norm_scale": NORM_SCALE_TOL},
     }
-    worst = {k: [f(v[k] for v in by_param.values() if v[k] is not None)
-                 for f in (min, max)]
-             for k in ("grad_cos", "grad_norm_ratio", "update_err")}
-    report["worst"] = worst
+    report["worst"] = {
+        k: [f(v[k] for v in by_param.values() if v[k] is not None)
+            for f in (min, max)]
+        for k in ("grad_cos", "grad_norm_ratio", "update_err")}
+    report["failed"] = verdict(report)
+    report["ok"] = not report["failed"]
+    # each number a limit of PR 48 holds beside that limit: the harness
+    # prints these last, on standard error and in the result's line
+    report["compared"] = {name: [reading, limit] for name, (reading, limit)
+                          in numbers_set_again(report).items()}
+    return report
+
+
+def _grad_held(key, v):
+    kind = "expert" if key.startswith("expert_") else key
+    cos_min, ratio_tol = GRAD_LIMITS.get(kind, GRAD_LIMITS_ELSE)
+    return bool(kind in POOLED or (
+        v["grad_cos"] is not None and v["grad_cos"] >= cos_min
+        and (ratio_tol is None
+             or abs(v["grad_norm_ratio"] - 1.0) <= ratio_tol)))
+
+
+def verdict(report):
+    """Which limits the numbers of a `judge` report fail, by name: the
+    report's own numbers against THIS module's limits, so that a study's
+    saved reports can be judged again after a limit was set from them
+    (`chipbench/tests/test_limits_study.py`)."""
+    by_param = report["by_param"]
+    rows = report["product_rows_written_held_chosen"]
+    bias_moved = report["router_bias_moved_by_the_rule"]
+    logits = [report[k] for k in ("logits_err_max", "mtp_logits_err_max",
+                                  "logits_err_rms", "mtp_logits_err_rms")]
     held = {
         "routing": all(
             r["ok"] and r["flipped_share"] <= ROUTING_FLIP_MAX
-            for r in route + route_eval),
-        "logits": bool(np.isfinite(main_max) and np.isfinite(mtp_max)
-                       and max(main_max, mtp_max) <= LOGITS_TOL
-                       and max(main_rms, mtp_rms) <= LOGITS_RMS_TOL),
+            for r in report["routing"] + report["routing_inference"]),
+        "logits": bool(np.all(np.isfinite(logits))
+                       and max(logits[:2]) <= LOGITS_TOL
+                       and max(logits[2:]) <= LOGITS_RMS_TOL),
         "loss": max(report["train_loss_err"], report["cross_entropy_err"],
                     report["mtp_cross_entropy_err"]) <= LOSS_TOL,
         "global_grad_norm": report["global_grad_norm_err"]
         <= GLOBAL_NORM_TOL,
         "clip_scale": report["clip_scale_err"] <= CLIP_SCALE_TOL,
-        "gradients": all(v["grad_ok"] for v in by_param.values()),
-        "update": worst["update_err"][1] <= UPDATE_TOL,
+        "gradients": gradients_held(report),
+        "update": max(v["update_err"] for v in by_param.values())
+        <= UPDATE_TOL,
         "mixers": report["first_mixer_err"] <= MIXER_TOL
         and report["sinkhorn_column_err"] <= SINKHORN_TOL,
         "norms": report["first_norm_scale_err"] <= NORM_SCALE_TOL,
-        "product_rows": len(rows) == len(got["ids_eval"])
+        "product_rows": len(rows) == len(report["routing_inference"])
         and all(w == h == c for w, h, c in rows),
-        "router_bias": len(bias_moved) == len(got["ids"])
+        "router_bias": len(bias_moved) == len(report["routing"])
         and all(bias_moved),
     }
-    report["failed"] = sorted(k for k, v in held.items() if not v)
-    report["ok"] = not report["failed"]
-    return report
+    return sorted(k for k, v in held.items() if not v)
 
 
 def against_reference(fluid, cfg, builder, place, seed, tokens, labels):

@@ -11,9 +11,12 @@ gives it, so a later PR adds a cell by adding files and one entry:
     chipbench/traffic/<traffic>.json     the mix's parameters; its "kind"
                                          names the general generator
                                          chipbench/kinds/<kind>.py
-    chipbench/layer_metrics/<name>.py    read(obs) -> number or None
-                                         (`<traffic prefix>.<base>` falls
-                                         back to <base>.py)
+    chipbench/layer_metrics/<name>.py    read(obs) -> number (`<traffic
+                                         prefix>.<base>` falls back to
+                                         <base>.py); None = nothing to
+                                         read, which on the chip ends the
+                                         run with no result: the cell
+                                         lists the metric (PR 48)
 """
 
 import contextlib
@@ -54,6 +57,20 @@ def load_module(path, name=None):
 def load_json(path):
     with open(path) as f:
         return json.load(f)
+
+
+def json_objects(text):
+    """The JSON objects among the lines of a run's output, in order (a
+    run prints progress and warnings between them)."""
+    found = []
+    for ln in text.splitlines():
+        ln = ln.strip()
+        if ln.startswith("{"):
+            try:
+                found.append(json.loads(ln))
+            except ValueError:
+                pass
+    return found
 
 
 class Files:
@@ -429,7 +446,7 @@ def run_cell(workload, seed, seconds, trace, t_start=None, rehearsal=False,
               "memory_peak_bytes": memory_peak(devices)}
     obs = dict(res, cfg=cfg, traffic=traffic, peaks=peaks, cell=cell,
                chips=len(devices), trace=ctx.tracer.reduced, device=device)
-    metrics = {}
+    metrics, missing, notes = {}, [], {}
     names = (bench["per_layer"] if trace else bench["end_to_end"])
     for m in names:
         if "workloads" in m and cell["name"] not in m["workloads"]:
@@ -441,13 +458,41 @@ def run_cell(workload, seed, seconds, trace, t_start=None, rehearsal=False,
         else:
             reader = files.metric_reader(m["name"])
             value = reader.read(obs) if reader is not None else None
+            # what a reader has to say beside its number (`note(obs)`,
+            # where it has one: the grouped kernels' events against those
+            # wanted): into the run's detail and on standard error
+            said = getattr(reader, "note", lambda obs: None)(obs)
+            if said:
+                notes[m["name"]] = said
+                note(f"{m['name']}: {json.dumps(said)}")
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        else:
+            missing.append(m["name"])
     print(json.dumps({"chipbench_setup": setup.itemised(setup_s),
                       "compile_cache_dir": cache_dir,
                       "setup_compile": res["setup_compile"]}), file=out)
-    print(json.dumps({"chipbench_detail": res.get("detail", {}),
+    print(json.dumps({"chipbench_detail": dict(
+        res.get("detail", {}), **({"layer_metric_notes": notes}
+                                  if notes else {})),
                       "reference": res.get("reference")}), file=out)
+    if missing and not rehearsal:
+        # the contract wants every metric the cell lists: a line that
+        # lacks one is refused as malformed (PR 47), so say which reader
+        # found nothing and print no result at all. (A rehearsal on the
+        # CPU has no device plane: its line names what it left out.)
+        def where(name):
+            if not trace:
+                return "the kind's end_to_end"
+            reader = files.metric_reader(name)
+            return os.path.relpath(reader.__file__, files.root) \
+                if reader else "no reader file"
+
+        raise Refused(
+            f"cell {cell['name']!r}, --trace {int(bool(trace))}: "
+            + "; ".join(f"{n!r} ({where(n)}) read None" for n in missing)
+            + ": the line would lack a metric the cell lists, so no "
+            "result is printed")
     line = {"correct": bool(res["correct"]),
             "attempted": int(res["attempted"]),
             "failed": int(res["failed"]), "metrics": metrics,
@@ -455,6 +500,7 @@ def run_cell(workload, seed, seconds, trace, t_start=None, rehearsal=False,
             "checks": res.get("checks", {})}
     if rehearsal:
         line["rehearsal"] = True
+        line["metrics_missing"] = missing
     if override:
         line["override"] = override
     if trace and ctx.tracer.reduced:
@@ -463,5 +509,17 @@ def run_cell(workload, seed, seconds, trace, t_start=None, rehearsal=False,
         device["window_s"] = r["window_s"]
         line["breakdown"] = {"device_ops": r["device_ops"],
                              "idle_gaps": r["idle_gaps"]}
+    # the numbers the comparison held beside their limits, where its
+    # report pairs them (`compared`): the line's last key and the run's
+    # last lines on standard error, which is what the driver keeps of a
+    # run that is not `correct`
+    compared = (res.get("reference") or {}).get("compared")
+    if compared:
+        line["compared"] = dict(
+            compared, failed=list(res["reference"].get("failed", ())))
+        for name, value in line["compared"].items():
+            print(f"[chipbench compared] {name}: "
+                  + (f"{value[0]} (limit {value[1]})" if name != "failed"
+                     else json.dumps(value)), file=sys.stderr, flush=True)
     print(json.dumps(line), file=out, flush=True)
     return line

@@ -12,9 +12,11 @@ copied, and with it `token_rows`, `TokenSource`, the `train` kind's
 warm and time FIRST, compare AFTER the window on the window's own chunk 0
 (so `setup_s` holds no comparison and `window_peak_bytes` is what the
 traffic holds), the timed scan's losses of steps 0 and 1 held to the
-reference AND to the comparison's second build run step by step (what
+reference, and those of step 0 and of the LAST step of its first chunk to
+the comparison's second build run step by step through that chunk (what
 tells a carried state from one left as it was: at this depth and rate a
-step moves the loss by less than bf16 stands from float32), and in every
+step moves the loss by less than bf16 stands from float32, PR 48), and in
+every
 step fetched: every token routed, the products
 took the held experts' rows. That loop names a router bias and replays a
 rule over it: this model has neither, its `expert_bias` is zero and its

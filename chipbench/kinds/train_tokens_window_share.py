@@ -87,7 +87,9 @@ def _timed(ctx):
     """The timed program from build to the window's close. Returns (the
     result without the comparison, (tokens, labels) int32 [2 x rows, S] of
     steps 0 and 1 as the executable was fed them, {"losses": its losses
-    of those steps}); its scope is gone when this returns."""
+    of those steps, "chunk_losses": of all K steps of its first chunk,
+    "chunk_rows": (tokens, labels) [K x rows, S] of that chunk}); its
+    scope is gone when this returns."""
     from paddle_tpu.ops.lm_ops import window_blocks
 
     fluid, jax, t, cfg = ctx.fluid, ctx.jax, ctx.traffic, ctx.cfg
@@ -200,8 +202,12 @@ def _timed(ctx):
     name = next(iter(t["end_to_end"]))
     blocks = {"visited": visited, "full_causal": whole} if whole else None
     chunk0 = source.chunks[0]
-    fed = tuple(np.asarray(chunk0[built[k]])[:2].reshape(2 * rows, S)
-                for k in ("token_feed", "label_feed"))
+    # the executable's first chunk, whole: what a comparison that follows
+    # the scan to its LAST step replays (`compare_lm_delta_share`, PR 48)
+    chunk_rows = tuple(
+        np.asarray(chunk0[built[k]])[:K].reshape(K * rows, S)
+        for k in ("token_feed", "label_feed"))
+    fed = tuple(v[:2 * rows] for v in chunk_rows)
     return {
         "t_open": t_open, "checks": checks,
         "attempted": len(win["done"]) + 1, "failed": 0,
@@ -230,4 +236,5 @@ def _timed(ctx):
                    "window_peak_bytes": int(window_peak),
                    "steps_run": int(len(all_loads)),
                    "scopes": tokens_kind._scope_detail(by_scope)},
-    }, fed, {"losses": losses[:2]}
+    }, fed, {"losses": losses[:2], "chunk_losses": losses[:K],
+             "chunk_rows": chunk_rows}

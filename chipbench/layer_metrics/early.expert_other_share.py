@@ -2,8 +2,9 @@
 the seconds under the `moe_ffn` and `moe_ffn_grad` scopes, the router over
 all 64 experts (on the layer's input), the sorts, the row gathers into
 expert order and back, the visit lists, the zeroing of rows past the
-groups and the combine. None unless the trace holds the kernels a step
-makes (`early.grouped_matmul_roofline.kernel_seconds`)."""
+groups and the combine. None where
+the trace holds none of the grouped kernels, whatever their count
+(`early.grouped_matmul_roofline.kernel_seconds`)."""
 
 import os
 

@@ -2,8 +2,8 @@
 seconds under the `moe_ffn` and `moe_ffn_grad` scopes (the kernels' own
 keys lie under them), the part that is the router, softmax and top-k, the
 two sorts, the row gathers into expert order and back, the visit lists,
-SiLU, the combine and the router losses. None unless the trace holds the
-program's grouped kernels, nine a step and layer
+SiLU, the combine and the router losses. None where
+the trace holds none of the grouped kernels, whatever their count
 (`grouped_matmul_roofline.kernel_seconds`)."""
 
 from chipbench import scopes

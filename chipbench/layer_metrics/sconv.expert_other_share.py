@@ -1,8 +1,8 @@
 """% of the expert layers' device time OUTSIDE their grouped kernels: of
 the seconds under the `moe_ffn` and `moe_ffn_grad` scopes, the router over
 all 32 experts, the sorts, the row gathers into expert order and back, the
-zeroing of rows past the groups and the combine. None unless the trace
-holds the kernels a step makes
+zeroing of rows past the groups and the combine. None where
+the trace holds none of the grouped kernels, whatever their count
 (`sconv.grouped_matmul_roofline.kernel_seconds`)."""
 
 import os

@@ -5,27 +5,33 @@ experts received in that step
 (`costs_short_conv_share.expert_layer_least_seconds`, summed over every
 sparse layer and every step of the window: `RowsHeld` of each is fetched)
 over the seconds of the program's Pallas kernels in the traced window,
-found by their names as `grouped_matmul_roofline.py` finds them. None
-unless the trace holds exactly the kernels a step makes (nine a sparse
-layer)."""
+found by their names as `grouped_matmul_roofline.py` finds them. Nine a
+sparse layer and step are wanted; another count is reported and no longer
+erases the metric (`grouped_matmul_roofline.seconds_of_the_kernels`, PR
+48)."""
 
 from chipbench import costs_short_conv_share as costs
-from chipbench import scopes
-from chipbench.layer_metrics.grouped_matmul_roofline import KERNELS
+from chipbench.layer_metrics.grouped_matmul_roofline import (
+    events_note, seconds_of_the_kernels)
 
 
-def kernel_seconds(red, obs):
-    keys = [k for k in red["by_scope"] if scopes.in_scope(k, *KERNELS)]
-    want = (obs.get("steps_in_window") or 0) * \
-        costs.grouped_kernels_per_step(obs["cfg"])
-    if not want or sum(red["events"].get(k, 0) for k in keys) != want:
-        return None
-    return sum(red["by_scope"][k] for k in keys) or None
+def wanted_events(obs):
+    return (obs.get("steps_in_window") or 0) \
+        * costs.grouped_kernels_per_step(obs["cfg"])
+
+
+def kernel_seconds(red, obs, for_all_wanted=False):
+    return seconds_of_the_kernels(red, obs, wanted_events(obs),
+                                  for_all_wanted)
+
+
+def note(obs):
+    return events_note(obs, wanted_events(obs))
 
 
 def read(obs):
     red = obs.get("scopes")
-    spent = kernel_seconds(red, obs) if red else None
+    spent = kernel_seconds(red, obs, True) if red else None
     by_layer = obs.get("held_rows_by_layer")
     if not spent or not by_layer:
         return None

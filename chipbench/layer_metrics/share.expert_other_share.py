@@ -3,8 +3,8 @@ model whose layers hold a share of their experts: of the seconds under
 the `moe_ffn` and `moe_ffn_grad` scopes, the router over all experts, the
 sorts, the row gathers into expert order and back over ALL top_k x tokens
 rows (the shape is static, the held rows a part of it), the visit lists,
-the zeroing of rows past the groups, SiLU and the combine. None unless
-the trace holds the kernels a step makes
+the zeroing of rows past the groups, SiLU and the combine. None where
+the trace holds none of the grouped kernels, whatever their count
 (`share.grouped_matmul_roofline.kernel_seconds`)."""
 
 import os

@@ -3,8 +3,9 @@ the seconds under the `moe_ffn` and `moe_ffn_grad` scopes, the router over
 all 256 experts, the sorts, the row gathers into expert order and back
 over ALL top_k x tokens rows (65,536 at 8192 tokens: the shape is static,
 the held rows an eighth of it), the visit lists, the zeroing of rows past
-the groups and the combine. None unless the trace holds the kernels a
-step makes (`swa.grouped_matmul_roofline.kernel_seconds`)."""
+the groups and the combine. None where
+the trace holds none of the grouped kernels, whatever their count
+(`swa.grouped_matmul_roofline.kernel_seconds`)."""
 
 import os
 

@@ -171,7 +171,8 @@ def test_stock_timed_run_is_unchanged_and_records_nothing(tiny_pipe):
                            rehearsal=True, override=tiny_pipe)
     assert set(line) == {"correct", "attempted", "failed", "metrics",
                          "device", "workload", "seed", "checks",
-                         "rehearsal", "override"}
+                         "rehearsal", "override", "metrics_missing"}
+    assert line["metrics_missing"] == []
     assert set(line["metrics"]) == {"train_pipe_items_per_s", "setup_s"}
     assert [next(iter(v)) for v in lines[:2]] == ["chipbench_setup",
                                                   "chipbench_detail"]

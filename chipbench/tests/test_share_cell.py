@@ -82,8 +82,8 @@ def test_share_cell_traced():
     line, _ = _run(True)
     # the scope-read metrics need a device plane, which XLA:CPU does not
     # write: their readers return None and the line leaves them out
-    assert {"share.host_dispatch_ms", "share.model_flops_util",
-            "share.expert_load_max_over_mean", "share.held_rows_share",
+    assert {"host_dispatch_ms", "share.model_flops_util",
+            "expert_load_max_over_mean", "share.held_rows_share",
             "setup_compile_s"} <= set(line["metrics"])
     assert not {"share.mhc_share", "share.grouped_matmul_roofline",
                 "share.attention_roofline", "share.mtp_share"} & set(
@@ -100,16 +100,22 @@ def test_benchmark_entries_of_the_cell():
     assert cell["chips"] == 1 and cell["config"] == "xing4_0_29b_a4b"
     rate = next(m for m in bench["end_to_end"]
                 if m["name"] == "train_items_per_s")
-    assert rate["workloads"][-1] == CELL
-    mine = {m["name"].split(".", 1)[1]: m for m in bench["per_layer"]
-            if m["name"].startswith("share.")}
-    assert set(mine) == SHARE_METRICS
+    assert CELL in rate["workloads"]
+    # the cell's own entries under its prefix, and the entries PR 48 folded
+    # into one a quantity, which list the cell among their `workloads`
+    mine = {m["name"].split(".", 1)[-1]: m for m in bench["per_layer"]
+            if m["name"].startswith("share.") or (
+                "." not in m["name"] and m["moves"] == "train_items_per_s"
+                and CELL in m["workloads"])}
+    assert SHARE_METRICS <= set(mine)
     files = harness.Files()
     for name, m in mine.items():
-        assert m["workloads"] == [CELL] and m["moves"] == "train_items_per_s"
+        assert (m["workloads"] == [CELL] if "." in m["name"]
+                else CELL in m["workloads"])
+        assert m["moves"] == "train_items_per_s"
         assert files.metric_reader("share." + name) is not None
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    assert len(bench["workloads"]) == 6
+    assert len(bench["workloads"]) >= 6
 
 
 def test_configuration_file_states_the_share():
@@ -208,7 +214,7 @@ def test_model_flops_util_counts_the_mean_rows_of_the_layers():
         dict(obs, held_rows_by_layer=None)) is None
 
 
-@pytest.mark.parametrize("kernels, found", [(90, True), (108, False),
+@pytest.mark.parametrize("kernels, found", [(90, True), (108, True),
                                             (None, False)])
 def test_share_readers_on_a_made_reduction(kernels, found):
     """The readers that count the program's kernels read nothing unless
@@ -278,3 +284,39 @@ def test_lower_precision_study_tells_the_variants_apart(tmp_path,
     assert "update" in lines["masters"]["failed"]
     assert lines["mixers"]["report"]["logits_err_rms"] != \
         lines["stated"]["report"]["logits_err_rms"]
+
+
+def test_reference_faults_of_the_mixers_backward_read_in_the_pooled_numbers(
+        tmp_path, monkeypatch):
+    """The study's second mode at a tiny size on the CPU: a fault of the
+    mixers' hand-written backward planted in the reference put in the
+    program's place, the weights the system's own draw. The values stay
+    (the loss is asserted equal inside); d HRes with its stream axes
+    exchanged turns the pooled `phi_res` and d HPost left out the pooled
+    `alpha`, each beyond `POOLED_LIMITS`, and neither moves any other
+    sampled parameter's gradient; the coefficients' path left out of d x
+    reads under every limit (what `limits_study.json` holds of the chip
+    at the cell's size: `compare_lm_share`'s comment)."""
+    from chipbench import compare_lm_share, lower_precision_lm_share
+
+    monkeypatch.chdir(tmp_path)
+    tiny = dict(TINY, config=dict(TINY["config"], amp="bfloat16"))
+    lower_precision_lm_share.main([
+        "--seeds", str(2 ** 31 + 31), "--reference-faults",
+        "--override", json.dumps(tiny)])
+    lines = {d["variant"]: d for d in map(json.loads, (
+        tmp_path / "chiprun_out" / "lower_precision_lm_share.jsonl"
+    ).read_text().splitlines())}
+    assert set(lines) == set(lower_precision_lm_share.REFERENCE_FAULTS)
+    held = {k: compare_lm_share.pooled_held(d["report"])
+            for k, d in lines.items()}
+    assert held["res_grad_transposed"]["phi_res"] is False
+    assert held["post_grad_dropped"]["alpha"] is False
+    assert held["post_grad_dropped"]["phi_res"] is True
+    assert lines["coefficient_path_dropped"]["ok"] is True
+    for name in ("res_grad_transposed", "post_grad_dropped"):
+        assert lines[name]["failed"] == ["gradients"]
+        others = {k: v for k, v in lines[name]["report"]["by_param"].items()
+                  if k not in ("phi_res", "alpha")}
+        assert all(compare_lm_share._grad_held(k, v)
+                   for k, v in others.items())

@@ -61,12 +61,12 @@ def test_tokens_cell_traced():
     line, _ = _run(True)
     # the scope-read metrics need a device plane, which XLA:CPU does not
     # write: their readers return None and the line leaves them out
-    assert {"tokens.host_dispatch_ms", "tokens.model_flops_util",
-            "tokens.expert_load_max_over_mean",
+    assert {"host_dispatch_ms", "tokens.model_flops_util",
+            "expert_load_max_over_mean",
             "setup_compile_s"} <= set(line["metrics"])
     assert not {"tokens.moe_share", "tokens.expert_matmul_roofline",
                 "tokens.attention_roofline"} & set(line["metrics"])
-    assert line["metrics"]["tokens.expert_load_max_over_mean"]["value"] >= 1
+    assert line["metrics"]["expert_load_max_over_mean"]["value"] >= 1
     assert line["checks"]["window_compiles_zero"]
     assert line["checks"]["every_token_routed"]
     assert line["attempted"] == 2
