@@ -59,6 +59,11 @@ WHITE_LIST = frozenset({
     # out, g, beta, the decays and the carried state float32 inside
     # (parallel/delta_rule.py)
     "gated_delta_rule",
+    # attention over an indexer's selection: the masked flash kernels take
+    # bf16 operands as `causal_attention`'s do (`indexer_select` and
+    # `indexer_loss` stay neutral: they take what arrives and score in
+    # float32 inside)
+    "sparse_attention",
     # the residual path of n streams: the state and the sublayers' outputs
     # flow in the compute dtype, the mixers themselves are float32 inside
     "mhc_expand", "mhc_mix", "mhc_update",
@@ -82,6 +87,8 @@ FLOAT32_SLOTS = {
     # A_log, dt_bias: [Hv] float32 masters read as they are; the saved
     # chunk-start states stay float32
     "gated_delta_rule": frozenset({"ALog", "DtBias", "States"}),
+    # the saved logsumexp: the backward's weights are exp(s - lse)
+    "sparse_attention": frozenset({"Lse"}),
 }
 
 # Input slots of a white-list op whose value the lowering hands to a Pallas

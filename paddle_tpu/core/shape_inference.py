@@ -825,6 +825,68 @@ def _causal_attention_grad(ctx):
             ctx.set_output_dim(slot + "@GRAD", d)
 
 
+def _indexer_dims(ctx):
+    """QI [B, S, Hi, Di], KI [B, S, 1, Di], W [B, S, Hi], checked against
+    each other; QI's dims or None."""
+    qi, ki, w = (ctx.input_dim(s) for s in ("QI", "KI", "W"))
+    if qi is None:
+        return None
+    ctx.enforce(len(qi) == 4, f"QI must be [B, S, Hi, Di], got {qi}")
+    if ki is not None:
+        ctx.enforce(len(ki) == 4 and ki[2] == 1 and _shapes_match(
+            qi[:2] + qi[3:], ki[:2] + ki[3:]),
+            f"KI{ki} must be [B, S, 1, Di] of QI{qi}: one key head")
+    if w is not None:
+        ctx.enforce(len(w) == 3 and _shapes_match(qi[:3], w),
+                    f"W{w} must be [B, S, Hi] of QI{qi}")
+    return qi
+
+
+@register_infer_shape("indexer_select")
+def _indexer_select(ctx):
+    qi = _indexer_dims(ctx)
+    if qi is None:
+        return
+    ctx.enforce(int(ctx.attr("topk") or 0) >= 1, "topk must be >= 1")
+    ctx.set_output_dim("Mask", (qi[0], qi[1], qi[1]))
+    ctx.set_output_dim("Threshold", (qi[0], qi[1]))
+
+
+@register_infer_shape("sparse_attention")
+def _sparse_attention(ctx):
+    _causal_attention(ctx)
+    q, m = ctx.input_dim("Q"), ctx.input_dim("Mask")
+    if q is not None and m is not None:
+        ctx.enforce(len(m) == 3 and _shapes_match(
+            (q[0], q[1], q[1]), m),
+            f"Mask{m} must be [B, S, S] (query, key) of Q{q}")
+
+
+register_infer_shape("sparse_attention_grad")(_causal_attention_grad)
+
+
+@register_infer_shape("indexer_loss")
+def _indexer_loss(ctx):
+    qi = _indexer_dims(ctx)
+    q, lse = ctx.input_dim("Q"), ctx.input_dim("Lse")
+    if q is not None and lse is not None:
+        ctx.enforce(len(lse) == 3 and _shapes_match(
+            (q[0], q[2], q[1]), lse), f"Lse{lse} must be [B, H, S] of Q{q}")
+    ctx.set_output_dim("Loss", (1,))
+    for slot in ("QI", "KI", "W"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "Grad", d)
+
+
+@register_infer_shape("indexer_loss_grad")
+def _indexer_loss_grad(ctx):
+    for slot in ("QI", "KI", "W"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "@GRAD", d)
+
+
 @register_infer_shape("short_conv")
 def _short_conv(ctx):
     x, f = ctx.input_dim("X"), ctx.input_dim("Filter")

@@ -19,7 +19,8 @@ __all__ = [
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
-    "short_conv", "gated_delta_rule", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
+    "short_conv", "gated_delta_rule", "detached", "indexer_select",
+    "sparse_attention", "indexer_loss", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_first_step", "sequence_last_step", "dropout",
     "l2_normalize", "matmul", "topk", "warpctc", "sequence_reshape",
@@ -694,6 +695,80 @@ def causal_attention(q, k, v, scale=None, window=None, name=None):
     helper.append_op("causal_attention", {"Q": [q], "K": [k], "V": [v]},
                      {"Out": [y], "Lse": [lse]}, attrs)
     return y
+
+
+def detached(x):
+    """A copy of `x` that no gradient crosses: what a consumer reads that
+    must not train `x`'s producers while another consumer does (an `assign`
+    whose output is `stop_gradient`; XLA drops the copy)."""
+    from .tensor import assign
+
+    y = assign(x)
+    y.stop_gradient = True
+    return y
+
+
+def indexer_select(q_i, k_i, w, topk, name=None):
+    """The selection of DeepSeek Sparse Attention's lightning indexer:
+    `q_i` [B, S, Hi, Di], `k_i` [B, S, 1, Di] (one key head for all), `w`
+    [B, S, Hi] -> (mask [B, S, S] int8 (query, key), 1 where the key is
+    among the query's `topk` causal keys of largest I[t, s] = sum_j w[t,
+    j] relu(q_i[t, j] . k_i[s]) (all of them while t < topk; of equal
+    scores the lower position); threshold [B, S] float32, each query's
+    least chosen score). Exact, float32 scores, no gradient
+    (ops/lm_ops.py: indexer_select, parallel/sparse_index.py)."""
+    helper = LayerHelper("indexer_select", **locals())
+    B, S = q_i.shape[0], q_i.shape[1]
+    mask = helper.create_tmp_variable("int8", shape=(B, S, S),
+                                      stop_gradient=True)
+    threshold = helper.create_tmp_variable("float32", shape=(B, S),
+                                           stop_gradient=True)
+    helper.append_op("indexer_select", {"QI": [q_i], "KI": [k_i], "W": [w]},
+                     {"Mask": [mask], "Threshold": [threshold]},
+                     {"topk": int(topk)})
+    return mask, threshold
+
+
+def sparse_attention(q, k, v, mask, scale=None, name=None):
+    """Grouped-query attention over a selection: `q` [B, S, H, D], `k` [B,
+    S, Hkv, D], `v` [B, S, Hkv, Dv], `mask` [B, S, S] int8
+    (`indexer_select`'s, the same keys for every head) -> (out [B, S, H,
+    Dv], the scores' logsumexp [B, H, S] float32): each query's softmax
+    runs over its chosen keys alone. On a TPU place the masked flash
+    kernels of parallel/flash.py under the mask, with a hand-written
+    backward."""
+    helper = LayerHelper("sparse_attention", **locals())
+    y = helper.create_tmp_variable(
+        q.dtype, shape=tuple(q.shape[:3]) + (v.shape[3],))
+    lse = helper.create_tmp_variable(
+        "float32", shape=(q.shape[0], q.shape[2], q.shape[1]),
+        stop_gradient=True)
+    attrs = {} if scale is None else {"scale": float(scale)}
+    helper.append_op("sparse_attention",
+                     {"Q": [q], "K": [k], "V": [v], "Mask": [mask]},
+                     {"Out": [y], "Lse": [lse]}, attrs)
+    return y, lse
+
+
+def indexer_loss(q, k, lse, q_i, k_i, w, mask, scale=None, name=None):
+    """The loss that trains the indexer beside the model: the mean over
+    the queries of KL(p_t || softmax over the chosen keys of I[t, .]), p_t
+    the probabilities of `sparse_attention(q, k, ., mask)` (its `lse`
+    given) averaged over the heads and DETACHED (`q` and `k` are read
+    through `detached` copies: the loss trains what made `q_i`, `k_i` and
+    `w` and nothing else). -> loss [1] float32."""
+    helper = LayerHelper("indexer_loss", **locals())
+    loss = helper.create_tmp_variable("float32", shape=(1,))
+    saved = {s + "Grad": [helper.create_tmp_variable(
+        "float32", shape=tuple(x.shape), stop_gradient=True)]
+        for s, x in (("QI", q_i), ("KI", k_i), ("W", w))}
+    attrs = {} if scale is None else {"scale": float(scale)}
+    helper.append_op(
+        "indexer_loss",
+        {"Q": [detached(q)], "K": [detached(k)], "Lse": [lse], "QI": [q_i],
+         "KI": [k_i], "W": [w], "Mask": [mask]},
+        dict(saved, Loss=[loss]), attrs)
+    return loss
 
 
 def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None,

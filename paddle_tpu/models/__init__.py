@@ -25,7 +25,11 @@ and `qwen3_next` (a gated delta rule's matrix-valued state behind a 4-tap
 convolution in three of four layers, output-gated grouped-query attention
 at heads of 256 in the fourth, softmax top-10 experts beside a gated shared
 expert; as one chip's share): `qwen3_next.qwen3_next(tokens, cfg)`,
-`qwen3_next.qwen3_next_loss`, `qwen3_next.optimizer`.
+`qwen3_next.qwen3_next_loss`, `qwen3_next.optimizer`; and `keye_vl`
+(grouped-query attention over the keys a learned indexer picks for each
+query, the indexer trained beside the model by its own loss, softmax top-8
+experts; as one chip's share): `keye_vl.keye_vl(tokens, cfg)`,
+`keye_vl.keye_vl_loss`, `keye_vl.optimizer`.
 """
 
 from . import mnist
@@ -40,10 +44,11 @@ from . import laguna
 from . import smallthinker
 from . import lfm2
 from . import qwen3_next
+from . import keye_vl
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
            "machine_translation", "olmoe", "xing4", "laguna", "smallthinker",
-           "lfm2", "qwen3_next"]
+           "lfm2", "qwen3_next", "keye_vl"]
 
 
 def get_model(name):

@@ -1,5 +1,6 @@
 """Ops of a decoder language model with sparse experts: rms_norm,
-rotary_embedding, causal_attention, short_conv, moe_ffn.
+rotary_embedding, causal_attention, indexer_select, sparse_attention,
+indexer_loss, short_conv, gated_delta_rule, moe_ffn.
 
 No reference-framework counterpart (the reference predates them); the
 equations are those of OLMoE (Muennighoff et al., arXiv:2409.02060) as the
@@ -228,6 +229,190 @@ def causal_attention_grad_op(ctx, ins, attrs):
         grads = vjp(do.astype(q.dtype))
     dq, dk, dv = (jnp.swapaxes(g, 1, 2) for g in grads)
     return out(**{"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv})
+
+
+# ------------------------------------------- attention behind an indexer
+# DeepSeek Sparse Attention's form (DeepSeek-V3.2-Exp's report), as
+# Keye-VL-2.0's language model has it: `indexer_select` scores every causal
+# (query, key) pair with a small multi-head ReLU product and keeps a
+# query's `topk` best keys, exactly; `sparse_attention` is grouped-query
+# attention whose softmax runs over those keys alone; `indexer_loss` trains
+# the indexer towards the attention's own head-mean probabilities. The
+# lowerings are `parallel/sparse_index.py` (plain, a block of queries at a
+# time) and `parallel/flash.py`'s kernels given the mask.
+def _rows_of(fn, *batched):
+    """`fn` of one row of tokens over the leading (batch) axis, a row at a
+    time: a row's temporaries are large."""
+    return lax.map(lambda xs: fn(*xs), batched)
+
+
+def _indexer_inputs(ins):
+    """QI [B, S, Hi, Di], KI [B, S, 1, Di] (one key head for all), W [B,
+    S, Hi] -> q_i, k_i [B, S, Di], w."""
+    return first(ins, "QI"), first(ins, "KI")[:, :, 0, :], first(ins, "W")
+
+
+@register_op("indexer_select")
+def indexer_select_op(ctx, ins, attrs):
+    """QI [B, S, Hi, Di], KI [B, S, 1, Di], W [B, S, Hi] -> Mask [B, S, S]
+    int8 (query, key): 1 where the key is among the query's `topk` causal
+    keys of largest I[t, s] = sum_j W[t, j] relu(QI[t, j] . KI[s]) (every
+    causal key while t < topk; of equal scores the lower position), 0
+    elsewhere and above the diagonal; and Threshold [B, S] float32, each
+    query's least chosen score as the bisection found it (the one thing
+    the op writes of the scores it forms: a step that fetches it not
+    pays nothing for it). The scores are float32 from products on the
+    operands' dtype; the selection is exact, by bisection on the scores'
+    bits (`parallel/sparse_index.py`: no sort), a block of queries at a
+    time. Nothing is differentiated: the choice has no gradient."""
+    from ..parallel import sparse_index
+
+    topk = int(attrs["topk"])
+    mask, threshold = _rows_of(
+        lambda q, k, w: sparse_index.select(q, k, w, topk),
+        *_indexer_inputs(ins))
+    return out(Mask=mask, Threshold=threshold)
+
+
+set_stop_gradient_outputs("indexer_select", ["Mask", "Threshold"])
+
+
+def _plain_sparse_attention(q, k, v, mask, scale=None):
+    """softmax over the chosen keys alone of Q K^T * scale, times V, on Q
+    [B, H, S, D], K [B, Hkv, S, D], V [B, Hkv, S, Dv], mask [B, S, S]
+    (nonzero: chosen), float32 scores, and their logsumexp [B, H, S]: the
+    lowering for places without Mosaic."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=F32)
+    s = s / (q.shape[3] ** 0.5) if scale is None else s * scale
+    s = jnp.where((mask != 0)[:, None], s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                   preferred_element_type=F32).astype(q.dtype)
+    return o, lse
+
+
+@register_op("sparse_attention")
+def sparse_attention_op(ctx, ins, attrs):
+    """Q [B, S, H, D], K [B, S, Hkv, D], V [B, S, Hkv, Dv] (H a multiple
+    of Hkv: H / Hkv consecutive query heads read one key/value head),
+    Mask [B, S, S] int8 (`indexer_select`'s: the same keys for every head)
+    -> Out [B, S, H, Dv] and Lse [B, H, S] float32: each query's softmax
+    over ITS CHOSEN KEYS ALONE of Q K^T times the attr `scale` (1/sqrt(D)
+    where 0). On a TPU place the Pallas kernels of `parallel/flash.py`
+    with the mask's block as their predicate (no [S, S] scores in HBM);
+    elsewhere the plain composition."""
+    q, k, v = _heads_first(ins, "Q", "K", "V")
+    scale = float(attrs.get("scale", 0.0)) or None
+    if on_tpu():
+        from ..parallel.flash import flash_attention_fwd
+
+        o, lse = flash_attention_fwd(q, k, v, causal=True, scale=scale,
+                                     mask=first(ins, "Mask"),
+                                     **FLASH_FWD_BLOCKS)
+    else:
+        o, lse = _plain_sparse_attention(q, k, v, first(ins, "Mask"), scale)
+    return out(Out=jnp.swapaxes(o, 1, 2), Lse=lse)
+
+
+set_stop_gradient_outputs("sparse_attention", ["Lse"])
+
+
+@register_grad_maker("sparse_attention")
+def _sparse_attention_grad_maker(op, gout, gin):
+    """Hand-written, as `causal_attention`'s: the backward takes Out and
+    Lse as the forward left them, and the mask has no gradient."""
+    return [dict(
+        type="sparse_attention_grad",
+        inputs={"Q": op.input("Q"), "K": op.input("K"), "V": op.input("V"),
+                "Mask": op.input("Mask"), "Out": op.output("Out"),
+                "Lse": op.output("Lse"),
+                "Out@GRAD": [x or "" for x in gout.get("Out", [])]},
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in ("Q", "K", "V")},
+        attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
+
+
+@register_op("sparse_attention_grad")
+def sparse_attention_grad_op(ctx, ins, attrs):
+    """On a TPU place the dK/dV and dQ kernels of `parallel/flash.py`
+    under the mask, from the saved output and logsumexp; elsewhere the vjp
+    of the plain composition."""
+    q, k, v, o, do = _heads_first(ins, "Q", "K", "V", "Out", "Out@GRAD")
+    scale = float(attrs.get("scale", 0.0)) or None
+    mask = first(ins, "Mask")
+    if on_tpu():
+        from ..parallel.flash import flash_attention_bwd
+
+        grads = flash_attention_bwd(q, k, v, o, first(ins, "Lse"),
+                                    do.astype(q.dtype), causal=True,
+                                    scale=scale, mask=mask,
+                                    **FLASH_BWD_BLOCKS)
+    else:
+        _, vjp = jax.vjp(
+            lambda *a: _plain_sparse_attention(*a, mask, scale)[0], q, k, v)
+        grads = vjp(do.astype(q.dtype))
+    dq, dk, dv = (jnp.swapaxes(g, 1, 2) for g in grads)
+    return out(**{"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv})
+
+
+_INDEXER_TRAINED = ("QI", "KI", "W")
+
+
+@register_op("indexer_loss")
+def indexer_loss_op(ctx, ins, attrs):
+    """The loss that trains the indexer beside the model (the report's
+    sparse stage). Q [B, S, H, D], K [B, S, Hkv, D] and Lse [B, H, S], the
+    `sparse_attention`'s inputs and logsumexp, all three CONSTANTS here
+    (the target is detached); QI, KI, W and Mask as `indexer_select` took
+    and gave them -> Loss [1] float32 = the mean over all B x S queries of
+    KL(p_t || softmax over the chosen keys of I[t, .]), p_t the
+    attention's probabilities over the chosen keys averaged over the H
+    heads. The loss is a scalar, so its gradient with respect to QI, KI
+    and W is formed in the same pass over the query blocks (QIGrad,
+    KIGrad, WGrad, float32; the grad op scales them by the loss's
+    cotangent), and the head-mean probabilities, a pass over the main
+    attention's scores, are formed once a step."""
+    from ..parallel import sparse_index
+
+    q, k = _heads_first(ins, "Q", "K")
+    scale = float(attrs.get("scale", 0.0)) or 1.0 / (q.shape[3] ** 0.5)
+    q_i, k_i, w = _indexer_inputs(ins)
+    total, d_q, d_k, d_w = _rows_of(
+        lambda *row: sparse_index.loss_and_grads(*row, scale),
+        q, k, first(ins, "Lse"), q_i, k_i, w, first(ins, "Mask"))
+    tokens = q.shape[0] * q.shape[2]
+    return out(Loss=(jnp.sum(total) / tokens).reshape(1),
+               QIGrad=d_q / tokens, KIGrad=d_k[:, :, None, :] / tokens,
+               WGrad=d_w / tokens)
+
+
+set_stop_gradient_outputs("indexer_loss", ["QIGrad", "KIGrad", "WGrad"])
+
+
+@register_grad_maker("indexer_loss")
+def _indexer_loss_grad_maker(op, gout, gin):
+    """Hand-written: the forward left the gradients; Q, K, Lse and Mask
+    get none (the layer hands the op detached copies of Q and K)."""
+    inputs = {s: op.input(s) for s in _INDEXER_TRAINED}
+    inputs.update({s + "Grad": op.output(s + "Grad")
+                   for s in _INDEXER_TRAINED})
+    inputs["Loss@GRAD"] = [x or "" for x in gout.get("Loss", [])]
+    return [dict(
+        type="indexer_loss_grad", inputs=inputs,
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in _INDEXER_TRAINED},
+        attrs={})]
+
+
+@register_op("indexer_loss_grad")
+def indexer_loss_grad_op(ctx, ins, attrs):
+    """d QI, d KI, d W = the saved gradients times the loss's cotangent,
+    in the inputs' dtypes."""
+    g = first(ins, "Loss@GRAD").astype(F32).reshape(())
+    return out(**{s + "@GRAD": (first(ins, s + "Grad") * g).astype(
+        first(ins, s).dtype) for s in _INDEXER_TRAINED})
 
 
 # -------------------------------------------------------------- short_conv
@@ -1423,6 +1608,18 @@ def window_blocks(program):
     return visited, whole
 
 
+def _selection_pairs(op, block):
+    """(chosen, causal) (query, key) pairs of an `indexer_select` op a
+    step, from the shapes the program states: sum_t min(t + 1, topk) and S
+    (S + 1) / 2 a row of S tokens (a batch dimension the program leaves
+    open, -1, counts as one row)."""
+    B, S = block.vars[op.input("QI")[0]].shape[:2]
+    k = min(int(op.attrs["topk"]), S)
+    rows = max(int(B), 1)
+    return (rows * (k * (k + 1) // 2 + (S - k) * k),
+            rows * (S * (S + 1) // 2))
+
+
 def _forward_blocks(count):
     """`flash.<count>` (`blocks_visited`, `blocks_masked`) of the grid the
     forward kernel of a `causal_attention` op runs a head."""
@@ -1488,7 +1685,17 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("gated_delta_rule_grad", "delta_rule_grad_kernel", True,
              _delta_kernel_takes),
             ("causal_attention", "flash_attention_head_256", True,
-             lambda op, block: block.vars[op.input("Q")[0]].shape[3] >= 256))
+             lambda op, block: block.vars[op.input("Q")[0]].shape[3] >= 256),
+            ("indexer_select", "indexer_select_bisection", False, None),
+            ("sparse_attention", "sparse_attention_plain", False, None),
+            ("sparse_attention", "sparse_attention_kernel", True, None),
+            ("sparse_attention_grad", "sparse_attention_grad_kernel", True,
+             None),
+            ("indexer_loss", "indexer_loss_with_grads", False, None),
+            ("indexer_select", "sparse_attention_selected_pairs", False,
+             lambda op, block: _selection_pairs(op, block)[0]),
+            ("indexer_select", "sparse_attention_causal_pairs", False,
+             lambda op, block: _selection_pairs(op, block)[1]))
 
 
 def lowered_counts(program, device):
@@ -1536,7 +1743,16 @@ def lowered_counts(program, device):
     count as `delta_rule_kernel` / `delta_rule_grad_kernel` too, the others
     form them as batched products a head group at a time), and on a TPU
     place its `causal_attention` ops at heads of 256 or more
-    (`flash_attention_head_256`).
+    (`flash_attention_head_256`). Its `indexer_select` ops
+    (`indexer_select_bisection`: the exact top-k by bisection on the
+    scores' bits, on every place) with the (query, key) pairs they choose
+    and the causal pairs they choose among a step, static
+    (`sparse_attention_selected_pairs`, `sparse_attention_causal_pairs`),
+    its `sparse_attention` ops (`sparse_attention_plain`, each; on a TPU
+    place the flash kernels of parallel/flash.py under the mask take every
+    one and its grad: `sparse_attention_kernel`,
+    `sparse_attention_grad_kernel`) and its `indexer_loss` ops
+    (`indexer_loss_with_grads`: the loss and its gradient in one pass).
     A program without them reports none. Kept on the program until that
     is mutated or the mixed-precision policy changes, like
     `bn_pool.count`."""

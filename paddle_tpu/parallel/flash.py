@@ -41,6 +41,21 @@ the band's far edge crosses a block. Without a window and with one
 key/value head a query head the kernels lower as they did before either
 existed (tests/test_flash_attention.py holds the grids and index maps).
 
+A SELECTION (PR 49): with `mask` [B, S, S] int8 (query, key; nonzero = the
+query sees the key; the same for every head; nothing above the diagonal)
+the predicate on a pair is not a function of the two positions but a byte
+of the mask, which the three kernels take as one more operand (the dK/dV
+kernel transposed, formed once by the caller's side here): the softmax is
+over the chosen keys ALONE (`sparse_attention`, the attention behind a
+learned indexer). Blocks above the diagonal are skipped by block index as
+ever; every other block is visited and takes the mask's block, also one
+none of whose pairs is chosen (its weights come out zero: skipping those
+takes a table of occupied blocks a later change can prefetch). Padding
+needs no channel of its own there: padded keys and padded query rows are
+zero bytes of the mask, and a query row with no chosen key gets output 0,
+logsumexp -inf and weight 0 everywhere in the backward. Under their own
+names (`SPARSE_KERNELS`). A caller that passes no mask lowers as before.
+
 On a TPU place Mosaic compiles the kernels; on any other place (CPU tests)
 they run in Pallas interpret mode (core.places.pallas_interpret).
 """
@@ -61,6 +76,8 @@ __all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd",
 # device trace's op_name ends `.../causal_attention/flash_fwd/pallas_call`
 # (forward), `.../causal_attention_grad/flash_dkv/...` and `.../flash_dq/...`
 KERNELS = ("flash_fwd", "flash_dkv", "flash_dq")
+# and with a mask (`.../sparse_attention/sparse_flash_fwd/pallas_call`)
+SPARSE_KERNELS = tuple("sparse_" + name for name in KERNELS)
 
 
 # The running max and normaliser of the forward live in VMEM as [block_q,
@@ -78,16 +95,24 @@ def _across(x, n):
         x[:, :1], (x.shape[0], n))
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-            scale, causal, block_q, block_k, nk, window=None, n_keys=None):
+def _chosen(ref):
+    """A mask's block as booleans (int8 has no compare on every chip:
+    widened first)."""
+    return ref[0].astype(jnp.int32) != 0
+
+
+def _kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k, nk,
+            window=None, n_keys=None):
     """One query block against the key blocks it sees: the streaming
-    softmax, float32. A block that no edge crosses (`_for_block`) pays
-    neither for the mask nor for the guards of an empty row: all its scores
-    are finite, so its row maxima are, and `exp(m_prev - m_new)` is 0 for a
+    softmax, float32. `rest`: the mask's block if the caller gave one, then
+    the outputs and the scratch. A block no edge crosses (`_for_block`)
+    pays neither for the mask nor for the guards of an empty row: its scores
+    are all finite, so its row maxima are, and `exp(m_prev - m_new)` is 0 for a
     row that has seen nothing yet (m_prev = -inf), not NaN. m and l stay in
     the layout the row reductions give and `s - m`, `acc * corr` consume
     (`_LANES`): kept as 1-D [block_q] scratch, the relayout into lanes and
     back at every step cost a third of the kernel (PERF.md, PR 41)."""
+    *mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
     step = ki = pl.program_id(2)
     if window is not None:
@@ -105,7 +130,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         k = k_ref[0]                   # [bk, D]
         v = v_ref[0]                   # [bk, Dv]
         s = _dot(q, k, _NT) * scale    # [bq, bk]
-        if masked:
+        if masked and mask_ref:
+            s = jnp.where(_chosen(mask_ref[0]), s, -jnp.inf)
+        elif masked:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -131,9 +158,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         m_scr[...] = m_new
 
     # the mask where the diagonal or the band's far edge crosses, and
-    # nowhere else; padded keys are finite scores (`_fwd_padded`), not masked
-    _for_block(_accumulate, qi, ki, block_q, block_k, causal, None, window,
-               True if window is None else ki < n_keys)
+    # nowhere else (a selection's: on every visited block); padded keys are
+    # finite scores (`_fwd_padded`), not masked
+    if mask_ref:
+        _for_chosen(_accumulate, qi, ki, block_q, block_k)
+    else:
+        _for_block(_accumulate, qi, ki, block_q, block_k, causal, None,
+                   window, True if window is None else ki < n_keys)
 
     @pl.when(step == nk - 1)
     def _finish():
@@ -243,17 +274,35 @@ def _of_head(group):
     return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
+def _mask_spec(mask, heads, rows, cols, at):
+    """The operand list's tail for a mask [B, ., .] read in [rows, cols]
+    blocks by a grid whose first axis folds `heads` heads into each batch
+    entry; `at(i, j)` names the block of grid step (i, j). Nothing without
+    a mask."""
+    if mask is None:
+        return [], ()
+    return [pl.BlockSpec((1, rows, cols),
+                         lambda b, i, j: (b // heads, *at(i, j)))], (mask,)
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None,
+               mask=None):
     """q [BH, Sq, D] (Sq % block_q == 0), k/v [BHkv, Sk, D] (Sk % block_k
     == 0; BH a multiple of BHkv: that many consecutive query heads read one
-    key/value head, in place) -> (out [BH, Sq, D], lse [BH, Sq])."""
+    key/value head, in place), mask [B, Sq, Sk] int8 or None -> (out [BH,
+    Sq, D], lse [BH, Sq])."""
     BH, Sq, Dq = q.shape  # Dq may carry the +1 padding-mask channel
     Sk = k.shape[1]
     Dv = v.shape[-1]
     nq, nk = Sq // block_q, Sk // block_k
     kv = _of_head(BH // k.shape[0])
     # without a window the forward names key block j itself, visited or not
-    _, k_of = _band(block_q, block_k, nq, nk, window is not None, window)
+    # (a mask's blocks are a MiB each: a skipped step names a resident one)
+    _, k_of = _band(block_q, block_k, nq, nk,
+                    window is not None or mask is not None, window)
+    mask_spec, mask_arg = _mask_spec(
+        mask, BH // (1 if mask is None else mask.shape[0]), block_q, block_k,
+        lambda i, j: (i, k_of(i, j)))
     static = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k)
     if window is not None:
@@ -269,7 +318,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
                          lambda b, i, j: (kv(b), k_of(i, j), 0)),
             pl.BlockSpec((1, block_k, Dv),
                          lambda b, i, j: (kv(b), k_of(i, j), 0)),
-        ],
+        ] + mask_spec,
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
@@ -284,10 +333,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **({} if mask is None else {"vmem_limit_bytes": _BWD_VMEM_LIMIT})),
         interpret=pallas_interpret(),
-        name=KERNELS[0],
-    )(q, k, v)
+        name=(KERNELS if mask is None else SPARSE_KERNELS)[0],
+    )(q, k, v, *mask_arg)
 
 
 def _folded(x, pad=0):
@@ -298,10 +348,19 @@ def _folded(x, pad=0):
     return x
 
 
-def _fwd_padded(q, k, v, scale, causal, block_q, block_k, window=None):
+def _padded_mask(mask, pad_q, pad_k):
+    """mask [B, Sq, Sk] with zero bytes (nothing chosen) for the padding."""
+    if mask is not None and (pad_q or pad_k):
+        mask = jnp.pad(mask, ((0, 0), (0, pad_q), (0, pad_k)))
+    return mask
+
+
+def _fwd_padded(q, k, v, scale, causal, block_q, block_k, window=None,
+                mask=None):
     """Pad S to block multiples; padded KEYS are neutralized by extending D
     with a bias channel (q gains a 1, real keys a 0, padded keys -BIG), so
-    their scores vanish under exp without any in-kernel mask plumbing.
+    their scores vanish under exp without any in-kernel mask plumbing (under
+    a mask they are zero bytes of it, and no channel).
     k, v [B, Hkv, Sk, .]: H / Hkv consecutive query heads read one
     key/value head."""
     B, H, Sq, _ = q.shape
@@ -314,6 +373,7 @@ def _fwd_padded(q, k, v, scale, causal, block_q, block_k, window=None):
     if pad_k:
         kw = jnp.pad(kw, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vw = jnp.pad(vw, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    if pad_k and mask is None:
         BIG = jnp.asarray(3e4 / max(scale, 1e-6), jnp.float32).astype(q.dtype)
         qw = jnp.concatenate([qw, jnp.ones_like(qw[..., :1])], axis=-1)
         maskch = jnp.where(
@@ -322,7 +382,8 @@ def _fwd_padded(q, k, v, scale, causal, block_q, block_k, window=None):
         kw = jnp.concatenate(
             [kw, jnp.broadcast_to(maskch, kw.shape[:3] + (1,))], axis=-1)
     out, lse = _flash_fwd(_folded(qw), _folded(kw), _folded(vw), scale,
-                          causal, block_q, block_k, window)
+                          causal, block_q, block_k, window,
+                          _padded_mask(mask, pad_q, pad_k))
     out = out.reshape(B, H, Sq + pad_q, Dv)[:, :, :Sq]
     lse = lse[:, 0, :].reshape(B, H, Sq + pad_q)[:, :, :Sq]
     return out, lse
@@ -371,7 +432,10 @@ def flash_attention(q, k, v, causal=False, scale=None,
     return _flash(q, k, v, scale, bool(causal), block_q, block_k, window)
 
 
-def _resolve(q, k, scale, block_q, block_k, causal=True, window=None):
+def _resolve(q, k, scale, block_q, block_k, causal=True, window=None,
+             mask=None):
+    if mask is not None and (not causal or window is not None):
+        raise ValueError("a mask names causal pairs, and no band")
     block_q, block_k = normalize_blocks(block_q, block_k,
                                         q.shape[2], k.shape[2])
     if scale is None:
@@ -389,26 +453,29 @@ def _resolve(q, k, scale, block_q, block_k, causal=True, window=None):
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None,
-                        block_q=256, block_k=256, window=None):
+                        block_q=256, block_k=256, window=None, mask=None):
     """The forward kernel alone: (out [B, H, S, D], lse [B, H, S]). For a
     caller that keeps `lse` itself and calls `flash_attention_bwd` later
-    (an op whose backward is another op), so the kernel runs once."""
+    (an op whose backward is another op), so the kernel runs once. With
+    `mask` [B, S, S] int8 (causal only): the softmax over each query's
+    chosen keys alone."""
     scale, block_q, block_k, window = _resolve(q, k, scale, block_q,
-                                               block_k, causal, window)
+                                               block_k, causal, window, mask)
     return _fwd_padded(q, k, v, scale, bool(causal), block_q, block_k,
-                       window)
+                       window, mask)
 
 
 def flash_attention_bwd(q, k, v, out, lse, do, causal=False, scale=None,
-                        block_q=1024, block_k=1024, window=None):
+                        block_q=1024, block_k=1024, window=None, mask=None):
     """(dq, dk, dv) from the saved output and logsumexp of
-    `flash_attention_fwd` with the same q, k, v, causal, scale and window.
-    The blocks are the backward kernels' own; the default is the fastest of
-    a sweep on the v5e at [2, 16, 4096, 128] bf16 causal (ops/lm_ops.py)."""
+    `flash_attention_fwd` with the same q, k, v, causal, scale, window and
+    mask. The blocks are the backward kernels' own; the default is the
+    fastest of a sweep on the v5e at [2, 16, 4096, 128] bf16 causal
+    (ops/lm_ops.py)."""
     scale, block_q, block_k, window = _resolve(q, k, scale, block_q,
-                                               block_k, causal, window)
+                                               block_k, causal, window, mask)
     return _flash_vjp_bwd(scale, bool(causal), block_q, block_k, window,
-                          (q, k, v, out, lse), do)
+                          (q, k, v, out, lse), do, mask)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -437,14 +504,18 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len, window=None):
+def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len, window=None,
+             chosen=()):
     """p = exp(s - lse) on one float32 score block; where `masked`, zero
     for the pairs above the diagonal, for those `window` or more positions
-    back, and for padded keys. Queries run along `q_axis` of the block and
-    keys along the other."""
+    back, and for padded keys, or with `chosen` (a mask's block, laid out
+    as the scores are) for the pairs it does not name. Queries run along
+    `q_axis` of the block and keys along the other."""
     p = jnp.exp(s - lse)
     if not masked:
         return p
+    if chosen:
+        return jnp.where(_chosen(chosen[0]), p, 0.0)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     keep = None if kv_len is None else k_pos < kv_len
     if causal:
@@ -494,16 +565,23 @@ def _for_block(accumulate, qi, ki, block_q, block_k, causal, kv_len,
     pl.when(visited & jnp.logical_not(edge))(lambda: accumulate(False))
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, causal, block_q, block_k, nq,
-                kv_len, window=None, group=1, n_queries=None):
+def _for_chosen(accumulate, qi, ki, block_q, block_k):
+    """Under a selection's mask: `accumulate(True)` for every score block
+    (qi, ki) that does not lie wholly above the diagonal."""
+    pl.when(_crossed(qi, ki, block_q, block_k)[0])(lambda: accumulate(True))
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, *rest, scale, causal,
+                block_q, block_k, nq, kv_len, window=None, group=1,
+                n_queries=None):
     """One key block against the query blocks at or below it (within the
     band, with `window`), of each of the `group` query heads that read
     it: the innermost axis runs over (head of the group, query block).
     Scores are held transposed, [bk, bq], so that every product is a plain
     one (no block is transposed on its way into the MXU) and the per-query
     `lse` and `delta` broadcast along sublanes from their lane-major
-    rows."""
+    rows; a mask's block arrives transposed too, (key, query)."""
+    *mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     ki = pl.program_id(1)
     step = pl.program_id(2)
     qi = step if group == 1 else step % nq
@@ -519,14 +597,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dk_ref, dv_ref,
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         st = _dot(k, q, _NT) * scale                       # [bk, bq]
         pt = _weights(st, ld_ref[0, 0:1, :], masked, qi * block_q,
-                      ki * block_k, 1, causal, kv_len, window)
+                      ki * block_k, 1, causal, kv_len, window, mask_ref)
         dv_scr[:] += _dot(pt.astype(do.dtype), do, _NN)
         dpt = _dot(v, do, _NT)
         dst = pt * (dpt - ld_ref[0, 1:2, :])
         dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len, window,
-               True if window is None else qi < n_queries)
+    if mask_ref:
+        _for_chosen(_accumulate, qi, ki, block_q, block_k)
+    else:
+        _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len,
+                   window, True if window is None else qi < n_queries)
 
     @pl.when(step == group * nq - 1)
     def _finish():
@@ -535,12 +616,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dk_ref, dv_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dq_ref, dq_scr, *,
-               scale, causal, block_q, block_k, nk, kv_len, window=None,
-               n_keys=None):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, *rest, scale, causal,
+               block_q, block_k, nk, kv_len, window=None, n_keys=None):
     """One query block against the key blocks at or below the diagonal
     (within the band, with `window`); scores [bq, bk], `lse` and `delta`
     as columns."""
+    *mask_ref, dq_ref, dq_scr = rest
     qi = pl.program_id(1)
     step = ki = pl.program_id(2)
     if window is not None:
@@ -554,13 +635,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dq_ref, dq_scr, *,
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = _dot(q, k, _NT) * scale                        # [bq, bk]
         p = _weights(s, ld_ref[0, :, 0:1], masked, qi * block_q,
-                     ki * block_k, 0, causal, kv_len, window)
+                     ki * block_k, 0, causal, kv_len, window, mask_ref)
         dp = _dot(do, v, _NT)
         ds = p * (dp - ld_ref[0, :, 1:2])
         dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len, window,
-               True if window is None else ki < n_keys)
+    if mask_ref:
+        _for_chosen(_accumulate, qi, ki, block_q, block_k)
+    else:
+        _for_block(_accumulate, qi, ki, block_q, block_k, causal, kv_len,
+                   window, True if window is None else ki < n_keys)
 
     @pl.when(step == nk - 1)
     def _finish():
@@ -568,19 +652,20 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, dq_ref, dq_scr, *,
 
 
 def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
-               kv_len, window=None):
+               kv_len, window=None, mask=None):
     """q [BH, Sq, D], k [BHkv, Sk, D], v [BHkv, Sk, Dv], do [BH, Sq, Dv]
     (Dv may differ from D, as in the forward; BH / BHkv consecutive query
     heads read one key/value head), lse, delta [BH, Sq] float32 (Sq %
     block_q == 0, Sk % block_k == 0; keys at and past `kv_len`, if given,
-    are padding) -> dq, dk, dv. Two kernels: dK/dV with the query blocks
-    (of every query head of the group) innermost, dQ with the key blocks
-    innermost, each recomputing its score block in VMEM from the saved
-    logsumexp. Without a window a step above the causal frontier is
-    skipped, and its index maps name the block of the nearest visited
-    step, so it moves nothing either; with one the innermost axes run over
-    the band's blocks alone, and a step past its last block names that
-    block and computes nothing (`_band`, `_band_extents`)."""
+    are padding), mask [B, Sq, Sk] int8 or None -> dq, dk, dv. Two
+    kernels: dK/dV with the query blocks (of every query head of the
+    group) innermost, dQ with the key blocks innermost, each recomputing
+    its score block in VMEM from the saved logsumexp. Without a window a
+    step above the causal frontier is skipped, and its index maps name the
+    block of the nearest visited step, so it moves nothing either; with
+    one the innermost axes run over the band's blocks alone, and a step
+    past its last block names that block and computes nothing (`_band`,
+    `_band_extents`)."""
     BH, Sq, D = q.shape
     Sk, Dv = k.shape[1], v.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
@@ -607,6 +692,15 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=_BWD_VMEM_LIMIT)
     ld = jnp.stack([lse, delta], axis=1)                   # [BH, 2, Sq]
+    names = KERNELS if mask is None else SPARSE_KERNELS
+    batch = 1 if mask is None else mask.shape[0]
+    # the dK/dV kernel holds its scores transposed and reads the mask so
+    mask_t_spec, mask_t_arg = _mask_spec(
+        None if mask is None else jnp.swapaxes(mask, 1, 2),
+        k.shape[0] // batch, block_k, block_q,
+        lambda j, i: (j, q_at(0, j, i)[1]))
+    mask_spec, mask_arg = _mask_spec(mask, BH // batch, block_q, block_k,
+                                     lambda i, j: (i, k_of(i, j)))
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, nq=steps_q, **static, **band_q,
@@ -622,7 +716,7 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
             pl.BlockSpec((1, 2, block_q),
                          lambda b, j, i: (q_at(b, j, i)[0], 0,
                                           q_at(b, j, i)[1])),
-        ],
+        ] + mask_t_spec,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
@@ -633,8 +727,8 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
                         pltpu.VMEM((block_k, Dv), jnp.float32)],
         compiler_params=params,
         interpret=pallas_interpret(),
-        name=KERNELS[1],
-    )(q, k, v, do, ld)
+        name=names[1],
+    )(q, k, v, do, ld, *mask_t_arg)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nk=steps_k, **static, **band_k),
@@ -647,22 +741,24 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
                          lambda b, i, j: (kv(b), k_of(i, j), 0)),
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 2), lambda b, i, j: (b, i, 0)),
-        ],
+        ] + mask_spec,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=params,
         interpret=pallas_interpret(),
-        name=KERNELS[2],
-    )(q, k, v, do, jnp.swapaxes(ld, 1, 2))
+        name=names[2],
+    )(q, k, v, do, jnp.swapaxes(ld, 1, 2), *mask_arg)
     return dq, dk, dv
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, do):
+def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, do,
+                   mask=None):
     """Pad as `_fwd_padded` does and run the two backward kernels. A
-    padded key gets no weight (the kernels mask keys past Sk); a padded
-    query row carries dO = 0 and delta = 0, so with any finite lse it adds
-    nothing to dK or dV, and its dQ row is cut off."""
+    padded key gets no weight (the kernels mask keys past Sk, or read the
+    padded mask's zero bytes); a padded query row carries dO = 0 and delta
+    = 0, so with any finite lse it adds nothing to dK or dV, and its dQ
+    row is cut off."""
     q, k, v, out, lse = res
     Sq, Sk = q.shape[2], k.shape[2]
     pad_q = (-Sq) % block_q
@@ -672,7 +768,8 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, do):
         _folded(q, pad_q), _folded(k, pad_k), _folded(v, pad_k),
         _folded(do.astype(q.dtype), pad_q), _folded(lse, pad_q),
         _folded(delta, pad_q), scale, causal, block_q, block_k,
-        Sk if pad_k else None, window)
+        Sk if pad_k and mask is None else None, window,
+        _padded_mask(mask, pad_q, pad_k))
     return (dq[:, :Sq].reshape(q.shape), dk[:, :Sk].reshape(k.shape),
             dv[:, :Sk].reshape(v.shape))
 

@@ -92,6 +92,25 @@ def _estimate(block, op, batch):
             ("chunk", 64), ("head_k_dim", 128), ("head_v_dim", 128)))
         return out_elems / dv * (4.0 * c * dk + c * (dk + dv)
                                  + 6.0 * dk * dv + 2.0 * c * dv)
+    if t in ("indexer_select", "indexer_loss"):
+        # the indexer's scores over the causal pairs: 2 Hi Di a pair (the
+        # loss forms them again with their two gradients, and the main
+        # attention's scores once: 2 H D a pair)
+        qi = _shape_of(block, op.input("QI")[0], batch)
+        B, S, hi, di = (max(1, int(d)) if d and int(d) > 0 else batch
+                        for d in qi)
+        pairs = B * S * (S + 1) / 2.0
+        if t == "indexer_select":
+            return pairs * 2.0 * hi * di
+        q = _shape_of(block, op.input("Q")[0], batch)
+        return pairs * (6.0 * hi * di + 2.0 * max(1, int(q[2]))
+                        * max(1, int(q[3])))
+    if t == "sparse_attention":
+        # Q K^T and P V over the chosen pairs: the mask's `topk` is the
+        # selecting op's, so the triangle stands in (an upper bound)
+        q = _shape_of(block, op.input("Q")[0], batch)
+        S = max(1, int(q[1])) if q[1] and int(q[1]) > 0 else batch
+        return out_elems * 2.0 * (S + 1)
     if t in ("pool2d", "pool3d"):
         k = op.attrs.get("ksize") or []
         kk = 1.0

@@ -1,0 +1,84 @@
+"""The `keye_vl_2_0_30b_a3b` step and the kernels it added, compiled for a
+described v5e without the chip: `tests/test_tpu_compile.py`'s fixtures and
+helpers, in a file of its own so that it shares no test worker with that
+file (which alone runs for twelve minutes)."""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import test_tpu_compile as base  # noqa: E402
+from test_tpu_compile import (no_compile_cache, one_chip,  # noqa: E402,F401
+                              topo)
+
+
+@pytest.mark.parametrize("kind", ["forward", "backward"])
+def test_masked_flash_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch, kind):
+    """Mosaic takes the three kernels of `parallel/flash.py` under a mask,
+    at the `keye_vl_2_0_30b_a3b` cell's shape, 32 query heads on 4 key/value
+    heads of 128 over a row of 8192 with an int8 mask [8192, 8192]: one custom
+    call forward, two backward, and no [S, S] float32 temporary."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import flash
+
+    monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
+    S, H, Hkv, D = 8192, 32, 4, 128
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    q, kv = sds((1, H, S, D)), sds((1, Hkv, S, D))
+    mask, lse = sds((1, S, S), "int8"), sds((1, H, S), "float32")
+    if kind == "forward":
+        compiled = jax.jit(
+            lambda q, k, v, m: flash.flash_attention_fwd(
+                q, k, v, causal=True, mask=m, block_q=1024, block_k=1024)
+        ).lower(q, kv, kv, mask).compile()
+        calls = ["sparse_flash_fwd"]
+    else:
+        compiled = jax.jit(
+            lambda q, k, v, m, o, lse, do: flash.flash_attention_bwd(
+                q, k, v, o, lse, do, causal=True, mask=m)
+        ).lower(q, kv, kv, mask, q, lse, q).compile()
+        calls = ["sparse_flash_dkv", "sparse_flash_dq"]
+    assert base._custom_calls(compiled.as_text()) == calls
+    # the backward's transposed mask (67 MB), d O in q's dtype, lse | delta
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e8
+
+
+def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
+        one_chip, no_compile_cache, monkeypatch):
+    """The `keye_vl_2_0_30b_a3b` step at 1 x 8192 tokens (four layers of
+    attention behind an indexer, each with 16 held experts of 768) compiles
+    for one v5e chip with the masked flash kernels a layer (a forward,
+    dK/dV and dQ: no causal flash kernel is left), the grouped kernels
+    over the 16 held groups at K 2048 / F 768, the embedding's gradient by
+    the row-tile kernel, no sort of an [S, S] operand (the selection is by
+    bisection), no float32 [32, S, S] scores; and it fits the chip's
+    15.75 GB."""
+    cfg, compiled = base._lm_step(
+        one_chip, monkeypatch, "keye_vl_2_0_30b_a3b", 1,
+        lambda built: [built["routing"][0][1].name]
+        + [r[2].name for r in built["routing"]])
+    text = compiled.as_text()
+    calls = base._custom_calls(text)
+    layers = cfg["num_hidden_layers"]
+    assert [c for c in calls if "flash" in c] == \
+        ["sparse_flash_dkv"] * layers + ["sparse_flash_dq"] * layers \
+        + ["sparse_flash_fwd"] * layers
+    assert calls.count("row_tile_sum") >= 1
+    assert base.ragged_dots(text) == []
+    S = cfg["sequence_length"]
+    assert [ln for ln in text.splitlines()
+            if " sort(" in ln and "%d,%d]" % (S, S) in ln] == []
+    assert "f32[32,%d,%d]" % (S, S) not in text
+    mem = compiled.memory_analysis()
+    # the file's `arithmetic`: 465.4 M parameters x 12 B (weights and two
+    # moments; the gradients are temporaries) + 0.60 GB of kept copies
+    assert 6.1e9 < mem.argument_size_in_bytes < 6.3e9
+    print("temp bytes", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 9.0e9, mem.temp_size_in_bytes
