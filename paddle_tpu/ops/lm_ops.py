@@ -239,7 +239,8 @@ def causal_attention_grad_op(ctx, ins, attrs):
 # attention whose softmax runs over those keys alone; `indexer_loss` trains
 # the indexer towards the attention's own head-mean probabilities. The
 # lowerings are `parallel/sparse_index.py` (plain, a block of queries at a
-# time) and `parallel/flash.py`'s kernels given the mask.
+# time), `parallel/flash.py`'s kernels given the mask and, for the loss on
+# a TPU place, `parallel/index_loss.py`'s two kernels.
 def _rows_of(fn, *batched):
     """`fn` of one row of tokens over the leading (batch) axis, a row at a
     time: a row's temporaries are large."""
@@ -374,14 +375,22 @@ def indexer_loss_op(ctx, ins, attrs):
     and W is formed in the same pass over the query blocks (QIGrad,
     KIGrad, WGrad, float32; the grad op scales them by the loss's
     cotangent), and the head-mean probabilities, a pass over the main
-    attention's scores, are formed once a step."""
-    from ..parallel import sparse_index
+    attention's scores, are formed once a step. On a TPU place, for the
+    shapes `index_loss.takes`, the two Pallas kernels of
+    `parallel/index_loss.py`, a (query block, key block) tile at a time;
+    elsewhere `sparse_index.loss_and_grads`, a scan over blocks of
+    queries."""
+    from ..parallel import index_loss, sparse_index
 
     q, k = _heads_first(ins, "Q", "K")
     scale = float(attrs.get("scale", 0.0)) or 1.0 / (q.shape[3] ** 0.5)
     q_i, k_i, w = _indexer_inputs(ins)
+    kernels = on_tpu() and index_loss.takes(
+        q.shape[2], q.shape[1], k.shape[1], q.shape[3], *q_i.shape[2:],
+        q.dtype)
+    lowering = (index_loss if kernels else sparse_index).loss_and_grads
     total, d_q, d_k, d_w = _rows_of(
-        lambda *row: sparse_index.loss_and_grads(*row, scale),
+        lambda *row: lowering(*row, scale),
         q, k, first(ins, "Lse"), q_i, k_i, w, first(ins, "Mask"))
     tokens = q.shape[0] * q.shape[2]
     return out(Loss=(jnp.sum(total) / tokens).reshape(1),
@@ -1556,6 +1565,17 @@ def _delta_kernel_takes(op, block):
     return _delta_shapes_taken(tokens, low, op.attrs)
 
 
+def _index_loss_kernel_takes(op, block):
+    """Whether the Pallas kernels of `parallel/index_loss.py` take this
+    `indexer_loss`, from the shapes the program states."""
+    from ..parallel import index_loss
+
+    q, k, q_i = (block.vars[op.input(s)[0]] for s in ("Q", "K", "QI"))
+    low = amp.compute_dtype() if amp.is_enabled() else q.dtype
+    return index_loss.takes(q.shape[1], q.shape[2], k.shape[2], q.shape[3],
+                            *q_i.shape[2:], low)
+
+
 def _reads_a_tied_table(op, block):
     """A `lookup_table` whose W a `matmul` of the block reads transposed as
     its Y, or such a `matmul`: one parameter [V, C] that is the embedding
@@ -1695,7 +1715,9 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("indexer_select", "sparse_attention_selected_pairs", False,
              lambda op, block: _selection_pairs(op, block)[0]),
             ("indexer_select", "sparse_attention_causal_pairs", False,
-             lambda op, block: _selection_pairs(op, block)[1]))
+             lambda op, block: _selection_pairs(op, block)[1]),
+            ("indexer_loss", "indexer_loss_kernel", True,
+             _index_loss_kernel_takes))
 
 
 def lowered_counts(program, device):
@@ -1752,7 +1774,10 @@ def lowered_counts(program, device):
     place the flash kernels of parallel/flash.py under the mask take every
     one and its grad: `sparse_attention_kernel`,
     `sparse_attention_grad_kernel`) and its `indexer_loss` ops
-    (`indexer_loss_with_grads`: the loss and its gradient in one pass).
+    (`indexer_loss_with_grads`: the loss and its gradient in one pass; on
+    a TPU place those whose shapes the Pallas kernels of
+    parallel/index_loss.py take count as `indexer_loss_kernel` too, the
+    others scan over blocks of queries).
     A program without them reports none. Kept on the program until that
     is mutated or the mixed-precision policy changes, like
     `bn_pool.count`."""

@@ -15,6 +15,7 @@ first AdamW step is judged on the gradients the system itself produced,
 for the reason given in tests/test_xing4.py.
 """
 
+import functools
 import json
 import os
 import sys
@@ -612,6 +613,115 @@ def test_masked_flash_kernels_interpreted(n, block, dtype, which):
                                    **tol)
 
 
+INDEX_LOSS_CASES = {
+    # name: (tokens, query heads, key/value heads, (block_q, block_k),
+    #        dtype, what q is multiplied by)
+    "two_blocks": (64, 8, 2, (32, 32), "float32", 1.0),
+    "wide_keys": (64, 8, 2, (16, 32), "float32", 1.0),
+    "wide_queries": (64, 8, 2, (32, 16), "float32", 1.0),
+    "one_block": (32, 8, 2, (32, 32), "float32", 1.0),
+    "heads_32_on_4": (64, 32, 4, (32, 32), "float32", 1.0),
+    "bf16": (64, 8, 2, (32, 32), "bfloat16", 1.0),
+    # scores hundreds apart: exp(s - lse) is exactly 0 on chosen pairs, in
+    # all eight heads at once on some
+    "p_underflows": (64, 8, 2, (32, 32), "float32", 400.0),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _index_loss_case(name):
+    """(the kernels' (loss, d q_I, d k_I, d w), `sparse_index
+    .loss_and_grads`'s, the share of chosen pairs with p == 0) of a case,
+    made once: topk 12 of 32 or 64 tokens, so the first 12 queries of a
+    row have fewer keys than topk."""
+    import ml_dtypes
+
+    from paddle_tpu.ops.lm_ops import _plain_sparse_attention
+    from paddle_tpu.parallel import index_loss, sparse_index
+
+    n, H, Hkv, blocks, dtype, q_scale = INDEX_LOSS_CASES[name]
+    np_dtype = np.float32 if dtype == "float32" else ml_dtypes.bfloat16
+    rng = np.random.default_rng(7 + n + H)
+    q, k, _ = (t[0].astype(np_dtype)
+               for t in _attention_draw(rng, 1, H, Hkv, n, 16))
+    q = (q * q_scale).astype(np_dtype)
+    q_i, k_i, w = (t[0].astype(np_dtype)
+                   for t in _indexer_draw(rng, 1, n, hi=4))
+    k_i = k_i[:, 0]
+    mask = sparse_index.select(q_i, k_i, w, 12, block=16)[0]
+    q_h, k_h = (jnp.swapaxes(t, 0, 1) for t in (q, k))
+    scale = 16 ** -0.5
+    lse = _plain_sparse_attention(
+        *(t[None].astype(jnp.float32) for t in (q_h, k_h, k_h)),
+        mask[None], scale)[1][0]
+    want = sparse_index.loss_and_grads(q_h, k_h, lse, q_i, k_i, w, mask,
+                                       scale, block=16)
+    got = index_loss.loss_and_grads(q_h, k_h, lse, q_i, k_i, w, mask,
+                                    scale, blocks=blocks)
+    p = sparse_index.head_mean(q_h, k_h, lse, mask, scale, block=16)
+    zero = float(jnp.sum((p == 0) & (mask != 0)) / jnp.sum(mask != 0))
+    return got, want, zero
+
+
+@pytest.mark.parametrize("which", ["loss", "d_q", "d_k", "d_w"])
+@pytest.mark.parametrize("name", list(INDEX_LOSS_CASES))
+def test_index_loss_kernels_interpreted(name, which):
+    """The two kernels of `parallel/index_loss.py` (interpreted) against
+    `sparse_index.loss_and_grads`, their oracle: the loss and its gradient
+    with respect to q_I, k_I and w; blocks that are and are not square,
+    rows whose first queries have fewer than `topk` keys, 32 query heads on
+    4, bf16 operands (the gradient products' left operand is rounded to
+    them, as the chip's default precision rounds the plain lowering's),
+    chosen pairs whose p underflows to 0."""
+    got, want, zero = _index_loss_case(name)
+    i = ["loss", "d_q", "d_k", "d_w"].index(which)
+    assert got[i].shape == want[i].shape and got[i].dtype == jnp.float32
+    assert (zero > 0.02) == (name == "p_underflows"), zero
+    bf16 = INDEX_LOSS_CASES[name][4] == "bfloat16"
+    top = float(np.abs(np.asarray(want[i])).max())
+    np.testing.assert_allclose(
+        np.asarray(got[i]), np.asarray(want[i]), rtol=1e-4,
+        atol=top * (1e-2 if bf16 and which in ("d_q", "d_k") else 2e-5))
+
+
+def test_indexer_loss_takes_the_kernels_on_a_tpu_place_alone(monkeypatch):
+    """The op hands a row to `index_loss.loss_and_grads` only where the
+    step is traced for a TPU place AND `index_loss.takes` the shapes: with
+    both steered true the op's four outputs are the plain lowering's."""
+    from paddle_tpu.ops import lm_ops
+    from paddle_tpu.parallel import index_loss, sparse_index
+
+    rng = np.random.default_rng(11)
+    q, k, v = _attention_draw(rng, 2, 8, 2, 32, 16)
+    q_i, k_i, w = _indexer_draw(rng, 2, 32, hi=4)
+    mask = jnp.stack([sparse_index.select(q_i[b], k_i[b, :, 0], w[b],
+                                          12, block=16)[0] for b in range(2)])
+    _, lse = lm_ops._plain_sparse_attention(
+        *(jnp.swapaxes(t, 1, 2) for t in (q, k, v)), mask)
+    ins = {"Q": [q], "K": [k], "Lse": [lse], "QI": [q_i], "KI": [k_i],
+           "W": [w], "Mask": [mask]}
+    plain = lm_ops.indexer_loss_op(None, ins, {})
+    calls = []
+    real = index_loss.loss_and_grads
+    monkeypatch.setattr(
+        index_loss, "loss_and_grads",
+        lambda *a, **kw: calls.append(a[0].shape) or real(
+            *a, blocks=(16, 16), **kw))
+    monkeypatch.setattr(lm_ops, "on_tpu", lambda: True)
+    assert lm_ops.indexer_loss_op(None, ins, {})["Loss"][0] \
+        == plain["Loss"][0] and calls == []       # shapes not taken
+    monkeypatch.setattr(index_loss, "takes", lambda *a, **kw: True)
+    kernels = lm_ops.indexer_loss_op(None, ins, {})
+    assert calls == [(8, 32, 16)]                 # a row, heads first
+    for slot in ("Loss", "QIGrad", "KIGrad", "WGrad"):
+        a, b = kernels[slot][0], plain[slot][0]
+        assert a.shape == b.shape and a.dtype == b.dtype
+        _close(a, b, tol=1e-4, floor=1e-9)
+    monkeypatch.setattr(lm_ops, "on_tpu", lambda: False)
+    lm_ops.indexer_loss_op(None, ins, {})
+    assert len(calls) == 1                        # never off a TPU place
+
+
 def test_the_saved_logsumexp_stays_float32_under_amp():
     """`sparse_attention` is on AMP's white list (bf16 operands into the
     kernels) and its grad op reads `Lse` as the forward left it."""
@@ -683,10 +793,53 @@ def test_lowered_counts_name_the_new_lowerings(small):
     assert tpu["sparse_attention_kernel"] == 4 \
         == tpu["sparse_attention_grad_kernel"]
     assert "flash_attention" not in tpu      # no causal flash kernel left
+    # 48 tokens are no whole block of the loss's kernels: the plain scan
+    assert "indexer_loss_kernel" not in cpu
+    assert "indexer_loss_kernel" not in tpu
     # the cell's own: 14,681,088 of 33,558,528 a layer at one row of 8192
     from chipbench import costs_sparse_attn_share as costs
     assert costs.selected_pairs(8192, 2048) == 14681088
     assert costs.causal_pairs(8192) == 33558528
+
+
+@pytest.mark.parametrize("policy", ["bfloat16", None])
+def test_the_loss_s_kernels_are_counted_at_the_cell_s_shapes(policy):
+    """Four `indexer_loss` ops at the `keye_vl_2_0_30b_a3b` cell's shapes
+    (one row of 8192 tokens, 32 heads on 4 of 128, an indexer of 16 heads
+    of 64): `indexer_loss_kernel` 4 on a TPU place under the bf16 policy,
+    none for a float32 program, never on the CPU."""
+    from paddle_tpu import amp
+    from paddle_tpu.ops.lm_ops import lowered_counts
+
+    class Cpu:
+        platform = "cpu"
+
+    class Tpu:
+        platform = "tpu"
+
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup):
+        L = fluid.layers
+        S = 8192
+        q = L.data(name="q", shape=[S, 32, 128], dtype="float32")
+        kv = L.data(name="kv", shape=[S, 4, 128], dtype="float32")
+        q_i = L.data(name="qi", shape=[S, 16, 64], dtype="float32")
+        k_i = L.data(name="ki", shape=[S, 1, 64], dtype="float32")
+        w = L.data(name="w", shape=[S, 16], dtype="float32")
+        mask = L.data(name="m", shape=[S, S], dtype="int8")
+        lse = L.data(name="lse", shape=[32, S], dtype="float32")
+        for _ in range(4):
+            L.indexer_loss(q, kv, lse, q_i, k_i, w, mask)
+    if policy:
+        amp.enable(policy)
+    try:
+        cpu, tpu = lowered_counts(prog, Cpu), lowered_counts(prog, Tpu)
+    finally:
+        amp.disable()
+    assert cpu["indexer_loss_with_grads"] == 4 \
+        == tpu["indexer_loss_with_grads"]
+    assert "indexer_loss_kernel" not in cpu
+    assert tpu.get("indexer_loss_kernel") == (4 if policy else None)
 
 
 def test_op_costs_weigh_the_new_ops(small):

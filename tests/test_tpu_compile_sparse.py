@@ -50,6 +50,42 @@ def test_masked_flash_kernels_compile_for_v5e(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e8
 
 
+def test_index_loss_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                            monkeypatch):
+    """Mosaic takes the two kernels of `parallel/index_loss.py` at the
+    `keye_vl_2_0_30b_a3b` cell's shape (a row of 8192; q 32 heads on k 4
+    heads of 128; an indexer of 16 heads of 64): two custom
+    calls, neither of the plain scan's float32 blocks ([8, 256, 8192] of
+    the attention's scores a key/value head, [256, 16, 8192] of the
+    indexer's product), no float32 [heads, ., .] block of a tile's size
+    either, and of temporaries p [S, S] float32 (268 MB, written once)
+    beside the three [S, 128] statistics and the operands' small copies."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import index_loss
+
+    monkeypatch.setattr(index_loss, "pallas_interpret", lambda: False)
+    S, H, Hkv, D, Hi, Di = 8192, 32, 4, 128, 16, 64
+    assert index_loss.takes(S, H, Hkv, D, Hi, Di, jnp.bfloat16)
+
+    def sds(shape, dt="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: index_loss.loss_and_grads(*a, D ** -0.5)).lower(
+        sds((H, S, D)), sds((Hkv, S, D)), sds((H, S), "float32"),
+        sds((S, Hi, Di)), sds((S, Di)), sds((S, Hi)),
+        sds((S, S), "int8")).compile()
+    text = compiled.as_text()
+    assert base._custom_calls(text) == ["index_grads", "index_target"]
+    bq, bk = index_loss.BLOCKS
+    for block in ("f32[8,256,%d]" % S, "f32[256,16,%d]" % S,
+                  "f32[%d,%d,%d]" % (Hi, bq, bk), "f32[%d,%d,%d]" % (H, bq, bk)):
+        assert block not in text, block
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 4 * S * S <= temp < 4 * S * S + 8.0e7, temp
+
+
 def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
         one_chip, no_compile_cache, monkeypatch):
     """The `keye_vl_2_0_30b_a3b` step at 1 x 8192 tokens (four layers of
@@ -58,8 +94,8 @@ def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
     dK/dV and dQ: no causal flash kernel is left), the grouped kernels
     over the 16 held groups at K 2048 / F 768, the embedding's gradient by
     the row-tile kernel, no sort of an [S, S] operand (the selection is by
-    bisection), no float32 [32, S, S] scores; and it fits the chip's
-    15.75 GB."""
+    bisection), no float32 [32, S, S] scores, the indexer's loss in its
+    two kernels a layer; and it fits the chip's 15.75 GB."""
     cfg, compiled = base._lm_step(
         one_chip, monkeypatch, "keye_vl_2_0_30b_a3b", 1,
         lambda built: [built["routing"][0][1].name]
@@ -70,9 +106,16 @@ def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
     assert [c for c in calls if "flash" in c] == \
         ["sparse_flash_dkv"] * layers + ["sparse_flash_dq"] * layers \
         + ["sparse_flash_fwd"] * layers
+    # the indexer's loss: its two kernels a layer, no scan over blocks of
+    # 256 queries with their float32 [8, 256, S] and [256, 16, S] blocks
+    assert [c for c in calls if c.startswith("index_")] == \
+        ["index_grads"] * layers + ["index_target"] * layers
     assert calls.count("row_tile_sum") >= 1
     assert base.ragged_dots(text) == []
     S = cfg["sequence_length"]
+    # (the selection's score product, under `indexer_select`, keeps its)
+    assert [ln for ln in text.splitlines() if "/indexer_loss/" in ln and (
+        "f32[8,256,%d]" % S in ln or "f32[256,16,%d]" % S in ln)] == []
     assert [ln for ln in text.splitlines()
             if " sort(" in ln and "%d,%d]" % (S, S) in ln] == []
     assert "f32[32,%d,%d]" % (S, S) not in text
