@@ -22,23 +22,29 @@ The executors own one CompileCache each (kind "executor" /
 l2_* family) always track and surface through compile_cache_info();
 monitor-registry counters additionally tick when FLAGS_monitor is on
 (the disabled-mode contract keeps the registry untouched otherwise).
+
+Every miss of L1 also leaves a record of where its seconds went
+(builds.py): compile_cache_info()["builds"] for one executor,
+build_log() for the process. Always on; a hit of L1 leaves none.
 """
 
 import os
 import pickle
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 import jax
 from jax.experimental import serialize_executable as _se
 
 from .. import flags
-from . import service
+from . import builds, service
+from .builds import build_log
 from .keys import environment, program_digest, stable_digest
 from .store import L2Store
 
-__all__ = ["CompileCache", "L2Store", "default_store", "environment",
-           "place_jax_cache", "program_digest", "service", "stable_digest"]
+__all__ = ["CompileCache", "L2Store", "build_log", "builds",
+           "default_store", "environment", "place_jax_cache",
+           "program_digest", "service", "stable_digest"]
 
 flags.define(
     "compile_cache_dir", str, "",
@@ -113,6 +119,10 @@ class CompileCache:
         # local compile (we won the single-flight lease, or no service)
         self.l2_remote_hits = 0
         self.l2_remote_misses = 0
+        # this cache's newest builds (builds.py; the process's log keeps
+        # them too): compile_cache_info()["builds"]
+        self._builds = deque(maxlen=builds.LOG_CAP)
+        builds.install()
 
     # -- L1 ------------------------------------------------------------
     def get(self, key):
@@ -190,39 +200,52 @@ class CompileCache:
                 "remote_misses": self.l2_remote_misses,
                 "service": flags.get("compile_service") or None,
             },
+            "builds": [b.as_dict() for b in list(self._builds)
+                       if b.t1 is not None],
         }
 
     # -- a miss of L1: L2, then fresh -----------------------------------
+    def open_build(self, name, fingerprint, ident, parts, iters):
+        """The record of the build this miss starts (builds.Build), open on
+        the calling thread until its `close`: the caller stamps what it
+        runs itself (`lap`), `load_or_build` its own stretches, JAX's
+        events the seconds inside the jit call."""
+        record = builds.open_build(self.kind, name, fingerprint, ident,
+                                   parts, iters)
+        self._builds.append(record)
+        return record
+
     def load_or_build(self, key, *, content, program, build, devices,
-                      extra=(), use_cache=True, mon=None):
+                      record, extra=(), use_cache=True, mon=None):
         """What an executor does with a key `get` did not find: the stored
         executable of the key's content digest loaded onto `devices` and
         guarded through its first call, or `build(aot)` — the caller's
         closure, handed the export hook for
         executor_core.compile_step_fn(aot=...), None when nothing is to be
-        exported; then `put`. Returns (callable, level, seconds): level
-        "l2" for a loaded executable, None for a fresh one. `content` is
-        the key's process-stable part (executor_core.step_key) and `extra`
-        the caller's device / mesh context; `use_cache=False` builds fresh
-        and touches neither level."""
-        t0 = time.perf_counter()
-        digest = stable_digest(
-            program, content,
-            extra=(("kind", self.kind),) + tuple(extra)) \
-            if use_cache and self._l2_enabled() else None
-        loaded = self._l2_load(digest, devices, mon=mon) \
-            if digest is not None else None
+        exported; then `put`. Returns (callable, level): level "l2" for a
+        loaded executable, None for a fresh one. `content` is the key's
+        process-stable part (executor_core.step_key) and `extra` the
+        caller's device / mesh context; `use_cache=False` builds fresh
+        and touches neither level. `record`: the open build (`open_build`),
+        which is told the `digest`, `l2_load` and `export` stretches."""
+        digest = loaded = None
+        if use_cache and self._l2_enabled():
+            digest = stable_digest(
+                program, content,
+                extra=(("kind", self.kind),) + tuple(extra))
+            record.lap("digest")
+            loaded = self._l2_load(digest, devices, mon=mon)
+            record.lap("l2_load")
         if loaded is not None:
             # warm start (a restarted process, a fleet replica, an elastic
             # re-join): deserialized instead of compiled
             value, level = self._guard_l2(
                 loaded, lambda: build(None), mon=mon), "l2"
         else:
-            value, level = build(self._aot_sink(digest)), None
-        seconds = time.perf_counter() - t0
+            value, level = build(self._aot_sink(digest, record)), None
         if use_cache:
             self.put(key, value, mon=mon)
-        return value, level, seconds
+        return value, level
 
     # -- L2 ------------------------------------------------------------
     def _l2_enabled(self):
@@ -313,16 +336,22 @@ class CompileCache:
                 mon.extra = {}
             mon.extra["cache_l2_fallback"] = reason or "fallback"
 
-    def _aot_sink(self, digest, meta=None):
+    def _aot_sink(self, digest, record, meta=None):
         """Export callback for executor_core.compile_step_fn(aot=...):
         receives the freshly AOT-compiled executable once, right after its
-        first execution is set up, and serializes it into the store. None
-        when L2 is off (compile_step_fn then skips the AOT detour). Export
+        first execution is set up, and serializes it into the store; the
+        seconds that takes are the build record's `export`. None when L2
+        is off (compile_step_fn then skips the AOT detour). Export
         failures are swallowed — a cache write must never fail the step."""
         if digest is None or not self._l2_enabled():
             return None
 
         def sink(compiled_exe):
+            t0 = time.perf_counter()
+            export(compiled_exe)
+            record.add("export", time.perf_counter() - t0)
+
+        def export(compiled_exe):
             store = self.store()
             if store is None:
                 return

@@ -498,6 +498,20 @@ def step_key(program, feed_vals, fetch_names, state_names, *, iters=None,
     return (id(program), program._mutation), content
 
 
+# the names of step_key's content, in its order; the caller's `extra`
+# entries follow them
+CONTENT_PARTS = ("feeds", "fetches", "state", "amp", "debug_nans", "iters",
+                 "wire", "donate_feeds", "health")
+
+
+def key_parts(content):
+    """{name: part} of a step_key content: what a build record compares
+    with its program's last build to say which part changed
+    (cache.builds: `key_diff`)."""
+    n = len(CONTENT_PARTS)
+    return dict(zip(CONTENT_PARTS, content[:n]), extra=content[n:])
+
+
 # ---------------------------------------------------------------------------
 # Feed/fetch conversion helpers
 # ---------------------------------------------------------------------------

@@ -271,7 +271,7 @@ def _wire_var_dtypes(program, wire):
 # one prepared step: what split_state and finish_step need of prepare_step
 _Step = collections.namedtuple(
     "_Step", "compiled program scope fetch_names state_names "
-             "state_out_names iters hplan mon kind was_miss build_s fp")
+             "state_out_names iters hplan mon kind was_miss build fp")
 
 
 def prepare_step(cache, program, scope, feed_vals, fetch_names, *, iters,
@@ -307,8 +307,19 @@ def prepare_step(cache, program, scope, feed_vals, fetch_names, *, iters,
     if mon is not None:
         fp = monitor.fingerprint_of(cache_key)
         mon.lap("cache_lookup")
-    build_s, level = 0.0, "l1"
+    record, level = None, "l1"
     if compiled is None:
+        if fp is None:
+            fp = monitor.fingerprint_of(cache_key)
+        # the build's record (cache/builds.py), always on: open from here
+        # to finish_step, on this thread, so that JAX's trace, lowering
+        # and backend events of the first call are filed under it; the
+        # name is the jitted wrap's (see `build` below)
+        record = cache.open_build(
+            "multi" if iters is not None else "health_step"
+            if hplan is not None else "wired" if wire is not None
+            else "step",
+            fp, ident, executor_core.key_parts(content), iters)
         # FLAGS_verify: static checks ride the compile-cache MISS path
         # only (memoized per program+mutation+config), so the enabled
         # flag's steady-state cost is this one dict lookup
@@ -317,6 +328,7 @@ def prepare_step(cache, program, scope, feed_vals, fetch_names, *, iters,
             fetch_names=list(fetch_names),
             donate_state=not flags.get("debug_nans"), context=kind,
             **(verify or {}))
+        record.lap("verify")
 
         def build(aot):
             step = executor_core.build_step_fn(
@@ -353,20 +365,23 @@ def prepare_step(cache, program, scope, feed_vals, fetch_names, *, iters,
                 step, donate_state=not flags.get("debug_nans"),
                 donate_feeds=donate_feeds, probe=probe, aot=aot)
 
-        compiled, level, build_s = cache.load_or_build(
+        compiled, level = cache.load_or_build(
             cache_key, content=content, program=program, build=build,
-            devices=devices, extra=l2_extra(), use_cache=use_cache, mon=mon)
+            devices=devices, extra=l2_extra(), use_cache=use_cache, mon=mon,
+            record=record)
+        record.level = level
         if mon is not None:
             # a miss compiles inside the first call as well (async
             # dispatch): both stretches are the `compile` phase
             mon.lap("cache_load" if level == "l2" else "compile")
+            mon.build = record
     if mon is not None:
         mon.mark_cache(level is not None, fingerprint=fp, level=level,
                        lowered=executor_core.lowered_counts(
                            program, devices[0]))
     return _Step(compiled, program, scope, fetch_names, state_names,
                  state_out_names, iters, hplan, mon, kind, level is None,
-                 build_s, fp)
+                 record, fp)
 
 
 def split_state(step, place=None):
@@ -398,15 +413,20 @@ def finish_step(step, fetches, new_mut, step0):
     """After the call of step.compiled: the health leaf off the fetches,
     the call's lap — `dispatch` (enqueue time under async dispatch) on a
     hit or a load from the persistent store, `compile` on a miss, whose
-    first call holds the XLA compile — the state written back, the health
-    hook. Returns the caller's fetches."""
+    first call holds the XLA compile — the record of a build closed
+    (flags or none), the state written back, the health hook. Returns
+    the caller's fetches."""
     mon, hstats = step.mon, None
     if step.hplan is not None:
         hstats, fetches = fetches[-1], fetches[:-1]
     if mon is not None:
-        call_s = mon.lap("compile" if step.was_miss else "dispatch")
-        if step.was_miss and mon.monitored:
-            monitor.record_compile(step.fp, wall_s=step.build_s + call_s)
+        mon.lap("compile" if step.was_miss else "dispatch")
+    if step.build is not None:
+        # built or loaded in this run(): the build ends with the first
+        # call, and its wall is the compile's one stamp
+        step.build.close()
+        if step.was_miss and mon is not None and mon.monitored:
+            monitor.record_compile(step.fp, wall_s=step.build.wall)
     # write back BEFORE any nan check can raise: mut_state was donated,
     # so skipping this would leave the scope holding deleted buffers
     for n, v in new_mut.items():

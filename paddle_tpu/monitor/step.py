@@ -29,8 +29,10 @@ Compile-cache visibility: executors mark every cache lookup
 executor_core.compile_step_fn — the probe lowers the jitted step once,
 immediately before its first execution (inputs are still alive there;
 after the call donated buffers are deleted), and records the HLO cost
-analysis (FLOPs + bytes accessed) plus compile wall time per cache-key
-fingerprint. bench.py turns those FLOPs into MFU (see mfu.py).
+analysis (FLOPs + bytes accessed) per cache-key fingerprint. bench.py
+turns those FLOPs into MFU (see mfu.py). The compile's wall time per
+fingerprint is the wall of the build's record (cache/builds.py, always
+on), whose phases step_end replays under the step span's `compile`.
 """
 
 import threading
@@ -121,7 +123,7 @@ class StepRecord:
 
     __slots__ = ("kind", "t0", "phases", "cache", "cache_level",
                  "fingerprint", "extra", "intervals", "monitored", "t_lap",
-                 "lowered")
+                 "lowered", "build")
 
     def __init__(self, kind, monitored=True):
         self.kind = kind
@@ -132,6 +134,8 @@ class StepRecord:
         self.cache_level = None  # "l1" | "l2" on a hit (l2 = warm start)
         self.fingerprint = None
         self.lowered = {}       # see mark_cache
+        self.build = None       # the cache.builds.Build of a step that was
+        #                         built or loaded in this run()
         self.extra = None    # journal-only extras
         self.intervals = []  # (name, t0, t1) per occurrence — the phase
         #                      boundaries step_end replays as trace spans
@@ -317,9 +321,39 @@ def step_end(rec, iters=None, datapipe=None, replica_ms=None,
         ctx = tr.record(f"{rec.kind}.step", rec.t0,
                         rec.t0 + total_ms / 1000.0, kind="step",
                         attrs=attrs)
+        # a step built or loaded in this run(): the build's phases go under
+        # its `compile` (or `cache_load`) stretches, the one before the
+        # call first
+        stretches = iter((rec.build.BEFORE_CALL, rec.build.IN_CALL)) \
+            if rec.build is not None else None
         for name, p0, p1 in rec.intervals:
-            tr.record(name, p0, p1, kind="phase", parent=ctx)
+            phase = tr.record(name, p0, p1, kind="phase", parent=ctx)
+            if stretches is not None and name in ("compile", "cache_load"):
+                _replay_build(tr, rec.build, name, p0, p1, phase,
+                              next(stretches, ()))
     return record
+
+
+def _replay_build(tr, build, name, p0, p1, parent, phases):
+    """One `compile` (or `cache_load`) stretch of a step span, tiled by the
+    build's phases (cache.builds) as its children: `compile.verify`,
+    `compile.trace`, `compile.lower`, `compile.backend`, ...,
+    `compile.self`. As the laps tile the step: the stretch before the call
+    holds what prepare_step stamped, the call what JAX reported, each in
+    the record's order from the stretch's start, and `self` is what is
+    left of the stretch."""
+    attrs = {"fingerprint": build.fingerprint,
+             "persistent_hit": build.persistent_hit}
+    t = p0
+    for phase in phases:
+        t1 = min(p1, t + build.phases[phase])
+        if t1 > t:
+            tr.record(f"{name}.{phase}", t, t1, kind="phase", parent=parent,
+                      attrs=attrs)
+            t = t1
+    if p1 > t:
+        tr.record(f"{name}.self", t, p1, kind="phase", parent=parent,
+                  attrs=attrs)
 
 
 def _monitor_step(rec, total_ms, iters, datapipe, replica_ms, replica_ids):

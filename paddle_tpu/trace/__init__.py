@@ -17,7 +17,11 @@ hang". Three hot paths are instrumented end to end:
             slot_wait, ticket_wait, lock_wait, upstream_wait, stack,
             transfer, next), each recorded where the work happens — a
             decode worker's own stamps ride its ack.
-  compiles  compile phases carry the cache fingerprint.
+  compiles  a miss's `compile` phase (`cache_load` for a step loaded from
+            the persistent store) is tiled by `compile.verify | digest |
+            l2_load | trace | lower | backend | export | self` children
+            carrying the cache fingerprint, replayed from the build's
+            always-on record (cache/builds.py).
 
 Spans land in an in-memory flight recorder (recorder.py): per-thread
 fixed-size rings, dumped (spans.jsonl + chrome trace.json +

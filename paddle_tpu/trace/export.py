@@ -82,8 +82,13 @@ def chrome_events(spans, t0=None, pid=CHROME_PID,
     return events
 
 
-def write_dump(path, spans, reason="manual", dropped=0, buffers=0):
-    """Materialize one dump directory at `path`; returns the path."""
+def write_dump(path, spans, reason="manual", dropped=0, buffers=0,
+               open_builds=()):
+    """Materialize one dump directory at `path`; returns the path.
+    open_builds: the compiled-step builds open as the dump is taken
+    (cache.builds.open_builds): a step span is recorded when its step
+    ends, so of a compile that hangs this is all a dump can show; the
+    phases that have arrived say which one it hangs in."""
     os.makedirs(path, exist_ok=True)
     with open(os.path.join(path, "spans.jsonl"), "w") as f:
         for s in spans:
@@ -106,6 +111,7 @@ def write_dump(path, spans, reason="manual", dropped=0, buffers=0):
         "buffers": int(buffers),
         "traces": len({s["trace"] for s in spans}),
         "names": names,
+        "open_builds": list(open_builds),
         "files": {"spans": "spans.jsonl", "chrome": "trace.json"},
     }
     with open(os.path.join(path, "manifest.json"), "w") as f:

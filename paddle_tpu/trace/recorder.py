@@ -138,6 +138,7 @@ def dump(reason="manual", out_dir=None):
     (out_dir defaults to FLAGS_trace_dump_dir, then cwd) and return the
     directory path. Format: export.write_dump (spans.jsonl + chrome
     trace.json + manifest.json)."""
+    from ..cache import builds
     from . import export
 
     reason = _REASON_RE.sub("_", str(reason)) or "manual"
@@ -149,7 +150,7 @@ def dump(reason="manual", out_dir=None):
         buffers = len(_rings)
     path = os.path.join(base, f"trace_{reason}_{seq}")
     export.write_dump(path, spans, reason=reason, dropped=dropped,
-                      buffers=buffers)
+                      buffers=buffers, open_builds=builds.open_builds())
     _last_dump[0] = path
     monitor.registry().counter(
         "trace_dumps_total",
