@@ -338,9 +338,10 @@ def _sparse_attention_grad_maker(op, gout, gin):
 
 @register_op("sparse_attention_grad")
 def sparse_attention_grad_op(ctx, ins, attrs):
-    """On a TPU place the dK/dV and dQ kernels of `parallel/flash.py`
-    under the mask, from the saved output and logsumexp; elsewhere the vjp
-    of the plain composition."""
+    """On a TPU place the masked backward of `parallel/flash.py` from the
+    saved output and logsumexp (ONE kernel for all three gradients where
+    `flash.fused_backward_fits`, else the dK/dV and the dQ kernel);
+    elsewhere the vjp of the plain composition."""
     q, k, v, o, do = _heads_first(ins, "Q", "K", "V", "Out", "Out@GRAD")
     scale = float(attrs.get("scale", 0.0)) or None
     mask = first(ins, "Mask")
@@ -1576,6 +1577,17 @@ def _index_loss_kernel_takes(op, block):
                             *q_i.shape[2:], low)
 
 
+def _sparse_grad_is_one_kernel(op, block):
+    """Whether this `sparse_attention_grad` runs as ONE masked flash kernel
+    (`flash.fused_backward_fits`, which the dispatch asks too), from the
+    shapes the program states."""
+    from ..parallel import flash
+
+    q, k, v = (block.vars[op.input(s)[0]] for s in ("Q", "K", "V"))
+    return flash.fused_backward_fits(q.shape[1], k.shape[1], q.shape[3],
+                                     v.shape[3])
+
+
 def _reads_a_tied_table(op, block):
     """A `lookup_table` whose W a `matmul` of the block reads transposed as
     its Y, or such a `matmul`: one parameter [V, C] that is the embedding
@@ -1717,7 +1729,9 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("indexer_select", "sparse_attention_causal_pairs", False,
              lambda op, block: _selection_pairs(op, block)[1]),
             ("indexer_loss", "indexer_loss_kernel", True,
-             _index_loss_kernel_takes))
+             _index_loss_kernel_takes),
+            ("sparse_attention_grad", "sparse_attention_grad_fused", True,
+             _sparse_grad_is_one_kernel))
 
 
 def lowered_counts(program, device):
@@ -1773,7 +1787,10 @@ def lowered_counts(program, device):
     its `sparse_attention` ops (`sparse_attention_plain`, each; on a TPU
     place the flash kernels of parallel/flash.py under the mask take every
     one and its grad: `sparse_attention_kernel`,
-    `sparse_attention_grad_kernel`) and its `indexer_loss` ops
+    `sparse_attention_grad_kernel`; a grad whose three float32
+    accumulators fit VMEM for the length of a row is ONE kernel that visits
+    each score block once, `sparse_attention_grad_fused`:
+    `flash.fused_backward_fits`) and its `indexer_loss` ops
     (`indexer_loss_with_grads`: the loss and its gradient in one pass; on
     a TPU place those whose shapes the Pallas kernels of
     parallel/index_loss.py take count as `indexer_loss_kernel` too, the

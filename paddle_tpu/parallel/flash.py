@@ -44,8 +44,7 @@ existed (tests/test_flash_attention.py holds the grids and index maps).
 A SELECTION (PR 49): with `mask` [B, S, S] int8 (query, key; nonzero = the
 query sees the key; the same for every head; nothing above the diagonal)
 the predicate on a pair is not a function of the two positions but a byte
-of the mask, which the three kernels take as one more operand (the dK/dV
-kernel transposed, formed once by the caller's side here): the softmax is
+of the mask, which the kernels take as one more operand: the softmax is
 over the chosen keys ALONE (`sparse_attention`, the attention behind a
 learned indexer). Blocks above the diagonal are skipped by block index as
 ever; every other block is visited and takes the mask's block, also one
@@ -55,6 +54,27 @@ needs no channel of its own there: padded keys and padded query rows are
 zero bytes of the mask, and a query row with no chosen key gets output 0,
 logsumexp -inf and weight 0 everywhere in the backward. Under their own
 names (`SPARSE_KERNELS`). A caller that passes no mask lowers as before.
+
+The masked BACKWARD is ONE kernel (PR 53, `SPARSE_BWD_KERNEL`) wherever the
+three float32 accumulators fit VMEM for the length of a row
+(`fused_backward_fits`: dQ [Sq, D] of a query head, dK and dV [Sk, .] of
+its key/value head, together at most a quarter of the kernels' VMEM limit;
+12 of 16 MiB at one row of 8192 and heads of 128): grid (key/value head,
+head of its group, key block, query block), each score block visited ONCE,
+s, exp, dO V^T, the mask's block and ds formed once a visit for all three
+gradients, five products a pair where the two kernels spend seven (dK/dV
+four, dQ three, each forming the block again). The visit is the dK/dV
+kernel's, scores [bk, bq], plus dQ[i] += ds^T k with the first axis of
+both operands contracted; the mask's block is read as it lies and transposed in
+VMEM, so no transposed copy of the mask is written. On the v5e at [1, 32 on
+4, 8192, 128] bf16 under a top-2048 mask: 8.82 ms a layer, 7.30 ps a
+visited pair, 89% of the MXU's peak on its five products, where the two
+kernels with the mask's transposed copy took 14.80 (12.25 ps, 74% on
+seven); the same bits out (PERF.md, PR 53). A longer row (16k at heads of
+128) keeps the two kernels, dK/dV reading a transposed copy of the mask
+formed once on the caller's side here. The plain causal backward is the two
+kernels as they were: its callers do not share a block size, a band or a
+memory margin (ROADMAP Speed 5(a)).
 
 On a TPU place Mosaic compiles the kernels; on any other place (CPU tests)
 they run in Pallas interpret mode (core.places.pallas_interpret).
@@ -78,6 +98,9 @@ __all__ = ["flash_attention", "flash_attention_fwd", "flash_attention_bwd",
 KERNELS = ("flash_fwd", "flash_dkv", "flash_dq")
 # and with a mask (`.../sparse_attention/sparse_flash_fwd/pallas_call`)
 SPARSE_KERNELS = tuple("sparse_" + name for name in KERNELS)
+# and the masked backward as ONE kernel, where its accumulators fit
+# (`fused_backward_fits`): `.../sparse_attention_grad/sparse_flash_bwd/...`
+SPARSE_BWD_KERNEL = "sparse_flash_bwd"
 
 
 # The running max and normaliser of the forward live in VMEM as [block_q,
@@ -95,10 +118,11 @@ def _across(x, n):
         x[:, :1], (x.shape[0], n))
 
 
-def _chosen(ref):
+def _chosen(ref, transposed=False):
     """A mask's block as booleans (int8 has no compare on every chip:
-    widened first)."""
-    return ref[0].astype(jnp.int32) != 0
+    widened first), `transposed` for scores held the other way round."""
+    wide = ref[0].astype(jnp.int32)
+    return (wide.T if transposed else wide) != 0
 
 
 def _kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k, nk,
@@ -494,9 +518,10 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
 # of the v5e's 128 MiB of VMEM.
 _BWD_VMEM_LIMIT = 64 * 2 ** 20
 
-# dot_general dimension numbers on 2-d blocks: a @ b.T and a @ b
+# dot_general dimension numbers on 2-d blocks: a @ b.T, a @ b and a.T @ b
 _NT = (((1,), (1,)), ((), ()))
 _NN = (((1,), (0,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
 
 
 def _dot(a, b, dims):
@@ -505,17 +530,18 @@ def _dot(a, b, dims):
 
 
 def _weights(s, lse, masked, q0, k0, q_axis, causal, kv_len, window=None,
-             chosen=()):
+             chosen=(), chosen_transposed=False):
     """p = exp(s - lse) on one float32 score block; where `masked`, zero
     for the pairs above the diagonal, for those `window` or more positions
     back, and for padded keys, or with `chosen` (a mask's block, laid out
-    as the scores are) for the pairs it does not name. Queries run along
-    `q_axis` of the block and keys along the other."""
+    as the scores are, or with `chosen_transposed` the other way round)
+    for the pairs it does not name. Queries run along `q_axis` of the
+    block and keys along the other."""
     p = jnp.exp(s - lse)
     if not masked:
         return p
     if chosen:
-        return jnp.where(_chosen(chosen[0]), p, 0.0)
+        return jnp.where(_chosen(chosen[0], chosen_transposed), p, 0.0)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     keep = None if kv_len is None else k_pos < kv_len
     if causal:
@@ -752,9 +778,134 @@ def _flash_bwd(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
     return dq, dk, dv
 
 
+# The share of `_BWD_VMEM_LIMIT` that the one-kernel masked backward's three
+# float32 accumulators may take, whole rows of a head each: dQ [Sq, D], dK
+# [Sk, D], dV [Sk, Dv]. 12 MiB of 16 at one row of 8192 and heads of 128;
+# the rest holds the operands' double buffers (the mask's int8 block 2 x 1
+# MiB among them), the three outputs' and a visit's four float32 tiles.
+_FUSED_ACCUMULATORS_SHARE = 0.25
+
+
+def fused_backward_fits(Sq, Sk, D, Dv):
+    """Whether a masked backward over rows of Sq queries and Sk keys, as
+    the caller states them (the padding to whole blocks, less than a block
+    a row, is the share's slack), is ONE kernel (`_fused_bwd`): its
+    accumulators fit their share of the VMEM limit. From shapes alone; the
+    dispatch (`_flash_vjp_bwd`) and the program's counter
+    (`lm_ops.lowered_counts`) both ask here."""
+    return (Sq * D + Sk * (D + Dv)) * 4 \
+        <= _FUSED_ACCUMULATORS_SHARE * _BWD_VMEM_LIMIT
+
+
+def _fused_bwd_kernel(q_ref, k_ref, v_ref, do_ref, ld_ref, mask_ref, dq_ref,
+                      dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, scale,
+                      block_q, block_k, nq, nk, group):
+    """Every score block of one key/value head's `group` query heads, each
+    visited ONCE for all three gradients: grid (key/value head, head of the
+    group, key block, query block). The visit is the dK/dV kernel's
+    (scores transposed, [bk, bq]; `lse` and `delta` lane-major rows) and
+    one product more, dQ[i] += dst^T k, which contracts the first axis of
+    both operands; the mask's block arrives as it lies, (query, key), and
+    is transposed here, widened (no transposed copy of the mask in HBM).
+    dQ [Sq, D] of the query head and dK, dV [Sk, .] of the key/value head
+    stay in VMEM, float32: a query block's sum runs over the key blocks in
+    ascending order and a key block's over (head, query block), as in the
+    two kernels."""
+    g, ki, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    first = (ki == 0) & (qi == 0)
+    last = (ki == nk - 1) & (qi == nq - 1)
+
+    @pl.when(first)
+    def _init_dq():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(first & (g == 0))
+    def _init_dkv():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def _accumulate(masked):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        rows_q = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        rows_k = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        st = _dot(k, q, _NT) * scale                       # [bk, bq]
+        pt = _weights(st, ld_ref[0, 0:1, :], masked, qi * block_q,
+                      ki * block_k, 1, True, None, chosen=[mask_ref],
+                      chosen_transposed=True)
+        dv_scr[rows_k, :] += _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v, do, _NT)
+        dst = (pt * (dpt - ld_ref[0, 1:2, :])).astype(q.dtype)
+        dk_scr[rows_k, :] += _dot(dst, q, _NN)
+        dq_scr[rows_q, :] += _dot(dst, k, _TN)
+
+    _for_chosen(_accumulate, qi, ki, block_q, block_k)
+
+    @pl.when(last)
+    def _finish_dq():
+        # ds = p (dp - delta) scale: the scale once, on the float32 sums
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last & (g == group - 1))
+    def _finish_dkv():
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _fused_bwd(q, k, v, do, lse, delta, scale, block_q, block_k, mask):
+    """`_flash_bwd`'s operands under a mask [B, Sq, Sk] -> dq, dk, dv
+    through ONE kernel (`SPARSE_BWD_KERNEL`), for the shapes
+    `fused_backward_fits`. Steps above the diagonal are skipped by block
+    index and name the block of the nearest visited step, as in the two
+    kernels; each output block is a head's whole row, so it leaves VMEM
+    once, as the head's (the group's) last step ends."""
+    BH, Sq, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[2]
+    nq, nk = Sq // block_q, Sk // block_k
+    group = BH // k.shape[0]
+    heads = k.shape[0] // mask.shape[0]
+    q_of, _ = _band(block_q, block_k, nq, nk, True, None)
+
+    def q_at(b, g, j, i):
+        return b * group + g, q_of(j, i)
+
+    return pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, nq=nq, nk=nk, group=group),
+        grid=(k.shape[0], group, nk, nq),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda *at: (*q_at(*at), 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, g, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, g, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda *at: (*q_at(*at), 0)),
+            pl.BlockSpec((1, 2, block_q),
+                         lambda *at: (q_at(*at)[0], 0, q_at(*at)[1])),
+            pl.BlockSpec((1, block_q, block_k),
+                         lambda b, g, j, i: (b // heads, q_of(j, i), j)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, Sq, D), lambda b, g, j, i: (b * group + g, 0, 0)),
+            pl.BlockSpec((1, Sk, D), lambda b, g, j, i: (b, 0, 0)),
+            pl.BlockSpec((1, Sk, Dv), lambda b, g, j, i: (b, 0, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((Sq, D), jnp.float32),
+                        pltpu.VMEM((Sk, D), jnp.float32),
+                        pltpu.VMEM((Sk, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) + ("arbitrary",) * 3,
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=pallas_interpret(),
+        name=SPARSE_BWD_KERNEL,
+    )(q, k, v, do, jnp.stack([lse, delta], axis=1), mask)
+
+
 def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, do,
                    mask=None):
-    """Pad as `_fwd_padded` does and run the two backward kernels. A
+    """Pad as `_fwd_padded` does and run the backward kernels: dK/dV and
+    dQ, or under a mask whose accumulators fit (`fused_backward_fits`) the
+    one kernel for all three. A
     padded key gets no weight (the kernels mask keys past Sk, or read the
     padded mask's zero bytes); a padded query row carries dO = 0 and delta
     = 0, so with any finite lse it adds nothing to dK or dV, and its dQ
@@ -764,12 +915,17 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, do,
     pad_q = (-Sq) % block_q
     pad_k = (-Sk) % block_k
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
-    dq, dk, dv = _flash_bwd(
-        _folded(q, pad_q), _folded(k, pad_k), _folded(v, pad_k),
-        _folded(do.astype(q.dtype), pad_q), _folded(lse, pad_q),
-        _folded(delta, pad_q), scale, causal, block_q, block_k,
-        Sk if pad_k and mask is None else None, window,
-        _padded_mask(mask, pad_q, pad_k))
+    operands = (_folded(q, pad_q), _folded(k, pad_k), _folded(v, pad_k),
+                _folded(do.astype(q.dtype), pad_q), _folded(lse, pad_q),
+                _folded(delta, pad_q))
+    mask = _padded_mask(mask, pad_q, pad_k)
+    if mask is not None and fused_backward_fits(Sq, Sk, q.shape[3],
+                                                v.shape[3]):
+        dq, dk, dv = _fused_bwd(*operands, scale, block_q, block_k, mask)
+    else:
+        dq, dk, dv = _flash_bwd(
+            *operands, scale, causal, block_q, block_k,
+            Sk if pad_k and mask is None else None, window, mask)
     return (dq[:, :Sq].reshape(q.shape), dk[:, :Sk].reshape(k.shape),
             dv[:, :Sk].reshape(v.shape))
 

@@ -569,14 +569,43 @@ def test_indexer_loss_and_its_gradient(n, topk, blocks_of_16):
         _close(g, 3.0 * wg, tol=1e-4, floor=1e-9)
 
 
-@pytest.mark.parametrize("which", ["forward", "backward"])
-@pytest.mark.parametrize("n,block,dtype", [
-    (64, 32, "float32"), (40, 16, "float32"), (24, 1024, "float32"),
-    (64, 32, "bfloat16")], ids=["two_blocks", "padded", "one_block", "bf16"])
-def test_masked_flash_kernels_interpreted(n, block, dtype, which):
-    """The three kernels of `parallel/flash.py` under a mask (interpreted)
+def _pallas_call_names(fn, *args):
+    """The names of the pallas_call equations of `fn`'s jaxpr, in order."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    # a function of its own a call: `make_jaxpr` keeps a function's trace
+    walk(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr)
+    return names
+
+
+@pytest.mark.parametrize("which", ["forward", "backward", "two_kernels",
+                                   "one_against_two"])
+@pytest.mark.parametrize("n,block,dtype,holes", [
+    (64, 32, "float32", False), (40, 16, "float32", False),
+    (24, 1024, "float32", False), (64, 32, "bfloat16", False),
+    (64, 32, "float32", True)],
+    ids=["two_blocks", "padded", "one_block", "bf16", "empty_rows"])
+def test_masked_flash_kernels_interpreted(n, block, dtype, holes, which,
+                                          monkeypatch):
+    """The kernels of `parallel/flash.py` under a mask (interpreted)
     against the plain composition: 8 query heads on 2 key/value heads
-    under a selection, rows that are and are not whole blocks."""
+    under a selection, rows that are and are not whole blocks. The
+    backward as the ONE kernel a call of these sizes gets (`backward`), as
+    the dK/dV and the dQ kernel a call past `fused_backward_fits` gets
+    (`two_kernels`: the share patched to nothing), and the one against the
+    two on the same draw. `empty_rows`: in the first row of tokens the
+    queries of the first block (and the first of the second) see no key at
+    all (output 0, logsumexp -inf, dQ 0, nothing added to dK or dV, no
+    NaN) and none of the second block sees a key of the first key block, a
+    visited block; the oracle gives the empty queries their own key and a
+    zero cotangent."""
     import ml_dtypes
 
     from paddle_tpu.ops.lm_ops import _plain_sparse_attention
@@ -590,27 +619,69 @@ def test_masked_flash_kernels_interpreted(n, block, dtype, which):
     mask = jnp.stack([sparse_index.select(q_i[b], k_i[b, :, 0], w[b], 12)[0]
                       for b in range(2)])
     do = jnp.asarray(rng.normal(size=q.shape)).astype(np_dtype)
+    want_mask, want_do = mask, do
+    empty = np.zeros(mask.shape[:2], bool)
+    if holes:
+        mask = mask.at[0, :2 * block, :block].set(0)
+        empty = ~np.asarray(mask).any(axis=2)        # [B, S]: no key at all
+        assert empty[0, :block].all() and not empty[0, block:].all()
+        assert not empty[1].any()
+        want_mask = mask | (jnp.eye(n, dtype=mask.dtype) * empty[:, :, None])
+        want_do = do * ~empty[:, None, :, None]
     f32 = [t.astype(jnp.float32) for t in (q, k, v)]
-    want_o, want_lse = _plain_sparse_attention(*f32, mask)
+    want_o, want_lse = _plain_sparse_attention(*f32, want_mask)
     o, lse = flash.flash_attention_fwd(q, k, v, causal=True, mask=mask,
                                        block_q=block, block_k=block)
     tol = dict(atol=1e-4, rtol=1e-3) if dtype == "float32" \
         else dict(atol=0.1, rtol=0.05)
     if which == "forward":
         assert o.dtype == q.dtype and lse.dtype == jnp.float32
+        if holes:
+            rows = np.broadcast_to(empty[:, None], lse.shape)
+            assert not np.asarray(o)[rows].any()
+            assert np.isneginf(np.asarray(lse)[rows]).all()
+            o, lse, want_o, want_lse = (np.asarray(t)[~rows] for t in (
+                o, lse, want_o, want_lse))
         np.testing.assert_allclose(np.asarray(o, np.float32),
                                    np.asarray(want_o), **tol)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
                                    **tol)
         return
-    got = flash.flash_attention_bwd(q, k, v, o, lse, do, causal=True,
-                                    mask=mask, block_q=block, block_k=block)
-    _, vjp = jax.vjp(lambda *a: _plain_sparse_attention(*a, mask)[0], *f32)
-    want = vjp(do.astype(jnp.float32))
+
+    def backward(*operands):
+        return flash.flash_attention_bwd(*operands, causal=True, mask=mask,
+                                         block_q=block, block_k=block)
+
+    operands = (q, k, v, o, lse, do)
+    if which != "backward":
+        one = backward(*operands)
+        assert _pallas_call_names(backward, *operands) \
+            == [flash.SPARSE_BWD_KERNEL]
+        monkeypatch.setattr(flash, "_FUSED_ACCUMULATORS_SHARE", 0.0)
+        assert _pallas_call_names(backward, *operands) \
+            == list(flash.SPARSE_KERNELS[1:])
+    got = backward(*operands)
+    if which == "one_against_two":
+        # the same products on the same operands; dQ's alone is given to
+        # the MXU transposed
+        same = dict(atol=1e-6, rtol=1e-6) if dtype == "float32" \
+            else dict(atol=0.02, rtol=0.02)
+        for a, b in zip(one, got):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32), **same)
+        return
+    _, vjp = jax.vjp(
+        lambda *a: _plain_sparse_attention(*a, want_mask)[0], *f32)
+    want = vjp(want_do.astype(jnp.float32))
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == q.dtype
+        assert not np.isnan(np.asarray(a, np.float32)).any()
         np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b),
                                    **tol)
+    if holes:
+        assert not np.asarray(got[0], np.float32)[
+            np.broadcast_to(empty[:, None], lse.shape)].any()
 
 
 INDEX_LOSS_CASES = {
@@ -792,6 +863,9 @@ def test_lowered_counts_name_the_new_lowerings(small):
     assert "sparse_attention_kernel" not in cpu
     assert tpu["sparse_attention_kernel"] == 4 \
         == tpu["sparse_attention_grad_kernel"]
+    # 48 tokens: the backward's accumulators fit, ONE kernel a grad op
+    assert "sparse_attention_grad_fused" not in cpu
+    assert tpu["sparse_attention_grad_fused"] == 4
     assert "flash_attention" not in tpu      # no causal flash kernel left
     # 48 tokens are no whole block of the loss's kernels: the plain scan
     assert "indexer_loss_kernel" not in cpu
@@ -840,6 +914,40 @@ def test_the_loss_s_kernels_are_counted_at_the_cell_s_shapes(policy):
         == tpu["indexer_loss_with_grads"]
     assert "indexer_loss_kernel" not in cpu
     assert tpu.get("indexer_loss_kernel") == (4 if policy else None)
+
+
+@pytest.mark.parametrize("S,fused", [(8192, 4), (16384, None)])
+def test_the_one_kernel_backward_is_counted_where_it_fits(S, fused):
+    """Four `sparse_attention_grad` ops at the `keye_vl_2_0_30b_a3b` cell's
+    heads (32 on 4 of 128): at the cell's row of 8192 dQ, dK and dV of a
+    head are 12 MiB of float32, a quarter of the kernels' VMEM limit holds
+    them and each grad op is ONE kernel (`sparse_attention_grad_fused` 4 of
+    `sparse_attention_grad_kernel` 4 on a TPU place); at a row of 16384
+    they are 24 MiB and the dK/dV and dQ kernels stay. Never on the CPU.
+    The counter asks `flash.fused_backward_fits`, as the dispatch does."""
+    from paddle_tpu.ops.lm_ops import lowered_counts
+    from paddle_tpu.parallel import flash
+
+    class Cpu:
+        platform = "cpu"
+
+    class Tpu:
+        platform = "tpu"
+
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup):
+        L = fluid.layers
+        q = L.data(name="q", shape=[S, 32, 128], dtype="float32")
+        kv = L.data(name="kv", shape=[S, 4, 128], dtype="float32")
+        mask = L.data(name="m", shape=[S, S], dtype="int8")
+        q.stop_gradient = False
+        outs = [L.sparse_attention(q, kv, kv, mask)[0] for _ in range(4)]
+        fluid.backward.calc_gradient(L.mean(L.sums(outs)), [q])
+    cpu, tpu = lowered_counts(prog, Cpu), lowered_counts(prog, Tpu)
+    assert tpu["sparse_attention_grad_kernel"] == 4
+    assert tpu.get("sparse_attention_grad_fused") == fused
+    assert "sparse_attention_grad_fused" not in cpu
+    assert flash.fused_backward_fits(S, S, 128, 128) == bool(fused)
 
 
 def test_op_costs_weigh_the_new_ops(small):

@@ -14,19 +14,27 @@ from test_tpu_compile import (no_compile_cache, one_chip,  # noqa: E402,F401
                               topo)
 
 
-@pytest.mark.parametrize("kind", ["forward", "backward"])
+@pytest.mark.parametrize("kind", ["forward", "backward",
+                                  "backward_past_the_fit"])
 def test_masked_flash_kernels_compile_for_v5e(one_chip, no_compile_cache,
                                               monkeypatch, kind):
-    """Mosaic takes the three kernels of `parallel/flash.py` under a mask,
-    at the `keye_vl_2_0_30b_a3b` cell's shape, 32 query heads on 4 key/value
-    heads of 128 over a row of 8192 with an int8 mask [8192, 8192]: one custom
-    call forward, two backward, and no [S, S] float32 temporary."""
+    """Mosaic takes the kernels of `parallel/flash.py` under a mask, at the
+    `keye_vl_2_0_30b_a3b` cell's shape, 32 query heads on 4 key/value heads
+    of 128 over a row of 8192 with an int8 mask [8192, 8192]: one custom
+    call forward, ONE backward (dQ, dK and dV accumulated in VMEM for the
+    length of the row: `flash.fused_backward_fits`), and no [S, S]
+    temporary of any type (the mask is read as it lies). A row of 16384,
+    whose accumulators do not fit their share, gets the dK/dV and the dQ
+    kernel with the mask's transposed copy between them."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.parallel import flash
 
     monkeypatch.setattr(flash, "pallas_interpret", lambda: False)
     S, H, Hkv, D = 8192, 32, 4, 128
+    if kind == "backward_past_the_fit":
+        S = 16384
+    assert flash.fused_backward_fits(S, S, D, D) == (S == 8192)
 
     def sds(shape, dt="bfloat16"):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
@@ -44,10 +52,17 @@ def test_masked_flash_kernels_compile_for_v5e(one_chip, no_compile_cache,
             lambda q, k, v, m, o, lse, do: flash.flash_attention_bwd(
                 q, k, v, o, lse, do, causal=True, mask=m)
         ).lower(q, kv, kv, mask, q, lse, q).compile()
-        calls = ["sparse_flash_dkv", "sparse_flash_dq"]
+        calls = ["sparse_flash_bwd"] if kind == "backward" \
+            else ["sparse_flash_dkv", "sparse_flash_dq"]
     assert base._custom_calls(compiled.as_text()) == calls
-    # the backward's transposed mask (67 MB), d O in q's dtype, lse | delta
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e8
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if kind == "backward_past_the_fit":
+        # the transposed mask (268 MB) and lse | delta as columns, 128
+        # lanes a row (268 MB)
+        assert S * S <= temp < 6.0e8, temp
+    else:
+        # d O in q's dtype, delta, lse | delta as rows: 2 MB
+        assert temp < 1.0e7, temp
 
 
 def test_index_loss_kernels_compile_for_v5e(one_chip, no_compile_cache,
@@ -90,8 +105,8 @@ def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
         one_chip, no_compile_cache, monkeypatch):
     """The `keye_vl_2_0_30b_a3b` step at 1 x 8192 tokens (four layers of
     attention behind an indexer, each with 16 held experts of 768) compiles
-    for one v5e chip with the masked flash kernels a layer (a forward,
-    dK/dV and dQ: no causal flash kernel is left), the grouped kernels
+    for one v5e chip with the masked flash kernels a layer (a forward and
+    ONE backward: no causal flash kernel is left), the grouped kernels
     over the 16 held groups at K 2048 / F 768, the embedding's gradient by
     the row-tile kernel, no sort of an [S, S] operand (the selection is by
     bisection), no float32 [32, S, S] scores, the indexer's loss in its
@@ -104,8 +119,7 @@ def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
     calls = base._custom_calls(text)
     layers = cfg["num_hidden_layers"]
     assert [c for c in calls if "flash" in c] == \
-        ["sparse_flash_dkv"] * layers + ["sparse_flash_dq"] * layers \
-        + ["sparse_flash_fwd"] * layers
+        ["sparse_flash_bwd"] * layers + ["sparse_flash_fwd"] * layers
     # the indexer's loss: its two kernels a layer, no scan over blocks of
     # 256 queries with their float32 [8, 256, S] and [256, 16, S] blocks
     assert [c for c in calls if c.startswith("index_")] == \
