@@ -59,6 +59,10 @@ WHITE_LIST = frozenset({
     # out, g, beta, the decays and the carried state float32 inside
     # (parallel/delta_rule.py)
     "gated_delta_rule",
+    # the selective state-space scan behind such a convolution: bf16 x, B,
+    # C, dt in and y out, the steps, every decay and the carried state
+    # float32 inside (parallel/ssd.py)
+    "ssd_scan",
     # attention over an indexer's selection: the masked flash kernels take
     # bf16 operands as `causal_attention`'s do (`indexer_select` and
     # `indexer_loss` stay neutral: they take what arrives and score in
@@ -82,11 +86,13 @@ FLOAT32_SLOTS = {
     "mhc_mix": frozenset({"PhiPre", "PhiPost", "PhiRes", "Alpha", "BPre",
                           "BPost", "BRes", "HPost@GRAD", "HRes@GRAD"}),
     "mhc_update": frozenset({"HRes", "HPost"}),
-    # the taps: a [L, C] float32 master read as it is
-    "short_conv": frozenset({"Filter"}),
+    # the taps [L, C] and the "silu" variant's bias [C]: float32 masters
+    # read as they are
+    "short_conv": frozenset({"Filter", "Bias"}),
     # A_log, dt_bias: [Hv] float32 masters read as they are; the saved
     # chunk-start states stay float32
     "gated_delta_rule": frozenset({"ALog", "DtBias", "States"}),
+    "ssd_scan": frozenset({"ALog", "DtBias", "D", "States"}),
     # the saved logsumexp: the backward's weights are exp(s - lse)
     "sparse_attention": frozenset({"Lse"}),
 }

@@ -896,19 +896,25 @@ def _short_conv(ctx):
     # gating "silu": X [T, C] whole; else the thirds B, C, z side by side
     parts = 1 if ctx.attr("gating") else 3
     ctx.enforce(len(x) == 2 and (x[1] < 0 or x[1] % parts == 0),
-                f"X must be [T, 3C] (the thirds B, C, z), got {x}")
+                "X must be " + ("[T, C]" if parts == 1 else
+                                "[T, 3C] (the thirds B, C, z)")
+                + f", got {x}")
     ctx.enforce(seq_len > 0 and (x[0] < 0 or x[0] % seq_len == 0),
                 f"seq_len {seq_len} must divide X's {x[0]} tokens")
     if f is not None:
         ctx.enforce(len(f) == 2 and f[0] >= 1
                     and (x[1] < 0 or _dim_match(f[1], x[1] // parts)),
                     f"Filter{f} must be [L, C] of X{x}")
+    b = ctx.input_dim("Bias")
+    if b is not None:
+        ctx.enforce(parts == 1 and (x[1] < 0 or tuple(b) == (x[1],)),
+                    f"Bias{b} must be [C] of X{x}, under gating 'silu'")
     ctx.set_output_dim("Out", (x[0], x[1] // parts if x[1] > 0 else -1))
 
 
 @register_infer_shape("short_conv_grad")
 def _short_conv_grad(ctx):
-    for slot in ("X", "Filter"):
+    for slot in ("X", "Filter", "Bias"):
         d = ctx.input_dim(slot)
         if d is not None:
             ctx.set_output_dim(slot + "@GRAD", d)
@@ -955,6 +961,47 @@ def _gated_delta_rule_grad(ctx):
             ctx.set_output_dim(slot + "@GRAD", d)
 
 
+@register_infer_shape("ssd_scan")
+def _ssd_scan(ctx):
+    x = ctx.input_dim("X")
+    if x is None:
+        return
+    heads, p, groups, n, chunk, seq_len = (int(ctx.attr(k) or 0) for k in (
+        "num_heads", "head_dim", "num_groups", "state_size", "chunk",
+        "seq_len"))
+    ctx.enforce(min(heads, p, groups, n, chunk) > 0 and heads % groups == 0,
+                f"{heads} heads must be a multiple of {groups} groups")
+    ctx.enforce(len(x) == 2 and (x[1] < 0 or x[1] == heads * p),
+                f"X must be [T, {heads * p}] = {heads} heads of {p}, got {x}")
+    ctx.enforce(seq_len > 0 and (x[0] < 0 or x[0] % seq_len == 0),
+                f"seq_len {seq_len} must divide X's {x[0]} tokens")
+    for slot, width in (("B", groups * n), ("C", groups * n), ("Dt", heads)):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.enforce(len(d) == 2 and (d[1] < 0 or d[1] == width)
+                        and _dim_match(d[0], x[0]),
+                        f"{slot}{d} must be [T, {width}] of X{x}")
+    for slot in ("ALog", "DtBias", "D"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.enforce(tuple(d) == (heads,), f"{slot}{d} must be [H={heads}]")
+    from ..parallel.ssd import states_shape
+
+    rows = x[0] // seq_len if x[0] > 0 else -1
+    ctx.set_output_dim("Out", x)
+    states = states_shape(rows, seq_len, heads, p, groups, n, chunk)
+    ctx.set_output_dim("States", states)
+    ctx.set_output_dim("FinalState", (rows, heads, p, n))
+
+
+@register_infer_shape("ssd_scan_grad")
+def _ssd_scan_grad(ctx):
+    for slot in ("X", "B", "C", "Dt", "ALog", "DtBias", "D"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "@GRAD", d)
+
+
 @register_infer_shape("moe_ffn")
 def _moe_ffn(ctx):
     x, r = ctx.input_dim("X"), ctx.input_dim("Router")
@@ -974,12 +1021,18 @@ def _moe_ffn(ctx):
     b = ctx.input_dim("Bias")
     if b is not None:
         ctx.enforce(tuple(b) == (r[1],), f"Bias{b} must be [E={r[1]}]")
+    ungated = ctx.attr("activation") == "relu2"
+    ctx.enforce(not (ungated and g is not None),
+                "activation 'relu2' is an un-gated expert: no Gate")
+    if ungated:
+        g = u
     if g is not None and u is not None and d is not None:
         ctx.enforce(len(g) == 3 and g[0] == held and _dim_match(g[1], x[1]),
-                    f"Gate{g} must be [E'={held}, H={x[1]}, F]")
+                    f"{'Up' if ungated else 'Gate'}{g} must be "
+                    f"[E'={held}, H={x[1]}, F]")
         ctx.enforce(tuple(u) == tuple(g), f"Up{u} must match Gate{g}")
         ctx.enforce(tuple(d) == (g[0], g[2], g[1]),
-                    f"Down{d} must be [E, F, H] of Gate{g}")
+                    f"Down{d} must be [E, F, H] of Up{u}")
     ctx.set_output_dim("Out", x)
     ctx.set_output_dim("AuxLoss", (1,))
     ctx.set_output_dim("ZLoss", (1,))
@@ -993,7 +1046,8 @@ def _moe_ffn(ctx):
         # where every expert is held), down over all
         rows = x[0] * k if x[0] >= 0 else -1
         bounded = row_bound(rows, held, r[1]) if rows >= 0 else -1
-        ctx.set_output_dim("GateOut", (bounded, g[2]))
+        if not ungated:
+            ctx.set_output_dim("GateOut", (bounded, g[2]))
         ctx.set_output_dim("UpOut", (bounded, g[2]))
         ctx.set_output_dim("DownOut", (rows, x[1]))
 

@@ -19,7 +19,8 @@ __all__ = [
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
-    "short_conv", "gated_delta_rule", "detached", "indexer_select",
+    "short_conv", "gated_delta_rule", "ssd_scan", "detached",
+    "indexer_select",
     "sparse_attention", "indexer_loss", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_first_step", "sequence_last_step", "dropout",
@@ -635,17 +636,27 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1, epsilon=1e-05,
     return helper.append_activation(layer_norm_out)
 
 
-def rms_norm(input, epsilon=1e-05, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-05, param_attr=None, name=None,
+             group_size=None):
     """Root-mean-square norm over the last axis with a learned scale and no
-    bias (Zhang & Sennrich, arXiv:1910.07467), statistics in float32."""
+    bias (Zhang & Sennrich, arXiv:1910.07467), statistics in float32.
+    `group_size` g: the statistics over each run of g numbers of the last
+    axis, the scale still one number a channel (None: the whole axis, and
+    the op is appended as it has always been)."""
     helper = LayerHelper("rms_norm", **locals())
     dtype = helper.input_dtype()
     scale_p = helper.create_parameter(
         attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
         default_initializer=Constant(1.0))
     y = helper.create_tmp_variable(dtype, shape=input.shape)
+    attrs = {"epsilon": epsilon}
+    if group_size:
+        if int(input.shape[-1]) % int(group_size):
+            raise ValueError(f"rms_norm: group_size {group_size} does not "
+                             f"divide the last axis {input.shape[-1]}")
+        attrs["group_size"] = int(group_size)
     helper.append_op("rms_norm", {"X": [input], "Scale": [scale_p]},
-                     {"Y": [y]}, {"epsilon": epsilon})
+                     {"Y": [y]}, attrs)
     return y
 
 
@@ -772,7 +783,7 @@ def indexer_loss(q, k, lse, q_i, k_i, w, mask, scale=None, name=None):
 
 
 def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None,
-               gating=None):
+               gating=None, bias_attr=None):
     """The operator of a gated short-convolution layer (LFM2's): `input`
     [T, 3C], the input projection's output, its thirds B, C, z side by
     side, T = rows x `seq_len` tokens -> [T, C] = C * conv(B * z), conv a
@@ -781,17 +792,24 @@ def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None,
     sequences), no bias. The taps are one parameter [kernel_size, C],
     float32 under AMP; the gates and the taps' sum are float32 whatever
     the input's dtype, rounded once on the output. `gating` "silu": the
-    convolution of a Gated DeltaNet layer instead, `input` [T, C] -> [T,
-    C] = silu(conv(input)), the taps [kernel_size, C]."""
+    convolution of a Gated DeltaNet or Mamba-2 layer instead, `input` [T,
+    C] whole -> [T, C] = silu(conv(input)), the taps [kernel_size, C];
+    that variant alone takes `bias_attr`: a bias [C] a channel (zero at the
+    start, float32 under AMP) added to the taps' sum before the SiLU (None:
+    no bias, and the op is appended as it has always been)."""
     helper = LayerHelper("short_conv", **locals())
     dtype = helper.input_dtype()
     width = int(input.shape[-1])
     if gating not in (None, "silu"):
         raise ValueError(f"short_conv: gating {gating!r} is not 'silu'")
+    if bias_attr is not None and not gating:
+        raise ValueError("short_conv: only the variant gating='silu' takes "
+                         "a bias")
     parts = 1 if gating else 3
     if width % parts:
         raise ValueError(f"short_conv: the input's last dimension {width} "
-                         "is not three thirds B, C, z")
+                         "is not three thirds B, C, z (gating None takes "
+                         "[T, 3C]; gating 'silu' takes [T, C] whole)")
     taps = helper.create_parameter(
         attr=helper.param_attr, shape=[int(kernel_size), width // parts],
         dtype=dtype)
@@ -800,9 +818,49 @@ def short_conv(input, seq_len, kernel_size=3, param_attr=None, name=None,
     attrs = {"seq_len": int(seq_len)}
     if gating:
         attrs["gating"] = gating
-    helper.append_op("short_conv", {"X": [input], "Filter": [taps]},
-                     {"Out": [y]}, attrs)
+    inputs = {"X": [input], "Filter": [taps]}
+    if bias_attr is not None:
+        inputs["Bias"] = [helper.create_parameter(
+            attr=ParamAttr.to_attr(bias_attr), shape=[width], dtype=dtype,
+            default_initializer=Constant(0.0))]
+    helper.append_op("short_conv", inputs, {"Out": [y]}, attrs)
     return y
+
+
+def ssd_scan(x, b, c, dt, seq_len, num_heads, head_dim, num_groups,
+             state_size, a_log_attr=None, dt_bias_attr=None, d_attr=None,
+             chunk=None, name=None):
+    """The selective state-space scan of a Mamba-2 mixer (arXiv:2405.21060):
+    `x` [T, H P], `b`, `c` [T, G N] (the convolution's output, split), `dt`
+    [T, H], T = rows x `seq_len` tokens -> (out [T, H P], the state behind
+    each row's last token [rows, H, P, N] float32). delta = softplus(dt +
+    dt_bias), A = -exp(A_log), and per head h_t = exp(delta_t A) h_{t-1} +
+    delta_t x_t B_t^T; y_t = h_t C_t + D x_t from h = 0 at a row's first
+    token, head h reading group h // (H / G); A_log, dt_bias, D three
+    parameters [H] (float32 under AMP; 0, 0 and 1 unless their attrs say),
+    in chunks of `chunk` tokens (the lowering's own `parallel.ssd.CHUNK`
+    where None) with a hand-written backward (ops/lm_ops.py: ssd_scan,
+    parallel/ssd.py)."""
+    from ..parallel import ssd
+
+    helper = LayerHelper("ssd_scan", **locals())
+    a_log, dt_bias, d = (
+        helper.create_parameter(attr=ParamAttr.to_attr(a),
+                                shape=[int(num_heads)], dtype="float32",
+                                default_initializer=Constant(v))
+        for a, v in ((a_log_attr, 0.0), (dt_bias_attr, 0.0), (d_attr, 1.0)))
+    y = helper.create_tmp_variable(x.dtype, shape=tuple(x.shape))
+    states = helper.create_tmp_variable("float32", stop_gradient=True)
+    last = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op(
+        "ssd_scan",
+        {"X": [x], "B": [b], "C": [c], "Dt": [dt], "ALog": [a_log],
+         "DtBias": [dt_bias], "D": [d]},
+        {"Out": [y], "States": [states], "FinalState": [last]},
+        {"seq_len": int(seq_len), "num_heads": int(num_heads),
+         "head_dim": int(head_dim), "num_groups": int(num_groups),
+         "state_size": int(state_size), "chunk": int(chunk or ssd.CHUNK)})
+    return y, last
 
 
 def gated_delta_rule(qkv, ba, seq_len, num_k_heads, num_v_heads, head_k_dim,
@@ -849,7 +907,9 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
             score_func="softmax", norm_topk=False, routed_scale=1.0,
             bias_attr=None, held=None, router_input=None, activation="silu",
             norm_eps=None):
-    """A layer of `num_experts` gated experts of width `expert_size` on
+    """A layer of `num_experts` experts of width `expert_size` (gated,
+    act(x Gate_e) * (x Up_e), or under `activation` "relu2" un-gated,
+    relu(x Up_e)^2) on
     tokens [T, H], each token through its `top_k` by router score,
     grouped matmuls over the rows really routed. The defaults are OLMoE's
     (softmax scores, not renormalised, SwiGLU experts, the router reading
@@ -865,7 +925,10 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
     `RouterInput`, the router's gradient flows to it alone and the experts'
     to `input` alone; None (or `input` itself) appends the op as it has
     always been. `activation` "silu" | "relu": what gates an expert,
-    act(x Gate_e) * (x Up_e), in the grouped kernels' epilogues.
+    act(x Gate_e) * (x Up_e), in the grouped kernels' epilogues; "relu2":
+    an UN-GATED expert relu(x Up_e)^2 Down_e, two stacked matrices a layer:
+    no Gate parameter is made (`gate_attr` must be None) and the op has no
+    Gate input and no GateOut.
     `norm_eps`: what `norm_topk` adds to the sum it divides by (None: the
     op's 1e-20, and the op is appended as it has always been).
     Returns (out, load-balance loss [1], router z-loss [1], expert ids
@@ -875,21 +938,26 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
     dtype = helper.input_dtype()
     hidden = int(input.shape[-1])
     first, n_held = held or (0, num_experts)
-    router, gate, up, down = (
-        helper.create_parameter(attr=ParamAttr.to_attr(a), shape=shape,
-                                dtype=dtype)
-        for a, shape in ((router_attr, [hidden, num_experts]),
-                         (gate_attr, [n_held, hidden, expert_size]),
-                         (up_attr, [n_held, hidden, expert_size]),
-                         (down_attr, [n_held, expert_size, hidden])))
-    inputs = {"X": [input], "Router": [router], "Gate": [gate], "Up": [up],
-              "Down": [down]}
+    if activation not in ("silu", "relu", "relu2"):
+        raise ValueError(f"moe_ffn: activation {activation!r} is none of "
+                         "'silu', 'relu', 'relu2'")
+    gated = activation != "relu2"
+    if not gated and gate_attr is not None:
+        raise ValueError("moe_ffn: activation 'relu2' is an un-gated expert "
+                         "and takes no gate_attr")
+    shapes = {"Router": (router_attr, [hidden, num_experts]),
+              "Gate": (gate_attr, [n_held, hidden, expert_size]),
+              "Up": (up_attr, [n_held, hidden, expert_size]),
+              "Down": (down_attr, [n_held, expert_size, hidden])}
+    if not gated:
+        del shapes["Gate"]
+    inputs = {"X": [input]}
+    for slot, (a, shape) in shapes.items():
+        inputs[slot] = [helper.create_parameter(
+            attr=ParamAttr.to_attr(a), shape=shape, dtype=dtype)]
     attrs = {"top_k": int(top_k)}
     if router_input is not None and router_input is not input:
         inputs["RouterInput"] = [router_input]
-    if activation not in ("silu", "relu"):
-        raise ValueError(f"moe_ffn: activation {activation!r} is neither "
-                         "'silu' nor 'relu'")
     if activation != "silu":
         attrs["activation"] = activation
     if norm_eps is not None:
@@ -917,7 +985,8 @@ def moe_ffn(input, num_experts, expert_size, top_k, router_attr=None,
     # the three grouped products as computed, for the backward op alone
     outputs.update(
         {slot: [helper.create_tmp_variable(dtype, stop_gradient=True)]
-         for slot in ("GateOut", "UpOut", "DownOut")})
+         for slot in ("GateOut", "UpOut", "DownOut")
+         if gated or slot != "GateOut"})
     helper.append_op("moe_ffn", inputs, outputs, attrs)
     return (y, aux, z, ids, load) + ((rows,) if held else ())
 

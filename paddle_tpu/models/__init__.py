@@ -29,7 +29,12 @@ expert; as one chip's share): `qwen3_next.qwen3_next(tokens, cfg)`,
 (grouped-query attention over the keys a learned indexer picks for each
 query, the indexer trained beside the model by its own loss, softmax top-8
 experts; as one chip's share): `keye_vl.keye_vl(tokens, cfg)`,
-`keye_vl.keye_vl_loss`, `keye_vl.optimizer`.
+`keye_vl.keye_vl_loss`, `keye_vl.optimizer`; and `nemotron_h` (layers of
+ONE branch each by a pattern string: Mamba-2 mixers, a selective
+state-space scan behind a 4-tap convolution with a bias; grouped-query
+attention without positions; sigmoid top-6 un-gated relu^2 experts beside
+a shared one; as one chip's share): `nemotron_h.nemotron_h(tokens, cfg)`,
+`nemotron_h.nemotron_h_loss`, `nemotron_h.optimizer`.
 """
 
 from . import mnist
@@ -45,10 +50,11 @@ from . import smallthinker
 from . import lfm2
 from . import qwen3_next
 from . import keye_vl
+from . import nemotron_h
 
 __all__ = ["mnist", "resnet", "vgg", "se_resnext", "stacked_dynamic_lstm",
            "machine_translation", "olmoe", "xing4", "laguna", "smallthinker",
-           "lfm2", "qwen3_next", "keye_vl"]
+           "lfm2", "qwen3_next", "keye_vl", "nemotron_h"]
 
 
 def get_model(name):

@@ -33,11 +33,16 @@ F32 = jnp.float32
 @register_op("rms_norm")
 def rms_norm_op(ctx, ins, attrs):
     """Y = X / sqrt(mean(X^2, last axis) + eps) * Scale; statistics in
-    float32 whatever X's dtype (as layer_norm), Y in X's dtype."""
+    float32 whatever X's dtype (as layer_norm), Y in X's dtype. With the
+    attr `group_size` g the mean is over each run of g numbers of the last
+    axis (a grouped norm: Scale keeps the whole axis' length)."""
     x, scale = first(ins, "X"), first(ins, "Scale")
     xf = x.astype(F32)
+    group = int(attrs.get("group_size", 0))
+    if group:
+        xf = xf.reshape(*x.shape[:-1], x.shape[-1] // group, group)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    y = xf * lax.rsqrt(ms + attrs.get("epsilon", 1e-5))
+    y = (xf * lax.rsqrt(ms + attrs.get("epsilon", 1e-5))).reshape(x.shape)
     return out(Y=(y * scale.astype(F32)).astype(x.dtype))
 
 
@@ -516,30 +521,37 @@ def short_conv_grad(x, w, d_out, seq_len):
     return _whole((d_x.reshape(x.shape).astype(x.dtype), d_w))
 
 
-def silu_conv(x, w, seq_len):
-    """Out [T, C] = silu(causal_depthwise_conv_L(X)) of X [T, C] and the
-    taps w [L, C] (w[L - 1] weighs the token itself), `short_conv`'s
-    variant `gating="silu"`: the L shifted multiply-adds and the SiLU in
-    float32, one rounding to X's dtype."""
+def silu_conv(x, w, seq_len, bias=None):
+    """Out [T, C] = silu(causal_depthwise_conv_L(X) + bias) of X [T, C],
+    the taps w [L, C] (w[L - 1] weighs the token itself) and, where given,
+    a bias [C] a channel, `short_conv`'s variant `gating="silu"`: the L
+    shifted multiply-adds, the bias and the SiLU in float32, one rounding
+    to X's dtype."""
     L = w.shape[0]
     xr, wf = _rows(x, seq_len), w.astype(F32)
     with jax.named_scope(TAPS):
         conv = sum(wf[j] * win.astype(F32)
                    for j, win in enumerate(_shifted(xr, L, past=True)))
+        if bias is not None:
+            conv = conv + bias.astype(F32)
     with jax.named_scope(GATE):
         return _whole(jax.nn.silu(conv).reshape(x.shape).astype(x.dtype))
 
 
-def silu_conv_grad(x, w, d_out, seq_len):
+def silu_conv_grad(x, w, d_out, seq_len, bias=None):
     """(d X [T, C] in X's dtype, d Filter [L, C] float32) of `silu_conv`
     from X, the taps and d Out alone: the convolution is formed again, d
     conv = d Out * silu'(conv), d X the taps' transpose over the tokens
-    after, d Filter a float32 reduction over the tokens, a tap."""
+    after, d Filter a float32 reduction over the tokens, a tap. With a
+    `bias` [C], d Bias [C] float32 (d conv summed over the tokens) is the
+    third result."""
     L = w.shape[0]
     xr, wf = _rows(x, seq_len), w.astype(F32)
     back = [win.astype(F32) for win in _shifted(xr, L, past=True)]
     with jax.named_scope(GATE):
         conv = sum(wf[j] * back[j] for j in range(L))
+        if bias is not None:
+            conv = conv + bias.astype(F32)
         s = jax.nn.sigmoid(conv)
         d_conv = d_out.reshape(xr.shape).astype(F32) \
             * (s * (1.0 + conv * (1.0 - s)))
@@ -549,7 +561,8 @@ def silu_conv_grad(x, w, d_out, seq_len):
     with jax.named_scope(FILTER_GRAD):
         d_w = jnp.stack([jnp.sum(back[j] * d_conv, axis=(0, 1))
                          for j in range(L)])
-    return _whole((d_x.reshape(x.shape).astype(x.dtype), d_w))
+        d_b = () if bias is None else (jnp.sum(d_conv, axis=(0, 1)),)
+    return _whole((d_x.reshape(x.shape).astype(x.dtype), d_w, *d_b))
 
 
 @register_op("short_conv")
@@ -571,9 +584,15 @@ def short_conv_op(ctx, ins, attrs):
     Filter[j] x_{t - (L - 1 - j)}, the sum and the SiLU in float32, one
     rounding; on a TPU place the variant's Pallas kernels where they take
     the shapes (`parallel/short_conv.py: silu_conv_fwd`), L shifted
-    multiply-adds elsewhere (`silu_conv`)."""
+    multiply-adds elsewhere (`silu_conv`). That variant alone takes a Bias
+    [C] (a Mamba-2 mixer's convolution, float32 master read as it is):
+    Out = silu(c + Bias); with it the op is the L shifted multiply-adds on
+    every place (the kernels have no bias operand)."""
     x, w = first(ins, "X"), first(ins, "Filter")
     seq_len = int(attrs["seq_len"])
+    bias = first(ins, "Bias")
+    if bias is not None:
+        return out(Out=silu_conv(x, w, seq_len, bias))
     if attrs.get("gating") == SILU:
         if _silu_kernels_take(x, w, seq_len):
             from ..parallel.short_conv import silu_conv_fwd
@@ -609,21 +628,28 @@ def _silu_kernels_take(x, w, seq_len):
 def _short_conv_grad_maker(op, gout, gin):
     """Hand-written: the generic vjp of the shifted slices keeps a copy of
     v = B * z a tap for the backward; this one reads X, Filter and d Out."""
+    slots = [s for s in ("X", "Filter", "Bias") if op.input(s)]
     return [dict(
         type="short_conv_grad",
-        inputs={"X": op.input("X"), "Filter": op.input("Filter"),
+        inputs={**{s: op.input(s) for s in slots},
                 "Out@GRAD": [x or "" for x in gout.get("Out", [])]},
-        outputs={s + "@GRAD": gin.get(s, [""]) for s in ("X", "Filter")},
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in slots},
         attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
 
 
 @register_op("short_conv_grad")
 def short_conv_grad_op(ctx, ins, attrs):
-    """d X [T, 3C] and d Filter [L, C] (float32) of `short_conv`: the
-    backward kernel where the forward took its kernel, else the plain
-    form's."""
+    """d X and d Filter [L, C] (float32) of `short_conv` (and d Bias [C]
+    of the "silu" variant given one): the backward kernel where the forward
+    took its kernel, else the plain form's."""
     x, w = first(ins, "X"), first(ins, "Filter")
     seq_len = int(attrs["seq_len"])
+    bias = first(ins, "Bias")
+    if bias is not None:
+        d_x, d_w, d_b = silu_conv_grad(x, w, first(ins, "Out@GRAD"),
+                                       seq_len, bias)
+        return out(**{"X@GRAD": d_x, "Filter@GRAD": d_w.astype(w.dtype),
+                      "Bias@GRAD": d_b.astype(bias.dtype)})
     if attrs.get("gating") == SILU:
         if _silu_kernels_take(x, w, seq_len):
             from ..parallel.short_conv import silu_conv_bwd as grad
@@ -734,6 +760,73 @@ def gated_delta_rule_grad_op(ctx, ins, attrs):
                   for s, g, a in zip(_DELTA_INPUTS, grads, args)})
 
 
+# --------------------------------------------------------------- ssd_scan
+def _ssd_shape(attrs):
+    """The keyword arguments of `parallel/ssd.py`'s two functions."""
+    return dict(seq_len=int(attrs["seq_len"]), heads=int(attrs["num_heads"]),
+                head_dim=int(attrs["head_dim"]),
+                groups=int(attrs["num_groups"]),
+                state=int(attrs["state_size"]), chunk=int(attrs["chunk"]))
+
+
+_SSD_INPUTS = ("X", "B", "C", "Dt", "ALog", "DtBias", "D")
+
+
+@register_op("ssd_scan")
+def ssd_scan_op(ctx, ins, attrs):
+    """The selective state-space scan of a Mamba-2 mixer (Dao & Gu,
+    arXiv:2405.21060). X [T, H P], B, C [T, G N] (the convolution's output,
+    split), Dt [T, H], T = rows x `seq_len` tokens; ALog, DtBias, D [H]
+    (float32 masters read as they are) -> Out [T, H P]. delta =
+    softplus(Dt + DtBias), A = -exp(ALog), float32; per head a state h [P,
+    N], zero at a row's first token (rows are separate sequences): h_t =
+    exp(delta_t A) h_{t-1} + delta_t x_t B_t^T; y_t = h_t C_t + D x_t, head
+    h reading group h // (H / G)'s B and C. Worked in chunks of `chunk`
+    tokens (`parallel/ssd.py`, the state-space dual form): the in-chunk
+    products as batched products over all chunks (C B^T once a group), a
+    scan over the chunks that carries the state in float32. States [chunks,
+    rows, G, H / G, P, N] float32, the state each chunk starts from, is
+    kept for the backward op; FinalState [rows, H, P, N] is the state
+    behind each row's last token. A row need not be whole chunks: its tail
+    is padded with steps of zero."""
+    from ..parallel import ssd
+
+    o, starts, last = ssd.ssd_fwd(
+        *(first(ins, s) for s in _SSD_INPUTS), **_ssd_shape(attrs))
+    return out(Out=o, States=starts, FinalState=last)
+
+
+set_stop_gradient_outputs("ssd_scan", ["States", "FinalState"])
+
+
+@register_grad_maker("ssd_scan")
+def _ssd_scan_grad_maker(op, gout, gin):
+    """Hand-written: the generic vjp of the chunk scan would keep every
+    chunk's [Q, Q] decays and products; this one takes the chunk-start
+    states the forward left and forms the rest again."""
+    inputs = {s: op.input(s) for s in _SSD_INPUTS}
+    inputs["States"] = op.output("States")
+    inputs["Out@GRAD"] = [x or "" for x in gout.get("Out", [])]
+    return [dict(
+        type="ssd_scan_grad", inputs=inputs,
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in _SSD_INPUTS},
+        attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
+
+
+@register_op("ssd_scan_grad")
+def ssd_scan_grad_op(ctx, ins, attrs):
+    """d X, d B, d C, d Dt (in their dtypes), d ALog, d DtBias, d D of
+    `ssd_scan`: the reverse recurrence of the state's cotangent over the
+    chunks, written out, from the saved chunk-start states."""
+    from ..parallel import ssd
+
+    args = [first(ins, s) for s in _SSD_INPUTS]
+    grads = ssd.ssd_bwd(*args, first(ins, "States"), first(ins, "Out@GRAD"),
+                        **_ssd_shape(attrs))
+    return out(**{s + "@GRAD": g.astype(a.dtype)
+                  for s, g, a in zip(_SSD_INPUTS, grads, args)})
+
+
 # ----------------------------------------------------------------- moe_ffn
 # The parts of the layer's lowering outside its kernels, each under a
 # `jax.named_scope` of its own inside the op's, forward and backward, so a
@@ -754,7 +847,7 @@ _VJP = "vjp"
 
 
 def _first_rows(a, rows):
-    return a if a.shape[0] <= rows else a[:rows]
+    return a if a is None or a.shape[0] <= rows else a[:rows]
 
 
 def _sum_of_choices(table, inv, k, weights=None):
@@ -1118,7 +1211,10 @@ def moe_ffn_op(ctx, ins, attrs):
     not swamp scores near 1 / E); w is the chosen scores, divided by their
     sum (plus `norm_eps`, 1e-20 unless given) with `norm_topk`, times
     `routed_scale`. `activation` silu | relu
-    is what gates an expert: silu(Gate_e x) or relu(Gate_e x) times Up_e x.
+    is what gates an expert: silu(Gate_e x) or relu(Gate_e x) times Up_e x;
+    `activation` relu2 is an UN-GATED expert, Down_e(relu(Up_e x)^2): the
+    op then has no Gate input and leaves no GateOut (two stacked matrices
+    a layer, six grouped kernels a step where a gated layer runs nine).
     RouterInput [T, H], where given, is what the router scores INSTEAD of
     X (a router placed before attention reads the layer's input, the
     experts the normed state after it): the routing then depends on no
@@ -1160,7 +1256,8 @@ def moe_ffn_op(ctx, ins, attrs):
         first(ins, _ROUTER_INPUT))
     return out(Out=o, AuxLoss=aux, ZLoss=z, ExpertIds=ids,
                TokensPerExpert=counts, RowsHeld=rows,
-               **dict(zip(_MOE_PRODUCTS, products)))
+               **{s: p for s, p in zip(_MOE_PRODUCTS, products)
+                  if p is not None})
 
 
 set_stop_gradient_outputs(
@@ -1197,7 +1294,8 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     router_x = first(ins, _ROUTER_INPUT)
     r = Routing(attrs, router.shape[1])
     products = tuple(first(ins, s) for s in _MOE_PRODUCTS)
-    if any(p is None for p in products):
+    # an un-gated expert ("relu2") has no Gate and leaves no GateOut
+    if any(p is None for p in products[gate is None:]):
         products = None
     differentiated = jax.named_scope(_VJP)
     (top_p, aux, z), route_vjp, (_, _, held_counts, order, inv) = jax.vjp(
@@ -1212,7 +1310,7 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     def gradients(rows):
         # the saved gate and up products are of the rows the forward op
         # took: after an overflow they are computed again
-        saved = products if products and products[0].shape[0] == rows \
+        saved = products if products and products[1].shape[0] == rows \
             else None
         return jax.vjp(
             differentiated(
@@ -1223,7 +1321,8 @@ def moe_ffn_grad_op(ctx, ins, attrs):
     d_x, d_top_p, *d_weights = _held_rows_take(
         r, order.shape[0], router.shape[1], held_counts, gradients)
     d_x_routed, d_router = route_vjp((d_top_p, d_aux, d_z))
-    grads = dict(zip(_MOE_TRAINED, (d_x, d_router, *d_weights)))
+    grads = {s: g for s, g in zip(_MOE_TRAINED, (d_x, d_router, *d_weights))
+             if g is not None}
     if router_x is None:
         with jax.named_scope(COMBINE):
             grads["X"] = d_x + d_x_routed
@@ -1456,7 +1555,8 @@ def _kernels_take(op, block, whole_mlp=False):
     products in their epilogues."""
     from ..parallel import grouped
 
-    x, gate = (block.vars[op.input(s)[0]].shape for s in ("X", "Gate"))
+    # an un-gated layer ("relu2") has no Gate; Up has its shape
+    x, gate = (block.vars[op.input(s)[0]].shape for s in ("X", "Up"))
     rows = x[0] * int(op.attrs.get("top_k", 1)) if x[0] > 0 else None
     takes = grouped.mlp_takes if whole_mlp else grouped.takes
     return takes(rows, gate[1], gate[2])
@@ -1497,6 +1597,14 @@ def _sums_rows_by_token(op, block):
         op.input("Router")[0]].shape[1])
     low = amp.compute_dtype() if amp.is_enabled() else x.dtype
     return row_sum.takes_choices(T, k, x.shape[1], bound, low)
+
+
+def _scan_chunks(op, block):
+    """The chunks an `ssd_scan` walks a step: rows x ceil(seq_len / chunk)
+    (rows the program leaves open are taken to be one)."""
+    tokens = block.vars[op.input("X")[0]].shape[0]
+    seq_len, chunk = int(op.attrs["seq_len"]), int(op.attrs["chunk"])
+    return max(1, tokens // seq_len) * -(-seq_len // chunk)
 
 
 def _has_window(op, block):
@@ -1548,7 +1656,7 @@ def _silu_kernel_takes(op, block):
     its grad), from the shapes the program states."""
     from ..parallel import short_conv as kernels
 
-    if not _conv_is_silu(op, block):
+    if not _conv_is_silu(op, block) or op.input("Bias"):
         return False
     x, w = (block.vars[op.input(s)[0]] for s in ("X", "Filter"))
     seq_len = int(op.attrs["seq_len"])
@@ -1731,11 +1839,26 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("indexer_loss", "indexer_loss_kernel", True,
              _index_loss_kernel_takes),
             ("sparse_attention_grad", "sparse_attention_grad_fused", True,
-             _sparse_grad_is_one_kernel))
+             _sparse_grad_is_one_kernel),
+            ("ssd_scan", "ssd_scan_chunked", False, None),
+            ("ssd_scan_grad", "ssd_scan_grad_by_hand", False, None),
+            ("ssd_scan", "ssd_scan_chunks", False, _scan_chunks),
+            ("short_conv", "short_conv_silu_bias", False,
+             lambda op, block: bool(op.input("Bias"))),
+            ("moe_ffn", "moe_ffn_relu2", False,
+             lambda op, block: op.attrs.get("activation") == "relu2"))
 
 
 def lowered_counts(program, device):
-    """{counter: n} for the step spans and the registry: `moe_ffn` ops of
+    """{counter: n} for the step spans and the registry (the newest first:
+    its `ssd_scan` ops, `ssd_scan_chunked`: the chunked form, one scan over
+    the chunks, plain `jax.numpy` on every place, with the chunks they walk
+    a step, static, `ssd_scan_chunks`, and their grads,
+    `ssd_scan_grad_by_hand`; its `short_conv` ops of the "silu" variant
+    that take a bias, `short_conv_silu_bias`, which lower as shifted
+    multiply-adds on every place; its `moe_ffn` ops of un-gated experts
+    relu(x U)^2 D, `moe_ffn_relu2`, two stacked matrices and six grouped
+    kernels a step): `moe_ffn` ops of
     the program (each lowers through the grouped products; on a TPU place
     those whose shapes the Pallas grouped-matmul kernels take count as
     `grouped_matmul_kernel` too, and as `grouped_mlp_epilogues` where

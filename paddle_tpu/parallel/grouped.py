@@ -194,6 +194,18 @@ def _relu_mul_grad(p, a, b):
     return jnp.where(a > 0.0, p * b, 0.0), p * jnp.maximum(a, 0.0)
 
 
+def _relu_sq(p):
+    """The up product's epilogue of an UN-GATED expert relu(x U)^2 D: a =
+    p, h = relu(p)^2."""
+    return p, jnp.square(jnp.maximum(p, 0.0))
+
+
+def _relu_sq_grad(p, a):
+    """The d h product's epilogue of relu(a)^2, p = d h: d a = 2 relu(a) p.
+    No transcendental."""
+    return (2.0 * jnp.maximum(a, 0.0) * p,)
+
+
 def _add(p, existing):
     return (existing + p,)
 
@@ -203,16 +215,21 @@ def _add(p, existing):
 _EPILOGUES = {None: (lambda p: (p,), 0, 1), "silu_mul": (_silu_mul, 1, 2),
               "silu_mul_grad": (_silu_mul_grad, 2, 2), "add": (_add, 1, 1),
               "relu_mul": (_relu_mul, 1, 2),
-              "relu_mul_grad": (_relu_mul_grad, 2, 2)}
-# what gates an expert (`grouped_mlp`'s `activation`), on float32 tiles
-_ACTIVATIONS = {"silu": jax.nn.silu, "relu": lambda a: jnp.maximum(a, 0.0)}
+              "relu_mul_grad": (_relu_mul_grad, 2, 2),
+              "relu_sq": (_relu_sq, 0, 2),
+              "relu_sq_grad": (_relu_sq_grad, 1, 1)}
+# what gates an expert (`grouped_mlp`'s `activation`), on float32 tiles;
+# "relu2" is no gate: the un-gated expert's own activation relu(a)^2
+RELU2 = "relu2"
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": lambda a: jnp.maximum(a, 0.0),
+                RELU2: lambda a: jnp.square(jnp.maximum(a, 0.0))}
 
 
 def _named(epilogue):
     """The scope a kernel with this epilogue is called under: ReLU's name
     is on the op_name's path (`.../grouped/relu_mul/grouped_matmul`);
     SiLU's, the kernels' first, stand where they have always stood."""
-    if epilogue in ("relu_mul", "relu_mul_grad"):
+    if epilogue in ("relu_mul", "relu_mul_grad", "relu_sq", "relu_sq_grad"):
         return jax.named_scope(epilogue)
     return contextlib.nullcontext()
 
@@ -346,7 +363,8 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs,
     """One visit: the tile's rows of the visit's group, lhs^T times rhs,
     summed in `acc` over the group's visits and written at its last.
     `refs`: lhs, or the two tiles a, b that lhs = `activation`(a) * b is
-    formed from here; rhs, out, `acc`."""
+    formed from here (one tile a under "relu2": lhs = relu(a)^2); rhs, out,
+    `acc`."""
     *lhs, rhs, out, acc = refs
     visit = pl.program_id(2)
     last = pl.num_programs(2) - 1
@@ -362,6 +380,8 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs,
         if len(lhs) == 2:
             a = (_ACTIVATIONS[activation](a.astype(jnp.float32))
                  * lhs[1][rows, :].astype(jnp.float32)).astype(a.dtype)
+        elif activation == RELU2:
+            a = _ACTIVATIONS[RELU2](a.astype(jnp.float32)).astype(a.dtype)
         # rows of other groups zeroed in ONE operand: a zero row of either
         # contributes nothing
         if mask_of is not None and mask_lhs:
@@ -383,7 +403,8 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, *refs, tm, tk, tn, mask_lhs,
 def _tgmm(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs=None,
           activation="silu"):
     lhs = lhs if isinstance(lhs, tuple) else (lhs,)
-    with _named(activation + "_mul" if len(lhs) == 2 else None):
+    with _named("relu_sq" if activation == RELU2 else
+                activation + "_mul" if len(lhs) == 2 else None):
         return _tgmm_call(lhs, rhs, meta, n_visits, tiles,
                           jnp.dtype(out_dtype), mask_lhs, pallas_interpret(),
                           activation)
@@ -394,7 +415,9 @@ def _tgmm_call(lhs, rhs, meta, n_visits, tiles, out_dtype, mask_lhs,
                interpret, activation):
     """[N, K], [N, M] -> [E, K, M]: group g's rows of lhs, transposed,
     times its rows of rhs. `lhs` a pair (a, b): lhs is `activation`(a) *
-    b, formed from the two tiles in VMEM. `mask_lhs`: which operand has the
+    b, formed from the two tiles in VMEM; one array under `activation`
+    "relu2": lhs is relu(a)^2, formed likewise (under any other name one
+    array is lhs as it stands). `mask_lhs`: which operand has the
     rows of other groups zeroed in a tile a boundary crosses; the narrower
     tile unless said (rows past the groups must be FINITE in the other
     one: a zero row times a NaN is a NaN)."""
@@ -499,8 +522,22 @@ def _matmul_bwd(tiles, rows_past, res, g):
 grouped_matmul.defvjp(_matmul_fwd, _matmul_bwd)
 
 
+# a dimension of whole HALF lane tiles that is no multiple of a lane tile
+# (an expert width of 1856 = 29 x 64) is worked WHOLE, as one tile (a block
+# that spans its array's dimension needs no alignment: Mosaic pads its last
+# half tile in VMEM), from past one lane tile up to this many numbers
+_WHOLE_MOST = 2048
+
+
+def _taken_whole(dim):
+    return dim % 128 != 0 and dim % 64 == 0 and 128 < dim <= _WHOLE_MOST
+
+
 def _largest_tile(dim, most):
-    """The largest multiple of 128 that divides `dim` and is <= `most`."""
+    """The largest multiple of 128 that divides `dim` and is <= `most`;
+    `dim` itself where it is no multiple of 128 and `_taken_whole`."""
+    if _taken_whole(dim):
+        return dim
     for t in range(min(dim, most) // 128 * 128, 0, -128):
         if dim % t == 0:
             return t
@@ -509,10 +546,11 @@ def _largest_tile(dim, most):
 
 def takes(n_rows, k, m):
     """Whether the kernels take [n_rows, k] x [E, k, m] and its two
-    gradients: K and M multiples of 128 (a lane tile), the rows a multiple
-    of the smallest row tile (`n_rows` None: not known yet, taken to
-    fit)."""
-    return k % 128 == 0 and m % 128 == 0 and (
+    gradients: K and M multiples of 128 (a lane tile) or, between 128 and
+    2048, of 64 and then worked whole (an expert width of 1856 = 14.5 lane
+    tiles: PR 54), the rows a multiple of the smallest row tile (`n_rows`
+    None: not known yet, taken to fit)."""
+    return all(d % 128 == 0 or _taken_whole(d) for d in (k, m)) and (
         n_rows is None or n_rows % ROW_TILES[-1] == 0)
 
 
@@ -665,6 +703,47 @@ def _mlp_bwd(tiles, rows_past, activation, res, cots):
 _mlp.defvjp(_mlp_fwd, _mlp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _mlp_sq(xs, up, down, counts, saved, tiles, rows_past):
+    return _mlp_sq_fwd(xs, up, down, counts, saved, tiles, rows_past)[0]
+
+
+def _mlp_sq_fwd(xs, up, down, counts, saved, tiles, rows_past):
+    """`_mlp_fwd` of an un-gated expert, ys = relu(xs up)^2 down -> (ys,
+    a): two kernels, six a step with `_mlp_sq_bwd`."""
+    if saved is None:
+        (tm, up_fwd, _, _), (_, down_fwd, _, _) = tiles
+        with jax.named_scope(_SCOPE):
+            meta, n = visits(counts, xs.shape[0], tm, False)
+            a, h = _gmm(xs, up, meta, n, (tm,) + up_fwd, False, "relu_sq")
+            ys = _gmm(h, down, meta, n, (tm,) + down_fwd, False)
+            saved = (a, _rows_of_groups(ys, counts) if rows_past else ys)
+    a, ys = saved
+    return (ys, a), (xs, up, down, counts, a)
+
+
+def _mlp_sq_bwd(tiles, rows_past, res, cots):
+    xs, up, down, counts, a = res
+    (tm, _, up_dlhs, up_drhs), (_, _, down_dlhs, down_drhs) = tiles
+    d_ys = cots[0].astype(xs.dtype)
+    with jax.named_scope(_SCOPE):
+        meta, n = visits(counts, xs.shape[0], tm, False)
+        d_a = _gmm(d_ys, down, meta, n, (tm,) + down_dlhs, True,
+                   "relu_sq_grad", (a,))
+        d_xs = _gmm(d_a, up, meta, n, (tm,) + up_dlhs, True)
+        if rows_past:
+            d_xs = _rows_of_groups(d_xs, counts)
+        meta, n = visits(counts, xs.shape[0], tm, True)
+        d_down = _tgmm(a, d_ys, meta, n, (tm,) + down_drhs, down.dtype,
+                       mask_lhs=True, activation=RELU2)
+        d_up = _tgmm(xs, d_a, meta, n, (tm,) + up_drhs, up.dtype,
+                     mask_lhs=False)
+    return d_xs, d_up, d_down, None, None
+
+
+_mlp_sq.defvjp(_mlp_sq_fwd, _mlp_sq_bwd)
+
+
 def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False,
                 activation="silu"):
     """A sparse-expert layer's MLP over rows sorted by group: xs [N, H],
@@ -697,8 +776,18 @@ def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False,
     No gradient flows through the a and b it returns (an op's saved
     outputs). With `rows_past`, ys and d xs are zero past the groups'
     rows; a, b (and h, d a, d b inside) hold there whatever their buffers
-    held: kernels alone read them, on visited rows."""
+    held: kernels alone read them, on visited rows.
+
+    `activation` "relu2" with `gate` None is the UN-GATED expert, ys =
+    relu(xs @ up[g])^2 @ down[g] -> (ys, None, a = xs @ up[g]); `saved` is
+    (None, a, ys). Six kernels a step: a, h = G(xs, up) with h = relu(a)^2
+    written beside a (`relu_sq`); ys = G(h, down); d a = NT(d ys, down)
+    with 2 relu(a) taken on the d h tile (`relu_sq_grad`); d xs = NT(d a,
+    up); d down = TN(h, d ys) with h formed again from a; d up = TN(xs,
+    d a)."""
     counts = counts.astype(jnp.int32)
+    if activation == RELU2:
+        return _ungated_mlp(xs, up, down, counts, saved, rows_past)
     H, F = gate.shape[1:]
     if (on_tpu() and xs.dtype == gate.dtype == up.dtype == down.dtype
             and mlp_takes(xs.shape[0], H, F)):
@@ -711,3 +800,19 @@ def grouped_mlp(xs, gate, up, down, counts, saved=None, rows_past=False,
     b = grouped_dot(xs, up, counts, saved[1], rows_past)
     return grouped_dot(_ACTIVATIONS[activation](a) * b, down, counts,
                        saved[2], rows_past), a, b
+
+
+def _ungated_mlp(xs, up, down, counts, saved, rows_past):
+    """`grouped_mlp` under "relu2": (ys, None, a)."""
+    H, F = up.shape[1:]
+    saved = None if saved is None else saved[1:]
+    if (on_tpu() and xs.dtype == up.dtype == down.dtype
+            and mlp_takes(xs.shape[0], H, F)):
+        tiles = (tiles_for(xs.shape[0], H, F, xs.dtype),
+                 tiles_for(xs.shape[0], F, H, xs.dtype))
+        ys, a = _mlp_sq(xs, up, down, counts, saved, tiles, bool(rows_past))
+        return ys, None, a
+    saved = saved or (None, None)
+    a = grouped_dot(xs, up, counts, saved[0], rows_past)
+    return grouped_dot(_ACTIVATIONS[RELU2](a), down, counts, saved[1],
+                       rows_past), None, a

@@ -92,6 +92,16 @@ def _estimate(block, op, batch):
             ("chunk", 64), ("head_k_dim", 128), ("head_v_dim", 128)))
         return out_elems / dv * (4.0 * c * dk + c * (dk + dv)
                                  + 6.0 * dk * dv + 2.0 * c * dv)
+    if t == "ssd_scan":
+        # the chunked form a token and head: C B^T a group (2 Q N G / H),
+        # the masked product with x (2 Q P), the chunk's own state and the
+        # carried part (4 P N)
+        a = op.attrs
+        q, p, n, h, g = (int(a.get(k, d)) for k, d in (
+            ("chunk", 128), ("head_dim", 64), ("state_size", 128),
+            ("num_heads", 64), ("num_groups", 8)))
+        return out_elems / p * (2.0 * q * n * g / h + 2.0 * q * p
+                                + 4.0 * p * n)
     if t in ("indexer_select", "indexer_loss"):
         # the indexer's scores over the causal pairs: 2 Hi Di a pair (the
         # loss forms them again with their two gradients, and the main

@@ -136,7 +136,7 @@ def test_under_amp_the_taps_stay_float32_and_their_sum_is_float32():
     bf16, the taps as the float32 master; the output is bf16 and is the
     float32 form rounded ONCE (a bf16 sum of three products differs)."""
     assert "short_conv" in amp.WHITE_LIST
-    assert amp.FLOAT32_SLOTS["short_conv"] == frozenset({"Filter"})
+    assert amp.FLOAT32_SLOTS["short_conv"] == frozenset({"Filter", "Bias"})
     x, w, _ = _inputs(2, 16, 8, 3, "float32", seed=5)
     amp.enable("bfloat16")
     try:
