@@ -783,17 +783,39 @@ def ssd_scan_op(ctx, ins, attrs):
     exp(delta_t A) h_{t-1} + delta_t x_t B_t^T; y_t = h_t C_t + D x_t, head
     h reading group h // (H / G)'s B and C. Worked in chunks of `chunk`
     tokens (`parallel/ssd.py`, the state-space dual form): the in-chunk
-    products as batched products over all chunks (C B^T once a group), a
-    scan over the chunks that carries the state in float32. States [chunks,
+    products as batched products over all chunks (C B^T once a group; on a
+    TPU place, for shapes they take, in the Pallas kernels of
+    `parallel/ssd_parts.py`, which keep a chunk's decays in VMEM), a scan
+    over the chunks that carries the state in float32. States [chunks,
     rows, G, H / G, P, N] float32, the state each chunk starts from, is
     kept for the backward op; FinalState [rows, H, P, N] is the state
     behind each row's last token. A row need not be whole chunks: its tail
     is padded with steps of zero."""
     from ..parallel import ssd
 
-    o, starts, last = ssd.ssd_fwd(
-        *(first(ins, s) for s in _SSD_INPUTS), **_ssd_shape(attrs))
+    args = [first(ins, s) for s in _SSD_INPUTS]
+    fwd = ssd.kernels_fwd if _ssd_kernels_take(args[0], attrs) \
+        else ssd.ssd_fwd
+    o, starts, last = fwd(*args, **_ssd_shape(attrs))
     return out(Out=o, States=starts, FinalState=last)
+
+
+def _ssd_kernels_take(x, attrs):
+    """Whether this trace hands the op's in-chunk work to the Pallas kernels
+    of `parallel/ssd_parts.py`: a TPU place and shapes they take (rows of
+    whole chunks of 128, lane-tile widths, bf16 or float32); anything else
+    lowers the plain form. Read from the call: no flag."""
+    return on_tpu() and _ssd_shapes_taken(x.shape[0], x.dtype, attrs)
+
+
+def _ssd_shapes_taken(tokens, dtype, attrs):
+    """`ssd.takes` of `tokens` tokens in `dtype` under the op's attrs."""
+    from ..parallel import ssd
+
+    shape = _ssd_shape(attrs)
+    seq_len = shape.pop("seq_len")
+    return tokens % seq_len == 0 and ssd.takes(
+        tokens // seq_len, seq_len, dtype=dtype, **shape)
 
 
 set_stop_gradient_outputs("ssd_scan", ["States", "FinalState"])
@@ -821,8 +843,10 @@ def ssd_scan_grad_op(ctx, ins, attrs):
     from ..parallel import ssd
 
     args = [first(ins, s) for s in _SSD_INPUTS]
-    grads = ssd.ssd_bwd(*args, first(ins, "States"), first(ins, "Out@GRAD"),
-                        **_ssd_shape(attrs))
+    bwd = ssd.kernels_bwd if _ssd_kernels_take(args[0], attrs) \
+        else ssd.ssd_bwd
+    grads = bwd(*args, first(ins, "States"), first(ins, "Out@GRAD"),
+                **_ssd_shape(attrs))
     return out(**{s + "@GRAD": g.astype(a.dtype)
                   for s, g, a in zip(_SSD_INPUTS, grads, args)})
 
@@ -1674,6 +1698,15 @@ def _delta_kernel_takes(op, block):
     return _delta_shapes_taken(tokens, low, op.attrs)
 
 
+def _ssd_kernel_takes(op, block):
+    """Whether the Pallas kernels of `parallel/ssd_parts.py` take this
+    `ssd_scan` (or its grad), from the shapes the program states."""
+    x = block.vars[op.input("X")[0]]
+    tokens = x.shape[0] if x.shape[0] > 0 else int(op.attrs["seq_len"])
+    low = amp.compute_dtype() if amp.is_enabled() else x.dtype
+    return _ssd_shapes_taken(tokens, low, op.attrs)
+
+
 def _index_loss_kernel_takes(op, block):
     """Whether the Pallas kernels of `parallel/index_loss.py` take this
     `indexer_loss`, from the shapes the program states."""
@@ -1846,16 +1879,21 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("short_conv", "short_conv_silu_bias", False,
              lambda op, block: bool(op.input("Bias"))),
             ("moe_ffn", "moe_ffn_relu2", False,
-             lambda op, block: op.attrs.get("activation") == "relu2"))
+             lambda op, block: op.attrs.get("activation") == "relu2"),
+            ("ssd_scan", "ssd_scan_kernel", True, _ssd_kernel_takes),
+            ("ssd_scan_grad", "ssd_scan_grad_kernel", True,
+             _ssd_kernel_takes))
 
 
 def lowered_counts(program, device):
     """{counter: n} for the step spans and the registry (the newest first:
     its `ssd_scan` ops, `ssd_scan_chunked`: the chunked form, one scan over
-    the chunks, plain `jax.numpy` on every place, with the chunks they walk
-    a step, static, `ssd_scan_chunks`, and their grads,
-    `ssd_scan_grad_by_hand`; its `short_conv` ops of the "silu" variant
-    that take a bias, `short_conv_silu_bias`, which lower as shifted
+    the chunks, with the chunks they walk a step, static,
+    `ssd_scan_chunks`, and their grads, `ssd_scan_grad_by_hand`; on a TPU
+    place those whose in-chunk work the Pallas kernels of
+    parallel/ssd_parts.py take count as `ssd_scan_kernel` /
+    `ssd_scan_grad_kernel` too, the others are plain `jax.numpy`; its
+    `short_conv` ops of the "silu" variant that take a bias, `short_conv_silu_bias`, which lower as shifted
     multiply-adds on every place; its `moe_ffn` ops of un-gated experts
     relu(x U)^2 D, `moe_ffn_relu2`, two stacked matrices and six grouped
     kernels a step): `moe_ffn` ops of

@@ -42,17 +42,25 @@ recurrence of the state's cotangent
 and hands d S = dh_{c+1} and d exp(cum_Q) = <dh_{c+1}, h0_c> to the
 transposes of the products above. `jax.vjp` is taken of no scan.
 
-This is plain `jax.numpy` on every place, no Pallas kernel: at the
-`nemotron_3_nano_30b_a3b` cell's shape the op and its backward take 13% of
-a step's busy time at a tenth of the scan's (bandwidth) roofline (PERF.md,
-PR 54): what a kernel that keeps a chunk's decays in VMEM would win.
+`ssd_fwd` / `ssd_bwd` are plain `jax.numpy`, the lowering of every place.
+ON A TPU PLACE, for shapes `takes` takes, `kernels_fwd` / `kernels_bwd` are
+the same algorithm with everything that is [Q, Q] a head (L, C B^T, L o C
+B^T and their cotangents) inside the Pallas kernels of
+`parallel/ssd_parts.py`: one before each scan over the chunks (the chunk's
+own S; d h0), one behind it (y; the gradients), the scans and the per-token
+scalars (`steps`, the softplus' backward) left to XLA. Written as above,
+those arrays cost the `nemotron_3_nano_30b_a3b` step 13% of its busy time
+at a tenth of the scan's roofline (PERF.md, PR 54; the kernels: PR 55).
+Both paths decide precision in the same four places, which a study wraps
+from outside: `steps`, `decay`, `carried`, `STATE_PRECISION`.
 """
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["ssd_fwd", "ssd_bwd", "steps", "states_shape", "CHUNK"]
+__all__ = ["ssd_fwd", "ssd_bwd", "kernels_fwd", "kernels_bwd", "takes",
+           "steps", "states_shape", "CHUNK"]
 
 F32 = jnp.float32
 # tokens a chunk where the op's attr gives none: the published kernels'
@@ -87,6 +95,15 @@ def states_shape(rows, seq_len, heads, head_dim, groups, state, chunk):
     """The shape of `States`: the state every chunk starts from."""
     return (-(-seq_len // chunk), rows, groups, heads // groups, head_dim,
             state)
+
+
+def _over_chunks(g, s, reverse=False):
+    """The scan over the chunks, g [c, R, G, H/G] and s [c, R, G, H/G, P,
+    N]: h' = `carried`(g_c, h, s_c) from zero -> (the last h, the h each
+    chunk started from); `reverse`: from the last chunk down (the state's
+    cotangent)."""
+    return lax.scan(lambda h, c: (carried(c[0], h, c[1]), h),
+                    jnp.zeros(s.shape[1:], F32), (g, s), reverse=reverse)
 
 
 def _state_dot(spec, a, b):
@@ -161,13 +178,8 @@ def ssd_fwd(x, b, c, dt, a_log, dt_bias, d, *, seq_len, heads, head_dim,
         own = _dot("rcjghp,rcjgn->rcghpn",
                    (xf * (delta * to_end)[..., None]).astype(low), bc)
     with jax.named_scope(STATES):
-        def step(h, chunk_):
-            g, s = chunk_
-            return carried(g, h, s), h
-
-        final, starts = lax.scan(
-            step, jnp.zeros(own.shape[:1] + own.shape[2:], F32),
-            (jnp.moveaxis(decay(last), 1, 0), jnp.moveaxis(own, 1, 0)))
+        final, starts = _over_chunks(jnp.moveaxis(decay(last), 1, 0),
+                                     jnp.moveaxis(own, 1, 0))
     with jax.named_scope(OUTPUTS):
         y = y + _state_dot("rcign,crghpn->rcighp", cc, starts) \
             * decay(cum)[..., None]
@@ -201,14 +213,8 @@ def ssd_bwd(x, b, c, dt, a_log, dt_bias, d, starts, dy, *, seq_len, heads,
         d_h0 = _dot("rcighp,rcign->crghpn", dye.astype(low), cc)
     with jax.named_scope(STATES):
         g = jnp.moveaxis(decay(last), 1, 0)                 # [c, R, G, H/G]
-
-        def step(dh_next, chunk_):
-            g_c, d_h0_c = chunk_
-            return carried(g_c, dh_next, d_h0_c), dh_next
-
         # d_own[c]: the cotangent of the state chunk c + 1 starts from
-        _, d_own = lax.scan(step, jnp.zeros(starts.shape[1:], F32),
-                            (g, d_h0), reverse=True)
+        _, d_own = _over_chunks(g, d_h0, reverse=True)
     with jax.named_scope(OUTPUTS):
         d_last = jnp.moveaxis(g * jnp.sum(d_own * starts, axis=(-1, -2)),
                               0, 1)
@@ -237,15 +243,90 @@ def ssd_bwd(x, b, c, dt, a_log, dt_bias, d, starts, dy, *, seq_len, heads,
         d_b = d_b + _dot("rcgij,rcign->rcjgn", d_cb, cc)
         d_x = d_x + d_xd * delta[..., None]
         d_delta = d_delta + jnp.sum(d_xd * xf, axis=-1)
-        # cum is a's running sum, cum_Q its last entry; a = delta A
+        # cum_Q is cum's last entry; cum is a's running sum
         d_cum = d_cum.at[:, :, -1].add(d_last)
         d_a = jnp.cumsum(d_cum[:, :, ::-1], axis=2)[:, :, ::-1]
-        neg_a = -jnp.exp(a_log.astype(F32)).reshape(groups, per)
-        d_delta = d_delta + d_a * neg_a
-        d_a_log = jnp.sum(d_a * delta, axis=(0, 1, 2)) * neg_a
-        pre = _chunks(dt, rows, seq_len, chunk, groups, per).astype(F32) \
-            + dt_bias.astype(F32).reshape(groups, per)
-        d_dt = _tokens(d_delta * jax.nn.sigmoid(pre), seq_len)
         return (_tokens(d_x, seq_len), _tokens(d_b, seq_len),
-                _tokens(d_c, seq_len), d_dt, d_a_log.reshape(heads),
-                jnp.sum(d_dt, axis=0), d_d)
+                _tokens(d_c, seq_len)) + _steps_bwd(
+                    d_delta, d_a, delta, dt, a_log, dt_bias, seq_len,
+                    chunk) + (d_d,)
+
+
+def _steps_bwd(d_delta, d_a, delta, dt, a_log, dt_bias, seq_len, chunk):
+    """(d dt [T, H], d A_log, d dt_bias [H]) from the cotangents of delta
+    and of a = delta A [R, c, Q, G, H/G], delta = softplus(dt + dt_bias)."""
+    rows, _, _, groups, per = delta.shape
+    neg_a = -jnp.exp(a_log.astype(F32)).reshape(groups, per)
+    d_delta = d_delta + d_a * neg_a
+    d_a_log = jnp.sum(d_a * delta, axis=(0, 1, 2)) * neg_a
+    pre = _chunks(dt, rows, seq_len, chunk, groups, per).astype(F32) \
+        + dt_bias.astype(F32).reshape(groups, per)
+    d_dt = _tokens(d_delta * jax.nn.sigmoid(pre), seq_len)
+    return d_dt, d_a_log.reshape(-1), jnp.sum(d_dt, axis=0)
+
+
+# ------------------------------------------------------- the kernel path
+def takes(rows, seq_len, heads, head_dim, groups, state, chunk, dtype):
+    """Whether the kernel path takes these shapes (`ssd_parts.takes`)."""
+    from . import ssd_parts
+
+    return ssd_parts.takes(rows, seq_len, heads, head_dim, groups, state,
+                           chunk, dtype)
+
+
+def _by_head(dt, a_log, dt_bias, rows, seq_len, heads, groups, chunk):
+    """delta [R, c, Q, G, H/G] as `_parts` forms it (rows of whole chunks),
+    delta and a = delta A as the kernels read them (`ssd_parts.by_head`:
+    they form a's running sum themselves) and exp(cum_Q), chunks leading
+    [c, R, G, H/G]."""
+    from .ssd_parts import by_head
+
+    per = heads // groups
+    delta, a = steps(_chunks(dt, rows, seq_len, chunk, groups, per),
+                     a_log.reshape(groups, per), dt_bias.reshape(groups, per))
+    return (delta, by_head(delta, rows, seq_len, groups),
+            by_head(a, rows, seq_len, groups),
+            jnp.moveaxis(decay(jnp.sum(a, axis=2)), 1, 0))
+
+
+def kernels_fwd(x, b, c, dt, a_log, dt_bias, d, **shape):
+    """`ssd_fwd` with the in-chunk work in the Pallas kernels of
+    `parallel/ssd_parts.py`. `takes` must hold."""
+    from . import ssd_parts
+
+    seq_len, heads = shape["seq_len"], shape["heads"]
+    with jax.named_scope(CHUNKS):
+        _, delta, a, g = _by_head(dt, a_log, dt_bias, x.shape[0] // seq_len,
+                                  seq_len, heads, shape["groups"],
+                                  shape["chunk"])
+        own = ssd_parts.chunk_states(x, b, a, delta, **shape)
+    with jax.named_scope(STATES):
+        final, starts = _over_chunks(g, own)
+    with jax.named_scope(OUTPUTS):
+        y = ssd_parts.chunk_outputs(x, b, c, delta, a, d, starts, **shape)
+        return y, starts, final.reshape(final.shape[0], heads,
+                                        *final.shape[-2:])
+
+
+def kernels_bwd(x, b, c, dt, a_log, dt_bias, d, starts, dy, **shape):
+    """`ssd_bwd` through the kernels: d x, d B and d C come in the inputs'
+    dtype, the rest float32."""
+    from . import ssd_parts
+
+    seq_len, heads, groups = shape["seq_len"], shape["heads"], shape["groups"]
+    rows, dy = x.shape[0] // seq_len, dy.astype(x.dtype)
+    with jax.named_scope(CHUNKS):
+        delta_c, delta, a, g = _by_head(dt, a_log, dt_bias, rows, seq_len,
+                                        heads, groups, shape["chunk"])
+        d_h0 = ssd_parts.chunk_states(dy, c, a, **shape)
+    with jax.named_scope(STATES):
+        # left[c]: the cotangent of the state chunk c + 1 starts from
+        _, left = _over_chunks(g, d_h0, reverse=True)
+    with jax.named_scope(OUTPUTS):
+        d_x, d_b, d_c, d_delta, d_a, d_d = ssd_parts.chunk_grads(
+            x, dy, b, c, delta, a, d, starts, left, **shape)
+        n, per = seq_len // shape["chunk"], heads // groups
+        return (d_x, d_b, d_c) + _steps_bwd(
+            ssd_parts.by_token(d_delta, rows, n, per),
+            ssd_parts.by_token(d_a, rows, n, per), delta_c, dt, a_log,
+            dt_bias, seq_len, shape["chunk"]) + (d_d,)

@@ -464,6 +464,139 @@ def test_a_scan_of_the_wrong_shape_is_refused():
             L.ssd_scan(x, bc, bc, dt, 16, 4, 8, 2, 16)
 
 
+# --------------------------------------------- the scan's kernel path
+KERNEL_SHAPE = dict(heads=4, head_dim=64, groups=2, state=128)
+GRADS = "x B C dt A_log dt_bias D".split()
+
+
+def _kernel_case(seq_len, dtype, rows=1, seed=2):
+    """`_scan_case` at the kernels' widths with steps the size a trained
+    mixer has (delta ~ 0.01, so a chunk's log-decay stays within tens: the
+    kernels form the running sum in another order than `jnp.cumsum`, and a
+    float32 sum of 128 steps near -500 is an ulp of 3e-5 either way)."""
+    args, _, dy = _scan_case(seq_len, rows=rows, seed=seed, **KERNEL_SHAPE)
+    args = tuple(a.astype(dtype) for a in args[:3]) + (
+        args[3], args[4], args[5] - 3.0, args[6])
+    return args, dict(KERNEL_SHAPE, seq_len=seq_len), dy.astype(dtype)
+
+
+def _both_passes(fwd, bwd, args, dy, **shape):
+    """((y, states, final state), the seven gradients) of one path."""
+    with jax.default_matmul_precision("highest"):
+        res = jax.jit(lambda *a: fwd(*a, **shape))(*args)
+        return res, jax.jit(lambda *a: bwd(*a, **shape))(*args, res[1], dy)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("seq_len,rows", [(256, 2), (512, 1)])
+def test_the_scan_s_kernels_interpreted_are_the_plain_form(seq_len, rows,
+                                                           dtype):
+    """`ssd.kernels_fwd` / `kernels_bwd` (the Pallas kernels of
+    `parallel/ssd_parts.py`, interpreted on the CPU) against the plain
+    chunked form on the same operands: y, `States`, `FinalState` and all
+    seven gradients, in chunks of 128 with 2 heads of 64 a group of state
+    128. Float32: the order of float32 sums; bf16: one ulp of the largest
+    element besides."""
+    from paddle_tpu.parallel import ssd
+
+    args, shape, dy = _kernel_case(seq_len, jnp.dtype(dtype), rows)
+    assert ssd.takes(rows, chunk=128, dtype=args[0].dtype, **shape)
+    want, want_grads = _both_passes(ssd.ssd_fwd, ssd.ssd_bwd, args, dy,
+                                    chunk=128, **shape)
+    got, grads = _both_passes(ssd.kernels_fwd, ssd.kernels_bwd, args, dy,
+                              chunk=128, **shape)
+    assert got[1].shape == ssd.states_shape(rows, chunk=128, **shape)
+    # bf16: y, d x, d B and d C leave the kernel path ROUNDED to bf16 (the
+    # plain form leaves the gradients float32 for the op to round), and x
+    # delta w is rounded before the state's product: a float32 sum in
+    # another order may land on the bf16 neighbour
+    rounded = 2.0 ** -7 if dtype == "bfloat16" else 0.0
+    _close(got[0], want[0], 1e-5 + rounded)
+    for g, w in zip(got[1:], want[1:]):
+        _close(g, w, 1e-5 + rounded / 64)
+    for name, g, w in zip(GRADS, grads, want_grads):
+        assert g.shape == w.shape, name
+        _close(g, w.astype(g.dtype),
+               1e-5 + (rounded if name in GRADS[:3] else rounded / 64)), name
+
+
+def _lowers_kernels(monkeypatch, on_tpu, seq_len, chunk):
+    """Whether `ssd_scan` and its grad op, traced for a place that is (not)
+    a TPU, hold a Pallas call."""
+    from paddle_tpu.ops import lm_ops
+
+    monkeypatch.setattr(lm_ops, "on_tpu", lambda: on_tpu)
+    attrs = dict(seq_len=seq_len, num_heads=4, head_dim=64, num_groups=2,
+                 state_size=128, chunk=chunk)
+
+    def zeros(*shape):
+        return [jnp.zeros(shape, jnp.float32)]
+
+    ins = dict(X=zeros(seq_len, 256), B=zeros(seq_len, 256),
+               C=zeros(seq_len, 256), Dt=zeros(seq_len, 4), ALog=zeros(4),
+               DtBias=zeros(4), D=zeros(4))
+    states = jax.eval_shape(
+        lambda i: lm_ops.ssd_scan_op(None, i, attrs), ins)["States"][0]
+    grad_ins = dict(ins, States=zeros(*states.shape),
+                    **{"Out@GRAD": zeros(seq_len, 256)})
+    found = ["pallas_call" in str(jax.make_jaxpr(
+        lambda i: op(None, i, attrs))(given))
+        for op, given in ((lm_ops.ssd_scan_op, ins),
+                          (lm_ops.ssd_scan_grad_op, grad_ins))]
+    assert found[0] == found[1]
+    return found[0]
+
+
+@pytest.mark.parametrize("what,on_tpu,seq_len,chunk,kernels", [
+    ("whole_chunks_on_a_tpu_place", True, 256, 128, True),
+    ("a_ragged_row", True, 200, 128, False),
+    ("a_chunk_of_64", True, 256, 64, False),
+    ("the_cpu_place", False, 256, 128, False)])
+def test_who_takes_the_scan_s_kernels_is_read_from_the_call(
+        monkeypatch, what, on_tpu, seq_len, chunk, kernels):
+    """One predicate (`ssd.takes` behind `lm_ops._ssd_kernels_take`): a TPU
+    place, rows of whole chunks of 128, lane-tile widths; a ragged row, a
+    chunk the kernels were not swept at and the CPU place lower the plain
+    form, forward and backward alike (`States` means the same on both)."""
+    assert _lowers_kernels(monkeypatch, on_tpu, seq_len, chunk) == kernels
+
+
+@pytest.mark.parametrize("plant", ["state_bf16", "decays_bf16",
+                                   "state_one_pass"])
+def test_every_plant_of_the_study_bites_on_the_ssd_kernel_path(plant):
+    """`chipbench/lower_precision_lm_ssd_share` lowers a precision by
+    wrapping `ssd.carried`, `ssd.steps`, `ssd.decay` and
+    `ssd.STATE_PRECISION` from outside: the kernel path looks each up as it
+    is traced (`ssd.decay` INSIDE the kernel bodies, the state's products
+    split into as many bf16 passes as `STATE_PRECISION` says), so y and d x
+    move under every plant at least as far as the plain form's do (on the
+    CPU the plain form's products ignore a pass count: there it moves
+    nothing, the kernels' written-out passes do)."""
+    from chipbench.lower_precision_lm_ssd_share import _planted
+    from paddle_tpu.parallel import ssd
+
+    args, shape, dy = _kernel_case(256, jnp.float32)
+    paths = {"plain": (ssd.ssd_fwd, ssd.ssd_bwd),
+             "kernels": (ssd.kernels_fwd, ssd.kernels_bwd)}
+
+    def readings():
+        return {k: _both_passes(*v, args, dy, chunk=128, **shape)
+                for k, v in paths.items()}
+
+    def moved(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2))
+
+    stated = readings()
+    with _planted(plant):
+        planted = readings()
+    by = {k: (moved(planted[k][0][0], stated[k][0][0]),
+              moved(planted[k][1][0], stated[k][1][0])) for k in paths}
+    for kernel, plain in zip(by["kernels"], by["plain"]):
+        assert kernel > 3e-5, by
+        assert kernel >= 0.9 * plain, by
+
+
 # ------------------------------------------------ short_conv with a bias
 def _conv_program(with_bias, x, seq_len):
     prog, startup = fluid.Program(), fluid.Program()

@@ -19,38 +19,55 @@ SHAPE = dict(seq_len=4096, heads=64, head_dim=64, groups=8, state=128,
 
 
 @pytest.mark.parametrize("kind", ["forward", "backward"])
-def test_the_scan_compiles_for_v5e(one_chip, no_compile_cache, kind):
+@pytest.mark.parametrize("form,low", [
+    ("plain", "bfloat16"), ("kernels", "bfloat16"), ("kernels", "float32")])
+def test_the_scan_compiles_for_v5e(one_chip, no_compile_cache, monkeypatch,
+                                   kind, form, low):
     """The chunked scan at the `nemotron_3_nano_30b_a3b` cell's shape, x
-    [4096, 64 heads of 64], B, C [4096, 8 groups of 128] bf16 in chunks of
-    128: plain `jax.numpy` (no custom call), ONE `while` (the scan over the
-    32 chunks), the state-reading products at three bf16 passes, no array
-    of [T, H, P, N] (a state a token) and a transient well under the
-    step's spare memory."""
+    [4096, 64 heads of 64], B, C [4096, 8 groups of 128] in chunks of 128.
+    THE KERNEL PATH (what a TPU place runs at this shape, bf16 as the step
+    has it and float32 at full matmul precision as the comparison's probe
+    has it): Mosaic takes `ssd_chunk_states` before the scan and
+    `ssd_chunk_outputs` (backward: `ssd_chunk_grads`) behind it, and no
+    array of [chunks, heads, Q, Q] is left. The plain form (a ragged row,
+    another chunk): no custom call. Both: ONE `while` (the scan over the
+    32 chunks), no array of [T, H, P, N] (a state a token) and a transient
+    well under the step's spare memory."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.parallel import ssd
+    from paddle_tpu.parallel import ssd, ssd_parts
 
+    monkeypatch.setattr(ssd_parts, "pallas_interpret", lambda: False)
     T, H, P, G, N = 4096, 64, 64, 8, 128
+    fwd, bwd = (ssd.ssd_fwd, ssd.ssd_bwd) if form == "plain" \
+        else (ssd.kernels_fwd, ssd.kernels_bwd)
 
-    def sds(*shape, dt="bfloat16"):
+    def sds(*shape, dt=low):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
 
     head = (sds(H, dt="float32"),) * 3
     ins = [sds(T, H * P), sds(T, G * N), sds(T, G * N), sds(T, H), *head]
-    if kind == "forward":
-        compiled = jax.jit(lambda *a: ssd.ssd_fwd(*a, **SHAPE)).lower(
-            *ins).compile()
-    else:
-        compiled = jax.jit(lambda *a: ssd.ssd_bwd(*a, **SHAPE)).lower(
-            *ins, sds(*ssd.states_shape(1, T, H, P, G, N, 128),
-                      dt="float32"), sds(T, H * P)).compile()
+    with jax.default_matmul_precision(
+            "highest" if low == "float32" else "default"):
+        if kind == "forward":
+            compiled = jax.jit(lambda *a: fwd(*a, **SHAPE)).lower(
+                *ins).compile()
+        else:
+            compiled = jax.jit(lambda *a: bwd(*a, **SHAPE)).lower(
+                *ins, sds(*ssd.states_shape(1, T, H, P, G, N, 128),
+                          dt="float32"), sds(T, H * P)).compile()
     text = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in text
+    behind = "ssd_chunk_outputs" if kind == "forward" else "ssd_chunk_grads"
+    assert base._custom_calls(text) == (
+        [] if form == "plain" else sorted(["ssd_chunk_states", behind]))
     assert len(re.findall(r"= .* while\(", text)) == 1
     shapes = {tuple(int(d) for d in dims.split(",") if d)
               for _, dims in base._ARRAY.findall(text)}
     assert not [s for s in shapes if len(s) >= 4 and s[-2:] == (P, N)
                 and T in s]
+    if form == "kernels":
+        assert not [s for s in shapes if len(s) >= 4 and s[-2:] == (128, 128)
+                    and H // G in s[:-2]]
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -135,10 +152,17 @@ def test_nemotron_step_runs_flash_at_groups_of_16_and_six_grouped_kernels(
     dK/dV and dQ, the kernels unchanged), the grouped kernels over the 8
     held groups at K 2688 / F 1856 as SIX a layer (`relu_sq`,
     `relu_sq_grad` among their scopes; no gate), the embedding's gradient
-    by the row-tile kernel, the scan as `while` loops under `mamba/scan/`
-    (forward and reverse, a mixer), the convolution with its bias as
+    by the row-tile kernel, the scan's in-chunk work as the Pallas kernels
+    of `parallel/ssd_parts.py` under `mamba/scan/ssd_scan*` either side of
+    `while` loops (forward and reverse, a mixer: `ssd_chunk_states` before
+    each, `ssd_chunk_outputs` / `ssd_chunk_grads` behind), no more device
+    memory than the step took with the plain form (PR 54's tree: arguments
+    8,642,229,760 + temporaries 5,536,899,072 + code 311,612,928 bytes), the convolution with its bias as
     shifted multiply-adds (no Pallas conv kernel, no XLA convolution), no
     [S, S] scores; and it fits the chip's 15.75 GB."""
+    from paddle_tpu.parallel import ssd_parts
+
+    monkeypatch.setattr(ssd_parts, "pallas_interpret", lambda: False)
     cfg, compiled = base._lm_step(
         one_chip, monkeypatch, "nemotron_3_nano_30b_a3b", 1,
         lambda built: [built["routing"][0][1].name]
@@ -149,6 +173,19 @@ def test_nemotron_step_runs_flash_at_groups_of_16_and_six_grouped_kernels(
         "flash_dkv", "flash_dq", "flash_fwd"]
     assert calls.count("row_tile_sum") >= 1
     assert not [c for c in calls if "conv" in c]
+    # a mixer: the chunk's own states and d h0 before the two scans, the
+    # outputs and the gradients behind them
+    assert [c for c in calls if c.startswith("ssd_")] == (
+        ["ssd_chunk_grads"] * 4 + ["ssd_chunk_outputs"] * 4
+        + ["ssd_chunk_states"] * 8)
+    under = {m.group(1) for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             for m in [re.search(r'op_name="[^"]*?(mamba/scan/[^"]*?)/'
+                                 r'ssd_chunk_', ln)] if m}
+    print("scan kernels under:", sorted(under))
+    assert under == {
+        "mamba/scan/ssd_scan/chunks", "mamba/scan/ssd_scan/outputs",
+        "mamba/scan/ssd_scan_grad/chunks", "mamba/scan/ssd_scan_grad/outputs"}
     grouped = [c for c in calls if c.startswith("grouped_matmul")]
     # a layer's two `cond`s hold the kernels a branch each: forward 2 + 2;
     # backward 4 from the saved products (the bounded rows) and 2 + 4 with
@@ -173,9 +210,15 @@ def test_nemotron_step_runs_flash_at_groups_of_16_and_six_grouped_kernels(
              and re.match(r"\s*%?\S+ = .* while\(", ln)]
     assert len(loops) == 8, loops
     mem = compiled.memory_analysis()
-    print("nemotron step memory:", mem.argument_size_in_bytes,
+    print("nemotron step memory:", mem.generated_code_size_in_bytes,
+          mem.argument_size_in_bytes,
           mem.temp_size_in_bytes, mem.output_size_in_bytes)
     # the file's `arithmetic`: 8.00 GB of weights and two moments + 0.64 GB
     # of kept bf16 copies of the expert weights
     assert 8.5e9 < mem.argument_size_in_bytes < 8.8e9
     assert mem.temp_size_in_bytes < 6.5e9, mem.temp_size_in_bytes
+    # what the step took with the scan in its plain form (PR 54's tree,
+    # this same compile): the [chunks, H, Q, Q] arrays were ~1 GB of it
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) \
+        <= 8_642_229_760 + 5_536_899_072 + 311_612_928
