@@ -12,7 +12,8 @@ file imports, not copies) two objects are set against the reference: (1)
 THE EXECUTABLE THE WINDOW TIMES, its losses of steps 0 and 1 against the
 reference's first step and its second after its own update (every trained
 weight by AdamW behind the global clip, each router's stand-in bias by the
-rule at its layer's speed); (2) a second build of the same program run for
+rule at its layer's speed ON THE CHOICES THE SYSTEM'S STEP MADE: PR 56,
+`reference_side`); (2) a second build of the same program run for
 ONE step with the gradients fetched, and its inference clone.
 
 Compared on one row of 8192 tokens:
@@ -34,8 +35,11 @@ Compared on one row of 8192 tokens:
 * routing of layers 1-3, each judged on the tokens every layer before it
   routed alike: the share flipped, and every exchanged expert within
   `ROUTING_MARGIN` logit-spreads of the reference's k-th (r + b);
-* logits per token over the tokens routed alike everywhere; the loss; the
-  global gradient norm and the clip's scale;
+* the inference program's logits per token, over EVERY token, against the
+  reference sent where that program sent its tokens (PR 56; until then
+  the plain reference's over the tokens routed alike everywhere, which
+  is still reported: `logits_plain_err_max_rms`); the loss; the global
+  gradient norm and the clip's scale;
 * gradient cosine, norm ratio and first AdamW update of a sampled
   parameter of each kind (`sampled_params`): the router of layer 0 and
   the embedding both hold the term that travels through `RouterInput`
@@ -61,6 +65,7 @@ import time
 
 import numpy as np
 
+from chipbench import held
 from chipbench.compare_lm import _cos_ratio, _rel
 from chipbench.compare_lm_share import _logits_errors as _errors_over
 from chipbench.compare_lm_window_share import _branch_errors, system_side
@@ -85,14 +90,34 @@ ROUTING_FLIP_MAX = 0.14
 # 0.043 | `router` 0.069 / 0.039, `all` 0.086 / 0.053; an exchanged expert
 # that was no neighbour of the k-th reads ~1
 ROUTING_MARGIN = 0.15
-# COARSE: stated 0.0131 max, 0.00782 rms | `all` 0.0181, 0.0126 / 0.0112,
-# 0.0097; `router_after_attention` inf (no token routed alike)
+# COARSE. Until PR 56 against the PLAIN reference over the tokens routed
+# alike everywhere: stated 0.0131 max, 0.00782 rms over PR 36's 14 seeds |
+# `all` 0.0181, 0.0126 / 0.0112, 0.0097; `router_after_attention` inf (no
+# token routed alike). THAT MAX HAS A TAIL NO LIMIT BOUNDS (my chip runs,
+# PR 56): 0.0325 on seed 601926867 (the driver's; not `correct` by this
+# number alone, twice), 0.0167 on 2030405060, 0.0094 / 0.0093 / 0.0082 on
+# 313 / 1811223344 / 90210: a token at the row's start attends to a
+# handful of keys, and where one of them was routed otherwise (position 0
+# on the first seed: positions 1, 3, 4, 5 read 0.0325 - 0.021; position 3
+# on the second) it is half or a third of what the token reads. SINCE PR
+# 56 AGAINST THE REFERENCE SENT WHERE THE INFERENCE PROGRAM WENT, OVER
+# EVERY TOKEN: 0.0081 / 0.0087 / 0.0074 / 0.0076 / 0.0085 max and 0.0067 /
+# 0.0075 / 0.0072 / 0.0065 / 0.0072 rms on those five seeds (the max within
+# 1.1 x the 99.9th percentile on each). The limits stand as they stood
+# (x3.4, x2.7): no plant is on record under either on this statistic
 LOGITS_TOL = 0.03
 LOGITS_RMS_TOL = 0.02
 # the accepted share comparisons' limit: stated 1.32e-4 (the one step),
 # 1.39e-4 (the timed scan's steps 0 and 1) | `all` 2.7e-4 / 2.2e-3,
 # `router_after_attention` 1.2e-3 / 2.9e-3; the timed scan's second loss
-# had the first step carried nothing: 4.4e-3 - 6.0e-3
+# had the first step carried nothing: 4.4e-3 - 6.0e-3. THE TIMED SCAN'S
+# STEP 1 read 5.39e-4 on seed 601926867 (my chip runs, PR 56): in layer 1
+# the loads of experts 4 and 31 lay 23 tokens from the mean, the
+# reference's own choices (5.2% of the tokens flipped) moved their biases
+# the other way than the system's, and step 1 was routed under two other
+# biases. With the biases moved on the system's step-0 choices
+# (`reference_side`'s `sent`): 9.0e-5
+# on that seed; where no sign differs the number is what it was
 LOSS_TOL = 6e-4
 # stated 1.24e-3 (2e-5 - 1.24e-3 over the 14) | `router` 3.6e-4 / 1.1e-2,
 # `all` 1.8e-3 / 1.6e-2, `router_after_attention` 1.9e-2 / 1.0e-2
@@ -247,10 +272,38 @@ def reference_without_the_router_s_path(cfg, builder, wj, tokens, labels):
     return np.asarray(grads[P + "embed"])
 
 
-def reference_side(cfg, builder, w0, tokens, labels, attention_inputs):
+def reference_logits_sent(cfg, builder, w, tokens, sent):
+    """The reference's logits [T, V] on `tokens`, every token SENT to the
+    experts `sent` names ([T, k] a layer: a system's own choice), weighed
+    by the reference's own router logits."""
+    import jax
+    import jax.numpy as jnp
+
+    ref = builder.reference
+    with jax.default_matmul_precision(ref.PRECISION):
+        logits, _ = jax.jit(lambda w_, t, g: ref.forward(cfg, w_, t, g))(
+            {k: jnp.asarray(v) for k, v in w.items()}, jnp.asarray(tokens),
+            [jnp.asarray(ids) for ids in sent])
+    return np.asarray(logits).reshape(tokens.size, -1)
+
+
+def reference_side(cfg, builder, w0, tokens, labels, attention_inputs,
+                   sent, sent_eval):
     """The plain reference on the same weights and rows, as numpy;
     `tokens` may hold the rows of a second step behind those of the first
-    (`cfg["reference"]["rows"]`): `reference_second_step`."""
+    (`cfg["reference"]["rows"]`): `reference_second_step`. It is given
+    the system's own choices ([T, k] a layer; PR 56, as the three other
+    share comparisons' reference is routed as the system routed): `sent`,
+    the training step's, are the choices the second step's router biases
+    are moved on (the rule is a sign of load - mean load: an expert whose load
+    lies a few tokens from the mean moves the OTHER way on the reference's
+    own choices, and every token of step 1 is then routed under another
+    bias; `routing` holds the choices and `router_bias` the rule);
+    `sent_eval`, the inference program's, are where a second forward pass
+    sends every token (`logits_sent`: what that program's logits are held
+    against, over EVERY token; a flipped token changes its neighbours'
+    logits through attention, at a row's start by a half or a third of a
+    key, which no mask over the flipped tokens themselves takes out)."""
     import jax.numpy as jnp
 
     ref, picks = builder.reference, builder.sampled_params(cfg)
@@ -270,8 +323,11 @@ def reference_side(cfg, builder, w0, tokens, labels, attention_inputs):
     del logits
     if len(then[0]):
         side["second_step"] = reference_second_step(
-            cfg, builder, wj, grads, routing, *then)
+            cfg, builder, wj, grads,
+            [(b, ids) for (b, _), ids in zip(routing, sent)], *then)
     del grads
+    side["logits_sent"] = reference_logits_sent(cfg, builder, wj, first[0],
+                                                sent_eval)
     side["embedding_grad_without_router_path"] = \
         reference_without_the_router_s_path(cfg, builder, wj, t0, l0)
     del wj
@@ -326,7 +382,16 @@ def judge(cfg, builder, got, ref, timed=None):
     picks = builder.sampled_params(cfg)
     route, _ = _routing_by_layer(got["ids"], ref["routing"])
     route_eval, same = _routing_by_layer(got["ids_eval"], ref["routing"])
-    main_max, main_rms = _logits_errors(got["logits"], ref["logits"], same)
+    # the inference program's logits against the PLAIN reference's, over
+    # the tokens it routed as that reference did everywhere: reported. A
+    # token that attends to few keys, a flipped token among them, reads
+    # high here (position 1 behind a flipped position 0: 0.0325 on seed
+    # 601926867 where the other 7,290 tokens' 99.9th percentile is 0.0153)
+    plain = _logits_errors(got["logits"], ref["logits"], same)
+    # ... and against the reference SENT where that program went, over
+    # every token: compared
+    main_max, main_rms = _errors_over(got["logits"], ref["logits_sent"],
+                                      np.ones(len(same), bool))
     first = cfg["deployment"]["first_expert"]
     held_n = cfg["moe_num_primary_experts"]
     n_all = cfg["deployment"]["moe_num_primary_experts"]
@@ -397,6 +462,17 @@ def judge(cfg, builder, got, ref, timed=None):
                  "second_loss_had_nothing_carried": unmoved}
         steps["err"] = [_rel(a, b) for a, b in steps["loss_timed_reference"]]
         steps["err_had_nothing_carried"] = _rel(unmoved, after)
+        # the experts a layer whose bias the reference's own free choices
+        # would move the other way than the system's step did
+        steps["experts_the_free_choices_move_otherwise"] = [
+            [int(e) for e in np.flatnonzero(
+                np.sign(a.mean() - a) != np.sign(b.mean() - b))]
+            for a, b in ((np.bincount(np.ravel(top), minlength=n_all)
+                          .astype(np.float64),
+                          np.bincount(ids.ravel(), minlength=n_all)
+                          .astype(np.float64))
+                         for (_, top), ids in zip(ref["routing"],
+                                                  got["ids"]))]
     early = [1.0 - r[0]["flipped_share"] for r in (route, route_eval)]
     report = {
         "attention_branch_err_max_rms": attention,
@@ -417,6 +493,11 @@ def judge(cfg, builder, got, ref, timed=None):
         "routing": route, "routing_inference": route_eval,
         "tokens_routed_alike_everywhere": float(same.mean()),
         "logits_err_max": main_max, "logits_err_rms": main_rms,
+        "logits_plain_err_max_rms": list(plain),
+        # the logits and the second timed step are read against the
+        # reference routed as the system routed (`reference_side`): a
+        # report from before PR 56 lacks the key (`limits_study`)
+        "reference_routed_as_the_system": True,
         "train_loss": [got["loss"], ref["loss"]],
         "train_loss_err": _rel(got["loss"], ref["loss"]),
         "global_grad_norm": [got["gnorm"], ref["gnorm"]],
@@ -437,41 +518,111 @@ def judge(cfg, builder, got, ref, timed=None):
                    "attention_rms": ATTENTION_RMS_TOL,
                    "norm_scale": NORM_SCALE_TOL},
     }
-    worst = {k: [f(v[k] for v in by_param.values() if v[k] is not None)
-                 for f in (min, max)]
-             for k in ("grad_cos", "grad_norm_ratio", "update_err")}
-    report["worst"] = worst
-    held = {
-        "attention": len(attention) == len(FIRST_HAND) and all(
-            np.isfinite(mx) and mx <= ATTENTION_TOL
-            and rms <= ATTENTION_RMS_TOL for mx, rms in attention.values())
-        and band[W] < min(band[W - 1], band[W + 1]),
-        "norms": len(inputs) == len(FIRST_HAND) and all(
-            np.isfinite(scale) and scale <= NORM_SCALE_TOL[i]
-            for i, (_, scale) in zip(FIRST_HAND, inputs.values())),
-        "early_route": min(early) >= EARLY_ROUTE_SAME_MIN,
-        "routing": all(
-            r["ok"] and r["flipped_share"] <= ROUTING_FLIP_MAX
-            for r in route[1:] + route_eval[1:]),
-        "logits": bool(np.isfinite(main_max) and main_max <= LOGITS_TOL
-                       and main_rms <= LOGITS_RMS_TOL),
-        "loss": report["train_loss_err"] <= LOSS_TOL,
-        "global_grad_norm": report["global_grad_norm_err"]
-        <= GLOBAL_NORM_TOL,
-        "clip_scale": report["clip_scale_err"] <= CLIP_SCALE_TOL,
-        "gradients": all(v["grad_ok"] for v in by_param.values()),
-        "update": worst["update_err"][1] <= UPDATE_TOL,
-        "product_rows": len(rows) == len(got["ids_eval"])
-        and all(w == h == c for w, h, c in rows),
-        "router_bias": len(bias_moved) == len(got["ids"])
-        and all(bias_moved),
-    }
-    if timed is not None:
-        held["timed_steps"] = len(steps.get("err", ())) == 2 and all(
-            np.isfinite(e) and e <= LOSS_TOL for e in steps["err"])
-    report["failed"] = sorted(k for k, v in held.items() if not v)
+    report["worst"] = {
+        k: [f(v[k] for v in by_param.values() if v[k] is not None)
+            for f in (min, max)]
+        for k in ("grad_cos", "grad_norm_ratio", "update_err")}
+    report["failed"] = verdict(report, timed is not None)
     report["ok"] = not report["failed"]
+    # every number `verdict` read beside its limit, the failing ones
+    # first: the harness prints these last, on standard error and in the
+    # result's line
+    report["compared"] = held.compared(numbers_held(report))
     return report
+
+
+def _grad_limits(key):
+    return GRAD_LIMITS.get("expert" if key.startswith("expert_") else key,
+                           GRAD_LIMITS_ELSE)
+
+
+def numbers_held(report, timed=False):
+    """{the number's name: (the reading of a `judge` report, its limit)} of
+    EVERY number `verdict` reads (the timed scan's wherever the report
+    holds them, whatever `timed` says: `verdict` picks the checks), each
+    entry reading `reading <= limit` (a share or a cosine as 1 - it, a norm
+    ratio as |ratio - 1|, an exact check as a count against 0). `verdict`
+    holds the readings THROUGH this table, the run prints it last
+    (`compared`, the failing ones first) and `chipbench.limits_study` lays
+    the part set again (`SET_AGAIN`) over the rows on record
+    (`chipbench/held.py`; PR 56)."""
+    attention = report["attention_branch_err_max_rms"]
+    band = report["window_branch_err_rms_by_reference_window"]
+    inputs = report["attention_input_err_rms_rowscale"]
+    # layer 0's choices are `early_route`'s: exact, not a near-tie's
+    routing = report["routing"][1:] + report["routing_inference"][1:]
+    moved = report["router_bias_moved_by_the_rule"]
+    steps = report.get("timed_steps") or {}
+    below, stated, above = (band[w] for w in sorted(band, key=int))
+    found = {
+        "ATTENTION_TOL": (max((mx for mx, _ in attention.values()),
+                              default=None), ATTENTION_TOL),
+        "ATTENTION_RMS_TOL": (max((rms for _, rms in attention.values()),
+                                  default=None), ATTENTION_RMS_TOL),
+        "ATTENTION first-hand branches not compared": (
+            abs(len(attention) - len(FIRST_HAND)), 0),
+        "ATTENTION a neighbour's band fits no worse than the stated": (
+            int(not stated < min(below, above)), 0),
+        **{f"NORM_SCALE_TOL[{i}]": (scale, NORM_SCALE_TOL[i])
+           for i, (_, scale) in zip(FIRST_HAND, inputs.values())},
+        "NORM_SCALE first-hand inputs not compared": (
+            abs(len(inputs) - len(FIRST_HAND)), 0),
+        "EARLY_ROUTE_SAME_MIN": (
+            1.0 - min(report["layer_0_choices_same_share_train_inference"]),
+            1.0 - EARLY_ROUTE_SAME_MIN),
+        "ROUTING_FLIP_MAX": (max((r["flipped_share"] for r in routing),
+                                 default=None), ROUTING_FLIP_MAX),
+        "ROUTING_MARGIN": (max((r["worst_gap_in_spreads"] for r in routing),
+                               default=None), ROUTING_MARGIN),
+        "ROUTING layers judged on no token": (
+            sum(not r["tokens"] for r in routing), 0),
+        "LOGITS_TOL": (report["logits_err_max"], LOGITS_TOL),
+        "LOGITS_RMS_TOL": (report["logits_err_rms"], LOGITS_RMS_TOL),
+        "LOSS_TOL": (report["train_loss_err"], LOSS_TOL),
+        "GLOBAL_NORM_TOL": (report["global_grad_norm_err"], GLOBAL_NORM_TOL),
+        "CLIP_SCALE_TOL": (report["clip_scale_err"], CLIP_SCALE_TOL),
+        "UPDATE_TOL": (max(v["update_err"]
+                           for v in report["by_param"].values()), UPDATE_TOL),
+        **held.product_rows(report),
+        "router_bias not moved by the rule": (
+            sum(not m for m in moved)
+            + abs(len(moved) - len(report["routing"])), 0),
+    }
+    found.update(held.gradients(report["by_param"], _grad_limits))
+    if steps:
+        found["TIMED LOSS_TOL"] = (
+            max(steps["err"]) if len(steps["err"]) == 2 else None, LOSS_TOL)
+    return found
+
+
+# which numbers each check holds, by the prefix of their names
+CHECKS = {"attention": ("ATTENTION",), "norms": ("NORM_SCALE",),
+          "early_route": ("EARLY_ROUTE",), "routing": ("ROUTING",),
+          "logits": ("LOGITS",), "loss": ("LOSS_TOL",),
+          "global_grad_norm": ("GLOBAL_NORM_TOL",),
+          "clip_scale": ("CLIP_SCALE_TOL",), "gradients": ("GRAD[",),
+          "update": ("UPDATE_TOL",), "product_rows": ("product_rows",),
+          "router_bias": ("router_bias",)}
+TIMED_CHECKS = {"timed_steps": ("TIMED",)}
+# the numbers whose STATISTIC PR 56 changed (the limits stand as they
+# stood): a report read against the plain reference says nothing of them
+# (`chipbench.limits_study`), and `limits_study table` lays these over the
+# rows on record
+FOLLOWS_ROUTING = ("LOGITS", "TIMED")
+SET_AGAIN = ("LOGITS_TOL", "LOGITS_RMS_TOL", "TIMED LOSS_TOL")
+
+
+def numbers_set_again(report):
+    return held.set_again(numbers_held(report), SET_AGAIN)
+
+
+def verdict(report, timed=False, without=()):
+    """Which checks the numbers of a `judge` report fail, by name: the
+    report's own numbers against THIS module's limits; `without`: name
+    prefixes of numbers a record does not hold."""
+    return held.failed_checks(
+        numbers_held(report),
+        dict(CHECKS, **(TIMED_CHECKS if timed else {})), without)
 
 
 def against_reference(fluid, cfg, builder, place, seed, tokens, labels,
@@ -488,7 +639,8 @@ def against_reference(fluid, cfg, builder, place, seed, tokens, labels,
                       labels[:rows])
     gc.collect()
     ref = reference_side(cfg, builder, got["w0"], tokens, labels,
-                         [u for u, _ in got["attention"]])
+                         [u for u, _ in got["attention"]],
+                         sent=got["ids"], sent_eval=got["ids_eval"])
     report = judge(cfg, builder, got, ref, timed)
     report["device_peak_bytes"] = int(memory_peak(jax.local_devices()))
     report["seconds"] = time.perf_counter() - t0

@@ -52,6 +52,21 @@ published id, and its limits were set one layer deep); this file takes its
 * each router's bias after the step: moved by the configuration's speed
   towards an even load of that step's own choices, exactly.
 
+SINCE PR 56 THE REFERENCE IS ROUTED AS THE SYSTEM ROUTED: its one pass
+(`reference_pass`) sends every token of every expert layer to the experts
+the system's TRAINING step chose (`loss_and_grads(routing=)`), weighs them
+by its own scores and returns its own free top-k beside. `routing` judges
+the system's choices against those free ones, as choices (the flipped
+share, every exchanged expert a neighbour of the threshold); the losses,
+the global norm, every gradient and the pooled mixers are then read over
+EVERY token on a reference that went where the system went, so a near-tie
+that fell the other way under bf16 is judged once and not again in every
+number behind it (a router's gradient is a sum over the few percent of a
+row its held experts see: a handful of such tokens moved its norm by
+percents, seed by seed, and refused the accepted program: PERF.md section
+6, PR 56); the inference program's logits are read over the tokens it sent
+where the training step sent them.
+
 The limits, each from two readings (PERF.md section 6 has the table with
 every number): the largest reading of the system as the configuration
 states it over the builder's seeds ("stated"), and the SYSTEM one
@@ -85,6 +100,7 @@ import time
 
 import numpy as np
 
+from chipbench import held
 from chipbench.compare_lm import (_clip_vars, _cos_ratio, _rel, _scalar,
                                   routing_report)
 from chipbench.harness import memory_peak
@@ -125,7 +141,20 @@ M = 1.4
 # cosine catch those), the four faults of the mixers' backward <= 0.0002.
 # A limit there could only fail sound runs. Its cosine stays held as every
 # other kind's (1 - cos <= 4.2e-4 against 3e-3)
-GRAD_LIMITS = {"router": (0.95, 0.10), "expert": (0.98, 0.04),
+# PR 56, THE ROUTER'S AND THE HELD EXPERT'S LIMITS SET AGAIN ON THE ROUTED
+# REFERENCE. Against the plain reference they read, as stated, 1 - cos up
+# to 0.0204 (the router) and 0.0039 (the expert), ratios up to 0.063 and
+# 0.018: the tokens on the other side of a near-tie, not the program. Sent
+# where the system went the same runs read (three seeds of the census,
+# `limits_study.json`, routed rows): the router 1 - cos <= 2.7e-4, ratio
+# <= 0.0052; the expert 1 - cos <= 1.4e-4, ratio <= 0.0034 | a bf16 router
+# (`lower_precision_lm_share --variants router`, seed 1906508178, on the
+# routed reference too) its own 1 - cos 0.540 and the expert's 0.194 -
+# 0.341 behind it (plain rows before: 0.2 - 0.7, 0.18; a cosine that far
+# never hung on a flip). Were (0.95, 0.10) and (0.98,
+# 0.04): each now stands about six times over the worst of the three (so
+# few seeds: not the 1.4 of a census of 24) and far under the plants
+GRAD_LIMITS = {"router": (0.998, 0.03), "expert": (0.999, 0.02),
                "w_qa": (0.997, None)}
 GRAD_LIMITS_ELSE = (0.997, 0.01)
 # THE MIXERS' `phi_res` AND `alpha` ARE JUDGED POOLED (PR 48). One sampled
@@ -218,13 +247,53 @@ def pooled_gradients(got, grads_ref):
     return found
 
 
-def numbers_set_again(report):
-    """{the limit's name: (the reading of a `judge` report it holds, the
-    limit)} of every limit PR 48 set again, a reading the report does not
-    hold None; `pooled_held` holds the pooled readings through this table
-    and `chipbench.limits_study` lays it over the rows on record (as
-    `compare_lm_delta_share.numbers_set_again`)."""
+def _grad_limits(key):
+    kind = "expert" if key.startswith("expert_") else key
+    return GRAD_LIMITS.get(kind, GRAD_LIMITS_ELSE)
+
+
+def numbers_held(report, timed=False):
+    """{the number's name: (the reading of a `judge` report, its limit)} of
+    EVERY number `verdict` reads (`timed`: as the sibling modules take it;
+    this kind holds no timed executable), each entry reading `reading <=
+    limit` (a cosine as 1 - cos, a norm ratio as |ratio - 1|, an exact
+    check as a count against 0); a reading the report does not hold is
+    None. `verdict` holds the readings THROUGH this table, the run prints
+    it last (`compared`, the failing ones first) and
+    `chipbench.limits_study` lays the part set again (`SET_AGAIN`) over the
+    rows on record: a limit and what it reads are spelt once
+    (`chipbench/held.py`)."""
     found = {}
+    routing = report.get("routing", []) + report.get("routing_inference", [])
+    if routing:
+        found["ROUTING_FLIP_MAX"] = (
+            max(r["flipped_share"] for r in routing), ROUTING_FLIP_MAX)
+        found["ROUTING_MARGIN"] = (max(r["worst_gap"] for r in routing),
+                                   ROUTING_MARGIN)
+        found["ROUTING layers not ok"] = (
+            sum(not r["ok"] for r in routing), 0)
+    for name, keys, limit in (
+            ("LOGITS_TOL", ("logits_err_max", "mtp_logits_err_max"),
+             LOGITS_TOL),
+            ("LOGITS_RMS_TOL", ("logits_err_rms", "mtp_logits_err_rms"),
+             LOGITS_RMS_TOL),
+            ("LOSS_TOL", ("train_loss_err", "cross_entropy_err",
+                          "mtp_cross_entropy_err"), LOSS_TOL),
+            ("GLOBAL_NORM_TOL", ("global_grad_norm_err",), GLOBAL_NORM_TOL),
+            ("CLIP_SCALE_TOL", ("clip_scale_err",), CLIP_SCALE_TOL),
+            ("MIXER_TOL", ("first_mixer_err",), MIXER_TOL),
+            ("SINKHORN_TOL", ("sinkhorn_column_err",), SINKHORN_TOL),
+            ("NORM_SCALE_TOL", ("first_norm_scale_err",), NORM_SCALE_TOL)):
+        if keys[0] in report:
+            found[name] = (max(report[k] for k in keys), limit)
+    by_param = report.get("by_param", {})
+    if any("update_err" in v for v in by_param.values()):
+        found["UPDATE_TOL"] = (max(v["update_err"]
+                                   for v in by_param.values()), UPDATE_TOL)
+    # a pooled kind's sampled mixer is reported, and judged with its kind
+    found.update(held.gradients(
+        {k: v for k, v in by_param.items() if k not in POOLED},
+        _grad_limits))
     for kind, (cos_min, ratio_tol) in POOLED_LIMITS.items():
         v = report.get(kind + "_pooled") or {}
         cos, ratio = v.get("grad_cos"), v.get("grad_norm_ratio")
@@ -232,26 +301,49 @@ def numbers_set_again(report):
             None if cos is None else 1.0 - cos, 1.0 - cos_min)
         found[f"POOLED_LIMITS[{kind}] ratio"] = (
             None if ratio is None else abs(ratio - 1.0), ratio_tol)
+    if "product_rows_written_held_chosen" in report:
+        found.update(held.product_rows(report))
+    if "router_bias_moved_by_the_rule" in report:
+        moved = report["router_bias_moved_by_the_rule"]
+        found["router_bias not moved by the rule"] = (
+            sum(not m for m in moved)
+            + abs(len(moved) - len(report["routing"])), 0)
     return found
 
 
-def pooled_held(report):
-    """{kind: whether the mixers' gradients of that kind, laid end to end,
-    lie within `POOLED_LIMITS`}; a kind the report does not hold is not
-    held."""
-    again = numbers_set_again(report)
-    return {kind: all(
-        again[f"POOLED_LIMITS[{kind}] {what}"][0] is not None
-        and again[f"POOLED_LIMITS[{kind}] {what}"][0]
-        <= again[f"POOLED_LIMITS[{kind}] {what}"][1]
-        for what in ("1 - cos", "ratio")) for kind in POOLED}
+# which numbers each check holds, by the prefix of their names
+CHECKS = {"routing": ("ROUTING",), "logits": ("LOGITS",),
+          "loss": ("LOSS_TOL",), "global_grad_norm": ("GLOBAL_NORM_TOL",),
+          "clip_scale": ("CLIP_SCALE_TOL",),
+          "gradients": ("GRAD[", "POOLED_LIMITS["),
+          "update": ("UPDATE_TOL",),
+          "mixers": ("MIXER_TOL", "SINKHORN_TOL"),
+          "norms": ("NORM_SCALE_TOL",), "product_rows": ("product_rows",),
+          "router_bias": ("router_bias",)}
+# the limits set again from rows on record (PR 48: the pooled mixers; PR
+# 56: what the routed reference let tighten, and what its census left
+# under M), which `limits_study table` and its test hold to M
+SET_AGAIN = ("POOLED_LIMITS[phi_res] 1 - cos", "POOLED_LIMITS[phi_res] ratio",
+             "POOLED_LIMITS[alpha] 1 - cos", "POOLED_LIMITS[alpha] ratio",
+             "GRAD[router] 1 - cos", "GRAD[router] ratio") + tuple(
+                 f"GRAD[expert_{m}] {what}" for m in ("gate", "up", "down")
+                 for what in ("1 - cos", "ratio"))
+# the numbers that read otherwise once the reference is routed as the
+# system routed: a row read against the plain reference says nothing of
+# their limits (`limits_study`)
+FOLLOWS_ROUTING = ("LOGITS", "LOSS_TOL", "GLOBAL_NORM_TOL", "GRAD[",
+                   "POOLED_LIMITS[", "ROUTING")
+
+
+def numbers_set_again(report):
+    return held.set_again(numbers_held(report), SET_AGAIN)
 
 
 def gradients_held(report):
-    """The check `gradients`: every sampled parameter within its kind's
-    limits and every pooled kind within its own."""
-    return all(_grad_held(k, v) for k, v in report["by_param"].items()) \
-        and all(pooled_held(report).values())
+    """The check `gradients` alone: every sampled parameter within its
+    kind's limits and every pooled kind within its own."""
+    return not held.failed_checks(
+        numbers_held(report), {"gradients": CHECKS["gradients"]})
 
 
 def system_side(fluid, cfg, builder, place, seed, tokens, labels):
@@ -322,19 +414,40 @@ def system_side(fluid, cfg, builder, place, seed, tokens, labels):
     return got
 
 
-def reference_side(cfg, builder, w0, tokens, labels):
-    """The plain reference on the same weights and row, as numpy."""
+def reference_pass(cfg, builder, w0, tokens, labels, sent=None):
+    """The reference's ONE pass over the row, as numpy: losses, logits, its
+    own free choices (`routing`), every gradient asked for. With `sent`
+    (the expert ids [T, k] the system's training step chose, an expert
+    layer) it is ROUTED AS THE SYSTEM ROUTED (`loss_and_grads(routing=)`);
+    None: the plain reference."""
     import jax.numpy as jnp
 
     ref, picks = builder.reference, builder.sampled_params(cfg)
     loss, (ce, ce_mtp, logits, mtp, routing), grads = ref.loss_and_grads(
         cfg, {k: jnp.asarray(v) for k, v in w0.items()},
-        jnp.asarray(tokens), jnp.asarray(labels))
+        jnp.asarray(tokens), jnp.asarray(labels),
+        routing=None if sent is None else [jnp.asarray(v) for v in sent])
     gnorm = float(jnp.sqrt(sum(jnp.sum(g * g) for g in grads.values())))
     T = tokens.size
-    # the first mixer and the norm after it, on the embedding itself
-    import jax
+    return dict(loss=float(loss), ce=float(ce), ce_mtp=float(ce_mtp),
+                gnorm=gnorm, sent=sent,
+                routing=[(np.asarray(b), np.asarray(t)) for b, t in routing],
+                logits=np.asarray(logits).reshape(T, -1),
+                mtp_logits=np.asarray(mtp).reshape(T, -1),
+                grads={k: np.asarray(grads[n]) for k, n in picks.items()},
+                grads_pooled={n: np.asarray(g) for n, g in grads.items()
+                              if n.endswith(tuple(POOLED.values()))})
 
+
+def reference_side(cfg, builder, w0, tokens, labels, sent=None):
+    """`reference_pass` and, first-hand, the first mixer and the norm after
+    it on the embedding itself."""
+    import jax
+    import jax.numpy as jnp
+
+    ref = builder.reference
+    side = reference_pass(cfg, builder, w0, tokens, labels, sent)
+    T = tokens.size
     p = "xing.l0.attn_"
     with jax.default_matmul_precision(ref.PRECISION):
         x0 = jnp.broadcast_to(
@@ -345,15 +458,19 @@ def reference_side(cfg, builder, w0, tokens, labels):
         pre, post, res = ref.mixers(x0, wj, p + "mhc_", cfg)
         normed = ref.rms_norm(jnp.einsum("tn,tnc->tc", pre, x0),
                               wj[p + "norm"], cfg["rms_norm_eps"])
-    return dict(loss=float(loss), ce=float(ce), ce_mtp=float(ce_mtp),
-                gnorm=gnorm,
-                routing=[(np.asarray(b), np.asarray(t)) for b, t in routing],
-                logits=np.asarray(logits).reshape(T, -1),
-                mtp_logits=np.asarray(mtp).reshape(T, -1),
-                first_mixer=[np.asarray(v) for v in (res, post, normed)],
-                grads={k: np.asarray(grads[n]) for k, n in picks.items()},
-                grads_pooled={n: np.asarray(g) for n, g in grads.items()
-                              if n.endswith(tuple(POOLED.values()))})
+    side["first_mixer"] = [np.asarray(v) for v in (res, post, normed)]
+    return side
+
+
+def reference_of(cfg, builder, got, tokens, labels, routed=True,
+                 whole=True):
+    """The reference for the system side `got`: sent where its training
+    step's experts went (`routed`) or plain; `whole`: with the first-hand
+    part, else the pass alone (one signature in the three share
+    comparisons: `chipbench.census` reads every seed both ways)."""
+    side = reference_side if whole else reference_pass
+    return side(cfg, builder, got["w0"], tokens, labels,
+                sent=got["ids"] if routed else None)
 
 
 def _logits_errors(got, ref, same):
@@ -364,20 +481,40 @@ def _logits_errors(got, ref, same):
                   / np.sqrt(np.mean(np.square(ref[same])))))
 
 
-def _routing_by_layer(ids, routing_ref):
-    """Each expert layer's report over the tokens that all earlier layers
-    routed as the reference did (a token routed otherwise upstream arrives
-    as another token: its choice here says nothing); and the tokens every
-    layer routed alike."""
+def sent_alike(ids, sent):
+    """The tokens [T] bool that `ids` sends, in every layer, to the experts
+    `sent` names (as sets): where a routed reference (`reference_side(
+    sent=)`) went the way of the program that chose `ids`."""
+    alike = np.ones(len(ids[0]), bool)
+    for a, b in zip(ids, sent):
+        alike &= (np.sort(a, axis=1) == np.sort(b, axis=1)).all(axis=1)
+    return alike
+
+
+def routing_by_layer(report_of, margin, ids, routing_ref, sent=None):
+    """Each expert layer's report (`report_of`: a `routing_report`) over
+    the tokens that all earlier layers sent where the reference went (a
+    token routed otherwise upstream arrives as another token: its choice
+    here says nothing); and the tokens every layer sent so. Where the
+    reference went: by its own free top-k (the plain reference), or, with
+    `sent`, where it was SENT (a reference routed as a system: `ids` of
+    that system's own step are then judged on every token of every layer,
+    another program's on the tokens it sent the same way so far)."""
     alike = np.ones(ids[0].shape[0], bool)
     reports = []
-    for ids_l, (biased, top) in zip(ids, routing_ref):
-        rep, same = routing_report(ids_l[alike], biased[alike], top[alike],
-                                   ROUTING_MARGIN)
+    for i, (ids_l, (chosen_by, top)) in enumerate(zip(ids, routing_ref)):
+        rep, same = report_of(ids_l[alike], chosen_by[alike], top[alike],
+                              margin)
         rep["tokens_alike_before"] = int(alike.sum())
         reports.append(rep)
-        alike[alike] = same
+        alike[alike] = same if sent is None else sent_alike(
+            [ids_l], [sent[i]])[alike]
     return reports, alike
+
+
+def _routing_by_layer(ids, routing_ref, sent=None):
+    return routing_by_layer(routing_report, ROUTING_MARGIN, ids,
+                            routing_ref, sent)
 
 
 def judge(cfg, builder, got, ref):
@@ -385,8 +522,11 @@ def judge(cfg, builder, got, ref):
     picks = builder.sampled_params(cfg)
     # the inference program and the training step are two compiled
     # programs: near-ties need not fall the same way in both
-    route, _ = _routing_by_layer(got["ids"], ref["routing"])
-    route_eval, same = _routing_by_layer(got["ids_eval"], ref["routing"])
+    # the reference went where the TRAINING step went (`sent`): the
+    # inference program's choices and logits compare where it went there too
+    route, _ = _routing_by_layer(got["ids"], ref["routing"], ref.get("sent"))
+    route_eval, same = _routing_by_layer(got["ids_eval"], ref["routing"],
+                                         ref.get("sent"))
     main_max, main_rms = _logits_errors(got["logits"], ref["logits"], same)
     mtp_max, mtp_rms = _logits_errors(got["mtp_logits"], ref["mtp_logits"],
                                       same)
@@ -445,6 +585,10 @@ def judge(cfg, builder, got, ref):
         "router_bias_moved_by_the_rule": bias_moved,
         "config": cfg["name"], "rows": int(cfg["reference"]["rows"]),
         "expert": first + expert, "reference": cfg["reference"]["file"],
+        "reference_routed_as_the_system": ref.get("sent") is not None,
+        # each layer's routing judged on the tokens sent, so far, where the
+        # reference went (`_routing_by_layer(sent=)`)
+        "routing_judged_where_sent": True,
         "routing": route, "routing_inference": route_eval,
         "tokens_routed_alike_everywhere": float(same.mean()),
         "logits_err_max": main_max, "logits_err_rms": main_rms,
@@ -480,56 +624,41 @@ def judge(cfg, builder, got, ref):
         for k in ("grad_cos", "grad_norm_ratio", "update_err")}
     report["failed"] = verdict(report)
     report["ok"] = not report["failed"]
-    # each number a limit of PR 48 holds beside that limit: the harness
-    # prints these last, on standard error and in the result's line
-    report["compared"] = {name: [reading, limit] for name, (reading, limit)
-                          in numbers_set_again(report).items()}
+    # every number `verdict` read beside its limit, the failing ones
+    # first: the harness prints these last, on standard error and in the
+    # result's line
+    report["compared"] = held.compared(numbers_held(report))
     return report
 
 
 def _grad_held(key, v):
-    kind = "expert" if key.startswith("expert_") else key
-    cos_min, ratio_tol = GRAD_LIMITS.get(kind, GRAD_LIMITS_ELSE)
-    return bool(kind in POOLED or (
+    """One sampled parameter's gradient within its kind's limits (a pooled
+    kind's sampled mixer is judged with its kind)."""
+    cos_min, ratio_tol = _grad_limits(key)
+    return bool(key in POOLED or (
         v["grad_cos"] is not None and v["grad_cos"] >= cos_min
         and (ratio_tol is None
              or abs(v["grad_norm_ratio"] - 1.0) <= ratio_tol)))
 
 
-def verdict(report):
-    """Which limits the numbers of a `judge` report fail, by name: the
+def pooled_held(report):
+    """{kind: whether the mixers' gradients of that kind, laid end to end,
+    lie within `POOLED_LIMITS`}; a kind the report does not hold is not
+    held."""
+    table = numbers_held(report)
+    return {kind: not any(held.fails(*table[f"POOLED_LIMITS[{kind}] {what}"])
+                          for what in ("1 - cos", "ratio"))
+            for kind in POOLED}
+
+
+def verdict(report, timed=False, without=()):
+    """Which checks the numbers of a `judge` report fail, by name: the
     report's own numbers against THIS module's limits, so that a study's
     saved reports can be judged again after a limit was set from them
-    (`chipbench/tests/test_limits_study.py`)."""
-    by_param = report["by_param"]
-    rows = report["product_rows_written_held_chosen"]
-    bias_moved = report["router_bias_moved_by_the_rule"]
-    logits = [report[k] for k in ("logits_err_max", "mtp_logits_err_max",
-                                  "logits_err_rms", "mtp_logits_err_rms")]
-    held = {
-        "routing": all(
-            r["ok"] and r["flipped_share"] <= ROUTING_FLIP_MAX
-            for r in report["routing"] + report["routing_inference"]),
-        "logits": bool(np.all(np.isfinite(logits))
-                       and max(logits[:2]) <= LOGITS_TOL
-                       and max(logits[2:]) <= LOGITS_RMS_TOL),
-        "loss": max(report["train_loss_err"], report["cross_entropy_err"],
-                    report["mtp_cross_entropy_err"]) <= LOSS_TOL,
-        "global_grad_norm": report["global_grad_norm_err"]
-        <= GLOBAL_NORM_TOL,
-        "clip_scale": report["clip_scale_err"] <= CLIP_SCALE_TOL,
-        "gradients": gradients_held(report),
-        "update": max(v["update_err"] for v in by_param.values())
-        <= UPDATE_TOL,
-        "mixers": report["first_mixer_err"] <= MIXER_TOL
-        and report["sinkhorn_column_err"] <= SINKHORN_TOL,
-        "norms": report["first_norm_scale_err"] <= NORM_SCALE_TOL,
-        "product_rows": len(rows) == len(report["routing_inference"])
-        and all(w == h == c for w, h, c in rows),
-        "router_bias": len(bias_moved) == len(report["routing"])
-        and all(bias_moved),
-    }
-    return sorted(k for k, v in held.items() if not v)
+    (`chipbench/tests/test_limits_study.py`); `timed`: as the sibling
+    modules take it (nothing here); `without`: name prefixes of numbers a
+    record does not hold."""
+    return held.failed_checks(numbers_held(report), CHECKS, without)
 
 
 def against_reference(fluid, cfg, builder, place, seed, tokens, labels):
@@ -542,7 +671,7 @@ def against_reference(fluid, cfg, builder, place, seed, tokens, labels):
 
     t0 = time.perf_counter()
     got = system_side(fluid, cfg, builder, place, seed, tokens, labels)
-    ref = reference_side(cfg, builder, got["w0"], tokens, labels)
+    ref = reference_of(cfg, builder, got, tokens, labels)
     report = judge(cfg, builder, got, ref)
     report["device_peak_bytes"] = int(memory_peak(jax.local_devices()))
     report["seconds"] = time.perf_counter() - t0

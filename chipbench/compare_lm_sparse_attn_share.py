@@ -22,7 +22,16 @@ the SELECTION is compared as a selection (the share of (t, s) choices the
 system and the reference agree on, and how far from the row's threshold,
 in spreads of the row's scores, the farthest disagreement lies), and
 EVERYTHING DOWNSTREAM OF A CHOICE IS COMPARED ON THE SYSTEM'S OWN CHOICE:
-the reference takes the system's masks (`selections`).
+the reference takes the system's masks (`selections`) and, SINCE PR 56, THE
+EXPERTS THE SYSTEM'S TRAINING STEP SENT EACH TOKEN TO (`routing`): it
+weighs them by its own scores and returns its own free top-k beside, which
+`routing` judges the system's choices against, as choices; the losses, the
+norm and every gradient are then read over every token on a reference that
+went where the system went (a router's gradient is a sum over the few
+percent of a row its held experts see: a handful of tokens on the other
+side of a bf16 near-tie moved the first router's norm by 2 - 7% and
+refused the accepted program on three seeds of seven: PERF.md section 6,
+PR 56).
 
 Compared on one row of 8192 tokens, of the first and of the last layer
 first-hand (`first_hand_layers`):
@@ -64,10 +73,11 @@ import time
 
 import numpy as np
 
+from chipbench import held
 from chipbench.compare_lm import _clip_vars, _cos_ratio, _rel, _scalar
 from chipbench.compare_lm_early_route_share import routing_report
 from chipbench.compare_lm_share import _logits_errors as _errors_over
-from chipbench.compare_lm_share import _products
+from chipbench.compare_lm_share import _products, routing_by_layer
 from chipbench.compare_lm_window_share import _branch_errors
 
 # READINGS, every one from a run on the chip at the cell's size (my chip
@@ -167,8 +177,25 @@ UPDATE_TOL = 0.03
 # 0.0035 / 220. The others: stated 0.99996 / 0.0041 | `triangle_softmax`
 # 0.99525 / 0.053 (W_q), `no_qk_norm` 0.98711 / 0.15, `indexer_reads_u`
 # 0.61216 / 0.66 (a norm scale)
-GRAD_LIMITS = {"router": (0.999, 0.015), "router_last": (0.992, 0.05),
-               "expert": (0.99, 0.12)}
+# PR 56, ON THE REFERENCE SENT WHERE THE SYSTEM'S EXPERTS WENT. The first
+# router's (0.999, 0.015) STANDS AS IT STOOD: against the plain reference
+# its ratio read 0.0714 and 0.0196 on PR 53's two refused seeds (1999000444,
+# 1853000777; 1 - cos 2.5e-3, 6.6e-4), against the routed one 0.0045 and
+# 0.0050 (1 - cos 2.4e-5), two more seeds 0.0003 and 0.0031: the limit has
+# 3 times of room over them, and the plant `indexer_reads_u` reads 0.2725
+# (1 - cos 0.0397) on the routed reference (seed 1906508178; 0.070 and
+# 0.0129 against the plain one at seed 11): 18 and 40 times over. The
+# last router's and the expert's, which no plant held from above (ratios
+# 0.05 and 0.12: "a scale, not a precision"; the router's cosine 1.2 times
+# under its plants), are set again from the routed rows: the last router 1 -
+# cos <= 1.2e-5, ratio <= 0.0037; the expert 1 - cos <= 2.0e-5, ratio <=
+# 0.0040 (three seeds) | `triangle_softmax` the expert's 1 - cos 0.0069,
+# `previous_selection` 0.026, the router's 0.0095 / 0.0107 (plain rows, PR
+# 49). Were (0.992, 0.05) and (0.99, 0.12): now some six times over the
+# worst of three seeds on the ratios, and the cosines ten times under the
+# plants that read 1.2 times over them or passed
+GRAD_LIMITS = {"router": (0.999, 0.015), "router_last": (0.999, 0.025),
+               "expert": (0.999, 0.03)}
 GRAD_LIMITS_INDEXER = (0.999, 0.03)
 GRAD_LIMITS_ELSE = (0.9995, 0.012)
 INDEXER_KEYS = ("w_qi", "w_ki", "ki_norm", "w_w")
@@ -379,10 +406,12 @@ def reference_second_step(cfg, builder, wj, grads, tokens, labels):
         return float(loss(w1, t, l)), float(loss(wj, t, l))
 
 
-def reference_side(cfg, builder, got, tokens, labels):
-    """The plain reference on the same weights and rows and ON THE
-    SYSTEM'S OWN CHOICES (the training step's masks), as numpy; `tokens`
-    may hold the rows of a second step behind those of the first."""
+def reference_pass(cfg, builder, got, tokens, labels, routed=True):
+    """The reference's pass over the rows ON THE SYSTEM'S OWN CHOICES (the
+    training step's masks and, with `routed`, the experts it sent each
+    token to: `loss_and_grads(routing=)`; the reference's own free top-k
+    beside, `routing`), as numpy; `tokens` may hold the rows of a second
+    step behind those of the first."""
     import jax.numpy as jnp
 
     ref, picks = builder.reference, builder.sampled_params(cfg)
@@ -392,10 +421,11 @@ def reference_side(cfg, builder, got, tokens, labels):
     wj = {k: jnp.asarray(v) for k, v in got["w0"].items()}
     t0, l0 = jnp.asarray(first[0]), jnp.asarray(first[1])
     loss, (logits, routing, ce, losses, _), grads = ref.loss_and_grads(
-        cfg, wj, t0, l0, [jnp.asarray(m) != 0 for m in got["masks"]])
+        cfg, wj, t0, l0, [jnp.asarray(m) != 0 for m in got["masks"]],
+        routing=[jnp.asarray(v) for v in got["ids"]] if routed else None)
     T = first[0].size
     side = dict(
-        loss=float(loss), ce=float(ce),
+        loss=float(loss), ce=float(ce), routed=bool(routed),
         indexer_loss=float(sum(losses)),
         gnorm=float(jnp.sqrt(sum(jnp.sum(g * g) for g in grads.values()))),
         routing=[(np.asarray(b), np.asarray(t)) for b, t in routing],
@@ -405,7 +435,14 @@ def reference_side(cfg, builder, got, tokens, labels):
     if len(then[0]):
         side["second_step"] = reference_second_step(cfg, builder, wj, grads,
                                                     *then)
-    del grads, wj
+    return side
+
+
+def reference_side(cfg, builder, got, tokens, labels, routed=True):
+    """`reference_pass` and the first-hand layers (`first_hand`)."""
+    import jax.numpy as jnp
+
+    side = reference_pass(cfg, builder, got, tokens, labels, routed)
     gc.collect()
     side["first_hand"] = first_hand(cfg, builder, {
         k: jnp.asarray(v) for k, v in got["w0"].items()
@@ -414,18 +451,20 @@ def reference_side(cfg, builder, got, tokens, labels):
     return side
 
 
-def _routing_by_layer(ids, routing_ref):
-    """Each layer's report over the tokens that all earlier layers routed
-    as the reference did, and the tokens every layer routed alike."""
-    alike = np.ones(ids[0].shape[0], bool)
-    reports = []
-    for ids_l, (chosen_by, top) in zip(ids, routing_ref):
-        rep, same = routing_report(ids_l[alike], chosen_by[alike],
-                                   top[alike], ROUTING_MARGIN)
-        rep["tokens_alike_before"] = int(alike.sum())
-        reports.append(rep)
-        alike[alike] = same
-    return reports, alike
+def reference_of(cfg, builder, got, tokens, labels, routed=True,
+                 whole=True):
+    """The reference for the system side `got`: on its masks, and sent
+    where its training step's experts went (`routed`) or routed by itself;
+    `whole`: with the first-hand layers, else the pass alone (one signature
+    in the three share comparisons: `chipbench.census` reads every seed
+    both ways)."""
+    side = reference_side if whole else reference_pass
+    return side(cfg, builder, got, tokens, labels, routed)
+
+
+def _routing_by_layer(ids, routing_ref, sent=None):
+    return routing_by_layer(routing_report, ROUTING_MARGIN, ids,
+                            routing_ref, sent)
 
 
 def _grad_limits(key):
@@ -462,8 +501,12 @@ def _timed_steps(got, ref, timed):
 def judge(cfg, builder, got, ref, timed=None):
     """The report: every number, the limits, which of them `failed`."""
     picks = builder.sampled_params(cfg)
-    route, same = _routing_by_layer(got["ids"], ref["routing"])
-    route_eval, _ = _routing_by_layer(got["ids_eval"], ref["routing"])
+    # the reference went where the TRAINING step went (`routed`): its
+    # logits compare on every token, the inference program's choices where
+    # it went there too
+    sent = got["ids"] if ref.get("routed") else None
+    route, same = _routing_by_layer(got["ids"], ref["routing"], sent)
+    route_eval, _ = _routing_by_layer(got["ids_eval"], ref["routing"], sent)
     main_max, main_rms = _logits_errors(got["logits"], ref["logits"], same)
     first = cfg["deployment"]["first_expert"]
     held_n, n_all = cfg["num_experts"], cfg["deployment"]["num_experts"]
@@ -511,6 +554,10 @@ def judge(cfg, builder, got, ref, timed=None):
         "product_rows_written_held_chosen": rows,
         "config": cfg["name"], "rows": int(cfg["reference"]["rows"]),
         "expert": first + expert, "reference": cfg["reference"]["file"],
+        "reference_routed_as_the_system": bool(ref.get("routed")),
+        # each layer's routing judged on the tokens sent, so far, where the
+        # reference went (`_routing_by_layer(sent=)`)
+        "routing_judged_where_sent": True,
         "routing": route, "routing_inference": route_eval,
         "tokens_routed_alike_everywhere": float(same.mean()),
         "logits_err_max": main_max, "logits_err_rms": main_rms,
@@ -529,17 +576,23 @@ def judge(cfg, builder, got, ref, timed=None):
     }
     report["failed"] = verdict(report, timed is not None)
     report["ok"] = not report["failed"]
-    report["compared"] = {name: [reading, limit] for name, (reading, limit)
-                          in numbers_held(report).items()}
+    # every number `verdict` read beside its limit, the failing ones
+    # first: the harness prints these last, on standard error and in the
+    # result's line
+    report["compared"] = held.compared(numbers_held(report))
     return report
 
 
-def numbers_held(report):
-    """{the limit's name: (the reading of a `judge` report it holds, the
-    limit)}: `verdict` holds each reading to its limit THROUGH this table,
-    and the harness prints it last, so a limit and what it reads are spelt
-    once. A floor is written as its negative (every entry reads `reading
-    <= limit`)."""
+def numbers_held(report, timed=False):
+    """{the number's name: (the reading of a `judge` report, its limit)} of
+    EVERY number `verdict` reads (the timed scan's wherever the report holds
+    them, whatever `timed` says: `verdict` picks the checks), each entry
+    reading `reading <= limit` (an agreement or a cosine as 1 - it, a norm
+    ratio as |ratio - 1|, an exact check as a count against 0). `verdict`
+    holds the readings THROUGH this table, the run prints it last
+    (`compared`, the failing ones first) and `chipbench.limits_study` lays
+    the part set again (`SET_AGAIN`) over the rows on record, so a limit
+    and what it reads are spelt once (`chipbench/held.py`)."""
     hand = report["first_hand"].values()
     routing = report["routing"] + report["routing_inference"]
     steps = report.get("timed_steps") or {}
@@ -548,13 +601,15 @@ def numbers_held(report):
     def worst(key, pick, of=max):
         return of(pick(v[key]) for v in hand)
 
-    held = {
+    found = {
         "THRESHOLD_RMS_TOL": (worst("threshold_rms", float),
                               THRESHOLD_RMS_TOL),
-        "SELECTION_AGREE_MIN": (-worst("selection", lambda s: s[0], min),
-                                -SELECTION_AGREE_MIN),
+        "SELECTION_AGREE_MIN": (1.0 - worst("selection", lambda s: s[0], min),
+                                1.0 - SELECTION_AGREE_MIN),
         "SELECTION_MARGIN": (worst("selection", lambda s: s[1]),
                              SELECTION_MARGIN),
+        "SELECTION rows not of min(t + 1, topk) causal keys": (
+            sum(not v["selection"][2] for v in hand), 0),
         "ATTENTION_TOL": (worst("branch", lambda b: b[0]), ATTENTION_TOL),
         "ATTENTION_RMS_TOL": (worst("branch", lambda b: b[1]),
                               ATTENTION_RMS_TOL),
@@ -566,6 +621,8 @@ def numbers_held(report):
                              ROUTING_FLIP_MAX),
         "ROUTING_MARGIN": (max(r["worst_gap_in_spreads"] for r in routing),
                            ROUTING_MARGIN),
+        "ROUTING layers judged on no token": (
+            sum(not r["tokens"] for r in routing), 0),
         "LOGITS_TOL": (report["logits_err_max"], LOGITS_TOL),
         "LOGITS_RMS_TOL": (report["logits_err_rms"], LOGITS_RMS_TOL),
         "LOSS_TOL": (max(report["train_loss_err"],
@@ -574,57 +631,56 @@ def numbers_held(report):
         "CLIP_SCALE_TOL": (report["clip_scale_err"], CLIP_SCALE_TOL),
         "UPDATE_TOL": (max(v["update_err"] for v in by_param.values()),
                        UPDATE_TOL),
+        "GRADIENT_SETS not disjoint or not whole": (
+            sum(not v for v in report["gradient_sets"].values()), 0),
+        **held.product_rows(report),
     }
+    found.update(held.gradients(by_param, _grad_limits))
     if steps:
-        held["LOSS_TOL_TIMED"] = (max(steps["err"]), LOSS_TOL)
-        held["TIMED_TWIN_TOL"] = (steps["err_second_build"][0],
-                                  TIMED_TWIN_TOL)
-        held["TIMED_TWIN_LAST_TOL"] = (steps.get("err_second_build_last"),
-                                       TIMED_TWIN_LAST_TOL)
-    return held
+        found["TIMED LOSS_TOL"] = (max(steps["err"]), LOSS_TOL)
+        found["TIMED_TWIN_TOL"] = (steps["err_second_build"][0],
+                                   TIMED_TWIN_TOL)
+        found["TIMED_TWIN_LAST_TOL"] = (steps.get("err_second_build_last"),
+                                        TIMED_TWIN_LAST_TOL)
+    return found
 
 
-def verdict(report, timed=False):
+# which numbers each check holds, by the prefix of their names
+CHECKS = {"indexer_scores": ("THRESHOLD_RMS_TOL",),
+          "selection": ("SELECTION",), "sparse_attention": ("ATTENTION_",),
+          "head_mean_probabilities": ("PROBS_RMS_TOL",),
+          "indexer_loss": ("INDEXER_LOSS_TOL",), "routing": ("ROUTING",),
+          "logits": ("LOGITS",), "loss": ("LOSS_TOL",),
+          "global_grad_norm": ("GLOBAL_NORM_TOL",),
+          "clip_scale": ("CLIP_SCALE_TOL",), "gradients": ("GRAD[",),
+          "gradient_sets_disjoint": ("GRADIENT_SETS",),
+          "update": ("UPDATE_TOL",), "product_rows": ("product_rows",)}
+TIMED_CHECKS = {"timed_steps": ("TIMED LOSS_TOL",),
+                "timed_steps_second_build": ("TIMED_TWIN",)}
+# the limits set again from rows on record (PR 56: what the routed
+# reference let tighten, and what its census left under M), which
+# `limits_study table` and its test hold to M
+SET_AGAIN = tuple(f"GRAD[{k}] {what}" for k in (
+    "router", "router_last", "expert_gate", "expert_up", "expert_down")
+    for what in ("1 - cos", "ratio"))
+# the numbers that read otherwise once the reference is routed as the
+# system routed: a row read against the plain reference says nothing of
+# their limits (`limits_study`)
+FOLLOWS_ROUTING = ("ROUTING", "LOGITS", "LOSS_TOL", "GLOBAL_NORM_TOL",
+                   "GRAD[", "INDEXER_LOSS_TOL", "TIMED LOSS_TOL")
+
+
+def numbers_set_again(report):
+    return held.set_again(numbers_held(report), SET_AGAIN)
+
+
+def verdict(report, timed=False, without=()):
     """Which checks the numbers of a `judge` report fail, by name: the
-    report's own numbers against THIS module's limits."""
-    again = numbers_held(report)
-    rows = report["product_rows_written_held_chosen"]
-
-    def within(*names):
-        return all(again[n][0] is not None and np.isfinite(again[n][0])
-                   and again[n][0] <= again[n][1] for n in names)
-
-    def grad_held(key, v):
-        cos_min, ratio_tol = _grad_limits(key)
-        return bool(v["grad_cos"] is not None and v["grad_cos"] >= cos_min
-                    and abs(v["grad_norm_ratio"] - 1.0) <= ratio_tol)
-
-    held = {
-        "indexer_scores": within("THRESHOLD_RMS_TOL"),
-        "selection": within("SELECTION_AGREE_MIN", "SELECTION_MARGIN")
-        and all(v["selection"][2] for v in report["first_hand"].values()),
-        "sparse_attention": within("ATTENTION_TOL", "ATTENTION_RMS_TOL"),
-        "head_mean_probabilities": within("PROBS_RMS_TOL"),
-        "indexer_loss": within("INDEXER_LOSS_TOL"),
-        "routing": all(r["tokens"] for r in report["routing"]
-                       + report["routing_inference"])
-        and within("ROUTING_MARGIN", "ROUTING_FLIP_MAX"),
-        "logits": within("LOGITS_TOL", "LOGITS_RMS_TOL"),
-        "loss": within("LOSS_TOL"),
-        "global_grad_norm": within("GLOBAL_NORM_TOL"),
-        "clip_scale": within("CLIP_SCALE_TOL"),
-        "gradients": all(grad_held(k, v)
-                         for k, v in report["by_param"].items()),
-        "gradient_sets_disjoint": all(report["gradient_sets"].values()),
-        "update": within("UPDATE_TOL"),
-        "product_rows": len(rows) == len(report["routing_inference"])
-        and all(w == h == c for w, h, c in rows),
-    }
-    if timed:
-        held["timed_steps"] = within("LOSS_TOL_TIMED")
-        held["timed_steps_second_build"] = within("TIMED_TWIN_TOL",
-                                                  "TIMED_TWIN_LAST_TOL")
-    return sorted(k for k, v in held.items() if not v)
+    report's own numbers against THIS module's limits; `without`: name
+    prefixes of numbers a record does not hold."""
+    return held.failed_checks(
+        numbers_held(report),
+        dict(CHECKS, **(TIMED_CHECKS if timed else {})), without)
 
 
 def against_reference(fluid, cfg, builder, place, seed, tokens, labels,
@@ -644,7 +700,7 @@ def against_reference(fluid, cfg, builder, place, seed, tokens, labels,
     got = system_side(fluid, cfg, builder, place, seed, tokens[:rows],
                       labels[:rows], then=(t_all[rows:], l_all[rows:]))
     gc.collect()
-    ref = reference_side(cfg, builder, got, tokens, labels)
+    ref = reference_of(cfg, builder, got, tokens, labels)
     report = judge(cfg, builder, got, ref, timed)
     report["device_peak_bytes"] = int(memory_peak(jax.local_devices()))
     report["seconds"] = time.perf_counter() - t0

@@ -22,6 +22,7 @@ gives it, so a later PR adds a cell by adding files and one entry:
 import contextlib
 import importlib.util
 import json
+import math
 import os
 import sys
 import threading
@@ -392,6 +393,19 @@ def memory_peak(devices):
     return int(max(peaks)) if peaks else 0
 
 
+def _strict(v):
+    """`v` with every float JSON has no word for (inf, nan: a reading of a
+    run that is not `correct`) as its name: the result's line is read by
+    strict parsers."""
+    if isinstance(v, float) and not math.isfinite(v):
+        return repr(v)
+    if isinstance(v, dict):
+        return {k: _strict(x) for k, x in v.items()}
+    if isinstance(v, (list, tuple)):
+        return [_strict(x) for x in v]
+    return v
+
+
 def run_cell(workload, seed, seconds, trace, t_start=None, rehearsal=False,
              override=None, files=None, dump=None, out=sys.stdout):
     """Runs the cell and prints its lines to `out`; returns the result
@@ -513,13 +527,39 @@ def run_cell(workload, seed, seconds, trace, t_start=None, rehearsal=False,
     # report pairs them (`compared`): the line's last key and the run's
     # last lines on standard error, which is what the driver keeps of a
     # run that is not `correct`
-    compared = (res.get("reference") or {}).get("compared")
+    report = res.get("reference") or {}
+    compared = report.get("compared")
+    if not compared and "failed" in report:
+        # a comparison that pairs no numbers yet (the olmoe, laguna and
+        # lfm2 cells'): the checks that failed by name, the report's own
+        # scalar readings and its limits, so that a refused run of theirs
+        # says more than `reference: false`
+        line["compared"] = {
+            "failed": list(report["failed"]),
+            "readings": {k: v for k, v in report.items()
+                         if isinstance(v, (int, float))
+                         and not isinstance(v, bool)},
+            "limits": report.get("limits", {})}
+        for name, value in line["compared"].items():
+            print(f"[chipbench compared] {name}: {json.dumps(value)}",
+                  file=sys.stderr, flush=True)
     if compared:
+        from chipbench import held
+
         line["compared"] = dict(
             compared, failed=list(res["reference"].get("failed", ())))
         for name, value in line["compared"].items():
             print(f"[chipbench compared] {name}: "
                   + (f"{value[0]} (limit {value[1]})" if name != "failed"
                      else json.dumps(value)), file=sys.stderr, flush=True)
+        # the numbers that fail once more, LAST: the driver keeps the end
+        # of standard error, and a comparison holds more numbers than that
+        # end has room for
+        for name, value in compared.items():
+            if held.fails(*value):
+                print(f"[chipbench compared] FAILS {name}: {value[0]} "
+                      f"(limit {value[1]})", file=sys.stderr, flush=True)
+    if "compared" in line:
+        line["compared"] = _strict(line["compared"])
     print(json.dumps(line), file=out, flush=True)
     return line

@@ -117,8 +117,9 @@ def main(argv=None):
                 inputs = [u for u, _ in got["attention"]]
                 if ref is None:
                     w0 = got["w0"]
-                    ref = compare.reference_side(cfg, builder, w0, tok, lab,
-                                                 inputs)
+                    ref = compare.reference_side(
+                        cfg, builder, w0, tok, lab, inputs, got["ids"],
+                        got["ids_eval"])
                 else:
                     # the first-hand check holds the branch, not its
                     # input: a variant's branches are set against the
@@ -129,6 +130,10 @@ def main(argv=None):
                         attention_band=compare.reference_band_neighbours(
                             cfg, builder, w0, tok, inputs))
                 assert all(np.array_equal(got["w0"][n], w0[n]) for n in w0)
+                # the logits are held against the reference sent where
+                # THIS variant's inference program went (PR 56)
+                ref = dict(ref, logits_sent=compare.reference_logits_sent(
+                    cfg, builder, w0, tok, got["ids_eval"]))
                 report = compare.judge(cfg, builder, got, ref)
                 line = json.dumps({"seed": seed, "variant": name,
                                    "ok": report["ok"],

@@ -229,13 +229,16 @@ def main(argv=None):
             for name in args.variants:
                 got = run_variant(name, fluid, cfg, builder, place, seed,
                                   tok, lab)
-                if ref is None:
-                    w0 = got["w0"]
-                    ref = compare_lm_share.reference_side(cfg, builder, w0,
-                                                          tok, lab)
-                    w0 = {n: w0[n]
-                          for n in builder.sampled_params(cfg).values()}
+                w0 = w0 or got["w0"]
                 assert all(np.array_equal(got["w0"][n], w0[n]) for n in w0)
+                if ref is None or not all(
+                        np.array_equal(a, b)
+                        for a, b in zip(got["ids"], ref["sent"])):
+                    # the reference goes where THAT system's experts went
+                    # (PR 56): formed again a variant, unless the variant
+                    # before sent every token the same way
+                    ref = compare_lm_share.reference_side(
+                        cfg, builder, w0, tok, lab, sent=got["ids"])
                 got["w0"] = w0
                 report = compare_lm_share.judge(cfg, builder, got, ref)
                 line = json.dumps({"seed": seed, "variant": name,

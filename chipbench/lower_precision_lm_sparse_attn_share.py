@@ -213,7 +213,8 @@ def _same_forward(got, other):
     """Whether two systems chose, and wrote into the first-hand layers, the
     same numbers: the reference side of one is then the other's."""
     return other is not None and all(
-        np.array_equal(a, b) for a, b in zip(got["masks"], other["masks"])) \
+        np.array_equal(a, b) for key in ("masks", "ids")
+        for a, b in zip(got[key], other[key])) \
         and all(np.array_equal(v, other["own"][i][k])
                 for i, own in got["own"].items() for k, v in own.items())
 
@@ -253,7 +254,8 @@ def main(argv=None):
                 if not _same_forward(got, before):
                     ref = compare.reference_side(cfg, builder, got, tok,
                                                  lab)
-                before = {"masks": got["masks"], "own": got["own"]}
+                before = {"masks": got["masks"], "own": got["own"],
+                          "ids": got["ids"]}
                 report = compare.judge(cfg, builder, got, ref)
                 line = json.dumps({"seed": seed, "variant": name,
                                    "ok": report["ok"],

@@ -327,30 +327,35 @@ def attention(u, w, p, cfg, chosen=None, detach_indexer=True,
 
 
 # ---------------------------------------------------------------- experts
-def route(u, w, p, cfg):
+def route(u, w, p, cfg, sent=None):
     """u [T, C] -> (scores p [T, E_all], what the choice is made by,
-    chosen experts [T, k], their weights [T, k])."""
+    chosen experts [T, k], their weights [T, k]). With `sent` [T, k] (a
+    system's own choice: `loss_and_grads(routing=)`) the weights are those
+    experts', by this function's own scores; the chosen experts returned
+    stay the free top-k."""
     k = cfg["num_experts_per_tok"]
     logits = u @ w[p + "router"]
     s = jax.nn.softmax(logits, axis=-1)
     # the choice by logits + b is the choice by softmax(logits + b); b = 0
     chosen_by = logits + jax.lax.stop_gradient(w[p + "expert_bias"])
     _, top_e = jax.lax.top_k(chosen_by, k)
-    top_s = jnp.take_along_axis(s, top_e, axis=1)
+    top_s = jnp.take_along_axis(s, top_e if sent is None else sent, axis=1)
     if cfg["norm_topk_prob"]:
         top_s = top_s / jnp.sum(top_s, axis=1, keepdims=True)
     return s, chosen_by, top_e, top_s
 
 
-def experts(u, w, p, cfg):
+def experts(u, w, p, cfg, sent=None):
     """u [T, C] (normed) -> (the held experts' part [T, C], (what chose
-    [T, E_all], chosen experts [T, k]))."""
+    [T, E_all], chosen experts [T, k])): the part of the free top-k, or
+    of `sent` [T, k] where that is given (the pair returned is the free
+    choice either way)."""
     E_all = cfg["deployment"]["num_experts"]
     first, held = cfg["deployment"]["first_expert"], cfg["num_experts"]
-    _, chosen_by, top_e, top_w = route(u, w, p, cfg)
+    _, chosen_by, top_e, top_w = route(u, w, p, cfg, sent)
     # DEPARTURE: dense over the held experts, masked by the router weights
-    weight = jnp.einsum("tk,tke->te", top_w,
-                        jax.nn.one_hot(top_e, E_all, dtype=top_w.dtype))
+    weight = jnp.einsum("tk,tke->te", top_w, jax.nn.one_hot(
+        top_e if sent is None else sent, E_all, dtype=top_w.dtype))
     weight = weight[:, first:first + held]
 
     def one(carry, e):
@@ -365,33 +370,38 @@ def experts(u, w, p, cfg):
 
 
 # ------------------------------------------------------------ whole model
-def layer(x, w, i, cfg, chosen=None, **detach):
+def layer(x, w, i, cfg, chosen=None, sent=None, **detach):
     """x [B, S, C] -> (x', the indexer's loss, (what chose, chosen), the
-    attention's choice)."""
+    attention's choice); `sent` [T, k]: the experts the layer's tokens are
+    sent to (`experts`)."""
     B, S, C = x.shape
     p, eps = f"{P}l{i}.", cfg["rms_norm_eps"]
     branch, loss, picked = attention(
         rms_norm(x, w[p + "attn_norm"], eps), w, p, cfg, chosen, **detach)
     x = x + branch
     u = rms_norm(x, w[p + "ffn_norm"], eps)
-    part, r = experts(u.reshape(B * S, C), w, p, cfg)
+    part, r = experts(u.reshape(B * S, C), w, p, cfg, sent)
     return x + part.reshape(B, S, C), loss, r, picked
 
 
-def forward(cfg, w, tokens, selections=None, **detach):
+def forward(cfg, w, tokens, selections=None, sent=None, **detach):
     """tokens [B, S] -> (logits [B, S, V], [the indexer's loss] a layer,
     [(what chose [T, E_all], chosen [T, k])] a layer, [the attention's
-    choice [B, S, S] bool] a layer)."""
+    choice [B, S, S] bool] a layer). `sent`: [chosen experts [T, k]] a
+    layer, which the layers then send their tokens to; the list returned
+    holds each layer's own free choice either way."""
     x = w[P + "embed"][tokens]
     losses, routing, choices = [], [], []
     for i in range(cfg["num_hidden_layers"]):
         given = None if selections is None else selections[i]
+        sent_i = None if sent is None else sent[i]
         # DEPARTURE: a layer's activations are computed again in the
         # backward (the same numbers; memory)
         x, loss, r, picked = jax.checkpoint(
-            lambda x_, w_, g_, i=i: layer(x_, w_, i, cfg, g_, **detach))(
+            lambda x_, w_, g_, s_, i=i: layer(x_, w_, i, cfg, g_, s_,
+                                              **detach))(
                 x, {k: v for k, v in w.items() if k.startswith(f"{P}l{i}.")},
-                given)
+                given, sent_i)
         losses.append(loss)
         routing.append(r)
         choices.append(picked)
@@ -401,12 +411,12 @@ def forward(cfg, w, tokens, selections=None, **detach):
 
 
 def loss_fn(cfg, w, tokens, labels, selections=None, parts=(1.0, 1.0),
-            **detach):
+            routing=None, **detach):
     """parts[0] x the mean cross-entropy of the next token + parts[1] x
     the sum over the layers of the indexer's loss. Returns (loss, (logits,
     routing, cross-entropy, [the indexer's loss] a layer, choices))."""
     logits, losses, routing, choices = forward(cfg, w, tokens, selections,
-                                               **detach)
+                                               routing, **detach)
     logp = jax.nn.log_softmax(logits, axis=-1)
     ce = jnp.mean(-jnp.take_along_axis(logp, labels[..., None],
                                        axis=-1)[..., 0])
@@ -415,13 +425,22 @@ def loss_fn(cfg, w, tokens, labels, selections=None, parts=(1.0, 1.0),
 
 
 def loss_and_grads(cfg, w, tokens, labels, selections=None,
-                   parts=(1.0, 1.0), **detach):
+                   parts=(1.0, 1.0), routing=None, **detach):
+    """`selections`: [the keys [B, S, S] bool a SYSTEM's indexers chose] a
+    layer; `routing`: [the expert ids [T, k] it chose] a layer. The
+    reference then attends where the system attended and sends every
+    token where the system sent it, weighs those experts by its own
+    scores, and still returns its own free choices beside: a near-tie
+    that fell the other way is judged once, as a choice, and not again in
+    every number behind it (the experts': PR 56). None: the reference's
+    own."""
     # tokens and labels are arguments, not constants of the compiled
     # program: another seed's row then finds it in the compile cache
     with jax.default_matmul_precision(PRECISION):
         (loss, rest), grads = jax.jit(jax.value_and_grad(
-            lambda w_, t, l, s: loss_fn(cfg, w_, t, l, s, parts, **detach),
-            has_aux=True))(w, tokens, labels, selections)
+            lambda w_, t, l, s, r: loss_fn(cfg, w_, t, l, s, parts, r,
+                                           **detach),
+            has_aux=True))(w, tokens, labels, selections, routing)
     return loss, rest, {k: g for k, g in grads.items() if trained(k)}
 
 

@@ -286,16 +286,20 @@ def operator(u, w, p, cfg, kind):
     return (delta_branch if kind == DELTA else attention)(u, w, p, cfg)
 
 
-def route(u, w, p, cfg):
+def route(u, w, p, cfg, chosen=None):
     """u [T, C] -> (scores p [T, E_all], what the choice is made by,
-    chosen experts [T, k], their weights [T, k])."""
+    chosen experts [T, k], their weights [T, k]). With `chosen` [T, k] (a
+    system's own choice: `loss_and_grads(routing=)`) the weights are those
+    experts', by this function's own scores; the chosen experts returned
+    stay the free top-k."""
     k = cfg["num_experts_per_tok"]
     logits = u @ w[p + "router"]
     s = jax.nn.softmax(logits, axis=-1)
     # the choice by logits + b is the choice by softmax(logits + b); b = 0
     chosen_by = logits + jax.lax.stop_gradient(w[p + "expert_bias"])
     _, top_e = jax.lax.top_k(chosen_by, k)
-    top_s = jnp.take_along_axis(s, top_e, axis=1)
+    top_s = jnp.take_along_axis(s, top_e if chosen is None else chosen,
+                                axis=1)
     if cfg["norm_topk_prob"]:
         top_s = top_s / jnp.sum(top_s, axis=1, keepdims=True)
     return s, chosen_by, top_e, top_s
@@ -306,16 +310,17 @@ def shared_expert(u, w, p):
     return jax.nn.sigmoid(u @ w[p + "shared_w"]) * (hid @ w[p + "shared_down"])
 
 
-def experts(u, w, p, cfg, shared=True):
+def experts(u, w, p, cfg, shared=True, chosen=None):
     """u [T, C] (normed) -> (the held experts' part plus (with `shared`)
     the shared expert [T, C], (what chose [T, E_all], chosen experts [T,
-    k]))."""
+    k])): the part of the free top-k, or of `chosen` [T, k] where that is
+    given (the pair returned is the free choice either way)."""
     E_all = cfg["deployment"]["num_experts"]
     first, held = cfg["deployment"]["first_expert"], cfg["num_experts"]
-    _, chosen_by, top_e, top_w = route(u, w, p, cfg)
+    _, chosen_by, top_e, top_w = route(u, w, p, cfg, chosen)
     # DEPARTURE: dense over the held experts, masked by the router weights
-    weight = jnp.einsum("tk,tke->te", top_w,
-                        jax.nn.one_hot(top_e, E_all, dtype=top_w.dtype))
+    weight = jnp.einsum("tk,tke->te", top_w, jax.nn.one_hot(
+        top_e if chosen is None else chosen, E_all, dtype=top_w.dtype))
     weight = weight[:, first:first + held]
 
     def one(carry, e):
@@ -331,50 +336,61 @@ def experts(u, w, p, cfg, shared=True):
     return part, (chosen_by, top_e)
 
 
-def layer(x, w, i, kind, cfg):
-    """x [B, S, C] -> (x', (what chose, chosen))."""
+def layer(x, w, i, kind, cfg, chosen=None):
+    """x [B, S, C] -> (x', (what chose, chosen)); `chosen` [T, k]: the
+    experts the layer's tokens are sent to (`experts`)."""
     B, S, C = x.shape
     p, eps = f"{P}l{i}.", cfg["rms_norm_eps"]
     x = x + operator(rms_norm(x, w[p + "operator_norm"], eps), w, p, cfg,
                      kind)
     u = rms_norm(x, w[p + "ffn_norm"], eps)
-    part, r = experts(u.reshape(B * S, C), w, p, cfg)
+    part, r = experts(u.reshape(B * S, C), w, p, cfg, chosen=chosen)
     return x + part.reshape(B, S, C), r
 
 
-def forward(cfg, w, tokens):
+def forward(cfg, w, tokens, given=None):
     """tokens [B, S] -> (logits [B, S, V], [(what chose [T, E_all], chosen
-    [T, k])] for each layer)."""
+    [T, k])] for each layer). `given`: [chosen experts [T, k]] a layer,
+    which the layers then send their tokens to; the list returned holds
+    each layer's own free choice either way."""
     x = w[P + "embed"][tokens]
     routing = []
     for i, kind in enumerate(layer_kinds(cfg)):
         # DEPARTURE: a layer's activations are computed again in the
         # backward (the same numbers; memory)
         x, r = jax.checkpoint(
-            lambda x_, w_, i=i, kind=kind: layer(x_, w_, i, kind, cfg))(
-                x, {k: v for k, v in w.items() if k.startswith(f"{P}l{i}.")})
+            lambda x_, w_, c_, i=i, kind=kind: layer(x_, w_, i, kind, cfg,
+                                                     c_))(
+                x, {k: v for k, v in w.items() if k.startswith(f"{P}l{i}.")},
+                None if given is None else given[i])
         routing.append(r)
     logits = rms_norm(x, w[P + "final_norm"],
                       cfg["rms_norm_eps"]) @ w[P + "head"]
     return logits, routing
 
 
-def loss_fn(cfg, w, tokens, labels):
+def loss_fn(cfg, w, tokens, labels, given=None):
     """Mean cross-entropy of the next token. Returns (loss, (logits,
     routing))."""
-    logits, routing = forward(cfg, w, tokens)
+    logits, routing = forward(cfg, w, tokens, given)
     logp = jax.nn.log_softmax(logits, axis=-1)
     ce = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(ce), (logits, routing)
 
 
-def loss_and_grads(cfg, w, tokens, labels):
+def loss_and_grads(cfg, w, tokens, labels, routing=None):
+    """`routing`: [the expert ids [T, k] a SYSTEM chose] a layer. The
+    reference then sends every token where the system sent it, weighs
+    those experts by its own scores, and still returns its own free top-k
+    beside: a near-tie that fell the other way is judged once, as a
+    choice, and not again in every number behind it (PR 56). None: the
+    plain reference."""
     # tokens and labels are arguments, not constants of the compiled
     # program: another seed's row then finds it in the compile cache
     with jax.default_matmul_precision(PRECISION):
         (loss, rest), grads = jax.jit(jax.value_and_grad(
-            lambda w_, t, l: loss_fn(cfg, w_, t, l),
-            has_aux=True))(w, tokens, labels)
+            lambda w_, t, l, r: loss_fn(cfg, w_, t, l, r),
+            has_aux=True))(w, tokens, labels, routing)
     return loss, rest, {k: g for k, g in grads.items() if trained(k)}
 
 

@@ -165,29 +165,37 @@ def attention(u, w, p, cfg, i):
     return jnp.concatenate(outs, axis=1).reshape(B, S, H * D) @ w[p + "w_o"]
 
 
-def route(x_in, w, p, cfg):
+def route(x_in, w, p, cfg, chosen=None):
     """x_in [T, C], the layer's INPUT -> (logits r [T, E], what the choice
     is made by (r + b) [T, E], chosen experts [T, k], their weights [T, k]:
-    the softmax of r over the chosen)."""
+    the softmax of r over the chosen). With `chosen` [T, k] (a system's
+    own choice: `forward(given=)`) the weights are those experts', by this
+    function's own logits; the chosen experts returned stay the free
+    top-k."""
     k = cfg["moe_num_active_primary_experts"]
     r = x_in @ w[p + "router"]
     biased = r + jax.lax.stop_gradient(w[p + "router_bias"])
     _, top_e = jax.lax.top_k(biased, k)
-    top_r = jnp.take_along_axis(r, top_e, axis=1)
+    top_r = jnp.take_along_axis(r, top_e if chosen is None else chosen,
+                                axis=1)
     return r, biased, top_e, jax.nn.softmax(top_r, axis=1)
 
 
-def experts(u, x_in, w, p, cfg):
+def experts(u, x_in, w, p, cfg, chosen=None):
     """u [T, C] (the normed state after attention), x_in [T, C] (the
     layer's input) -> (the held experts' part [T, C], (r + b [T, E],
-    chosen experts [T, k]))."""
+    chosen experts [T, k])): the part of the free top-k, or of `chosen`
+    [T, k] where that is given (the pair returned is the free choice
+    either way)."""
     E_all = cfg["deployment"]["moe_num_primary_experts"]
     first, held = (cfg["deployment"]["first_expert"],
                    cfg["moe_num_primary_experts"])
-    _, biased, top_e, top_w = route(x_in, w, p, cfg)
+    # (callers that stand in for `route` take its four first arguments)
+    _, biased, top_e, top_w = route(x_in, w, p, cfg) if chosen is None \
+        else route(x_in, w, p, cfg, chosen)
     # DEPARTURE: dense over the held experts, masked by the router weights
-    weight = jnp.einsum("tk,tke->te", top_w,
-                        jax.nn.one_hot(top_e, E_all, dtype=top_w.dtype))
+    weight = jnp.einsum("tk,tke->te", top_w, jax.nn.one_hot(
+        top_e if chosen is None else chosen, E_all, dtype=top_w.dtype))
     weight = weight[:, first:first + held]
 
     def one(carry, e):
@@ -201,49 +209,60 @@ def experts(u, x_in, w, p, cfg):
     return part, (biased, top_e)
 
 
-def layer(x, w, i, cfg):
-    """x [B, S, C] -> (x', (r + b, chosen))."""
+def layer(x, w, i, cfg, chosen=None):
+    """x [B, S, C] -> (x', (r + b, chosen)); `chosen` [T, k]: the experts
+    the layer's tokens are sent to (`experts`)."""
     B, S, C = x.shape
     p, eps = f"{P}l{i}.", cfg["rms_norm_eps"]
     x_in = x
     x = x + attention(rms_norm(x, w[p + "attn_norm"], eps), w, p, cfg, i)
     u = rms_norm(x, w[p + "ffn_norm"], eps)
-    part, r = experts(u.reshape(B * S, C), x_in.reshape(B * S, C), w, p, cfg)
+    part, r = experts(u.reshape(B * S, C), x_in.reshape(B * S, C), w, p, cfg,
+                      chosen)
     return x + part.reshape(B, S, C), r
 
 
-def forward(cfg, w, tokens):
+def forward(cfg, w, tokens, given=None):
     """tokens [B, S] -> (logits [B, S, V], [(r + b [T, E], chosen [T, k])]
-    for each layer)."""
+    for each layer). `given`: [chosen experts [T, k]] a layer, which the
+    layers then send their tokens to; the list returned holds each layer's
+    own free choice either way."""
     x = w[P + "embed"][tokens]
     routing = []
     for i in range(cfg["num_hidden_layers"]):
         # DEPARTURE: a layer's activations are computed again in the
         # backward (the same numbers; memory)
         x, r = jax.checkpoint(
-            lambda x_, w_, i=i: layer(x_, w_, i, cfg))(x, w)
+            lambda x_, w_, c_, i=i: layer(x_, w_, i, cfg, c_))(
+                x, w, None if given is None else given[i])
         routing.append(r)
     logits = rms_norm(x, w[P + "final_norm"],
                       cfg["rms_norm_eps"]) @ w[P + "head"]
     return logits, routing
 
 
-def loss_fn(cfg, w, tokens, labels):
+def loss_fn(cfg, w, tokens, labels, given=None):
     """Mean cross-entropy of the next token. Returns (loss, (logits,
     routing))."""
-    logits, routing = forward(cfg, w, tokens)
+    logits, routing = forward(cfg, w, tokens, given)
     logp = jax.nn.log_softmax(logits, axis=-1)
     ce = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(ce), (logits, routing)
 
 
-def loss_and_grads(cfg, w, tokens, labels):
+def loss_and_grads(cfg, w, tokens, labels, routing=None):
+    """`routing`: [the expert ids [T, k] a SYSTEM chose] a layer. The
+    reference then sends every token where the system sent it, weighs
+    those experts by its own logits, and still returns its own free top-k
+    beside: a near-tie that fell the other way is judged once, as a
+    choice, and not again in every number behind it (PR 56). None: the
+    plain reference."""
     # tokens and labels are arguments, not constants of the compiled
     # program: another seed's row then finds it in the compile cache
     with jax.default_matmul_precision(PRECISION):
         (loss, rest), grads = jax.jit(jax.value_and_grad(
-            lambda w_, t, l: loss_fn(cfg, w_, t, l),
-            has_aux=True))(w, tokens, labels)
+            lambda w_, t, l, r: loss_fn(cfg, w_, t, l, r),
+            has_aux=True))(w, tokens, labels, routing)
     return loss, rest, {k: g for k, g in grads.items() if trained(k)}
 
 

@@ -196,28 +196,34 @@ def swiglu(u, w, p):
     return (jax.nn.silu(u @ w[p + "gate"]) * (u @ w[p + "up"])) @ w[p + "down"]
 
 
-def route(u, w, p, cfg):
+def route(u, w, p, cfg, chosen=None):
     """u [T, C] -> (scores [T, E], the scores the choice is made by
-    (score + bias) [T, E], chosen experts [T, k], their weights [T, k])."""
+    (score + bias) [T, E], chosen experts [T, k], their weights [T, k]).
+    With `chosen` [T, k] (a system's own choice: `loss_and_grads(routing=)`)
+    the weights are those experts', by this function's own scores; the
+    chosen experts returned stay the free top-k."""
     k = cfg["num_experts_per_tok"]
     scores = jax.nn.sigmoid(u @ w[p + "router"])
     biased = scores + jax.lax.stop_gradient(w[p + "router_bias"])
     _, top_e = jax.lax.top_k(biased, k)
-    top_s = jnp.take_along_axis(scores, top_e, axis=1)
+    top_s = jnp.take_along_axis(scores, top_e if chosen is None else chosen,
+                                axis=1)
     if cfg["norm_topk_prob"]:
         top_s = top_s / (top_s.sum(axis=1, keepdims=True) + 1e-20)
     return scores, biased, top_e, top_s * cfg["routed_scaling_factor"]
 
 
-def experts(u, w, p, cfg):
+def experts(u, w, p, cfg, chosen=None):
     """u [T, C] (normed) -> (the held experts' part [T, C], the shared
-    expert [T, C], (biased scores [T, E], chosen experts [T, k]))."""
+    expert [T, C], (biased scores [T, E], chosen experts [T, k])): the
+    part of the free top-k, or of `chosen` [T, k] where that is given (the
+    pair returned is the free choice either way)."""
     E_all = cfg["deployment"]["n_routed_experts"]
     first, held = cfg["deployment"]["first_expert"], cfg["n_routed_experts"]
-    _, biased, top_e, top_w = route(u, w, p, cfg)
+    _, biased, top_e, top_w = route(u, w, p, cfg, chosen)
     # DEPARTURE: dense over the held experts, masked by the router weights
-    weight = jnp.einsum("tk,tke->te", top_w,
-                        jax.nn.one_hot(top_e, E_all, dtype=top_w.dtype))
+    weight = jnp.einsum("tk,tke->te", top_w, jax.nn.one_hot(
+        top_e if chosen is None else chosen, E_all, dtype=top_w.dtype))
     weight = weight[:, first:first + held]
     g = jnp.einsum("tc,ecf->tef", u, w[p + "gate"])
     a = jnp.einsum("tc,ecf->tef", u, w[p + "up"])
@@ -258,8 +264,9 @@ def around(x, w, prefix, cfg, sublayer):
             + post[..., None] * y[..., None, :])
 
 
-def layer(x, w, p, cfg, dense):
-    """x [B, S, n, C] -> (x', (biased scores, chosen) or None)."""
+def layer(x, w, p, cfg, dense, chosen=None):
+    """x [B, S, n, C] -> (x', (biased scores, chosen) or None); `chosen`
+    [T, k]: the experts the layer's tokens are sent to (`experts`)."""
     B, S = x.shape[:2]
     x = around(x, w, p + "attn_", cfg, lambda u: attention(u, w, p, cfg))
     if dense:
@@ -268,7 +275,7 @@ def layer(x, w, p, cfg, dense):
     routing = []
 
     def ffn(u):
-        part, shared, r = experts(u.reshape(B * S, -1), w, p, cfg)
+        part, shared, r = experts(u.reshape(B * S, -1), w, p, cfg, chosen)
         routing.append(r)
         return (part + shared).reshape(B, S, -1)
 
@@ -279,21 +286,24 @@ def _streams(h, n):
     return jnp.broadcast_to(h[..., None, :], h.shape[:-1] + (n, h.shape[-1]))
 
 
-def forward(cfg, w, tokens, next_tokens):
+def forward(cfg, w, tokens, next_tokens, given=None):
     """tokens, next_tokens [B, S] -> (logits [B, S, V], the module's
     logits or None, [(biased scores [T, E], chosen [T, k])] for each
-    expert layer, the module's last)."""
+    expert layer, the module's last). `given`: [chosen experts [T, k]] in
+    that order, which the layers then send their tokens to; the list
+    returned holds each layer's own free choice either way."""
     n, eps = cfg["hc_mult"], cfg["rms_norm_eps"]
     x = _streams(w["xing.embed"][tokens], n)
     routing = []
     for p, dense in _layer_names(cfg):
         if p == "xing.mtp.":
             break
+        chosen = None if given is None or dense else given[len(routing)]
         # DEPARTURE: a block's activations are computed again in the
         # backward (the same numbers; memory)
         x, r = jax.checkpoint(
-            lambda x_, w_, p=p, dense=dense: layer(x_, w_, p, cfg, dense))(
-                x, w)
+            lambda x_, w_, c_, p=p, dense=dense: layer(
+                x_, w_, p, cfg, dense, c_))(x, w, chosen)
         if r is not None:
             routing.append(r)
     h = x.sum(axis=-2)
@@ -305,8 +315,9 @@ def forward(cfg, w, tokens, next_tokens):
          rms_norm(w["xing.embed"][next_tokens], w["xing.mtp.e_norm"], eps)],
         -1)
     x, r = jax.checkpoint(
-        lambda x_, w_: layer(x_, w_, "xing.mtp.", cfg, False))(
-            _streams(joined @ w["xing.mtp.proj"], n), w)
+        lambda x_, w_, c_: layer(x_, w_, "xing.mtp.", cfg, False, c_))(
+            _streams(joined @ w["xing.mtp.proj"], n), w,
+            None if given is None else given[len(routing)])
     routing.append(r)
     mtp = rms_norm(x.sum(axis=-2), w["xing.mtp.final_norm"],
                    eps) @ w["xing.head"]
@@ -318,12 +329,12 @@ def _cross_entropy(logits, labels):
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
 
 
-def loss_fn(cfg, w, tokens, labels):
+def loss_fn(cfg, w, tokens, labels, given=None):
     """CE + mtp_coef * CE_mtp: `labels` the next tokens (which the module
     embeds), the module's labels the ones after, a row's last position
     left out. Returns (loss, (CE, CE_mtp, logits, module's logits,
     routing))."""
-    logits, mtp, routing = forward(cfg, w, tokens, labels)
+    logits, mtp, routing = forward(cfg, w, tokens, labels, given)
     ce = jnp.mean(_cross_entropy(logits, labels))
     if mtp is None:
         return ce, (ce, None, logits, None, routing)
@@ -332,13 +343,19 @@ def loss_fn(cfg, w, tokens, labels):
             (ce, ce_mtp, logits, mtp, routing))
 
 
-def loss_and_grads(cfg, w, tokens, labels):
+def loss_and_grads(cfg, w, tokens, labels, routing=None):
+    """`routing`: [the expert ids [T, k] a SYSTEM chose] for each expert
+    layer in `forward`'s order. The reference then sends every token where
+    the system sent it, weighs those experts by its own scores, and still
+    returns its own free top-k beside: a near-tie that fell the other way
+    is judged once, as a choice, and not again in every number behind it
+    (PR 56). None: the plain reference."""
     # tokens and labels are arguments, not constants of the compiled
     # program: another seed's row then finds it in the compile cache
     with jax.default_matmul_precision(PRECISION):
         (loss, rest), grads = jax.jit(jax.value_and_grad(
-            lambda w_, t, l: loss_fn(cfg, w_, t, l),
-            has_aux=True))(w, tokens, labels)
+            lambda w_, t, l, r: loss_fn(cfg, w_, t, l, r),
+            has_aux=True))(w, tokens, labels, routing)
     return loss, rest, {k: g for k, g in grads.items() if trained(k)}
 
 

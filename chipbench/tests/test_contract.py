@@ -26,7 +26,12 @@ TINY_OF = {
     "smallthinker_21b_a3b_train_packed8k": "test_early_route_cell.py",
     "lfm2_8b_a1b_train_packed8k": "test_short_conv_cell.py",
     "qwen3_next_80b_a3b_train_packed8k": "test_delta_cell.py",
+    "keye_vl_2_0_30b_a3b_train_packed8k": "test_sparse_attn_cell.py",
+    "nemotron_3_nano_30b_a3b_train_packed4k": "test_ssd_cell.py",
 }
+# the image cells, which `tiny.json` sizes
+TINY_JSON = ("resnet50_train_pipe", "se_resnext50_train_resident",
+             "resnet50_train_dp4", "resnet50_train_resident")
 BENCH = harness.Files().bench()
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
@@ -34,6 +39,11 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 def _override(cell):
     if cell in TINY_OF:
         return harness.load_module(os.path.join(HERE, TINY_OF[cell])).TINY
+    # a cell this file does not know would be built at its FULL size on
+    # the CPU (PR 49's and PR 54's cells were: 148 GB asked at once)
+    assert cell in TINY_JSON, (
+        f"{cell}: no rehearsal size on record; name the cell test's file "
+        "in TINY_OF (its `TINY` is the override)")
     with open(os.path.join(HERE, "tiny.json")) as f:
         return json.load(f)
 
@@ -63,14 +73,24 @@ def test_a_traced_line_holds_exactly_the_metrics_its_cell_lists(cell):
     real = check_line.problems(line, BENCH)
     assert sorted(p for p in real if p.startswith("metrics lacks ")) == [
         f"metrics lacks {n}" for n in sorted(line["metrics_missing"])]
-    # the two comparisons whose limits PR 48 set again say each number
-    # they held beside its limit, as the line's last key
-    if cell.startswith(("xing4_0_29b_a4b", "qwen3_next_80b_a3b")):
+    # the comparisons that pair their numbers with the limits (`compared`)
+    # say each number they held beside its limit, as the line's last key
+    if cell.startswith(("xing4_0_29b_a4b", "qwen3_next_80b_a3b",
+                        "keye_vl_2_0_30b_a3b", "nemotron_3_nano_30b_a3b",
+                        "smallthinker_21b_a3b")):
         assert list(line)[-1] == "compared"
         failed = line["compared"].pop("failed")
         assert bool(failed) != line["checks"]["reference"]
         assert all(len(pair) == 2 and pair[1] is not None
                    for pair in line["compared"].values())
+    elif cell.startswith(("olmoe_1b_7b", "laguna_xs_2", "lfm2_8b_a1b")):
+        # a comparison that pairs no numbers yet: the failing checks by
+        # name, its scalar readings and its limits
+        assert list(line)[-1] == "compared"
+        assert set(line["compared"]) == {"failed", "readings", "limits"}
+        assert bool(line["compared"]["failed"]) \
+            != line["checks"]["reference"]
+        assert line["compared"]["readings"] and line["compared"]["limits"]
     else:
         assert "compared" not in line
 

@@ -227,9 +227,9 @@ def test_benchmark_entries_of_the_cell():
     for name in ("delta_rule", "short_conv", "full_attention",
                  "grouped_matmul"):
         assert by_name[f"gdn.{name}_roofline"]["better"] == "higher"
-    # the driver's contract allows 128 entries; PR 48 made room: at least
-    # 24 are free for the next configuration's own metrics
-    assert len(bench["per_layer"]) <= 128 - 24
+    # the driver's contract allows 128 entries (how many are free is no
+    # property of this cell: later cells append their own)
+    assert len(bench["per_layer"]) <= 128
     # one entry a quantity where one reader serves all: two entries share
     # a base name and a `moves` only where one has a reader of its own
     seen = {}
@@ -257,8 +257,6 @@ def test_benchmark_entries_of_the_cell():
     assert entry["reduced"] == cfg["reduced"] == [
         "num_hidden_layers", "num_experts", "vocab_size"]
     assert all(len(e["why"]) <= 200 for e in (cell, entry))
-    # appended: the accepted entries stand before them, in their order
-    assert bench["workloads"][-1] is cell and bench["configs"][-1] is entry
 
 
 def test_configuration_file_states_the_share():

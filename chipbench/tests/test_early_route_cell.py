@@ -149,8 +149,9 @@ def test_the_comparison_fails_a_router_that_reads_the_normed_state():
     for name in ("stated", "router_after_attention"):
         got = study.run_variant(name, fluid, dict(cfg, amp="bfloat16"),
                                 builder, fluid.CPUPlace(), 3, tok, lab)
-        ref = compare.reference_side(cfg, builder, got["w0"], tok, lab,
-                                     [u for u, _ in got["attention"]])
+        ref = compare.reference_side(
+            cfg, builder, got["w0"], tok, lab,
+            [u for u, _ in got["attention"]], got["ids"], got["ids_eval"])
         reports[name] = compare.judge(cfg, builder, got, ref)
     assert "early_route" not in reports["stated"]["failed"]
     assert "early_route" in reports["router_after_attention"]["failed"]

@@ -191,3 +191,18 @@ def test_shipped_benchmark_file_names_files_that_exist():
     for m in bench["per_layer"]:
         assert files.metric_reader(m["name"]) is not None, m["name"]
         assert m["moves"] in e2e
+
+
+def test_the_result_line_s_compared_key_is_strict_json():
+    """A run that is not `correct` may hold a reading that is not finite
+    (no token routed alike: inf); the line's last key says it by name, so
+    a parser that refuses `Infinity` still reads the line."""
+    def refuse(word):
+        raise ValueError(word)
+
+    compared = {"LOGITS_TOL": [float("inf"), 0.03], "failed": ["logits"],
+                "readings": {"x": float("nan"), "y": 1.5}}
+    assert json.loads(json.dumps(harness._strict(compared)),
+                      parse_constant=refuse) == {
+        "LOGITS_TOL": ["inf", 0.03], "failed": ["logits"],
+        "readings": {"x": "nan", "y": 1.5}}
