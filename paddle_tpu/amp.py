@@ -49,8 +49,10 @@ WHITE_LIST = frozenset({
     "lstm", "gru", "lstm_unit", "gru_unit", "sequence_conv", "row_conv",
     "attention_lstm_decoder", "im2sequence",
     # the flash kernel and the grouped expert products take bf16 operands
-    # and accumulate in float32; rms_norm and rotary_embedding stay neutral
-    # (dtype-preserving, float32 inside, like layer_norm)
+    # and accumulate in float32; rms_norm, gated_rms_norm and
+    # rotary_embedding stay neutral (dtype-preserving, float32 inside, like
+    # layer_norm: inputs arrive as they are, a Scale stays the float32
+    # master)
     "causal_attention", "moe_ffn",
     # the gated short convolution between two `fc` products: bf16 in and
     # out, its gates and taps float32 inside (ops/lm_ops.py: short_conv)

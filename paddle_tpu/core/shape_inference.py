@@ -920,6 +920,35 @@ def _short_conv_grad(ctx):
             ctx.set_output_dim(slot + "@GRAD", d)
 
 
+@register_infer_shape("gated_rms_norm")
+def _gated_rms_norm(ctx):
+    x, gate = ctx.input_dim("X"), ctx.input_dim("Gate")
+    if x is None:
+        return
+    ctx.enforce(len(x) == 3, f"X must be [T, H, D], got {x}")
+    scale = ctx.input_dim("Scale")
+    if scale is not None:
+        ctx.enforce(len(scale) == 1 and _dim_match(scale[0], x[-1]),
+                    f"Scale{scale} must be [{x[-1]}], X's last dim")
+    if gate is not None:
+        width = x[1] * x[2] if min(x[1:]) > 0 else -1
+        ctx.enforce(
+            _dim_match(gate[0], x[0]) and (
+                (len(gate) == 2 and _dim_match(gate[1], width))
+                or (len(gate) == 3 and all(map(_dim_match, gate[1:],
+                                               x[1:])))),
+            f"Gate{gate} must be [T, H D] or [T, H, D] of X{x}")
+    ctx.set_output_dim("Y", x)
+
+
+@register_infer_shape("gated_rms_norm_grad")
+def _gated_rms_norm_grad(ctx):
+    for slot in ("X", "Gate", "Scale"):
+        d = ctx.input_dim(slot)
+        if d is not None:
+            ctx.set_output_dim(slot + "@GRAD", d)
+
+
 @register_infer_shape("gated_delta_rule")
 def _gated_delta_rule(ctx):
     x, ba = ctx.input_dim("QKV"), ctx.input_dim("BA")

@@ -19,7 +19,8 @@ __all__ = [
     "sequence_concat",
     "sequence_pool", "sequence_softmax", "softmax", "pool2d", "batch_norm",
     "layer_norm", "rms_norm", "rotary_embedding", "causal_attention",
-    "short_conv", "gated_delta_rule", "ssd_scan", "detached",
+    "short_conv", "gated_rms_norm", "gated_delta_rule", "ssd_scan",
+    "detached",
     "indexer_select",
     "sparse_attention", "indexer_loss", "moe_ffn", "mhc_expand", "mhc_mix", "mhc_update", "beam_search_decode", "conv2d_transpose", "sequence_expand",
     "beam_search", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
@@ -657,6 +658,25 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None,
         attrs["group_size"] = int(group_size)
     helper.append_op("rms_norm", {"X": [input], "Scale": [scale_p]},
                      {"Y": [y]}, attrs)
+    return y
+
+
+def gated_rms_norm(input, gate, epsilon=1e-05, param_attr=None, name=None):
+    """The gated output norm of a Gated DeltaNet layer as one op: `input`
+    [T, H, D] normed over its last axis with a learned scale [D] and no
+    bias, times silu(`gate`), the gate [T, H D] or [T, H, D] -> [T, H, D];
+    statistics, scale, SiLU and the products in float32 whatever the
+    inputs' dtype, rounded once on the output (ops/lm_ops.py:
+    gated_rms_norm, with a hand-written backward)."""
+    helper = LayerHelper("gated_rms_norm", **locals())
+    dtype = helper.input_dtype()
+    scale_p = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])], dtype=dtype,
+        default_initializer=Constant(1.0))
+    y = helper.create_tmp_variable(dtype, shape=input.shape)
+    helper.append_op("gated_rms_norm",
+                     {"X": [input], "Gate": [gate], "Scale": [scale_p]},
+                     {"Y": [y]}, {"epsilon": epsilon})
     return y
 
 

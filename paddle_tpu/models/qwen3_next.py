@@ -23,7 +23,8 @@ operator_norm):
             = -exp(A_log) softplus(a + dt_bias), and per value head
             S <- exp(g_t) S;  S <- S + beta_t k_t (v_t - S^T k_t)^T;
             o_t = S^T q_t, S = 0 at a row's first token
-            y = RMSNorm_dv(o; gated_norm [dv]) * silu(z);  x <- x + y W_o
+            y = RMSNorm_dv(o; gated_norm [dv]) * silu(z), float32 inside
+            and rounded once (`gated_rms_norm`);  x <- x + y W_o
     full:   [q | gate] = u W_qg;  k = u W_k, v = u W_v [T, Hkv, D]
             q, k <- RMSNorm over each head's D numbers (q_norm, k_norm),
             then rotary on the first `partial_rotary_factor` x D numbers;
@@ -141,9 +142,10 @@ def delta(u, cfg, seq_len, prefix):
                 initializer=_InverseSoftplusOfLogUniform(*DT_RANGE)),
             epsilon=L2_EPS)
     with fluid.name_scope("gated_norm"):
-        y = L.reshape(_norm(L.reshape(o, [-1, hv, dv]), cfg,
-                            prefix + "gated_norm"), [-1, hv * dv])
-        y = L.elementwise_mul(y, L.swish(z))
+        y = L.reshape(L.gated_rms_norm(
+            L.reshape(o, [-1, hv, dv]), z, epsilon=cfg["rms_norm_eps"],
+            param_attr=fluid.ParamAttr(name=prefix + "gated_norm")),
+            [-1, hv * dv])
     with fluid.name_scope("out_proj"):
         return _linear(y, cfg["hidden_size"], prefix + "w_o"), \
             (qkv, mixed, ba, o, last)

@@ -1,13 +1,14 @@
 """Ops of a decoder language model with sparse experts: rms_norm,
 rotary_embedding, causal_attention, indexer_select, sparse_attention,
-indexer_loss, short_conv, gated_delta_rule, moe_ffn.
+indexer_loss, short_conv, gated_rms_norm, gated_delta_rule, moe_ffn.
 
 No reference-framework counterpart (the reference predates them); the
 equations are those of OLMoE (Muennighoff et al., arXiv:2409.02060) as the
 `transformers` OlmoeDecoderLayer computes them. Gradients come from the
 generic vjp of core/registry.py, but for `causal_attention`, whose
-backward op takes the forward's output and logsumexp, `short_conv`, whose
-backward op reads the forward's inputs alone, and `moe_ffn`,
+backward op takes the forward's output and logsumexp, `short_conv` and
+`gated_rms_norm`, whose backward ops read the forward's inputs alone, and
+`moe_ffn`,
 whose backward op takes the forward's three grouped products (the generic
 vjp would run the Pallas kernels twice). `moe_ffn`'s token permutation has a
 custom_vjp so that both directions are row gathers (the transpose of a
@@ -661,6 +662,103 @@ def short_conv_grad_op(ctx, ins, attrs):
         grad = short_conv_grad
     d_x, d_w = grad(x, w, first(ins, "Out@GRAD"), seq_len)
     return out(**{"X@GRAD": d_x, "Filter@GRAD": d_w.astype(w.dtype)})
+
+
+# --------------------------------------------------------- gated_rms_norm
+_GATED_NORM_INPUTS = ("X", "Gate", "Scale")
+
+
+def _inverse_rms(xf, eps):
+    return lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+
+
+def gated_rms_norm(x, gate, w, eps):
+    """Y = X r w silu(gate) in X's shape and dtype, r = rsqrt(mean(X^2, last
+    axis) + eps): X [T, H, D], the gate [T, H D] or [T, H, D], w [D];
+    everything float32, one rounding."""
+    xf = x.astype(F32)
+    z = gate.astype(F32).reshape(x.shape)
+    return (xf * _inverse_rms(xf, eps) * w.astype(F32)
+            * (z * jax.nn.sigmoid(z))).astype(x.dtype)
+
+
+def gated_rms_norm_grad(x, gate, w, d_out, eps):
+    """(d X, d gate in their shapes and dtypes, d w [D] float32) of
+    `gated_rms_norm` from X, the gate, w and d Y alone: r is formed again.
+    With n = x r w, s = silu(z), g = d y s w: d z = d y n silu'(z), d x = r
+    g - x r^3 mean(g x), d w = the sum over tokens and heads of d y s x r."""
+    xf, wf = x.astype(F32), w.astype(F32)
+    z = gate.astype(F32).reshape(x.shape)
+    dy = d_out.astype(F32).reshape(x.shape)
+    r = _inverse_rms(xf, eps)
+    xr, sig = xf * r, jax.nn.sigmoid(z)
+    dys = dy * (z * sig)
+    g = dys * wf
+    d_z = dy * (xr * wf) * (sig * (1.0 + z * (1.0 - sig)))
+    d_x = r * (g - xr * jnp.mean(g * xr, axis=-1, keepdims=True))
+    d_w = jnp.sum((dys * xr).astype(jnp.float32).reshape(-1, x.shape[-1]),
+                  axis=0)
+    return (d_x.astype(x.dtype), d_z.reshape(gate.shape).astype(gate.dtype),
+            d_w)
+
+
+@register_op("gated_rms_norm")
+def gated_rms_norm_op(ctx, ins, attrs):
+    """The output norm of a Gated DeltaNet layer as ONE op: X [T, H, D] (the
+    delta rule's output a head), Gate [T, H D] or [T, H, D] (z of the input
+    projection), Scale [D] (a float32 master read as it is) -> Y = X /
+    sqrt(mean(X^2, last axis) + `epsilon`) * Scale * silu(Gate) in X's shape
+    and dtype; statistics, scale, SiLU and the products float32 whatever
+    arrives, ONE rounding on Y (the three ops `rms_norm`, `swish`,
+    `elementwise_mul` round twice more in between). On a TPU place the
+    Pallas kernel of parallel/gated_norm.py where it takes the shapes (X
+    and Gate read once, Y written); plain `jax.numpy` elsewhere."""
+    x, gate, w = (first(ins, s) for s in _GATED_NORM_INPUTS)
+    eps = float(attrs.get("epsilon", 1e-5))
+    if _gated_norm_kernels_take(x, gate):
+        from ..parallel.gated_norm import gated_norm_fwd
+
+        return out(Y=gated_norm_fwd(x, gate, w, eps))
+    return out(Y=gated_rms_norm(x, gate, w, eps))
+
+
+def _gated_norm_kernels_take(x, gate):
+    """Whether this trace hands `gated_rms_norm` to the Pallas kernels: a
+    TPU place, shapes they take, X and the gate of one dtype, and the op's
+    inner precision the stated one (a study one precision down runs the
+    plain form)."""
+    from ..parallel import gated_norm as kernels
+
+    return on_tpu() and F32 == jnp.float32 and gate.dtype == x.dtype \
+        and kernels.fits(x.shape, x.dtype)
+
+
+@register_grad_maker("gated_rms_norm")
+def _gated_rms_norm_grad_maker(op, gout, gin):
+    """Hand-written: the generic vjp keeps the float32 normed X and SiLU for
+    the backward; this one reads X, Gate, Scale and d Y."""
+    return [dict(
+        type="gated_rms_norm_grad",
+        inputs={**{s: op.input(s) for s in _GATED_NORM_INPUTS},
+                "Y@GRAD": [x or "" for x in gout.get("Y", [])]},
+        outputs={s + "@GRAD": gin.get(s, [""]) for s in _GATED_NORM_INPUTS},
+        attrs={k: v for k, v in op.attrs.items() if k != "op_role_var"})]
+
+
+@register_op("gated_rms_norm_grad")
+def gated_rms_norm_grad_op(ctx, ins, attrs):
+    """d X, d Gate (in their dtypes) and d Scale [D] of `gated_rms_norm`:
+    the backward kernel where the forward took its kernel, else the plain
+    form's."""
+    x, gate, w = (first(ins, s) for s in _GATED_NORM_INPUTS)
+    eps = float(attrs.get("epsilon", 1e-5))
+    if _gated_norm_kernels_take(x, gate):
+        from ..parallel.gated_norm import gated_norm_bwd as grad
+    else:
+        grad = gated_rms_norm_grad
+    d_x, d_z, d_w = grad(x, gate, w, first(ins, "Y@GRAD"), eps)
+    return out(**{"X@GRAD": d_x, "Gate@GRAD": d_z,
+                  "Scale@GRAD": d_w.astype(w.dtype)})
 
 
 # ------------------------------------------------------- gated_delta_rule
@@ -1689,6 +1787,20 @@ def _silu_kernel_takes(op, block):
     return kernels.silu_takes(tokens, x.shape[1], seq_len, w.shape[0], low)
 
 
+def _gated_norm_kernel_takes(op, block):
+    """Whether the Pallas kernels of `parallel/gated_norm.py` take this
+    `gated_rms_norm` (or its grad), from the shapes the program states
+    (tokens it leaves open, a batch dimension of -1, are taken to be whole
+    blocks)."""
+    from ..parallel import gated_norm
+
+    x, gate = (block.vars[op.input(s)[0]] for s in ("X", "Gate"))
+    tokens = x.shape[0] if x.shape[0] > 0 else gated_norm._BLOCKS[0]
+    low = amp.compute_dtype() if amp.is_enabled() else x.dtype
+    return gate.dtype == x.dtype and gated_norm.fits(
+        (tokens,) + tuple(x.shape[1:]), low)
+
+
 def _delta_kernel_takes(op, block):
     """Whether the Pallas kernels of `parallel/delta_parts.py` take this
     `gated_delta_rule` (or its grad), from the shapes the program states."""
@@ -1882,12 +1994,22 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
              lambda op, block: op.attrs.get("activation") == "relu2"),
             ("ssd_scan", "ssd_scan_kernel", True, _ssd_kernel_takes),
             ("ssd_scan_grad", "ssd_scan_grad_kernel", True,
-             _ssd_kernel_takes))
+             _ssd_kernel_takes),
+            ("gated_rms_norm", "gated_norm_one_op", False, None),
+            ("gated_rms_norm_grad", "gated_norm_grad_by_hand", False, None),
+            ("gated_rms_norm", "gated_norm_kernel", True,
+             _gated_norm_kernel_takes),
+            ("gated_rms_norm_grad", "gated_norm_grad_kernel", True,
+             _gated_norm_kernel_takes))
 
 
 def lowered_counts(program, device):
     """{counter: n} for the step spans and the registry (the newest first:
-    its `ssd_scan` ops, `ssd_scan_chunked`: the chunked form, one scan over
+    its `gated_rms_norm` ops, `gated_norm_one_op`, and their hand-written
+    grads, `gated_norm_grad_by_hand`; on a TPU place those whose shapes the
+    Pallas kernels of parallel/gated_norm.py take count as
+    `gated_norm_kernel` / `gated_norm_grad_kernel` too, the others are plain
+    `jax.numpy`; its `ssd_scan` ops, `ssd_scan_chunked`: the chunked form, one scan over
     the chunks, with the chunks they walk a step, static,
     `ssd_scan_chunks`, and their grads, `ssd_scan_grad_by_hand`; on a TPU
     place those whose in-chunk work the Pallas kernels of

@@ -24,6 +24,8 @@ _ELEM_WEIGHTS = {
     "dropout": 2.0, "cross_entropy": 5.0,
     "softmax_with_cross_entropy": 10.0, "sigmoid_cross_entropy_with_logits":
     8.0, "swish": 4.0, "gelu": 8.0, "elu": 3.0, "selu": 3.0,
+    # x^2 and its mean, x r w (4), a SiLU (4), the gate's product
+    "gated_rms_norm": 11.0,
 }
 
 

@@ -347,10 +347,12 @@ def test_lowered_counts_name_the_delta_rule_and_the_conv(small, place):
     want = {"moe_ffn_grouped": 4, "moe_ffn_held_experts": 4,
             "moe_ffn_row_bound": 4, "short_conv_silu": 3,
             "short_conv_silu_grad_by_hand": 3, "delta_rule_chunked": 3,
-            "delta_rule_grad_by_hand": 3}
+            "delta_rule_grad_by_hand": 3, "gated_norm_one_op": 3,
+            "gated_norm_grad_by_hand": 3}
     if place == "tpu":
         # 128 channels are a lane tile: the variant's kernels take them
-        # (heads of 16 in chunks of 8 are none: no `delta_rule_kernel`)
+        # (heads of 16 in chunks of 8 are none: no `delta_rule_kernel`, no
+        # `gated_norm_kernel`)
         want.update(flash_attention=1, flash_attention_bwd=1,
                     flash_attention_head_groups=1,
                     flash_fwd_visited_blocks=1, flash_fwd_masked_blocks=1,
@@ -367,7 +369,8 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
     grouped kernels at K 2048 / F 512, the convolution's silu variant by
     its two kernels ([8192, 8192] in blocks of 256 tokens) and the delta
     rule's in-chunk work by its two kernels (heads of 128 in chunks of
-    128: the counters are read under the lowering's real chunk)."""
+    128: the counters are read under the lowering's real chunk), the gated
+    norm as one op a layer by its two kernels ([8192, 32 heads of 128])."""
     from chipbench.configs import qwen3_next_80b_a3b as builder
     from paddle_tpu import amp
     from paddle_tpu.ops import lm_ops
@@ -386,6 +389,8 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
         amp.disable()
     assert "delta_rule_kernel" not in on_cpu
     assert on_cpu["delta_rule_chunked"] == 3
+    assert "gated_norm_kernel" not in on_cpu
+    assert on_cpu["gated_norm_one_op"] == 3
     assert got == dict(
         moe_ffn_grouped=4, grouped_matmul_kernel=4, grouped_mlp_epilogues=4,
         flash_attention=1, flash_attention_bwd=1,
@@ -396,6 +401,8 @@ def test_lowered_counts_at_the_published_widths_under_the_policy():
         short_conv_silu_kernel=3, short_conv_silu_grad_kernel=3,
         delta_rule_chunked=3, delta_rule_grad_by_hand=3,
         delta_rule_kernel=3, delta_rule_grad_kernel=3,
+        gated_norm_one_op=3, gated_norm_grad_by_hand=3,
+        gated_norm_kernel=3, gated_norm_grad_kernel=3,
         flash_fwd_visited_blocks=36, flash_fwd_masked_blocks=8)
 
 
@@ -415,6 +422,19 @@ def test_the_program_names_its_scopes(small):
     assert by_type["short_conv"] == {"delta/short_conv"}
     assert by_type["gated_delta_rule"] == {"delta/delta_rule"}
     assert by_type["gated_delta_rule_grad"] == {"delta/delta_rule"}
+    # the gated norm is ONE op a layer (and one grad op), between two
+    # reshapes: no `rms_norm`, `swish` or `elementwise_mul` of its own
+    assert by_type["gated_rms_norm"] == {"delta/gated_norm"}
+    assert by_type["gated_rms_norm_grad"] == {"delta/gated_norm"}
+    under = [op.type for op in prog.global_block().ops
+             if str(op.attrs.get("op_namescope", "")).strip("/")
+             == "delta/gated_norm"]
+    assert sorted(set(under) - {"reshape", "reshape_grad", "reshape2",
+                                "reshape2_grad"}) \
+        == ["gated_rms_norm", "gated_rms_norm_grad"]
+    assert under.count("gated_rms_norm") == 3
+    assert "delta/gated_norm" not in by_type["rms_norm"] \
+        | by_type.get("swish", set())
     assert by_type["causal_attention"] == {"attn"}
     assert by_type["moe_ffn"] == {"moe"}
     assert by_type["rotary_embedding"] == {"attn/rotary"}

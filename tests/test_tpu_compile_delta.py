@@ -91,6 +91,53 @@ def test_delta_parts_kernels_compile_for_v5e(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kind", ["forward", "backward"])
+def test_gated_norm_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                            monkeypatch, kind, dtype):
+    """Mosaic takes the gated norm's two kernels at the `qwen3_next_80b_a3b`
+    cell's shape, X [8192, 32 heads of 128] and the gate [8192, 4096] with a
+    float32 scale, in bf16 (the step) and in float32: one custom call each,
+    no temporary of an activation's size (the backward's d Scale leaves as
+    eight rows of partial sums). X arrives as the delta rule's output
+    product leaves o, [chunks, H, 128 tokens, D], turned token-major inside
+    the program as `delta_rule._tokens_first` does: XLA cancels that
+    transposition against the kernels' own, which read X head-major within
+    a block of that chunk; d X leaves token-major [T, H D], as the delta
+    rule's backward kernel takes it."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import delta_rule, gated_norm
+
+    monkeypatch.setattr(gated_norm, "pallas_interpret", lambda: False)
+    T, H, D = 8192, 32, 128
+    chunk = delta_rule.CHUNK
+    assert gated_norm.fits((T, H, D), dtype)
+    assert gated_norm._block(T, H * D, jnp.dtype(dtype).itemsize) == chunk
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def heads(o):       # [chunks, H, C, D] -> [T, H, D]
+        return jnp.moveaxis(o, 2, 1).reshape(T, H, D)
+
+    o, z, w = sds((T // chunk, H, chunk, D), dtype), \
+        sds((T, H * D), dtype), sds((D,), "float32")
+    if kind == "forward":
+        compiled = jax.jit(lambda o, z, w: gated_norm.gated_norm_fwd(
+            heads(o), z, w, 1e-6).reshape(T, H * D)).lower(o, z, w).compile()
+    else:
+        def backward(o, z, w, g):
+            d_x, d_z, d_w = gated_norm.gated_norm_bwd(
+                heads(o), z, w, g.reshape(T, H, D), 1e-6)
+            return d_x.reshape(T, H * D), d_z, d_w
+
+        compiled = jax.jit(backward).lower(o, z, w, z).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
 def test_qwen3_next_step_runs_the_delta_kernels_and_flash_at_256(
         one_chip, no_compile_cache, monkeypatch):
     """The `qwen3_next_80b_a3b` step at 1 x 8192 tokens (one period: three
@@ -103,8 +150,9 @@ def test_qwen3_next_step_runs_the_delta_kernels_and_flash_at_256(
     and once more in its backward) and `delta_parts_bwd`, its chunk scan as
     `while` loops under `delta/delta_rule/` (forward and reverse, a delta
     layer, over all heads at once: no loop over head groups), the
-    convolution's silu variant by its two kernels, no XLA convolution, no
-    [S, S] scores; and it fits the chip's 15.75 GB."""
+    convolution's silu variant by its two kernels, the gated norm by its
+    two (one op a layer: `gated_norm_fwd`, `gated_norm_bwd`), no XLA
+    convolution, no [S, S] scores; and it fits the chip's 15.75 GB."""
     cfg, compiled = base._lm_step(
         one_chip, monkeypatch, "qwen3_next_80b_a3b", 1,
         lambda built: [built["routing"][0][1].name]
@@ -118,6 +166,8 @@ def test_qwen3_next_step_runs_the_delta_kernels_and_flash_at_256(
         ["silu_conv_bwd"] * 3 + ["silu_conv_fwd"] * 3
     assert [c for c in calls if c.startswith("delta_parts")] == \
         ["delta_parts_bwd"] * 3 + ["delta_parts_fwd"] * 6
+    assert [c for c in calls if c.startswith("gated_norm")] == \
+        ["gated_norm_bwd"] * 3 + ["gated_norm_fwd"] * 3
     assert base.ragged_dots(text) == []
     assert "feature_group_count=8192" not in text
     S = cfg["sequence_length"]
