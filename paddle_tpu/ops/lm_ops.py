@@ -245,8 +245,9 @@ def causal_attention_grad_op(ctx, ins, attrs):
 # attention whose softmax runs over those keys alone; `indexer_loss` trains
 # the indexer towards the attention's own head-mean probabilities. The
 # lowerings are `parallel/sparse_index.py` (plain, a block of queries at a
-# time), `parallel/flash.py`'s kernels given the mask and, for the loss on
-# a TPU place, `parallel/index_loss.py`'s two kernels.
+# time), `parallel/flash.py`'s kernels given the mask and, on a TPU place,
+# `parallel/index_select.py`'s kernel for the selection's threshold and
+# mask and `parallel/index_loss.py`'s two kernels for the loss.
 def _rows_of(fn, *batched):
     """`fn` of one row of tokens over the leading (batch) axis, a row at a
     time: a row's temporaries are large."""
@@ -271,7 +272,11 @@ def indexer_select_op(ctx, ins, attrs):
     pays nothing for it). The scores are float32 from products on the
     operands' dtype; the selection is exact, by bisection on the scores'
     bits (`parallel/sparse_index.py`: no sort), a block of queries at a
-    time. Nothing is differentiated: the choice has no gradient."""
+    time; on a TPU place, for the shapes `index_select.takes`, a block's
+    threshold and mask are ONE Pallas kernel (`parallel/index_select.py`:
+    the keys resident in VMEM, only the causal key tiles counted), bit-equal
+    to the plain form. Nothing is differentiated: the choice has no
+    gradient."""
     from ..parallel import sparse_index
 
     topk = int(attrs["topk"])
@@ -1830,6 +1835,17 @@ def _index_loss_kernel_takes(op, block):
                             *q_i.shape[2:], low)
 
 
+def _index_select_kernel_takes(op, block):
+    """Whether the Pallas kernel of `parallel/index_select.py` takes the
+    blocks of queries `sparse_index.select` hands `select_rows` for this
+    `indexer_select`, from the shapes the program states."""
+    from ..parallel import index_select, sparse_index
+
+    S = block.vars[op.input("QI")[0]].shape[1]
+    return index_select.takes(sparse_index._blocked(S, sparse_index.BLOCK)[0],
+                              S, int(op.attrs["topk"]))
+
+
 def _sparse_grad_is_one_kernel(op, block):
     """Whether this `sparse_attention_grad` runs as ONE masked flash kernel
     (`flash.fused_backward_fits`, which the dispatch asks too), from the
@@ -2000,7 +2016,9 @@ _LOWERED = (("moe_ffn", "moe_ffn_grouped", False, None),
             ("gated_rms_norm", "gated_norm_kernel", True,
              _gated_norm_kernel_takes),
             ("gated_rms_norm_grad", "gated_norm_grad_kernel", True,
-             _gated_norm_kernel_takes))
+             _gated_norm_kernel_takes),
+            ("indexer_select", "indexer_select_kernel", True,
+             _index_select_kernel_takes))
 
 
 def lowered_counts(program, device):
@@ -2064,7 +2082,10 @@ def lowered_counts(program, device):
     place its `causal_attention` ops at heads of 256 or more
     (`flash_attention_head_256`). Its `indexer_select` ops
     (`indexer_select_bisection`: the exact top-k by bisection on the
-    scores' bits, on every place) with the (query, key) pairs they choose
+    scores' bits, on every place; on a TPU place those whose blocks of
+    queries the Pallas kernel of parallel/index_select.py takes count as
+    `indexer_select_kernel` too, the others count in XLA passes over the
+    whole block) with the (query, key) pairs they choose
     and the causal pairs they choose among a step, static
     (`sparse_attention_selected_pairs`, `sparse_attention_causal_pairs`),
     its `sparse_attention` ops (`sparse_attention_plain`, each; on a TPU

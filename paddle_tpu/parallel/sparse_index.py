@@ -22,7 +22,9 @@ positions by one running count. (`lax.top_k` and `lax.sort` over [8192,
 8192] compile for seconds an operand on the TPU and run as XLA's sort;
 `lax.approx_max_k` is another function.) The result is a mask [S, S] int8,
 the causal triangle included: what `parallel/flash.py`'s kernels read as
-`mask`.
+`mask`. (On a TPU place a block's threshold and mask are ONE Pallas kernel,
+`parallel/index_select.py`, which `select_rows` hands the block's scores:
+the same bisection with the keys in VMEM, bit-equal.)
 
 `loss_and_grads` returns the loss AND its gradient with respect to q_I,
 k_I and w in one pass over the blocks (the loss is a scalar: its
@@ -78,7 +80,15 @@ def select_rows(I, first, topk):
     ([n, S] bool: the causal keys while there are no more than `topk`, else
     the `topk` causal keys of largest score, ties to the lower position;
     the THRESHOLD [n] float32: the least score chosen, as the bisection
-    found it)."""
+    found it). On a TPU place, for the shapes `index_select.takes`, ONE
+    Pallas kernel that keeps a chunk's keys in VMEM and counts only its
+    causal key tiles (`parallel/index_select.py`), bit-equal to this."""
+    from . import index_select
+
+    if not index_select.pallas_interpret() \
+            and index_select.takes(*I.shape, topk):
+        mask, tau = index_select.select_rows(I, first, topk)
+        return mask != 0, tau
     n, S = I.shape
     t = first + jnp.arange(n)
     causal = jnp.arange(S)[None, :] <= t[:, None]

@@ -793,6 +793,192 @@ def test_indexer_loss_takes_the_kernels_on_a_tpu_place_alone(monkeypatch):
     assert len(calls) == 1                        # never off a TPU place
 
 
+def _select_case_scores(name, rng, n, S):
+    I = rng.normal(size=(n, S))
+    if name == "keys_in_identical_pairs":
+        I = np.repeat(I[:, ::2], 2, axis=1)
+    elif name == "a_row_of_equal_scores":
+        I[n - 3] = 0.25
+    elif name == "signed_zeros_are_one_score":
+        I = np.where(rng.random((n, S)) < 0.5, 0.0, -0.0) \
+            * np.where(rng.random((n, S)) < 0.9, 1.0, np.nan)
+        I = np.where(np.isnan(I), rng.normal(size=(n, S)), I)
+    elif name == "a_negative_threshold":
+        I = -1.0 - np.abs(I)
+    elif name == "ties_across_a_key_tile":
+        # row t's keys 120 .. 135 alike and above every other key but 40:
+        # with topk 48 a row keeps the first 8 of the 16, up to key 127
+        I = -1.0 - np.abs(I)
+        I[:, :40] = 5.0 + np.abs(I[:, :40])
+        I[:, 120:136] = 2.0
+    elif name == "one_row_needs_the_running_count":
+        I[n - 40, 7:90] = 0.5 * np.float32(I[n - 40]).max()
+    elif name in ("ties_everywhere", "ties_by_the_mxu_s_sum",
+                  "ties_by_a_sum_down_the_sublanes"):
+        # on a grid of halves: every row has keys at its threshold
+        I = np.round(I * 2) / 2
+    return jnp.asarray(I, jnp.float32)
+
+
+INDEX_SELECT_CASES = {
+    # name: (queries, first query, keys, topk, (chunk, key tile), lane sum)
+    "random": (256, 0, 256, 64, (32, 128), "lanes"),
+    # the chunk of queries 32 .. 63 straddles t = topk
+    "topk_not_a_multiple_of_the_chunk": (256, 0, 256, 40, (32, 128),
+                                         "lanes"),
+    "topk_of_one": (128, 0, 128, 1, (32, 128), "lanes"),
+    "topk_past_the_row": (128, 0, 128, 500, (32, 128), "lanes"),
+    # a scan's step: queries 128 .. 191 of a row of 256
+    "a_block_of_a_scan": (64, 128, 256, 40, (32, 128), "lanes"),
+    # the plain form's padded queries past the row see every key
+    "queries_past_the_row": (64, 224, 256, 40, (32, 128), "lanes"),
+    "wide_key_tiles": (256, 0, 512, 100, (64, 256), "lanes"),
+    "the_mxu_s_sum": (256, 0, 256, 40, (64, 128), "mxu"),
+    "a_sum_down_the_sublanes": (256, 0, 256, 40, (128, 128), "sublanes"),
+    "keys_in_identical_pairs": (256, 0, 256, 40, (32, 128), "lanes"),
+    "a_row_of_equal_scores": (256, 0, 256, 40, (32, 128), "lanes"),
+    "signed_zeros_are_one_score": (256, 0, 256, 40, (32, 128), "lanes"),
+    "a_negative_threshold": (256, 0, 256, 40, (32, 128), "lanes"),
+    "ties_across_a_key_tile": (256, 0, 256, 48, (32, 128), "lanes"),
+    "one_row_needs_the_running_count": (256, 0, 256, 40, (32, 128),
+                                        "lanes"),
+    "ties_everywhere": (256, 0, 512, 100, (64, 256), "lanes"),
+    "ties_by_the_mxu_s_sum": (256, 0, 256, 40, (32, 128), "mxu"),
+    "ties_by_a_sum_down_the_sublanes": (128, 128, 256, 40, (128, 128),
+                                        "sublanes"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _index_select_case(name):
+    """(the scores, the kernel's (mask, threshold), the plain
+    `sparse_index.select_rows`'s) of a case, made once."""
+    from paddle_tpu.parallel import index_select, sparse_index
+
+    n, first, S, topk, blocks, lane_sum = INDEX_SELECT_CASES[name]
+    I = _select_case_scores(name, np.random.default_rng(len(name)), n, S)
+    chosen, tau = sparse_index.select_rows(I, first, topk)
+    return I, index_select.select_rows(I, first, topk, blocks, lane_sum), \
+        (np.asarray(chosen).astype(np.int8), np.asarray(tau))
+
+
+@pytest.mark.parametrize("which", ["mask", "threshold"])
+@pytest.mark.parametrize("name", list(INDEX_SELECT_CASES))
+def test_index_select_kernel_interpreted(name, which):
+    """The kernel of `parallel/index_select.py` (interpreted) against the
+    plain `sparse_index.select_rows`, its oracle: mask and threshold EQUAL,
+    every bit. Chunks whose queries all have fewer than `topk` keys, the
+    chunk that straddles t = topk, a block in the middle of a row and one
+    past its end, the three ways a count's lane-wise partial sums are
+    added up, and planted ties: keys in identical pairs, a whole row of
+    equal scores (which keeps its FIRST topk keys), +0.0 and -0.0 one
+    score, a negative threshold, more ties than a row may keep lying
+    across a key tile's boundary, a chunk where one row needs the running
+    count and its neighbours do not."""
+    n, first, S, topk, _, _ = INDEX_SELECT_CASES[name]
+    I, got, want = _index_select_case(name)
+    i = ["mask", "threshold"].index(which)
+    assert got[i].shape == want[i].shape and got[i].dtype == want[i].dtype
+    bits = np.int8 if which == "mask" else np.int32
+    assert np.array_equal(np.asarray(got[i]).view(bits), want[i].view(bits))
+    if which == "threshold":
+        return
+    mask, t = np.asarray(got[0]), first + np.arange(n)
+    assert np.array_equal(mask.sum(axis=1),
+                          np.minimum(np.minimum(t + 1, S), topk))
+    assert not mask[np.arange(S)[None, :] > t[:, None]].any()
+    if name == "a_row_of_equal_scores":
+        assert np.array_equal(np.flatnonzero(mask[n - 3]), np.arange(topk))
+        assert float(got[1][n - 3]) == 0.25
+    if name == "ties_across_a_key_tile":
+        assert np.array_equal(np.flatnonzero(mask[n - 1]),
+                              np.r_[0:40, 120:128])
+        assert float(got[1][n - 1]) == 2.0
+    if name == "signed_zeros_are_one_score":
+        zero = np.asarray(got[1]) == 0
+        assert zero.sum() > n // 2 and not np.signbit(
+            np.asarray(got[1])[zero]).any()
+    if name == "a_negative_threshold":
+        assert (np.asarray(got[1]) < -1).all()
+    if name == "one_row_needs_the_running_count":
+        # the rows around row n - 40 have a key of their own at the
+        # threshold and no other
+        at = np.asarray(I) == np.asarray(got[1])[:, None]
+        at &= np.arange(S)[None, :] <= t[:, None]
+        assert at[n - 40].sum() > 40 and (np.delete(at, n - 40, 0)
+                                          .sum(axis=1) == 1).all()
+
+
+def test_select_rows_takes_the_kernel_on_a_tpu_place_alone(monkeypatch):
+    """`sparse_index.select_rows` hands a block to `index_select
+    .select_rows` only where the step is traced for a TPU place AND
+    `index_select.takes` the shapes: with the place steered true `select`
+    (the score product, the scan over blocks of queries) gives the plain
+    lowering's mask and threshold, bit for bit."""
+    from paddle_tpu.parallel import index_select, sparse_index
+
+    rng = np.random.default_rng(13)
+    rows, S = index_select.BLOCKS               # one chunk, one key tile
+    q_i, k_i, w = (t[0] for t in _indexer_draw(rng, 1, S, hi=4))
+    k_i = k_i[:, 0]
+    plain = sparse_index.select(q_i, k_i, w, 100, block=rows)
+    calls = []
+    real = index_select.select_rows
+    monkeypatch.setattr(
+        index_select, "select_rows",
+        lambda I, first, topk: calls.append(I.shape) or real(
+            I, first, topk, interpret=True))
+    assert index_select.takes(rows, S, 100)
+    assert not index_select.takes(48, 48, 16)      # the model tests' row
+    sparse_index.select(q_i, k_i, w, 100, block=rows)
+    assert calls == []                             # never off a TPU place
+    monkeypatch.setattr(index_select, "pallas_interpret", lambda: False)
+    kernel = sparse_index.select(q_i, k_i, w, 100, block=rows)
+    assert calls == [(rows, S)]                # a scan's step, traced once
+    for a, b in zip(kernel, plain):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    sparse_index.select(q_i, k_i, w, 100, block=rows - 32)
+    assert len(calls) == 1                         # shapes not taken
+
+
+@pytest.mark.parametrize("plant", ["no_relu", "w_one", "scores_bf16",
+                                   "whole_triangle"])
+def test_every_plant_of_the_study_bites_on_the_kernel_path(plant,
+                                                           monkeypatch):
+    """The study one precision down (`chipbench
+    .lower_precision_lm_sparse_attn_share`) plants `no_relu`, `w_one` and
+    `scores_bf16` by replacing `sparse_index.scores`, and `whole_triangle`
+    by replacing `sparse_index.select_rows`, from outside: the kernel
+    TAKES the scores `select` formed and is reached through `select_rows`,
+    so mask and threshold move under every one of them (a kernel that
+    formed the product itself, or that `select` called by another name,
+    would make the plant a no-op)."""
+    from chipbench import lower_precision_lm_sparse_attn_share as study
+    from paddle_tpu.parallel import index_select, sparse_index
+
+    rng = np.random.default_rng(17)
+    q_i, k_i, w = (t[0] for t in _indexer_draw(rng, 1, 256, hi=4))
+    k_i = k_i[:, 0]
+    real = index_select.select_rows
+    monkeypatch.setattr(
+        index_select, "select_rows",
+        lambda I, first, topk: real(I, first, topk, (32, 128),
+                                    interpret=True))
+    monkeypatch.setattr(index_select, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(index_select, "takes", lambda *a: True)
+    stated = sparse_index.select(q_i, k_i, w, 40, block=64)
+    with study._planted(plant):
+        planted = sparse_index.select(q_i, k_i, w, 40, block=64)
+    assert np.asarray(stated[0]).sum() == 40 * 41 // 2 + 216 * 40
+    # (rounding the scores to bf16 moves a few keys a row across the
+    # threshold; the others move a pair in ten or more)
+    moved = (np.asarray(planted[0]) != np.asarray(stated[0])).mean()
+    assert moved > (1e-4 if plant == "scores_bf16" else 0.05)
+    assert not np.array_equal(np.asarray(planted[1])[40:],
+                              np.asarray(stated[1])[40:])
+
+
 def test_the_saved_logsumexp_stays_float32_under_amp():
     """`sparse_attention` is on AMP's white list (bf16 operands into the
     kernels) and its grad op reads `Lse` as the forward left it."""
@@ -870,6 +1056,9 @@ def test_lowered_counts_name_the_new_lowerings(small):
     # 48 tokens are no whole block of the loss's kernels: the plain scan
     assert "indexer_loss_kernel" not in cpu
     assert "indexer_loss_kernel" not in tpu
+    # nor a whole chunk of the selection's: XLA's passes over the block
+    assert "indexer_select_kernel" not in cpu
+    assert "indexer_select_kernel" not in tpu
     # the cell's own: 14,681,088 of 33,558,528 a layer at one row of 8192
     from chipbench import costs_sparse_attn_share as costs
     assert costs.selected_pairs(8192, 2048) == 14681088
@@ -914,6 +1103,45 @@ def test_the_loss_s_kernels_are_counted_at_the_cell_s_shapes(policy):
         == tpu["indexer_loss_with_grads"]
     assert "indexer_loss_kernel" not in cpu
     assert tpu.get("indexer_loss_kernel") == (4 if policy else None)
+
+
+@pytest.mark.parametrize("S,topk,kernel", [(8192, 2048, 4), (8192, 100, 4),
+                                           (4000, 2048, None),
+                                           (65536, 2048, None)])
+def test_the_selection_s_kernel_is_counted_at_the_cell_s_shapes(S, topk,
+                                                                kernel):
+    """Four `indexer_select` ops at the `keye_vl_2_0_30b_a3b` cell's shapes
+    (one row of 8192 tokens, an indexer of 16 heads of 64, topk 2048):
+    `indexer_select_kernel` 4 on a TPU place beside
+    `indexer_select_bisection` 4 (it is still a bisection), whatever the
+    dtype or topk; none for a row that is no whole key tile or whose
+    chunk's scores, keys and mask pass the kernel's share of VMEM; never on
+    the CPU. The counter asks `index_select.takes` of the block of queries
+    `sparse_index.select` hands `select_rows`, as the dispatch does."""
+    from paddle_tpu.ops.lm_ops import lowered_counts
+    from paddle_tpu.parallel import index_select, sparse_index
+
+    class Cpu:
+        platform = "cpu"
+
+    class Tpu:
+        platform = "tpu"
+
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup):
+        L = fluid.layers
+        q_i = L.data(name="qi", shape=[S, 16, 64], dtype="float32")
+        k_i = L.data(name="ki", shape=[S, 1, 64], dtype="float32")
+        w = L.data(name="w", shape=[S, 16], dtype="float32")
+        for _ in range(4):
+            L.indexer_select(q_i, k_i, w, topk)
+    cpu, tpu = lowered_counts(prog, Cpu), lowered_counts(prog, Tpu)
+    assert cpu["indexer_select_bisection"] == 4 \
+        == tpu["indexer_select_bisection"]
+    assert "indexer_select_kernel" not in cpu
+    assert tpu.get("indexer_select_kernel") == kernel
+    assert index_select.takes(min(sparse_index.BLOCK, S), S, topk) \
+        == bool(kernel)
 
 
 @pytest.mark.parametrize("S,fused", [(8192, 4), (16384, None)])

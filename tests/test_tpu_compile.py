@@ -419,8 +419,8 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     from paddle_tpu.core import executor_core
     from paddle_tpu.ops import lm_ops
     from paddle_tpu.parallel import (delta_parts, flash, gated_norm,
-                                     grouped, index_loss, row_sum,
-                                     short_conv)
+                                     grouped, index_loss, index_select,
+                                     row_sum, short_conv)
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     import sys
@@ -438,6 +438,7 @@ def _lm_step(one_chip, monkeypatch, config, rows, fetches):
     monkeypatch.setattr(delta_parts, "pallas_interpret", lambda: False)
     monkeypatch.setattr(gated_norm, "pallas_interpret", lambda: False)
     monkeypatch.setattr(index_loss, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(index_select, "pallas_interpret", lambda: False)
     # the policy on before the build, as the cells have it: the optimizer
     # then keeps the bf16 copies of the expert weights
     amp.enable("bfloat16")

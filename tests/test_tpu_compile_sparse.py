@@ -101,6 +101,33 @@ def test_index_loss_kernels_compile_for_v5e(one_chip, no_compile_cache,
     assert 4 * S * S <= temp < 4 * S * S + 8.0e7, temp
 
 
+def test_index_select_kernel_compiles_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """Mosaic takes the kernel of `parallel/index_select.py` at the
+    `keye_vl_2_0_30b_a3b` cell's shape, as `sparse_index.select`'s scan
+    hands it a block (256 queries of a row of 8192, the block's first query
+    traced; topk 2048): one custom call, no [256, 8192] int32 or uint32
+    array of the plain form's keys and running count beside it, and of
+    temporaries the thresholds' [256, 128] tile alone."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.parallel import index_select, sparse_index
+
+    monkeypatch.setattr(index_select, "pallas_interpret", lambda: False)
+    n, S, topk = sparse_index.BLOCK, 8192, 2048
+    assert index_select.takes(n, S, topk)
+    compiled = jax.jit(
+        lambda I, first: sparse_index.select_rows(I, first, topk)).lower(
+        jax.ShapeDtypeStruct((n, S), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert base._custom_calls(text) == ["index_select"]
+    for block in ("s32[%d,%d]" % (n, S), "u32[%d,%d]" % (n, S)):
+        assert block not in text, block
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 3 * n * S, temp
+
+
 def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
         one_chip, no_compile_cache, monkeypatch):
     """The `keye_vl_2_0_30b_a3b` step at 1 x 8192 tokens (four layers of
@@ -122,8 +149,11 @@ def test_keye_vl_step_runs_the_masked_flash_kernels_and_fits(
         ["sparse_flash_bwd"] * layers + ["sparse_flash_fwd"] * layers
     # the indexer's loss: its two kernels a layer, no scan over blocks of
     # 256 queries with their float32 [8, 256, S] and [256, 16, S] blocks
+    # (and the selection's threshold and mask in ONE kernel a layer, in the
+    # scan over blocks of 256 queries behind the score product)
     assert [c for c in calls if c.startswith("index_")] == \
-        ["index_grads"] * layers + ["index_target"] * layers
+        ["index_grads"] * layers + ["index_select"] * layers \
+        + ["index_target"] * layers
     assert calls.count("row_tile_sum") >= 1
     assert base.ragged_dots(text) == []
     S = cfg["sequence_length"]
